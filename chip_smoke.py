@@ -1,0 +1,668 @@
+#!/usr/bin/env python
+"""chip_smoke.py — the quickest proof that the system starts on the chip.
+
+    python chip_smoke.py              # one chip: train, serve, kernels, cache
+    python chip_smoke.py --multichip  # four chips: only the sharded paths
+
+One process, the entry points a user calls (``parallel.TrainStep``,
+``serving.Server``, ``text.generation.Generator``, ``ops.pallas``), random
+weights from ``--seed``, full widths with depth as the models ship it.  It
+is a smoke run, not a benchmark: the seconds it prints say that a phase ran,
+not how fast the system is.
+
+Every phase prints one JSON line (phase, seconds, compile_seconds, checked);
+any failed check raises, so the run cannot end with exit code 0.  The LAST
+line of stdout is exactly
+
+    {"ok": true, "device": {"platform": "tpu", "kind": "...", "count": N}}
+
+The script refuses to run (non-zero exit, no result line) when
+``jax.devices()[0].platform`` is not ``tpu``.  The phase functions take a
+``Size``; ``tests/test_chip_smoke.py`` drives them at ``TINY`` on the CPU.
+"""
+from __future__ import annotations
+
+import argparse
+import json
+import shutil
+import sys
+import time
+from dataclasses import dataclass
+from typing import Sequence, Tuple
+
+import numpy as np
+
+import jax
+import jax.numpy as jnp
+
+import paddle_tpu as paddle
+from paddle_tpu import serving
+from paddle_tpu.framework.flags import flags_restore, flags_snapshot, set_flags
+from paddle_tpu.parallel import TrainStep, init_mesh, make_mesh
+from paddle_tpu.profiler import ledger
+from paddle_tpu.text.generation import Generator
+from paddle_tpu.text.models.bert import (BertConfig, BertForPretraining,
+                                         apply_tensor_parallel)
+from paddle_tpu.text.models.gpt import (GPTConfig, GPTModel, GPTMoEConfig,
+                                        GPTMoEModel)
+from paddle_tpu.utils import cache_dirs
+
+# -- tolerances ---------------------------------------------------------------
+# bf16 keeps 8 bits of mantissa (eps 2**-8); 12 layers of bf16 matmuls at
+# hidden 768 put the run-to-run difference between two evaluation orders of
+# the same logits at about 1% of their range.
+#
+# LOGIT_TOL: relative to max|logit| — first-token logits of the cached
+# prefill vs the plain (uncached) forward, and the top-2 margin below which
+# a token difference between the server and generate() counts as a near-tie
+# of random weights.  A difference at a larger margin fails.
+LOGIT_TOL = 2.0 ** -5
+# sharded vs one-device bf16 training loss (tests/test_parallel.py's 1e-5 is
+# for f32 on the CPU): relative, per step
+LOSS_TOL = 2e-2
+# Pallas kernel vs XLA reference (f32, highest precision) on bf16 inputs:
+# (atol, rtol).  flash-decode and fused BN use their CPU tests' bf16 cases
+# (tests/test_pallas_flash_decode.py 4e-3/2e-2, test_pallas_fused_bn.py
+# 5e-2/5e-2); flash attention and fused conv only have f32 cases on the CPU,
+# so they take the same bf16 form here.
+KERNEL_TOL = {
+    "flash_attention_fwd": (2e-2, 2e-2),
+    "flash_attention_bwd": (5e-2, 5e-2),
+    "flash_decode": (4e-3, 2e-2),
+    "flash_decode_int8": (4e-3, 2e-2),
+    "fused_conv_bn_relu": (5e-2, 5e-2),
+    "fused_bn_relu": (5e-2, 5e-2),
+}
+
+
+@dataclass(frozen=True)
+class Size:
+    """What one run of the phases builds: FULL on the chip, TINY on the CPU."""
+    name: str
+    bert: BertConfig
+    train_batch: int
+    train_seq: int
+    train_steps: int
+    gpt: GPTConfig
+    serve_batch_buckets: Tuple[int, ...]
+    serve_seq_buckets: Tuple[int, ...]
+    serve_max_new: int
+    serve_max_len: int
+    prompt_lens: Tuple[int, ...]
+    slots: int
+    attn: Tuple[int, int, int, int]            # B, N, S, H
+    conv_x: Tuple[int, int, int, int]          # N, H, W, C (NHWC)
+    conv_w: Tuple[int, int, int, int]          # O, I, kh, kw
+    moe: GPTMoEConfig
+    moe_batch: int
+    moe_seq: int
+
+
+def _moe_cfg(**kw) -> GPTMoEConfig:
+    cfg = GPTMoEConfig.tiny(top_k=2, capacity_factor=1.25, **kw)
+    cfg.dropout = 0.0
+    return cfg
+
+
+# bench.py's on-chip configurations (bench_bert, bench_decode, bench_moe);
+# the MoE stack keeps its width and is cut to 4 layers
+FULL = Size(
+    name="full", bert=BertConfig.base(), train_batch=64, train_seq=128,
+    train_steps=6,
+    gpt=GPTConfig(vocab_size=32000, hidden_size=768, num_layers=12,
+                  num_heads=12, intermediate_size=3072,
+                  max_position_embeddings=1024, dropout=0.0),
+    serve_batch_buckets=(1, 4), serve_seq_buckets=(32, 128),
+    serve_max_new=16, serve_max_len=256, prompt_lens=(5, 19, 32, 70, 128, 9),
+    slots=8, attn=(8, 12, 1024, 64), conv_x=(32, 56, 56, 64),
+    conv_w=(64, 64, 3, 3),
+    moe=_moe_cfg(vocab_size=128, hidden_size=512, layers=4, heads=8,
+                 seq=128, experts=16),
+    moe_batch=32, moe_seq=128)
+
+TINY = Size(
+    name="tiny", bert=BertConfig.tiny(seq=128), train_batch=8, train_seq=32,
+    train_steps=6,
+    gpt=GPTConfig.tiny(vocab_size=128, hidden_size=32, layers=2, heads=2,
+                       seq=128),
+    serve_batch_buckets=(1, 2), serve_seq_buckets=(8, 16), serve_max_new=4,
+    serve_max_len=32, prompt_lens=(3, 7, 12, 1, 9, 5), slots=4,
+    attn=(1, 2, 256, 64), conv_x=(2, 8, 8, 8), conv_w=(8, 8, 3, 3),
+    moe=_moe_cfg(vocab_size=64, hidden_size=16, layers=2, heads=2, seq=32,
+                 experts=4),
+    moe_batch=8, moe_seq=16)
+
+
+def device_record() -> dict:
+    devs = jax.devices()
+    return {"platform": devs[0].platform, "kind": devs[0].device_kind,
+            "count": len(devs)}
+
+
+def _emit(phase: str, t0: float, compile_s: float, checked: dict) -> dict:
+    rec = {"phase": phase, "seconds": round(time.perf_counter() - t0, 3),
+           "compile_seconds": round(compile_s, 3), "checked": checked}
+    print(json.dumps(rec), flush=True)
+    return rec
+
+
+def _check(ok: bool, what: str) -> None:
+    if not ok:
+        raise AssertionError(what)
+
+
+def _platforms(tree) -> set:
+    return {d.platform for leaf in jax.tree_util.tree_leaves(tree)
+            for d in leaf.devices()}
+
+
+# -- train --------------------------------------------------------------------
+
+def _bert_batch(cfg: BertConfig, batch: int, seq: int, seed: int):
+    """bench.py's BERT pretraining feed: fixed masked positions per row."""
+    rng = np.random.RandomState(seed)
+    ids = rng.randint(0, cfg.vocab_size, (batch, seq))
+    n_pred = max(2, int(seq * 0.15))
+    pos = np.stack([rng.choice(seq, size=n_pred, replace=False)
+                    for _ in range(batch)]).astype("int64")
+    labels = np.take_along_axis(ids, pos, 1)
+    return (jnp.asarray(ids), None, None, jnp.asarray(labels), None,
+            jnp.asarray(pos))
+
+
+def _bert_step(size: Size, mesh, seed: int, tensor_parallel: bool = False):
+    paddle.seed(seed)
+    model = BertForPretraining(size.bert)
+    if tensor_parallel:
+        apply_tensor_parallel(model)
+    opt = paddle.optimizer.AdamW(parameters=model.parameters(),
+                                 learning_rate=1e-4, weight_decay=0.01)
+    return TrainStep(model, opt, mesh=mesh, compute_dtype=jnp.bfloat16,
+                     seed=seed)
+
+
+def _run_steps(step: TrainStep, feed, n: int):
+    """n steps on ``feed`` = (inputs, label); returns (losses, seconds of
+    the first step, seconds of the rest)."""
+    t0 = time.perf_counter()
+    losses = [float(step(*feed))]
+    t1 = time.perf_counter()
+    losses += [float(step(*feed)) for _ in range(n - 1)]
+    return losses, t1 - t0, time.perf_counter() - t1
+
+
+def phase_train(size: Size, seed: int = 0) -> dict:
+    """BERT pretraining steps through init_mesh + TrainStep, as bench.py's
+    headline builds it: loss finite and falling, state on the device."""
+    t0 = time.perf_counter()
+    platform = jax.devices()[0].platform
+    step = _bert_step(size, init_mesh({"dp": -1}), seed)
+    args = _bert_batch(size.bert, size.train_batch, size.train_seq, seed)
+    losses, first_s, rest_s = _run_steps(step, (args, None), size.train_steps)
+    _check(all(np.isfinite(losses)), f"train: non-finite loss {losses}")
+    _check(losses[-1] < losses[0], f"train: loss did not fall {losses}")
+    where = _platforms((step.state["params"], step.state["opt"]))
+    _check(where == {platform},
+           f"train: params/optimizer state on {where}, not {platform}")
+    steady = rest_s / (size.train_steps - 1)
+    # does block_until_ready fence?  One more step: after blocking on its
+    # loss, fetching that loss to the host must find it already there
+    t1 = time.perf_counter()
+    loss = jax.block_until_ready(step(args))
+    t2 = time.perf_counter()
+    float(loss)
+    fetch_s = time.perf_counter() - t2
+    _check(fetch_s < 0.5 * (t2 - t1),
+           f"train: block_until_ready returned after {t2 - t1:.4f}s but the "
+           f"fetch then took {fetch_s:.4f}s — it does not fence")
+    return _emit("train", t0, max(0.0, first_s - steady), {
+        "model": f"bert {size.name}", "batch": size.train_batch,
+        "seq": size.train_seq, "steps": size.train_steps,
+        "loss_first": losses[0], "loss_last": losses[-1],
+        "steady_step_seconds": round(steady, 4),
+        "block_until_ready_seconds": round(t2 - t1, 4),
+        "fetch_after_block_seconds": round(fetch_s, 6),
+        "state_platform": sorted(where)})
+
+
+# -- serve + cache ------------------------------------------------------------
+
+def _gpt(size: Size, seed: int) -> GPTModel:
+    paddle.seed(seed)
+    model = GPTModel(size.gpt)
+    model.eval()
+    paddle.amp.decorate(models=model, level="O2", dtype="bfloat16")
+    return model
+
+
+def _prompts(size: Size, seed: int):
+    rng = np.random.RandomState(seed)
+    return [rng.randint(1, size.gpt.vocab_size, n) for n in size.prompt_lens]
+
+
+def _plain_logits(model: GPTModel, ids: np.ndarray) -> np.ndarray:
+    """Next-token logits of the plain (uncached, unpadded) forward."""
+    out = model(paddle.to_tensor(ids[None, :].astype(np.int64)))
+    return np.asarray(out.numpy(), np.float32)[0, -1]
+
+
+def _boot(model: GPTModel, size: Size, slots: int):
+    """Server -> register_decode -> start(); returns (server, warm-up
+    seconds, ledger events of the warm-up)."""
+    set_flags({"FLAGS_decode_slots": slots})
+    mark = len(ledger.compile_events())
+    srv = serving.Server(serving.ServingConfig(workers=2))
+    srv.register_decode("gpt", model, batch_buckets=size.serve_batch_buckets,
+                        seq_buckets=size.serve_seq_buckets,
+                        max_new_tokens=size.serve_max_new,
+                        max_len=size.serve_max_len)
+    t0 = time.perf_counter()
+    srv.start()
+    return srv, time.perf_counter() - t0, ledger.compile_events()[mark:]
+
+
+def _serve(srv, size: Size, prompts) -> list:
+    futs = [srv.submit_decode("gpt", [p], max_new_tokens=size.serve_max_new)
+            for p in prompts]
+    toks = [np.asarray(f.result(timeout=600)[0][0]) for f in futs]
+    srv.assert_zero_steady_state_recompiles()
+    return toks
+
+
+def _kv_platforms(srv, oracle: Generator, size: Size, slots: int) -> set:
+    """Where the KV cache lives: the slot loop's resident ring, or (scanned
+    mode, one cache per request) what the prefill executable returns."""
+    if slots:
+        return _platforms(srv._models["gpt"]._loop._cache)
+    p = size.serve_seq_buckets[0]
+    ids, start = oracle.pack_prompts([np.ones((p,), np.int32)], p)
+    cache, _ = oracle.prefill(ids, start,
+                              oracle.cache_bucket(p, size.serve_max_new))
+    return _platforms(cache)
+
+
+def _tokens_agree(model, prompt, want, got, tol: float) -> int:
+    """Served tokens must equal generate()'s up to the first position where
+    the reference's top-2 logit margin is below ``tol`` (a near-tie of
+    random weights under bf16); returns how many positions were compared
+    equal.  A difference at a larger margin raises."""
+    for k in range(len(want)):
+        if want[k] == got[k]:
+            continue
+        ctx = np.concatenate([prompt, want[:k]])
+        logits = _plain_logits(model, ctx)
+        top2 = np.sort(logits)[-2:]
+        margin = float(top2[1] - top2[0])
+        bound = tol * float(np.abs(logits).max())
+        _check(margin < bound,
+               f"serve: token {k} differs ({got[k]} vs {want[k]}) at "
+               f"top-2 margin {margin:.4g} >= {bound:.4g}")
+        return k
+    return len(want)
+
+
+def phase_serve(size: Size, seed: int = 0) -> dict:
+    """The decode server, scanned run-to-completion and slot loop, against
+    Generator.generate on the same prompts.  Fills the persistent
+    executable cache that phase_cache reads."""
+    t0 = time.perf_counter()
+    platform = jax.devices()[0].platform
+    model = _gpt(size, seed)
+    prompts = _prompts(size, seed + 1)
+    oracle = Generator(model, seq_buckets=size.serve_seq_buckets,
+                       max_len=size.serve_max_len)
+    want, logit_err = [], 0.0
+    for p in prompts:
+        want.append(np.asarray(oracle.generate(
+            p[None, :].astype(np.int64),
+            max_new_tokens=size.serve_max_new).numpy())[0])
+        # first-token logits: cached, left-padded, bucketed prefill vs the
+        # plain forward of the same bf16 model
+        bucket = oracle.prefill_bucket(len(p))
+        ids, start = oracle.pack_prompts([p], bucket)
+        _, logits0 = oracle.prefill(
+            ids, start, oracle.cache_bucket(bucket, size.serve_max_new))
+        got0 = np.asarray(logits0, np.float32)[0]
+        ref0 = _plain_logits(model, p)
+        _check(got0.shape == ref0.shape and np.isfinite(got0).all(),
+               f"serve: first-token logits {got0.shape} not finite "
+               f"{ref0.shape}")
+        err = float(np.abs(got0 - ref0).max() / np.abs(ref0).max())
+        _check(err < LOGIT_TOL, f"serve: first-token logits off by {err:.4g} "
+                                f"of max|logit| (tolerance {LOGIT_TOL:.4g})")
+        logit_err = max(logit_err, err)
+
+    exec_dir = cache_dirs.executable_cache_dir("chip_smoke")
+    shutil.rmtree(exec_dir, ignore_errors=True)   # this run stores, then loads
+    snap = flags_snapshot()
+    runs, compile_s = {}, 0.0
+    try:
+        set_flags({"FLAGS_executable_cache": "readwrite",
+                   "FLAGS_executable_cache_dir": exec_dir})
+        for slots in (0, size.slots):
+            srv, warm_s, events = _boot(model, size, slots)
+            try:
+                toks = _serve(srv, size, prompts)
+                kv = _kv_platforms(srv, oracle, size, slots)
+            finally:
+                srv.stop()
+            _check(kv == {platform},
+                   f"serve: KV cache on {kv}, not {platform}")
+            compared = [_tokens_agree(model, p, w, g, LOGIT_TOL)
+                        for p, w, g in zip(prompts, want, toks)]
+            compile_s += warm_s
+            runs[slots] = {"tokens": toks, "warmup_seconds": round(warm_s, 3),
+                           "warmup_compiles": len(events),
+                           "positions_equal": int(sum(compared)),
+                           "positions": int(sum(len(w) for w in want))}
+    finally:
+        flags_restore(snap)
+    rec = _emit("serve", t0, compile_s, {
+        "model": f"gpt {size.name} bf16", "requests": len(prompts),
+        "prompt_lens": list(size.prompt_lens),
+        "max_new_tokens": size.serve_max_new,
+        "first_token_logit_err": round(logit_err, 5),
+        "logit_tolerance": LOGIT_TOL,
+        "runs": {f"decode_slots={s}": {k: v for k, v in r.items()
+                                       if k != "tokens"}
+                 for s, r in runs.items()},
+        "zero_steady_state_recompiles": True, "kv_platform": platform})
+    return {"record": rec, "model": model, "prompts": prompts,
+            "tokens": {s: r["tokens"] for s, r in runs.items()},
+            "exec_dir": exec_dir}
+
+
+def phase_cache(size: Size, served: dict) -> dict:
+    """A second Server per decode runtime, same process, over the executable
+    cache phase_serve filled: every warm-up event is a cache_load (zero
+    fresh compiles) and the tokens are bit-equal to the first boot's."""
+    t0 = time.perf_counter()
+    snap = flags_snapshot()
+    loads, load_s = 0, 0.0
+    try:
+        set_flags({"FLAGS_executable_cache": "readwrite",
+                   "FLAGS_executable_cache_dir": served["exec_dir"]})
+        for slots, first in served["tokens"].items():
+            srv, warm_s, events = _boot(served["model"], size, slots)
+            try:
+                toks = _serve(srv, size, served["prompts"])
+            finally:
+                srv.stop()
+            kinds = [e["kind"] for e in events]
+            _check(kinds and all(k == "cache_load" for k in kinds),
+                   f"cache: decode_slots={slots} warm-up compiled: {kinds}")
+            for a, b in zip(first, toks):
+                _check(np.array_equal(a, b),
+                       f"cache: decode_slots={slots} tokens changed")
+            loads += len(kinds)
+            load_s += warm_s
+    finally:
+        flags_restore(snap)
+    return _emit("cache", t0, 0.0, {
+        "executables_loaded": loads, "fresh_compiles": 0,
+        "second_boot_seconds": round(load_s, 3), "tokens_bit_equal": True})
+
+
+# -- kernels ------------------------------------------------------------------
+
+def _close(name: str, got, ref) -> float:
+    atol, rtol = KERNEL_TOL[name]
+    worst = 0.0
+    for g, r in zip(jax.tree_util.tree_leaves(got),
+                    jax.tree_util.tree_leaves(ref)):
+        g, r = np.asarray(g, np.float32), np.asarray(r, np.float32)
+        _check(g.shape == r.shape and np.isfinite(g).all(),
+               f"kernels: {name} shape {g.shape} vs {r.shape} / non-finite")
+        err = np.abs(g - r) - (atol + rtol * np.abs(r))
+        worst = max(worst, float(np.abs(g - r).max()))
+        _check(bool((err <= 0).all()),
+               f"kernels: {name} off by {float(np.abs(g - r).max()):.4g} "
+               f"(atol {atol}, rtol {rtol})")
+    return worst
+
+
+def _run_kernel(name: str, fn, ref_fn, args, compiled_expected: bool):
+    """AOT-compile ``fn`` at ``args``, require the Mosaic custom call in the
+    lowered program, run it, compare with ``ref_fn`` (f32, highest matmul
+    precision).  Returns (compile seconds, max abs error)."""
+    t0 = time.perf_counter()
+    compiled = jax.jit(fn).lower(*args).compile()
+    compile_s = time.perf_counter() - t0
+    if compiled_expected:
+        _check("tpu_custom_call" in compiled.as_text(),
+               f"kernels: {name} lowered without tpu_custom_call "
+               "(interpret mode or reference fallback)")
+    got = jax.block_until_ready(compiled(*args))
+    with jax.default_matmul_precision("highest"):
+        ref = jax.jit(ref_fn)(*args)
+    return compile_s, _close(name, got, ref)
+
+
+def _attention_ref(q, k, v):
+    q, k, v = (a.astype(jnp.float32) for a in (q, k, v))
+    s = jnp.einsum("bnsh,bnth->bnst", q, k) / np.sqrt(q.shape[-1])
+    mask = jnp.tril(jnp.ones(s.shape[-2:], bool))
+    p = jax.nn.softmax(jnp.where(mask, s, -1e30), axis=-1)
+    return jnp.einsum("bnst,bnth->bnsh", p, v)
+
+
+def _conv_bn_relu_ref(x, w, gamma, beta):
+    xf, wf = x.astype(jnp.float32), w.astype(jnp.float32)
+    y = jax.lax.conv_general_dilated(
+        xf, jnp.transpose(wf, (2, 3, 1, 0)), (1, 1), [(1, 1), (1, 1)],
+        dimension_numbers=("NHWC", "HWIO", "NHWC"))
+    return _bn_relu_ref(y, gamma, beta)
+
+
+def _bn_relu_ref(x, gamma, beta):
+    xf = x.astype(jnp.float32)
+    axes = tuple(range(xf.ndim - 1))
+    mean, var = xf.mean(axes), xf.var(axes)
+    y = (xf - mean) * jax.lax.rsqrt(var + 1e-5) * gamma + beta
+    return jnp.maximum(y, 0.0), mean, var
+
+
+def phase_kernels(size: Size, seed: int = 0) -> dict:
+    """Every Pallas kernel a default or a flag can reach, compiled (not
+    interpreted) once at a main-path shape, against its XLA reference."""
+    from paddle_tpu.ops.pallas import (decode_attention_reference,
+                                       dequantize_kv, flash_attention_fn,
+                                       flash_decode_fn,
+                                       flash_decode_quant_fn, fused_bn,
+                                       fused_conv)
+    from paddle_tpu.nn.layer.transformer import quantize_kv_rows
+    t0 = time.perf_counter()
+    on_chip = jax.default_backend() == "tpu"
+    rng = np.random.RandomState(seed)
+    bf = jnp.bfloat16
+
+    def rand(shape, dtype=bf, scale=1.0):
+        return jnp.asarray(rng.randn(*shape) * scale, dtype)
+
+    B, N, S, H = size.attn
+    q, k, v = rand((B, N, S, H)), rand((B, N, S, H)), rand((B, N, S, H))
+    q1 = rand((B, N, 1, H))
+    # a left-padded ring: each row's valid window starts somewhere else
+    start = jnp.asarray(rng.randint(0, S // 2, (B,)), jnp.int32)
+    end = jnp.asarray(rng.randint(S // 2 + 1, S + 1, (B,)), jnp.int32)
+    k8, ks = quantize_kv_rows(k.astype(jnp.float32))
+    v8, vs = quantize_kv_rows(v.astype(jnp.float32))
+    x, w = rand(size.conv_x), rand(size.conv_w, scale=0.1)
+    cout = size.conv_w[0]
+    gamma = jnp.asarray(1.0 + 0.1 * rng.randn(cout), jnp.float32)
+    beta = jnp.asarray(0.1 * rng.randn(cout), jnp.float32)
+    x2d = rand((int(np.prod(size.conv_x[:3])), size.conv_x[3]))
+    gamma2, beta2 = gamma[:x2d.shape[1]], beta[:x2d.shape[1]]
+
+    def loss(fn):
+        return lambda *a: fn(*a).astype(jnp.float32).sum()
+
+    cases = {
+        "flash_attention_fwd": (
+            lambda q, k, v: flash_attention_fn(q, k, v, causal=True),
+            _attention_ref, (q, k, v)),
+        "flash_attention_bwd": (
+            jax.grad(loss(lambda q, k, v: flash_attention_fn(
+                q, k, v, causal=True)), argnums=(0, 1, 2)),
+            jax.grad(loss(_attention_ref), argnums=(0, 1, 2)), (q, k, v)),
+        "flash_decode": (
+            flash_decode_fn,
+            lambda q, k, v, s, e: decode_attention_reference(
+                *(a.astype(jnp.float32) for a in (q, k, v)), s, e),
+            (q1, k, v, start, end)),
+        "flash_decode_int8": (
+            flash_decode_quant_fn,
+            lambda q, k, v, ks, vs, s, e: decode_attention_reference(
+                q.astype(jnp.float32), dequantize_kv(k, ks),
+                dequantize_kv(v, vs), s, e),
+            (q1, k8, v8, ks, vs, start, end)),
+        "fused_conv_bn_relu": (
+            lambda x, w, g, b: fused_conv.fused_conv_bn_act(
+                x, w, g, b, 1, 1, 1e-5, True),
+            _conv_bn_relu_ref, (x, w, gamma, beta)),
+        "fused_bn_relu": (
+            lambda x, g, b: fused_bn.fused_bn_act(x, g, b, 1e-5, True),
+            _bn_relu_ref, (x2d, gamma2, beta2)),
+    }
+    checked, compile_s = {}, 0.0
+    for name, (fn, ref_fn, args) in cases.items():
+        c_s, err = _run_kernel(name, fn, ref_fn, args, on_chip)
+        compile_s += c_s
+        checked[name] = {"compile_seconds": round(c_s, 3),
+                         "max_abs_err": round(err, 6),
+                         "tolerance": list(KERNEL_TOL[name])}
+    checked["compiled_not_interpreted"] = on_chip
+    checked["shapes"] = {"attention": list(size.attn),
+                         "conv_x": list(size.conv_x),
+                         "conv_w": list(size.conv_w)}
+    return _emit("kernels", t0, compile_s, checked)
+
+
+# -- multichip ----------------------------------------------------------------
+
+def _shard_census(params: dict, mesh) -> dict:
+    """Every parameter lives on all of the mesh's devices; one whose spec
+    names a mesh axis is really cut along it."""
+    n_dev = mesh.devices.size
+    sharded = 0
+    for name, arr in params.items():
+        devs = {s.device for s in arr.addressable_shards}
+        _check(len(devs) == n_dev,
+               f"multichip: {name} lives on {len(devs)} of {n_dev} devices")
+        want = int(np.prod([mesh.shape[a] for entry in arr.sharding.spec
+                            if entry is not None
+                            for a in ((entry,) if isinstance(entry, str)
+                                      else entry)]))
+        pieces = {tuple((sl.start, sl.stop) for sl in s.index)
+                  for s in arr.addressable_shards}
+        _check(len(pieces) == want,
+               f"multichip: {name} spec {arr.sharding.spec} has "
+               f"{len(pieces)} distinct shards, expected {want}")
+        sharded += want > 1
+    return {"params": len(params), "params_sharded": sharded,
+            "devices": n_dev}
+
+
+def phase_multichip_train(size: Size, devices: Sequence, seed: int = 0
+                          ) -> dict:
+    """The train phase's BERT TrainStep on a dp=2 x mp=2 mesh (tensor-parallel
+    layout as __graft_entry__'s dry run applies it) against the same steps
+    from the same seed on one device of the same process."""
+    t0 = time.perf_counter()
+    _check(len(devices) == 4, f"multichip: need 4 devices, got {len(devices)}")
+    args = _bert_batch(size.bert, size.train_batch, size.train_seq, seed)
+    one = _bert_step(size, make_mesh({"dp": 1}, devices=devices[:1]), seed)
+    ref, _, _ = _run_steps(one, (args, None), size.train_steps)
+    del one
+    mesh = init_mesh({"dp": 2, "mp": 2}, devices=devices)
+    step = _bert_step(size, mesh, seed, tensor_parallel=True)
+    losses, first_s, rest_s = _run_steps(step, (args, None), size.train_steps)
+    _check(all(np.isfinite(losses)) and losses[-1] < losses[0],
+           f"multichip: sharded loss not finite/falling {losses}")
+    for i, (a, b) in enumerate(zip(losses, ref)):
+        _check(abs(a - b) <= LOSS_TOL * abs(b),
+               f"multichip: step {i} loss {a} vs one-device {b} "
+               f"(tolerance {LOSS_TOL} relative)")
+    census = _shard_census(step.state["params"], mesh)
+    _check(census["params_sharded"] > 0, "multichip: no parameter is sharded")
+    steady = rest_s / (size.train_steps - 1)
+    return _emit("multichip_train", t0, max(0.0, first_s - steady), {
+        "model": f"bert {size.name}", "mesh": {"dp": 2, "mp": 2},
+        "steps": size.train_steps, "losses": losses,
+        "losses_one_device": ref, "loss_tolerance": LOSS_TOL, **census})
+
+
+def phase_multichip_moe(size: Size, devices: Sequence, seed: int = 0) -> dict:
+    """GPTMoEModel TrainStep with ep=4 (ops/routing.py's shard_map
+    all-to-all) against the dense-dispatch control the MoE tests use: the
+    loss of the first step, and the loss of a second step that has the
+    first one's update in it (whether it falls is not asked: at lr 1e-3
+    this stack's first AdamW step overshoots, in both dispatches alike).
+    (Parameters are not compared one by one:
+    AdamW's first step moves a weight by lr * sign(grad), so on the chip
+    the matmul rounding flips near-zero gradients by 2 * lr either way;
+    the CPU tests' bit-equality is an f32 property.)"""
+    t0 = time.perf_counter()
+    mesh = make_mesh({"ep": len(devices)}, devices=devices)
+    ids = jnp.asarray(np.random.RandomState(seed).randint(
+        0, size.moe.vocab_size, (size.moe_batch, size.moe_seq)))
+    losses, first_s, steps = {}, {}, {}
+    for dispatch in ("routed", "dense"):
+        paddle.seed(seed)
+        model = GPTMoEModel(size.moe, mesh=mesh, dispatch=dispatch,
+                            annotate=(dispatch == "routed"))
+        opt = paddle.optimizer.AdamW(parameters=model.parameters(),
+                                     learning_rate=1e-3)
+        steps[dispatch] = TrainStep(model, opt, mesh=mesh, seed=seed)
+        losses[dispatch], first_s[dispatch], _ = _run_steps(
+            steps[dispatch], ((ids, ids), None), 2)
+    for i, (a, b) in enumerate(zip(losses["routed"], losses["dense"])):
+        _check(np.isfinite(a) and abs(a - b) <= LOSS_TOL * abs(b),
+               f"multichip: MoE step {i} routed loss {a} vs dense "
+               f"control {b} (tolerance {LOSS_TOL} relative)")
+    params = steps["routed"].state["params"]
+    stack = next(n for n in params if n.endswith("experts.w1"))
+    held = {s.device for s in params[stack].addressable_shards}
+    _check(len(held) == len(devices),
+           f"multichip: expert stack on {len(held)} devices")
+    return _emit("multichip_moe", t0, first_s["routed"], {
+        "model": "gpt_moe", "mesh": {"ep": len(devices)},
+        "losses_routed": losses["routed"], "losses_dense": losses["dense"],
+        "loss_tolerance": LOSS_TOL, "expert_stack_devices": len(held)})
+
+
+# -- entry --------------------------------------------------------------------
+
+def main(argv=None) -> int:
+    ap = argparse.ArgumentParser(description=__doc__.split("\n\n")[0])
+    ap.add_argument("--multichip", action="store_true",
+                    help="run only the four-chip sharded paths and what "
+                         "they are compared with (no one-chip phase)")
+    ap.add_argument("--seed", type=int, default=0)
+    args = ap.parse_args(argv)
+
+    device = device_record()
+    if device["platform"] != "tpu":
+        print(f"chip_smoke: needs a TPU, JAX found {device} — no phase was "
+              "run (the CPU rehearsal is tests/test_chip_smoke.py)",
+              file=sys.stderr)
+        return 1
+    cache_dirs.enable_jax_compile_cache()
+    if args.multichip:
+        if device["count"] != 4:
+            print(f"chip_smoke: --multichip needs 4 chips, JAX found "
+                  f"{device['count']}", file=sys.stderr)
+            return 1
+        phase_multichip_train(FULL, jax.devices(), args.seed)
+        phase_multichip_moe(FULL, jax.devices(), args.seed)
+    else:
+        phase_train(FULL, args.seed)
+        served = phase_serve(FULL, args.seed)
+        phase_kernels(FULL, args.seed)
+        phase_cache(FULL, served)
+    print(json.dumps({"ok": True, "device": device}), flush=True)
+    return 0
+
+
+if __name__ == "__main__":
+    sys.exit(main())

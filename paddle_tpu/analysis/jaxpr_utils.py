@@ -13,6 +13,7 @@ from __future__ import annotations
 from typing import Iterator, List, Optional, Set, Tuple
 
 import jax
+from jax.extend.core import Literal
 
 
 def user_source(eqn) -> Optional[str]:
@@ -22,7 +23,7 @@ def user_source(eqn) -> Optional[str]:
     but resolved to the outermost user frame)."""
     try:
         from jax._src import source_info_util
-        frame = source_info_util.user_frame(eqn.source_info)
+        frame = source_info_util.user_frame(eqn.source_info.traceback)
         if frame is None:
             return None
         return (f"{frame.file_name}:{frame.start_line}"
@@ -109,7 +110,7 @@ def dead_eqns(closed_jaxpr) -> List[object]:
     DCE'd by jax itself at lowering and their liveness is relative to
     their own carry."""
     jaxpr = getattr(closed_jaxpr, "jaxpr", closed_jaxpr)
-    live = {v for v in jaxpr.outvars if not isinstance(v, jax.core.Literal)}
+    live = {v for v in jaxpr.outvars if not isinstance(v, Literal)}
     # backwards sweep: an eqn is live iff any output is live (or it has
     # effects); its inputs then become live
     dead: List[object] = []
@@ -118,7 +119,7 @@ def dead_eqns(closed_jaxpr) -> List[object]:
                         for v in eqn.outvars)
         if outs_live or getattr(eqn, "effects", None):
             for v in eqn.invars:
-                if not isinstance(v, jax.core.Literal):
+                if not isinstance(v, Literal):
                     live.add(v)
         else:
             dead.append(eqn)
@@ -136,7 +137,7 @@ def static_vars(jaxpr) -> Set[object]:
     for eqn in jaxpr.eqns:
         if getattr(eqn, "effects", None):
             continue
-        if all(isinstance(v, jax.core.Literal) or v in static
+        if all(isinstance(v, Literal) or v in static
                for v in eqn.invars):
             static.update(v for v in eqn.outvars
                           if type(v).__name__ != "DropVar")

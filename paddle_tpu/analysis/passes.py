@@ -386,8 +386,7 @@ def _layout(ctx: LintContext) -> List[Diagnostic]:
         return out
     pid = "layout"
     seen = set()
-    import jax as _jax
-    from .jaxpr_utils import static_vars
+    from .jaxpr_utils import Literal, static_vars
     for jaxpr in iter_jaxprs(ctx.closed_jaxpr):
         # per-level static set: slice starts that are functions of
         # trace-time constants fold away; only genuinely traced offsets
@@ -395,7 +394,7 @@ def _layout(ctx: LintContext) -> List[Diagnostic]:
         statics = static_vars(jaxpr)
 
         def _static(v):
-            return isinstance(v, _jax.core.Literal) or v in statics
+            return isinstance(v, Literal) or v in statics
 
         for eqn in jaxpr.eqns:
             name = eqn.primitive.name

@@ -39,7 +39,9 @@ Key discipline (what makes a load safe):
 
 Entry layout under ``FLAGS_executable_cache_dir``::
 
-    <digest>.pjrt   pickled (blob, in_tree, out_tree) from serialize()
+    <digest>.pjrt   pickled (blob, in_tree, out_tree, device_ids): what
+                    serialize() returns plus the ids of the devices the
+                    executable was compiled for, in assignment order
     <digest>.json   manifest: sha256 of the payload + key/kind/site/
                     fingerprint provenance + hit count
 
@@ -278,9 +280,16 @@ class ExecutableCache:
         try:
             from jax.experimental.serialize_executable import (
                 deserialize_and_load)
+            import jax
             with open(path, "rb") as f:
-                blob, in_tree, out_tree = pickle.load(f)
-            compiled = deserialize_and_load(blob, in_tree, out_tree)
+                blob, in_tree, out_tree, device_ids = pickle.load(f)
+            # load onto the executable's own devices: the default is ALL
+            # local devices, which breaks a one-device executable on a
+            # multi-device host at its first call
+            by_id = {d.id: d for d in jax.devices()}
+            compiled = deserialize_and_load(
+                blob, in_tree, out_tree,
+                execution_devices=[by_id[i] for i in device_ids])
         except Exception:
             # jaxlib moved underneath the fingerprint, or the pickle is
             # subtly poisoned: heal by recompiling
@@ -313,7 +322,10 @@ class ExecutableCache:
         try:
             from jax.experimental.serialize_executable import serialize
             blob, in_tree, out_tree = serialize(compiled)
-            payload = pickle.dumps((blob, in_tree, out_tree), protocol=4)
+            device_ids = [d.id for d in compiled._executable
+                          ._unloaded_executable.device_list]
+            payload = pickle.dumps((blob, in_tree, out_tree, device_ids),
+                                   protocol=4)
         except Exception:
             return False            # unsupported backend: compile-only
         from ..checkpoint.atomic import atomic_write_bytes

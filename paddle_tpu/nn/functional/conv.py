@@ -132,11 +132,13 @@ def conv_bn_fusable(x, weight, stride, padding, dilation, groups,
     if not fused_conv.enabled() or core.in_static_mode():
         return False
     xv, wv = unwrap(x), unwrap(weight)
+    itemsize = jnp.dtype(xv.dtype).itemsize
     if s2d:
-        return fused_conv.stem_supported(tuple(xv.shape), tuple(wv.shape))
+        return fused_conv.stem_supported(tuple(xv.shape), tuple(wv.shape),
+                                         itemsize=itemsize)
     return fused_conv.supports(
         tuple(xv.shape), tuple(wv.shape), stride, padding, dilation, groups,
-        channel_last=data_format in ("NHWC",))
+        channel_last=data_format in ("NHWC",), itemsize=itemsize)
 
 
 def conv_bn_act(x, weight, gamma, beta, running_mean, running_var,

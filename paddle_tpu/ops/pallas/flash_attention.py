@@ -28,9 +28,7 @@ from jax import lax
 from jax.experimental import pallas as pl
 from jax.experimental.pallas import tpu as pltpu
 
-# jax renamed TPUCompilerParams -> CompilerParams (~0.6); support both
-_CompilerParams = getattr(pltpu, "CompilerParams", None) or \
-    pltpu.TPUCompilerParams
+from . import _mode
 
 DEFAULT_BLOCK = 128
 # Measured on v5e (chained-dispatch, bf16): larger blocks feed the MXU much
@@ -51,13 +49,6 @@ def _pick_block(size: int, target: int) -> int:
     while b > 128 and size % b:
         b -= 128
     return max(b, min(size, 128))
-
-
-def _interpret() -> bool:
-    try:
-        return jax.devices()[0].platform == "cpu"
-    except Exception:
-        return True
 
 
 # --------------------------------------------------------------------------
@@ -174,13 +165,13 @@ def _flash_fwd_call(q3, k3, v3, bias4, n_heads, scale, causal, bq, bk):
             pltpu.VMEM((bq, 128), jnp.float32),
             pltpu.VMEM((bq, H), jnp.float32),
         ],
-        compiler_params=_CompilerParams(
+        compiler_params=pltpu.CompilerParams(
             dimension_semantics=("parallel", "parallel", "arbitrary")),
         cost_estimate=pl.CostEstimate(
             flops=4 * BN * Sq * Sk * H // (2 if causal else 1),
             bytes_accessed=(2 * q3.size + k3.size + v3.size) * 2,
             transcendentals=BN * Sq * Sk),
-        interpret=_interpret(),
+        interpret=_mode.interpret(),
     )(*args)
     return out, lse
 
@@ -288,7 +279,7 @@ def _flash_bwd_call(q3, k3, v3, bias4, out3, lse, do3, n_heads, scale,
 
     common = dict(scale=scale, causal=causal, bq=bq, bk=bk,
                   offset=Sk - Sq)
-    interp = _interpret()
+    interp = _mode.interpret()
 
     def specs(qmajor):
         # index helpers: i is the "owner" block dim, j sweeps
@@ -330,7 +321,7 @@ def _flash_bwd_call(q3, k3, v3, bias4, out3, lse, do3, n_heads, scale,
         out_specs=[pl.BlockSpec((1, bq, H), lambda b, i, j: (b, i, 0))],
         out_shape=[jax.ShapeDtypeStruct((BN, Sq, H), q3.dtype)],
         scratch_shapes=[pltpu.VMEM((bq, H), jnp.float32)],
-        compiler_params=_CompilerParams(
+        compiler_params=pltpu.CompilerParams(
             dimension_semantics=("parallel", "parallel", "arbitrary")),
         interpret=interp,
     )(*args)[0]
@@ -351,7 +342,7 @@ def _flash_bwd_call(q3, k3, v3, bias4, out3, lse, do3, n_heads, scale,
             pltpu.VMEM((bk, H), jnp.float32),
             pltpu.VMEM((bk, H), jnp.float32),
         ],
-        compiler_params=_CompilerParams(
+        compiler_params=pltpu.CompilerParams(
             dimension_semantics=("parallel", "parallel", "arbitrary")),
         interpret=interp,
     )(*args)

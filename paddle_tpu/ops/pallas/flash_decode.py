@@ -26,8 +26,10 @@ Layout: q ``(B, N, 1, H)``, cached k/v ``(B, N, S, H)``; internally
 ``(B*N, 8, H)`` (the query row broadcast over the 8 sublanes of one tile)
 vs ``(B*N, S, H)``.  Decode is inference-only: no VJP.
 
-Gated OFF behind ``FLAGS_use_flash_decode`` / ``PADDLE_TPU_FLASH_DECODE``
-(no chip this round — PERF.md records the pending-measurement state); the
+Gated OFF behind ``FLAGS_use_flash_decode`` / ``PADDLE_TPU_FLASH_DECODE``:
+both kernels compile for v5e and match the XLA reference on the chip
+(chip_smoke.py's kernels phase, tests/test_tpu_compile.py), but whether
+they beat the XLA masked attention is unmeasured (PERF.md); the
 interpret-mode tests bit-match the XLA masked-attention reference.
 """
 from __future__ import annotations
@@ -41,13 +43,28 @@ from jax import lax
 from jax.experimental import pallas as pl
 from jax.experimental.pallas import tpu as pltpu
 
-from .flash_attention import _CompilerParams, _interpret, _pick_block
+from . import _mode
+from .flash_attention import _pick_block
 
 # split-K block: each grid cell streams this many cached keys through VMEM;
 # S/bk splits run in parallel (vs the 1-program degenerate flash grid)
 DEFAULT_BLOCK_K_DECODE = 512
 _NEG_INF = -1e30  # finite mask value: exp(s - m) underflows to exactly 0
 _SUBLANES = 8     # the query row is broadcast over one (8, 128) tile's rows
+
+
+# the per-row [start, end) window bounds are scalars: the whole int32 [B]
+# arrays live in SMEM (a (1, 1) VMEM block of them does not tile)
+_SMEM_WHOLE = pl.BlockSpec(memory_space=pltpu.SMEM)
+
+
+def _window(start, end, B, S):
+    """int32 ``[B]`` window bounds (defaults: the full cache)."""
+    lo = (jnp.zeros((B,), jnp.int32) if start is None
+          else jnp.asarray(start, jnp.int32).reshape(B))
+    hi = (jnp.full((B,), S, jnp.int32) if end is None
+          else jnp.asarray(end, jnp.int32).reshape(B))
+    return lo, hi
 
 
 def supports_decode(q_shape, k_shape, block: int = 128) -> bool:
@@ -66,16 +83,18 @@ def supports_decode(q_shape, k_shape, block: int = 128) -> bool:
 
 
 def _decode_kernel(q_ref, k_ref, v_ref, s_ref, e_ref,
-                   o_ref, m_ref, l_ref, *, scale, bk):
+                   o_ref, m_ref, l_ref, *, scale, bk, n_heads):
     """One (sequence*head, split) cell: partial attention over the split's
-    ``bk`` cached columns, masked to the row's [start, end) window."""
+    ``bk`` cached columns, masked to the row's [start, end) window
+    (``s_ref``/``e_ref``: the whole int32 ``[B]`` arrays in SMEM)."""
+    row = pl.program_id(0) // n_heads
     isplit = pl.program_id(1)
     q = q_ref[0]                                        # [8, H]
     k = k_ref[0]                                        # [bk, H]
     s = lax.dot_general(q, k, (((1,), (1,)), ((), ())),
                         preferred_element_type=jnp.float32) * scale
     col = lax.broadcasted_iota(jnp.int32, (_SUBLANES, bk), 1) + isplit * bk
-    valid = (col >= s_ref[0, 0]) & (col < e_ref[0, 0])
+    valid = (col >= s_ref[row]) & (col < e_ref[row])
     s = jnp.where(valid, s, _NEG_INF)
     m = jnp.max(s, axis=-1, keepdims=True)              # [8, 1]
     # explicit zeroing (not just the -1e30 mask): a fully-masked split has
@@ -110,20 +129,18 @@ def flash_decode_fn(q, k, v, start=None, end=None, *, scale=None,
     q3 = jnp.broadcast_to(q.reshape(BN, 1, H), (BN, _SUBLANES, H))
     k3 = k.reshape(BN, S, H)
     v3 = v.reshape(BN, S, H)
-    start2 = (jnp.zeros((B, 1), jnp.int32) if start is None
-              else jnp.asarray(start, jnp.int32).reshape(B, 1))
-    end2 = (jnp.full((B, 1), S, jnp.int32) if end is None
-            else jnp.asarray(end, jnp.int32).reshape(B, 1))
+    start1, end1 = _window(start, end, B, S)
 
     o_part, m_part, l_part = pl.pallas_call(
-        functools.partial(_decode_kernel, scale=float(scale), bk=bk),
+        functools.partial(_decode_kernel, scale=float(scale), bk=bk,
+                          n_heads=N),
         grid=(BN, nsplit),
         in_specs=[
             pl.BlockSpec((1, _SUBLANES, H), lambda b, s: (b, 0, 0)),
             pl.BlockSpec((1, bk, H), lambda b, s: (b, s, 0)),
             pl.BlockSpec((1, bk, H), lambda b, s: (b, s, 0)),
-            pl.BlockSpec((1, 1), lambda b, s, n=N: (b // n, 0)),
-            pl.BlockSpec((1, 1), lambda b, s, n=N: (b // n, 0)),
+            _SMEM_WHOLE,
+            _SMEM_WHOLE,
         ],
         out_specs=[
             pl.BlockSpec((1, 1, _SUBLANES, H), lambda b, s: (b, s, 0, 0)),
@@ -135,14 +152,14 @@ def flash_decode_fn(q, k, v, start=None, end=None, *, scale=None,
             jax.ShapeDtypeStruct((BN, nsplit, _SUBLANES, 128), jnp.float32),
             jax.ShapeDtypeStruct((BN, nsplit, _SUBLANES, 128), jnp.float32),
         ],
-        compiler_params=_CompilerParams(
+        compiler_params=pltpu.CompilerParams(
             dimension_semantics=("parallel", "parallel")),
         cost_estimate=pl.CostEstimate(
             flops=4 * BN * S * H,
             bytes_accessed=(k3.size + v3.size + q3.size) * 2,
             transcendentals=BN * S),
-        interpret=_interpret(),
-    )(q3, k3, v3, start2, end2)
+        interpret=_mode.interpret(),
+    )(q3, k3, v3, start1, end1)
 
     # split-K merge: exact online-softmax recombination of the partials
     m = m_part[:, :, :, 0]                       # (BN, nsplit, 8)
@@ -157,19 +174,20 @@ def flash_decode_fn(q, k, v, start=None, end=None, *, scale=None,
 
 
 def _decode_kernel_quant(q_ref, k_ref, v_ref, ks_ref, vs_ref, s_ref, e_ref,
-                         o_ref, m_ref, l_ref, *, scale, bk):
+                         o_ref, m_ref, l_ref, *, scale, bk, n_heads):
     """Quantized-KV variant of one (sequence*head, split) cell: the
     split's ``bk`` int8 cached rows dequantize INSIDE the split-K loop —
     ``int8 row * per-(token, head) f32 scale`` is a rank-1 broadcast
     against the (bk, H) block, so the f32 K/V tile exists only in VMEM
     for the lifetime of this cell and HBM traffic stays int8."""
+    row = pl.program_id(0) // n_heads
     isplit = pl.program_id(1)
     q = q_ref[0].astype(jnp.float32)                    # [8, H]
     k = k_ref[0].astype(jnp.float32) * ks_ref[0]        # fused dequant
     s = lax.dot_general(q, k, (((1,), (1,)), ((), ())),
                         preferred_element_type=jnp.float32) * scale
     col = lax.broadcasted_iota(jnp.int32, (_SUBLANES, bk), 1) + isplit * bk
-    valid = (col >= s_ref[0, 0]) & (col < e_ref[0, 0])
+    valid = (col >= s_ref[row]) & (col < e_ref[row])
     s = jnp.where(valid, s, _NEG_INF)
     m = jnp.max(s, axis=-1, keepdims=True)              # [8, 1]
     p = jnp.exp(s - m) * valid.astype(jnp.float32)
@@ -209,13 +227,11 @@ def flash_decode_quant_fn(q, k, v, k_scale, v_scale, start=None, end=None,
     v3 = v.reshape(BN, S, H)
     ks3 = k_scale.reshape(BN, S, 1)
     vs3 = v_scale.reshape(BN, S, 1)
-    start2 = (jnp.zeros((B, 1), jnp.int32) if start is None
-              else jnp.asarray(start, jnp.int32).reshape(B, 1))
-    end2 = (jnp.full((B, 1), S, jnp.int32) if end is None
-            else jnp.asarray(end, jnp.int32).reshape(B, 1))
+    start1, end1 = _window(start, end, B, S)
 
     o_part, m_part, l_part = pl.pallas_call(
-        functools.partial(_decode_kernel_quant, scale=float(scale), bk=bk),
+        functools.partial(_decode_kernel_quant, scale=float(scale), bk=bk,
+                          n_heads=N),
         grid=(BN, nsplit),
         in_specs=[
             pl.BlockSpec((1, _SUBLANES, H), lambda b, s: (b, 0, 0)),
@@ -223,8 +239,8 @@ def flash_decode_quant_fn(q, k, v, k_scale, v_scale, start=None, end=None,
             pl.BlockSpec((1, bk, H), lambda b, s: (b, s, 0)),
             pl.BlockSpec((1, bk, 1), lambda b, s: (b, s, 0)),
             pl.BlockSpec((1, bk, 1), lambda b, s: (b, s, 0)),
-            pl.BlockSpec((1, 1), lambda b, s, n=N: (b // n, 0)),
-            pl.BlockSpec((1, 1), lambda b, s, n=N: (b // n, 0)),
+            _SMEM_WHOLE,
+            _SMEM_WHOLE,
         ],
         out_specs=[
             pl.BlockSpec((1, 1, _SUBLANES, H), lambda b, s: (b, s, 0, 0)),
@@ -236,7 +252,7 @@ def flash_decode_quant_fn(q, k, v, k_scale, v_scale, start=None, end=None,
             jax.ShapeDtypeStruct((BN, nsplit, _SUBLANES, 128), jnp.float32),
             jax.ShapeDtypeStruct((BN, nsplit, _SUBLANES, 128), jnp.float32),
         ],
-        compiler_params=_CompilerParams(
+        compiler_params=pltpu.CompilerParams(
             dimension_semantics=("parallel", "parallel")),
         cost_estimate=pl.CostEstimate(
             flops=4 * BN * S * H,
@@ -244,8 +260,8 @@ def flash_decode_quant_fn(q, k, v, k_scale, v_scale, start=None, end=None,
             bytes_accessed=(k3.size + v3.size
                             + (ks3.size + vs3.size + q3.size) * 4),
             transcendentals=BN * S),
-        interpret=_interpret(),
-    )(q3, k3, v3, ks3, vs3, start2, end2)
+        interpret=_mode.interpret(),
+    )(q3, k3, v3, ks3, vs3, start1, end1)
 
     m = m_part[:, :, :, 0]                       # (BN, nsplit, 8)
     l = l_part[:, :, :, 0]

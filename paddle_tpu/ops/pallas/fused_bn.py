@@ -28,9 +28,7 @@ import jax
 import jax.numpy as jnp
 from jax.experimental import pallas as pl
 
-
-def _interpret() -> bool:
-    return jax.default_backend() == "cpu"
+from . import _mode
 
 
 def enabled() -> bool:
@@ -88,7 +86,7 @@ def _moments(x2d, tm):
                    pl.BlockSpec((c,), lambda i: (0,))],
         out_shape=[jax.ShapeDtypeStruct((c,), jnp.float32),
                    jax.ShapeDtypeStruct((c,), jnp.float32)],
-        interpret=_interpret(),
+        interpret=_mode.interpret(),
     )(x2d)
     mean = s / m
     var = jnp.maximum(q / m - mean * mean, 0.0)
@@ -105,7 +103,7 @@ def _apply(x2d, scale, shift, tm, relu):
                   pl.BlockSpec((c,), lambda i: (0,))],
         out_specs=pl.BlockSpec((tm, c), lambda i: (i, 0)),
         out_shape=jax.ShapeDtypeStruct((m, c), x2d.dtype),
-        interpret=_interpret(),
+        interpret=_mode.interpret(),
     )(x2d, scale, shift)
 
 
@@ -195,7 +193,7 @@ def bn_bwd_reduce(x2d, dy, scale, shift, relu, tm=None):
                    pl.BlockSpec((c,), lambda i: (0,))],
         out_shape=[jax.ShapeDtypeStruct((c,), jnp.float32),
                    jax.ShapeDtypeStruct((c,), jnp.float32)],
-        interpret=_interpret(),
+        interpret=_mode.interpret(),
     )(x2d, dy, scale, shift)
 
 
@@ -217,7 +215,7 @@ def bn_bwd_dx(x2d, dy, scale, shift, a, b, cc, relu, tm=None):
                   pl.BlockSpec((c,), lambda i: (0,))],
         out_specs=pl.BlockSpec((tm, c), lambda i: (i, 0)),
         out_shape=jax.ShapeDtypeStruct((m, c), x2d.dtype),
-        interpret=_interpret(),
+        interpret=_mode.interpret(),
     )(x2d, dy, scale, shift, a, b, cc)
 
 

@@ -32,13 +32,16 @@ lanes (19.2 ms measured, r3).  ``stem_s2d_*`` reorganizes the padded input
 [N,230,230,3] → [N,115,115,12] and folds the 7×7/s2 weights into an
 equivalent 4×4/s1 kernel over 12 channels — and unlike the rejected r3
 s2d-at-XLA attempt (fwd 12.3 ms vs 8.4 plain: XLA's own im2col undid the
-lane win), the reorged conv feeds THIS kernel directly.
+lane win), the reorged conv feeds THIS kernel directly.  (At 224px the
+reorged 4×4 conv's 16 taps over a 112×112 output exceed the VMEM stack the
+v5e compiler allows — ``supports`` declines it and the stem stays on XLA;
+smaller inputs, e.g. 160px, fit.)
 
 Gating (the flash/fused_bn honesty rule): ships OFF by default —
 ``FLAGS_use_pallas_fused_conv`` / ``PADDLE_TPU_PALLAS_CONV=1`` opts in.
 The default flips only with an end-to-end ResNet-50 win recorded on the
-bench chip in PERF.md (this container has no chip; PERF.md round-6 records
-the pending-measurement state).
+chip in PERF.md (the kernel compiles and matches its reference there —
+chip_smoke.py's kernels phase — but has not been measured).
 """
 from __future__ import annotations
 
@@ -49,11 +52,7 @@ import jax
 import jax.numpy as jnp
 from jax.experimental import pallas as pl
 
-from . import fused_bn
-
-
-def _interpret() -> bool:
-    return jax.default_backend() == "cpu"
+from . import _mode, fused_bn
 
 
 def enabled() -> bool:
@@ -65,9 +64,42 @@ def enabled() -> bool:
         os.environ.get("PADDLE_TPU_PALLAS_CONV", "0") == "1"
 
 
-# VMEM working-set cap for one grid step (per-image block + f32 accumulator
-# + weights, double-buffered by the pipeline); ~16 MB/core on v5e
+# Per-image block estimate of ``supports`` (padded input + f32 accumulator +
+# stored output + weights, f32 upper bound) — the pipeline's buffers
 _VMEM_CAP_BYTES = 12 * 1024 * 1024
+# The kernel's own stack, which the chip's compiler holds to 16 MiB of
+# scoped VMEM on v5e.  ``_stack_bytes`` is fitted to what that compiler
+# reports when it refuses a site ("ran out of memory in memory space vmem
+# while allocating on stack", compiles for a described v5e:2x2): it
+# reproduces the reported size of the refused bf16 sites within a few
+# percent and leans high elsewhere, so every site it admits in those
+# sweeps compiles (tests/test_tpu_compile.py keeps the boundary cases).
+_STACK_CAP_BYTES = int(15.5 * 1024 * 1024)
+_S2_WINDOW_BYTES_PER_PIXEL = 32 * 1024      # per 128 channels, at 9 taps
+
+
+def _stack_bytes(ho, wo, cin, cout, kh, kw, stride, itemsize):
+    """VMEM stack of one ``_conv_stats_kernel`` step: the f32 accumulator
+    over lane-padded Cout (twice when the tap's product is a temporary of
+    its own: Cout beyond one 128-lane tile), plus max(kw, 2) live
+    [Ho*Wo, Cin] window copies over lane-padded Cin — three times that
+    when a packed (2-byte) window's rows do not fill whole 16-row tiles
+    and the collapse goes through an unpacked copy; f32 windows count at
+    twice their size (a margin over the few f32 sites probed, not a fit).
+    A strided tap instead folds the
+    stride into a [Ho, 2, Wo, 2, Cin] reshape whose (2, Cin) minor dims
+    pad to whole tiles."""
+    lane = lambda c: -(-c // 128) * 128
+    px = ho * wo
+    wide = cout > 128 or (itemsize == 4 and cout >= 128)
+    acc = px * lane(cout) * 4 * (2 if wide else 1)
+    if stride == 2 and kh * kw > 1:
+        windows = _S2_WINDOW_BYTES_PER_PIXEL * px * (lane(cin) // 128) \
+            * kh * kw // 9
+    else:
+        relayout = 2 if itemsize == 4 else (3 if wo % 16 else 1)
+        windows = max(kw, 2) * px * lane(cin) * itemsize * relayout
+    return acc + windows
 
 
 def _out_hw(h, w, kh, kw, stride, padding):
@@ -77,14 +109,15 @@ def _out_hw(h, w, kh, kw, stride, padding):
 
 
 def supports(x_shape, w_shape, stride=1, padding=0, dilation=1, groups=1,
-             channel_last=True) -> bool:
+             channel_last=True, itemsize=4) -> bool:
     """Static eligibility of the fused kernel for a conv+BN(+ReLU) site.
 
     NHWC, groups=1, dilation=1, stride 1 or 2, symmetric int padding,
     kernels ≤5 (the 7×7 stem goes through the s2d reorg instead — at
     C_in=3 a direct 49-tap kernel wastes the very lanes s2d reclaims),
     single device (pallas_call has no GSPMD partition rule), and the
-    per-image working set must fit VMEM."""
+    per-image working set must fit VMEM.  ``itemsize``: bytes per
+    activation element (the f32 default is the conservative side)."""
     def _pair(v):
         return (v, v) if isinstance(v, int) else tuple(v)
     if not channel_last or groups != 1 or len(x_shape) != 4:
@@ -110,7 +143,7 @@ def supports(x_shape, w_shape, stride=1, padding=0, dilation=1, groups=1,
         return False
     if (n * ho * wo) % 8 != 0:
         return False         # apply/backward tiles ladder in units of 8
-    if jax.device_count() > 1 and not _interpret():
+    if jax.device_count() > 1 and not _mode.interpret():
         # compiled pallas_call has no GSPMD partition rule; interpret mode
         # lowers to plain jax ops and partitions like any jnp code, so the
         # CPU test mesh keeps exercising the fused path
@@ -120,7 +153,14 @@ def supports(x_shape, w_shape, stride=1, padding=0, dilation=1, groups=1,
     hp = h + 2 * padding + (s[0] - 1)
     wp = w + 2 * padding + (s[0] - 1)
     vmem = 4 * (hp * wp * cin + 2 * ho * wo * cout + kh * kw * cin * cout)
-    return vmem <= _VMEM_CAP_BYTES
+    if vmem > _VMEM_CAP_BYTES:
+        return False
+    if wo % 8 and cin % 128:
+        # Mosaic refuses the window's [Ho, Wo, Cin] -> [Ho*Wo, Cin]
+        # collapse ("unsupported shape cast") when neither dim tiles
+        return False
+    return _stack_bytes(ho, wo, cin, cout, kh, kw, s[0],
+                        itemsize) <= _STACK_CAP_BYTES
 
 
 # -- forward: conv with fused output statistics -------------------------------
@@ -186,7 +226,7 @@ def _conv_stats(x, w, stride, padding):
         out_shape=[jax.ShapeDtypeStruct((n, ho, wo, cout), x.dtype),
                    jax.ShapeDtypeStruct((cout,), jnp.float32),
                    jax.ShapeDtypeStruct((cout,), jnp.float32)],
-        interpret=_interpret(),
+        interpret=_mode.interpret(),
     )(xp, wk)
     m = n * ho * wo
     mean = s / m
@@ -311,7 +351,7 @@ def stem_s2d_weight(w):
     return w2.reshape(o, b * b * c, (kh + 1) // b, (kw + 1) // b)
 
 
-def stem_supported(x_shape, w_shape) -> bool:
+def stem_supported(x_shape, w_shape, itemsize=4) -> bool:
     """The s2d reorg applies to the canonical 7×7/s2/p3 NHWC stem with an
     even input size, and only when the reorged conv itself passes
     ``supports`` — s2d WITHOUT the fused kernel was measured slower at
@@ -324,4 +364,4 @@ def stem_supported(x_shape, w_shape) -> bool:
         return False
     s2d_x = (n, (h + 6) // 2, (w + 6) // 2, 4 * c)
     s2d_w = (cout, 4 * c, 4, 4)
-    return supports(s2d_x, s2d_w, stride=1, padding=0)
+    return supports(s2d_x, s2d_w, stride=1, padding=0, itemsize=itemsize)

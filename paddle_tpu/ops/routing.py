@@ -38,10 +38,7 @@ import jax.numpy as jnp
 from jax import lax
 from jax.sharding import PartitionSpec as P
 
-try:                                     # jax >= 0.6
-    from jax import shard_map as _shard_map
-except ImportError:                      # pragma: no cover - version compat
-    from jax.experimental.shard_map import shard_map as _shard_map
+from jax import shard_map as _shard_map
 
 __all__ = [
     "PackPlan", "rows_per_shard", "storage_table_rows", "storage_index",
@@ -234,7 +231,7 @@ def all_to_all_gather(arrays: Sequence, ids, *, mesh, axis: str, rps: int,
         body, mesh=mesh,
         in_specs=(P(axis),) + (P(axis, None),) * len(arrays),
         out_specs=(P(),) + (P(axis, None),) * len(arrays),
-        check_rep=False)
+        check_vma=False)
     out = fn(ids, *arrays)
     return list(out[1:]), out[0]
 
@@ -256,7 +253,7 @@ def all_to_all_set(arrays: Sequence, ids, rows: Sequence, *, mesh,
         in_specs=(P(axis),) + (P(axis, None),) * len(arrays)
         + (P(axis, None),) * len(arrays),
         out_specs=(P(),) + (P(axis, None),) * len(arrays),
-        check_rep=False)
+        check_vma=False)
     out = fn(ids, *rows, *arrays)
     return list(out[1:]), out[0]
 
@@ -277,7 +274,7 @@ def all_to_all_apply_rule(table, state: dict, ids, grads, *, opt: str,
         body, mesh=mesh,
         in_specs=(P(axis), P(axis, None)) + (P(axis, None),) * (1 + len(names)),
         out_specs=(P(),) + (P(axis, None),) * (1 + len(names)),
-        check_rep=False)
+        check_vma=False)
     out = fn(ids, grads, table, *[state[k] for k in names])
     new_state = {k: out[2 + i] for i, k in enumerate(names)}
     return out[1], new_state, out[0]
@@ -384,7 +381,7 @@ def all_to_all_experts(x_dup, pos, expert_params: Sequence, expert_fn, *,
     specs = tuple(P(axis, *([None] * (w.ndim - 1))) for w in expert_params)
     fn = _shard_map(body, mesh=mesh,
                     in_specs=(P(axis), P(axis, None)) + specs,
-                    out_specs=P(axis), check_rep=False)
+                    out_specs=P(axis), check_vma=False)
     return fn(x_dup, pos, *expert_params)
 
 

@@ -27,10 +27,6 @@ from ..framework import functional as F
 from ..framework.tensor import Tensor
 from .mesh import get_mesh, PP_AXIS, DP_AXIS
 
-# lax.pvary arrived with the varying-manual-axes rep rule (~jax 0.6); on
-# older jax shard_map has no VMA typing and the marker is a no-op
-_pvary = getattr(lax, "pvary", lambda x, axes: x)
-
 
 def pipeline_spmd_train(stage_fn: Callable, num_stages: int,
                         num_microbatches: int):
@@ -53,7 +49,8 @@ def pipeline_spmd_train(stage_fn: Callable, num_stages: int,
         base = jax.random.wrap_key_data(key_data)
         # carry becomes pp-varying after the first ppermute; mark the initial
         # zeros as varying over pp so scan's carry types line up (VMA rule)
-        zero = _pvary(jnp.zeros_like(x_mb[0]), (PP_AXIS,))
+        zero = lax.pcast(jnp.zeros_like(x_mb[0]), (PP_AXIS,),
+                         to="varying")
 
         def tick(carry, t):
             incoming = carry
@@ -198,10 +195,7 @@ class PipelineModule:
     def build_body(self, remat: bool = False):
         """fn(stacked_params, x [B, ...], key_data) -> trunk output [B, ...],
         SPMD over the pp (and dp) mesh axes."""
-        try:
-            from jax import shard_map  # jax >= 0.6
-        except ImportError:
-            from jax.experimental.shard_map import shard_map
+        from jax import shard_map
         block0 = self.blocks[0]
         names = self.block_param_names
         per_stage = self.per_stage
@@ -311,10 +305,7 @@ class GPipe:
     def build_forward(self):
         """Return pure fn(stacked_params, x [B, ...]) -> y executed as SPMD
         over the pp (and dp) axes of the mesh."""
-        try:
-            from jax import shard_map  # jax >= 0.6
-        except ImportError:
-            from jax.experimental.shard_map import shard_map
+        from jax import shard_map
         S, M = self.S, self.M
         body = pipeline_spmd(self._stage_fn(), S, M)
         mesh = self.mesh

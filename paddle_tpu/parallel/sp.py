@@ -20,10 +20,6 @@ import jax.numpy as jnp
 from jax import lax
 from jax.sharding import NamedSharding, PartitionSpec as P
 
-# lax.axis_size is ~jax 0.6; the classic psum-of-1 idiom is its exact
-# definition and constant-folds to a Python int on older jax
-_axis_size = getattr(lax, "axis_size", None) or (lambda a: lax.psum(1, a))
-
 from .mesh import get_mesh, SP_AXIS, DP_AXIS
 
 
@@ -32,7 +28,7 @@ def _ring_attention_shard(q, k, v, *, scale, causal, axis):
 
     q,k,v: [B, H, s_loc, D] local blocks; returns [B, H, s_loc, D].
     """
-    S = _axis_size(axis)
+    S = lax.axis_size(axis)
     idx = lax.axis_index(axis)
     s_loc = q.shape[2]
     perm = [(i, (i + 1) % S) for i in range(S)]
@@ -85,10 +81,7 @@ def ring_attention(q, k, v, mesh=None, causal=False, axis=SP_AXIS):
     softmax attention (identical numerics — ring with S=1 is exact).
     """
     from ..framework.tensor import Tensor
-    try:
-        from jax import shard_map  # jax >= 0.6
-    except ImportError:
-        from jax.experimental.shard_map import shard_map
+    from jax import shard_map
 
     unwrap = lambda x: x._value if isinstance(x, Tensor) else jnp.asarray(x)
     qa, ka, va = unwrap(q), unwrap(k), unwrap(v)

@@ -124,10 +124,7 @@ def test_dispatch_gate_defaults_off_and_respects_platform(monkeypatch):
         assert not A._use_flash_decode(q, k, win)        # flag off
         set_flags({"FLAGS_use_flash_decode": True})
         assert not A._use_flash_decode(q, k, win)        # CPU platform
-
-        class _Dev:
-            platform = "tpu"
-        monkeypatch.setattr(jax, "devices", lambda *a: [_Dev()])
+        monkeypatch.setattr(jax, "default_backend", lambda: "tpu")
         assert A._use_flash_decode(q, k, win)            # tpu + flag
         assert not A._use_flash_decode(q, k, None)       # no window
         # ineligible shape falls back even on TPU with the flag on

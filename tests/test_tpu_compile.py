@@ -1,0 +1,196 @@
+"""Sandbox compiles for the chip: every Pallas kernel family of
+chip_smoke.py's kernels phase, at that phase's shapes, handed to the TPU
+compiler for a *described* (not attached) ``v5e:2x2``.
+
+Interpret mode passes shapes the chip's compiler refuses (the flash-decode
+``start``/``end`` windows were a ``(1, 1)`` VMEM block until PR 21), so
+these compiles are the regression guard that costs no chip time.  Nothing
+runs: a passing compile is not a chip run.
+
+One file on purpose: only one process at a time may load libtpu, the
+xdist worker that gets this file keeps it until it exits.  The topology is
+described inside the fixture, never at import or collection.
+"""
+import jax
+import jax.numpy as jnp
+import pytest
+from jax.sharding import SingleDeviceSharding
+
+import chip_smoke
+from paddle_tpu.ops.pallas import (flash_attention_fn, flash_decode_fn,
+                                   flash_decode_quant_fn, fused_bn,
+                                   fused_conv, supports, supports_decode)
+from paddle_tpu.ops.pallas import _mode
+
+BF16 = jnp.bfloat16
+
+
+@pytest.fixture(scope="module")
+def topo():
+    from jax.experimental import topologies
+    try:
+        return topologies.get_topology_desc(platform="tpu",
+                                            topology_name="v5e:2x2")
+    except Exception as e:  # noqa: BLE001 — any failure to describe = skip
+        pytest.skip(f"no v5e:2x2 topology can be described here: {e}")
+
+
+@pytest.fixture(scope="module")
+def one_chip(topo):
+    return SingleDeviceSharding(topo.devices[0])
+
+
+@pytest.fixture()
+def cache_off():
+    """JAX's compilation cache off around these compiles: an entry written
+    for a described chip cannot be read back without one."""
+    from jax.experimental.compilation_cache import compilation_cache
+    was = jax.config.jax_enable_compilation_cache
+    jax.config.update("jax_enable_compilation_cache", False)
+    compilation_cache.reset_cache()
+    yield
+    jax.config.update("jax_enable_compilation_cache", was)
+    compilation_cache.reset_cache()
+
+
+@pytest.fixture()
+def compile_for_chip(one_chip, cache_off, monkeypatch):
+    """compile(fn, *(shape, dtype)) -> optimized HLO text, with the kernels
+    steered out of interpret mode for the compile."""
+    def compile_(fn, *specs):
+        avals = [jax.ShapeDtypeStruct(s, d, sharding=one_chip)
+                 for s, d in specs]
+        with monkeypatch.context() as m:
+            m.setattr(_mode, "interpret", lambda: False)
+            return jax.jit(fn).lower(*avals).compile().as_text()
+    return compile_
+
+
+B, N, S, H = chip_smoke.FULL.attn
+QKV = ((B, N, S, H), BF16)
+Q1 = ((B, N, 1, H), BF16)
+WIN = ((B,), jnp.int32)
+CONV_X, CONV_W = chip_smoke.FULL.conv_x, chip_smoke.FULL.conv_w
+COUT = CONV_W[0]
+CH = ((COUT,), jnp.float32)
+X2D = ((CONV_X[0] * CONV_X[1] * CONV_X[2], CONV_X[3]), BF16)
+
+
+def _flash(q, k, v):
+    return flash_attention_fn(q, k, v, causal=True)
+
+
+def _conv(x, w, g, b):
+    return fused_conv.fused_conv_bn_act(x, w, g, b, 1, 1, 1e-5, True)
+
+
+def _bn(x, g, b):
+    return fused_bn.fused_bn_act(x, g, b, 1e-5, True)
+
+
+def _loss(fn):
+    def loss(*a):
+        out = fn(*a)
+        out = out[0] if isinstance(out, tuple) else out
+        return out.astype(jnp.float32).sum()
+    return loss
+
+
+CASES = {
+    "flash_attention_fwd": (_flash, (QKV, QKV, QKV)),
+    "flash_attention_bwd": (jax.grad(_loss(_flash), argnums=(0, 1, 2)),
+                            (QKV, QKV, QKV)),
+    # regression for PR 21 §5: both refused by Mosaic before the windows
+    # moved to SMEM
+    "flash_decode": (flash_decode_fn, (Q1, QKV, QKV, WIN, WIN)),
+    "flash_decode_int8": (
+        flash_decode_quant_fn,
+        (Q1, ((B, N, S, H), jnp.int8), ((B, N, S, H), jnp.int8),
+         ((B, N, S, 1), jnp.float32), ((B, N, S, 1), jnp.float32),
+         WIN, WIN)),
+    "fused_conv_bn_relu_fwd": (_conv, ((CONV_X, BF16), (CONV_W, BF16),
+                                       CH, CH)),
+    "fused_conv_bn_relu_bwd": (
+        jax.grad(_loss(_conv), argnums=(0, 1, 2, 3)),
+        ((CONV_X, BF16), (CONV_W, BF16), CH, CH)),
+    "fused_bn_relu_fwd": (_bn, (X2D, ((CONV_X[3],), jnp.float32),
+                                ((CONV_X[3],), jnp.float32))),
+    "fused_bn_relu_bwd": (
+        jax.grad(_loss(_bn), argnums=(0, 1, 2)),
+        (X2D, ((CONV_X[3],), jnp.float32), ((CONV_X[3],), jnp.float32))),
+}
+
+
+@pytest.mark.parametrize("name", sorted(CASES))
+def test_kernel_compiles_for_v5e(name, compile_for_chip):
+    fn, specs = CASES[name]
+    assert "tpu_custom_call" in compile_for_chip(fn, *specs), \
+        f"{name}: no Mosaic custom call in the compiled program"
+
+
+def test_gates_admit_the_compiled_shapes():
+    """What the kernels phase compiles is what the dispatch gates admit
+    (head dim 64 included) — a gate that said no would hide the kernel."""
+    assert supports((B, N, S, H), (B, N, S, H), causal=True)
+    assert supports_decode((B, N, 1, H), (B, N, S, H))
+    assert fused_conv.supports(CONV_X, CONV_W, stride=1, padding=1,
+                               itemsize=2)
+    assert fused_bn._pick_tile(*X2D[0]) > 0
+
+
+# (x NHWC, w OIHW, stride, padding): sites fused_conv.supports admits
+# close to its caps, and sites the chip's compiler refused ("ran out of
+# memory in memory space vmem") while the pre-PR-21 estimate admitted them
+ADMITTED_NEAR_CAP = [
+    ((8, 112, 112, 64), (64, 64, 3, 3), 1, 1),
+    ((8, 80, 80, 256), (64, 256, 3, 3), 1, 1),
+    ((8, 83, 83, 12), (64, 12, 4, 4), 1, 0),        # the s2d stem, 160px
+    ((8, 28, 28, 256), (256, 256, 3, 3), 2, 1),
+]
+REFUSED_BY_COMPILER = [
+    ((8, 96, 96, 256), (32, 256, 3, 3), 1, 1),
+    ((8, 115, 115, 12), (64, 12, 4, 4), 1, 0),      # the s2d stem, 224px
+    ((8, 56, 56, 128), (128, 128, 3, 3), 2, 1),     # ResNet-50 stage 2
+    ((8, 80, 80, 128), (512, 128, 3, 3), 2, 1),
+    ((8, 28, 28, 16), (32, 16, 5, 5), 2, 2),
+]
+
+
+@pytest.mark.parametrize("x,w,stride,padding", ADMITTED_NEAR_CAP)
+def test_fused_conv_gate_admits_only_what_compiles(x, w, stride, padding,
+                                                   compile_for_chip):
+    assert fused_conv.supports(x, w, stride=stride, padding=padding,
+                               itemsize=2)
+    cout = w[0]
+    text = compile_for_chip(
+        lambda a, b, g, s: fused_conv.fused_conv_bn_act(
+            a, b, g, s, stride, padding, 1e-5, True),
+        (x, BF16), (w, BF16), ((cout,), jnp.float32),
+        ((cout,), jnp.float32))
+    assert "tpu_custom_call" in text
+
+
+@pytest.mark.parametrize("x,w,stride,padding", REFUSED_BY_COMPILER)
+def test_fused_conv_gate_refuses_what_the_compiler_refused(x, w, stride,
+                                                           padding):
+    assert not fused_conv.supports(x, w, stride=stride, padding=padding,
+                                   itemsize=2)
+
+
+def test_slot_loop_step_compiles_for_v5e(one_chip, cache_off):
+    """The slot loop's hot program (one decode step over 8 slots of the
+    full-width GPT, ring cache donated) as the serve phase builds it —
+    needs no steering: the Generator hands out its own step function and
+    avals.  (The BERT-base TrainStep step takes ~40 s and a stub for
+    TrainStep's array placement; CHANGES.md PR 21 reports that rehearsal.)"""
+    size = chip_smoke.FULL
+    gen = chip_smoke.Generator(chip_smoke._gpt(size, 0),
+                               seq_buckets=size.serve_seq_buckets,
+                               max_len=size.serve_max_len)
+    avals = jax.tree_util.tree_map(
+        lambda a: jax.ShapeDtypeStruct(a.shape, a.dtype, sharding=one_chip),
+        (*gen._state_avals(),
+         *gen.step_avals(size.slots, size.serve_max_len)))
+    step = gen._build_step(size.slots, size.serve_max_len, -1)
+    compiled = jax.jit(step, donate_argnums=(2,)).lower(*avals).compile()
+    assert compiled.memory_analysis().argument_size_in_bytes > 2 ** 27

@@ -20,7 +20,7 @@ jittery CPU-backed lane, so the gate has to know what noise looks like:
     a regression outright (a silently dropped benchmark is the worst
     kind of "improvement").
 
-    python tools/bench_gate.py BENCH_r04.json BENCH_r05.json
+    python tools/bench_gate.py BENCH_prev.json BENCH_new.json
     python tools/bench_gate.py old.json new.json --tolerance-pct 5 \\
         --tolerance mnist_lenet_static=25 --json
 

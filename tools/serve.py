@@ -2,8 +2,12 @@
 """serve — export zoo models, warm a serving engine, drive traffic, check SLOs.
 
 The CLI face of ``paddle_tpu.serving``: the whole deploy walkthrough
-(export → warm-up → serve → SLO check) in one command, runnable on any
-backend (defaults to CPU, like tools/graph_lint.py).
+(export → warm-up → serve → SLO check) in one command, on whatever
+backend JAX finds (``JAX_PLATFORMS=cpu`` for a run off the chip).
+
+One process per chip: under ``--router`` / ``--ramp`` every replica is a
+child process that needs a chip of its own, and the parent (router,
+traffic, observer) never initialises a JAX backend.
 
     python tools/serve.py --model lenet --duration 2 --clients 4
     python tools/serve.py --model lenet --model bert --int8 --json
@@ -24,19 +28,31 @@ import threading
 import time
 
 sys.path.insert(0, os.path.dirname(os.path.dirname(os.path.abspath(__file__))))
-# serving smoke runs anywhere the framework imports; explicit env wins
-os.environ.setdefault("JAX_PLATFORMS", "cpu")
 
 import numpy as np  # noqa: E402
 
 
+# What a client feeds each zoo model: (input specs, token vocab or None).
+# The --router/--ramp parents read THIS and never build a layer: a parent
+# that created a JAX array would hold the chip its replica children need.
+_BLOCK_CH = _BLOCK_HW = 8
+_BERT_SEQ, _BERT_VOCAB = 32, 128
+ZOO_FEEDS = {
+    "lenet": ([([None, 1, 28, 28], "float32")], None),
+    "resnet_block": ([([None, _BLOCK_CH, _BLOCK_HW, _BLOCK_HW],
+                       "float32")], None),
+    "bert": ([([None, _BERT_SEQ], "int32")], _BERT_VOCAB),
+}
+
+
 def build_lenet():
     from paddle_tpu.vision.models import LeNet
-    return LeNet(), [([None, 1, 28, 28], "float32")]
+    return LeNet(), ZOO_FEEDS["lenet"][0]
 
 
-def build_resnet_block(ch=8, hw=8):
+def build_resnet_block():
     import paddle_tpu.nn as nn
+    ch = _BLOCK_CH
 
     class Block(nn.Layer):
         """One residual conv-BN-ReLU pair (bench.py's high-res stage)."""
@@ -53,15 +69,15 @@ def build_resnet_block(ch=8, hw=8):
             h = self.relu(self.b1(self.c1(x)))
             return self.relu(self.b2(self.c2(h)) + x)
 
-    return Block(), [([None, ch, hw, hw], "float32")]
+    return Block(), ZOO_FEEDS["resnet_block"][0]
 
 
-def build_bert(seq=32):
+def build_bert():
     from paddle_tpu.text.models.bert import BertConfig, BertModel
-    cfg = BertConfig.tiny(seq=seq)
+    cfg = BertConfig.tiny(vocab_size=_BERT_VOCAB, seq=_BERT_SEQ)
     m = BertModel(cfg)
     m._serve_vocab = cfg.vocab_size
-    return m, [([None, seq], "int32")]
+    return m, ZOO_FEEDS["bert"][0]
 
 
 ZOO = {
@@ -338,7 +354,6 @@ def _router_main(args):
                 victim.send_signal(signal.SIGKILL)
             threading.Thread(target=killer, daemon=True).start()
 
-        model_meta = {name: ZOO[name]() for name in names}
         errors = []
         if args.decode and args.sessions:
             # stateful leg rides ALONGSIDE the one-shot traffic (mixed
@@ -354,11 +369,10 @@ def _router_main(args):
                 args.max_request_rows, max(seq_buckets), args.max_new,
                 128, args.seed)
         for name in names:
-            layer, specs = model_meta[name]
+            specs, vocab = ZOO_FEEDS[name]
             errors += _traffic(router, name, specs, args.duration,
                                args.clients, args.max_request_rows,
-                               getattr(layer, "_serve_vocab", None),
-                               args.seed)
+                               vocab, args.seed)
         report["traffic_errors"] = errors
         if errors:
             rc = 1
@@ -788,7 +802,8 @@ def _ramp_main(args):
     cfg_dir = tempfile.mkdtemp(prefix="serve_ramp_")
     # a shared executable cache is what makes elastic scale-up viable:
     # the seed replica compiles once, every later spawn boots O(load)
-    cache_dir = args.cache_dir or os.path.join(cfg_dir, "exec_cache")
+    from paddle_tpu.utils.cache_dirs import executable_cache_dir
+    cache_dir = args.cache_dir or executable_cache_dir("serve_ramp")
     os.makedirs(cache_dir, exist_ok=True)
     sess_dir = ""
     if args.sessions:
@@ -884,10 +899,7 @@ def _ramp_main(args):
             return _router_report(report, args, 1)
         report["boot_s"] = round(time.perf_counter() - t0, 3)
 
-        model_meta = {name: ZOO[name]() for name in names}
-        dense = [(name, model_meta[name][1],
-                  getattr(model_meta[name][0], "_serve_vocab", None))
-                 for name in names]
+        dense = [(name,) + ZOO_FEEDS[name] for name in names]
         traffic = _BgTraffic(router, dense, args.decode, seq_buckets,
                              args.max_new, clients=args.clients,
                              seed=args.seed, tenant="steady").start()
