@@ -1044,7 +1044,9 @@ class TrainStep:
                     _STEP_PHASE.labels(phase="device_fence").observe(
                         t_d2 - t_d1)
             else:
-                self._state, out = fn(self.state, inputs, label, lr, scale)
+                with _span("train_step::dispatch"):
+                    self._state, out = fn(self.state, inputs, label, lr,
+                                          scale)
         self.optimizer._step_count += 1
         self._host_step += 1
         if self._sentinel_active:

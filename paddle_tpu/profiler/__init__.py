@@ -12,11 +12,12 @@ Perfetto — the CUPTI analogue is built into PJRT), activated per record
 window; host-side RecordEvent spans are a lightweight aggregator with the
 reference's summary table, and export_chrome_tracing writes the standard
 chrome://tracing JSON.  The runtime's hot paths (static Executor, @to_static
-dispatch, TrainStep, device.synchronize) are instrumented with ``span(...)``
-— a shared no-op unless a Profiler window is recording or
-FLAGS_enable_profiler / PADDLE_TPU_PROFILE is set, so the off-path cost is
-one branch.  Recompile accounting lives in ``profiler.ledger`` and is
-always on.
+dispatch, TrainStep, device.synchronize, the serving workers and the slot
+loop's driver thread) are instrumented with ``span(...)``: always a
+``jax.profiler.TraceAnnotation`` (on the device trace's clock; a no-op
+while no capture runs), plus a RecordEvent while a Profiler window is
+recording or FLAGS_enable_profiler / PADDLE_TPU_PROFILE is set.  Recompile
+accounting lives in ``profiler.ledger`` and is always on.
 """
 from __future__ import annotations
 
@@ -77,30 +78,43 @@ class RecordEvent:
         self.end()
 
 
-class _NullSpan:
-    """Shared no-op stand-in returned by span() when profiling is off."""
-    __slots__ = ()
+class _Span:
+    """What ``span()`` returns: a ``jax.profiler.TraceAnnotation`` (the
+    host span on the device trace's clock; a no-op while no capture
+    runs) and, while profiling is enabled, the ``RecordEvent`` of the
+    same name."""
+    __slots__ = ("_ann", "_rec")
+
+    def __init__(self, name):
+        self._ann = jax.profiler.TraceAnnotation(name)
+        self._rec = RecordEvent(name) if profiling_enabled() else None
 
     def begin(self):
-        pass
+        self._ann.__enter__()
+        if self._rec is not None:
+            self._rec.begin()
 
     def end(self):
-        pass
+        if self._rec is not None:
+            self._rec.end()
+        self._ann.__exit__(None, None, None)
 
     def __enter__(self):
+        self.begin()
         return self
 
     def __exit__(self, *exc):
+        self.end()
         return False
 
 
-_NULL_SPAN = _NullSpan()
-
-
 def span(name):
-    """Gated RecordEvent for runtime instrumentation points: a real span
-    while profiling is enabled, the shared no-op otherwise."""
-    return RecordEvent(name) if profiling_enabled() else _NULL_SPAN
+    """The span primitive of the runtime's instrumentation points.  It
+    always enters a ``TraceAnnotation``, so every site appears on its
+    thread's line of any ``.xplane.pb`` beside the device's own events,
+    and records a RecordEvent too while a Profiler window records (or
+    FLAGS_enable_profiler is set)."""
+    return _Span(name)
 
 
 class ProfilerTarget:

@@ -14,11 +14,12 @@ answer built TPU-native:
   * **XLA compile events are first-class annotations**: every recompile-
     ledger record lands as an event on the active span, so a steady-state
     recompile shows up inside the exact request that paid for it;
-  * **the decode scan is one device program**, so per-token span events
-    are attributed at the scan boundary: the decode span carries one
-    event per generated token with timestamps spread uniformly across
-    the fenced scan window (the honest TPU form of per-token timing —
-    the host never observes token k in isolation);
+  * **the decode scan is one device program**: the host never observes
+    token k in isolation, so the decode span carries ``steps`` and the
+    measured ``per_token_ms`` of the fenced scan window and no per-token
+    events; on the slot path the request's tree is cut from the
+    ``SlotRequest`` stamps (``slot_queue``, ``slot_prefill``,
+    ``slot_decode``, ``reply_hold`` — serving/slots.py);
   * gating is ``FLAGS_trace`` off|sample|full (PADDLE_TPU_TRACE).  Off
     is ONE Python branch per instrumentation point (the shared
     ``enabled()`` check); sample keeps every round(1/rate)-th root span

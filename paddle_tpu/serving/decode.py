@@ -109,6 +109,9 @@ class DecodeRequest:
     # conversation identity (FLAGS_session_store): single-prompt requests
     # only — the slot loop parks/restores the KV planes under this key
     session_id: Optional[str] = None
+    # slot mode: the SlotRequest of each prompt (execute fills it), whose
+    # lives the worker closes when it resolves ``future``
+    slot_rows: List[object] = field(default_factory=list)
 
 
 class _DecodeRuntime:
@@ -452,6 +455,8 @@ class _DecodeRuntime:
         if self._loop is not None:
             futs = []
             for r in batch.requests:
+                rows = r.slot_rows = []
+                stamp = dict(t_arrival=r.t_enqueue_mono, trace=r.trace)
                 sid = getattr(r, "session_id", None)
                 snap = None
                 if sid is not None and self.session_store is not None:
@@ -463,14 +468,16 @@ class _DecodeRuntime:
                         snap = None
                 for p in r.prompts:
                     try:
-                        futs.append(self._loop.submit(
-                            p, r.max_new, session_id=sid, snapshot=snap))
+                        rows.append(self._loop.enqueue(
+                            p, r.max_new, session_id=sid, snapshot=snap,
+                            **stamp))
                     except (InvalidArgumentError, OutOfRangeError):
                         # a malformed snapshot must not fail the turn —
                         # fall back to the plain (bit-identical) prefill
-                        futs.append(self._loop.submit(
-                            p, r.max_new, session_id=sid))
+                        rows.append(self._loop.enqueue(
+                            p, r.max_new, session_id=sid, **stamp))
                     snap = None             # one snapshot, one restore
+                futs += [row.future for row in rows]
             out = np.zeros((batch.bucket, self.steps), np.int32)
             row = 0
             for r in batch.requests:
@@ -553,13 +560,9 @@ class _DecodeRuntime:
                                            "acceptance_rate"])
                         d.set_attr(gamma=spec["gamma"], acceptance_rate=
                                    spec["acceptance_rate"])
-                    # per-token events, attributed at the scan boundary:
-                    # the whole token loop is ONE jitted lax.scan (one
-                    # device program), so the host never observes token k
-                    # alone — timestamps spread uniformly across the
-                    # fenced scan window
-                    for k in range(r.max_new):
-                        d.event("token", t=t_p1 + (k + 1) * dt, index=k)
+                    # no per-token events: the whole token loop is ONE
+                    # jitted lax.scan, the host never observes token k
+                    # alone; ``per_token_ms`` is what was measured
                     _tracing.finish(d, end=t_d1)
         if key_missing:
             self._warmed_prefill.add((B, P, C))
@@ -655,6 +658,12 @@ class _DecodeRuntime:
         rows = int(handoff.meta.get("rows", B))
         mn = int(handoff.meta.get("max_new", self.steps))
         return out[:rows, :mn]
+
+    def replied(self, r, t_reply):
+        """The worker resolved ``r``'s Future at ``t_reply``: close the
+        lives of its slot rows (nothing on the scanned path)."""
+        for row in r.slot_rows:
+            self._loop.replied(row, t_reply)
 
     def slot_signals(self):
         """Token-level slot accounting for Server.signals(), or None on

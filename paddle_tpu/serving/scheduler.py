@@ -66,6 +66,21 @@ SLOTS_RETIRED = _registry().counter(
     "Rows retired from the slot loop (eos or per-request token budget) "
     "— retirement frees the slot the same step.",
     labels=("model",))
+SLOT_STEPS = _registry().counter(
+    "decode_slot_steps_total",
+    "Slot-steps of the slot loop by what the slot was doing at that "
+    "decode step: emitting (a token came out), prefilling (admitted, "
+    "its prompt chunks or its activation still ahead), drain_blocked "
+    "(empty while the FIFO head waits for the ring session to drain "
+    "and restart) or no_demand (empty, nothing pending).  The four "
+    "sum to steps x slots.",
+    labels=("model", "state"))
+SLOT_SESSION_RESETS = _registry().counter(
+    "decode_slot_session_resets_total",
+    "Ring-session restarts of the slot loop: the FIFO head did not fit "
+    "the ring's remaining columns, the loop drained and the position "
+    "went back to 0.",
+    labels=("model",))
 # per-tenant admission (cluster lifecycle PR): quotas bound how much of
 # the shared queue one tenant can hold, so a burst from tenant A fills
 # A's allowance and then bounces with a retry_after hint instead of
@@ -89,6 +104,18 @@ SLOT_TTFT = _registry().histogram(
     labels=("model",),
     buckets=(0.001, 0.005, 0.01, 0.025, 0.05, 0.1, 0.25, 0.5, 1.0,
              2.5, 5.0))
+SLOT_PHASE = _registry().histogram(
+    "decode_slot_phase_seconds",
+    "A replied slot request's life by phase, from one set of stamps: "
+    "handoff (arrival at the Server -> row handed to the loop), "
+    "admit_wait (the loop's FIFO, ring drains included), prefill "
+    "(slot -> first token), decode (first token -> row retired), "
+    "reply_hold (row retired -> the client's Future resolved; the "
+    "worker's wait for the row's batch-mates); the five sum to total, "
+    "and arrival_ttft is arrival -> first token.",
+    labels=("model", "phase"),
+    buckets=(0.001, 0.005, 0.025, 0.1, 0.25, 0.5, 1.0, 2.5, 5.0, 10.0,
+             20.0, 40.0))
 
 
 @dataclass

@@ -494,15 +494,19 @@ class _Worker(threading.Thread):
             # prefill + scanned decode: one long device program — run it
             # synchronously (the scan IS the pipeline) and slice per
             # request, honoring each request's own max_new cap.  The
-            # runtime emits prefill/decode spans (+ per-token events at
-            # the scan boundary); an eventual escape-hatch compile lands
-            # on the ambient request span
+            # runtime emits prefill/decode spans (the slot loop: the
+            # request's life from its stamps, closed by rt.replied); an
+            # eventual escape-hatch compile lands on the ambient span
             with _tracing.use_span(_first_trace(batch)):
                 toks = rt.execute(batch)
             now = time.perf_counter()
             t_r0 = time.monotonic()
             off = 0
             for r in batch.requests:
+                # the slot rows' lives close here, before the client
+                # can see the answer: whoever reads the loop's stats
+                # after a reply finds that request in them
+                rt.replied(r, time.monotonic())
                 # a parked session's future was already failed
                 # (UnavailableError) by the slot loop's drain park —
                 # don't double-resolve it

@@ -63,6 +63,20 @@ driver thread owns every device dispatch.  Token-level occupancy
 accounting (``decode_slot_occupancy_ratio`` + joined/retired counters,
 scheduler.py instruments) feeds Server.signals() and the PR-16
 ClusterSignals snapshot.
+
+What the loop measures about itself, always on:
+
+  * **the driver thread's phases** — every moment of an iteration lies
+    in exactly one of ``PHASES`` (flat, never nested), each a
+    ``profiler.span`` named by ``SPAN_NAMES`` on the device trace's
+    clock, its seconds accumulated into ``stats()["phase_s"]``;
+  * **a request's life** — ``SlotRequest`` carries the stamps
+    ``t_arrival .. t_reply``; once replied, its phases feed
+    ``stats()["phases_ms"]``, ``decode_slot_phase_seconds`` and, under
+    FLAGS_trace, the children of the request's root span;
+  * **every slot-step** — each decode step counts its ``S`` slots as
+    emitting, prefilling, drain-blocked or without demand
+    (``counters["slot_steps_*"]``, summing to ``steps x S``).
 """
 from __future__ import annotations
 
@@ -77,12 +91,32 @@ import numpy as np
 
 from ..framework.enforce import (InvalidArgumentError, OutOfRangeError,
                                  UnavailableError)
-from .scheduler import (SLOT_OCCUPANCY, SLOT_TTFT, SLOTS_JOINED,
-                        SLOTS_RETIRED)
+from ..profiler import span as _span
+from ..profiler import tracing as _tracing
+from ..profiler.metrics import LatencyWindow
+from .scheduler import (SLOT_OCCUPANCY, SLOT_PHASE, SLOT_SESSION_RESETS,
+                        SLOT_STEPS, SLOT_TTFT, SLOTS_JOINED, SLOTS_RETIRED)
 
-__all__ = ["SlotLoop", "SlotRequest"]
+__all__ = ["SlotLoop", "SlotRequest", "PHASES", "SPAN_NAMES",
+           "REQUEST_PHASES"]
 
 _EMPTY, _PREFILL, _GEN = 0, 1, 2
+
+# the driver thread's phases in loop order: the keys of
+# ``stats()["phase_s"]``.  ``idle_wait`` (nothing live), ``chunk_fetch``
+# (a final chunk's logits) and ``step_fetch`` (the step's tokens) wait;
+# the other five are the host's own work.
+PHASES = ("idle_wait", "admit", "chunk_dispatch", "chunk_fetch", "activate",
+          "step_dispatch", "step_fetch", "retire")
+# their spans in a profiler capture; spelled here and nowhere else
+SPAN_NAMES = tuple(f"slot_loop::{p}" for p in PHASES)
+_SPAN_OF = dict(zip(PHASES, SPAN_NAMES))
+# a replied request's life: five phases that sum to ``total``, and the
+# time to its first token from its arrival
+REQUEST_PHASES = ("handoff", "admit_wait", "prefill", "decode", "reply_hold",
+                  "arrival_ttft", "total")
+# what a slot is doing at a decode step
+_SLOT_STATES = ("emitting", "prefilling", "drain_blocked", "no_demand")
 
 
 @dataclass
@@ -97,12 +131,27 @@ class SlotRequest:
     replayed into the result), ``planes``/``planes_len`` the host KV
     pytree covering the leading ``planes_len`` transcript tokens, and
     ``resume_logits``/``resume_cur`` the activation payload for the
-    no-suffix mid-generation resume (plain / speculative loop)."""
+    no-suffix mid-generation resume (plain / speculative loop).
+
+    The stamps (``time.monotonic``) follow the request from its arrival
+    at the Server (``t_arrival``; a bare loop's is ``t_submit``) through
+    ``t_submit`` (handed to the loop), ``t_admit`` (a slot), ``t_first``
+    (first token), ``t_retire`` (row done) to ``t_reply`` (the client's
+    Future resolved; a bare loop's is ``t_retire``).  A Server worker
+    that holds the reply back for the row's batch-mates sets
+    ``deferred_reply`` and calls ``SlotLoop.replied``."""
 
     prompt: np.ndarray
     max_new: int
     future: Future = field(default_factory=Future)
     t_submit: float = field(default_factory=time.monotonic)
+    t_arrival: Optional[float] = None
+    t_admit: Optional[float] = None
+    t_first: Optional[float] = None
+    t_retire: Optional[float] = None
+    t_reply: Optional[float] = None
+    deferred_reply: bool = False
+    trace: Any = None                   # the request's root span, if traced
     session_id: Optional[str] = None
     preseed: List[int] = field(default_factory=list)
     planes: Any = None
@@ -110,6 +159,16 @@ class SlotRequest:
     resume_logits: Optional[np.ndarray] = None
     resume_cur: Optional[int] = None
     snapshot: Any = None                # original snapshot (re-park on abort)
+
+    def phases(self) -> dict:
+        """Seconds of each of ``REQUEST_PHASES`` for a replied request."""
+        return {"handoff": self.t_submit - self.t_arrival,
+                "admit_wait": self.t_admit - self.t_submit,
+                "prefill": self.t_first - self.t_admit,
+                "decode": self.t_retire - self.t_first,
+                "reply_hold": self.t_reply - self.t_retire,
+                "arrival_ttft": self.t_first - self.t_arrival,
+                "total": self.t_reply - self.t_arrival}
 
 
 class _Slot:
@@ -182,13 +241,31 @@ class SlotLoop:
         self.counters = {"joined": 0, "retired": 0, "steps": 0,
                          "chunks": 0, "session_resets": 0,
                          "emitted_tokens": 0, "parked": 0, "restored": 0,
-                         "prefix_hit_tokens": 0, "restore_pushes": 0}
+                         "prefix_hit_tokens": 0, "restore_pushes": 0,
+                         **{f"slot_steps_{k}": 0 for k in _SLOT_STATES}}
+        # the driver's phase clock (driver-thread-owned): the phase it is
+        # in, since when, its open span, and the seconds not yet
+        # committed to _phase_s
+        self._ph: Optional[str] = None
+        self._ph_t0 = 0.0
+        self._ph_span = None
+        self._ph_acc = dict.fromkeys(PHASES, 0.0)
+        self._blocked = False           # driver-thread-owned: _admit left the head waiting
+        self._step_emitted = 0          # driver-thread-owned: tokens of the step under way
+        self._phase_s = dict.fromkeys(PHASES, 0.0)          # guarded-by: _cond
+        self._phase_win = {k: LatencyWindow()               # guarded-by: _cond
+                           for k in REQUEST_PHASES}
         # child instruments resolved once — .labels() is a registry
         # lookup and the step path is hot
         self._m_occ = SLOT_OCCUPANCY.labels(model=self._model)
         self._m_joined = SLOTS_JOINED.labels(model=self._model)
         self._m_retired = SLOTS_RETIRED.labels(model=self._model)
         self._m_ttft = SLOT_TTFT.labels(model=self._model)
+        self._m_resets = SLOT_SESSION_RESETS.labels(model=self._model)
+        self._m_steps = [SLOT_STEPS.labels(model=self._model, state=k)
+                         for k in _SLOT_STATES]
+        self._m_phase = {k: SLOT_PHASE.labels(model=self._model, phase=k)
+                         for k in REQUEST_PHASES}
         self._occupancy = 0.0               # EWMA of generating/S
         self._ttft: "deque[float]" = deque(maxlen=512)
         if self._spec:
@@ -281,13 +358,26 @@ class SlotLoop:
     # -- producer ------------------------------------------------------------
     def submit(self, prompt, max_new: int, session_id: Optional[str] = None,
                snapshot=None) -> Future:
+        return self.enqueue(prompt, max_new, session_id, snapshot).future
+
+    def enqueue(self, prompt, max_new: int,
+                session_id: Optional[str] = None, snapshot=None,
+                t_arrival: Optional[float] = None,
+                trace=None) -> SlotRequest:
+        """``submit`` for a caller that replies to its own client later
+        (the Server worker): returns the request, stamped with the
+        client's ``t_arrival`` and carrying its root span, and leaves
+        ``t_reply`` to that caller's ``replied``."""
         p = np.asarray(prompt).reshape(-1).astype(np.int32)
         if p.size == 0:
             raise InvalidArgumentError("empty prompt (0 tokens)")
         mn = int(max_new)
         if mn < 1:
             raise InvalidArgumentError("max_new must be >= 1")
-        req = SlotRequest(prompt=p, max_new=mn, session_id=session_id)
+        req = SlotRequest(prompt=p, max_new=mn, session_id=session_id,
+                          trace=trace)
+        req.deferred_reply = t_arrival is not None
+        req.t_arrival = req.t_submit if t_arrival is None else t_arrival
         if snapshot is not None:
             self._prepare_restore(req, snapshot)
         if len(req.preseed) >= mn:
@@ -295,7 +385,7 @@ class SlotLoop:
             # without touching a slot (deterministic replay)
             req.future.set_result(
                 np.asarray(req.preseed[:mn], np.int32))
-            return req.future
+            return req
         if self._min_need(req) > self.C:
             raise OutOfRangeError(
                 f"prompt of {p.size} tokens + max_new {mn} can never fit "
@@ -314,7 +404,31 @@ class SlotLoop:
                     daemon=True)
                 self._thread.start()
             self._cond.notify_all()
-        return req.future
+        return req
+
+    def replied(self, req: SlotRequest, t_reply: Optional[float] = None):
+        """Close a retired request's life at ``t_reply`` (now, if not
+        given): its phases go to the windows behind
+        ``stats()["phases_ms"]``, to ``decode_slot_phase_seconds`` and,
+        if the request is traced, under its root span.  A request whose
+        row never retired (failed, parked, resolved from its preseed)
+        adds nothing; a second call neither."""
+        if req.t_retire is None or req.t_reply is not None:
+            return
+        req.t_reply = time.monotonic() if t_reply is None else t_reply
+        ph = req.phases()
+        with self._cond:
+            for k, v in ph.items():
+                self._phase_win[k].observe(v)
+        for k, v in ph.items():
+            self._m_phase[k].observe(v)
+        if req.trace is not None:
+            for name, t0, t1 in (
+                    ("slot_queue", req.t_submit, req.t_admit),
+                    ("slot_prefill", req.t_admit, req.t_first),
+                    ("slot_decode", req.t_first, req.t_retire),
+                    ("reply_hold", req.t_retire, req.t_reply)):
+                _tracing.child(req.trace, name, t0, t1)
 
     def close(self):
         """Stop the driver once in-flight work drains; pending requests
@@ -327,9 +441,35 @@ class SlotLoop:
             t.join(timeout=30)
 
     # -- the driver loop -----------------------------------------------------
+    def _phase(self, name: Optional[str]):
+        """Driver thread: leave the phase it is in and enter ``name``
+        (None: leave only).  One clock reading per boundary; the span
+        and the seconds share it, so the phases are flat and cover the
+        whole iteration."""
+        if name == self._ph:
+            return
+        now = time.monotonic()
+        if self._ph is not None:
+            self._ph_acc[self._ph] += now - self._ph_t0
+            self._ph_span.end()
+        self._ph, self._ph_t0 = name, now
+        if name is not None:
+            self._ph_span = _span(_SPAN_OF[name])
+            self._ph_span.begin()
+
+    def _commit_phases(self):
+        """Driver thread, under the lock: hand the seconds gathered since
+        the last commit to ``_phase_s`` (what ``stats()`` reads and
+        ``reset_stats()`` zeroes)."""
+        for k, v in self._ph_acc.items():
+            if v:
+                self._phase_s[k] += v
+                self._ph_acc[k] = 0.0
+
     def _drive(self):
         try:
             while True:
+                self._phase("admit")
                 with self._cond:
                     while (not self._pending
                            and all(s.state == _EMPTY
@@ -344,7 +484,10 @@ class SlotLoop:
                             # so a drain never waits on an idle loop
                             self._park_req[0].set()
                             self._park_req = None
+                        self._phase("idle_wait")
                         self._cond.wait(0.05)
+                        self._phase("admit")
+                        self._commit_phases()
                     if self._closed and not self._any_live():
                         self._fail_pending(UnavailableError(
                             "slot loop closed before this request was "
@@ -354,8 +497,11 @@ class SlotLoop:
                     self._park_req = None
                     if park is not None:
                         self._do_park(park)
-                    self._admit()
+                    self._commit_phases()
+                    self._blocked = self._admit()
+                self._phase("chunk_dispatch")
                 self._dispatch_chunks()
+                self._phase("activate")
                 self._activate()
                 if not any(s.state == _GEN for s in self._slots):
                     self._fast_forward()
@@ -372,6 +518,10 @@ class SlotLoop:
                         s.req.future.set_exception(e)
                     s.state, s.req = _EMPTY, None
                 self._fail_pending(e)
+        finally:
+            self._phase(None)
+            with self._cond:
+                self._commit_phases()
 
     def _any_live(self) -> bool:
         return bool(self._pending) or any(s.state != _EMPTY
@@ -418,7 +568,9 @@ class SlotLoop:
         """Move pending FIFO heads into empty slots at the current token
         boundary.  Strict FIFO: if the head does not fit the remaining
         ring columns, nothing behind it jumps the line — the loop drains
-        and restarts the session instead."""
+        and restarts the session instead.  Returns whether it left the
+        head waiting for that drain: the slots then stand empty with
+        demand behind them."""
         for slot in self._slots:
             if not self._pending or slot.state != _EMPTY:
                 continue
@@ -431,10 +583,12 @@ class SlotLoop:
                     # restart, planes stay — stale columns are invisible)
                     self.pos = 0
                     self.counters["session_resets"] += 1
+                    self._m_resets.inc()
                 else:
-                    break                        # drain first
+                    return True                  # drain first
             self._pending.popleft()
             self._install(slot, head)
+        return False
 
     def _install(self, slot: "_Slot", head: "SlotRequest"):
         """Stage one admitted request into a slot row: pick the restore
@@ -506,6 +660,7 @@ class SlotLoop:
                                for k in range(n_chunks)]
                 slot.act = self._plan_act(lp)
                 slot.start = slot.act - lp
+        head.t_admit = time.monotonic()
         slot.req = head
         slot.next_chunk = 0
         slot.emitted = list(head.preseed)
@@ -547,6 +702,7 @@ class SlotLoop:
                 # fresh buffers per dispatch: the CPU runtime may alias
                 # a numpy argument zero-copy and read it asynchronously,
                 # so a buffer handed to a dispatch is immutable forever
+                self._phase("chunk_dispatch")
                 ids = slot.chunks[slot.next_chunk].reshape(1, self.T)
                 start = np.array([slot.start], np.int32)
                 base = slot.act - len(slot.chunks) * self.T \
@@ -562,6 +718,7 @@ class SlotLoop:
                     # MUST be a host copy: activation reads it one or
                     # more dispatches later, after the runtime may have
                     # reused the output buffer a zero-copy view aliases.
+                    self._phase("chunk_fetch")
                     slot._act_logits = np.array(logits, np.float32)
 
     def _push_restores(self, i: int, slot: "_Slot"):
@@ -635,23 +792,43 @@ class SlotLoop:
     def _decode_step(self):
         gen_slots = [i for i, s in enumerate(self._slots)
                      if s.state == _GEN]
+        n_prefill = sum(1 for s in self._slots if s.state == _PREFILL)
+        n_empty = self.S - len(gen_slots) - n_prefill
+        # an empty slot had demand behind it only if _admit, which ran in
+        # this same iteration, left the FIFO head waiting for the drain
+        split = (len(gen_slots), n_prefill,
+                 n_empty if self._blocked else 0,
+                 0 if self._blocked else n_empty)
         ratio = len(gen_slots) / self.S
         self._occupancy = ratio if self.counters["steps"] == 0 \
             else 0.9 * self._occupancy + 0.1 * ratio
         self._m_occ.set(round(ratio, 4))
+        self._phase("step_dispatch")
         if self._spec:
             self._spec_step(gen_slots)
         else:
             self._plain_step(gen_slots)
-        self.counters["steps"] += 1
+        # one commit under the lock, so a reset_stats() from another
+        # thread never splits a step: the four states sum to steps x S
+        with self._cond:
+            self.counters["steps"] += 1
+            self.counters["emitted_tokens"] += self._step_emitted
+            for k, n in zip(_SLOT_STATES, split):
+                self.counters[f"slot_steps_{k}"] += n
+        self._step_emitted = 0
+        for m, n in zip(self._m_steps, split):
+            if n:
+                m.inc(n)
 
     def _plain_step(self, gen_slots):
         self._cache, self._logits, finished, tok = self._step(
             *self._gen._state_args(), self._cache, self._logits,
             self._start, self._finished, self._active,
             np.int32(self.pos))
+        self._phase("step_fetch")
         tok = np.asarray(tok)
         self._finished = np.array(finished)
+        self._phase("retire")
         self.pos += 1
         for i in gen_slots:
             slot = self._slots[i]
@@ -672,10 +849,12 @@ class SlotLoop:
             *self._gen._state_args(), self._cache, self._cur,
             self._start, self._finished, self._active,
             np.int32(self.pos), np.int32(mc))
+        self._phase("step_fetch")
         self._cur = np.array(cur)
         self._finished = np.array(finished)
         e = np.asarray(e)
         k = int(ncommit)
+        self._phase("retire")
         self.pos += k
         self._accepted += int(n)
         self._proposed += self._gamma
@@ -686,13 +865,15 @@ class SlotLoop:
                 self._retire(i)
 
     def _emit(self, slot, toks):
-        if not slot.emitted:
-            dt = time.monotonic() - slot.req.t_submit
-            self._ttft.append(dt)
-            self._m_ttft.observe(dt)
+        if slot.req.t_first is None:
+            slot.req.t_first = time.monotonic()
+            if not slot.emitted:
+                dt = slot.req.t_first - slot.req.t_submit
+                self._ttft.append(dt)
+                self._m_ttft.observe(dt)
         take = slot.req.max_new - len(slot.emitted)
         slot.emitted.extend(toks[:take])
-        self.counters["emitted_tokens"] += min(len(toks), take)
+        self._step_emitted += min(len(toks), take)
 
     def _publish_prefix(self, i: int, slot: "_Slot"):
         """Publish the activated row's prompt blocks into the prefix
@@ -752,6 +933,9 @@ class SlotLoop:
             # admit could reuse this row and overwrite the columns the
             # snapshot needs (its padded block may start below pos)
             self._park(i, slot, remaining=0)
+        req.t_retire = time.monotonic()
+        if not req.deferred_reply:
+            self.replied(req, req.t_retire)
         # eos freeze: every position after finish reads eos, exactly the
         # scanned decode's padding — retiring early never changes bytes
         req.future.set_result(out)
@@ -855,6 +1039,8 @@ class SlotLoop:
                 self.counters[k] = 0
             self._occupancy = 0.0
             self._ttft.clear()
+            self._phase_s = dict.fromkeys(PHASES, 0.0)
+            self._phase_win = {k: LatencyWindow() for k in REQUEST_PHASES}
             if self._spec:
                 self._accepted = 0
                 self._proposed = 0
@@ -885,8 +1071,17 @@ class SlotLoop:
         with self._cond:
             c = dict(self.counters)
             ttft = sorted(self._ttft)
+            phase_s = dict(self._phase_s)
+            wins = dict(self._phase_win)
         out = {"slots": self.S, "cache": self.C, "chunk": self.T,
-               "occupancy_ewma": round(self._occupancy, 4), **c}
+               "occupancy_ewma": round(self._occupancy, 4), **c,
+               # the driver's seconds by phase, and the phases of the
+               # requests replied, both since the last reset_stats()
+               "phase_s": phase_s,
+               "phases_ms": {k: {"n": w.count,
+                                 "p50": 1e3 * w.percentile(50),
+                                 "p90": 1e3 * w.percentile(90)}
+                             for k, w in wins.items() if w.count}}
         if ttft:
             out["ttft_p50_ms"] = round(
                 ttft[len(ttft) // 2] * 1e3, 3)

@@ -779,8 +779,7 @@ class Generator:
                               eos_token_id=eos_token_id)
         else:
             # traced call: fence at the scan boundary so the
-            # prefill/decode split (and the per-token attribution across
-            # the scanned token loop) is honest device time; any compile
+            # prefill/decode split is honest device time; any compile
             # the call pays lands on this span via the ledger hook
             with _tracing.use_span(tr):
                 t0 = time.monotonic()
@@ -808,13 +807,11 @@ class Generator:
         return Tensor(paths), Tensor(scores)
 
     def _annotate_decode_span(self, d, t1, t2, steps):
-        """Fill the traced decode span: one event per generated token,
-        spread uniformly across the fenced scan window (the token loop
-        is ONE device program; the host never observes token k alone).
-        The speculative subclass adds draft/verify children here."""
-        dt = (t2 - t1) / steps
-        for k in range(steps):
-            d.event("token", t=t1 + (k + 1) * dt, index=k)
+        """Hook for what a subclass knows about the fenced decode window
+        (the speculative one adds draft/verify children).  The token
+        loop is ONE device program: the host never observes token k
+        alone, so the span carries ``steps`` and ``per_token_ms`` and no
+        per-token events."""
 
     __call__ = generate
 

@@ -492,7 +492,7 @@ class SpeculativeGenerator(_Generator):
         """The speculative step is one device program: split the fenced
         decode window into estimated ``draft``/``verify`` child spans by
         the models' parameter-byte ratio and attach the measured
-        acceptance stats, then the uniform per-token events."""
+        acceptance stats."""
         st = self.last_stats or {}
         tm = t1 + (t2 - t1) * self._draft_fraction
         _tracing.child(d, "draft", t1, tm, estimated=True,
@@ -504,4 +504,3 @@ class SpeculativeGenerator(_Generator):
         d.set_attr(gamma=self._gamma,
                    acceptance_rate=st.get("acceptance_rate"),
                    spec_steps=st.get("spec_steps"))
-        super()._annotate_decode_span(d, t1, t2, steps)

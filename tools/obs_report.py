@@ -49,6 +49,11 @@ REQUIRED_PHASES = {
     "dense": {"queue_wait", "pack", "execute", "reply"},
     "decode": {"queue_wait", "pack", "prefill", "decode", "reply"},
 }
+# a decode request served by the slot loop (FLAGS_decode_slots): its
+# phases are cut from the SlotRequest stamps (serving/slots.py) instead
+# of the scanned path's prefill/decode
+SLOT_DECODE_PHASES = {"queue_wait", "pack", "slot_queue", "slot_prefill",
+                      "slot_decode", "reply_hold", "reply"}
 # cross-process chains: what a cluster trace must carry beyond the
 # route root.  Unified routing proxies the whole request to one replica
 # (request subroot + its in-process phases); disaggregated decode
@@ -75,6 +80,14 @@ def load_traces(trace_dir):
     return out
 
 
+def required_phases(kind, names):
+    """The phases a root of ``kind`` must carry, given the span names it
+    has: a decode request that went through slots shows ``slot_queue``."""
+    if kind == "decode" and "slot_queue" in names:
+        return SLOT_DECODE_PHASES
+    return REQUIRED_PHASES.get(kind, set())
+
+
 def check_chain(spans):
     """Validate one trace: returns (ok, problems list).  Complete =
     every phase the root's kind requires is present; well-nested = every
@@ -87,7 +100,7 @@ def check_chain(spans):
     root = roots[0]
     kind = root.get("attrs", {}).get("kind", "dense")
     names = {s["name"] for s in spans if s is not root}
-    missing = REQUIRED_PHASES.get(kind, set()) - names
+    missing = required_phases(kind, names) - names
     if missing:
         problems.append(f"incomplete chain (kind={kind}): missing "
                         f"{sorted(missing)}")
@@ -137,7 +150,7 @@ def check_cluster_chain(spans, eps=_CLUSTER_EPS_S):
     shape = "disaggregated" if "handoff" in names else "unified"
     required = set(REQUIRED_CLUSTER_PHASES[shape])
     if shape == "unified":
-        required |= REQUIRED_PHASES.get(kind, set())
+        required |= required_phases(kind, names)
     missing = required - names
     if missing:
         problems.append(f"incomplete cluster chain (kind={kind}, "
