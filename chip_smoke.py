@@ -70,6 +70,7 @@ KERNEL_TOL = {
     "flash_attention_bwd": (5e-2, 5e-2),
     "flash_decode": (4e-3, 2e-2),
     "flash_decode_int8": (4e-3, 2e-2),
+    "packed_cached_attention": (4e-3, 2e-2),
     "fused_conv_bn_relu": (5e-2, 5e-2),
     "fused_bn_relu": (5e-2, 5e-2),
 }
@@ -531,6 +532,30 @@ def phase_kernels(size: Size, seed: int = 0) -> dict:
         checked[name] = {"compile_seconds": round(c_s, 3),
                          "max_abs_err": round(err, 6),
                          "tolerance": list(KERNEL_TOL[name])}
+    # not a Pallas kernel, but chip-only all the same: the XLA attention
+    # over PACKED ring planes (two heads of 64 per lane row, an odd head
+    # count).  The chip's compiler once returned other values than the
+    # CPU's for it (a concatenate of lane-offset slices; PERF.md, PR 25),
+    # and "slot loop equals generate()" cannot see that: both are packed
+    from paddle_tpu.nn.functional.attention import _sdpa_packed_fn
+    from paddle_tpu.nn.layer.transformer import (kv_heads_per_lane_row,
+                                                 pack_heads)
+    odd = max(1, N - 1)
+    g = kv_heads_per_lane_row(H)
+    col = jnp.arange(S)
+    window = (col >= start[:, None]) & (col < end[:, None])
+    mask = jnp.where(window, 0.0, -1e30).astype(jnp.float32)[:, None, None]
+    got = jax.block_until_ready(jax.jit(_sdpa_packed_fn)(
+        q1[:, :odd], pack_heads(k[:, :odd], g), pack_heads(v[:, :odd], g),
+        mask))
+    with jax.default_matmul_precision("highest"):
+        ref = decode_attention_reference(
+            *(a[:, :odd].astype(jnp.float32) for a in (q1, k, v)),
+            start, end)
+    checked["packed_cached_attention"] = {
+        "heads_per_lane_row": g,
+        "max_abs_err": round(_close("packed_cached_attention", got, ref), 6),
+        "tolerance": list(KERNEL_TOL["packed_cached_attention"])}
     checked["compiled_not_interpreted"] = on_chip
     checked["shapes"] = {"attention": list(size.attn),
                          "conv_x": list(size.conv_x),

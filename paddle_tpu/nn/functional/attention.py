@@ -49,8 +49,50 @@ def _sdpa_mask_fn(q, k, v, mask, scale=None, causal=False):
                       preferred_element_type=jnp.float32).astype(q.dtype)
 
 
+def _sdpa_packed_fn(q, k, v, mask=None):
+    """Masked attention of ``(B, N, Tq, H)`` queries over PACKED ring
+    planes ``(B, G, C, g*H)`` (``g`` adjacent heads side by side on the
+    minor dim, ``G = ceil(N/g)``; nn/layer/transformer.py
+    ``gen_ring_cache``).  The planes are never reshaped — splitting
+    their lanes would bring back a full-plane copy — only the small
+    operands are rearranged: each query is spread over its group's
+    ``g*H`` lanes with zeros outside its own head's, so contracting
+    over all lanes is the head's own dot product plus exact zeros; the
+    probabilities contract with V over the columns and each head keeps
+    its own ``H`` lanes of the result (the inverse of ``pack_heads``).
+    Same scale, mask, f32 softmax and accumulation as
+    :func:`_sdpa_mask_fn`."""
+    b, n, t, hd = q.shape
+    groups, lanes = k.shape[1], k.shape[3]
+    g = lanes // hd
+    qg = jnp.pad(q, ((0, 0), (0, groups * g - n), (0, 0), (0, 0))) \
+        .reshape(b, groups, g, t, hd)
+    own = jnp.arange(lanes)[None, :] // hd == jnp.arange(g)[:, None]
+    qs = jnp.where(own[None, None, :, None, :],
+                   jnp.tile(qg, (1, 1, 1, 1, g)), jnp.zeros((), q.dtype))
+    logits = jnp.einsum("bgjtl,bgcl->bgjtc", qs, k,
+                        preferred_element_type=jnp.float32) \
+        * (1.0 / math.sqrt(hd))
+    if mask is not None:
+        logits = logits + mask.astype(logits.dtype)[:, :, None]
+    probs = jax.nn.softmax(logits, axis=-1).astype(q.dtype)
+    out = jnp.einsum("bgjtc,bgcl->bgjtl", probs, v,
+                     preferred_element_type=jnp.float32).astype(q.dtype)
+    # each head keeps its own lanes: select and sum over j (one term is
+    # non-zero, so the sum is exact), NOT a stack of out[..., j, :,
+    # j*H:(j+1)*H] slices — the TPU compiler of jax 0.9.0 miscompiles a
+    # concatenate of slices taken at a lane offset (wrong values on the
+    # v5e, right on the CPU; PERF.md section 6, PR 25)
+    out = jnp.where(own[None, None, :, None, :], out,
+                    jnp.zeros((), q.dtype)).sum(axis=2)
+    return out.reshape(b, groups, t, g, hd).transpose(0, 1, 3, 2, 4) \
+        .reshape(b, groups * g, t, hd)[:, :n]
+
+
 _sdpa = Primitive("scaled_dot_product_attention", _sdpa_fn)
 _sdpa_mask = Primitive("scaled_dot_product_attention_mask", _sdpa_mask_fn)
+_sdpa_packed = Primitive("scaled_dot_product_attention_packed",
+                         _sdpa_packed_fn)
 
 
 def _use_pallas(q, k, mask=None, causal=False):
@@ -96,7 +138,12 @@ def scaled_dot_product_attention(query, key, value, attn_mask=None,
 def _use_flash_decode(q, k, window):
     """Dispatch gate for the decode step: FLAGS_use_flash_decode + TPU
     platform + single-query shapes + a contiguous [start, end) validity
-    window (the kernel masks a window, not an arbitrary dense mask)."""
+    window (the kernel masks a window, not an arbitrary dense mask) +
+    UNPACKED (B, N, S, H) planes (``supports_decode`` refuses a cache
+    whose head count or head_dim differs from the query's): the Pallas
+    kernels index heads at axis 1 and were not ported to the packed
+    ring planes, so today they can serve head_dim >= 128 and the int8
+    cache only."""
     if window is None or not flag("use_flash_decode") \
             or jax.default_backend() != "tpu":
         return False
@@ -107,7 +154,10 @@ def _use_flash_decode(q, k, window):
 def cached_attention(q, k, v, attn_mask=None, window=None, k_scale=None,
                      v_scale=None):
     """Incremental attention: (B, N, Tq, H) new-token queries over the
-    full (B, N, S, H) KV ring cache.
+    full KV ring cache — bf16/f32 planes packed ``(B, ceil(N/g), S,
+    g*H)`` as ``gen_ring_cache`` builds them (``g`` is read from the
+    plane's minor dim; ``g == 1`` is the plain (B, N, S, H) plane), or
+    the int8 cache's unpacked (B, N, S, H) rows.
 
     ``attn_mask`` is the additive validity+causality mask the caller
     built from cache_position / per-row start offsets.  ``window`` is the
@@ -135,6 +185,9 @@ def cached_attention(q, k, v, attn_mask=None, window=None, k_scale=None,
     if _use_flash_decode(q, k, window):
         from ...ops.pallas import flash_decode
         return flash_decode(q, k, v, window[0], window[1])
+    if unwrap(k).shape[-1] != unwrap(q).shape[-1]:
+        return _sdpa_packed(q, k, v, attn_mask) if attn_mask is not None \
+            else _sdpa_packed(q, k, v)
     if attn_mask is not None:
         return _sdpa_mask(q, k, v, attn_mask)
     return _sdpa(q, k, v)

@@ -45,10 +45,20 @@ def ring_block_write(plane, new, pos, axis=None):
       * leg 2 at static 0: the wrapped head run ``[0, pos + T - C)``,
         a no-op rewrite of current contents when nothing wrapped.
 
-    Both legs keep the traced start on the SUBLANE (sequence) dim with
-    the lane dim fully spanned — the in-tile masked store/load pattern
-    the graph-lint layout pass exempts.  Shapes: ``plane [..., C, L]``,
-    ``new [..., T, L]``; ``axis`` defaults to ``ndim - 2``.
+    Shapes: ``plane [..., C, L]``, ``new [..., T, L]``; ``axis``
+    defaults to ``ndim - 2``.  Both legs put the traced start on the
+    second-minor LOGICAL dim and span ``L``.  Whether that is the
+    SUBLANE dim on the chip is the compiler's choice, made from the
+    shape: for a bf16/f32 plane whose ``L`` is a multiple of 128 the
+    TPU compiler keeps the row-major ``{..., C, L}`` device layout, the
+    start lands on the sublanes and a one-column write is one masked
+    tile row per ``[..., :, L]`` slab; for ``L`` < 128 it avoids
+    padding ``L`` by putting ``C`` on the LANES (``{2,3,1,0}`` for a
+    ``[B, N, C, 64]`` plane), and the same write becomes a lane-masked
+    read-modify-write of every slab's tiles.  ``gen_ring_cache`` packs
+    heads along ``L`` so that its planes are of the first kind
+    (:func:`kv_heads_per_lane_row`); ``tools/kv_layout_check.py`` reads
+    the layout back from the compiled program.
     """
     import jax.numpy as jnp
     from jax import lax
@@ -93,6 +103,34 @@ def ring_block_write(plane, new, pos, axis=None):
     return Tensor(out) if wrap else out
 
 
+_LANES = 128     # minor-dimension tile width of the TPU's device layouts
+
+
+def kv_heads_per_lane_row(head_dim):
+    """``g``: how many heads of ``head_dim`` a ring plane packs side by
+    side along its minor dimension so that it spans whole 128-lane
+    rows.  1 when ``head_dim`` already fills a row (>= 128) or does
+    not divide one (packing would not reach a multiple of 128)."""
+    head_dim = int(head_dim)
+    return _LANES // head_dim if _LANES % head_dim == 0 else 1
+
+
+def pack_heads(x, g):
+    """``[B, N, T, H]`` per-head rows -> ``[B, ceil(N/g), T, g*H]``
+    with ``g`` adjacent heads side by side on the minor dimension (the
+    ring plane's layout, column dim still at axis 2).  ``N`` pads to a
+    multiple of ``g`` with zero heads; ``g == 1`` returns ``x``."""
+    import jax.numpy as jnp
+    xv = unwrap(x)
+    if g == 1:
+        return xv
+    b, n, t, h = xv.shape
+    groups = -(-n // g)
+    xv = jnp.pad(xv, ((0, 0), (0, groups * g - n), (0, 0), (0, 0)))
+    return xv.reshape(b, groups, g, t, h).transpose(0, 1, 3, 2, 4) \
+        .reshape(b, groups, t, g * h)
+
+
 def quantize_kv_rows(x):
     """Per-(token, head) symmetric int8 quantization of a K/V block
     ``[B, N, T, H]``: one f32 scale per head-row (the dequant is a
@@ -122,17 +160,23 @@ def dequantize_kv_rows(q, scale, dtype=None):
 class MultiHeadAttention(Layer):
     Cache = collections.namedtuple("Cache", ["k", "v"])
     StaticCache = collections.namedtuple("StaticCache", ["k", "v"])
-    # static-shape decoding cache: (B, N, max_len, H) ring buffers written
-    # in place with lax.dynamic_update_slice at an explicit (possibly
-    # traced) cache_position — unlike Cache's concat, the shape never
-    # grows, so one decode executable serves every step (zero per-token
-    # recompiles; single-token writes wrap modulo max_len and wider
-    # blocks split into two legs at the boundary via ring_block_write)
+    # static-shape decoding cache: (B, ceil(N/g), max_len, g*H) ring
+    # buffers, g = kv_heads_per_lane_row(H) adjacent heads side by side
+    # on the minor dim (g == 1, i.e. (B, N, max_len, H), for H >= 128),
+    # written in place with lax.dynamic_update_slice at an explicit
+    # (possibly traced) cache_position — unlike Cache's concat, the
+    # shape never grows, so one decode executable serves every step
+    # (zero per-token recompiles; single-token writes wrap modulo
+    # max_len and wider blocks split into two legs at the boundary via
+    # ring_block_write)
     RingCache = collections.namedtuple("RingCache", ["k", "v"])
     # int8-quantized ring cache (FLAGS_kv_cache_dtype=int8): k/v hold
     # int8 rows, k_scale/v_scale the per-(token, head) f32 scales as
     # extra (B, N, max_len, 1) cache planes written at the SAME traced
-    # position — cached-context HBM halves (plus the scale overhead)
+    # position — cached-context HBM halves (plus the scale overhead).
+    # Keeps the UNPACKED (B, N, max_len, H) contract: one scale per
+    # (token, head) and the Pallas flash_decode_quant kernel both index
+    # heads at axis 1
     QuantRingCache = collections.namedtuple(
         "QuantRingCache", ["k", "v", "k_scale", "v_scale"])
 
@@ -175,12 +219,23 @@ class MultiHeadAttention(Layer):
         return self.Cache(k, v)
 
     def gen_ring_cache(self, batch, max_len, dtype="float32"):
-        """Zero-initialized static-shape KV ring cache (B, N, max_len, H).
-        ``max_len`` is a compile-time constant; validity is tracked by the
-        caller's cache_position/window, not by the shape.  Under
-        ``FLAGS_kv_cache_dtype=int8`` the planes are int8 rows plus
-        per-(token, head) f32 scale planes (QuantRingCache) — one Python
-        branch here, zero graph change on the default path."""
+        """Zero-initialized static-shape KV ring cache
+        ``(B, ceil(N/g), max_len, g*H)``: ``g`` adjacent heads share a
+        row of the minor dimension (:func:`kv_heads_per_lane_row`; the
+        heads pad to a multiple of ``g`` and the padded head's lanes
+        stay zero), so a token's K/V for a row is contiguous on the
+        device and the step's one-column write at the traced position
+        lands on the sublanes (see :func:`ring_block_write`).  ``g`` is
+        computed from ``head_dim``: 2 for 64, 8 for 16, and 1 — the
+        plain ``(B, N, max_len, H)`` planes — for 128 and more.  This
+        is the ONE place that decides the layout; ``_forward_ring``
+        and ``cached_attention`` read ``g`` back from the plane's minor
+        dimension.  ``max_len`` is a compile-time constant; validity is
+        tracked by the caller's cache_position/window, not by the
+        shape.  Under ``FLAGS_kv_cache_dtype=int8`` the planes are
+        UNPACKED int8 rows ``(B, N, max_len, H)`` plus per-(token,
+        head) f32 scale planes (QuantRingCache) — one Python branch
+        here, zero graph change on the default path."""
         from ...framework import flags as _flags
         from ...ops import zeros
         if str(_flags.flag("kv_cache_dtype")).lower() == "int8":
@@ -190,23 +245,24 @@ class MultiHeadAttention(Layer):
                 zeros(rows, dtype="int8"), zeros(rows, dtype="int8"),
                 zeros(scales, dtype="float32"),
                 zeros(scales, dtype="float32"))
-        k = zeros([batch, self.num_heads, max_len, self.head_dim],
-                  dtype=dtype)
-        v = zeros([batch, self.num_heads, max_len, self.head_dim],
-                  dtype=dtype)
-        return self.RingCache(k, v)
+        g = kv_heads_per_lane_row(self.head_dim)
+        plane = [batch, -(-self.num_heads // g), max_len, g * self.head_dim]
+        return self.RingCache(zeros(plane, dtype=dtype),
+                              zeros(plane, dtype=dtype))
 
     def _forward_ring(self, query, attn_mask, cache, cache_position,
                       decode_window):
         """Incremental attention over the ring cache: project the new
-        tokens, write their K/V at cache_position (ring_block_write on
-        the sequence dim — sublane-masked store, full lanes, two legs at
-        the ring boundary for multi-token blocks), and attend the new
-        queries over the WHOLE cache under the caller's validity mask.
-        Quantized caches additionally write int8 rows + scale planes at
-        the same position and dequantize at the attention read (fused
-        into the flash-decode kernel when it dispatches).  Returns
-        (out, updated RingCache/QuantRingCache)."""
+        tokens, pack their K/V the way the planes are packed
+        (``pack_heads``; ``g`` read from the plane's minor dim), write
+        them at cache_position (ring_block_write on the column dim —
+        two legs at the ring boundary for multi-token blocks), and
+        attend the new queries over the WHOLE cache under the caller's
+        validity mask.  Quantized caches keep unpacked planes,
+        additionally write int8 rows + scale planes at the same
+        position and dequantize at the attention read (fused into the
+        flash-decode kernel when it dispatches).  Returns (out, updated
+        RingCache/QuantRingCache)."""
         from ..functional.attention import cached_attention
         q = self._split_heads(self.q_proj(query))
         k_new = self._split_heads(self.k_proj(query))
@@ -224,8 +280,11 @@ class MultiHeadAttention(Layer):
                                    k_scale=cache.k_scale,
                                    v_scale=cache.v_scale)
         else:
-            k = ring_block_write(cache.k, k_new, cache_position)
-            v = ring_block_write(cache.v, v_new, cache_position)
+            g = cache.k.shape[3] // self.head_dim
+            k = ring_block_write(cache.k, Tensor(pack_heads(k_new, g)),
+                                 cache_position)
+            v = ring_block_write(cache.v, Tensor(pack_heads(v_new, g)),
+                                 cache_position)
             cache = self.RingCache(k, v)
             out = cached_attention(q, k, v, attn_mask=attn_mask,
                                    window=decode_window)

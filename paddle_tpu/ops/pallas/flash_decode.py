@@ -24,7 +24,13 @@ ignores them.
 
 Layout: q ``(B, N, 1, H)``, cached k/v ``(B, N, S, H)``; internally
 ``(B*N, 8, H)`` (the query row broadcast over the 8 sublanes of one tile)
-vs ``(B*N, S, H)``.  Decode is inference-only: no VJP.
+vs ``(B*N, S, H)``.  Decode is inference-only: no VJP.  This is the
+UNPACKED plane contract: the bf16/f32 ring cache packs ``g = 128 // H``
+heads per row of the minor dimension for H < 128
+(nn/layer/transformer.py ``gen_ring_cache``) and these kernels were not
+ported to it, so ``supports_decode`` refuses packed planes and the
+kernels serve H >= 128 and the int8 cache (``flash_decode_quant``,
+unpacked rows + per-(token, head) scales) only.
 
 Gated OFF behind ``FLAGS_use_flash_decode`` / ``PADDLE_TPU_FLASH_DECODE``:
 both kernels compile for v5e and match the XLA reference on the chip
@@ -69,8 +75,10 @@ def _window(start, end, B, S):
 
 def supports_decode(q_shape, k_shape, block: int = 128) -> bool:
     """Shape gate: (B, N, 1, H) query vs (B, N, S, H) cache with S a
-    multiple of the split block and H MXU-friendly.  Callers fall back to
-    the XLA masked-attention path otherwise."""
+    multiple of the split block and H MXU-friendly.  A packed ring
+    plane (B, ceil(N/g), S, g*H) fails the head-count and head_dim
+    equalities below.  Callers fall back to the XLA masked-attention
+    path otherwise."""
     if len(q_shape) != 4 or len(k_shape) != 4:
         return False
     if q_shape[-2] != 1:

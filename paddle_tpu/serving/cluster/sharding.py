@@ -59,10 +59,17 @@ def serving_shard_specs(layer, mesh, rules=None) -> Dict[str, Any]:
 
 def kv_plane_spec(shape: Sequence[int], mesh) -> Any:
     """The pinned KV-cache plane layout for sharded decode: ring planes
-    are [B, heads, C, H] (rows) / [B, heads, C] (int8 scales) — shard
-    the heads axis by ``mp`` when it is live and divides, replicate
-    otherwise.  This single rule makes prefill outputs, decode inputs
-    and cross-pool device ingests agree without consulting each other."""
+    are [B, head groups, C, g*H] (bf16/f32 rows: ``g`` adjacent heads
+    share a row of the minor dim, ``gen_ring_cache``; g = 1 is
+    [B, heads, C, H]) / [B, heads, C, H] and [B, heads, C, 1] (int8
+    rows and their scales) — shard axis 1 by ``mp`` when it is live and
+    divides, replicate otherwise.  A group holds whole heads, so every
+    shard still owns whole heads; with an odd group count (GPT-2 XL: 25
+    heads -> 13 groups) the planes replicate where 25 heads did too.
+    The price of counting groups: ``mp`` must divide ``heads / g``, not
+    ``heads`` (12 heads of 64 shard over mp=2 or 3, no longer over 4).
+    This single rule makes prefill outputs, decode inputs and
+    cross-pool device ingests agree without consulting each other."""
     from jax.sharding import PartitionSpec as P
     mp = dict(mesh.shape).get("mp", 1)
     if len(shape) >= 3 and mp > 1 and int(shape[1]) % mp == 0:

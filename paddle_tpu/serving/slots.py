@@ -215,6 +215,7 @@ class SlotLoop:
         # later dispatch is a plain __call__ — zero steady-state compiles
         self._step = gen.step_exec(self.S, self.C, eos_token_id)
         self._chunk = gen.chunk_exec(self.S, self.T, self.C)
+        self._kv_heads_per_lane_row = gen.kv_heads_per_lane_row()
         # the KV reuse plane (prefix cache / session store): its three
         # data movers compile HERE, with the step/chunk programs, so an
         # arbitrary steady-state hit/miss/park/restore mix never
@@ -1074,6 +1075,7 @@ class SlotLoop:
             phase_s = dict(self._phase_s)
             wins = dict(self._phase_win)
         out = {"slots": self.S, "cache": self.C, "chunk": self.T,
+               "kv_heads_per_lane_row": self._kv_heads_per_lane_row,
                "occupancy_ewma": round(self._occupancy, 4), **c,
                # the driver's seconds by phase, and the phases of the
                # requests replied, both since the last reset_stats()

@@ -6,7 +6,9 @@ The reference stack decodes through the contrib beam-search DSL
 a shape-keyed compiler, would recompile per token as the sequence grows.
 The TPU-idiomatic form fixes every shape at compile time:
 
-  * **ring KV cache** — per attention layer a ``(B, N, C, H)`` buffer
+  * **ring KV cache** — per attention layer a ``(B, ceil(N/g), C, g*H)``
+    buffer (``g`` heads side by side on the minor dim so that a token's
+    K/V is contiguous on the device; ``g = 1`` for head_dim >= 128)
     written in place with ``lax.dynamic_update_slice`` at an explicit
     ``cache_position`` (nn/layer/transformer.py ``RingCache``); batch and
     cache length ``C`` are compile-time constants, validity is a mask;
@@ -215,6 +217,21 @@ class Generator:
         ring = self._layer.init_cache(B, C)
         return [tuple(unwrap(p) for p in c) for c in ring]
 
+    def kv_heads_per_lane_row(self):
+        """How many heads share a row of the minor dimension of this
+        model's ring planes (``g`` of ``gen_ring_cache``; 1 = unpacked
+        ``(B, N, C, H)`` planes: head_dim >= 128, or the int8 cache).
+        Read back from the planes the model builds, so the ledger's
+        ``generate_step`` / ``generate_chunk`` events and
+        ``SlotLoop.stats()`` say which layout a run used."""
+        from ..nn.layer.transformer import MultiHeadAttention
+        mha = next((l for l in self._layer.sublayers()
+                    if isinstance(l, MultiHeadAttention)), None)
+        if mha is None:
+            return 1
+        plane = jax.eval_shape(lambda: self._init_cache_raw(1, 1))[0][0]
+        return int(plane.shape[3]) // int(mha.head_dim)
+
     def _build_prefill(self, B, P, C):
         def prefill(params, buffers, ids, start):
             cache0 = self._init_cache_raw(B, C)
@@ -356,7 +373,9 @@ class Generator:
         fn = self._build_step(S, C, end)
         return self._compile(key, "generate_step", fn,
                              self.step_avals(S, C),
-                             {"slots": S, "cache": C, "eos": end},
+                             {"slots": S, "cache": C, "eos": end,
+                              "kv_heads_per_lane_row":
+                                  self.kv_heads_per_lane_row()},
                              donate_argnums=(2,))
 
     def chunk_exec(self, S, T, C):
@@ -370,7 +389,9 @@ class Generator:
         fn = self._build_chunk(S, T, C)
         return self._compile(key, "generate_chunk", fn,
                              self.chunk_avals(S, T, C),
-                             {"slots": S, "chunk": T, "cache": C},
+                             {"slots": S, "chunk": T, "cache": C,
+                              "kv_heads_per_lane_row":
+                                  self.kv_heads_per_lane_row()},
                              donate_argnums=(2,))
 
     def step_avals(self, S, C):
@@ -408,9 +429,11 @@ class Generator:
 
     def _block_avals(self, S, T, C):
         """Avals of one T-column single-row block of the slot cache:
-        every plane is 4-D with the column dim at axis 2 (bf16 k/v and
-        int8 k/v + f32 scales alike), so a block is the same tree with
-        shape (1, heads, T, head_dim-or-1)."""
+        every plane is 4-D with the column dim at axis 2 (packed bf16
+        k/v ``(B, ceil(N/g), C, g*H)``, int8 k/v ``(B, N, C, H)`` and
+        their f32 scales ``(B, N, C, 1)`` alike), so a block is the
+        same tree with shape ``(1, shape[1], T, shape[3])`` — the data
+        movers never need to know how heads lie inside a plane."""
         return jax.tree_util.tree_map(
             lambda p: jax.ShapeDtypeStruct(
                 (1, p.shape[1], T, p.shape[3]), p.dtype),
@@ -544,10 +567,13 @@ class Generator:
 
     def _program_identity(self):
         """Restart-stable architecture identity for the persistent
-        executable cache: layer class + config + state avals.  Weights
-        are runtime arguments, so two processes decoding the same
-        architecture share executables regardless of parameter values —
-        the cold host compiles the grid, every warm host loads it."""
+        executable cache: layer class + config + state avals + the ring
+        planes' packing (an executable stored by a build with another
+        plane layout takes other cache shapes and must not load).
+        Weights are runtime arguments, so two processes decoding the
+        same architecture share executables regardless of parameter
+        values — the cold host compiles the grid, every warm host loads
+        it."""
         cfg = getattr(self._layer, "config", None)
         cfg_r = repr(sorted(vars(cfg).items())) \
             if cfg is not None and hasattr(cfg, "__dict__") else repr(cfg)
@@ -559,6 +585,7 @@ class Generator:
                          for n, s in self._param_specs.items())))
         return ("generator", type(self._layer).__name__, cfg_r,
                 repr(avals), self._max_len, tuple(self._seq_buckets),
+                ("kv_heads_per_lane_row", self.kv_heads_per_lane_row()),
                 *mesh_id)
 
     def _compile(self, key, kind, fn, arg_avals, extra,

@@ -62,8 +62,12 @@ class GPTModel(nn.Layer):
 
     # -- incremental decoding (static-shape KV ring cache) -------------------
     def init_cache(self, batch, max_len, dtype=None):
-        """Per-layer zero ring caches [batch, heads, max_len, head_dim];
-        ``max_len`` is the compile-time cache length."""
+        """Per-layer zero ring caches ``[batch, ceil(heads/g), max_len,
+        g*head_dim]`` (``g`` adjacent heads per row of the minor dim,
+        ``MultiHeadAttention.gen_ring_cache``; ``g = 1``, i.e. ``[batch,
+        heads, max_len, head_dim]``, for head_dim >= 128 and for the
+        int8 cache); ``max_len`` is the compile-time cache length, at
+        axis 2 in every plane."""
         if dtype is None:
             dtype = str(self.wte.weight.dtype)
         return self.encoder.gen_ring_cache(batch, max_len, dtype)

@@ -27,10 +27,10 @@ from paddle_tpu.text.models.gpt import GPTConfig, GPTModel
 V = 64
 
 
-def _gpt(seed=21):
+def _gpt(seed=21, hidden=32, heads=2):
     paddle.seed(seed)
-    m = GPTModel(GPTConfig.tiny(vocab_size=V, hidden_size=32, layers=2,
-                                heads=2, seq=64))
+    m = GPTModel(GPTConfig.tiny(vocab_size=V, hidden_size=hidden, layers=2,
+                                heads=heads, seq=64))
     m.eval()
     return m
 
@@ -450,7 +450,11 @@ def test_sharded_decode_replica_matches_control():
         mesh = _mesh({"dp": 4, "mp": 2})
         ledger.clear()
         srv = serving.Server(serving.ServingConfig(workers=1))
-        srv.register_decode("gpt", _gpt(), batch_buckets=(1, 2),
+        # 16 heads of 16: the ring planes pack 8 heads per lane row, so
+        # axis 1 holds 2 head GROUPS, which mp=2 divides (the module's
+        # 2-head model packs into ONE group and would replicate)
+        model = _gpt(hidden=256, heads=16)
+        srv.register_decode("gpt", model, batch_buckets=(1, 2),
                             seq_buckets=(8,), max_new_tokens=4,
                             max_len=16, mesh=mesh)
         srv.start()
@@ -462,9 +466,14 @@ def test_sharded_decode_replica_matches_control():
             rng = np.random.RandomState(11)
             prompts = _prompts(rng, (5, 7))
             out = srv.run_decode("gpt", prompts, max_new_tokens=4)[0]
-            assert np.array_equal(out, _oracle_tokens(prompts))
-            # KV planes carry the pinned heads-by-mp layout
+            control = Generator(model, seq_buckets=(8, 16), max_len=32)
+            want = np.concatenate(
+                [np.asarray(control.generate(p[None], max_new_tokens=4)
+                            .numpy()) for p in prompts])
+            assert np.array_equal(out, want)
+            # KV planes carry the pinned head-groups-by-mp layout
             h = srv.prefill_handoff("gpt", prompts, 4)
+            assert tuple(h.cache[0][0].shape)[1::2] == (2, 128)
             assert "mp" in str(h.cache[0][0].sharding.spec)
             got = srv.decode_from_handoff("gpt", h.to_bytes())
             assert np.array_equal(got, out)

@@ -17,6 +17,7 @@ from paddle_tpu.framework.flags import flags_restore, flags_snapshot, \
     set_flags
 from paddle_tpu.nn.layer.transformer import (MultiHeadAttention,
                                              dequantize_kv_rows,
+                                             kv_heads_per_lane_row,
                                              quantize_kv_rows)
 from paddle_tpu.ops.pallas.flash_decode import (decode_attention_reference,
                                                 dequantize_kv,
@@ -233,11 +234,18 @@ def test_generate_with_int8_kv_two_executables_and_halved_planes():
     # the row planes shrink by exactly the itemsize ratio (the CPU seed
     # model stores f32 planes, so 4x here; bf16 planes halve on chip)
     # and the only overhead is one f32 scale per (token, head) per k/v
-    # plane per layer
+    # plane per layer.  The float planes are PACKED (g heads per lane
+    # row, heads padded to a multiple of g: 2 heads of 16 pad to 8);
+    # the int8 rows keep the unpacked layout, so compare head for head
     B, heads, layers = 2, 2, 2
-    assert rows8 == bf * (1 / np.dtype(np.float32).itemsize)
+    g = kv_heads_per_lane_row(m.config.hidden_size // heads)
+    padded_heads = -(-heads // g) * g
+    assert planes_f[0][0].shape == (B, padded_heads // g, 16,
+                                    g * m.config.hidden_size // heads)
+    assert rows8 * padded_heads == \
+        bf * heads * (1 / np.dtype(np.float32).itemsize)
     assert b8 - rows8 == layers * 2 * B * heads * 4    # scale planes
-    assert b8 < bf
+    assert b8 * padded_heads < bf * heads
 
 
 def test_int8_speculative_bit_matches_int8_plain():
