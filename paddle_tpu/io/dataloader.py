@@ -205,10 +205,16 @@ def _double_buffered(make_iter, maxsize=2):
         except Exception as e:
             err.append(e)
         finally:
-            try:
-                buf.put(stop, timeout=1.0)
-            except queue_mod.Full:
-                pass
+            # the end marker must arrive however slow the consumer is (a
+            # first step that compiles for seconds keeps the queue full):
+            # a marker given up after a second left the consumer waiting
+            # on an empty queue for ever
+            while not shutdown.is_set():
+                try:
+                    buf.put(stop, timeout=0.1)
+                    break
+                except queue_mod.Full:
+                    continue
 
     t = threading.Thread(target=producer, daemon=True)
     t.start()

@@ -43,7 +43,10 @@ from .layer.transformer import (  # noqa: F401
     TransformerDecoderLayer, TransformerDecoder, Transformer,
 )
 from .layer.moe import (  # noqa: F401
-    MoELayer, MoEEncoderLayer, ExpertFFN,
+    MoELayer, MoEEncoderLayer, ExpertFFN, DroplessMoE, SwiGLU,
+)
+from .layer.latent_attention import (  # noqa: F401
+    LatentAttention, RMSNorm,
 )
 from .layer.rnn import (  # noqa: F401
     RNNCellBase, SimpleRNNCell, LSTMCell, LSTMPCell, GRUCell, RNN, BiRNN, SimpleRNN,

@@ -201,3 +201,136 @@ def attention_bnsh(q, k, v, attn_mask=None, is_causal=False):
     if attn_mask is not None:
         return _sdpa_mask(q, k, v, attn_mask, causal=bool(is_causal))
     return _sdpa(q, k, v, causal=bool(is_causal))
+
+
+
+# ---------------------------------------------------------------------------
+# latent attention: a token's cache row is ``latent ‖ rotary key``, no
+# per-head K or V is ever stored.  Raw-array functions (decode is
+# inference-only, nothing is taped); nn/layer/latent_attention.py owns the
+# projections and the planes.
+# ---------------------------------------------------------------------------
+
+_NEG = -1e30
+
+
+def rotary(x, positions, base, dims=None):
+    """Rotate the first ``dims`` (default all; even) features of ``x``
+    ``[B, T, d]`` or ``[B, T, H, d]`` by ``positions [B, T]``; the two
+    halves of the rotated part are paired ("rotate-half").  Angles in
+    float32 whatever ``x`` is."""
+    d = x.shape[-1] if dims is None else int(dims)
+    inv = jnp.float32(base) ** (-jnp.arange(0, d, 2, dtype=jnp.float32) / d)
+    ang = positions.astype(jnp.float32)[..., None] * inv      # [B, T, d/2]
+    if x.ndim == 4:
+        ang = ang[:, :, None, :]
+    cos, sin = jnp.cos(ang), jnp.sin(ang)
+    x1 = x[..., :d // 2].astype(jnp.float32)
+    x2 = x[..., d // 2:d].astype(jnp.float32)
+    out = jnp.concatenate([x1 * cos - x2 * sin, x2 * cos + x1 * sin], -1)
+    return jnp.concatenate([out.astype(x.dtype), x[..., d:]], -1)
+
+
+def selector_scores(q_idx, w_idx, keys):
+    """The column selector's score ``I[b, t, s] = sum_j w[b, t, j] *
+    relu(q_idx[b, t, j] . keys[b, s])``, float32.  ``q_idx [B, T, J, D]``,
+    ``w_idx [B, T, J]``, ``keys [B, S, D]``."""
+    dots = jnp.einsum("btjd,bsd->btjs", q_idx, keys,
+                      preferred_element_type=jnp.float32)
+    return jnp.einsum("btjs,btj->bts", jax.nn.relu(dots),
+                      w_idx.astype(jnp.float32))
+
+
+def _descending_key(x):
+    """float32 -> uint32 whose unsigned order is the floats' order."""
+    b = jax.lax.bitcast_convert_type(x.astype(jnp.float32), jnp.uint32)
+    return jnp.where(b >> 31 == 1, ~b, b | jnp.uint32(0x80000000))
+
+
+def _kth_largest(keys, k, bits=32):
+    """Per row of ``keys [..., S]`` (uint32) the ``k``-th largest
+    (``k [..., 1]`` or an int), found four bits at a time from the top:
+    a pass counts, for each of the 15 next-digit candidates, the keys at
+    or above it, so the whole search reads the keys ``bits / 4`` times
+    where a sort moves them ``log^2 S`` times (on the chip a ``top_k`` of
+    [512, 12288] is a 5.5 ms sort)."""
+    u = jnp.uint32
+    prefix = jnp.zeros(keys.shape[:-1] + (1,), u)
+    digits = jnp.arange(1, 16, dtype=u)
+    for shift in range(bits - 4, -1, -4):
+        cands = prefix[..., None] | (digits << u(shift))       # [..., 1, 15]
+        counts = (keys[..., None] >= cands).sum(-2)             # [..., 15]
+        digit = (counts >= k).sum(-1, keepdims=True)
+        prefix = prefix | (digit.astype(u) << u(shift))
+    return prefix
+
+
+def select_columns(scores, valid, k):
+    """``Sel``: of the ``valid`` columns of each query, the ``k`` of
+    largest selector score (the lower column first among equals, as
+    ``lax.top_k`` orders them); all of them while fewer than ``k`` exist.
+    ``scores``, ``valid`` ``[..., S]``; returns the boolean membership
+    ``[..., S]``.  No sort: the ``k``-th largest score by a radix search
+    on its bits, then, among the columns that tie with it, the lowest by
+    the same search on their indices."""
+    S = scores.shape[-1]
+    if S <= k:
+        return valid
+    # (-0.0 + 0.0 is +0.0: the two zeros are one score)
+    keys = _descending_key(jnp.where(valid, scores + 0.0, -jnp.inf))
+    kth = _kth_largest(keys, k)
+    above, tied = keys > kth, keys == kth
+    room = k - above.sum(-1, keepdims=True)
+    # among the tied, the ``room`` of lowest index = of largest S - index
+    back = jnp.where(tied, jnp.uint32(S) - jnp.arange(S, dtype=jnp.uint32),
+                     jnp.uint32(0))
+    last = _kth_largest(back, room, bits=-(-S.bit_length() // 4) * 4)
+    return valid & (above | (tied & (back >= last)))
+
+
+def latent_attend(q_cat, rows, r_kv, keep, scale):
+    """Absorbed-form attention of queries already carried into the
+    latent: ``q_cat [B, T, H, r_kv + d_r]`` (``W_uk^T q_nope ‖ q_rope``)
+    against cache rows ``rows [B, S, r_kv + d_r]`` (``latent ‖ rotary
+    key``) under ``keep [B, T, S]``; returns ``sum_s p(s) latent(s)``
+    ``[B, T, H, r_kv]`` in the queries' dtype (the caller applies
+    ``W_uv``).  A query with nothing kept (a dead slot row) reads the
+    plain mean of the rows: finite, never used."""
+    s = jnp.einsum("bthk,bsk->bhts", q_cat, rows,
+                   preferred_element_type=jnp.float32) * scale
+    s = jnp.where(keep[:, None], s, _NEG)
+    p = jax.nn.softmax(s, axis=-1).astype(q_cat.dtype)
+    return jnp.einsum("bhts,bsr->bthr", p, rows[..., :r_kv],
+                      preferred_element_type=jnp.float32).astype(q_cat.dtype)
+
+
+def latent_attend_blocked(q_cat, plane, r_kv, keep_of, scale, lo, hi, block):
+    """:func:`latent_attend` over column blocks ``lo <= i < hi`` (traced)
+    of ``plane [B, S, K]``, ``block`` columns each, with a running
+    softmax, so that a wide query block never holds ``[H, T, S]`` scores
+    and columns no query can see cost nothing.  ``keep_of(s0)`` gives the
+    mask ``[B, T, block]`` of the block that starts at column ``s0``."""
+    B, T, H, _ = q_cat.shape
+    K = plane.shape[-1]
+
+    def body(i, carry):
+        m, l, acc = carry
+        s0 = i * block
+        rows = jax.lax.dynamic_slice(plane, (0, s0, 0), (B, block, K))
+        s = jnp.einsum("bthk,bsk->bhts", q_cat, rows,
+                       preferred_element_type=jnp.float32) * scale
+        s = jnp.where(keep_of(s0)[:, None], s, _NEG)
+        m_new = jnp.maximum(m, s.max(-1))
+        p = jnp.exp(s - m_new[..., None])
+        corr = jnp.exp(m - m_new)
+        l = l * corr + p.sum(-1)
+        pv = jnp.einsum("bhts,bsr->bhtr", p.astype(q_cat.dtype),
+                        rows[..., :r_kv], preferred_element_type=jnp.float32)
+        return m_new, l, acc * corr[..., None] + pv
+
+    init = (jnp.full((B, H, T), _NEG, jnp.float32),
+            jnp.zeros((B, H, T), jnp.float32),
+            jnp.zeros((B, H, T, r_kv), jnp.float32))
+    m, l, acc = jax.lax.fori_loop(lo, hi, body, init)
+    out = acc / jnp.maximum(l, 1e-30)[..., None]
+    return jnp.transpose(out, (0, 2, 1, 3)).astype(q_cat.dtype)

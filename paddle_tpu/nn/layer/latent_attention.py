@@ -1,0 +1,367 @@
+"""Latent (low-rank) attention with a latent KV plane, serving form.
+
+A token's cache row is ``latent ‖ rotary key`` (``r_kv + d_r`` numbers for
+all heads together); per-head keys and values are never stored.  Decoding
+uses the ABSORBED form: the query is carried into the latent
+(``q_hat_h = W_uk,h^T q_nope,h``), scored against the cache rows directly,
+and ``W_uv,h`` is applied to the attention-weighted latent.  Two kinds of
+layer share the code:
+
+  * **full** layers keep a plane as long as the session and, beside it, a
+    plane of selector keys; a learned selector (``index_n_heads`` small
+    heads over the query's low-rank latent) scores every cached column and
+    the attention reads the ``index_topk`` best of the causal ones;
+  * **window** layers keep ONE ring plane of ``window + cache_block - 1``
+    columns, shorter than the session, written at ``column mod length``
+    and masked by absolute column.
+
+The planes are what :meth:`LatentAttention.gen_ring_cache` builds; the
+namedtuple classes carry what the Generator and the slot loop need to know
+about them (``kind``, whether a plane wraps inside a session).  A headwise
+sigmoid gate, from the layer's normed input, multiplies each head's output
+before the output projection.
+
+Everything here runs on raw arrays under ``no_grad`` (decode is
+inference-only); ``forward`` is the cache-less PER-HEAD form over a whole
+sequence, the same numbers by another route.
+"""
+from __future__ import annotations
+
+import collections
+import math
+
+import jax
+import jax.numpy as jnp
+from jax import lax
+
+from ...framework.tensor import Tensor, unwrap
+from .. import initializer as I
+from ..functional.attention import (latent_attend, latent_attend_blocked,
+                                    rotary, select_columns, selector_scores)
+from .layers import Layer
+from .transformer import ring_block_write
+
+__all__ = ["RMSNorm", "LatentAttention", "LatentCache", "LatentWindowCache"]
+
+# full layers: ``latent [B, 1, C, r_kv + d_r]`` and the selector's keys
+# ``index_key [B, 1, C, d_i]``; columns at axis 2 like every ring plane
+LatentCache = collections.namedtuple("LatentCache", ["latent", "index_key"])
+LatentCache.kind = "latent+selector_key"
+LatentCache.wraps = False
+# window layers: one ring plane shorter than the session
+LatentWindowCache = collections.namedtuple("LatentWindowCache", ["latent"])
+LatentWindowCache.kind = "latent_window"
+LatentWindowCache.wraps = True
+
+
+def _rms(x, eps):
+    x = x.astype(jnp.float32)
+    return x * lax.rsqrt(jnp.mean(jnp.square(x), -1, keepdims=True) + eps)
+
+
+class RMSNorm(Layer):
+    """``x / rms(x) * weight``, statistics in float32."""
+
+    def __init__(self, size, epsilon=1e-5, weight_attr=None, dtype=None):
+        super().__init__()
+        self._epsilon = float(epsilon)
+        self.weight = self.create_parameter(
+            [size], attr=weight_attr, dtype=dtype,
+            default_initializer=I.Constant(1.0))
+
+    def forward(self, x):
+        raw = unwrap(x)
+        out = (_rms(raw, self._epsilon)
+               * unwrap(self.weight).astype(jnp.float32)).astype(raw.dtype)
+        return Tensor(out) if isinstance(x, Tensor) else out
+
+
+def _layer_norm(x, g, b, eps):
+    x = x.astype(jnp.float32)
+    mu = jnp.mean(x, -1, keepdims=True)
+    var = jnp.mean(jnp.square(x - mu), -1, keepdims=True)
+    return (x - mu) * lax.rsqrt(var + eps) * g.astype(jnp.float32) \
+        + b.astype(jnp.float32)
+
+
+class LatentAttention(Layer):
+    """One latent-attention layer.  ``window=None`` with ``index_topk``
+    set is a full layer with the selector; ``window=w`` is a window layer
+    (no selector).  ``cache_block`` is the widest token block one cached
+    call may append; it sizes the window plane."""
+
+    def __init__(self, hidden, num_heads, nope_dim, rope_dim, v_dim,
+                 q_rank, kv_rank, rope_base, *, window=None,
+                 index_heads=0, index_dim=0, index_topk=0, cache_block=512,
+                 attn_block=512, epsilon=1e-5, rescale=True,
+                 weight_attr=None, dtype=None):
+        super().__init__()
+        self.hidden, self.H = int(hidden), int(num_heads)
+        self.dn, self.dr, self.dv = int(nope_dim), int(rope_dim), int(v_dim)
+        self.rq, self.rkv = int(q_rank), int(kv_rank)
+        self.base = float(rope_base)
+        self.window = None if window is None else int(window)
+        self.J, self.D = int(index_heads), int(index_dim)
+        self.topk = int(index_topk)
+        self.selects = self.window is None and self.topk > 0
+        self.cache_block, self.attn_block = int(cache_block), int(attn_block)
+        self.eps = float(epsilon)
+        # the config's ``apply_mla_qkv_lora_rescale``: constants on the
+        # normed latents, sqrt(hidden / rank)
+        self.s_q = math.sqrt(hidden / q_rank) if rescale else 1.0
+        self.s_kv = math.sqrt(hidden / kv_rank) if rescale else 1.0
+        self.scale = 1.0 / math.sqrt(self.dn + self.dr)
+
+        def mat(*shape):
+            return self.create_parameter(
+                list(shape), attr=weight_attr, dtype=dtype,
+                default_initializer=I.Normal(0.0, 0.02))
+
+        H = self.H
+        self.q_a = mat(hidden, self.rq)
+        self.q_a_norm = RMSNorm(self.rq, epsilon, dtype=dtype)
+        self.q_b = mat(self.rq, H * (self.dn + self.dr))
+        self.kv_a = mat(hidden, self.rkv + self.dr)
+        self.kv_a_norm = RMSNorm(self.rkv, epsilon, dtype=dtype)
+        self.w_uk = mat(H, self.rkv, self.dn)
+        self.w_uv = mat(H, self.rkv, self.dv)
+        self.gate = mat(hidden, H)
+        self.o_proj = mat(H * self.dv, hidden)
+        if self.selects:
+            self.idx_q = mat(self.rq, self.J * self.D)
+            self.idx_k = mat(hidden, self.D)
+            self.idx_k_norm_g = self.create_parameter(
+                [self.D], dtype=dtype, default_initializer=I.Constant(1.0))
+            self.idx_k_norm_b = self.create_parameter(
+                [self.D], dtype=dtype, is_bias=True)
+            self.idx_w = mat(hidden, self.J)
+
+    # -- the planes ----------------------------------------------------------
+    def ring_len(self, max_len):
+        """Columns of this layer's latent plane in a session of
+        ``max_len``: the session's own for a full layer, ``window +
+        cache_block - 1`` (never more than the session) for a window."""
+        if self.window is None:
+            return int(max_len)
+        return min(int(max_len), self.window + self.cache_block - 1)
+
+    def ring_cache_spec(self, max_len):
+        """What the Generator and the slot loop may know of this layer's
+        planes (text/generation.py ``cache_spec``)."""
+        cls = LatentWindowCache if self.window is not None else LatentCache
+        return {"kind": cls.kind, "heads_per_lane_row": 1,
+                "columns": self.ring_len(max_len), "wraps": cls.wraps,
+                "window": self.window,
+                "select_top": self.topk if self.selects else None}
+
+    @property
+    def row_width(self):
+        """Numbers a cache row takes in the plane: ``r_kv + d_r`` padded
+        to a multiple of 128.  A plane whose minor dimension is not a
+        multiple of the lane count gets its COLUMNS put on the lanes by
+        the TPU compiler, and the step's one-column write then carries
+        its traced index there (``ring_block_write``;
+        tools/kv_layout_check.py found it for 576 and 1088)."""
+        return -(-(self.rkv + self.dr) // 128) * 128
+
+    def gen_ring_cache(self, batch, max_len, dtype="float32"):
+        from ...ops import zeros
+        n = self.ring_len(max_len)
+        lat = zeros([batch, 1, n, self.row_width], dtype=dtype)
+        if self.window is not None:
+            return LatentWindowCache(lat)
+        return LatentCache(lat, zeros([batch, 1, n, max(self.D, 1)],
+                                      dtype=dtype))
+
+    # -- projections shared by both forms --------------------------------------
+    def _project(self, x, pos_ids):
+        """From the normed input ``x [B, T, hidden]``: per-head queries
+        ``q_n [B, T, H, dn]``, ``q_r [B, T, H, dr]`` (rotated), the cache
+        row ``latent ‖ rotary key [B, T, rkv + dr]``, the gate ``[B, T,
+        H]`` and the selector's query latent ``c_q``."""
+        B, T, _ = x.shape
+        dt = x.dtype
+        w = lambda p: unwrap(p)                                # noqa: E731
+        c_q = (self.s_q * unwrap(self.q_a_norm(
+            jnp.einsum("bth,hr->btr", x, w(self.q_a),
+                       preferred_element_type=jnp.float32)))).astype(dt)
+        q = jnp.einsum("btr,rk->btk", c_q, w(self.q_b),
+                       preferred_element_type=jnp.float32).astype(dt)
+        q = q.reshape(B, T, self.H, self.dn + self.dr)
+        q_n, q_r = q[..., :self.dn], rotary(q[..., self.dn:], pos_ids,
+                                            self.base)
+        kv = jnp.einsum("bth,hk->btk", x, w(self.kv_a),
+                        preferred_element_type=jnp.float32)
+        c_kv = (self.s_kv * unwrap(self.kv_a_norm(kv[..., :self.rkv]))
+                ).astype(dt)
+        k_r = rotary(kv[..., self.rkv:].astype(dt), pos_ids, self.base)
+        row = jnp.concatenate([c_kv, k_r], -1)
+        gate = jax.nn.sigmoid(jnp.einsum(
+            "bth,hn->btn", x, w(self.gate),
+            preferred_element_type=jnp.float32))
+        return q_n, q_r, row, gate, c_q
+
+    def _selector(self, x, c_q, pos_ids):
+        """The selector's per-token pieces: queries ``[B, T, J, D]``,
+        head weights ``[B, T, J]`` and the key ``[B, T, D]`` that is
+        cached beside the latent."""
+        B, T, _ = x.shape
+        dt = x.dtype
+        qi = jnp.einsum("btr,rk->btk", c_q, unwrap(self.idx_q),
+                        preferred_element_type=jnp.float32).astype(dt)
+        qi = rotary(qi.reshape(B, T, self.J, self.D), pos_ids, self.base,
+                    dims=self.dr)
+        ki = _layer_norm(jnp.einsum("bth,hd->btd", x, unwrap(self.idx_k),
+                                    preferred_element_type=jnp.float32),
+                         unwrap(self.idx_k_norm_g), unwrap(self.idx_k_norm_b),
+                         self.eps).astype(dt)
+        ki = rotary(ki, pos_ids, self.base, dims=self.dr)
+        wi = jnp.einsum("bth,hj->btj", x, unwrap(self.idx_w),
+                        preferred_element_type=jnp.float32) \
+            * (self.J ** -0.5) * (self.D ** -0.5)
+        return qi, wi, ki
+
+    def _finish(self, out_lat, gate):
+        """``W_uv`` on the attention-weighted latent, the headwise gate,
+        the output projection."""
+        B, T = out_lat.shape[:2]
+        dt = out_lat.dtype
+        o = jnp.einsum("bthr,hrv->bthv", out_lat, unwrap(self.w_uv),
+                       preferred_element_type=jnp.float32)
+        o = (o * gate[..., None]).astype(dt).reshape(B, T, self.H * self.dv)
+        return jnp.einsum("btk,kh->bth", o, unwrap(self.o_proj),
+                          preferred_element_type=jnp.float32).astype(dt)
+
+    # -- cached, absorbed form -------------------------------------------------
+    def forward_cached(self, x, cache, pos, start, write_rows=None):
+        """Append the block ``x [B, T, hidden]`` (normed) at column
+        ``pos`` and attend.  ``start [B]`` is each row's first valid
+        column; ``write_rows [B]`` (step programs of a slot loop) keeps
+        dead rows from writing into a plane that wraps."""
+        B, T, _ = x.shape
+        cols = pos + jnp.arange(T, dtype=jnp.int32)
+        pos_ids = jnp.maximum(cols[None, :] - start[:, None], 0)
+        q_n, q_r, row, gate, c_q = self._project(x, pos_ids)
+        q_hat = jnp.einsum("bthd,hrd->bthr", q_n, unwrap(self.w_uk),
+                           preferred_element_type=jnp.float32).astype(x.dtype)
+        pad = self.row_width - self.rkv - self.dr
+        # the row's padding: zeros in the row and in the query, so the
+        # scores over the padded width are the scores
+        q_cat = jnp.concatenate(
+            [q_hat, q_r, jnp.zeros(q_r.shape[:-1] + (pad,), q_r.dtype)], -1)
+        row = jnp.concatenate(
+            [row, jnp.zeros(row.shape[:-1] + (pad,), row.dtype)], -1)
+        with jax.named_scope("latent_attention"):
+            if self.window is not None:
+                out, cache = self._window(q_cat, row, unwrap(cache.latent),
+                                          cols, start, write_rows)
+            else:
+                out, cache = self._full(x, c_q, q_cat, row, cache, cols,
+                                        pos_ids, start)
+        return self._finish(out, gate), cache
+
+    def _window(self, q_cat, row, lat, cols, start, write_rows):
+        B, T = q_cat.shape[:2]
+        n = lat.shape[2]
+        if n == self.window + self.cache_block - 1 and T > self.cache_block:
+            # (a shorter plane is a whole session: it never wraps)
+            raise ValueError(
+                f"a block of {T} tokens does not fit a window plane of {n} "
+                f"columns (window {self.window}, cache_block "
+                f"{self.cache_block})")
+        slot = cols[0] % n
+        new = row[:, None].astype(lat.dtype)
+        if write_rows is not None:
+            # a dead slot row must not write: the column it would garble
+            # may be one its own earlier chunk wrote, a ring length back
+            old = lax.dynamic_slice(lat, (0, 0, slot, 0),
+                                    (B, 1, T, lat.shape[3]))
+            new = jnp.where(write_rows[:, None, None, None], new, old)
+        lat = unwrap(ring_block_write(lat, new, slot))
+        # ring slot j holds, for the query at column t, the newest column
+        # c <= t with c = j (mod n)
+        j = jnp.arange(n, dtype=jnp.int32)
+        c = cols[:, None] - (cols[:, None] - j[None, :]) % n      # [T, n]
+        keep = (c[None] > cols[None, :, None] - self.window) \
+            & (c[None] >= start[:, None, None])
+        out = latent_attend(q_cat, lat[:, 0], self.rkv, keep, self.scale)
+        return out, LatentWindowCache(Tensor(lat))
+
+    def _full(self, x, c_q, q_cat, row, cache, cols, pos_ids, start):
+        B, T = q_cat.shape[:2]
+        lat, keys = unwrap(cache.latent), unwrap(cache.index_key)
+        C = lat.shape[2]
+        pos = cols[0] % C
+        lat = unwrap(ring_block_write(lat, row[:, None].astype(lat.dtype),
+                                      pos))
+        valid_of = lambda s: (                                  # noqa: E731
+            (s[None, None, :] >= start[:, None, None])
+            & (s[None, None, :] <= cols[None, :, None]))
+        # column blocks with a running softmax, over the blocks some query
+        # can see, for a chunk's block of queries and a step's one query a
+        # row alike.  (A step could gather its index_topk chosen rows
+        # instead of masking the others; on the v5e that gather moves 76
+        # GB/s, 3.2 ms a layer at 64 rows, where reading every valid
+        # column takes 1.2: PERF.md section 6, PR 27.)
+        blk = self.attn_block if C % self.attn_block == 0 else C
+        lo = jnp.min(start) // blk
+        hi = cols[-1] // blk + 1
+        keep_of = lambda s0: valid_of(                           # noqa: E731
+            s0 + jnp.arange(blk, dtype=jnp.int32))
+        if self.selects:
+            with jax.named_scope("selector"):
+                qi, wi, ki = self._selector(x, c_q, pos_ids)
+                keys = unwrap(ring_block_write(
+                    keys, ki[:, None].astype(keys.dtype), pos))
+                if C > self.topk:       # else every valid column is chosen
+                    valid_blk = keep_of
+
+                    def score(i, sc):
+                        s0 = i * blk
+                        kb = lax.dynamic_slice(
+                            keys, (0, 0, s0, 0),
+                            (B, 1, blk, keys.shape[3]))[:, 0]
+                        sb = jnp.where(valid_blk(s0),
+                                       selector_scores(qi, wi, kb), -jnp.inf)
+                        return lax.dynamic_update_slice(sc, sb, (0, 0, s0))
+                    sc = lax.fori_loop(
+                        lo, hi, score,
+                        jnp.full((B, T, C), -jnp.inf, jnp.float32))
+                    sel = select_columns(sc, jnp.isfinite(sc), self.topk)
+                    keep_of = lambda s0: lax.dynamic_slice(     # noqa: E731
+                        sel, (0, 0, s0), (B, T, blk))
+        out = latent_attend_blocked(q_cat, lat[:, 0], self.rkv, keep_of,
+                                    self.scale, lo, hi, blk)
+        return out, LatentCache(Tensor(lat), Tensor(keys))
+
+    # -- cache-less, per-head form over a whole sequence -----------------------
+    def forward(self, x):
+        """``x [B, T, hidden]`` (normed), causal, every token from
+        position 0: per-head keys and values from the latent, the same
+        selection and window rules.  The slow, plain route."""
+        raw = unwrap(x)
+        B, T, _ = raw.shape
+        t = jnp.arange(T, dtype=jnp.int32)
+        pos_ids = jnp.broadcast_to(t[None], (B, T))
+        q_n, q_r, row, gate, c_q = self._project(raw, pos_ids)
+        c_kv, k_r = row[..., :self.rkv], row[..., self.rkv:]
+        k_n = jnp.einsum("bsr,hrd->bshd", c_kv, unwrap(self.w_uk),
+                         preferred_element_type=jnp.float32)
+        v = jnp.einsum("bsr,hrv->bshv", c_kv, unwrap(self.w_uv),
+                       preferred_element_type=jnp.float32)
+        s = (jnp.einsum("bthd,bshd->bhts", q_n.astype(jnp.float32), k_n)
+             + jnp.einsum("bthd,bsd->bhts", q_r.astype(jnp.float32),
+                          k_r.astype(jnp.float32))) * self.scale
+        keep = jnp.broadcast_to((t[None, :] <= t[:, None])[None], (B, T, T))
+        if self.window is not None:
+            keep = keep & (t[None, None, :] > t[None, :, None] - self.window)
+        elif self.selects:
+            qi, wi, ki = self._selector(raw, c_q, pos_ids)
+            keep = select_columns(selector_scores(qi, wi, ki), keep,
+                                  self.topk)
+        p = jax.nn.softmax(jnp.where(keep[:, None], s, -1e30), -1)
+        o = jnp.einsum("bhts,bshv->bthv", p, v) * gate[..., None]
+        o = o.astype(raw.dtype).reshape(B, T, self.H * self.dv)
+        out = jnp.einsum("btk,kh->bth", o, unwrap(self.o_proj),
+                         preferred_element_type=jnp.float32).astype(raw.dtype)
+        return Tensor(out) if isinstance(x, Tensor) else out

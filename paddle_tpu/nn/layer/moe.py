@@ -57,7 +57,8 @@ from .norm import LayerNorm
 from .transformer import MultiHeadAttention
 
 __all__ = [
-    "MoELayer", "MoEEncoderLayer", "ExpertFFN", "top_k_gating",
+    "MoELayer", "MoEEncoderLayer", "ExpertFFN", "DroplessMoE", "SwiGLU",
+    "top_k_gating",
     "load_balance_loss", "moe_layers", "total_aux_loss",
     "publish_moe_metrics", "moe_axis", "moe_top_k", "moe_capacity_factor",
 ]
@@ -579,6 +580,9 @@ class MoEEncoderLayer(Layer):
     def gen_ring_cache(self, batch, max_len, dtype="float32"):
         return self.self_attn.gen_ring_cache(batch, max_len, dtype)
 
+    def ring_cache_spec(self, max_len):
+        return self.self_attn.ring_cache_spec(max_len)
+
 
 # ---------------------------------------------------------------------------
 # model-level plumbing
@@ -621,3 +625,184 @@ def publish_moe_metrics(layer, model: str = "moe"):
     for v in loads:
         h.observe(float(v))
     return dropped, loads
+
+
+# ---------------------------------------------------------------------------
+# dropless, sigmoid-routed experts of which this chip holds a share
+# ---------------------------------------------------------------------------
+
+class SwiGLU(Layer):
+    """``W_down(silu(W_gate u) * W_up u)``, no biases."""
+
+    def __init__(self, hidden, width, weight_attr=None, dtype=None):
+        super().__init__()
+        from .. import initializer as I
+
+        def mat(*shape):
+            return self.create_parameter(
+                list(shape), attr=weight_attr, dtype=dtype,
+                default_initializer=I.Normal(0.0, 0.02))
+        self.w_gate = mat(hidden, width)
+        self.w_up = mat(hidden, width)
+        self.w_down = mat(width, hidden)
+
+    def forward(self, u):
+        raw = unwrap(u)
+        f32 = jnp.float32
+        g = jnp.einsum("...h,hf->...f", raw, unwrap(self.w_gate),
+                       preferred_element_type=f32)
+        v = jnp.einsum("...h,hf->...f", raw, unwrap(self.w_up),
+                       preferred_element_type=f32)
+        y = jnp.einsum("...f,fh->...h", (jax.nn.silu(g) * v).astype(raw.dtype),
+                       unwrap(self.w_down), preferred_element_type=f32)
+        y = y.astype(raw.dtype)
+        return Tensor(y) if isinstance(u, Tensor) else y
+
+
+class DroplessMoE(Layer):
+    """Sigmoid-routed experts without capacity and without drops, told
+    WHICH experts it holds.
+
+    The router scores all ``num_experts`` (its published width) with a
+    sigmoid, chooses the ``top_k`` of ``score + bias`` (the bias corrects
+    the load and is used to choose only), and weighs the chosen by their
+    scores, renormalised over the chosen (``norm_topk``) and times
+    ``scaling``.  This layer holds experts ``held = (lo, hi)`` and
+    computes THEIR part of ``sum_chosen w_i E_i(u)``: the assignments are
+    sorted by expert and the three products run as grouped (ragged)
+    products over the rows of each held expert, however many rows that
+    is.  An assignment to an expert held elsewhere adds nothing here: on
+    a mesh the other shares' parts arrive by the exchange that this layer
+    does not do (the one-chip share of an expert-parallel deployment).
+    ``shared`` experts (dense SwiGLU of ``shared x width``) see every
+    token and are counted once.
+
+    After a forward, ``last_counts`` holds (assignments made, assignments
+    that fell on held experts, the largest per-expert row count), over
+    the tokens marked live, as int32 scalars of the same trace.
+    """
+
+    def __init__(self, hidden, width, num_experts, top_k, *, held=None,
+                 shared=0, scaling=1.0, norm_topk=True, weight_attr=None,
+                 dtype=None):
+        super().__init__()
+        from .. import initializer as I
+        lo, hi = (0, num_experts) if held is None else map(int, held)
+        if not 0 <= lo < hi <= num_experts:
+            raise InvalidArgumentError(
+                f"held experts [{lo}, {hi}) are not inside the router's "
+                f"{num_experts}")
+        if not 1 <= top_k <= num_experts:
+            raise InvalidArgumentError(
+                f"top_k {top_k} of {num_experts} experts")
+        self.num_experts, self.top_k = int(num_experts), int(top_k)
+        self.lo, self.hi = lo, hi
+        self.scaling, self.norm_topk = float(scaling), bool(norm_topk)
+
+        def mat(*shape):
+            return self.create_parameter(
+                list(shape), attr=weight_attr, dtype=dtype,
+                default_initializer=I.Normal(0.0, 0.02))
+        n = hi - lo
+        self.router = mat(hidden, num_experts)
+        self.router_bias = self.create_parameter(
+            [num_experts], dtype="float32", is_bias=True)
+        self.w_gate = mat(n, hidden, width)
+        self.w_up = mat(n, hidden, width)
+        self.w_down = mat(n, width, hidden)
+        self.shared = SwiGLU(hidden, shared * width, weight_attr, dtype) \
+            if shared else None
+        self.last_counts = None
+
+    def route(self, u2d):
+        """(expert ids ``[N, k]``, weights ``[N, k]`` float32) of the
+        tokens ``u2d [N, hidden]``."""
+        s = jax.nn.sigmoid(jnp.einsum(
+            "nh,he->ne", u2d, unwrap(self.router),
+            preferred_element_type=jnp.float32))
+        _, ids = jax.lax.top_k(s + unwrap(self.router_bias), self.top_k)
+        w = jnp.take_along_axis(s, ids, axis=1)
+        if self.norm_topk:
+            w = w / jnp.maximum(w.sum(-1, keepdims=True), 1e-20)
+        return ids.astype(jnp.int32), w * self.scaling
+
+    def forward(self, u, live=None):
+        """``u [..., hidden]``; ``live [...]`` marks the tokens that are
+        routed and that count in ``last_counts`` (all of them when None);
+        the others get the shared expert's part only."""
+        raw = unwrap(u)
+        shape, dt = raw.shape, raw.dtype
+        x = raw.reshape(-1, shape[-1])
+        N, k, n = x.shape[0], self.top_k, self.hi - self.lo
+        with jax.named_scope("experts"):
+            ids, w = self.route(x)
+            alive = jnp.ones((N,), bool) if live is None \
+                else unwrap(live).reshape(-1)
+            # a dead token (left padding, a dead slot row) is computed by
+            # no expert: padding is one token many times over, and would
+            # land on one expert all at once
+            held = (ids >= self.lo) & (ids < self.hi) & alive[:, None]
+            # sort the N*k assignments by held expert, absent ones last
+            key = jnp.where(held, ids - self.lo, n).reshape(-1)
+            order = jnp.argsort(key, stable=True)
+            sizes = jnp.bincount(key, length=n + 1)[:n].astype(jnp.int32)
+            tok = order // k                    # sorted row -> its token
+            place = jnp.argsort(order)          # assignment -> sorted row
+            f32, A = jnp.float32, N * k
+
+            def ragged():
+                """Grouped products over the sorted rows, as they lie:
+                any number of rows an expert."""
+                xs = x[tok]
+                g = jax.lax.ragged_dot(xs, unwrap(self.w_gate), sizes,
+                                       preferred_element_type=f32)
+                v = jax.lax.ragged_dot(xs, unwrap(self.w_up), sizes,
+                                       preferred_element_type=f32)
+                y = jax.lax.ragged_dot(
+                    (jax.nn.silu(g) * v).astype(dt), unwrap(self.w_down),
+                    sizes, preferred_element_type=f32)
+                return y[place]                            # [A, h], unsorted
+
+            def padded(cap):
+                """The same products with each expert's rows padded to
+                ``cap``: one batched product over the held experts, which
+                streams their weights once (the grouped product's custom
+                call reaches 45% of the chip's bandwidth, and the weights
+                are the cost: PERF.md section 6, PR 27)."""
+                first = jnp.cumsum(sizes) - sizes
+                at = jnp.minimum(first[:, None] + jnp.arange(cap)[None],
+                                 A - 1)                        # [n, cap]
+                xs = x[tok[at]]                                # [n, cap, h]
+                g = jnp.einsum("ech,ehf->ecf", xs, unwrap(self.w_gate),
+                               preferred_element_type=f32)
+                v = jnp.einsum("ech,ehf->ecf", xs, unwrap(self.w_up),
+                               preferred_element_type=f32)
+                y = jnp.einsum("ecf,efh->ech",
+                               (jax.nn.silu(g) * v).astype(dt),
+                               unwrap(self.w_down), preferred_element_type=f32)
+                # assignment a lies at sorted row p, in its expert's padded
+                # row p - first[expert]
+                e = jnp.minimum(key, n - 1)
+                src = e * cap + jnp.clip(place - first[e], 0, cap - 1)
+                return y.reshape(n * cap, -1)[src]             # [A, h]
+
+            # even routing gives an expert A / E rows; pad to four times
+            # that (64 for a 512-token chunk's 4,096 assignments over 256
+            # experts, the floor of 8 for a 64-row step's 512).
+            # An expert with more, however skewed the router, sends the
+            # block through the grouped products: nothing is ever dropped
+            cap = max(8, -(-4 * A // self.num_experts // 8) * 8)
+            if n * cap < A:
+                y = jax.lax.cond(jnp.max(sizes) <= cap,
+                                 lambda: padded(cap), ragged)
+            else:
+                y = ragged()
+            y = jnp.where(held.reshape(-1, 1), y * w.reshape(-1, 1), 0.0)
+            out = y.reshape(N, k, shape[-1]).sum(1).astype(dt)
+            self.last_counts = (
+                alive.sum().astype(jnp.int32) * k,
+                held.sum().astype(jnp.int32), jnp.max(sizes))
+            if self.shared is not None:
+                out = out + self.shared(x)
+        out = out.reshape(shape)
+        return Tensor(out) if isinstance(u, Tensor) else out

@@ -179,6 +179,10 @@ class MultiHeadAttention(Layer):
     # heads at axis 1
     QuantRingCache = collections.namedtuple(
         "QuantRingCache", ["k", "v", "k_scale", "v_scale"])
+    # what kind of planes a cache class holds, for whoever must cut or
+    # move them (prefix cache, sessions, handoff)
+    RingCache.kind = "kv"
+    QuantRingCache.kind = "kv_int8"
 
     def __init__(self, embed_dim, num_heads, dropout=0.0, kdim=None,
                  vdim=None, need_weights=False, weight_attr=None,
@@ -249,6 +253,18 @@ class MultiHeadAttention(Layer):
         plane = [batch, -(-self.num_heads // g), max_len, g * self.head_dim]
         return self.RingCache(zeros(plane, dtype=dtype),
                               zeros(plane, dtype=dtype))
+
+    def ring_cache_spec(self, max_len):
+        """What :meth:`gen_ring_cache` builds, described (the Generator's
+        ``cache_spec``): uniform K/V planes as long as the session."""
+        from ...framework import flags as _flags
+        int8 = str(_flags.flag("kv_cache_dtype")).lower() == "int8"
+        cls = self.QuantRingCache if int8 else self.RingCache
+        return {"kind": cls.kind,
+                "heads_per_lane_row":
+                    1 if int8 else kv_heads_per_lane_row(self.head_dim),
+                "columns": int(max_len), "wraps": False, "window": None,
+                "select_top": None}
 
     def _forward_ring(self, query, attn_mask, cache, cache_position,
                       decode_window):
@@ -399,6 +415,9 @@ class TransformerEncoderLayer(Layer):
     def gen_ring_cache(self, batch, max_len, dtype="float32"):
         return self.self_attn.gen_ring_cache(batch, max_len, dtype)
 
+    def ring_cache_spec(self, max_len):
+        return self.self_attn.ring_cache_spec(max_len)
+
 
 class TransformerEncoder(Layer):
     def __init__(self, encoder_layer, num_layers=None, norm=None):
@@ -446,6 +465,10 @@ class TransformerEncoder(Layer):
         """Per-layer static-shape KV ring caches for incremental decode."""
         return [layer.gen_ring_cache(batch, max_len, dtype)
                 for layer in self.layers]
+
+    def ring_cache_spec(self, max_len):
+        """Per layer, what its ring cache is (each layer's own word)."""
+        return [layer.ring_cache_spec(max_len) for layer in self.layers]
 
 
 class TransformerDecoderLayer(Layer):

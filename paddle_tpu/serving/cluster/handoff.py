@@ -311,3 +311,10 @@ KV_HANDOFF_SPEC = register_protocol(ProtocolSpec(
          "a request is replied to at most once"),
     ),
 ))
+
+
+def require_kv_planes(kinds) -> None:
+    """Raise ``InvalidArgumentError`` naming the plane kinds of a model
+    that this module cannot cut (anything but uniform K/V planes)."""
+    from ...text.generation import require_kv_planes as _require
+    _require(kinds, "the prefill -> decode KV handoff (it ships whole K/V planes between pools)")

@@ -320,7 +320,8 @@ class _DecodeRuntime:
         if bool(_flags.flag("prefix_cache")):
             import jax.tree_util as tu
             from .cluster.handoff import _np_dtype
-            from .prefix_cache import PrefixCache
+            from .prefix_cache import PrefixCache, require_kv_planes
+            require_kv_planes(self.gen.plane_kinds())
             block_nbytes = sum(
                 int(np.prod(tuple(a.shape)))
                 * _np_dtype(str(a.dtype)).itemsize
@@ -598,7 +599,8 @@ class _DecodeRuntime:
                 f"decode model {self.name!r}: this replica is in the "
                 "decode pool (FLAGS_serving_role=decode) — prefill "
                 "belongs to the prefill pool")
-        from .cluster.handoff import KVHandoff
+        from .cluster.handoff import KVHandoff, require_kv_planes
+        require_kv_planes(self.gen.plane_kinds())
         arrs, mn = self.validate(list(prompts), max_new_tokens)
         rows = len(arrs)
         B = self.ladder.bucket_for(rows)
@@ -639,6 +641,8 @@ class _DecodeRuntime:
                 f"decode model {self.name!r}: this replica is in the "
                 "prefill pool (FLAGS_serving_role=prefill) — decode "
                 "belongs to the decode pool")
+        from .cluster.handoff import require_kv_planes
+        require_kv_planes(self.gen.plane_kinds())
         cache = handoff.cache
         if not cache:
             raise InvalidArgumentError("empty KV handoff (no planes)")

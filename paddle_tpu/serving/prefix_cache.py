@@ -209,3 +209,10 @@ class PrefixCache:
                     "hits": self._hits, "misses": self._misses,
                     "hit_tokens": self._hit_tokens,
                     "evictions": self._evictions}
+
+
+def require_kv_planes(kinds) -> None:
+    """Raise ``InvalidArgumentError`` naming the plane kinds of a model
+    that this module cannot cut (anything but uniform K/V planes)."""
+    from ..text.generation import require_kv_planes as _require
+    _require(kinds, "the prefix KV cache (it cuts chunk-wide column blocks out of every plane of a row)")

@@ -346,3 +346,10 @@ SESSION_SPEC = register_protocol(ProtocolSpec(
          "an import never overwrites a fresher parked copy"),
     ),
 ))
+
+
+def require_kv_planes(kinds) -> None:
+    """Raise ``InvalidArgumentError`` naming the plane kinds of a model
+    that this module cannot cut (anything but uniform K/V planes)."""
+    from ..text.generation import require_kv_planes as _require
+    _require(kinds, "the session store (it parks a row's validity window of every plane)")

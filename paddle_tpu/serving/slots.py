@@ -211,6 +211,24 @@ class SlotLoop:
         self._model = model
         self._spec = getattr(gen, "_draft", None) is not None
         self._gamma = int(gen._gamma) if self._spec else 0
+        # what the model's layers say of their planes (Generator.
+        # cache_spec): layers that select columns, planes that wrap
+        # inside the session; and the counts the model's own forward
+        # hands back with the step's tokens and the chunk's logits
+        spec = gen.cache_spec(self.C)
+        self._plane_kinds = sorted({str(s["kind"]) for s in spec})
+        self._select_tops = [int(s["select_top"]) for s in spec
+                             if s.get("select_top")]
+        self._wrap_lens = [int(s["columns"]) for s in spec
+                           if s.get("wraps") and int(s["columns"]) < self.C]
+        names = getattr(gen, "decode_count_names", None)
+        self._count_names = tuple(names()) if names is not None else ()
+        if prefix_cache is not None:
+            from .prefix_cache import require_kv_planes
+            require_kv_planes(self._plane_kinds)
+        if session_store is not None:
+            from .sessions import require_kv_planes
+            require_kv_planes(self._plane_kinds)
         # compiled once here (ledgered compile or warm cache hit); every
         # later dispatch is a plain __call__ — zero steady-state compiles
         self._step = gen.step_exec(self.S, self.C, eos_token_id)
@@ -243,7 +261,26 @@ class SlotLoop:
                          "chunks": 0, "session_resets": 0,
                          "emitted_tokens": 0, "parked": 0, "restored": 0,
                          "prefix_hit_tokens": 0, "restore_pushes": 0,
-                         **{f"slot_steps_{k}": 0 for k in _SLOT_STATES}}
+                         **{f"slot_steps_{k}": 0 for k in _SLOT_STATES},
+                         **dict.fromkeys(self._count_names, 0)}
+        if self._select_tops:
+            self.counters.update(attn_columns_valid=0,
+                                 attn_columns_selected=0,
+                                 chunk_attn_columns_valid=0,
+                                 chunk_attn_columns_selected=0)
+        if self._select_tops or self._count_names:
+            # the chunks' own part of the totals, so that a reader can
+            # take the average step and the average chunk apart
+            self.counters["chunk_tokens"] = 0
+            self.counters.update({
+                "chunk_" + k: 0 for k in self._count_names
+                if not k.endswith("_max")})
+        if self._wrap_lens:
+            self.counters["window_wraps"] = 0
+        # driver-thread-owned: what the dispatches since the last commit
+        # add to those counters; committed with ``steps`` in one piece
+        self._tally = {}
+        self._chunk_counts = []         # device handles of chunks' counts
         # the driver's phase clock (driver-thread-owned): the phase it is
         # in, since when, its open span, and the seconds not yet
         # committed to _phase_s
@@ -708,9 +745,14 @@ class SlotLoop:
                 start = np.array([slot.start], np.int32)
                 base = slot.act - len(slot.chunks) * self.T \
                     + slot.next_chunk * self.T
-                self._cache, logits = self._chunk(
+                out = self._chunk(
                     *self._gen._state_args(), self._cache, ids, start,
                     np.int32(i), np.int32(base))
+                self._cache, logits = out[0], out[1]
+                if self._count_names:
+                    self._chunk_counts.append(out[2])
+                self._tally_columns(base + np.arange(self.T), slot.start,
+                                    chunk=True)
                 slot.next_chunk += 1
                 self.counters["chunks"] += 1
                 if slot.next_chunk == len(slot.chunks):
@@ -721,6 +763,57 @@ class SlotLoop:
                     # reused the output buffer a zero-copy view aliases.
                     self._phase("chunk_fetch")
                     slot._act_logits = np.array(logits, np.float32)
+                    # the chunks' counts, all dispatched before these
+                    # logits: reading them waits for nothing more
+                    for h in self._chunk_counts:
+                        self._tally_counts(np.asarray(h), chunk=True)
+                    self._chunk_counts = []
+
+    def _tally_columns(self, cols, start, chunk=False):
+        """Driver thread: the columns at which a dispatch appends tokens
+        of rows whose first valid columns are ``start`` (left padding
+        lies below it and counts nothing).  A selecting layer reads
+        ``min(context, select_top)`` of a token's ``context = column -
+        start + 1`` causal columns: summed over the live rows of the
+        steps under ``attn_columns_*``, over the tokens of the chunks
+        under ``chunk_attn_columns_*``.  A write at a column that is a
+        multiple of a wrapping plane's length has gone once round it."""
+        cols = np.asarray(cols)
+        ctx = cols - np.asarray(start) + 1
+        cols, ctx = cols[ctx > 0], ctx[ctx > 0]
+        add = self._add
+        if chunk and "chunk_tokens" in self.counters:
+            add("chunk_tokens", int(ctx.size))
+        pre = "chunk_" if chunk else ""
+        for top in self._select_tops:
+            add(pre + "attn_columns_valid", int(ctx.sum()))
+            add(pre + "attn_columns_selected",
+                int(np.minimum(ctx, top).sum()))
+        for n in self._wrap_lens:
+            add("window_wraps", int(((cols > 0) & (cols % n == 0)).sum()))
+
+    def _add(self, key, n, chunk=False):
+        t = self._tally
+        t[key] = t.get(key, 0) + n
+        if chunk:
+            t["chunk_" + key] = t.get("chunk_" + key, 0) + n
+
+    def _tally_counts(self, values, chunk=False):
+        """Driver thread: the model's own counts of one dispatch, by
+        ``_count_names``: sums, and the largest of a ``*_max``."""
+        t = self._tally
+        for k, v in zip(self._count_names, values):
+            if k.endswith("_max"):
+                t[k] = max(t.get(k, 0), int(v))
+            else:
+                self._add(k, int(v), chunk)
+
+    def _commit_tally(self):
+        """Under the lock, with ``steps``."""
+        for k, v in self._tally.items():
+            self.counters[k] = max(self.counters[k], v) \
+                if k.endswith("_max") else self.counters[k] + v
+        self._tally = {}
 
     def _push_restores(self, i: int, slot: "_Slot"):
         """Dispatch every push-eligible restore block of one row.  A
@@ -804,24 +897,30 @@ class SlotLoop:
         self._occupancy = ratio if self.counters["steps"] == 0 \
             else 0.9 * self._occupancy + 0.1 * ratio
         self._m_occ.set(round(ratio, 4))
+        def commit():
+            # one commit under the lock, so a reset_stats() from another
+            # thread never splits a step: the four states sum to steps x
+            # S.  The step calls it once its tokens are emitted and BEFORE
+            # it retires a row, so a client that holds its answer finds
+            # the step that produced it in stats()
+            with self._cond:
+                self.counters["steps"] += 1
+                self.counters["emitted_tokens"] += self._step_emitted
+                self._commit_tally()
+                for k, n in zip(_SLOT_STATES, split):
+                    self.counters[f"slot_steps_{k}"] += n
+            self._step_emitted = 0
+            for m, n in zip(self._m_steps, split):
+                if n:
+                    m.inc(n)
+
         self._phase("step_dispatch")
         if self._spec:
-            self._spec_step(gen_slots)
+            self._spec_step(gen_slots, commit)
         else:
-            self._plain_step(gen_slots)
-        # one commit under the lock, so a reset_stats() from another
-        # thread never splits a step: the four states sum to steps x S
-        with self._cond:
-            self.counters["steps"] += 1
-            self.counters["emitted_tokens"] += self._step_emitted
-            for k, n in zip(_SLOT_STATES, split):
-                self.counters[f"slot_steps_{k}"] += n
-        self._step_emitted = 0
-        for m, n in zip(self._m_steps, split):
-            if n:
-                m.inc(n)
+            self._plain_step(gen_slots, commit)
 
-    def _plain_step(self, gen_slots):
+    def _plain_step(self, gen_slots, commit):
         self._cache, self._logits, finished, tok = self._step(
             *self._gen._state_args(), self._cache, self._logits,
             self._start, self._finished, self._active,
@@ -830,14 +929,24 @@ class SlotLoop:
         tok = np.asarray(tok)
         self._finished = np.array(finished)
         self._phase("retire")
+        # the model's counts came back behind the S tokens
+        self._tally_counts(tok[self.S:])
+        self._tally_columns(
+            np.full(len(gen_slots), self.pos),
+            np.array([self._slots[i].start for i in gen_slots], np.int64))
         self.pos += 1
         for i in gen_slots:
+            self._emit(self._slots[i], [int(tok[i])])
+        commit()
+        self._retire_done(gen_slots)
+
+    def _retire_done(self, gen_slots):
+        for i in gen_slots:
             slot = self._slots[i]
-            self._emit(slot, [int(tok[i])])
             if self._finished[i] or len(slot.emitted) >= slot.req.max_new:
                 self._retire(i)
 
-    def _spec_step(self, gen_slots):
+    def _spec_step(self, gen_slots, commit):
         # clamp the stride so the commit lands exactly on the nearest
         # activation boundary — a prefilling row's window must start
         # the moment the frontier reaches its planned position (every
@@ -860,10 +969,9 @@ class SlotLoop:
         self._accepted += int(n)
         self._proposed += self._gamma
         for i in gen_slots:
-            slot = self._slots[i]
-            self._emit(slot, [int(t) for t in e[i, :k]])
-            if self._finished[i] or len(slot.emitted) >= slot.req.max_new:
-                self._retire(i)
+            self._emit(self._slots[i], [int(t) for t in e[i, :k]])
+        commit()
+        self._retire_done(gen_slots)
 
     def _emit(self, slot, toks):
         if slot.req.t_first is None:
@@ -1076,6 +1184,7 @@ class SlotLoop:
             wins = dict(self._phase_win)
         out = {"slots": self.S, "cache": self.C, "chunk": self.T,
                "kv_heads_per_lane_row": self._kv_heads_per_lane_row,
+               "plane_kinds": list(self._plane_kinds),
                "occupancy_ewma": round(self._occupancy, 4), **c,
                # the driver's seconds by phase, and the phases of the
                # requests replied, both since the last reset_stats()

@@ -63,27 +63,47 @@ def _aval(a):
     return jax.ShapeDtypeStruct(tuple(a.shape), a.dtype)
 
 
-def _rebuild_ring(cache):
-    """Raw plane tuples -> per-layer RingCache/QuantRingCache namedtuples
-    (arity decides: 2 planes = bf16 rows, 4 = int8 rows + scale planes)."""
-    from ..nn.layer.transformer import MultiHeadAttention as _MHA
-    out = []
-    for c in cache:
-        cls = _MHA.RingCache if len(c) == 2 else _MHA.QuantRingCache
-        out.append(cls(*(Tensor(p) for p in c)))
-    return out
+def _rebuild_ring(layer, cache):
+    """Raw plane tuples -> the per-layer cache namedtuples that
+    ``layer.init_cache`` builds (RingCache, QuantRingCache, LatentCache,
+    ...): each layer's cache is of the class the MODEL says it is, asked
+    at a one-column size, so unlike layers may keep unlike planes."""
+    types = [type(c) for c in layer.init_cache(1, 1)]
+    return [cls(*(Tensor(p) for p in c)) for cls, c in zip(types, cache)]
 
 
-def _apply_layer(layer, params, buffers, ids, cache, pos, start):
+def _apply_layer(layer, params, buffers, ids, cache, pos, start, rows=None):
     """Raw-array incremental forward of ONE model: bind the state
     snapshot into the live layer and run its forward_cached under
     no-grad (the @to_static pure-fn pattern, jit/__init__.py).  Shared
-    by the Generator (target) and the speculative draft."""
-    ring = _rebuild_ring(cache)
+    by the Generator (target) and the speculative draft.  ``rows`` (a
+    slot step's live rows) reaches only a model that asks for it
+    (``cached_forward_takes_rows``: one of its planes wraps inside a
+    session, so a dead row must not write)."""
+    ring = _rebuild_ring(layer, cache)
+    kw = {}
+    if rows is not None and getattr(layer, "cached_forward_takes_rows",
+                                    False):
+        kw["write_rows"] = Tensor(rows)
     with core.no_grad_guard(), _bound_state(layer, params, buffers):
         logits, new_cache = layer.forward_cached(
-            Tensor(ids), ring, pos, Tensor(start))
+            Tensor(ids), ring, pos, Tensor(start), **kw)
     return unwrap(logits), [tuple(unwrap(p) for p in c) for c in new_cache]
+
+
+def require_kv_planes(kinds, who):
+    """Refuse plane kinds that ``who`` cannot cut: the prefix cache, the
+    session store and the KV handoff slice, park and ship UNIFORM K/V
+    ring planes (kinds ``kv``, ``kv_int8``: every plane as long as the
+    session, a column a token).  A latent plane beside a selector-key
+    plane, or a window plane shorter than the session, is not theirs
+    yet."""
+    bad = sorted(k for k in set(kinds) if not str(k).startswith("kv"))
+    if bad:
+        raise InvalidArgumentError(
+            f"{who} handles uniform K/V ring planes only; this model "
+            f"keeps planes of kind {', '.join(map(repr, bad))}, which it "
+            "cannot cut (serve the model with that feature off)")
 
 
 def _slice_row(cache, rowidx):
@@ -209,28 +229,53 @@ class Generator:
             f"exceeds FLAGS_decode_max_len={self._max_len}")
 
     # -- the two pure programs ----------------------------------------------
-    def _apply_cached(self, params, buffers, ids, cache, pos, start):
+    def _apply_cached(self, params, buffers, ids, cache, pos, start,
+                      rows=None):
         return _apply_layer(self._layer, params, buffers, ids, cache, pos,
-                            start)
+                            start, rows)
 
     def _init_cache_raw(self, B, C):
         ring = self._layer.init_cache(B, C)
         return [tuple(unwrap(p) for p in c) for c in ring]
 
+    def cache_spec(self, C):
+        """Per layer, what the model says its cache planes are at cache
+        length ``C``: ``kind`` (the cache class's), ``heads_per_lane_row``
+        (``g`` of ``gen_ring_cache``), ``columns`` of its planes,
+        whether they ``wrap`` inside a session, its ``window`` and
+        ``select_top``.  The model's ``cache_spec``; a model that has
+        none is described from the classes its ``init_cache`` builds."""
+        fn = getattr(self._layer, "cache_spec", None)
+        if fn is not None:
+            return list(fn(int(C)))
+        return [{"kind": getattr(type(c), "kind", type(c).__name__),
+                 "heads_per_lane_row": 1, "columns": int(C), "wraps": False,
+                 "window": None, "select_top": None}
+                for c in self._layer.init_cache(1, 1)]
+
+    def plane_kinds(self):
+        return sorted({str(s["kind"]) for s in self.cache_spec(1)})
+
     def kv_heads_per_lane_row(self):
         """How many heads share a row of the minor dimension of this
         model's ring planes (``g`` of ``gen_ring_cache``; 1 = unpacked
-        ``(B, N, C, H)`` planes: head_dim >= 128, or the int8 cache).
-        Read back from the planes the model builds, so the ledger's
+        planes: head_dim >= 128, the int8 cache, a latent plane), as the
+        model's first layer describes its cache, so the ledger's
         ``generate_step`` / ``generate_chunk`` events and
         ``SlotLoop.stats()`` say which layout a run used."""
-        from ..nn.layer.transformer import MultiHeadAttention
-        mha = next((l for l in self._layer.sublayers()
-                    if isinstance(l, MultiHeadAttention)), None)
-        if mha is None:
-            return 1
-        plane = jax.eval_shape(lambda: self._init_cache_raw(1, 1))[0][0]
-        return int(plane.shape[3]) // int(mha.head_dim)
+        spec = self.cache_spec(1)
+        return int(spec[0]["heads_per_lane_row"]) if spec else 1
+
+    def decode_count_names(self):
+        """Names of the int32 counts the model's cached forward leaves
+        behind (``decode_counts``), which the slot programs hand back
+        with the step's tokens and beside the chunk's logits; ``()`` for
+        a model that counts nothing."""
+        return tuple(getattr(self._layer, "decode_count_names", ()))
+
+    def _decode_counts(self):
+        fn = getattr(self._layer, "decode_counts", None)
+        return None if fn is None else fn()
 
     def _build_prefill(self, B, P, C):
         def prefill(params, buffers, ids, start):
@@ -332,9 +377,13 @@ class Generator:
             # — clamp their fed token; their write lands in a dead column
             fed = jnp.where(active, tok, jnp.int32(0))
             nlogits, ncache = apply(params, buffers, fed[:, None], cache,
-                                    pos, start)
+                                    pos, start, active)
             nlog = jnp.where(active[:, None],
                              nlogits[:, 0].astype(jnp.float32), logits)
+            counts = self._decode_counts()
+            if counts is not None:
+                # the model's counts ride the token read-back: [S + n]
+                tok = jnp.concatenate([tok, counts.astype(jnp.int32)])
             return ncache, nlog, finished, tok
 
         return step
@@ -355,8 +404,10 @@ class Generator:
         def chunk(params, buffers, cache, ids, start, rowidx, pos):
             sub = _slice_row(cache, rowidx)
             logits, nsub = apply(params, buffers, ids, sub, pos, start)
-            return _splice_row(cache, nsub, rowidx), \
-                logits[0, -1, :].astype(jnp.float32)
+            out = (_splice_row(cache, nsub, rowidx),
+                   logits[0, -1, :].astype(jnp.float32))
+            counts = self._decode_counts()
+            return out if counts is None else out + (counts,)
 
         return chunk
 
@@ -586,6 +637,10 @@ class Generator:
         return ("generator", type(self._layer).__name__, cfg_r,
                 repr(avals), self._max_len, tuple(self._seq_buckets),
                 ("kv_heads_per_lane_row", self.kv_heads_per_lane_row()),
+                # a model that takes the live rows compiles another step
+                *((("step_passes_rows", True),) if getattr(
+                    self._layer, "cached_forward_takes_rows", False)
+                  else ()),
                 *mesh_id)
 
     def _compile(self, key, kind, fn, arg_avals, extra,
@@ -733,7 +788,9 @@ class Generator:
         prefill result.  Greedy returns tokens [B, steps]; beam returns
         (ids [B, K, steps], scores [B, K])."""
         B = logits0.shape[0]
-        C = cache[0][0].shape[2]
+        # the session's length: the longest plane's (a window layer's
+        # plane may be shorter)
+        C = max(p.shape[2] for c in cache for p in c)
         ex = self.decode_exec(B, int(C), int(steps), int(beam_size),
                               eos_token_id)
         if self._mesh is not None:

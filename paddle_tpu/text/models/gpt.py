@@ -72,6 +72,12 @@ class GPTModel(nn.Layer):
             dtype = str(self.wte.weight.dtype)
         return self.encoder.gen_ring_cache(batch, max_len, dtype)
 
+    def cache_spec(self, max_len):
+        """Per layer, what its cache planes are (the attention layers'
+        own ``ring_cache_spec``): the Generator and the slot loop read
+        the layout from here, not from the planes' count."""
+        return self.encoder.ring_cache_spec(max_len)
+
     def forward_cached(self, input_ids, cache, cache_position,
                        start_positions):
         """One incremental step over the ring cache.

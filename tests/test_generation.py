@@ -32,13 +32,36 @@ def _prompts(rng, b, l):
     return rng.randint(2, V, (b, l)).astype(np.int64)
 
 
-def _naive_greedy(m, ids_row, steps):
+def _padded_forward(m, pad=16):
+    """``ids [1, pad] -> logits`` of ``m``, compiled once per model."""
+    import jax
+    from paddle_tpu.framework.functional import _bound_state, layer_state
+    from paddle_tpu.framework.tensor import Tensor, unwrap
+    fn = getattr(m, "_test_padded_forward", None)
+    if fn is None:
+        params, buffers = layer_state(m)
+
+        @jax.jit
+        def forward(ids):
+            with _bound_state(m, params, buffers):
+                return unwrap(m(Tensor(ids)))
+        fn = m._test_padded_forward = forward
+    return fn
+
+
+def _naive_greedy(m, ids_row, steps, pad=16):
     """Reference: recompute the FULL forward per token and take argmax —
-    the O(T^2) path the KV cache replaces."""
+    the O(T^2) path the KV cache replaces.  The sequence is right-padded
+    to ``pad`` positions (causal, so what follows a position cannot reach
+    it): one compiled program per model, where the eager forward compiled
+    every op anew for every length."""
+    forward = _padded_forward(m, pad)
     seq = list(ids_row)
     for _ in range(steps):
-        logits = m(paddle.to_tensor(np.asarray([seq], np.int64))).numpy()
-        seq.append(int(np.argmax(logits[0, -1])))
+        ids = np.zeros((1, pad), np.int64)
+        ids[0, :len(seq)] = seq
+        logits = np.asarray(forward(ids))
+        seq.append(int(np.argmax(logits[0, len(seq) - 1])))
     return np.asarray(seq[len(ids_row):])
 
 

@@ -206,3 +206,35 @@ def test_static_save_load_vars(tmp_path):
         assert _np.allclose(got, ref)
     finally:
         paddle.disable_static()
+
+
+@pytest.mark.parametrize("ends_with_error", [False, True])
+def test_double_buffer_end_marker_survives_a_slow_consumer(ends_with_error):
+    """A consumer that holds the queue full for longer than the producer
+    used to wait with its end marker (a first step that compiles for
+    seconds does): the marker, and a producer's error, must still arrive;
+    the consumer once waited on the emptied queue for ever."""
+    import threading
+    import time
+    from paddle_tpu.io.dataloader import _double_buffered
+
+    def items():
+        yield from range(3)          # the last two fill the queue
+        if ends_with_error:
+            raise ValueError("the source failed")
+
+    got, done = [], threading.Event()
+
+    def consume():
+        try:
+            for i, x in enumerate(_double_buffered(items, maxsize=2)):
+                if i == 0:
+                    time.sleep(1.6)     # queue full, producer finished
+                got.append(x)
+        except ValueError as e:
+            got.append(str(e))
+        done.set()
+
+    threading.Thread(target=consume, daemon=True).start()
+    assert done.wait(15), "the consumer never saw the end of the stream"
+    assert got == [0, 1, 2] + (["the source failed"] * ends_with_error)

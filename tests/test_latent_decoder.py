@@ -1,0 +1,286 @@
+"""The latent-attention MoE decoder (text/models/latent_moe.py) at tiny
+widths on the CPU, float32, seeded weights: the slot loop's chunks and steps
+against the plain reference's full forward (benchmark/reference/dots3.py,
+the one source of truth, which imports nothing of the program), the
+absorbed against the per-head form, the expert share, dropless routing,
+and the seam through which a model tells the Generator what its planes are.
+"""
+import json
+import os
+import sys
+
+import jax
+import jax.numpy as jnp
+import numpy as np
+import pytest
+
+ROOT = os.path.dirname(os.path.dirname(os.path.abspath(__file__)))
+if ROOT not in sys.path:
+    sys.path.insert(0, ROOT)
+
+from benchmark.models import dots3 as bench_models          # noqa: E402
+from benchmark.reference import dots3 as ref                 # noqa: E402
+from paddle_tpu.framework.enforce import InvalidArgumentError  # noqa: E402
+from paddle_tpu.framework.tensor import unwrap               # noqa: E402
+from paddle_tpu.nn.layer.moe import DroplessMoE              # noqa: E402
+from paddle_tpu.serving.slots import SlotLoop                # noqa: E402
+from paddle_tpu.text.generation import Generator             # noqa: E402
+
+# float32 on the CPU: the program (absorbed form, cache, chunks) and the
+# reference (per-head, one pass) differ by summation order only; a served
+# token may be the reference's second choice at a near-tie of that size
+GAP_TOL = 1e-4
+# prompts of 9-17 tokens in chunks of 4 (3-5 chunks, so a dead row's step
+# would hit a column its own chunk wrote a ring length back), answers that
+# carry every context past the selector's 6 and the window's 5 columns and
+# the session's columns round the 8-column window planes several times
+REQUESTS = [(9, 6), (13, 8), (5, 4), (17, 8), (7, 8), (11, 5), (14, 7),
+            (16, 6)]
+
+
+def _tiny():
+    with open(os.path.join(ROOT, "benchmark", "configs",
+                           "dots3-note-prev-ep8-serve.json")) as f:
+        cfg = json.load(f)
+    with open(os.path.join(ROOT, "benchmark", "tests", "data",
+                           "dots3_tiny.json")) as f:
+        over = json.load(f)["over"]
+    cfg["serve"].update(over.pop("serve"))
+    cfg.update(over, num_hidden_layers=4, reference_pad=32)
+    return cfg
+
+
+@pytest.fixture(scope="module")
+def served():
+    """ONE tiny model with the reference's seeded weights, and its view of
+    them for the reference (shared by the whole module: one build)."""
+    from benchmark import harness
+    cfg = _tiny()
+    mapped = bench_models.to_program(ref.init_weights(cfg, 5))
+    model = bench_models.build(cfg, mapped)
+    return cfg, model, harness.canonical_view(mapped,
+                                              bench_models.leaf_ids(cfg))
+
+
+def _serve(model, requests, **loop_kw):
+    gen = Generator(model, max_len=64, seq_buckets=[64])
+    loop = SlotLoop(gen, slots=3, cache_len=64, chunk=4, **loop_kw)
+    rng = np.random.default_rng(1)
+    prompts = [rng.integers(0, 96, n).astype(np.int32) for n, _ in requests]
+    futs = [loop.submit(p, k) for p, (_, k) in zip(prompts, requests)]
+    out = [np.asarray(f.result(timeout=300)) for f in futs]
+    stats = loop.stats()
+    loop.close()
+    return prompts, out, stats
+
+
+def _widest_gap(cfg, view, prompts, tokens):
+    return max(float(jnp.max(ref.served_gaps(cfg, view, p, t)))
+               for p, t in zip(prompts, tokens))
+
+
+def test_slot_loop_equals_the_reference(served):
+    """Prefill by chunks + decoding through SlotLoop, rows joining and
+    retiring (8 requests over 3 slots), equals the reference's full
+    forward; and the loop's counters say what ran."""
+    cfg, model, view = served
+    prompts, tokens, st = _serve(model, REQUESTS)
+    assert _widest_gap(cfg, view, prompts, tokens) < GAP_TOL
+    assert st["plane_kinds"] == ["latent+selector_key", "latent_window"]
+    moe_layers, k = 3, cfg["num_experts_per_tok"]
+    assert st["moe_assignments"] == \
+        (sum(n for n, _ in REQUESTS) + st["emitted_tokens"]) * k * moe_layers
+    assert 0 < st["moe_assignments_held"] < st["moe_assignments"]
+    assert 1 <= st["moe_expert_tokens_max"] <= 4 * k
+    # the selector binds (contexts pass 6 columns) and the planes wrap
+    assert st["attn_columns_selected"] < 0.7 * st["attn_columns_valid"]
+    assert st["chunk_tokens"] == sum(n for n, _ in REQUESTS)
+    assert st["window_wraps"] > len(REQUESTS)
+
+
+_PROGRAMS = {}
+
+
+def _prefill_beside_a_live_row(model, steps_between):
+    """Row 1 prefills a 14-token prompt in 4 chunks of 4 on the slot
+    loop's own schedule (admitted at column 20, so ``act = 24``; chunk
+    ``k`` goes out once the frontier has passed ``act - 4 + k``), through
+    the Generator's own chunk and step programs.  With ``steps_between``
+    row 0 decodes meanwhile: the step at column 22 falls on ring slot 6 of
+    the 8-column window planes, where row 1's chunk 1 has just put column
+    14, which chunk 2's queries read.  Returns every chunk's logits."""
+    gen = Generator(model, max_len=64, seq_buckets=[64])
+    # the chunk program is the same whether or not the step takes the live
+    # rows; only the step is built per setting (one compile each)
+    masked = type(model).cached_forward_takes_rows
+    if "chunk" not in _PROGRAMS:
+        _PROGRAMS["chunk"] = jax.jit(gen._build_chunk(2, 4, 64))
+    if steps_between and masked not in _PROGRAMS:
+        _PROGRAMS[masked] = jax.jit(gen._build_step(2, 64, -1))
+    chunk, step = _PROGRAMS["chunk"], _PROGRAMS.get(masked)
+    state, cache = gen._state_args(), gen.init_slot_cache(2, 64)
+    prompt = np.random.default_rng(0).integers(1, 96, 14).astype(np.int32)
+    padded = np.concatenate([np.zeros(2, np.int32), prompt])
+    logits = jnp.zeros((2, 96), jnp.float32)
+    start, done = np.zeros(2, np.int32), np.array([False, True])
+    live, outs = np.array([True, False]), []
+    for k in range(5):
+        if k:
+            cache, out = chunk(*state, cache, padded[None, 4 * k - 4:4 * k],
+                               np.array([24 - 14], np.int32), np.int32(1),
+                               np.int32(8 + 4 * (k - 1)))[:2]
+            outs.append(np.asarray(out))
+        if k < 4 and steps_between:
+            cache, logits, _, _ = step(*state, cache, logits, start, done,
+                                       live, np.int32(20 + k))
+    return np.stack(outs)
+
+
+@pytest.mark.parametrize("masked", [True, False])
+def test_a_dead_row_must_not_write_into_a_plane_that_wraps(served, monkeypatch,
+                                                           masked):
+    """The slot loop's dead-column rule (slots.py header) is argued for
+    planes as long as the session.  For a plane that wraps it does NOT
+    hold, so the step passes the live rows to a model that asks for them
+    and a dead row's write is masked; with the write left unmasked the
+    prefilling row's window plane is garbled by its neighbour's steps."""
+    _, model, _ = served
+    alone = _prefill_beside_a_live_row(model, steps_between=False)
+    monkeypatch.setattr(type(model), "cached_forward_takes_rows", masked)
+    beside = _prefill_beside_a_live_row(model, steps_between=True)
+    if masked:
+        np.testing.assert_array_equal(beside, alone)
+    else:
+        # (0.01-0.1 of logits of spread ~1: a garbled column of one layer)
+        assert np.abs(beside - alone).max() > 1e-3
+
+
+@pytest.mark.parametrize("layer", [0, 2], ids=["full", "window"])
+def test_absorbed_form_equals_the_per_head_form(served, layer):
+    """One attention layer: ``forward`` (no cache, per-head keys and values
+    from the latent) against ``forward_cached`` (absorbed; blocks of 3, 2
+    and 4 tokens, then single steps), batch of 2, contexts past the
+    selector's 6 and the window's 5 columns and round the 8-column plane:
+    float32 rounding of another summation order."""
+    _, model, _ = served
+    attn = model.layers[layer].attn
+    x = jax.random.normal(jax.random.key(2), (2, 20, 32))
+    full = np.asarray(jax.jit(attn.forward)(x))
+
+    @jax.jit
+    def cached(xs, planes, pos):
+        out, cache = attn.forward_cached(xs, type(planes0)(*planes), pos,
+                                         jnp.zeros(2, jnp.int32))
+        return out, tuple(unwrap(p) for p in cache)
+
+    planes0 = attn.gen_ring_cache(2, 32)
+    planes, pos, got = tuple(unwrap(p) for p in planes0), 0, []
+    for n in (3, 2, 4) + (1,) * 11:
+        out, planes = cached(x[:, pos:pos + n], planes, jnp.int32(pos))
+        got.append(np.asarray(out))
+        pos += n
+    np.testing.assert_allclose(np.concatenate(got, 1), full, atol=2e-6)
+    assert (planes[0].shape[2] == 8) == (layer == 2)
+
+
+def _moe_fn(layer):
+    """``f(params, u)`` of a DroplessMoE, compiled once per shape: the
+    parameters are arguments, so shares that differ in numbers only share
+    one program."""
+    from paddle_tpu.framework.functional import _bound_state
+
+    @jax.jit
+    def f(params, u):
+        with _bound_state(layer, params, {}):
+            return layer(u), layer.last_counts
+    return f
+
+
+@pytest.mark.parametrize("shares", [8, 2])
+def test_expert_shares_add_up_to_the_uncut_layer(shares):
+    """The share test: the routed parts of all the shares, with the shared
+    expert counted once, equal the uncut 16-expert layer.  Share ``i`` is
+    the layer that holds experts ``[0, n)`` of a router whose columns are
+    rolled by ``i n``: the same program for every share."""
+    whole = DroplessMoE(32, 16, 16, 3, shared=1)
+    whole.router_bias.set_value(jnp.linspace(-0.1, 0.1, 16))
+    w = {k: unwrap(v) for k, v in whole.named_parameters()}
+    u = jax.random.normal(jax.random.key(3), (2, 11, 32))
+    n = 16 // shares
+    part = _moe_fn(DroplessMoE(32, 16, 16, 3, held=(0, n)))
+    parts = [part({"router": jnp.roll(w["router"], -i * n, 1),
+                   "router_bias": jnp.roll(w["router_bias"], -i * n),
+                   **{k: w[k][i * n:(i + 1) * n]
+                      for k in ("w_gate", "w_up", "w_down")}}, u)[0]
+             for i in range(shares)]
+    (uncut, made), shared = _moe_fn(whole)(w, u), whole.shared(u)
+    np.testing.assert_allclose(sum(parts) + shared, uncut, atol=1e-6)
+    assert float(jnp.abs(parts[0] - (uncut - shared)).max()) > 1e-4
+    assert int(made[0]) == int(made[1]) == 2 * 11 * 3    # all held: none absent
+
+
+@pytest.mark.parametrize("tokens,skewed", [(1, True), (40, True),
+                                           (256, True), (256, False)])
+def test_no_assignment_is_dropped(tokens, skewed):
+    """8 of 64 experts held, 2 a token.  A skewed router sends every token
+    to the same 2 held experts: whatever the number of rows, each is
+    computed (no capacity, no drop).  At 256 tokens the 512 assignments
+    pass the size from which each expert's rows are padded to 4 x the even
+    share (32): evenly routed they take the one batched product, skewed
+    they take the grouped products over the sorted rows: the same
+    numbers either way, against every expert computed densely."""
+    layer = DroplessMoE(32, 16, 64, 2, held=(0, 8))
+    w = {k: unwrap(v) for k, v in layer.named_parameters()}
+    if skewed:
+        w["router"] = jnp.zeros((32, 64))
+        w["router_bias"] = jnp.zeros(64).at[jnp.array([2, 5])].set(5.0)
+    u = jax.random.normal(jax.random.key(4), (tokens, 32))
+    from paddle_tpu.framework.functional import _bound_state
+    with _bound_state(layer, w, {}):
+        ids, wt = layer.route(u)
+    want = 0.0
+    for e in range(8):
+        g, v, d = (w[n][e] for n in ("w_gate", "w_up", "w_down"))
+        we = jnp.where(ids == e, wt, 0.0).sum(-1, keepdims=True)
+        want = want + we * ((jax.nn.silu(u @ g) * (u @ v)) @ d)
+    got, counts = _moe_fn(layer)(w, u)
+    np.testing.assert_allclose(got, want, atol=2e-6)
+    made, held, most = (int(c) for c in counts)
+    assert made == 2 * tokens and held == int((ids < 8).sum())
+    if skewed:
+        assert (held, most) == (2 * tokens, tokens)
+    else:
+        assert 0 < most <= 32 and held > 32     # inside the padded rows
+
+
+@pytest.mark.parametrize("feature", ["prefix_cache", "session_store"])
+def test_kv_movers_refuse_planes_they_cannot_cut(served, feature):
+    _, model, _ = served
+    gen = Generator(model, max_len=64, seq_buckets=[64])
+    with pytest.raises(InvalidArgumentError, match="latent"):
+        SlotLoop(gen, slots=2, cache_len=64, chunk=4, **{feature: object()})
+
+
+def test_handoff_refuses_planes_it_cannot_cut():
+    from paddle_tpu.serving.cluster import handoff
+    handoff.require_kv_planes(["kv", "kv_int8"])
+    with pytest.raises(InvalidArgumentError, match="latent_window"):
+        handoff.require_kv_planes(["kv", "latent_window"])
+
+
+@pytest.mark.parametrize("flag,kind,g", [("bf16", "kv", 2), ("int8", "kv_int8", 1)])
+def test_a_gpt_says_what_its_planes_are(flag, kind, g):
+    """The cache class and the head packing come from the model's layers."""
+    from paddle_tpu.framework import flags
+    from paddle_tpu.text.models import GPTConfig, GPTModel
+    old = flags.flag("kv_cache_dtype")
+    flags.set_flags({"FLAGS_kv_cache_dtype": flag})
+    try:
+        gen = Generator(GPTModel(GPTConfig.tiny(hidden_size=128, heads=2)),
+                        max_len=32, seq_buckets=[32])
+        spec = gen.cache_spec(32)
+        assert [s["kind"] for s in spec] == [kind, kind]
+        assert gen.kv_heads_per_lane_row() == g and gen.plane_kinds() == [kind]
+        assert gen.decode_count_names() == ()
+    finally:
+        flags.set_flags({"FLAGS_kv_cache_dtype": old})

@@ -13,6 +13,7 @@ snapshot + retryable resume), the migration transport (export/import,
 keep-newer), Router session affinity, corrupt-spill fallback, and the
 FLAGS_prefix_cache / FLAGS_session_store surface."""
 import os
+import functools
 import random
 import time
 
@@ -35,6 +36,7 @@ from paddle_tpu.text.speculative import SpeculativeGenerator
 V = 64
 
 
+@functools.lru_cache(maxsize=None)
 def _gpt(seed=21):
     paddle.seed(seed)
     m = GPTModel(GPTConfig.tiny(vocab_size=V, hidden_size=32, layers=2,
@@ -43,6 +45,7 @@ def _gpt(seed=21):
     return m
 
 
+@functools.lru_cache(maxsize=None)
 def _draft(seed=101):
     paddle.seed(seed)
     d = GPTModel(GPTConfig.tiny(vocab_size=V, hidden_size=16, layers=1,
@@ -51,11 +54,32 @@ def _draft(seed=101):
     return d
 
 
+@functools.lru_cache(maxsize=None)
+def _oracle(seed=21, speculative=False):
+    """ONE stateless oracle per model for the whole module: the tests ask
+    it for many (prompt bucket, cache bucket, steps) programs, and a fresh
+    Generator per test compiled each of them again.  The models are
+    built once per seed too (nothing here mutates their weights); the
+    kv-cache dtype is part of every executable's key, so the int8 tests
+    share the oracle safely."""
+    if speculative:
+        return SpeculativeGenerator(_gpt(seed), _draft(), seq_buckets=(8, 16, 32),
+                                    max_len=64, gamma=3)
+    return Generator(_gpt(seed), seq_buckets=(8, 16, 32), max_len=64)
+
+
 def _want(oracle, p, mn):
+    """The oracle's ``mn`` greedy tokens after ``p``: the head of a
+    continuation of ``mn`` rounded up to a multiple of 8 where the model's
+    64 positions allow (greedy, so a longer continuation starts with the
+    shorter), so that the module compiles a few decode lengths and not one
+    per request."""
     ids = np.asarray([p], np.int32)
+    steps = -(-mn // 8) * 8
+    steps = steps if len(p) + steps <= 64 else mn
     return np.asarray(oracle.generate(
         ids, lengths=np.asarray([len(p)], np.int32),
-        max_new_tokens=mn).numpy())[0]
+        max_new_tokens=steps).numpy())[0][:mn]
 
 
 # -- host-side unit layer -----------------------------------------------------
@@ -212,7 +236,7 @@ def test_prefix_hit_bit_identical_and_counters():
     m = _gpt()
     gen = Generator(m, site="pfx:hit", seq_buckets=(8, 16, 32),
                     max_len=64)
-    oracle = Generator(m, seq_buckets=(8, 16, 32), max_len=64)
+    oracle = _oracle()
     pc = PrefixCache(block_tokens=8, block_nbytes=4096,
                      hbm_budget_mb=0.0)
     loop = SlotLoop(gen, slots=4, cache_len=64, chunk=8,
@@ -246,7 +270,7 @@ def test_prefix_eviction_pressure_stays_bit_identical():
     m = _gpt()
     gen = Generator(m, site="pfx:evict", seq_buckets=(8, 16, 32),
                     max_len=64)
-    oracle = Generator(m, seq_buckets=(8, 16, 32), max_len=64)
+    oracle = _oracle()
     import jax.tree_util as tu
     from paddle_tpu.serving.cluster.handoff import _np_dtype
     block_nbytes = sum(
@@ -320,7 +344,7 @@ def test_turn_park_restore_bit_identical_plain():
     _turn_roundtrip(
         lambda site: Generator(m, site=site, seq_buckets=(8, 16, 32),
                                max_len=64),
-        lambda: Generator(m, seq_buckets=(8, 16, 32), max_len=64),
+        _oracle,
         "sess:plain")
 
 
@@ -330,8 +354,7 @@ def test_turn_park_restore_bit_identical_speculative():
         lambda site: SpeculativeGenerator(m, d, site=site,
                                           seq_buckets=(8, 16, 32),
                                           max_len=64, gamma=3),
-        lambda: SpeculativeGenerator(m, d, seq_buckets=(8, 16, 32),
-                                     max_len=64, gamma=3),
+        lambda: _oracle(speculative=True),
         "sess:spec")
 
 
@@ -343,7 +366,7 @@ def test_turn_park_restore_bit_identical_int8_kv():
         _turn_roundtrip(
             lambda site: Generator(m, site=site, seq_buckets=(8, 16, 32),
                                    max_len=64),
-            lambda: Generator(m, seq_buckets=(8, 16, 32), max_len=64),
+            _oracle,
             "sess:int8")
     finally:
         flags_restore(snap)
@@ -357,7 +380,7 @@ def test_drain_parks_mid_generation_and_resumes_bit_identical():
     m = _gpt()
     gen = Generator(m, site="sess:drain", seq_buckets=(8, 16, 32),
                     max_len=64)
-    oracle = Generator(m, seq_buckets=(8, 16, 32), max_len=64)
+    oracle = _oracle()
     store = SessionStore()
     loop = SlotLoop(gen, slots=2, cache_len=64, chunk=8,
                     session_store=store)
@@ -403,7 +426,7 @@ def test_server_sessions_end_to_end_with_drain_and_spill(tmp_path):
                    "FLAGS_session_store_dir": spill,
                    "FLAGS_prefix_cache": True})
         m = _gpt(seed=45)
-        oracle = Generator(m, seq_buckets=(8, 16, 32), max_len=64)
+        oracle = _oracle(45)
         rng = np.random.RandomState(9)
         p1 = rng.randint(1, V, 6).astype(np.int32)
 
@@ -471,7 +494,7 @@ def test_router_affinity_and_migration_on_retire():
                    "FLAGS_session_store": True,
                    "FLAGS_prefix_cache": True})
         m = _gpt(seed=45)
-        oracle = Generator(m, seq_buckets=(8, 16, 32), max_len=64)
+        oracle = _oracle(45)
 
         def _server():
             srv = serving.Server(serving.ServingConfig(workers=2))

@@ -160,6 +160,10 @@ def test_profiler_scheduler_trace_has_runtime_spans(tmp_path):
     import paddle_tpu.nn as nn
     from paddle_tpu.parallel import TrainStep
 
+    # request traces that earlier tests of this process finished (with
+    # their instant events) are not this profiler's: start from none
+    from paddle_tpu.profiler import tracing
+    tracing.clear()
     exe, main, out = _build_static_runner()
     xd = np.zeros((2, 3), "float32")
 

@@ -8,6 +8,7 @@ recompiles across arbitrary slot occupancy.  Plus: bounded-ring
 session resets, the FLAGS_decode_slots / FLAGS_prefill_chunk surface
 (validation, snapshot/restore, off-path), token-level occupancy
 signals, and the slot-mode Server integration."""
+import functools
 import random
 
 import numpy as np
@@ -28,6 +29,7 @@ from paddle_tpu.text.speculative import SpeculativeGenerator
 V = 64
 
 
+@functools.lru_cache(maxsize=None)
 def _gpt(seed=21):
     paddle.seed(seed)
     m = GPTModel(GPTConfig.tiny(vocab_size=V, hidden_size=32, layers=2,
@@ -36,12 +38,27 @@ def _gpt(seed=21):
     return m
 
 
+@functools.lru_cache(maxsize=None)
 def _draft(seed=101):
     paddle.seed(seed)
     d = GPTModel(GPTConfig.tiny(vocab_size=V, hidden_size=16, layers=1,
                                 heads=2, seq=64))
     d.eval()
     return d
+
+
+@functools.lru_cache(maxsize=None)
+def _oracle(seed=21, speculative=False):
+    """ONE stateless oracle per model for the whole module: the tests ask
+    it for many (prompt bucket, cache bucket, steps) programs, and a fresh
+    Generator per test compiled each of them again.  The models are
+    built once per seed too (nothing here mutates their weights); the
+    kv-cache dtype is part of every executable's key, so the int8 tests
+    share the oracle safely."""
+    if speculative:
+        return SpeculativeGenerator(_gpt(seed), _draft(), seq_buckets=(8, 16, 32),
+                                    max_len=64, gamma=3)
+    return Generator(_gpt(seed), seq_buckets=(8, 16, 32), max_len=64)
 
 
 def _trace(rng, n, max_lp=20, max_mn=10):
@@ -71,12 +88,22 @@ def _run_churn(loop, reqs, waves=3):
     return [np.asarray(f.result(timeout=120)).reshape(-1) for f in futs]
 
 
+def _oracle_steps(p, mn):
+    """The oracle decodes greedily, so the first ``mn`` tokens of a longer
+    continuation ARE the ``mn``-token continuation (EOS freezing
+    included): ask for ``mn`` rounded up to a multiple of 8 where the
+    model's 64 positions allow, so that the module compiles three decode
+    lengths and not one per request."""
+    steps = -(-mn // 8) * 8
+    return steps if len(p) + steps <= 64 else mn
+
+
 def _assert_bit_identical(oracle, reqs, outs):
     for (p, mn), got in zip(reqs, outs):
         ids = np.asarray([p], np.int32)
         want = np.asarray(oracle.generate(
             ids, lengths=np.asarray([len(p)], np.int32),
-            max_new_tokens=mn).numpy())[0]
+            max_new_tokens=_oracle_steps(p, mn)).numpy())[0]
         np.testing.assert_array_equal(got[:mn], want[:mn])
 
 
@@ -84,7 +111,7 @@ def test_churn_bit_identical_plain_zero_steady_recompiles():
     m = _gpt()
     gen = Generator(m, site="slot:plain", seq_buckets=(8, 16, 32),
                     max_len=64)
-    oracle = Generator(m, seq_buckets=(8, 16, 32), max_len=64)
+    oracle = _oracle()
     loop = SlotLoop(gen, slots=4, cache_len=64, chunk=8)
     mark = len(ledger.compile_events("slot:plain"))
     try:
@@ -104,8 +131,7 @@ def test_churn_bit_identical_speculative():
     gen = SpeculativeGenerator(m, d, site="slot:spec",
                                seq_buckets=(8, 16, 32), max_len=64,
                                gamma=3)
-    oracle = SpeculativeGenerator(m, d, seq_buckets=(8, 16, 32),
-                                  max_len=64, gamma=3)
+    oracle = _oracle(speculative=True)
     loop = SlotLoop(gen, slots=4, cache_len=64, chunk=8)
     mark = len(ledger.compile_events("slot:spec"))
     try:
@@ -128,7 +154,7 @@ def test_churn_bit_identical_int8_kv():
         m = _gpt()
         gen = Generator(m, site="slot:int8", seq_buckets=(8, 16, 32),
                         max_len=64)
-        oracle = Generator(m, seq_buckets=(8, 16, 32), max_len=64)
+        oracle = _oracle()
         loop = SlotLoop(gen, slots=4, cache_len=64, chunk=8)
         mark = len(ledger.compile_events("slot:int8"))
         try:
@@ -148,7 +174,7 @@ def test_eos_early_retirement_matches_oracle_padding():
     the eos token exactly like the scanned decode's freeze."""
     m = _gpt(seed=37)
     gen = Generator(m, seq_buckets=(8, 16, 32), max_len=64)
-    oracle = Generator(m, seq_buckets=(8, 16, 32), max_len=64)
+    oracle = _oracle(37)
     # pick an eos that actually occurs: take the 3rd greedy token
     probe = np.asarray(oracle.generate(
         np.asarray([[5, 9, 2]], np.int32),
@@ -172,7 +198,7 @@ def test_eos_early_retirement_matches_oracle_padding():
 def test_bounded_ring_session_reset_and_rejection():
     m = _gpt(seed=39)
     gen = Generator(m, seq_buckets=(8, 16, 32), max_len=64)
-    oracle = Generator(m, seq_buckets=(8, 16, 32), max_len=64)
+    oracle = _oracle(39)
     loop = SlotLoop(gen, slots=2, cache_len=32, chunk=8)
     try:
         # a prompt+continuation that can NEVER fit C=32 fails at submit

@@ -14,13 +14,15 @@ program) and prints, from ``compiled.as_text()``:
     ``[1, ., C, .]`` row of one): which dimension carries the traced,
     unaligned index and whether that is the layout's minor-most (lane)
     dimension;
-  * whether every plane is aliased input to output (donation kept);
+  * whether every plane is aliased input to output (donation kept), and
+    the program's ``memory_analysis()`` (arguments, temporaries), which is
+    how a configuration's ``slots`` is sized before any chip time;
   * in each program, the ``copy``/``transpose`` instructions of a whole
     plane, and the copies of a cache row ``[1, ., C, .]`` whose operand
     has another layout (the relayout a lane-major plane forces on the
     chunk's block write).
 
-    JAX_PLATFORMS=cpu python3 tools/kv_layout_check.py gpt2-xl-serve
+    JAX_PLATFORMS=cpu python3 tools/kv_layout_check.py gpt2-xl-serve [slots]
 
 Exit code 1 when a write's traced index lies on the minor-most dimension,
 a plane is not aliased, a whole plane is copied, or a cache row changes
@@ -129,7 +131,7 @@ def _faults(what, facts):
 
 
 def main(argv):
-    if len(argv) != 1:
+    if len(argv) not in (1, 2):
         print(__doc__, file=sys.stderr)
         return 2
     os.environ.setdefault("TPU_LOG_DIR", "disabled")
@@ -138,11 +140,13 @@ def main(argv):
     import jax
     from jax.experimental import topologies
     from jax.sharding import SingleDeviceSharding
+    import importlib
     from paddle_tpu.text.generation import Generator
-    from benchmark.models import gpt as family
     with open(os.path.join(ROOT, "benchmark", "configs",
                            argv[0] + ".json")) as f:
         cfg = json.load(f)
+    # the configuration names its model family, as for the runners
+    family = importlib.import_module("benchmark.models." + cfg["family"])
     sv = cfg["serve"]
     topo = topologies.get_topology_desc(platform="tpu",
                                         topology_name="v5e:2x2")
@@ -153,6 +157,8 @@ def main(argv):
                     seq_buckets=sv["seq_buckets"], max_len=sv["max_len"])
     state = place(gen._state_avals())
     S, C, T = sv["slots"], sv["max_len"], sv["prefill_chunk"]
+    if len(argv) > 1:
+        S = int(argv[1])            # try another slot count
     plane_shapes = {tuple(p.shape) for c in gen.slot_cache_avals_all(S, C)
                     for p in c}
     faults = []
@@ -164,6 +170,11 @@ def main(argv):
         facts = inspect(compiled.as_text(), plane_shapes)
         print(json.dumps({"config": cfg["name"], "program": what,
                           "slots": S, "cache": C, **facts}), flush=True)
+        mem = compiled.memory_analysis()
+        print(json.dumps({"program": what, "memory_gib": {
+            k: round(getattr(mem, k + "_size_in_bytes") / 2 ** 30, 3)
+            for k in ("argument", "output", "alias", "temp",
+                      "generated_code")}}), flush=True)
         faults += _faults(what, facts)
     for f in faults:
         print("FAULT " + f, flush=True)
