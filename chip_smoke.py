@@ -71,6 +71,7 @@ KERNEL_TOL = {
     "flash_decode": (4e-3, 2e-2),
     "flash_decode_int8": (4e-3, 2e-2),
     "packed_cached_attention": (4e-3, 2e-2),
+    "blocked_decode_attention": (4e-3, 2e-2),
     "fused_conv_bn_relu": (5e-2, 5e-2),
     "fused_bn_relu": (5e-2, 5e-2),
 }
@@ -92,6 +93,7 @@ class Size:
     prompt_lens: Tuple[int, ...]
     slots: int
     attn: Tuple[int, int, int, int]            # B, N, S, H
+    decode_heads: int                          # of H, over the packed ring
     conv_x: Tuple[int, int, int, int]          # N, H, W, C (NHWC)
     conv_w: Tuple[int, int, int, int]          # O, I, kh, kw
     moe: GPTMoEConfig
@@ -115,7 +117,8 @@ FULL = Size(
                   max_position_embeddings=1024, dropout=0.0),
     serve_batch_buckets=(1, 4), serve_seq_buckets=(32, 128),
     serve_max_new=16, serve_max_len=256, prompt_lens=(5, 19, 32, 70, 128, 9),
-    slots=8, attn=(8, 12, 1024, 64), conv_x=(32, 56, 56, 64),
+    slots=8, attn=(8, 12, 1024, 64), decode_heads=25,
+    conv_x=(32, 56, 56, 64),
     conv_w=(64, 64, 3, 3),
     moe=_moe_cfg(vocab_size=128, hidden_size=512, layers=4, heads=8,
                  seq=128, experts=16),
@@ -128,7 +131,8 @@ TINY = Size(
                        seq=128),
     serve_batch_buckets=(1, 2), serve_seq_buckets=(8, 16), serve_max_new=4,
     serve_max_len=32, prompt_lens=(3, 7, 12, 1, 9, 5), slots=4,
-    attn=(1, 2, 256, 64), conv_x=(2, 8, 8, 8), conv_w=(8, 8, 3, 3),
+    attn=(1, 2, 256, 64), decode_heads=3, conv_x=(2, 8, 8, 8),
+    conv_w=(8, 8, 3, 3),
     moe=_moe_cfg(vocab_size=64, hidden_size=16, layers=2, heads=2, seq=32,
                  experts=4),
     moe_batch=8, moe_seq=16)
@@ -556,6 +560,41 @@ def phase_kernels(size: Size, seed: int = 0) -> dict:
         "heads_per_lane_row": g,
         "max_abs_err": round(_close("packed_cached_attention", got, ref), 6),
         "tolerance": list(KERNEL_TOL["packed_cached_attention"])}
+    # the decode step's form of it (ISSUE 28): the same planes read in
+    # column blocks over the live span under a traced trip count, at the
+    # served head geometry (25 heads of 64: 13 lane rows, one half
+    # empty), the frontier inside the third block, rows that start in
+    # unlike blocks and one that is not generating (start = S)
+    from paddle_tpu.nn.functional.attention import (_decode_span_fn,
+                                                    decode_block)
+    heads, rows = size.decode_heads, 4
+    qd = rand((rows, heads, 1, H))
+    kd, vd = rand((rows, heads, S, H)), rand((rows, heads, S, H))
+    block = decode_block(S)
+    pos = min(2 * block + block // 2, S - 1)
+    dstart = jnp.asarray([max(pos - block - 5, 0), S, pos // 2, pos],
+                         jnp.int32)
+    dend = jnp.full((rows,), pos + 1, jnp.int32)
+    live = np.asarray(dstart) <= pos
+    kp, vp = pack_heads(kd, g), pack_heads(vd, g)
+    got = jax.block_until_ready(_decode_span_fn(
+        qd, kp, vp, dstart, dend, block=block))
+    _check(bool(np.isfinite(np.asarray(got, np.float32)).all()),
+           "kernels: blocked_decode_attention non-finite on a dead row")
+    dmask = jnp.where((col >= dstart[:, None]) & (col < dend[:, None]),
+                      0.0, -1e30).astype(jnp.float32)[:, None, None]
+    whole = jax.jit(_sdpa_packed_fn)(qd, kp, vp, dmask)
+    with jax.default_matmul_precision("highest"):
+        ref = decode_attention_reference(
+            *(a.astype(jnp.float32) for a in (qd, kd, vd)), dstart, dend)
+    checked["blocked_decode_attention"] = {
+        "heads": heads, "block": block, "frontier": pos,
+        "max_abs_err": round(_close("blocked_decode_attention",
+                                    got[live], ref[live]), 6),
+        "max_abs_diff_one_expression": round(float(jnp.abs(
+            got[live].astype(jnp.float32)
+            - whole[live].astype(jnp.float32)).max()), 6),
+        "tolerance": list(KERNEL_TOL["blocked_decode_attention"])}
     checked["compiled_not_interpreted"] = on_chip
     checked["shapes"] = {"attention": list(size.attn),
                          "conv_x": list(size.conv_x),

@@ -9,14 +9,18 @@ TPU, the Pallas flash-attention kernel (paddle_tpu/ops/pallas/) takes over.
 """
 from __future__ import annotations
 
+import functools
 import math
 
 import jax
 import jax.numpy as jnp
+import numpy as np
 
 from ...framework.flags import flag
 from ...framework.primitive import Primitive
 from ...framework.tensor import Tensor, unwrap
+
+_NEG = -1e30
 
 
 def _sdpa_fn(q, k, v, scale=None, causal=False):
@@ -62,31 +66,121 @@ def _sdpa_packed_fn(q, k, v, mask=None):
     its own ``H`` lanes of the result (the inverse of ``pack_heads``).
     Same scale, mask, f32 softmax and accumulation as
     :func:`_sdpa_mask_fn`."""
-    b, n, t, hd = q.shape
-    groups, lanes = k.shape[1], k.shape[3]
-    g = lanes // hd
-    qg = jnp.pad(q, ((0, 0), (0, groups * g - n), (0, 0), (0, 0))) \
-        .reshape(b, groups, g, t, hd)
-    own = jnp.arange(lanes)[None, :] // hd == jnp.arange(g)[:, None]
-    qs = jnp.where(own[None, None, :, None, :],
-                   jnp.tile(qg, (1, 1, 1, 1, g)), jnp.zeros((), q.dtype))
+    qs, own = _spread_queries(q, k.shape[1], k.shape[3])
     logits = jnp.einsum("bgjtl,bgcl->bgjtc", qs, k,
                         preferred_element_type=jnp.float32) \
-        * (1.0 / math.sqrt(hd))
+        * (1.0 / math.sqrt(q.shape[-1]))
     if mask is not None:
         logits = logits + mask.astype(logits.dtype)[:, :, None]
     probs = jax.nn.softmax(logits, axis=-1).astype(q.dtype)
     out = jnp.einsum("bgjtc,bgcl->bgjtl", probs, v,
                      preferred_element_type=jnp.float32).astype(q.dtype)
-    # each head keeps its own lanes: select and sum over j (one term is
-    # non-zero, so the sum is exact), NOT a stack of out[..., j, :,
-    # j*H:(j+1)*H] slices — the TPU compiler of jax 0.9.0 miscompiles a
-    # concatenate of slices taken at a lane offset (wrong values on the
-    # v5e, right on the CPU; PERF.md section 6, PR 25)
-    out = jnp.where(own[None, None, :, None, :], out,
-                    jnp.zeros((), q.dtype)).sum(axis=2)
+    return _own_lanes(out, own, q.shape[1], q.shape[-1])
+
+
+def _spread_queries(q, groups, lanes):
+    """``(B, N, T, H)`` queries over their group's ``lanes = g*H`` lanes,
+    zeros outside the head's own: ``[B, groups, g, T, lanes]``, and the
+    ``own [1, 1, g, 1, lanes]`` mask of each head's lanes."""
+    b, n, t, hd = q.shape
+    g = lanes // hd
+    qg = jnp.pad(q, ((0, 0), (0, groups * g - n), (0, 0), (0, 0))) \
+        .reshape(b, groups, g, t, hd)
+    own = (jnp.arange(lanes)[None, :] // hd
+           == jnp.arange(g)[:, None])[None, None, :, None, :]
+    return jnp.where(own, jnp.tile(qg, (1, 1, 1, 1, g)),
+                     jnp.zeros((), q.dtype)), own
+
+
+def _own_lanes(out, own, n, hd):
+    """``[B, groups, g, T, lanes]`` -> ``(B, n, T, hd)``: each head keeps
+    its own lanes: select and sum over j (one term is non-zero, so the
+    sum is exact), NOT a stack of out[..., j, :, j*H:(j+1)*H] slices —
+    the TPU compiler of jax 0.9.0 miscompiles a concatenate of slices
+    taken at a lane offset (wrong values on the v5e, right on the CPU;
+    PERF.md section 6, PR 25)."""
+    b, groups, g, t, _ = out.shape
+    out = jnp.where(own, out, jnp.zeros((), out.dtype)).sum(axis=2)
     return out.reshape(b, groups, t, g, hd).transpose(0, 1, 3, 2, 4) \
         .reshape(b, groups * g, t, hd)[:, :n]
+
+
+# columns a decode step's attention reads at a time: the unit in which
+# the live span of the ring is rounded out
+DECODE_BLOCK = 128
+
+
+def decode_block(C):
+    """The block width over a cache of ``C`` columns (serving/slots.py
+    counts its ``attn_blocks_*`` in the same unit)."""
+    return min(DECODE_BLOCK, int(C))
+
+
+@functools.partial(jax.jit, static_argnames=("block",))
+def _decode_span_fn(q, k, v, start, end, block):
+    """One-query attention of ``(B, N, 1, H)`` queries over ring planes
+    ``(B, G, C, g*H)`` (packed as for :func:`_sdpa_packed_fn`; ``g == 1``
+    is the plain ``(B, N, C, H)`` plane) whose valid columns are each
+    row's ``[start[b], end[b])``: only the span of ``block``-column
+    blocks (``decode_block(C)``; jitted on its own so that a model's
+    layers, which all call it at one shape, trace its loops once) from
+    the one that holds the lowest ``start`` to the one that
+    holds the highest ``end`` is read, in two passes under a traced trip
+    count.  The first writes each block's float32 scores into its slot
+    of a ``[blocks, .., block]`` slab; then the mask and ONE softmax over
+    the slab, as the one-expression path takes it over the whole row
+    (a block outside the span holds no valid column, so its slot is
+    masked whatever it holds); the second adds up probabilities times V
+    block by block.  K and V are read once, the span's blocks only; same
+    scale, float32 scores and accumulation, and head select-and-sum as
+    :func:`_sdpa_packed_fn`.  Each pass's body is one fused device
+    operation a block: a running softmax in one loop costs nine, and a
+    profiler capture of a serving window pays for every one (PERF.md
+    section 6, PR 28).  A row that is not generating must come with
+    ``start >= C`` (the slot loop keeps it there) so that it does not
+    widen the span; it, and every row of a step with nothing live, gets
+    finite values that nobody uses."""
+    b, n, t, hd = q.shape
+    groups, C, lanes = k.shape[1], k.shape[2], k.shape[3]
+    blocks = -(-C // block)
+    qs, own = _spread_queries(q, groups, lanes)
+    # (the barrier keeps the spread queries one array that both loops
+    # read, where the compiler would rebuild them in every iteration)
+    qs = jax.lax.optimization_barrier(qs)
+    lo = jnp.clip(jnp.min(start) // block, 0, blocks)
+    hi = jnp.clip((jnp.max(end) + (block - 1)) // block, lo, blocks)
+    # slot i holds the columns from base[i]: i * block, but the last
+    # block of a cache that is no multiple of ``block`` starts early and
+    # does not count again what the slot before it holds
+    first = np.arange(blocks) * block
+    base = np.minimum(first, C - block)
+    cols = base[:, None] + np.arange(block)                # [blocks, block]
+    valid = (cols[:, None] >= start[:, None]) & (cols[:, None] < end[:, None]) \
+        & (cols >= first[:, None])[:, None]                # [blocks, B, block]
+    base = jnp.asarray(base, jnp.int32)
+
+    def cut(plane, i):
+        return jax.lax.dynamic_slice_in_dim(plane, base[i], block, 2)
+
+    def score(i, slab):
+        s = jnp.einsum("bgjtl,bgcl->bgjtc", qs, cut(k, i),
+                       preferred_element_type=jnp.float32)
+        return jax.lax.dynamic_update_index_in_dim(slab, s, i, 0)
+
+    slab = jax.lax.fori_loop(
+        lo, hi, score, jnp.zeros((blocks,) + qs.shape[:-1] + (block,),
+                                 jnp.float32))
+    slab = jnp.where(valid[:, :, None, None, None, :],
+                     slab * (1.0 / math.sqrt(hd)), _NEG)
+    e = jnp.exp(slab - slab.max(axis=(0, -1))[None, ..., None])
+    probs = (e / e.sum(axis=(0, -1))[None, ..., None]).astype(q.dtype)
+
+    def weigh(i, acc):
+        return acc + jnp.einsum("bgjtc,bgcl->bgjtl", probs[i], cut(v, i),
+                                preferred_element_type=jnp.float32)
+
+    out = jax.lax.fori_loop(lo, hi, weigh, jnp.zeros(qs.shape, jnp.float32))
+    return _own_lanes(out.astype(q.dtype), own, n, hd)
 
 
 _sdpa = Primitive("scaled_dot_product_attention", _sdpa_fn)
@@ -162,9 +256,12 @@ def cached_attention(q, k, v, attn_mask=None, window=None, k_scale=None,
     ``attn_mask`` is the additive validity+causality mask the caller
     built from cache_position / per-row start offsets.  ``window`` is the
     optional ``(start[B], end[B])`` contiguous form of the same validity
-    (decode steps: Tq == 1) — when present and eligible, the Pallas
-    flash-decoding kernel (split-K over the cached context) takes over;
-    otherwise the one-expression XLA masked attention runs.
+    (decode steps: Tq == 1).  With it, bf16/f32 planes are read in
+    column blocks over the live span only (:func:`_decode_span_fn`;
+    inference-only like every cached path, nothing is taped), unless
+    the Pallas flash-decoding kernel (split-K over the cached context)
+    is eligible and takes over; without it (prefill, a chunk, a verify
+    block) the one-expression XLA masked attention runs.
 
     With ``k_scale``/``v_scale`` given (FLAGS_kv_cache_dtype=int8), k/v
     are int8 row planes and the scales are the per-(token, head) f32
@@ -185,6 +282,11 @@ def cached_attention(q, k, v, attn_mask=None, window=None, k_scale=None,
     if _use_flash_decode(q, k, window):
         from ...ops.pallas import flash_decode
         return flash_decode(q, k, v, window[0], window[1])
+    if window is not None and k_scale is None:
+        k = unwrap(k)
+        return Tensor(_decode_span_fn(
+            unwrap(q), k, unwrap(v), unwrap(window[0]), unwrap(window[1]),
+            block=decode_block(k.shape[2])))
     if unwrap(k).shape[-1] != unwrap(q).shape[-1]:
         return _sdpa_packed(q, k, v, attn_mask) if attn_mask is not None \
             else _sdpa_packed(q, k, v)
@@ -210,9 +312,6 @@ def attention_bnsh(q, k, v, attn_mask=None, is_causal=False):
 # inference-only, nothing is taped); nn/layer/latent_attention.py owns the
 # projections and the planes.
 # ---------------------------------------------------------------------------
-
-_NEG = -1e30
-
 
 def rotary(x, positions, base, dims=None):
     """Rotate the first ``dims`` (default all; even) features of ``x``

@@ -273,8 +273,11 @@ class MultiHeadAttention(Layer):
         (``pack_heads``; ``g`` read from the plane's minor dim), write
         them at cache_position (ring_block_write on the column dim —
         two legs at the ring boundary for multi-token blocks), and
-        attend the new queries over the WHOLE cache under the caller's
-        validity mask.  Quantized caches keep unpacked planes,
+        attend the new queries over the cache under the caller's
+        validity mask: the whole of it, or, for a decode step's one
+        query with its ``decode_window``, the column blocks the window
+        spans (``cached_attention``).  Quantized caches keep unpacked
+        planes,
         additionally write int8 rows + scale planes at the same
         position and dequantize at the attention read (fused into the
         flash-decode kernel when it dispatches).  Returns (out, updated

@@ -18,7 +18,9 @@ per-request ``generate()`` of the same prompt:
 
   * **scalar lockstep position** — every dispatch writes at the shared
     ``pos``; a joining request is a row whose validity window restarts
-    (``start[s]`` moves), never a recompile or a cache copy;
+    (``start[s]`` moves), never a recompile or a cache copy; a row that
+    is not generating keeps ``start[s] = C``, an empty window, so that
+    the step's attention spans the generating rows' columns only;
   * **dead-column garbage discipline** — the step program does NOT
     mask its cache write per row (the cache is donated for in-place
     column updates; a per-row blend would force XLA into a full-plane
@@ -76,7 +78,12 @@ What the loop measures about itself, always on:
     FLAGS_trace, the children of the request's root span;
   * **every slot-step** — each decode step counts its ``S`` slots as
     emitting, prefilling, drain-blocked or without demand
-    (``counters["slot_steps_*"]``, summing to ``steps x S``).
+    (``counters["slot_steps_*"]``, summing to ``steps x S``);
+  * **the span a step's attention reads** — the step program attends
+    in column blocks from the oldest generating row's ``start`` to the
+    shared frontier (``cached_attention``); ``counters["attn_blocks_
+    read"]`` of ``["attn_blocks_total"]`` is that span, per step, by
+    the same arithmetic on the host.
 """
 from __future__ import annotations
 
@@ -91,6 +98,7 @@ import numpy as np
 
 from ..framework.enforce import (InvalidArgumentError, OutOfRangeError,
                                  UnavailableError)
+from ..nn.functional.attention import decode_block
 from ..profiler import span as _span
 from ..profiler import tracing as _tracing
 from ..profiler.metrics import LatencyWindow
@@ -277,6 +285,12 @@ class SlotLoop:
                 if not k.endswith("_max")})
         if self._wrap_lens:
             self.counters["window_wraps"] = 0
+        # the plain step over bf16/f32 K/V planes attends in blocks of
+        # this many columns (cached_attention)
+        self._attn_block = 0
+        if self._plane_kinds == ["kv"] and not self._spec:
+            self._attn_block = decode_block(self.C)
+            self.counters.update(attn_blocks_read=0, attn_blocks_total=0)
         # driver-thread-owned: what the dispatches since the last commit
         # add to those counters; committed with ``steps`` in one piece
         self._tally = {}
@@ -317,7 +331,7 @@ class SlotLoop:
         planes yet), neutral per-row vectors."""
         self.pos = 0
         self._cache = self._gen.init_slot_cache(self.S, self.C)
-        self._start = np.zeros((self.S,), np.int32)
+        self._start = np.full((self.S,), self.C, np.int32)
         self._finished = np.ones((self.S,), bool)
         self._active = np.zeros((self.S,), bool)
         if getattr(self, "_spec", False):
@@ -792,6 +806,19 @@ class SlotLoop:
         for n in self._wrap_lens:
             add("window_wraps", int(((cols > 0) & (cols % n == 0)).sum()))
 
+    def _tally_blocks(self, starts):
+        """Driver thread: the column blocks the step at ``pos`` reads
+        of each plane, of those the plane has: from the block of the
+        lowest ``start`` among the generating rows to the block of
+        ``pos`` (``cached_attention``'s own bounds)."""
+        block = self._attn_block
+        if not block:
+            return
+        read = self.pos // block + 1 - int(starts.min()) // block \
+            if starts.size else 0
+        self._add("attn_blocks_read", read)
+        self._add("attn_blocks_total", -(-self.C // block))
+
     def _add(self, key, n, chunk=False):
         t = self._tally
         t[key] = t.get(key, 0) + n
@@ -931,9 +958,9 @@ class SlotLoop:
         self._phase("retire")
         # the model's counts came back behind the S tokens
         self._tally_counts(tok[self.S:])
-        self._tally_columns(
-            np.full(len(gen_slots), self.pos),
-            np.array([self._slots[i].start for i in gen_slots], np.int64))
+        starts = np.array([self._slots[i].start for i in gen_slots], np.int64)
+        self._tally_columns(np.full(len(gen_slots), self.pos), starts)
+        self._tally_blocks(starts)
         self.pos += 1
         for i in gen_slots:
             self._emit(self._slots[i], [int(tok[i])])
@@ -1048,9 +1075,20 @@ class SlotLoop:
         # eos freeze: every position after finish reads eos, exactly the
         # scanned decode's padding — retiring early never changes bytes
         req.future.set_result(out)
+        self._vacate(i)
+        self.counters["retired"] += 1
+        self._m_retired.inc()
+
+    def _vacate(self, i):
+        """Row ``i`` generates no more: its slot is empty, its window
+        too (``start = C``, so that the step's attention does not span
+        the columns it leaves behind)."""
+        slot = self._slots[i]
         slot.state, slot.req = _EMPTY, None
         slot.emitted = []
         # copy-on-write for the same aliasing reason as _activate
+        self._start = self._start.copy()
+        self._start[i] = self.C
         self._finished = self._finished.copy()
         self._finished[i] = True
         self._active = self._active.copy()
@@ -1058,8 +1096,6 @@ class SlotLoop:
         if self._spec:
             self._cur = self._cur.copy()
             self._cur[i] = 0
-        self.counters["retired"] += 1
-        self._m_retired.inc()
 
     # -- drain-time parking --------------------------------------------------
     def park_sessions(self, timeout: float = 30.0) -> int:
@@ -1114,16 +1150,8 @@ class SlotLoop:
                     out[0] += 1
                 if not slot.req.future.done():
                     slot.req.future.set_exception(exc)
-                slot.state, slot.req = _EMPTY, None
-                slot.emitted = []
                 slot.restore = []
-                self._finished = self._finished.copy()
-                self._finished[i] = True
-                self._active = self._active.copy()
-                self._active[i] = False
-                if self._spec:
-                    self._cur = self._cur.copy()
-                    self._cur[i] = 0
+                self._vacate(i)
             keep: "deque[SlotRequest]" = deque()
             while self._pending:
                 r = self._pending.popleft()

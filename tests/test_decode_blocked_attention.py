@@ -1,0 +1,259 @@
+"""The decode step's attention over the live span of the ring (ISSUE 28).
+
+``cached_attention`` with a decode ``window`` reads bf16/f32 ring planes
+in blocks of ``DECODE_BLOCK`` columns, from the block of the lowest
+``start`` to the block of the highest ``end``, and nothing outside.
+Held here, for the head geometries of ``tests/test_kv_packed_layout.py``
+(packed ``g`` = 8 and 2, unpacked ``g`` = 1): the blocked form against the
+one-expression attention under the dense mask; the frontier on and
+around a block edge; unlike ``start``s, on and off an edge; a row that is
+not generating (``start = C``, where the slot loop keeps it) leaves the
+span alone; no live row gives finite values; stale values outside a row's
+window change nothing, and infinities in the blocks outside the span are
+never read; a cache that is no multiple of the block; the slot loop's
+tokens equal to ``generate()`` when the two sides cut their columns into
+different blocks; which calls of ``cached_attention`` take the blocked
+form; and the benchmark's reader of the loop's ``attn_blocks_*``.
+"""
+import importlib
+
+import numpy as np
+import pytest
+import jax
+import jax.numpy as jnp
+
+import paddle_tpu as paddle
+from paddle_tpu.framework.tensor import Tensor, unwrap
+from paddle_tpu.nn.functional import attention as A
+from paddle_tpu.nn.layer.transformer import (kv_heads_per_lane_row,
+                                             pack_heads, quantize_kv_rows)
+from paddle_tpu.serving.slots import SlotLoop
+from paddle_tpu.text.generation import Generator
+from paddle_tpu.text.models.gpt import GPTConfig, GPTModel
+
+# (head_dim, heads) as in tests/test_kv_packed_layout.py
+CASES = [(16, 5), (64, 5), (128, 3)]
+IDS = [f"h{hd}x{n}" for hd, n in CASES]
+BLOCK = 16          # the tests' block; C spans four of them
+C = 4 * BLOCK
+B = 5
+TOL = dict(rtol=2e-5, atol=2e-6)
+
+
+@pytest.fixture
+def block16(monkeypatch):
+    monkeypatch.setattr(A, "DECODE_BLOCK", BLOCK)
+
+
+def _planes(hd, heads, seed=0, cols=C):
+    """Queries, the planes as ``gen_ring_cache`` packs them, and the same
+    K/V head by head."""
+    rng = np.random.RandomState(seed)
+    q = jnp.asarray(rng.standard_normal((B, heads, 1, hd)), jnp.float32)
+    k = jnp.asarray(rng.standard_normal((B, heads, cols, hd)), jnp.float32)
+    v = jnp.asarray(rng.standard_normal((B, heads, cols, hd)), jnp.float32)
+    g = kv_heads_per_lane_row(hd)
+    return q, pack_heads(k, g), pack_heads(v, g), k, v
+
+
+def _dense(q, kp, vp, k, v, start, end):
+    """Today's one-expression attention under the dense additive mask."""
+    col = jnp.arange(kp.shape[2])
+    valid = (col[None, :] >= start[:, None]) & (col[None, :] < end[:, None])
+    mask = jnp.where(valid, 0.0, -1e30).astype(jnp.float32)[:, None, None, :]
+    if kp.shape[-1] != q.shape[-1]:
+        return A._sdpa_packed_fn(q, kp, vp, mask)
+    return A._sdpa_mask_fn(q, k, v, mask)
+
+
+def _window(start, pos):
+    start = jnp.asarray(start, jnp.int32)
+    return start, jnp.full(start.shape, pos + 1, jnp.int32)
+
+
+def blocked(q, k, v, start, end):
+    return A._decode_span_fn(q, k, v, start, end,
+                             block=A.decode_block(k.shape[2]))
+
+
+@pytest.mark.parametrize("pos", [BLOCK - 1, BLOCK, C - 1],
+                         ids=["edge-1", "edge", "last"])
+@pytest.mark.parametrize("hd,heads", CASES, ids=IDS)
+def test_blocked_equals_the_dense_mask(hd, heads, pos, block16):
+    """Rows with unlike starts, on a block edge (0, BLOCK) and off one."""
+    q, kp, vp, k, v = _planes(hd, heads)
+    starts = [0, 3, BLOCK, BLOCK + 5, 2 * BLOCK]
+    start, end = _window([min(s, pos) for s in starts], pos)
+    got = blocked(q, kp, vp, start, end)
+    want = _dense(q, kp, vp, k, v, start, end)
+    assert got.shape == want.shape == q.shape
+    np.testing.assert_allclose(got, want, **TOL)
+
+
+@pytest.mark.parametrize("hd,heads", CASES, ids=IDS)
+def test_cache_that_is_no_multiple_of_the_block(hd, heads, block16):
+    cols = 2 * BLOCK + 8
+    q, kp, vp, k, v = _planes(hd, heads, seed=3, cols=cols)
+    for pos in (BLOCK + 2, 2 * BLOCK, cols - 1):
+        start, end = _window([0, 1, BLOCK, BLOCK + 1, 2], pos)
+        np.testing.assert_allclose(blocked(q, kp, vp, start, end),
+                                   _dense(q, kp, vp, k, v, start, end), **TOL)
+
+
+@pytest.mark.parametrize("hd,heads", CASES, ids=IDS)
+def test_the_modules_own_block_width(hd, heads):
+    """``DECODE_BLOCK`` as it is spelled, on a cache of three blocks."""
+    cols = 3 * A.DECODE_BLOCK
+    q, kp, vp, k, v = _planes(hd, heads, seed=5, cols=cols)
+    pos = 2 * A.DECODE_BLOCK + 7
+    start, end = _window([A.DECODE_BLOCK + 9, cols, pos, cols,
+                          2 * A.DECODE_BLOCK], pos)
+    live = np.asarray(start) <= pos
+    got = blocked(q, kp, vp, start, end)
+    np.testing.assert_allclose(
+        got[live], _dense(q, kp, vp, k, v, start, end)[live], **TOL)
+    assert np.isfinite(np.asarray(got)).all()
+
+
+def _poison(plane, value, dead):
+    """``value`` at the columns ``dead [B, C]`` (or ``[C]``) of a plane."""
+    dead = jnp.broadcast_to(jnp.asarray(dead), (plane.shape[0],
+                                                plane.shape[2]))
+    return jnp.where(dead[:, None, :, None], value, plane)
+
+
+@pytest.mark.parametrize("hd,heads", CASES, ids=IDS)
+def test_stale_values_outside_a_rows_window_change_nothing(hd, heads, block16):
+    """1e4 above ``pos`` and below each row's ``start`` (what an earlier
+    occupant and the steps' unmasked writes leave there): bit-equal."""
+    q, kp, vp, _, _ = _planes(hd, heads, seed=1)
+    pos = 2 * BLOCK + 3
+    start, end = _window([BLOCK + 2, 2 * BLOCK, BLOCK, pos, 2 * BLOCK + 1], pos)
+    col = jnp.arange(C)
+    dead = (col[None, :] < start[:, None]) | (col[None, :] > pos)
+    clean = blocked(q, kp, vp, start, end)
+    stale = blocked(q, _poison(kp, 1e4, dead), _poison(vp, 1e4, dead),
+                    start, end)
+    np.testing.assert_array_equal(np.asarray(clean), np.asarray(stale))
+
+
+@pytest.mark.parametrize("hd,heads", CASES, ids=IDS)
+def test_blocks_outside_the_span_are_not_read(hd, heads, block16):
+    """A masked column that is READ multiplies its V by an exact zero,
+    so an infinity there would come out as NaN: infinities in every
+    block below the oldest live row's and above the frontier's leave the
+    live rows bit-equal.  Rows 1 and 3 are not generating: their
+    ``start`` is C, and the span is the live rows' alone."""
+    q, kp, vp, _, _ = _planes(hd, heads, seed=2)
+    pos = 2 * BLOCK + 3                             # frontier in block 2
+    start, end = _window([BLOCK + 2, C, 2 * BLOCK, C, BLOCK + 9], pos)
+    col = jnp.arange(C)
+    outside = (col < BLOCK) | (col >= 3 * BLOCK)    # blocks 0 and 3
+    live = np.asarray(start) <= pos
+    clean = np.asarray(blocked(q, kp, vp, start, end))
+    inf = np.asarray(blocked(q, _poison(kp, jnp.inf, outside),
+                             _poison(vp, jnp.inf, outside), start, end))
+    assert np.isfinite(inf).all()
+    np.testing.assert_array_equal(clean[live], inf[live])
+    # the span IS made from the starts it is handed: the same rows with
+    # one start in block 0 read that block
+    wide = blocked(q, _poison(kp, jnp.inf, outside),
+                   _poison(vp, jnp.inf, outside), start.at[1].set(0), end)
+    assert not np.isfinite(np.asarray(wide)).all()
+
+
+@pytest.mark.parametrize("starts", [[C] * B, [C - 1] * B],
+                         ids=["all-empty", "all-above-the-frontier"])
+@pytest.mark.parametrize("hd,heads", CASES, ids=IDS)
+def test_no_live_row_gives_finite_values(hd, heads, starts, block16):
+    q, kp, vp, _, _ = _planes(hd, heads, seed=4)
+    out = blocked(q, kp, vp, *_window(starts, BLOCK + 1))
+    assert out.shape == q.shape and np.isfinite(np.asarray(out)).all()
+
+
+def test_frontier_past_the_ring_reads_every_block(block16):
+    """A scanned decode that wraps (``pos >= C``) sees every column at or
+    above its start, as under the dense mask."""
+    hd, heads = CASES[1]
+    q, kp, vp, k, v = _planes(hd, heads, seed=6)
+    start, end = _window([0, 3, BLOCK, 1, 2], C + 5)
+    np.testing.assert_allclose(blocked(q, kp, vp, start, end),
+                               _dense(q, kp, vp, k, v, start, end), **TOL)
+
+
+def _gpt(hd, heads, seed=7):
+    paddle.seed(seed)
+    m = GPTModel(GPTConfig.tiny(vocab_size=64, hidden_size=hd * heads,
+                                layers=2, heads=heads, seq=64))
+    m.eval()
+    return m
+
+
+@pytest.mark.parametrize("hd,heads", CASES, ids=IDS)
+def test_slot_tokens_equal_generate_across_unlike_block_cuts(hd, heads,
+                                                             block16):
+    """A slot row's columns lie elsewhere in the ring than the same
+    prompt's in ``generate()``, so the two sides cut them into other
+    blocks of 16; the tokens are the same, through slot reuse."""
+    m = _gpt(hd, heads)
+    gen = Generator(m, site=f"blocked:slots{hd}", seq_buckets=(8, 16, 32),
+                    max_len=64)
+    oracle = Generator(m, seq_buckets=(8, 16, 32), max_len=64)
+    loop = SlotLoop(gen, slots=2, cache_len=64, chunk=8)
+    try:
+        rng = np.random.RandomState(23)
+        reqs = [(rng.randint(1, 64, lp).tolist(), mn)
+                for lp, mn in ((19, 7), (3, 9), (11, 6), (5, 8), (9, 5))]
+        futs = [loop.submit(p, mn) for p, mn in reqs]
+        for (p, mn), f in zip(reqs, futs):
+            got = np.asarray(f.result(timeout=120)).reshape(-1)
+            want = np.asarray(oracle.generate(
+                np.asarray([p], np.int32),
+                lengths=np.asarray([len(p)], np.int32),
+                max_new_tokens=mn).numpy())[0]
+            np.testing.assert_array_equal(got[:mn], want[:mn])
+        c = loop.stats()
+        assert 0 < c["attn_blocks_read"] < c["attn_blocks_total"] \
+            == c["steps"] * (64 // BLOCK)
+    finally:
+        loop.close()
+
+
+def test_which_calls_take_the_blocked_form(monkeypatch):
+    """A decode window over bf16/f32 planes, packed or not; not a block
+    of queries (no window), not the int8 cache."""
+    calls = []
+    real = A._decode_span_fn
+    monkeypatch.setattr(
+        A, "_decode_span_fn",
+        lambda *a, **kw: calls.append(a[1].shape) or real(*a, **kw))
+    for hd, heads in CASES:
+        q, kp, vp, k, v = _planes(hd, heads)
+        start, end = _window([0, 1, 2, 3, 4], 9)
+        mask = Tensor(jnp.zeros((B, 1, 1, C), jnp.float32))
+        win = (Tensor(start), Tensor(end))
+        out = A.cached_attention(Tensor(q), Tensor(kp), Tensor(vp),
+                                 attn_mask=mask, window=win)
+        assert calls.pop() == kp.shape and not calls
+        np.testing.assert_allclose(unwrap(out),
+                                   _dense(q, kp, vp, k, v, start, end), **TOL)
+        A.cached_attention(Tensor(q), Tensor(kp), Tensor(vp), attn_mask=mask)
+        kq, ks = quantize_kv_rows(k)
+        vq, vs = quantize_kv_rows(v)
+        A.cached_attention(Tensor(q), Tensor(kq), Tensor(vq), attn_mask=mask,
+                           window=win, k_scale=Tensor(ks), v_scale=Tensor(vs))
+        assert not calls
+
+
+@pytest.mark.parametrize("stats,value", [
+    ({"steps": 10, "attn_blocks_read": 30, "attn_blocks_total": 80}, 37.5),
+    ({"steps": 10, "attn_blocks_read": 0, "attn_blocks_total": 80}, 0.0),
+    ({"steps": 0, "attn_blocks_read": 0, "attn_blocks_total": 0}, None),
+    ({"steps": 10, "chunks": 3}, None),       # the parent: no such counter
+    (None, None),
+])
+def test_attn_span_read_pct_reader(stats, value):
+    reader = importlib.import_module(
+        "benchmark.layer_metrics.attn_span_read_pct")
+    got = reader.compute({"counters": {"slot_loop": stats} if stats else {}})
+    assert got == (pytest.approx(value) if value is not None else None)

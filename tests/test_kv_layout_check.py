@@ -76,3 +76,46 @@ def test_unaliased_plane_and_whole_plane_copy_are_faults(tool):
     facts = tool.inspect(text, {shape})
     assert facts["planes_aliased"] == 1 and facts["whole_plane_copies"] == 1
     assert len(tool._faults("step", facts)) == 2
+
+
+_PLANE = "bf16[32,13,1024,128]{3,2,1,0:T(8,128)(2,1)}"
+_CARRY = f"(s32[]{{:T(128)}}, {_PLANE}, {_PLANE})"
+
+
+def _loop_hlo(operands, body_extra=""):
+    """The packed step with the attention's block loop in it (ISSUE 28):
+    a ``while`` whose tuple carries the two planes, as the compiler
+    prints it."""
+    text = _hlo((32, 13, 1024, 128), "3,2,1,0", "true,true,false,true",
+                f"  %copy.7 = {_PLANE} copy(%dynamic_update_slice.2)\n"
+                f"  %tuple.9 = {_CARRY} tuple({operands})\n"
+                f"  %while.1 = {_CARRY} while(%tuple.9), "
+                "condition=%cond.1, body=%wide.body.1.sunk\n")
+    body = (f"%wide.body.1.sunk (arg: {_CARRY}) -> {_CARRY} {{\n"
+            f"  %arg = {_CARRY} parameter(0)\n"
+            f"  %get-tuple-element.5 = {_PLANE} get-tuple-element(%arg), index=1\n"
+            f"{body_extra}"
+            f"  ROOT %tuple.3 = {_CARRY} tuple(%i.1, %get-tuple-element.5, %get-tuple-element.5)\n"
+            "}\n\n")
+    return text.replace("ENTRY", body + "ENTRY", 1)
+
+
+@pytest.mark.parametrize("operands,body_extra,loop_copies", [
+    ("%lo.1, %dynamic_update_slice.1, %dynamic_update_slice.2", "", 0),
+    ("%lo.1, %dynamic_update_slice.1, %copy.7", "", 1),
+    ("%lo.1, %dynamic_update_slice.1, %dynamic_update_slice.2",
+     f"  %copy.8 = {_PLANE} copy(%get-tuple-element.5)\n", 1),
+], ids=["planes-enter-as-they-are", "plane-enters-as-a-copy",
+        "plane-copied-in-the-body"])
+def test_block_loop_over_the_planes(tool, operands, body_extra, loop_copies):
+    facts = tool.inspect(_loop_hlo(operands, body_extra),
+                         {(32, 13, 1024, 128)})
+    assert facts["while_loops"] == 1
+    assert facts["while_plane_copies"] == loop_copies
+    assert facts["planes_aliased"] == facts["planes_total"] == 2
+    # %copy.7 stands in ENTRY in every case: a whole-plane copy, whether
+    # or not the loop takes it
+    assert facts["whole_plane_copies"] == 1
+    faults = tool._faults("step", facts)
+    assert len(faults) == 1 + bool(loop_copies)
+    assert any("while loop" in f for f in faults) == bool(loop_copies)

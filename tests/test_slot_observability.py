@@ -249,6 +249,62 @@ def test_server_reply_hold_is_the_wait_for_batch_mates(flags_guard):
         srv.stop()
 
 
+def test_attn_blocks_follow_the_frontier_and_the_oldest_live_start(
+        monkeypatch):
+    """The span the step's attention reads (ISSUE 28), over the run of
+    the test above that crosses a session restart, in blocks of 16: the
+    counters are the host's arithmetic on what each step was handed; a
+    row that is not generating is handed ``start = C``, so the device's
+    own bound (the lowest ``start`` of ALL rows) is the live rows'; the
+    span falls back to the first block when the ring restarts."""
+    from paddle_tpu.nn.functional import attention
+    monkeypatch.setattr(attention, "DECODE_BLOCK", 16)
+    loop = _loop(seed=39, slots_=2, cache_len=32)
+    seen, real = [], loop._step
+
+    def step(*args):
+        start, _finished, active, pos = args[-4:]
+        seen.append((int(pos), np.array(start), np.array(active)))
+        return real(*args)
+
+    loop._step = step
+    try:
+        futs = [loop.submit([1, 2, 3, 4, 5, 6], n) for n in (4, 20, 20)]
+        for f in futs:
+            f.result(timeout=120)
+    finally:
+        loop.close()
+    c = loop.stats()
+    assert c["session_resets"] == 1 and c["steps"] == len(seen)
+    assert c["attn_blocks_total"] == c["steps"] * 32 // 16
+    reads = []
+    for pos, start, active in seen:
+        assert (start[~active] == 32).all()
+        assert active.any() and (start[active] <= pos).all()
+        assert start.min() == start[active].min()
+        reads.append(pos // 16 + 1 - start[active].min() // 16)
+    assert c["attn_blocks_read"] == sum(reads)
+    restart = next(i for i in range(1, len(seen))
+                   if seen[i][0] < seen[i - 1][0])
+    assert reads[restart - 1] == 2 and reads[restart] == 1
+    assert 0 < c["attn_blocks_read"] < c["attn_blocks_total"]
+    loop.reset_stats()
+    c = loop.stats()
+    assert c["attn_blocks_read"] == c["attn_blocks_total"] == c["steps"] == 0
+
+
+def test_attn_blocks_are_counted_for_the_plain_step_over_kv_planes_only(
+        flags_guard):
+    """The int8 cache is dequantised whole and the speculative step's
+    verify block has no window: no span to report, so no counter."""
+    set_flags({"FLAGS_kv_cache_dtype": "int8"})
+    loop = _loop(seed=25)
+    try:
+        assert "attn_blocks_read" not in loop.stats()
+    finally:
+        loop.close()
+
+
 def test_reset_stats_zeroes_phases_and_slot_steps():
     loop = _loop(seed=25)
     try:

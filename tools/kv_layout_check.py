@@ -20,14 +20,19 @@ program) and prints, from ``compiled.as_text()``:
   * in each program, the ``copy``/``transpose`` instructions of a whole
     plane, and the copies of a cache row ``[1, ., C, .]`` whose operand
     has another layout (the relayout a lane-major plane forces on the
-    chunk's block write).
+    chunk's block write);
+  * the ``while`` loops of each program (the decode attention reads the
+    planes in column blocks under one, ``cached_attention``; the step's
+    line says how wide a block is): whether a plane enters one as a
+    copy, or is copied inside its body.
 
     JAX_PLATFORMS=cpu python3 tools/kv_layout_check.py gpt2-xl-serve [slots]
 
 Exit code 1 when a write's traced index lies on the minor-most dimension,
-a plane is not aliased, a whole plane is copied, or a cache row changes
-layout.  Run by hand, one process at a time: only one process may load
-libtpu, so this is not a pytest file.
+a plane is not aliased, a whole plane is copied (on its way into a loop
+and inside one too), or a cache row changes layout.  Run by hand, one
+process at a time: only one process may load libtpu, so this is not a
+pytest file.
 """
 from __future__ import annotations
 
@@ -45,13 +50,28 @@ _INSTR = re.compile(
     r"\{(?P<layout>[\d,]*)[^}]*\} (?P<op>[\w\-]+)\((?P<args>[^)]*)\)")
 
 
-def _entry(hlo_text):
-    """{name: (dims, minor_to_major, opcode, operand names, line)} of the
-    ENTRY computation's array-valued instructions."""
+# "%while.48 = (s32[], ..) while(%tuple.1252), condition=%c, body=%b" and
+# the "%tuple.1252 = (..) tuple(%a, %b, ..)" it takes
+_WHILE = re.compile(r"^\s*(?:ROOT )?%[\w.\-]+ = \(.*\) while\(%(?P<arg>[\w.\-]+)"
+                    r"\), condition=%[\w.\-]+, body=%(?P<body>[\w.\-]+)")
+_TUPLE = re.compile(r"^\s*(?:ROOT )?%(?P<name>[\w.\-]+) = \(.*\) "
+                    r"tuple\((?P<args>[^)]*)\)")
+
+
+def _body(hlo_text, name=None):
+    """The lines of one computation: ``name``'s, or ENTRY's."""
     lines = hlo_text.splitlines()
-    start = next(i for i, l in enumerate(lines) if l.startswith("ENTRY"))
+    head = "ENTRY" if name is None else f"%{name} ("
+    start = next(i for i, l in enumerate(lines) if l.startswith(head))
+    end = next(i for i in range(start, len(lines)) if lines[i] == "}")
+    return lines[start + 1:end]
+
+
+def _entry(hlo_text, name=None):
+    """{name: (dims, minor_to_major, opcode, operand names, line)} of the
+    ENTRY computation's array-valued instructions (or of ``name``'s)."""
     out = collections.OrderedDict()
-    for line in lines[start + 1:]:
+    for line in _body(hlo_text, name):
         m = _INSTR.match(line)
         if m is None:
             continue
@@ -60,6 +80,25 @@ def _entry(hlo_text):
         args = re.findall(r"%([\w.\-]+)", m["args"])
         out[m["name"]] = (dims, layout, m["op"], args, line)
     return out
+
+
+def _while_plane_copies(hlo_text, instrs, plane_shapes):
+    """(loops of ENTRY, [copies]): the operands of a loop's tuple that are
+    a copy or transpose of a whole plane, and such instructions inside the
+    loop's body."""
+    lines = _body(hlo_text)
+    tuples = {m["name"]: re.findall(r"%([\w.\-]+)", m["args"])
+              for m in map(_TUPLE.match, lines) if m}
+    loops, copies = 0, []
+    for m in filter(None, map(_WHILE.match, lines)):
+        loops += 1
+        inside = _entry(hlo_text, m["body"])
+        for where, names in ((instrs, tuples.get(m["arg"], ())),
+                             (inside, inside)):
+            copies += [n for n in names if n in where
+                       and where[n][0] in plane_shapes
+                       and where[n][2] in ("copy", "transpose")]
+    return loops, copies
 
 
 def _aliased_params(hlo_text):
@@ -97,7 +136,10 @@ def inspect(hlo_text, plane_shapes):
             plane_copies.append(name)
         elif dims in rows and src is not None and src[1] != layout:
             row_relayouts.append(name)
+    loops, loop_copies = _while_plane_copies(hlo_text, instrs, plane_shapes)
     return {
+        "while_loops": loops,
+        "while_plane_copies": len(loop_copies),
         "planes": [{"shape": list(s), "minor_to_major": list(l), "count": c}
                    for (s, l), c in layouts.items()],
         "planes_aliased": sum(1 for p in planes.values() if p in aliased),
@@ -124,6 +166,9 @@ def _faults(what, facts):
     if facts["whole_plane_copies"]:
         out.append(f"{what}: {facts['whole_plane_copies']} copies or "
                    "transposes of a whole plane")
+    if facts["while_plane_copies"]:
+        out.append(f"{what}: {facts['while_plane_copies']} whole planes "
+                   "copied into a while loop or inside one")
     if facts["row_relayout_copies"]:
         out.append(f"{what}: {facts['row_relayout_copies']} layout-changing "
                    "copies of a cache row")
@@ -141,6 +186,7 @@ def main(argv):
     from jax.experimental import topologies
     from jax.sharding import SingleDeviceSharding
     import importlib
+    from paddle_tpu.nn.functional.attention import decode_block
     from paddle_tpu.text.generation import Generator
     with open(os.path.join(ROOT, "benchmark", "configs",
                            argv[0] + ".json")) as f:
@@ -168,6 +214,9 @@ def main(argv):
         compiled = jax.jit(fn, donate_argnums=(2,)).lower(
             *state, *place(avals)).compile()
         facts = inspect(compiled.as_text(), plane_shapes)
+        if what == "step" and "kv" in gen.plane_kinds():
+            # the column blocks of the step's attention (cached_attention)
+            facts = {"attn_block": decode_block(C), **facts}
         print(json.dumps({"config": cfg["name"], "program": what,
                           "slots": S, "cache": C, **facts}), flush=True)
         mem = compiled.memory_analysis()
