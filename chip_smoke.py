@@ -107,8 +107,8 @@ def _moe_cfg(**kw) -> GPTMoEConfig:
     return cfg
 
 
-# bench.py's on-chip configurations (bench_bert, bench_decode, bench_moe);
-# the MoE stack keeps its width and is cut to 4 layers
+# BERT-base at batch 64 x 128, a 12 x 768 GPT decoder, a 512-wide MoE stack
+# of 16 experts cut to 4 layers
 FULL = Size(
     name="full", bert=BertConfig.base(), train_batch=64, train_seq=128,
     train_steps=6,
@@ -164,7 +164,7 @@ def _platforms(tree) -> set:
 # -- train --------------------------------------------------------------------
 
 def _bert_batch(cfg: BertConfig, batch: int, seq: int, seed: int):
-    """bench.py's BERT pretraining feed: fixed masked positions per row."""
+    """A BERT pretraining feed: fixed masked positions per row."""
     rng = np.random.RandomState(seed)
     ids = rng.randint(0, cfg.vocab_size, (batch, seq))
     n_pred = max(2, int(seq * 0.15))
@@ -197,8 +197,8 @@ def _run_steps(step: TrainStep, feed, n: int):
 
 
 def phase_train(size: Size, seed: int = 0) -> dict:
-    """BERT pretraining steps through init_mesh + TrainStep, as bench.py's
-    headline builds it: loss finite and falling, state on the device."""
+    """BERT pretraining steps through init_mesh + TrainStep: loss finite and
+    falling, state on the device."""
     t0 = time.perf_counter()
     platform = jax.devices()[0].platform
     step = _bert_step(size, init_mesh({"dp": -1}), seed)

@@ -311,49 +311,17 @@ class MultiHeadAttention(Layer):
             out = F.dropout(out, self.dropout, training=self.training)
         return self.out_proj(self._merge_heads(out)), cache
 
-    def _fused_qkv(self, x):
-        """Self-attention QKV as ONE (E, 3E) matmul: three 768^2 GEMMs
-        underfeed the MXU at BERT shapes; the fused form is the
-        operators/fused/ play (fused_attention's qkv_weight) done at trace
-        time — the concat of the three weight Tensors is fused away by XLA
-        and autograd splits the gradient back onto q/k/v_proj params."""
-        from ...ops import matmul
-        w = concat([self.q_proj.weight, self.k_proj.weight,
-                    self.v_proj.weight], axis=1)
-        out = matmul(x, w)
-        if self.q_proj.bias is not None:
-            out = out + concat([self.q_proj.bias, self.k_proj.bias,
-                                self.v_proj.bias], axis=0)
-        e = self.embed_dim
-        return out[:, :, :e], out[:, :, e:2 * e], out[:, :, 2 * e:]
-
     def forward(self, query, key=None, value=None, attn_mask=None, cache=None,
                 cache_position=None, decode_window=None):
-        import os
         if isinstance(cache, (self.RingCache, self.QuantRingCache)):
             return self._forward_ring(query, attn_mask, cache,
                                       cache_position, decode_window)
-        # measured on v5e (BERT-base b64 s128): fused 1040 seq/s vs three
-        # GEMMs 1092 — XLA already schedules the three projections well and
-        # the trace-time weight concat only adds traffic; keep the fused
-        # path opt-in for future shapes where it may invert
-        fuse_qkv = (key is None and value is None and cache is None
-                    and self.kdim == self.embed_dim
-                    and self.vdim == self.embed_dim
-                    and os.environ.get("PADDLE_TPU_FUSED_QKV", "0")
-                    not in ("", "0", "false", "False"))
         key = query if key is None else key
         value = key if value is None else value
-        if fuse_qkv:
-            qf, kf, vf = self._fused_qkv(query)
-            q = self._split_heads(qf)
-            k = self._split_heads(kf)
-            v = self._split_heads(vf)
-        else:
-            q = self._split_heads(self.q_proj(query))
+        q = self._split_heads(self.q_proj(query))
         if isinstance(cache, self.StaticCache):
             k, v = cache.k, cache.v
-        elif not fuse_qkv:
+        else:
             k = self._split_heads(self.k_proj(key))
             v = self._split_heads(self.v_proj(value))
             if isinstance(cache, self.Cache):

@@ -150,7 +150,7 @@ class WideDeepTrainer:
     - ``feature_wire_dtype`` ("float32" default — bit-identical numerics
       with pull/push mode) is the H2D dtype for dense features.  Pass
       "bfloat16" to halve the hot-path wire bytes (standard for
-      normalized CTR features; bench.py opts in explicitly).  Labels
+      normalized CTR features; the caller opts in explicitly).  Labels
       always travel f32."""
 
     def __init__(self, model: WideDeep, lr: float = 1e-3,
@@ -742,8 +742,8 @@ class WideDeepTrainer:
         """Seconds per device-side train step, measured as the DELTA of
         two chained in-graph loop lengths over the cached-mode fused step
         (one dispatch per K, loss riding the carry so no step can be
-        dead-code-eliminated — the bench.py/mfu_audit methodology).  This
-        is Wide&Deep's in-graph control number (VERDICT r5 #2/#8): what
+        dead-code-eliminated).  This is Wide&Deep's in-graph control
+        number (VERDICT r5 #2/#8): what
         the framework's compiled sparse+dense step costs with the host
         hash/dedup and per-step dispatch factored out."""
         import time
@@ -829,8 +829,7 @@ class WideDeepTrainer:
     def sharded_step_stats(self, sparse_ids, dense_x, labels):
         """Collective census of the compiled sharded step for this batch
         signature (AOT lower + compile, NO execution): per-kind counts,
-        result bytes and ring-model wire bytes — the bytes/step
-        accounting bench.py and PERF.md record.  Call with an
+        result bytes and ring-model wire bytes.  Call with an
         already-trained batch so the prep pass leaves cache state
         effectively unchanged (all ids hit)."""
         if not getattr(self, "_sharded", False):

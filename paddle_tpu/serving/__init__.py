@@ -24,7 +24,6 @@ owns them:
     per-clone IO buffers.
 
 Gates: ``FLAGS_serving_*`` (framework/flags.py).  CLI: ``tools/serve.py``.
-Bench: ``bench.py``'s ``serving`` block (sustained QPS + p50/p99 SLOs).
 """
 from __future__ import annotations
 

@@ -63,8 +63,7 @@ SESSION_RESTORE = _registry().counter(
 SESSION_STORE_BYTES = _registry().gauge(
     "session_store_bytes",
     "Bytes currently held by the session store (host-RAM snapshots plus "
-    "disk-spilled blobs); the capacity side of the ≥1000-parked-sessions "
-    "claim in bench.py prefix_cache.")
+    "disk-spilled blobs).")
 
 
 def _tree_nbytes(tree) -> int:

@@ -445,7 +445,7 @@ class Executor:
         """AOT-lower the scanned epoch program for ``dataset`` and return
         the compiled executable WITHOUT running the epoch — the
         lowered-executable access surface for the dataset-training engine
-        (the HLO audit and tools/mfu_audit.py read ``cost_analysis()`` /
+        (the HLO audit reads ``cost_analysis()`` /
         ``memory_analysis()`` / ``as_text()`` off it; the hand-maintained
         FLOP models this replaces could silently drift from the program).
 

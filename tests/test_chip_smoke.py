@@ -134,9 +134,6 @@ subprocess.Popen = subprocess.run = _probe
 """
 
 _PARENTS = {
-    "bench": "import bench; sys.argv = ['bench.py']; bench.main()",
-    # the startup workload's own child boots server processes
-    "bench_startup": "import bench; bench._run_one('startup')",
     "serve_router": (
         "import serve; serve.main(['--router', '--replicas', '2', "
         "'--duration', '0.1'])"),

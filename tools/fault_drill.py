@@ -28,9 +28,9 @@ Scenarios:
                      checkpoint and finish with params identical to an
                      uninterrupted run.
 
-``--dry`` keeps every scenario at toy scale (tier-1 CPU semantics, the
-shape ``tools/mfu_audit.py --dry`` set); there is currently no chip-scale
-wet mode, the flag exists for CLI symmetry and future growth.
+``--dry`` keeps every scenario at toy scale (tier-1 CPU semantics); there
+is currently no chip-scale wet mode, the flag exists for CLI symmetry and
+future growth.
 """
 from __future__ import annotations
 

@@ -46,7 +46,7 @@ def build_resnet_block(batch=4, ch=8, hw=8):
     import paddle_tpu.nn as nn
 
     class Block(nn.Layer):
-        """One residual conv-BN-ReLU pair (bench.py's high-res stage)."""
+        """One residual conv-BN-ReLU pair (ResNet's high-res stage)."""
 
         def __init__(self):
             super().__init__()

@@ -111,7 +111,7 @@ def _build_resnet_block(mesh, zero, ch=8, hw=8):
     from paddle_tpu.parallel import TrainStep
 
     class Block(nn.Layer):
-        """Residual conv-BN-ReLU pair + linear head (bench.py's high-res
+        """Residual conv-BN-ReLU pair + linear head (ResNet's high-res
         stage with a classification tail so it trains end-to-end)."""
 
         def __init__(self):

@@ -55,7 +55,7 @@ def build_resnet_block():
     ch = _BLOCK_CH
 
     class Block(nn.Layer):
-        """One residual conv-BN-ReLU pair (bench.py's high-res stage)."""
+        """One residual conv-BN-ReLU pair (ResNet's high-res stage)."""
 
         def __init__(self):
             super().__init__()
