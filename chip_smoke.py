@@ -1,7 +1,8 @@
 #!/usr/bin/env python
 """chip_smoke.py — the quickest proof that the system starts on the chip.
 
-    python chip_smoke.py              # one chip: train, serve, kernels, cache
+    python chip_smoke.py              # one chip: train, serve, layouts,
+                                      # kernels, cache
     python chip_smoke.py --multichip  # four chips: only the sharded paths
 
 One process, the entry points a user calls (``parallel.TrainStep``,
@@ -372,7 +373,7 @@ def phase_serve(size: Size, seed: int = 0) -> dict:
                                        if k != "tokens"}
                  for s, r in runs.items()},
         "zero_steady_state_recompiles": True, "kv_platform": platform})
-    return {"record": rec, "model": model, "prompts": prompts,
+    return {"record": rec, "seed": seed, "prompts": prompts,
             "tokens": {s: r["tokens"] for s, r in runs.items()},
             "exec_dir": exec_dir}
 
@@ -387,8 +388,11 @@ def phase_cache(size: Size, served: dict) -> dict:
     try:
         set_flags({"FLAGS_executable_cache": "readwrite",
                    "FLAGS_executable_cache_dir": served["exec_dir"]})
+        # the same weights as a new process holds them: in their default
+        # layouts (the first boot's slot loop relaid its own model's)
+        model = _gpt(size, served["seed"])
         for slots, first in served["tokens"].items():
-            srv, warm_s, events = _boot(served["model"], size, slots)
+            srv, warm_s, events = _boot(model, size, slots)
             try:
                 toks = _serve(srv, size, served["prompts"])
             finally:
@@ -406,6 +410,71 @@ def phase_cache(size: Size, served: dict) -> dict:
     return _emit("cache", t0, 0.0, {
         "executables_loaded": loads, "fresh_compiles": 0,
         "second_boot_seconds": round(load_s, 3), "tokens_bit_equal": True})
+
+
+def phase_layouts(size: Size, seed: int = 0) -> dict:
+    """The slot step and chunk over RELAID weights (``Generator.slot_execs``:
+    each weight lying the way both programs contract over it) against the
+    same programs over the weights in their default layouts: last-column
+    logits and tokens bit for bit.  A layout change is where a miscompile
+    that only the chip shows would hide; a relaid weight holds the same
+    bits, so nothing may move."""
+    t0 = time.perf_counter()
+    S, C, T, steps = size.slots, size.serve_max_len, 16, 8
+    gens = [Generator(_gpt(size, seed), seq_buckets=size.serve_seq_buckets,
+                      max_len=C) for _ in range(2)]
+    plain, relaid = gens
+    t1 = time.perf_counter()
+    programs = [(plain.step_exec(S, C), plain.chunk_exec(S, T, C)),
+                relaid.slot_execs(S, T, C)]
+    compile_s = time.perf_counter() - t1
+    _check(plain.weights_layout["weights_relaid"] == 0 and not plain._formats,
+           "layouts: a program compiled alone relaid weights")
+    n = relaid.weights_layout["weights_relaid"]
+    if jax.devices()[0].platform == "tpu":
+        _check(n > 0, "layouts: the chip's compiler wanted every weight as "
+                      "it lay, so the comparison compares nothing")
+    # every weight lies where the served programs take it: an argument in
+    # another layout would be relaid by the runtime in every call
+    for ex in programs[1]:
+        for name, fmt in ex.input_formats[0][0].items():
+            _check(fmt.layout in (None, relaid._params[name].format.layout),
+                   f"layouts: {name} lies otherwise than its program takes it")
+    prompt = np.asarray(_prompts(size, seed + 2)[0][:T], np.int32)
+    ids = np.zeros((1, T), np.int32)
+    ids[0, T - len(prompt):] = prompt
+    start = np.full((S,), C, np.int32)      # rows not generating: no window
+    start[0] = T - len(prompt)
+    active = np.zeros((S,), bool)
+    active[0] = True
+    outs = []
+    for gen, (step, chunk) in zip(gens, programs):
+        cache = gen.init_slot_cache(S, C)
+        cache, last = chunk(*gen._state_args(), cache, jnp.asarray(ids),
+                            jnp.asarray(start[:1]), jnp.int32(0),
+                            jnp.int32(0))
+        logits = jnp.zeros((S, size.gpt.vocab_size), jnp.float32) \
+            .at[0].set(last)
+        finished = jnp.zeros((S,), bool)
+        toks = []
+        for k in range(steps):
+            cache, logits, finished, tok = step(
+                *gen._state_args(), cache, logits, jnp.asarray(start),
+                finished, jnp.asarray(active), jnp.int32(T + k))
+            toks.append(int(tok[0]))
+        outs.append((np.asarray(last), toks, np.asarray(logits[0])))
+    (last_p, toks_p, log_p), (last_r, toks_r, log_r) = outs
+    _check(np.isfinite(last_p).all() and np.isfinite(log_p).all(),
+           "layouts: non-finite logits")
+    _check(np.array_equal(last_p, last_r),
+           "layouts: the chunk's last-column logits moved with the layouts")
+    _check(toks_p == toks_r, f"layouts: tokens moved: {toks_p} vs {toks_r}")
+    _check(np.array_equal(log_p, log_r),
+           "layouts: the step's logits moved with the layouts")
+    return _emit("layouts", t0, compile_s, {
+        "model": f"gpt {size.name} bf16", "slots": S, "cache": C, "chunk": T,
+        "steps": steps, **relaid.weights_layout, "tokens": toks_r,
+        "chunk_logits_bit_equal": True, "step_logits_bit_equal": True})
 
 
 # -- kernels ------------------------------------------------------------------
@@ -722,6 +791,7 @@ def main(argv=None) -> int:
     else:
         phase_train(FULL, args.seed)
         served = phase_serve(FULL, args.seed)
+        phase_layouts(FULL, args.seed)
         phase_kernels(FULL, args.seed)
         phase_cache(FULL, served)
     print(json.dumps({"ok": True, "device": device}), flush=True)

@@ -472,7 +472,8 @@ def load_or_compile(lower: Callable[[], Any], *, site: str, kind: str,
                     extra: Optional[dict] = None,
                     ledger_miss: bool = True,
                     cache: Optional[ExecutableCache] = None,
-                    writable: Optional[bool] = None):
+                    writable: Optional[bool] = None,
+                    events: Optional[list] = None):
     """Consult the cache, else compile (and store under readwrite).
 
     ``lower`` runs the cold path: () -> ``jax.stages.Compiled``.  On a
@@ -485,15 +486,20 @@ def load_or_compile(lower: Callable[[], Any], *, site: str, kind: str,
 
     ``cache``/``writable`` override the flag-configured cache — the
     Executor's legacy per-predictor optim-cache dir passes its own.
+    ``events``, a list, receives the ledger event this call records (a
+    caller that learns more about the program later adds it there).
     """
+    def ledger(kind_, ms, extra_):
+        ev = _ledger.record_compile(site, kind_, key, ms, extra=extra_)
+        if events is not None:
+            events.append(ev)
+
     c = cache if cache is not None else get_cache()
     if c is None:                      # the one off-path branch
         t0 = time.perf_counter()
         compiled = lower()
         if ledger_miss:
-            _ledger.record_compile(site, kind, key,
-                                   (time.perf_counter() - t0) * 1e3,
-                                   extra=extra)
+            ledger(kind, (time.perf_counter() - t0) * 1e3, extra)
         return compiled, False
     digest = digest_for(key, extra_key)
     t0 = time.perf_counter()
@@ -503,16 +509,13 @@ def load_or_compile(lower: Callable[[], Any], *, site: str, kind: str,
         note_hit(kind, dt)
         ex = dict(extra or {})
         ex.update({"orig_kind": kind, "digest": digest[:16]})
-        _ledger.record_compile(site, "cache_load", key, dt * 1e3,
-                               extra=ex)
+        ledger("cache_load", dt * 1e3, ex)
         return loaded, True
     note_miss(kind)
     t0 = time.perf_counter()
     compiled = lower()
     if ledger_miss:
-        _ledger.record_compile(site, kind, key,
-                               (time.perf_counter() - t0) * 1e3,
-                               extra=extra)
+        ledger(kind, (time.perf_counter() - t0) * 1e3, extra)
     w = writable if writable is not None else (mode() == "readwrite")
     if w:
         c.store(digest, compiled, key=key, site=site, kind=kind,

@@ -315,8 +315,8 @@ class _DecodeRuntime:
         S, C, T = self.slots, self._slot_cache, self.chunk_width
         self.lint_gate_slot(S, C)
         eos = self.spec.eos_token_id
-        self._audit_gate(self.gen.step_exec(S, C, eos), S, None)
-        self._audit_gate(self.gen.chunk_exec(S, T, C), S, None)
+        for ex in self.gen.slot_execs(S, T, C, eos):
+            self._audit_gate(ex, S, None)
         if bool(_flags.flag("prefix_cache")):
             import jax.tree_util as tu
             from .cluster.handoff import _np_dtype

@@ -7,11 +7,15 @@ This module hoists the token loop onto the HOST — Orca-style iteration-
 level scheduling — over TWO slot executables the Generator compiles per
 (slot-count, cache-bucket):
 
-  * ``step_exec(S, C)``: one greedy token step for all ``S`` slot rows
-    (or one speculative propose/verify/accept step under a draft pair);
-  * ``chunk_exec(S, T, C)``: one Sarathi-style prefill chunk — ``T``
-    prompt tokens of ONE joining row, interleaved between decode steps
-    so long prompts never stall the running rows' token cadence.
+  * the step: one greedy token step for all ``S`` slot rows (or one
+    speculative propose/verify/accept step under a draft pair);
+  * the chunk: one Sarathi-style prefill chunk — ``T`` prompt tokens of
+    ONE joining row, interleaved between decode steps so long prompts
+    never stall the running rows' token cadence.
+
+They come as a pair (``Generator.slot_execs(S, T, C)``): both run
+thousands of times over one copy of the weights, so the pair first
+settles in which device layout each weight lies.
 
 The scheduling invariants that make slot reuse BIT-EXACT against a
 per-request ``generate()`` of the same prompt:
@@ -239,8 +243,8 @@ class SlotLoop:
             require_kv_planes(self._plane_kinds)
         # compiled once here (ledgered compile or warm cache hit); every
         # later dispatch is a plain __call__ — zero steady-state compiles
-        self._step = gen.step_exec(self.S, self.C, eos_token_id)
-        self._chunk = gen.chunk_exec(self.S, self.T, self.C)
+        self._step, self._chunk = gen.slot_execs(self.S, self.T, self.C,
+                                                 eos_token_id)
         self._kv_heads_per_lane_row = gen.kv_heads_per_lane_row()
         # the KV reuse plane (prefix cache / session store): its three
         # data movers compile HERE, with the step/chunk programs, so an
@@ -1212,6 +1216,9 @@ class SlotLoop:
             wins = dict(self._phase_win)
         out = {"slots": self.S, "cache": self.C, "chunk": self.T,
                "kv_heads_per_lane_row": self._kv_heads_per_lane_row,
+               # the weights the step and the chunk agreed to have relaid
+               # (Generator.slot_execs), and those they disagreed on
+               **self._gen.weights_layout,
                "plane_kinds": list(self._plane_kinds),
                "occupancy_ewma": round(self._occupancy, 4), **c,
                # the driver's seconds by phase, and the phases of the

@@ -43,6 +43,7 @@ import numpy as np
 import jax
 import jax.numpy as jnp
 from jax import lax
+from jax.experimental.layout import Format, Layout
 
 from ..framework import core
 from ..framework import flags as _flags
@@ -56,11 +57,83 @@ from ..profiler import ledger as _ledger
 from ..profiler import tracing as _tracing
 from ..serving.bucketing import BucketLadder
 
-__all__ = ["Generator", "generate"]
+__all__ = ["Generator", "generate", "agree_layouts"]
+
+# the key suffix of a slot program compiled with the weights' layouts left
+# to the compiler (``Generator.slot_execs``)
+_FREE_WEIGHTS = (("arg:weights", "auto"),)
 
 
-def _aval(a):
-    return jax.ShapeDtypeStruct(tuple(a.shape), a.dtype)
+def _aval(a, fmt=None):
+    """``a``'s abstract value; with ``fmt`` a program lowered from it takes
+    that argument in that device format and no other."""
+    return jax.ShapeDtypeStruct(tuple(a.shape), a.dtype, sharding=fmt)
+
+
+def _default_layout(a, device=None):
+    """The device layout a freshly made array of ``a``'s shape and dtype
+    has on ``device`` (``a``'s own where none is given): on the TPU it
+    depends on the shape."""
+    d = device if device is not None else next(iter(a.devices()))
+    return Layout.from_pjrt_layout(
+        d.client.get_default_layout(a.dtype, tuple(a.shape), d))
+
+
+def _own_format(a):
+    """``a``'s device format where it does not lie in its shape's default
+    layout (a weight that some Generator has relaid), else None."""
+    fmt = a.format
+    return None if fmt.layout == _default_layout(a) else fmt
+
+
+def _as_placed(x):
+    return x
+
+
+def _relay(a, fmt):
+    """``a`` in the device format ``fmt`` (the same bits, laid otherwise),
+    landed.  ``jax.device_put(a, fmt)`` is a jitted identity with an
+    output layout, and an executable that JAX's persistent compilation
+    cache hands back labels its results with their shapes' DEFAULT
+    layouts whatever it was compiled for (seen on the chip, PR 30: the
+    bytes relaid, ``format`` saying they are not, every later call
+    refused or, worse, fed transposed bytes).  So this program is never
+    written there (three shapes a model, compiled in a moment), and a
+    result that does not say what was asked for is refused here."""
+    floor = "jax_persistent_cache_min_compile_time_secs"
+    prev = getattr(jax.config, floor)
+    jax.config.update(floor, 1e30)
+    try:
+        new = jax.block_until_ready(
+            jax.jit(_as_placed, out_shardings=fmt)(a))
+    finally:
+        jax.config.update(floor, prev)
+    if new.format.layout != fmt.layout:
+        raise PreconditionNotMetError(
+            f"a weight of shape {tuple(a.shape)} asked for in layout "
+            f"{fmt.layout} came back as {new.format.layout}: the runtime "
+            "does not keep the layouts of this program's results")
+    return new
+
+
+def agree_layouts(have, *chosen):
+    """The rule by which programs that share ONE copy of the weights
+    settle where each lies.  ``have``: the layout each array has;
+    ``chosen``: per program, the layout its compile chose with the
+    weights' layouts left free (None where the program does not read the
+    weight: no wish).  A weight is relaid only where every program that
+    reads it chose the same layout and the array has another; where they
+    disagree it stays as it is (each program is then compiled for the
+    layout the array has).  Returns ``(relaid, disagreed)``:
+    ``{key: layout}`` and the keys left alone for disagreement."""
+    relaid, disagreed = {}, []
+    for k, h in have.items():
+        wishes = {c[k] for c in chosen if c[k] is not None}
+        if len(wishes) > 1:
+            disagreed.append(k)
+        elif wishes and wishes != {h}:
+            relaid[k] = wishes.pop()
+    return relaid, disagreed
 
 
 def _rebuild_ring(layer, cache):
@@ -170,6 +243,16 @@ class Generator:
             {b for b in ladder.buckets if b <= self._max_len}
             | {self._max_len})
         self._execs = {}
+        # the device formats of the weights that do NOT lie in their
+        # shape's default layout, keyed (position among the state
+        # arguments, parameter name); every other weight, every buffer
+        # and everything under a mesh is in the default one.  Settled
+        # once: by the first program compiled against the state, or by
+        # slot_execs(), which asks the compiler first.
+        self._formats = None
+        self._settled = False
+        self.weights_layout = {"weights_relaid": 0, "weights_relaid_mb": 0.0,
+                               "weights_layout_disagreed": 0}
         self.refresh_state()
 
     @property
@@ -180,17 +263,72 @@ class Generator:
     def seq_buckets(self):
         return list(self._seq_buckets)
 
+    def _models(self):
+        """The models whose (params, buffers) pairs lead every generate
+        program's arguments, in that order — the speculative subclass
+        appends its draft."""
+        return (self._layer,)
+
+    @property
+    def _params(self):
+        return self._state[0]
+
+    @property
+    def _buffers(self):
+        return self._state[1]
+
     def refresh_state(self):
         """Re-snapshot params/buffers from the live layer (after training
         or loading).  Shapes are unchanged, so no recompile — the fresh
-        arrays just flow through the existing executables."""
-        self._params, self._buffers = layer_state(self._layer)
+        arrays just flow through the existing executables, each placed
+        in the device format those were compiled for."""
+        old = getattr(self, "_state", ())
+        self._state = tuple(t for m in self._models()
+                            for t in layer_state(m))
         if self._mesh is not None:
-            self._params = {n: jax.device_put(
-                v, self._sharding(self._param_specs.get(n)))
-                for n, v in self._params.items()}
-            self._buffers = {n: jax.device_put(v, self._sharding())
-                             for n, v in self._buffers.items()}
+            self._state = (
+                {n: jax.device_put(
+                    v, self._sharding(self._param_specs.get(n)))
+                 for n, v in self._state[0].items()},
+                {n: jax.device_put(v, self._sharding())
+                 for n, v in self._state[1].items()})
+            return
+        first = self._formats is None
+        if first:
+            self._formats = {}
+        todo = {}
+        for i, tree in enumerate(self._state):
+            if i % 2:
+                continue                    # buffers keep their layouts
+            for n, a in tree.items():
+                if i < len(old) and old[i].get(n) is a:
+                    continue                # placed with the last snapshot
+                own = _own_format(a)
+                if first:
+                    if own is not None:     # relaid by another Generator
+                        self._formats[i, n] = own
+                    continue
+                want = self._formats.get((i, n))
+                if (own and own.layout) != (want and want.layout):
+                    todo[i, n] = want or Format(_default_layout(a),
+                                                a.sharding)
+        self._place(todo)
+
+    def _place(self, formats):
+        """Place the weights ``{(i, name): format}`` of the snapshot one
+        at a time; the snapshot and the bound layer drop each original as
+        they go and a copy has landed before the next begins, so the
+        transient is one weight, not the model.  Returns the bytes
+        placed."""
+        if not formats:
+            return 0
+        bound = [dict(m.named_parameters()) for m in self._models()]
+        nbytes = 0
+        for (i, n), fmt in formats.items():
+            new = _relay(self._state[i][n], fmt)
+            self._state[i][n] = bound[i // 2][n]._value = new
+            nbytes += int(new.nbytes)
+        return nbytes
 
     # -- sharded-serving layout (serving/cluster/sharding.py) ----------------
     def _sharding(self, spec=None):
@@ -411,39 +549,114 @@ class Generator:
 
         return chunk
 
-    def step_exec(self, S, C, eos_token_id=None):
-        """AOT single-step decode executable over ``S`` slots at cache
-        bucket ``C`` (ledger kind ``generate_step``) — the slot loop's
-        hot dispatch."""
+    def _require_unsharded_slots(self):
         if self._mesh is not None:
             raise InvalidArgumentError(
                 "slot decode (FLAGS_decode_slots) runs per-replica "
                 "unsharded — drop the mesh or the slot loop")
+
+    def _step_program(self, S, C, eos_token_id=None):
+        """What ``_compile_slot`` needs for the slot step over ``S`` slots
+        at cache bucket ``C``: ``(key, ledger kind, program, non-state
+        avals, extra, donated arguments)``.  A plain tuple, so that the
+        AST lint's resolver (analysis/ast_lint.py) follows the program
+        from its builder to ``jax.jit``."""
+        self._require_unsharded_slots()
         end = -1 if eos_token_id is None else int(eos_token_id)
-        key = self._key("step2", S, None, C, 1, 1, end)
-        fn = self._build_step(S, C, end)
-        return self._compile(key, "generate_step", fn,
-                             self.step_avals(S, C),
-                             {"slots": S, "cache": C, "eos": end,
-                              "kv_heads_per_lane_row":
-                                  self.kv_heads_per_lane_row()},
-                             donate_argnums=(2,))
+        return (self._key("step2", S, None, C, 1, 1, end), "generate_step",
+                self._build_step(S, C, end), self.step_avals(S, C),
+                {"slots": S, "cache": C, "eos": end,
+                 "kv_heads_per_lane_row": self.kv_heads_per_lane_row()},
+                (2,))
+
+    def _chunk_program(self, S, T, C):
+        """The same for the prefill chunk of width ``T`` (ledger kind
+        ``generate_chunk``)."""
+        self._require_unsharded_slots()
+        return (self._key("chunk2", S, T, C, None, None), "generate_chunk",
+                self._build_chunk(S, T, C), self.chunk_avals(S, T, C),
+                {"slots": S, "chunk": T, "cache": C,
+                 "kv_heads_per_lane_row": self.kv_heads_per_lane_row()},
+                (2,))
+
+    def step_exec(self, S, C, eos_token_id=None):
+        """AOT single-step decode executable over ``S`` slots at cache
+        bucket ``C`` — the slot loop's hot dispatch, for the weights as
+        they lie (``slot_execs`` is the pair that settles where)."""
+        return self._compile_slot(self._step_program(S, C, eos_token_id))
 
     def chunk_exec(self, S, T, C):
         """AOT prefill-chunk executable over ``S`` slots at chunk width
-        ``T`` and cache bucket ``C`` (ledger kind ``generate_chunk``)."""
-        if self._mesh is not None:
-            raise InvalidArgumentError(
-                "slot decode (FLAGS_decode_slots) runs per-replica "
-                "unsharded — drop the mesh or the slot loop")
-        key = self._key("chunk2", S, T, C, None, None)
-        fn = self._build_chunk(S, T, C)
-        return self._compile(key, "generate_chunk", fn,
-                             self.chunk_avals(S, T, C),
-                             {"slots": S, "chunk": T, "cache": C,
-                              "kv_heads_per_lane_row":
-                                  self.kv_heads_per_lane_row()},
-                             donate_argnums=(2,))
+        ``T`` and cache bucket ``C``, for the weights as they lie."""
+        return self._compile_slot(self._chunk_program(S, T, C))
+
+    def _compile_slot(self, prog, free=False, events=None):
+        """Compile a slot program: for the weights as they lie, or with
+        their layouts left ``free``; its ledger event says how many of
+        them ``slot_execs`` relaid."""
+        key, kind, fn, avals, extra, donate = prog
+        return self._compile(key + _FREE_WEIGHTS if free else key, kind, fn,
+                             avals, {**extra, **self.weights_layout},
+                             donate_argnums=donate, free=free, events=events)
+
+    def slot_execs(self, S, T, C, eos_token_id=None):
+        """The slot loop's ``(step, chunk)`` pair, with every weight
+        lying the way both programs contract over it.
+
+        The two run thousands of times a second over ONE copy of the
+        weights, so a weight that a program wants in another layout than
+        it has is transposed again in every run.  Where nothing is
+        compiled against the state yet, both are first compiled with the
+        layouts of the parameters left to the compiler (buffers, cache
+        planes and every other argument keep theirs) and
+        :func:`agree_layouts` decides: a weight both want alike, and
+        otherwise than it lies, is relaid once, here; the rest stay, and
+        a program that wanted one of those otherwise is compiled again
+        for the weights as they lie.  A program whose every wish was
+        granted IS the final one: no further compile, and a warm start
+        reads the same decision off the loaded executables' formats.
+        Every program compiled afterwards takes the state as placed
+        (``_state_avals``)."""
+        progs = (self._step_program(S, C, eos_token_id),
+                 self._chunk_program(S, T, C))
+        if self._settled:
+            return tuple(self._compile_slot(p) for p in progs)
+        events = []
+        free = [self._compile_slot(p, free=True, events=events)
+                for p in progs]
+        n = len(self._state)
+        chosen = [{(i, name): f.layout
+                   for i, tree in enumerate(ex.input_formats[0][:n])
+                   if not i % 2 for name, f in tree.items()} for ex in free]
+        have = self._held_layouts()
+        relaid, disagreed = agree_layouts(have, *chosen)
+        placed = {(i, name): Format(lay, self._state[i][name].sharding)
+                  for (i, name), lay in relaid.items()}
+        nbytes = self._place(placed)
+        # from here on the state's formats are fixed for this Generator's life
+        self._formats.update(placed)
+        self._settled = True
+        self.weights_layout = {
+            "weights_relaid": len(relaid),
+            "weights_relaid_mb": round(nbytes / 1e6, 3),
+            "weights_layout_disagreed": len(disagreed)}
+        for ev in events:
+            ev.update(self.weights_layout)
+        out = []
+        for p, ex, wish in zip(progs, free, chosen):
+            if all(wish[k] in (None, relaid.get(k, have[k])) for k in have):
+                self._execs[p[0]] = ex
+            else:
+                ex = self._compile_slot(p)
+            out.append(ex)
+        return tuple(out)
+
+    def _held_layouts(self):
+        """``{(i, name): layout}`` of every weight of the snapshot as it
+        lies on the device."""
+        return {(i, name): a.format.layout
+                for i, tree in enumerate(self._state) if not i % 2
+                for name, a in tree.items()}
 
     def step_avals(self, S, C):
         """Non-state avals of the slot step program (cache, logits,
@@ -599,8 +812,10 @@ class Generator:
 
     def _state_avals(self):
         """Avals of the leading state arguments every generate program
-        takes (params, buffers) — the speculative subclass appends the
-        draft model's pair.  Under a mesh the avals carry the param
+        takes: a (params, buffers) pair per model of ``_models()``.  A
+        weight that lies otherwise than in its shape's default layout
+        carries its format, so a program lowered from these takes the
+        state as it is placed.  Under a mesh the avals carry the param
         shardings, so the AOT programs lower SPMD."""
         if self._mesh is not None:
             return ({n: jax.ShapeDtypeStruct(
@@ -610,11 +825,12 @@ class Generator:
                     {n: jax.ShapeDtypeStruct(tuple(a.shape), a.dtype,
                                              sharding=self._sharding())
                      for n, a in self._buffers.items()})
-        return (jax.tree_util.tree_map(_aval, self._params),
-                jax.tree_util.tree_map(_aval, self._buffers))
+        return tuple({n: _aval(a, self._formats.get((i, n)))
+                      for n, a in tree.items()}
+                     for i, tree in enumerate(self._state))
 
     def _state_args(self):
-        return (self._params, self._buffers)
+        return self._state
 
     def _program_identity(self):
         """Restart-stable architecture identity for the persistent
@@ -641,14 +857,44 @@ class Generator:
                 *((("step_passes_rows", True),) if getattr(
                     self._layer, "cached_forward_takes_rows", False)
                   else ()),
+                # a program compiled for relaid weights takes no others
+                *((("weight_formats", tuple(sorted(
+                    (k, repr(f.layout)) for k, f in self._formats.items()))),)
+                  if self._formats else ()),
                 *mesh_id)
 
+    def _lower(self, fn, arg_avals, jit_kw, free=False):
+        """Lower and compile ``fn(*state, *args)``.  ``free`` leaves the
+        device layout of every parameter to the compiler
+        (``Layout.AUTO``); the compiled program's ``input_formats`` say
+        what it chose."""
+        state = self._state_avals()
+        if free:
+            def where(a):           # an aval's sharding without its layout
+                s = a.sharding
+                return s.sharding if isinstance(s, Format) else s
+            state = tuple(
+                tree if i % 2 else {n: _aval(a, where(a))
+                                    for n, a in tree.items()}
+                for i, tree in enumerate(state))
+            auto = tuple(
+                None if i % 2 else {n: Format(Layout.AUTO, a.sharding)
+                                    for n, a in tree.items()}
+                for i, tree in enumerate(state))
+            jit_kw = dict(jit_kw,
+                          in_shardings=auto + (None,) * len(arg_avals))
+        return jax.jit(fn, **jit_kw).lower(*state, *arg_avals).compile()
+
     def _compile(self, key, kind, fn, arg_avals, extra,
-                 out_shardings=None, donate_argnums=None):
+                 out_shardings=None, donate_argnums=None, free=False,
+                 events=None):
         ex = self._execs.get(key)
         if ex is not None:
             _ledger.record_cache_hit(self._site)
             return ex
+        # whatever is compiled for the weights as they lie fixes where
+        # they lie (slot_execs relays only while nothing is)
+        self._settled = self._settled or not free
         from ..jit import persistent_cache as _pcache
         jit_kw = {} if out_shardings is None \
             else {"out_shardings": out_shardings}
@@ -659,10 +905,10 @@ class Generator:
             # copies (the host never reuses the donated handle)
             jit_kw["donate_argnums"] = donate_argnums
         ex, _loaded = _pcache.load_or_compile(
-            lambda: jax.jit(fn, **jit_kw).lower(*self._state_avals(),
-                                                *arg_avals).compile(),
+            lambda: self._lower(fn, arg_avals, jit_kw, free),
             site=self._site, kind=kind, key=key,
-            extra_key=self._program_identity(), extra=extra)
+            extra_key=self._program_identity(), extra=extra,
+            events=events)
         self._execs[key] = ex
         return ex
 

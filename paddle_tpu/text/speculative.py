@@ -57,11 +57,10 @@ from jax import lax
 
 from ..framework import flags as _flags
 from ..framework.enforce import InvalidArgumentError as _InvalidArgument
-from ..framework.functional import layer_state as _layer_state
 from ..profiler import tracing as _tracing
 from ..profiler.metrics import default_registry as _registry
 from .generation import Generator as _Generator
-from .generation import (_apply_layer, _aval, _slice_row, _splice_row)
+from .generation import _apply_layer, _slice_row, _splice_row
 
 __all__ = ["SpeculativeGenerator"]
 SPEC_PROPOSED = _registry().counter(
@@ -139,17 +138,16 @@ class SpeculativeGenerator(_Generator):
     def gamma(self) -> int:
         return self._gamma
 
-    def refresh_state(self):
-        super().refresh_state()
-        self._d_params, self._d_buffers = _layer_state(self._draft)
+    def _models(self):
+        return (self._layer, self._draft)
 
-    def _state_avals(self):
-        return super()._state_avals() + (
-            jax.tree_util.tree_map(_aval, self._d_params),
-            jax.tree_util.tree_map(_aval, self._d_buffers))
+    @property
+    def _d_params(self):
+        return self._state[2]
 
-    def _state_args(self):
-        return super()._state_args() + (self._d_params, self._d_buffers)
+    @property
+    def _d_buffers(self):
+        return self._state[3]
 
     def cache_bucket(self, prefill: int, steps: int) -> int:
         """The verify block overshoots the requested steps by up to
@@ -351,27 +349,22 @@ class SpeculativeGenerator(_Generator):
 
         return chunk
 
-    def step_exec(self, S, C, eos_token_id=None):
-        """AOT single speculative step over ``S`` slots (ledger kind
+    def _step_program(self, S, C, eos_token_id=None):
+        """The single speculative step over ``S`` slots (ledger kind
         ``spec_step``)."""
         end = -1 if eos_token_id is None else int(eos_token_id)
-        key = self._key("step2", S, None, C, 1, 1, end)
-        fn = self._build_step(S, C, end)
-        return self._compile(key, "spec_step", fn, self.step_avals(S, C),
-                             {"slots": S, "cache": C, "eos": end,
-                              "gamma": self._gamma},
-                             donate_argnums=(4,))
+        return (self._key("step2", S, None, C, 1, 1, end), "spec_step",
+                self._build_step(S, C, end), self.step_avals(S, C),
+                {"slots": S, "cache": C, "eos": end, "gamma": self._gamma},
+                (4,))
 
-    def chunk_exec(self, S, T, C):
-        """AOT joint prefill-chunk executable over ``S`` slots (ledger
-        kind ``spec_chunk``)."""
-        key = self._key("chunk2", S, T, C, None, None)
-        fn = self._build_chunk(S, T, C)
-        return self._compile(key, "spec_chunk", fn,
-                             self.chunk_avals(S, T, C),
-                             {"slots": S, "chunk": T, "cache": C,
-                              "gamma": self._gamma},
-                             donate_argnums=(4,))
+    def _chunk_program(self, S, T, C):
+        """The joint prefill chunk over ``S`` slots (ledger kind
+        ``spec_chunk``)."""
+        return (self._key("chunk2", S, T, C, None, None), "spec_chunk",
+                self._build_chunk(S, T, C), self.chunk_avals(S, T, C),
+                {"slots": S, "chunk": T, "cache": C, "gamma": self._gamma},
+                (4,))
 
     def step_avals(self, S, C):
         """Non-state avals of the speculative slot step (cache pair,

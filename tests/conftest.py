@@ -16,3 +16,34 @@ if "xla_force_host_platform_device_count" not in _flags:
 import jax  # noqa: E402
 
 assert jax.devices()[0].platform == "cpu", "tests must run on CPU backend"
+
+
+import pytest  # noqa: E402
+
+
+@pytest.fixture()
+def weight_wishes(monkeypatch):
+    """``weight_wishes(pick)``: make the free compile of the slot programs
+    (``Generator.slot_execs``) ask for the transposed layout of the 2-D
+    weights that ``pick(program, name)`` names.  The CPU compiler asks for
+    nothing but default layouts, but honours an explicit one, so this is a
+    real program that takes no other layout."""
+    from jax.experimental.layout import Format, Layout
+    from paddle_tpu.text.generation import Generator
+    real = Generator._lower
+
+    def install(pick):
+        def lower(self, fn, arg_avals, jit_kw, free=False):
+            if not free:
+                return real(self, fn, arg_avals, jit_kw)
+            state = self._state_avals()
+            fmts = tuple(
+                None if i % 2 else {
+                    n: Format(Layout((1, 0), ()), self._state[i][n].sharding)
+                    if len(a.shape) == 2 and pick(fn.__name__, n) else None
+                    for n, a in tree.items()}
+                for i, tree in enumerate(state))
+            kw = dict(jit_kw, in_shardings=fmts + (None,) * len(arg_avals))
+            return jax.jit(fn, **kw).lower(*state, *arg_avals).compile()
+        monkeypatch.setattr(Generator, "_lower", lower)
+    return install

@@ -52,6 +52,20 @@ def test_serve_then_cache_phase_tiny(no_native_build):
         r["warmup_compiles"] for r in runs.values())
 
 
+def test_layouts_phase_tiny(no_native_build, weight_wishes):
+    """The relaid-weights comparison: the pass-through (the CPU compiler
+    wants every weight as it lies), then with the free compile made to ask
+    for transposed projections, where it compares two unlike programs."""
+    rec = chip_smoke.phase_layouts(chip_smoke.TINY)
+    assert rec["checked"]["weights_relaid"] == 0
+    assert rec["checked"]["step_logits_bit_equal"]
+    weight_wishes(lambda prog, name: name.endswith("_proj.weight"))
+    rec = chip_smoke.phase_layouts(chip_smoke.TINY)
+    layers = chip_smoke.TINY.gpt.num_layers
+    assert rec["checked"]["weights_relaid"] == 4 * layers
+    assert rec["checked"]["chunk_logits_bit_equal"]
+
+
 def test_kernels_phase_tiny_interprets_on_cpu(no_native_build):
     rec = chip_smoke.phase_kernels(chip_smoke.TINY)
     assert rec["checked"]["compiled_not_interpreted"] is False

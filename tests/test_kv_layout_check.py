@@ -119,3 +119,58 @@ def test_block_loop_over_the_planes(tool, operands, body_extra, loop_copies):
     faults = tool._faults("step", facts)
     assert len(faults) == 1 + bool(loop_copies)
     assert any("while loop" in f for f in faults) == bool(loop_copies)
+
+
+# -- weights copied again in every run (ISSUE 30) ----------------------------
+_W = "bf16[1600,1600]"
+_SLICE = "(bf16[400,1600]{1,0:T(8,128)(2,1)S(1)}, bf16[1600,1600]{1,0:T(8,128)(2,1)}, u32[]{:S(2)})"
+
+
+def _weights_hlo(q_layout, table_layout, extra=""):
+    """The recorded shape of the parent's step program: a projection
+    weight prefetched in slices, concatenated and TRANSPOSED, and the
+    160 MB table copied on its way to the tied head; ``q_layout`` and
+    ``table_layout`` are the layouts the parameters arrive in."""
+    q_copy = "" if q_layout == "0,1" else (
+        f"  %copy.196 = {_W}{{0,1:T(8,128)(2,1)S(1)}} copy(%custom-call.100), "
+        "metadata={op_name=\"params[\\'encoder.layers.0.self_attn.k_proj.weight\\']\"}\n")
+    t_copy = "" if table_layout == "1,0" else (
+        "  %copy.194 = bf16[50257,1600]{1,0:T(8,128)(2,1)} "
+        "copy(%params__wte_weight__.1), sharding={replicated}\n")
+    return f"""HloModule jit_step, is_scheduled=true
+
+ENTRY %main.1 (a: {_W}, b: bf16[50257,1600], c: bf16[32,25,1,64]) -> bf16[4] {{
+  %params__k__.1 = {_W}{{{q_layout}:T(8,128)(2,1)}} parameter(0), sharding={{replicated}}, metadata={{op_name="params[\\'encoder.layers.0.self_attn.k_proj.weight\\']"}}
+  %params__wte_weight__.1 = bf16[50257,1600]{{{table_layout}:T(8,128)(2,1)}} parameter(1), sharding={{replicated}}, metadata={{op_name="params[\\'wte.weight\\']"}}
+  %cache.1 = bf16[32,25,1,64]{{3,2,1,0:T(8,128)(2,1)}} parameter(2), metadata={{op_name="cache[0][0]"}}
+  %slice-start.384 = {_SLICE} slice-start(%params__k__.1), slice={{[0:400], [0:1600]}}
+  %slice-done.384 = bf16[400,1600]{{1,0:T(8,128)(2,1)S(1)}} slice-done(%slice-start.384)
+  %custom-call.100 = {_W}{{1,0:T(8,128)(2,1)S(1)}} custom-call(%slice-done.384, %slice-done.384), custom_call_target="ConcatBitcast"
+{q_copy}{t_copy}  %copy.9 = bf16[32,25,1,64]{{3,1,0,2:T(8,128)(2,1)S(1)}} copy(%cache.1)
+  %copy.10 = {_W}{{0,1:T(8,128)(2,1)}} copy(%fusion.3)
+{extra}  ROOT %r = bf16[4]{{0}} fusion(%copy.9), kind=kLoop
+}}
+"""
+
+
+@pytest.mark.parametrize("q_layout,table_layout,names,mb", [
+    ("1,0", "0,1", ["encoder.layers.0.self_attn.k_proj.weight",
+                    "wte.weight"], 5.12 + 160.8224),
+    ("0,1", "0,1", ["wte.weight"], 160.8224),
+    ("0,1", "1,0", [], 0.0),
+], ids=["default-layouts", "projection-relaid", "both-relaid"])
+def test_weight_copies_follow_the_prefetch_to_the_parameter(
+        tool, q_layout, table_layout, names, mb):
+    got = tool.weight_copies(_weights_hlo(q_layout, table_layout), n_state=2)
+    # the cache's copy is an activation's (parameter 2 is no state), the
+    # fusion's copy does not start at a parameter
+    assert sorted(n.split("'")[1] for n, _ in got) == sorted(names)
+    assert sum(m for _, m in got) == pytest.approx(mb)
+
+
+def test_small_weight_copies_are_not_counted(tool):
+    text = _weights_hlo("0,1", "1,0",
+                        "  %copy.11 = bf16[1,1600]{1,0:T(2,128)(2,1)} "
+                        "copy(%params__k__.1)\n")
+    assert tool.weight_copies(text, n_state=2) == []
+    assert len(tool.weight_copies(text, n_state=2, min_mb=0.001)) == 1

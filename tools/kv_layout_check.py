@@ -24,20 +24,31 @@ program) and prints, from ``compiled.as_text()``:
   * the ``while`` loops of each program (the decode attention reads the
     planes in column blocks under one, ``cached_attention``; the step's
     line says how wide a block is): whether a plane enters one as a
-    copy, or is copied inside its body.
+    copy, or is copied inside its body;
+  * ``weight_copies``: the ``copy``/``transpose`` instructions of at least
+    1 MB whose operand chain starts at a parameter of the model (a weight
+    transposed again in every run), with their MB; ``weight_copies_default``
+    is the same count for the weights in their default layouts (the
+    programs ``Generator.step_exec`` / ``chunk_exec`` compile alone).
+
+The two programs are compiled through ``Generator.slot_execs``, the slot
+loop's own way to them, so what is checked is what is served: the line
+``weights`` says how many weights the pair agreed to have relaid and on
+how many the two disagreed.
 
     JAX_PLATFORMS=cpu python3 tools/kv_layout_check.py gpt2-xl-serve [slots]
 
 Exit code 1 when a write's traced index lies on the minor-most dimension,
 a plane is not aliased, a whole plane is copied (on its way into a loop
-and inside one too), or a cache row changes layout.  Run by hand, one
-process at a time: only one process may load libtpu, so this is not a
-pytest file.
+and inside one too), a cache row changes layout, or a weight on which the
+two programs did not disagree is still copied.  Run by hand, one process at
+a time: only one process may load libtpu, so this is not a pytest file.
 """
 from __future__ import annotations
 
 import collections
 import json
+import math
 import os
 import re
 import sys
@@ -99,6 +110,46 @@ def _while_plane_copies(hlo_text, instrs, plane_shapes):
                        and where[n][0] in plane_shapes
                        and where[n][2] in ("copy", "transpose")]
     return loops, copies
+
+
+# any ENTRY instruction with operands: "%name = <type> opcode(%a, %b, ..)";
+# a type holds "T(8,128)" and "S(1)" but never "(%"
+_ANY = re.compile(r"^\s*(?:ROOT )?%(?P<name>[\w.\-]+) = .*? "
+                  r"(?P<op>[\w\-]+)\((?P<args>%[^)]*)\)")
+_PARAM = re.compile(r"^\s*%(?P<name>[\w.\-]+) = .* parameter\((?P<n>\d+)\)"
+                    r".*?op_name=\"(?P<arg>[^\"]*)\"")
+_BYTES = {"pred": 1, "s8": 1, "u8": 1, "bf16": 2, "f16": 2, "s16": 2,
+          "u16": 2, "f32": 4, "s32": 4, "u32": 4, "f64": 8, "s64": 8,
+          "u64": 8}
+# what a weight passes through on its way from the parameter to the copy:
+# the prefetch in slices and their concatenation, views, tuples
+_PASS = {"bitcast", "custom-call", "slice-start", "slice-done", "slice",
+         "copy-start", "copy-done", "get-tuple-element", "reshape"}
+
+
+def weight_copies(hlo_text, n_state, min_mb=1.0):
+    """[(argument name, MB)] of the ENTRY copies/transposes of at least
+    ``min_mb`` MB whose first operand leads, through prefetches and views
+    only, back to one of the first ``n_state`` parameters (the model's
+    state): a weight that the program lays out again in every run."""
+    lines = _body(hlo_text)
+    params = {m["name"]: m["arg"].replace("\\'", "'")
+              for m in map(_PARAM.match, lines)
+              if m and int(m["n"]) < n_state}
+    ops = {m["name"]: (m["op"], re.findall(r"%([\w.\-]+)", m["args"]))
+           for m in map(_ANY.match, lines) if m}
+    out = []
+    for name, (dims, _layout, op, args, line) in _entry(hlo_text).items():
+        if op not in ("copy", "transpose") or not args:
+            continue
+        dtype = _INSTR.match(line)["dtype"]
+        mb = _BYTES.get(dtype, 4) * math.prod(dims) / 1e6
+        src = args[0]
+        while src in ops and ops[src][0] in _PASS:
+            src = ops[src][1][0]
+        if mb >= min_mb and src in params:
+            out.append((params[src], mb))
+    return out
 
 
 def _aliased_params(hlo_text):
@@ -175,6 +226,41 @@ def _faults(what, facts):
     return out
 
 
+def described_generator(device):
+    """The Generator, lowering for a described chip: every aval placed on
+    ``device``, through the Generator's own ``slot_execs``.  Nothing can
+    be placed on a described chip, so a relay is only noted, and a weight
+    "lies" in its shape's default layout there."""
+    import jax
+    from jax.experimental.layout import Format
+    from jax.sharding import SingleDeviceSharding
+    from paddle_tpu.text import generation as G
+    one = SingleDeviceSharding(device)
+
+    def place(a):
+        fmt = a.sharding if isinstance(a.sharding, Format) else None
+        return G._aval(a, one if fmt is None else Format(fmt.layout, one))
+
+    class Described(G.Generator):
+        def _state_avals(self):
+            return jax.tree_util.tree_map(place, super()._state_avals())
+
+        def _lower(self, fn, arg_avals, jit_kw, free=False):
+            return super()._lower(
+                fn, jax.tree_util.tree_map(place, arg_avals), jit_kw, free)
+
+        def _held_layouts(self):
+            return {(i, name): G._default_layout(a, device)
+                    for i, tree in enumerate(self._state) if not i % 2
+                    for name, a in tree.items()}
+
+        def _place(self, formats):
+            return sum(int(self._state[i][name].nbytes)
+                       for i, name in formats)
+
+    return Described
+
+
 def main(argv):
     if len(argv) not in (1, 2):
         print(__doc__, file=sys.stderr)
@@ -184,10 +270,8 @@ def main(argv):
     sys.path.insert(0, ROOT)
     import jax
     from jax.experimental import topologies
-    from jax.sharding import SingleDeviceSharding
     import importlib
     from paddle_tpu.nn.functional.attention import decode_block
-    from paddle_tpu.text.generation import Generator
     with open(os.path.join(ROOT, "benchmark", "configs",
                            argv[0] + ".json")) as f:
         cfg = json.load(f)
@@ -196,27 +280,39 @@ def main(argv):
     sv = cfg["serve"]
     topo = topologies.get_topology_desc(platform="tpu",
                                         topology_name="v5e:2x2")
-    one = SingleDeviceSharding(topo.devices[0])
-    place = lambda tree: jax.tree_util.tree_map(  # noqa: E731
-        lambda a: jax.ShapeDtypeStruct(a.shape, a.dtype, sharding=one), tree)
-    gen = Generator(family.build_unweighted(cfg),
-                    seq_buckets=sv["seq_buckets"], max_len=sv["max_len"])
-    state = place(gen._state_avals())
+    gen = described_generator(topo.devices[0])(
+        family.build_unweighted(cfg), seq_buckets=sv["seq_buckets"],
+        max_len=sv["max_len"])
     S, C, T = sv["slots"], sv["max_len"], sv["prefill_chunk"]
     if len(argv) > 1:
         S = int(argv[1])            # try another slot count
     plane_shapes = {tuple(p.shape) for c in gen.slot_cache_avals_all(S, C)
                     for p in c}
+    n_state = len(jax.tree_util.tree_leaves(gen._state_avals()))
+    progs = {"step": gen._step_program(S, C),
+             "chunk": gen._chunk_program(S, T, C)}
+    # the weights in their default layouts first: what each program alone
+    # would do to them (nothing is settled by a bare lowering)
+    default = {what: weight_copies(gen._lower(
+        fn, avals, {"donate_argnums": donate}).as_text(), n_state)
+        for what, (_key, _kind, fn, avals, _extra, donate) in progs.items()}
+    served = dict(zip(progs, gen.slot_execs(S, T, C)))
+    print(json.dumps({"config": cfg["name"], "weights": gen.weights_layout}),
+          flush=True)
     faults = []
-    for what, fn, avals in (
-            ("step", gen._build_step(S, C, -1), gen.step_avals(S, C)),
-            ("chunk", gen._build_chunk(S, T, C), gen.chunk_avals(S, T, C))):
-        compiled = jax.jit(fn, donate_argnums=(2,)).lower(
-            *state, *place(avals)).compile()
-        facts = inspect(compiled.as_text(), plane_shapes)
+    for what, compiled in served.items():
+        text = compiled.as_text()
+        facts = inspect(text, plane_shapes)
         if what == "step" and "kv" in gen.plane_kinds():
             # the column blocks of the step's attention (cached_attention)
             facts = {"attn_block": decode_block(C), **facts}
+        left = weight_copies(text, n_state)
+        facts.update(
+            weight_copies_default=len(default[what]),
+            weight_copies_default_mb=round(
+                sum(mb for _, mb in default[what]), 1),
+            weight_copies=len(left),
+            weight_copies_mb=round(sum(mb for _, mb in left), 1))
         print(json.dumps({"config": cfg["name"], "program": what,
                           "slots": S, "cache": C, **facts}), flush=True)
         mem = compiled.memory_analysis()
@@ -225,6 +321,13 @@ def main(argv):
             for k in ("argument", "output", "alias", "temp",
                       "generated_code")}}), flush=True)
         faults += _faults(what, facts)
+        # a weight the two disagreed on stays as it lies, and the program
+        # that wanted it otherwise goes on copying it: no fault
+        n_agreed = len(left) - gen.weights_layout["weights_layout_disagreed"]
+        if n_agreed > 0:
+            faults.append(f"{what}: at least {n_agreed} weights on which the "
+                          "programs agreed are still copied in every run: "
+                          + ", ".join(sorted({n for n, _ in left})[:4]))
     for f in faults:
         print("FAULT " + f, flush=True)
     return 1 if faults else 0
