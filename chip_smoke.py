@@ -2,7 +2,7 @@
 """chip_smoke.py — the quickest proof that the system starts on the chip.
 
     python chip_smoke.py              # one chip: train, serve, layouts,
-                                      # kernels, cache
+                                      # hybrid, kernels, cache
     python chip_smoke.py --multichip  # four chips: only the sharded paths
 
 One process, the entry points a user calls (``parallel.TrainStep``,
@@ -100,6 +100,7 @@ class Size:
     moe: GPTMoEConfig
     moe_batch: int
     moe_seq: int
+    hybrid: dict            # over benchmark/tests/data/lfm2_tiny.json
 
 
 def _moe_cfg(**kw) -> GPTMoEConfig:
@@ -123,7 +124,12 @@ FULL = Size(
     conv_w=(64, 64, 3, 3),
     moe=_moe_cfg(vocab_size=128, hidden_size=512, layers=4, heads=8,
                  seq=128, experts=16),
-    moe_batch=32, moe_seq=128)
+    moe_batch=32, moe_seq=128,
+    # the served geometry in small: 2 KV heads of 64 a lane row, 4 queries a
+    # KV head, a state of two 512-lane rows
+    hybrid=dict(hidden_size=512, num_attention_heads=8, num_key_value_heads=2,
+                vocab_size=512, intermediate_size=512,
+                moe_intermediate_size=128))
 
 TINY = Size(
     name="tiny", bert=BertConfig.tiny(seq=128), train_batch=8, train_seq=32,
@@ -136,7 +142,7 @@ TINY = Size(
     conv_w=(8, 8, 3, 3),
     moe=_moe_cfg(vocab_size=64, hidden_size=16, layers=2, heads=2, seq=32,
                  experts=4),
-    moe_batch=8, moe_seq=16)
+    moe_batch=8, moe_seq=16, hybrid={})
 
 
 def device_record() -> dict:
@@ -477,6 +483,100 @@ def phase_layouts(size: Size, seed: int = 0) -> dict:
         "chunk_logits_bit_equal": True, "step_logits_bit_equal": True})
 
 
+# -- hybrid: conv states beside K/V planes ------------------------------------
+
+# float32 at precision "highest" on both sides: what is left is summation
+# order, relative to max|logit|
+HYBRID_TOL = 1e-3
+
+
+def phase_hybrid(size: Size, seed: int = 0) -> dict:
+    """The short-convolution / grouped-query MoE decoder at a small size
+    against its plain reference (``benchmark/reference/lfm2.py``), float32
+    at precision "highest": (a) one row's chunked prefill and 32 steps
+    through the Generator's own chunk and step programs, logits at every
+    position; (b) six requests over three slots of a ``SlotLoop`` (slots
+    reused, rows waiting between their chunks while others step), every
+    served token against the reference's best.  The CPU tests hold the same
+    comparisons; the chip's compiler has returned other values than the
+    CPU's before (PERF.md section 6, PR 25), and a state whose entries sit
+    side by side with a block's tokens is where that would show."""
+    import os
+    from benchmark import harness
+    from benchmark.models import lfm2 as models
+    from benchmark.reference import lfm2 as ref
+    from paddle_tpu.serving.slots import SlotLoop
+    t0 = time.perf_counter()
+    root = os.path.dirname(os.path.abspath(__file__))
+    with open(os.path.join(root, "benchmark", "configs",
+                           "lfm2-8b-a1b-pp2-serve.json")) as f:
+        cfg = json.load(f)
+    with open(os.path.join(root, "benchmark", "tests", "data",
+                           "lfm2_tiny.json")) as f:
+        cfg.update({k: v for k, v in json.load(f)["over"].items()
+                    if k != "serve"})
+    cfg.update(size.hybrid, reference_pad=32)
+    cfg["serve"] = {"max_new_tokens": 32}
+    V, S, C, T, steps = cfg["vocab_size"], 3, 256, 16, 32
+    with jax.default_matmul_precision("highest"):
+        mapped = models.to_program(ref.init_weights(cfg, seed))
+        model = models.build(cfg, mapped)
+        view = harness.canonical_view(mapped, models.leaf_ids(cfg))
+        gen = Generator(model, seq_buckets=(C,), max_len=C)
+        t1 = time.perf_counter()
+        step, chunk = gen.slot_execs(S, T, C)
+        compile_s = time.perf_counter() - t1
+        rng = np.random.default_rng(seed + 3)
+        prompt = rng.integers(0, V, 70).astype(np.int32)
+        n = -(-prompt.size // T)
+        ids = np.zeros((n * T,), np.int32)
+        ids[n * T - prompt.size:] = prompt
+        start = np.full((S,), C, np.int32)
+        start[1] = n * T - prompt.size
+        active = np.zeros((S,), bool)
+        active[1] = True
+        cache = gen.init_slot_cache(S, C)
+        for k in range(n):
+            cache, last, _ = chunk(
+                *gen._state_args(), cache,
+                jnp.asarray(ids[None, k * T:(k + 1) * T]),
+                jnp.asarray(start[1:2]), jnp.int32(1), jnp.int32(k * T))
+        logits = jnp.zeros((S, V), jnp.float32).at[1].set(last)
+        finished, got, toks = jnp.zeros((S,), bool), [np.asarray(last)], []
+        for k in range(steps):
+            cache, logits, finished, tok = step(
+                *gen._state_args(), cache, logits, jnp.asarray(start),
+                finished, jnp.asarray(active), jnp.int32(n * T + k))
+            toks.append(int(tok[1]))
+            got.append(np.asarray(logits[1]))
+        want = np.asarray(ref.served_logits(cfg, view, prompt,
+                                            np.asarray(toks, np.int32)))
+        got = np.stack(got[:steps])
+        _check(np.isfinite(got).all(), "hybrid: non-finite logits")
+        worst = float(np.abs(got - want).max() / np.abs(want).max())
+        _check(worst < HYBRID_TOL,
+               f"hybrid: logits {worst:.2e} of max|logit| from the reference")
+        loop = SlotLoop(gen, slots=S, cache_len=C, chunk=T)
+        prompts = [rng.integers(0, V, p).astype(np.int32)
+                   for p in (9, 40, 17, 70, 5, 33)]
+        futs = [loop.submit(p, 12 + 4 * i) for i, p in enumerate(prompts)]
+        served = [np.asarray(f.result(timeout=600)) for f in futs]
+        stats = loop.stats()
+        loop.close()
+        gap = max(float(np.max(ref.served_gaps(cfg, view, p, t)))
+                  for p, t in zip(prompts, served))
+    _check(gap < HYBRID_TOL, f"hybrid: a served token lies {gap:.2e} of "
+                             "max|logit| under the reference's best")
+    _check(stats["state_rows_held"] > 0 and stats["plane_kinds"]
+           == ["conv_state", "kv"], "hybrid: no row waited with a state")
+    return _emit("hybrid", t0, compile_s, {
+        "model": f"hybrid conv {size.name} f32 highest",
+        "hidden": cfg["hidden_size"], "slots": S, "cache": C, "chunk": T,
+        "steps": steps, "logits_rel_worst": worst, "served_gap_widest": gap,
+        "state_rows_held": stats["state_rows_held"],
+        "moe_assignments": stats["moe_assignments"]})
+
+
 # -- kernels ------------------------------------------------------------------
 
 def _close(name: str, got, ref) -> float:
@@ -792,6 +892,7 @@ def main(argv=None) -> int:
         phase_train(FULL, args.seed)
         served = phase_serve(FULL, args.seed)
         phase_layouts(FULL, args.seed)
+        phase_hybrid(FULL, args.seed)
         phase_kernels(FULL, args.seed)
         phase_cache(FULL, served)
     print(json.dumps({"ok": True, "device": device}), flush=True)

@@ -78,31 +78,38 @@ def _sdpa_packed_fn(q, k, v, mask=None):
     return _own_lanes(out, own, q.shape[1], q.shape[-1])
 
 
-def _spread_queries(q, groups, lanes):
+def _spread_queries(q, groups, lanes, rep=1):
     """``(B, N, T, H)`` queries over their group's ``lanes = g*H`` lanes,
-    zeros outside the head's own: ``[B, groups, g, T, lanes]``, and the
-    ``own [1, 1, g, 1, lanes]`` mask of each head's lanes."""
+    zeros outside the lanes of the cached head they read: ``[B, groups,
+    g * rep, T, lanes]``, and the ``own [1, 1, g * rep, 1, lanes]`` mask
+    of those lanes (query ``j`` of a lane row reads cached head ``j //
+    rep`` of it)."""
     b, n, t, hd = q.shape
     g = lanes // hd
-    qg = jnp.pad(q, ((0, 0), (0, groups * g - n), (0, 0), (0, 0))) \
-        .reshape(b, groups, g, t, hd)
+    qg = jnp.pad(q, ((0, 0), (0, groups * g * rep - n), (0, 0), (0, 0))) \
+        .reshape(b, groups, g * rep, t, hd)
     own = (jnp.arange(lanes)[None, :] // hd
-           == jnp.arange(g)[:, None])[None, None, :, None, :]
+           == jnp.arange(g * rep)[:, None] // rep)[None, None, :, None, :]
     return jnp.where(own, jnp.tile(qg, (1, 1, 1, 1, g)),
                      jnp.zeros((), q.dtype)), own
 
 
-def _own_lanes(out, own, n, hd):
-    """``[B, groups, g, T, lanes]`` -> ``(B, n, T, hd)``: each head keeps
-    its own lanes: select and sum over j (one term is non-zero, so the
-    sum is exact), NOT a stack of out[..., j, :, j*H:(j+1)*H] slices —
-    the TPU compiler of jax 0.9.0 miscompiles a concatenate of slices
-    taken at a lane offset (wrong values on the v5e, right on the CPU;
-    PERF.md section 6, PR 25)."""
-    b, groups, g, t, _ = out.shape
-    out = jnp.where(own, out, jnp.zeros((), out.dtype)).sum(axis=2)
-    return out.reshape(b, groups, t, g, hd).transpose(0, 1, 3, 2, 4) \
-        .reshape(b, groups * g, t, hd)[:, :n]
+def _own_lanes(out, own, n, hd, rep=1):
+    """``[B, groups, g * rep, T, lanes]`` -> ``(B, n, T, hd)``: each head
+    keeps the lanes of the cached head it read: select and sum (one term
+    is non-zero, so the sum is exact), NOT a stack of out[..., j, :,
+    j*H:(j+1)*H] slices — the TPU compiler of jax 0.9.0 miscompiles a
+    concatenate of slices taken at a lane offset (wrong values on the
+    v5e, right on the CPU; PERF.md section 6, PR 25).  With one query a
+    cached head the sum runs over the heads of the lane row; with
+    ``rep`` of them, over the row's ``g`` lane segments."""
+    b, groups, j, t, lanes = out.shape
+    out = jnp.where(own, out, jnp.zeros((), out.dtype))
+    if rep == 1:
+        out = out.sum(axis=2)
+        return out.reshape(b, groups, t, j, hd).transpose(0, 1, 3, 2, 4) \
+            .reshape(b, groups * j, t, hd)[:, :n]
+    return out.reshape(b, groups * j, t, lanes // hd, hd).sum(axis=3)[:, :n]
 
 
 # columns a decode step's attention reads at a time: the unit in which
@@ -116,12 +123,14 @@ def decode_block(C):
     return min(DECODE_BLOCK, int(C))
 
 
-@functools.partial(jax.jit, static_argnames=("block",))
-def _decode_span_fn(q, k, v, start, end, block):
+@functools.partial(jax.jit, static_argnames=("block", "rep"))
+def _decode_span_fn(q, k, v, start, end, block, rep=1):
     """One-query attention of ``(B, N, 1, H)`` queries over ring planes
-    ``(B, G, C, g*H)`` (packed as for :func:`_sdpa_packed_fn`; ``g == 1``
-    is the plain ``(B, N, C, H)`` plane) whose valid columns are each
-    row's ``[start[b], end[b])``: only the span of ``block``-column
+    ``(B, G, C, g*H)`` (packed as for :func:`_sdpa_packed_fn`, with
+    ``rep`` query heads a cached head: grouped-query attention over
+    planes of ``N / rep`` heads; ``g == 1`` is the plain ``(B, N, C, H)``
+    plane) whose valid columns are each row's ``[start[b], end[b])``:
+    only the span of ``block``-column
     blocks (``decode_block(C)``; jitted on its own so that a model's
     layers, which all call it at one shape, trace its loops once) from
     the one that holds the lowest ``start`` to the one that
@@ -143,7 +152,7 @@ def _decode_span_fn(q, k, v, start, end, block):
     b, n, t, hd = q.shape
     groups, C, lanes = k.shape[1], k.shape[2], k.shape[3]
     blocks = -(-C // block)
-    qs, own = _spread_queries(q, groups, lanes)
+    qs, own = _spread_queries(q, groups, lanes, rep)
     # (the barrier keeps the spread queries one array that both loops
     # read, where the compiler would rebuild them in every iteration)
     qs = jax.lax.optimization_barrier(qs)
@@ -180,7 +189,80 @@ def _decode_span_fn(q, k, v, start, end, block):
                                 preferred_element_type=jnp.float32)
 
     out = jax.lax.fori_loop(lo, hi, weigh, jnp.zeros(qs.shape, jnp.float32))
-    return _own_lanes(out.astype(q.dtype), own, n, hd)
+    return _own_lanes(out.astype(q.dtype), own, n, hd, rep)
+
+
+# columns a wider block's attention reads at a time over the live span
+SPAN_BLOCK = 512
+
+
+@functools.partial(jax.jit, static_argnames=("block", "rep"))
+def _block_span_fn(q, k, v, start, first, block, rep=1):
+    """Attention of a BLOCK of queries ``(B, N, T, H)`` that sit at the
+    columns ``first .. first + T - 1`` over ring planes ``(B, G, C, g*H)``
+    (packed as for :func:`_sdpa_packed_fn`, ``rep`` queries a cached
+    head): query ``t`` of row ``b`` sees the columns ``[start[b], first +
+    t]``.  Only the ``block``-column blocks from the one that holds the
+    lowest ``start`` to the one that holds the last query's column are
+    read, under a traced trip count, with a running softmax (float32
+    scores, maxima, sums and accumulation), so a prefill chunk never holds
+    ``[heads, T, C]`` scores and the columns nobody can see cost nothing:
+    the one-expression form scores every column of the ring whatever the
+    context.  A query with no valid column (left padding) gets finite
+    values that nobody uses."""
+    b, n, t, hd = q.shape
+    groups, C, lanes = k.shape[1], k.shape[2], k.shape[3]
+    blocks = -(-C // block)
+    qs, own = _spread_queries(q, groups, lanes, rep)
+    rows = first + jnp.arange(t, dtype=jnp.int32)
+    lo = jnp.clip(jnp.min(start) // block, 0, blocks)
+    hi = jnp.clip((first + t - 1) // block + 1, lo, blocks)
+    scale = 1.0 / math.sqrt(hd)
+
+    def body(i, carry):
+        m, l, acc = carry
+        # the last block of a cache that is no multiple of ``block`` starts
+        # early and does not count again what the one before it holds
+        s0 = jnp.minimum(i * block, C - block)
+        cols = s0 + jnp.arange(block, dtype=jnp.int32)
+        valid = (cols[None, None, :] >= start[:, None, None]) \
+            & (cols[None, None, :] <= rows[None, :, None]) \
+            & (cols >= i * block)[None, None, :]               # [B, T, block]
+        s = jnp.einsum("bgjtl,bgcl->bgjtc", qs,
+                       jax.lax.dynamic_slice_in_dim(k, s0, block, 2),
+                       preferred_element_type=jnp.float32) * scale
+        s = jnp.where(valid[:, None, None], s, _NEG)
+        m_new = jnp.maximum(m, s.max(-1))
+        p = jnp.exp(s - m_new[..., None])
+        corr = jnp.exp(m - m_new)
+        pv = jnp.einsum("bgjtc,bgcl->bgjtl", p.astype(q.dtype),
+                        jax.lax.dynamic_slice_in_dim(v, s0, block, 2),
+                        preferred_element_type=jnp.float32)
+        return m_new, l * corr + p.sum(-1), acc * corr[..., None] + pv
+
+    init = (jnp.full(qs.shape[:-1], _NEG, jnp.float32),
+            jnp.zeros(qs.shape[:-1], jnp.float32),
+            jnp.zeros(qs.shape, jnp.float32))
+    _, l, acc = jax.lax.fori_loop(lo, hi, body, init)
+    out = acc / jnp.maximum(l, 1e-30)[..., None]
+    return _own_lanes(out.astype(q.dtype), own, n, hd, rep)
+
+
+def span_attention(q, k, v, start, first, rep=1):
+    """Causal attention of the queries ``(B, N, T, H)`` at columns ``first
+    .. first + T - 1`` over bf16/f32 ring planes, each row from its
+    ``start[B]``, reading the live span of the ring only: a step's one
+    query a row through :func:`_decode_span_fn` (two passes, one softmax),
+    a wider block through :func:`_block_span_fn` (a running softmax).
+    Raw arrays; inference only."""
+    B, _, T, _ = q.shape
+    C = k.shape[2]
+    if T == 1:
+        return _decode_span_fn(q, k, v, start,
+                               jnp.broadcast_to(first + 1, (B,)),
+                               block=decode_block(C), rep=rep)
+    return _block_span_fn(q, k, v, start, first,
+                          block=min(SPAN_BLOCK, C), rep=rep)
 
 
 _sdpa = Primitive("scaled_dot_product_attention", _sdpa_fn)
@@ -251,7 +333,8 @@ def cached_attention(q, k, v, attn_mask=None, window=None, k_scale=None,
     full KV ring cache — bf16/f32 planes packed ``(B, ceil(N/g), S,
     g*H)`` as ``gen_ring_cache`` builds them (``g`` is read from the
     plane's minor dim; ``g == 1`` is the plain (B, N, S, H) plane), or
-    the int8 cache's unpacked (B, N, S, H) rows.
+    the int8 cache's unpacked (B, N, S, H) rows.  One query head a
+    cached head; grouped queries go through :func:`span_attention`.
 
     ``attn_mask`` is the additive validity+causality mask the caller
     built from cache_position / per-row start offsets.  ``window`` is the

@@ -669,22 +669,47 @@ class DroplessMoE(Layer):
     scores, renormalised over the chosen (``norm_topk``) and times
     ``scaling``.  This layer holds experts ``held = (lo, hi)`` and
     computes THEIR part of ``sum_chosen w_i E_i(u)``: the assignments are
-    sorted by expert and the three products run as grouped (ragged)
-    products over the rows of each held expert, however many rows that
-    is.  An assignment to an expert held elsewhere adds nothing here: on
-    a mesh the other shares' parts arrive by the exchange that this layer
-    does not do (the one-chip share of an expert-parallel deployment).
-    ``shared`` experts (dense SwiGLU of ``shared x width``) see every
-    token and are counted once.
+    sorted by expert and the three products run over the rows of each
+    held expert, however many rows that is.  An assignment to an expert
+    held elsewhere adds nothing here: on a mesh the other shares' parts
+    arrive by the exchange that this layer does not do (the one-chip
+    share of an expert-parallel deployment); ``held=None`` holds every
+    expert.  ``shared`` experts (dense SwiGLU of ``shared x width``) see
+    every token and are counted once.  ``norm_eps`` is what the
+    renormalisation adds to the sum of the chosen scores (``w_i = s_i /
+    (sum + norm_eps)``, the ``lfm2_moe`` form); ``None`` divides by
+    ``max(sum, 1e-20)`` (the ``dots3_note`` form).
+
+    **Which products a dispatch runs** is decided by its size, never by a
+    model's name.  Each held expert's rows are padded to ``cap`` (four
+    times the even share ``A / num_experts`` of the dispatch's ``A``
+    assignments, but at most 64 rows over it, at least 8) and run as ONE
+    batched product, which streams the experts' weights once at 92% of
+    the chip's bandwidth, as long as ``cap <= PADDED_ROWS_MAX``: up to
+    there the padded rows cost less than the weights they are multiplied
+    with.  Beyond it, and in any dispatch in which the router sent one
+    expert more than ``cap`` rows, the products run grouped as the rows
+    lie (``lax.ragged_dot``, 45% of the bandwidth whatever the rows:
+    PERF.md section 6, PR 27): nothing is ever dropped.  After PR 31 the
+    benchmark's cells enter: ``dots3-ep8-rag4k-saturated`` the padded
+    branch in its 64-row step (cap 8) and its 512-token chunk (cap 64);
+    ``lfm2-pp2-reason-saturated`` (all 32 experts held, top 4) the padded
+    branch in its 128-row step (cap 64) and its 512-token chunk (cap
+    128); the grouped branch is entered by a dispatch of more than
+    ``PADDED_ROWS_MAX x num_experts / 4`` assignments and by skew.
 
     After a forward, ``last_counts`` holds (assignments made, assignments
     that fell on held experts, the largest per-expert row count), over
     the tokens marked live, as int32 scalars of the same trace.
     """
 
+    # rows an expert may be padded to: past ~240 rows (v5e: 197 TFLOP/s
+    # over 819 GB/s) an expert's products cost more than its weights
+    PADDED_ROWS_MAX = 256
+
     def __init__(self, hidden, width, num_experts, top_k, *, held=None,
-                 shared=0, scaling=1.0, norm_topk=True, weight_attr=None,
-                 dtype=None):
+                 shared=0, scaling=1.0, norm_topk=True, norm_eps=None,
+                 weight_attr=None, dtype=None):
         super().__init__()
         from .. import initializer as I
         lo, hi = (0, num_experts) if held is None else map(int, held)
@@ -698,6 +723,7 @@ class DroplessMoE(Layer):
         self.num_experts, self.top_k = int(num_experts), int(top_k)
         self.lo, self.hi = lo, hi
         self.scaling, self.norm_topk = float(scaling), bool(norm_topk)
+        self.norm_eps = None if norm_eps is None else float(norm_eps)
 
         def mat(*shape):
             return self.create_parameter(
@@ -723,7 +749,9 @@ class DroplessMoE(Layer):
         _, ids = jax.lax.top_k(s + unwrap(self.router_bias), self.top_k)
         w = jnp.take_along_axis(s, ids, axis=1)
         if self.norm_topk:
-            w = w / jnp.maximum(w.sum(-1, keepdims=True), 1e-20)
+            total = w.sum(-1, keepdims=True)
+            w = w / (jnp.maximum(total, 1e-20) if self.norm_eps is None
+                     else total + self.norm_eps)
         return ids.astype(jnp.int32), w * self.scaling
 
     def forward(self, u, live=None):
@@ -787,12 +815,15 @@ class DroplessMoE(Layer):
                 return y.reshape(n * cap, -1)[src]             # [A, h]
 
             # even routing gives an expert A / E rows; pad to four times
-            # that (64 for a 512-token chunk's 4,096 assignments over 256
-            # experts, the floor of 8 for a 64-row step's 512).
-            # An expert with more, however skewed the router, sends the
-            # block through the grouped products: nothing is ever dropped
-            cap = max(8, -(-4 * A // self.num_experts // 8) * 8)
-            if n * cap < A:
+            # that, at most 64 rows over it (64 for a 512-token chunk's
+            # 4,096 assignments over 256 experts, the floor of 8 for a
+            # 64-row step's 512; 64 for a 128-row step's 512 over 32 and
+            # 128 for a chunk's 2,048).  An expert with more, however
+            # skewed the router, sends the block through the grouped
+            # products: nothing is ever dropped
+            even = -(-A // self.num_experts)
+            cap = max(8, -(-min(4 * even, even + 64) // 8) * 8)
+            if cap <= self.PADDED_ROWS_MAX:
                 y = jax.lax.cond(jnp.max(sizes) <= cap,
                                  lambda: padded(cap), ragged)
             else:

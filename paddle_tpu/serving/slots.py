@@ -57,6 +57,16 @@ per-request ``generate()`` of the same prompt:
     boundaries (committing fewer than accepted is always exact), and a
     stride that arrives at ``act`` early just bursts the remaining
     chunks first — chunk writes never depend on ``pos``;
+  * **a state without columns is kept, not garbled** — the dead-column
+    rule has no meaning for a plane that a feed overwrites in place
+    (``cache_spec`` ``columns == 0``: a short convolution's last
+    inputs).  Such a state's entries stand for the columns just before
+    the block being fed and count iff those columns are at or after the
+    row's ``start``, so a previous occupant's leftovers, left padding and
+    a ring restart need no reset; and the step hands the model its live
+    rows (``cached_forward_takes_rows``), so a row that waits between
+    two of its chunks, or is done, keeps what it has
+    (``counters["state_rows_held"]``);
   * **bounded ring sessions** — the validity mask compares absolute
     columns, so ``pos`` must stay inside ``[0, C)``: a request admits
     only if ``act + max_new (+ gamma)`` fits, and when the FIFO head
@@ -233,6 +243,13 @@ class SlotLoop:
                              if s.get("select_top")]
         self._wrap_lens = [int(s["columns"]) for s in spec
                            if s.get("wraps") and int(s["columns"]) < self.C]
+        # layers whose cache has no columns: a per-row state that every
+        # feed overwrites in place, so a step must leave the rows it does
+        # not feed as they are (the model takes the step's live rows)
+        self._state_layers = sum(1 for s in spec if not int(s["columns"]))
+        # the kinds of the planes that DO have columns
+        column_kinds = sorted({str(s["kind"]) for s in spec
+                               if int(s["columns"])})
         names = getattr(gen, "decode_count_names", None)
         self._count_names = tuple(names()) if names is not None else ()
         if prefix_cache is not None:
@@ -289,12 +306,18 @@ class SlotLoop:
                 if not k.endswith("_max")})
         if self._wrap_lens:
             self.counters["window_wraps"] = 0
+        if self._state_layers:
+            self.counters["state_rows_held"] = 0
         # the plain step over bf16/f32 K/V planes attends in blocks of
-        # this many columns (cached_attention)
+        # this many columns (cached_attention), whatever the model keeps
+        # beside them that has no columns
         self._attn_block = 0
-        if self._plane_kinds == ["kv"] and not self._spec:
+        if column_kinds == ["kv"] and not self._spec:
             self._attn_block = decode_block(self.C)
             self.counters.update(attn_blocks_read=0, attn_blocks_total=0)
+            # ... and the valid columns themselves, of steps and of
+            # chunks (what a roofline is counted from)
+            self.counters.update(kv_columns_valid=0, chunk_kv_columns_valid=0)
         # driver-thread-owned: what the dispatches since the last commit
         # add to those counters; committed with ``steps`` in one piece
         self._tally = {}
@@ -794,8 +817,10 @@ class SlotLoop:
         ``min(context, select_top)`` of a token's ``context = column -
         start + 1`` causal columns: summed over the live rows of the
         steps under ``attn_columns_*``, over the tokens of the chunks
-        under ``chunk_attn_columns_*``.  A write at a column that is a
-        multiple of a wrapping plane's length has gone once round it."""
+        under ``chunk_attn_columns_*``; a plain K/V layer reads all of
+        them (``kv_columns_valid``, once a dispatch, not a layer).  A
+        write at a column that is a multiple of a wrapping plane's length
+        has gone once round it."""
         cols = np.asarray(cols)
         ctx = cols - np.asarray(start) + 1
         cols, ctx = cols[ctx > 0], ctx[ctx > 0]
@@ -803,6 +828,8 @@ class SlotLoop:
         if chunk and "chunk_tokens" in self.counters:
             add("chunk_tokens", int(ctx.size))
         pre = "chunk_" if chunk else ""
+        if self._attn_block:
+            add(pre + "kv_columns_valid", int(ctx.sum()))
         for top in self._select_tops:
             add(pre + "attn_columns_valid", int(ctx.sum()))
             add(pre + "attn_columns_selected",
@@ -965,6 +992,12 @@ class SlotLoop:
         starts = np.array([self._slots[i].start for i in gen_slots], np.int64)
         self._tally_columns(np.full(len(gen_slots), self.pos), starts)
         self._tally_blocks(starts)
+        if self._state_layers:
+            # rows between two of their chunks: their state is what the
+            # last chunk left, and this step passed it by
+            self._add("state_rows_held", sum(
+                1 for s in self._slots
+                if s.state == _PREFILL and s.next_chunk > 0))
         self.pos += 1
         for i in gen_slots:
             self._emit(self._slots[i], [int(tok[i])])

@@ -169,8 +169,8 @@ def require_kv_planes(kinds, who):
     session store and the KV handoff slice, park and ship UNIFORM K/V
     ring planes (kinds ``kv``, ``kv_int8``: every plane as long as the
     session, a column a token).  A latent plane beside a selector-key
-    plane, or a window plane shorter than the session, is not theirs
-    yet."""
+    plane, a window plane shorter than the session, or a state without
+    columns (``conv_state``) is not theirs yet."""
     bad = sorted(k for k in set(kinds) if not str(k).startswith("kv"))
     if bad:
         raise InvalidArgumentError(
@@ -379,7 +379,8 @@ class Generator:
     def cache_spec(self, C):
         """Per layer, what the model says its cache planes are at cache
         length ``C``: ``kind`` (the cache class's), ``heads_per_lane_row``
-        (``g`` of ``gen_ring_cache``), ``columns`` of its planes,
+        (``g`` of ``gen_ring_cache``), ``columns`` of its planes (0: a
+        per-row state that a feed overwrites in place),
         whether they ``wrap`` inside a session, its ``window`` and
         ``select_top``.  The model's ``cache_spec``; a model that has
         none is described from the classes its ``init_cache`` builds."""
@@ -398,10 +399,10 @@ class Generator:
         """How many heads share a row of the minor dimension of this
         model's ring planes (``g`` of ``gen_ring_cache``; 1 = unpacked
         planes: head_dim >= 128, the int8 cache, a latent plane), as the
-        model's first layer describes its cache, so the ledger's
-        ``generate_step`` / ``generate_chunk`` events and
+        model's first layer that keeps columns describes its cache, so
+        the ledger's ``generate_step`` / ``generate_chunk`` events and
         ``SlotLoop.stats()`` say which layout a run used."""
-        spec = self.cache_spec(1)
+        spec = [s for s in self.cache_spec(1) if s["columns"]]
         return int(spec[0]["heads_per_lane_row"]) if spec else 1
 
     def decode_count_names(self):

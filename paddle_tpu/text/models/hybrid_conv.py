@@ -1,0 +1,421 @@
+"""Decoder-only LM whose layers differ in MIXER and in FFN independently:
+gated short convolutions or grouped-query attention, dense SwiGLU or a
+sigmoid-routed MoE that holds its experts (the ``lfm2_moe`` lineage).
+
+Block: ``h = x + Mix(RMSNorm(x))``, ``y = h + FFN(RMSNorm(h))``; final
+RMSNorm; the output head is the embedding (tied).  ``layer_types[i]`` is
+
+  * ``"conv"``: ``[B | C | X] = W_in x``, ``u = B * X``, ``c(t) = sum_j
+    w_j * u(t - (L-1) + j)`` (depthwise, ``L`` taps, ``u = 0`` before the
+    request's first token), ``Mix = W_out (C * c)``.  What such a layer
+    keeps of a row is ``u`` at the ``L - 1`` columns before the block being
+    fed: a plane ``[B, 1, L-1, hidden]`` WITHOUT columns (kind
+    ``conv_state``), overwritten in place;
+  * ``"full_attention"``: ``rep = heads / kv_heads`` query heads a cached
+    head, RMSNorm over each head's features of q and k (one learned vector
+    each), rotary positions, K (after norm and rotary) and V in the GPT
+    family's packed ring planes (``gen_ring_cache``'s layout, kind ``kv``).
+
+The first ``dense_layers`` FFNs are dense, the rest
+:class:`~paddle_tpu.nn.layer.moe.DroplessMoE`.
+
+**Liveness of the state is positional**, like everything else in a slot
+loop: the state's entries ARE ``u`` at the columns ``pos - (L-1) .. pos -
+1``, and an entry counts iff its column is at or after the row's
+``start``.  That covers a slot's previous occupant (its leftovers lie
+before the new ``start``), left padding inside a chunk and a ring restart,
+with no reset program.  What it cannot cover is a row that is not being
+fed by a step (it waits for the frontier between its chunks, or is done):
+a step must leave that row's state as it is, so the model takes the step's
+live rows (``write_rows``, ``cached_forward_takes_rows``).
+
+Inference only: nothing here is taped.
+"""
+from __future__ import annotations
+
+import collections
+import dataclasses
+from typing import Sequence
+
+import jax
+import jax.numpy as jnp
+
+from ... import nn
+from ...framework.tensor import Tensor, unwrap
+from ...nn import initializer as I
+from ...nn.functional.attention import rotary, span_attention
+from ...nn.layer.latent_attention import RMSNorm
+from ...nn.layer.moe import DroplessMoE, SwiGLU
+from ...nn.layer.transformer import (MultiHeadAttention,
+                                     kv_heads_per_lane_row, pack_heads,
+                                     ring_block_write)
+
+__all__ = ["HybridConvConfig", "HybridConvDecoder", "ConvStateCache",
+           "ShortConv", "GroupedQueryAttention"]
+
+CONV, ATTN = "conv", "full_attention"
+
+# a conv layer's cache: ``state [B, 1, L-1, hidden]``, row first like every
+# plane (so the slot programs cut a row out of it as out of any other),
+# the hidden features on the lanes, oldest entry first; no columns, nothing
+# to wrap.  (The step computes on ``[rows, hidden]`` operands in another
+# device layout than a plane of this shape, or of ``[B, (L-1) * hidden]``,
+# lies in, and relays each 1 MB plane there and back: tools/
+# kv_layout_check.py counts it, PERF.md section 7.)
+ConvStateCache = collections.namedtuple("ConvStateCache", ["state"])
+ConvStateCache.kind = "conv_state"
+ConvStateCache.wraps = False
+RingCache = MultiHeadAttention.RingCache
+
+
+@dataclasses.dataclass
+class HybridConvConfig:
+    vocab_size: int = 1024
+    hidden_size: int = 256
+    layer_types: Sequence[str] = (CONV, ATTN)
+    dense_layers: int = 1               # leading layers with a dense FFN
+    intermediate_size: int = 512
+    moe_intermediate_size: int = 128
+    num_experts: int = 8
+    experts_per_token: int = 2
+    routed_scaling: float = 1.0
+    norm_topk: bool = True
+    routing_norm_eps: float = 1e-6      # w_i = s_i / (sum_chosen s + eps)
+    num_heads: int = 4
+    num_kv_heads: int = 2
+    head_dim: int = 64
+    rope_base: float = 1e6
+    conv_taps: int = 3                  # the config's ``conv_L_cache``
+    rms_eps: float = 1e-5
+    dtype: str = "float32"
+
+    @classmethod
+    def tiny(cls, **over):
+        """A CPU-test size: hidden 64, 6 layers that cover dense + conv,
+        MoE + attention and MoE + conv, 8 experts of which 2 a token."""
+        base = dict(vocab_size=96, hidden_size=64,
+                    layer_types=(CONV, CONV, ATTN, CONV, CONV, ATTN),
+                    dense_layers=2, intermediate_size=96,
+                    moe_intermediate_size=32, num_experts=8,
+                    experts_per_token=2, num_heads=4, num_kv_heads=2,
+                    head_dim=16)
+        base.update(over)
+        return cls(**base)
+
+
+def _mat(layer, shape, weight_attr, dtype):
+    return layer.create_parameter(
+        list(shape), attr=weight_attr, dtype=dtype,
+        default_initializer=I.Normal(0.0, 0.02))
+
+
+def _product(x, w):
+    """``x [..., a] @ w [a, b]`` with float32 accumulation, in ``x``'s
+    dtype."""
+    return jnp.einsum("...a,ab->...b", x, unwrap(w),
+                      preferred_element_type=jnp.float32).astype(x.dtype)
+
+
+class ShortConv(nn.Layer):
+    """The gated short-convolution mixer (no bias)."""
+
+    def __init__(self, hidden, taps=3, weight_attr=None, dtype=None):
+        super().__init__()
+        self.hidden, self.taps = int(hidden), int(taps)
+        self.in_proj = _mat(self, (hidden, 3 * hidden), weight_attr, dtype)
+        # tap j weighs u(t - (taps-1) + j): the last tap is the token's own
+        self.conv = _mat(self, (hidden, self.taps), weight_attr, dtype)
+        self.out_proj = _mat(self, (hidden, hidden), weight_attr, dtype)
+
+    def cache_spec(self, max_len):
+        return {"kind": ConvStateCache.kind, "heads_per_lane_row": 1,
+                "columns": 0, "wraps": False, "window": None,
+                "select_top": None}
+
+    def gen_cache(self, batch, max_len, dtype="float32"):
+        from ...ops import zeros
+        return ConvStateCache(zeros(
+            [batch, 1, self.taps - 1, self.hidden], dtype=dtype))
+
+    def _gated(self, x, before, live):
+        """``x [B, T, hidden]`` (normed), ``before [B, L-1, hidden]`` the
+        values of ``u`` at the ``L - 1`` columns before the block (zeros
+        where there are none), ``live [B, T]`` the block's columns that
+        belong to the request.  Returns (the mixer's output, ``u`` over
+        ``before`` and the block ``[B, L-1+T, hidden]``)."""
+        T = x.shape[1]
+        b, c, xx = jnp.split(_product(x, self.in_proj), 3, axis=-1)
+        # ``u`` is rounded to the dtype the state keeps it in, so that a
+        # token fed in a chunk and a token fed by a step see the same past
+        u = jnp.where(live[..., None], b * xx, jnp.zeros((), x.dtype))
+        full = jnp.concatenate([before.astype(x.dtype), u], axis=1)
+        w = unwrap(self.conv).astype(jnp.float32)
+        conv = sum(w[:, j] * full[:, j:j + T].astype(jnp.float32)
+                   for j in range(self.taps))
+        y = (c.astype(jnp.float32) * conv).astype(x.dtype)
+        return _product(y, self.out_proj), full
+
+    def forward_cached(self, x, cache, pos, start, write_rows=None):
+        """Feed the block ``x [B, T, hidden]`` (normed) whose first column
+        is ``pos``; ``start [B]`` is each row's first valid column.  The
+        state's entries count iff their column is at or after ``start``;
+        a row outside ``write_rows [B]`` keeps its state as it was."""
+        T, n = x.shape[1], self.taps - 1
+        state = unwrap(cache.state)[:, 0]                     # [B, n, hidden]
+        cols = pos + jnp.arange(-n, T, dtype=jnp.int32)
+        live = cols[None, :] >= start[:, None]                # [B, n + T]
+        before = jnp.where(live[:, :n, None], state,
+                           jnp.zeros((), state.dtype))
+        with jax.named_scope("short_conv"):
+            y, full = self._gated(x, before, live[:, n:])
+            new = full[:, T:].astype(state.dtype)
+            if write_rows is not None:
+                new = jnp.where(write_rows[:, None, None], new, state)
+        return y, ConvStateCache(Tensor(new[:, None]))
+
+    def forward(self, x):
+        """Cache-less over a whole sequence from position 0."""
+        raw = unwrap(x)
+        B, T, h = raw.shape
+        y, _ = self._gated(raw, jnp.zeros((B, self.taps - 1, h), raw.dtype),
+                           jnp.ones((B, T), bool))
+        return y
+
+
+class GroupedQueryAttention(nn.Layer):
+    """``heads`` query heads over ``kv_heads`` cached heads, per-head
+    RMSNorm on q and k, rotary positions; no bias."""
+
+    def __init__(self, hidden, heads, kv_heads, head_dim, rope_base,
+                 epsilon=1e-5, weight_attr=None, dtype=None):
+        super().__init__()
+        if heads % kv_heads:
+            raise ValueError(f"{heads} query heads over {kv_heads} cached")
+        self.H, self.KV, self.d = int(heads), int(kv_heads), int(head_dim)
+        self.rep = self.H // self.KV
+        self.base, self.eps = float(rope_base), float(epsilon)
+        self.q_proj = _mat(self, (hidden, self.H * self.d), weight_attr, dtype)
+        self.k_proj = _mat(self, (hidden, self.KV * self.d), weight_attr,
+                           dtype)
+        self.v_proj = _mat(self, (hidden, self.KV * self.d), weight_attr,
+                           dtype)
+        self.o_proj = _mat(self, (self.H * self.d, hidden), weight_attr,
+                           dtype)
+        self.q_norm = RMSNorm(self.d, epsilon, dtype=dtype)
+        self.k_norm = RMSNorm(self.d, epsilon, dtype=dtype)
+
+    def cache_spec(self, max_len):
+        return {"kind": RingCache.kind,
+                "heads_per_lane_row": kv_heads_per_lane_row(self.d),
+                "columns": int(max_len), "wraps": False, "window": None,
+                "select_top": None}
+
+    def gen_cache(self, batch, max_len, dtype="float32"):
+        """The GPT family's packed ring planes over the CACHED heads:
+        ``[B, ceil(KV/g), max_len, g*d]``."""
+        from ...ops import zeros
+        g = kv_heads_per_lane_row(self.d)
+        plane = [batch, -(-self.KV // g), max_len, g * self.d]
+        return RingCache(zeros(plane, dtype=dtype), zeros(plane, dtype=dtype))
+
+    def _heads(self, x, pos_ids):
+        """q ``[B, H, T, d]``, k and v ``[B, KV, T, d]`` of the normed
+        block ``x``, q and k normed per head and rotated."""
+        B, T, _ = x.shape
+
+        def split(w, n, norm=None):
+            y = _product(x, w).reshape(B, T, n, self.d)
+            if norm is not None:
+                y = rotary(unwrap(norm(y)), pos_ids, self.base)
+            return jnp.swapaxes(y, 1, 2)
+        return (split(self.q_proj, self.H, self.q_norm),
+                split(self.k_proj, self.KV, self.k_norm),
+                split(self.v_proj, self.KV))
+
+    def _out(self, o):
+        B, _, T, _ = o.shape
+        return _product(jnp.swapaxes(o, 1, 2).reshape(B, T, self.H * self.d),
+                        self.o_proj)
+
+    def forward_cached(self, x, cache, pos, start, write_rows=None):
+        """Append the block's K and V at column ``pos`` (a dead row's
+        write lands in a dead column: the slot loop's own discipline, so
+        ``write_rows`` is not needed here) and attend over the live span
+        of the ring in column blocks (``span_attention``): a step's one
+        query a row, or a chunk's block of them."""
+        B, T, _ = x.shape
+        kp, vp = unwrap(cache.k), unwrap(cache.v)
+        C = kp.shape[2]
+        cols = pos + jnp.arange(T, dtype=jnp.int32)
+        pos_ids = jnp.maximum(cols[None, :] - start[:, None], 0)
+        q, k, v = self._heads(x, pos_ids)
+        g = kp.shape[3] // self.d
+        at = pos % jnp.int32(C)
+        kp = ring_block_write(kp, pack_heads(k, g), at)
+        vp = ring_block_write(vp, pack_heads(v, g), at)
+        with jax.named_scope("gqa_attention"):
+            o = span_attention(q, kp, vp, start, pos, rep=self.rep)
+        return self._out(o), RingCache(Tensor(kp), Tensor(vp))
+
+    def forward(self, x):
+        """Cache-less, causal, every token from position 0, one query head
+        group at a time by plain products."""
+        raw = unwrap(x)
+        B, T, _ = raw.shape
+        t = jnp.arange(T, dtype=jnp.int32)
+        q, k, v = self._heads(raw, jnp.broadcast_to(t[None], (B, T)))
+        k, v = (jnp.repeat(a, self.rep, axis=1) for a in (k, v))
+        s = jnp.einsum("bhtd,bhsd->bhts", q, k,
+                       preferred_element_type=jnp.float32) * self.d ** -0.5
+        p = jax.nn.softmax(jnp.where(t[None, :] <= t[:, None], s, -1e30), -1)
+        o = jnp.einsum("bhts,bhsd->bhtd", p.astype(raw.dtype), v,
+                       preferred_element_type=jnp.float32).astype(raw.dtype)
+        return self._out(o)
+
+
+class HybridDecoderLayer(nn.Layer):
+    def __init__(self, cfg: HybridConvConfig, index: int, weight_attr=None):
+        super().__init__()
+        kind = cfg.layer_types[index]
+        if kind == CONV:
+            self.mixer = ShortConv(cfg.hidden_size, cfg.conv_taps,
+                                   weight_attr, cfg.dtype)
+        elif kind == ATTN:
+            self.mixer = GroupedQueryAttention(
+                cfg.hidden_size, cfg.num_heads, cfg.num_kv_heads,
+                cfg.head_dim, cfg.rope_base, cfg.rms_eps, weight_attr,
+                cfg.dtype)
+        else:
+            raise ValueError(f"layer_types[{index}] = {kind!r}")
+        self.operator_norm = RMSNorm(cfg.hidden_size, cfg.rms_eps,
+                                     dtype=cfg.dtype)
+        self.ffn_norm = RMSNorm(cfg.hidden_size, cfg.rms_eps, dtype=cfg.dtype)
+        if index < cfg.dense_layers:
+            self.ffn = SwiGLU(cfg.hidden_size, cfg.intermediate_size,
+                              weight_attr, cfg.dtype)
+        else:
+            self.ffn = DroplessMoE(
+                cfg.hidden_size, cfg.moe_intermediate_size, cfg.num_experts,
+                cfg.experts_per_token, held=None, shared=0,
+                scaling=cfg.routed_scaling, norm_topk=cfg.norm_topk,
+                norm_eps=cfg.routing_norm_eps, weight_attr=weight_attr,
+                dtype=cfg.dtype)
+
+    # the residual stream is float32 whatever the weights are (as in the
+    # latent family: text/models/latent_moe.py says why)
+    def _operand(self, norm, x):
+        """``norm(x)`` rounded to the weights' dtype, as a product's
+        operand."""
+        return unwrap(norm(x)).astype(unwrap(self.ffn.w_gate).dtype)
+
+    def _ffn(self, h, live):
+        u = self._operand(self.ffn_norm, h)
+        y = self.ffn(u, live) if isinstance(self.ffn, DroplessMoE) \
+            else self.ffn(u)
+        return h + unwrap(y).astype(jnp.float32)
+
+    def forward_cached(self, x, cache, pos, start, write_rows, live):
+        a, cache = self.mixer.forward_cached(
+            self._operand(self.operator_norm, x), cache, pos, start,
+            write_rows)
+        return self._ffn(x + a.astype(jnp.float32), live), cache
+
+    def forward(self, x):
+        a = unwrap(self.mixer(self._operand(self.operator_norm, x)))
+        return self._ffn(x + a.astype(jnp.float32), None)
+
+
+# what ``decode_counts`` returns, in order (the latent family's names: the
+# slot loop sums the first two and keeps the largest of the third)
+DECODE_COUNT_NAMES = ("moe_assignments", "moe_assignments_held",
+                      "moe_expert_tokens_max")
+
+
+class HybridConvDecoder(nn.Layer):
+    """``weight_attr`` (a ``ParamAttr``) reaches every matrix: a server
+    that installs its own weights builds with a constant initializer."""
+
+    # a slot loop's step hands over its live rows: a row that is not fed
+    # keeps its conv state
+    cached_forward_takes_rows = True
+    decode_count_names = DECODE_COUNT_NAMES
+
+    def __init__(self, cfg: HybridConvConfig = None, weight_attr=None,
+                 **kwargs):
+        super().__init__()
+        cfg = cfg or HybridConvConfig(**kwargs)
+        self.config = cfg
+        self.embed = nn.Embedding(cfg.vocab_size, cfg.hidden_size,
+                                  weight_attr=weight_attr)
+        if str(self.embed.weight.dtype) != cfg.dtype:
+            # nn.Embedding builds in the default dtype
+            w = self.embed.weight
+            w._value = w._value.astype(cfg.dtype)
+        self.layers = nn.LayerList([
+            HybridDecoderLayer(cfg, i, weight_attr)
+            for i in range(len(cfg.layer_types))])
+        self.norm = RMSNorm(cfg.hidden_size, cfg.rms_eps, dtype=cfg.dtype)
+        self._counts = None
+
+    def _logits(self, h):
+        table = unwrap(self.embed.weight)
+        return jnp.einsum("bth,vh->btv",
+                          unwrap(self.norm(h)).astype(table.dtype), table,
+                          preferred_element_type=jnp.float32)
+
+    def forward(self, input_ids):
+        h = unwrap(self.embed(input_ids)).astype(jnp.float32)
+        for layer in self.layers:
+            h = layer(h)
+        return Tensor(self._logits(h))
+
+    # -- incremental decoding --------------------------------------------------
+    def cache_spec(self, max_len):
+        """Per layer, what it keeps: ``kv`` ring planes as long as the
+        session, or a ``conv_state`` plane without columns."""
+        return [l.mixer.cache_spec(max_len) for l in self.layers]
+
+    def init_cache(self, batch, max_len, dtype=None):
+        if dtype is None:
+            dtype = str(self.embed.weight.dtype)
+        return [l.mixer.gen_cache(batch, max_len, dtype) for l in self.layers]
+
+    def forward_cached(self, input_ids, cache, cache_position,
+                       start_positions, write_rows=None):
+        """Append ``input_ids [B, T]`` at column ``cache_position`` (the
+        LEFT-padded prompt or a chunk of it, or one token a row) and
+        return (logits ``[B, T, V]`` float32, the updated caches).
+        ``write_rows [B]`` marks the rows a slot loop's step feeds."""
+        ids = unwrap(input_ids)
+        T = ids.shape[1]
+        pos = unwrap(cache_position)
+        pos = jnp.int32(pos) if isinstance(pos, int) \
+            else jnp.asarray(pos, jnp.int32)
+        start = jnp.asarray(unwrap(start_positions), jnp.int32)
+        rows = None if write_rows is None else unwrap(write_rows)
+        h = unwrap(self.embed(Tensor(ids))).astype(jnp.float32)
+        live = (pos + jnp.arange(T, dtype=jnp.int32))[None, :] \
+            >= start[:, None]
+        if rows is not None:
+            live = live & rows[:, None]
+        zero = jnp.int32(0)
+        counts, new = (zero, zero, zero), []
+        for layer, c in zip(self.layers, cache):
+            h, c = layer.forward_cached(h, c, pos, start, rows, live)
+            new.append(c)
+            if isinstance(layer.ffn, DroplessMoE):
+                a, b, m = layer.ffn.last_counts
+                counts = (counts[0] + a, counts[1] + b,
+                          jnp.maximum(counts[2], m))
+        self._counts = counts
+        return Tensor(self._logits(h)), new
+
+    def decode_counts(self):
+        """int32 ``[3]`` of the last ``forward_cached`` (same trace):
+        ``DECODE_COUNT_NAMES``, over the live tokens of all MoE layers."""
+        return jnp.stack(self._counts) if self._counts is not None else None
+
+    def generate(self, input_ids, lengths=None, max_new_tokens=32, **kw):
+        from ..generation import generate as _generate
+        return _generate(self, input_ids, lengths=lengths,
+                         max_new_tokens=max_new_tokens, **kw)

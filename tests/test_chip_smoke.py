@@ -66,6 +66,14 @@ def test_layouts_phase_tiny(no_native_build, weight_wishes):
     assert rec["checked"]["chunk_logits_bit_equal"]
 
 
+def test_hybrid_phase_tiny(no_native_build):
+    """Conv states beside K/V planes: chunks + 32 steps and a slot loop
+    with reused slots and waiting rows, against the plain reference."""
+    rec = chip_smoke.phase_hybrid(chip_smoke.TINY)["checked"]
+    assert rec["logits_rel_worst"] < 1e-4 and rec["served_gap_widest"] < 1e-4
+    assert rec["state_rows_held"] > 0 and rec["steps"] == 32
+
+
 def test_kernels_phase_tiny_interprets_on_cpu(no_native_build):
     rec = chip_smoke.phase_kernels(chip_smoke.TINY)
     assert rec["checked"]["compiled_not_interpreted"] is False

@@ -121,6 +121,74 @@ def test_block_loop_over_the_planes(tool, operands, body_extra, loop_copies):
     assert any("while loop" in f for f in faults) == bool(loop_copies)
 
 
+# -- state planes: a cache without columns (ISSUE 31) ------------------------
+_STATE = "bf16[3,1,2,64]{3,2,1,0:T(2,128)(2,1)}"
+_KV = "bf16[3,1,64,128]{3,2,1,0:T(8,128)(2,1)}"
+
+
+def _hybrid_hlo(step=True, extra=""):
+    """The tiny hybrid model's programs in outline: one conv layer's state
+    plane and one attention layer's K plane, both aliased.  The step hands
+    the state back whole; the chunk splices one row into it."""
+    write = (f"  %fusion.7 = {_STATE} fusion(%cache_0__0_.1, %u.1), kind=kLoop\n"
+             if step else
+             f"  %dynamic_update_slice.3 = {_STATE} dynamic-update-slice("
+             "%cache_0__0_.1, %row.1, %rowidx.1, %c.0, %c.0, %c.0), "
+             'backend_config={"indices_config":{"is_index_aligned":'
+             "[false,true,true,true]}}\n")
+    out = "%fusion.7" if step else "%dynamic_update_slice.3"
+    return f"""HloModule jit_step, is_scheduled=true, input_output_alias={{ {{0}}: (0, {{}}, may-alias), {{1}}: (1, {{}}, may-alias) }}
+
+ENTRY %main.1 (c0: {_STATE}, c1: {_KV}) -> ({_STATE}, {_KV}) {{
+  %cache_0__0_.1 = {_STATE} parameter(0), sharding={{replicated}}, metadata={{op_name="cache[0][0]"}}
+  %cache_1__0_.1 = {_KV} parameter(1), sharding={{replicated}}, metadata={{op_name="cache[1][0]"}}
+  %dynamic_update_slice.1 = {_KV} dynamic-update-slice(%cache_1__0_.1, %new.1, %c.0, %c.0, %pos.1, %c.0), backend_config={{"indices_config":{{"is_index_aligned":[true,true,false,true]}}}}
+{write}{extra}  ROOT %tuple.1 = ({_STATE}, {_KV}) tuple({out}, %dynamic_update_slice.1)
+}}
+"""
+
+
+_SHAPES = {(3, 1, 2, 64), (3, 1, 64, 128)}
+
+
+@pytest.mark.parametrize("step", [True, False], ids=["step", "chunk"])
+def test_state_planes_are_reported_apart(tool, step):
+    facts = tool.inspect(_hybrid_hlo(step), _SHAPES, {(3, 1, 2, 64)})
+    assert facts["state_planes"] == [{"shape": [3, 1, 2, 64],
+                                      "minor_to_major": [3, 2, 1, 0],
+                                      "count": 1}]
+    assert facts["planes"] == [{"shape": [3, 1, 64, 128],
+                                "minor_to_major": [3, 2, 1, 0], "count": 1}]
+    assert facts["state_planes_aliased"] == 1
+    assert facts["planes_aliased"] == facts["planes_total"] == 2
+    # the K plane's write carries its index on the columns; the chunk's
+    # splice of a state row on the rows; the step writes no slice of it
+    assert [w["unaligned_index_dims"] for w in facts["writes"]] == [[2]]
+    if step:
+        assert facts["state_writes"] == "none in ENTRY"
+    else:
+        assert facts["state_writes"] == [{
+            "minor_to_major": [3, 2, 1, 0], "unaligned_index_dims": [0],
+            "on_minor_most": False, "count": 1}]
+    assert facts["state_plane_copies"] == facts["whole_plane_copies"] == 0
+    assert tool._faults("step", facts) == []
+
+
+def test_a_state_plane_copied_whole_is_a_fault(tool):
+    """What a per-row blend that the compiler cannot do in place, or a
+    relayout on the way to the step's operands, looks like."""
+    other = _STATE.replace("{3,2,1,0:T(2,128)", "{3,0,2,1:T(8,128)")
+    text = _hybrid_hlo(extra=f"  %copy.51 = {other} copy(%cache_0__0_.1)\n"
+                             f"  %copy.49 = {_STATE} copy(%fusion.9)\n")
+    facts = tool.inspect(text, _SHAPES, {(3, 1, 2, 64)})
+    assert facts["state_plane_copies"] == 2
+    assert facts["whole_plane_copies"] == 0          # counted apart
+    faults = tool._faults("step", facts)
+    assert len(faults) == 1 and "state plane" in faults[0]
+    # a model without such layers reports as before
+    assert "state_planes" not in tool.inspect(text, _SHAPES)
+
+
 # -- weights copied again in every run (ISSUE 30) ----------------------------
 _W = "bf16[1600,1600]"
 _SLICE = "(bf16[400,1600]{1,0:T(8,128)(2,1)S(1)}, bf16[1600,1600]{1,0:T(8,128)(2,1)}, u32[]{:S(2)})"

@@ -21,6 +21,14 @@ program) and prints, from ``compiled.as_text()``:
     plane, and the copies of a cache row ``[1, ., C, .]`` whose operand
     has another layout (the relayout a lane-major plane forces on the
     chunk's block write);
+  * the STATE planes apart (a layer whose ``cache_spec`` has no columns: a
+    short convolution's last inputs, ``[S, 1, L-1, hidden]``): their
+    layout, whether each is aliased in place, which dimension carries the
+    index of a write into one (the chunk splices ONE row, index on the
+    row dimension, where the compiler leaves that splice an instruction
+    of its own; the step writes no slice: it hands back the whole plane
+    with the rows it did not feed as they were), and the copies of a whole
+    state plane in either program;
   * the ``while`` loops of each program (the decode attention reads the
     planes in column blocks under one, ``cached_attention``; the step's
     line says how wide a block is): whether a plane enters one as a
@@ -40,8 +48,8 @@ how many the two disagreed.
 
 Exit code 1 when a write's traced index lies on the minor-most dimension,
 a plane is not aliased, a whole plane is copied (on its way into a loop
-and inside one too), a cache row changes layout, or a weight on which the
-two programs did not disagree is still copied.  Run by hand, one process at
+and inside one too; a state plane too), a cache row changes layout, or a
+weight on which the two programs did not disagree is still copied.  Run by hand, one process at
 a time: only one process may load libtpu, so this is not a pytest file.
 """
 from __future__ import annotations
@@ -157,19 +165,23 @@ def _aliased_params(hlo_text):
     return {int(p) for p in re.findall(r"\{\d+\}: \((\d+), \{\}", head)}
 
 
-def inspect(hlo_text, plane_shapes):
-    """The facts above for one compiled program, as a dict."""
+def inspect(hlo_text, plane_shapes, state_shapes=frozenset()):
+    """The facts above for one compiled program, as a dict.
+    ``state_shapes`` are those of ``plane_shapes`` that have no columns."""
     instrs = _entry(hlo_text)
+    state_shapes = set(state_shapes)
+    state_rows = {(1,) + s[1:] for s in state_shapes}
     planes = {}                      # instruction name -> parameter number
     for name, (dims, layout, op, _args, line) in instrs.items():
         if op == "parameter" and 'op_name="cache[' in line \
                 and dims in plane_shapes:
             planes[name] = int(re.search(r"parameter\((\d+)\)", line)[1])
-    layouts = collections.Counter(
-        (instrs[n][0], instrs[n][1]) for n in planes)
+    layouts, state_layouts = (collections.Counter(
+        (instrs[n][0], instrs[n][1]) for n in planes
+        if (instrs[n][0] in state_shapes) == kept) for kept in (False, True))
     aliased = _aliased_params(hlo_text)
     rows = {(1,) + s[1:] for s in plane_shapes}
-    writes = collections.Counter()
+    writes, state_writes = collections.Counter(), collections.Counter()
     for name, (dims, layout, op, args, line) in instrs.items():
         if op != "dynamic-update-slice" or \
                 not (dims in plane_shapes or dims in rows):
@@ -177,28 +189,45 @@ def inspect(hlo_text, plane_shapes):
         m = re.search(r'"is_index_aligned":\[([\w,]*)\]', line)
         unaligned = tuple(i for i, a in enumerate(m[1].split(","))
                           if a != "true") if m else ()
-        writes[(layout, unaligned)] += 1
-    plane_copies, row_relayouts = [], []
+        (state_writes if dims in state_shapes or dims in state_rows
+         else writes)[(layout, unaligned)] += 1
+    plane_copies, state_copies, row_relayouts = [], [], []
     for name, (dims, layout, op, args, _line) in instrs.items():
         if op not in ("copy", "transpose") or not args:
             continue
         src = instrs.get(args[0])
-        if dims in plane_shapes:
+        if dims in state_shapes:
+            state_copies.append(name)
+        elif dims in plane_shapes:
             plane_copies.append(name)
-        elif dims in rows and src is not None and src[1] != layout:
+        elif dims in rows and dims not in state_rows \
+                and src is not None and src[1] != layout:
             row_relayouts.append(name)
     loops, loop_copies = _while_plane_copies(hlo_text, instrs, plane_shapes)
+    def as_writes(counter):
+        return [{"minor_to_major": list(l), "unaligned_index_dims": list(u),
+                 "on_minor_most": bool(l) and l[0] in u, "count": c}
+                for (l, u), c in counter.items()]
+
+    state = {} if not state_shapes else {
+        "state_planes": [{"shape": list(s), "minor_to_major": list(l),
+                          "count": c} for (s, l), c in state_layouts.items()],
+        "state_planes_aliased": sum(
+            1 for n, p in planes.items()
+            if instrs[n][0] in state_shapes and p in aliased),
+        # no write of a slice among ENTRY's instructions: the program hands
+        # the plane back whole, or its splice of a row is fused
+        "state_writes": as_writes(state_writes) or "none in ENTRY",
+        "state_plane_copies": len(state_copies)}
     return {
+        **state,
         "while_loops": loops,
         "while_plane_copies": len(loop_copies),
         "planes": [{"shape": list(s), "minor_to_major": list(l), "count": c}
                    for (s, l), c in layouts.items()],
         "planes_aliased": sum(1 for p in planes.values() if p in aliased),
         "planes_total": len(planes),
-        "writes": [{"minor_to_major": list(l),
-                    "unaligned_index_dims": list(u),
-                    "on_minor_most": bool(l) and l[0] in u, "count": c}
-                   for (l, u), c in writes.items()],
+        "writes": as_writes(writes),
         "whole_plane_copies": len(plane_copies),
         "row_relayout_copies": len(row_relayouts),
     }
@@ -217,6 +246,13 @@ def _faults(what, facts):
     if facts["whole_plane_copies"]:
         out.append(f"{what}: {facts['whole_plane_copies']} copies or "
                    "transposes of a whole plane")
+    if facts.get("state_plane_copies"):
+        out.append(f"{what}: {facts['state_plane_copies']} copies or "
+                   "transposes of a whole state plane")
+    for w in facts.get("state_writes") or ():
+        if isinstance(w, dict) and w["on_minor_most"]:
+            out.append(f"{what}: {w['count']} writes into a state plane "
+                       "carry their traced index on the lane dimension")
     if facts["while_plane_copies"]:
         out.append(f"{what}: {facts['while_plane_copies']} whole planes "
                    "copied into a while loop or inside one")
@@ -286,8 +322,12 @@ def main(argv):
     S, C, T = sv["slots"], sv["max_len"], sv["prefill_chunk"]
     if len(argv) > 1:
         S = int(argv[1])            # try another slot count
-    plane_shapes = {tuple(p.shape) for c in gen.slot_cache_avals_all(S, C)
-                    for p in c}
+    planes = gen.slot_cache_avals_all(S, C)
+    plane_shapes = {tuple(p.shape) for c in planes for p in c}
+    # a layer without columns keeps a state (text/generation.py cache_spec)
+    state_shapes = {tuple(p.shape)
+                    for c, spec in zip(planes, gen.cache_spec(C))
+                    if not spec["columns"] for p in c}
     n_state = len(jax.tree_util.tree_leaves(gen._state_avals()))
     progs = {"step": gen._step_program(S, C),
              "chunk": gen._chunk_program(S, T, C)}
@@ -302,7 +342,7 @@ def main(argv):
     faults = []
     for what, compiled in served.items():
         text = compiled.as_text()
-        facts = inspect(text, plane_shapes)
+        facts = inspect(text, plane_shapes, state_shapes)
         if what == "step" and "kv" in gen.plane_kinds():
             # the column blocks of the step's attention (cached_attention)
             facts = {"attn_block": decode_block(C), **facts}
