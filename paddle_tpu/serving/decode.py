@@ -309,8 +309,10 @@ class _DecodeRuntime:
     def _warmup_slots(self):
         """Slot-mode warm-up: lint-gate + AOT-compile the step and chunk
         executables (persistent cache + ledger, like every grid point),
-        build the SlotLoop, run one dummy request end-to-end so every
-        dispatch path is warm, then zero the loop accounting."""
+        build the SlotLoop (which compiles its data movers the same way,
+        the activation's row write among them), run one dummy request
+        end-to-end so every dispatch path is warm (a chunk, the row
+        write, a step), then zero the loop accounting."""
         from .slots import SlotLoop
         S, C, T = self.slots, self._slot_cache, self.chunk_width
         self.lint_gate_slot(S, C)

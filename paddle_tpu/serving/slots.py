@@ -15,7 +15,14 @@ level scheduling — over TWO slot executables the Generator compiles per
 
 They come as a pair (``Generator.slot_execs(S, T, C)``): both run
 thousands of times over one copy of the weights, so the pair first
-settles in which device layout each weight lies.
+settles in which device layout each weight lies.  A third, small
+program joins them (``Generator.put_logits_row_exec(S)``): it writes the
+final chunk's logits into the joining row of the step's ``[S, V]``
+logits, in place and on the device.  That plane and a row's activation
+logits stay device arrays from the program that made them to the step
+that reads them: the driver thread, the only one that feeds the device,
+fetches neither, so it dispatches the next step BEHIND a final chunk
+and not after it.
 
 The scheduling invariants that make slot reuse BIT-EXACT against a
 per-request ``generate()`` of the same prompt:
@@ -85,7 +92,17 @@ What the loop measures about itself, always on:
   * **the driver thread's phases** — every moment of an iteration lies
     in exactly one of ``PHASES`` (flat, never nested), each a
     ``profiler.span`` named by ``SPAN_NAMES`` on the device trace's
-    clock, its seconds accumulated into ``stats()["phase_s"]``;
+    clock, its seconds accumulated into ``stats()["phase_s"]``.
+    ``activate`` is host bookkeeping and one dispatch of the row write
+    for each row that joins; ``chunk_fetch`` is the reading of the
+    chunks' counts (a model that hands none back never enters it) and,
+    in the speculative loop, of a final chunk's logits;
+  * **how a row was activated** — ``counters["rows_activated"]``, and
+    ``["logits_bytes_via_host"]``: the bytes of logits that crossed the
+    host boundary for it, either way (0 for a row whose final chunk
+    made them; ``V x 4`` up for a session row resumed from its
+    snapshot's, and ``V x 4`` down in the speculative loop, whose step
+    carries a token for each row and no logits);
   * **a request's life** — ``SlotRequest`` carries the stamps
     ``t_arrival .. t_reply``; once replied, its phases feed
     ``stats()["phases_ms"]``, ``decode_slot_phase_seconds`` and, under
@@ -93,6 +110,12 @@ What the loop measures about itself, always on:
   * **every slot-step** — each decode step counts its ``S`` slots as
     emitting, prefilling, drain-blocked or without demand
     (``counters["slot_steps_*"]``, summing to ``steps x S``);
+  * **the model's own counts** — a step's ride its token read-back; a
+    chunk's are read behind the NEXT step's read-back, where they wait
+    for nothing (the device runs its programs in order), and committed
+    with that step: the ``chunk_*`` counters run one step behind the
+    chunks, and a chunk that no step follows is counted when the loop
+    closes;
   * **the span a step's attention reads** — the step program attends
     in column blocks from the oldest generating row's ``start`` to the
     shared frontier (``cached_attention``); ``counters["attn_blocks_
@@ -125,9 +148,11 @@ __all__ = ["SlotLoop", "SlotRequest", "PHASES", "SPAN_NAMES",
 _EMPTY, _PREFILL, _GEN = 0, 1, 2
 
 # the driver thread's phases in loop order: the keys of
-# ``stats()["phase_s"]``.  ``idle_wait`` (nothing live), ``chunk_fetch``
-# (a final chunk's logits) and ``step_fetch`` (the step's tokens) wait;
-# the other five are the host's own work.
+# ``stats()["phase_s"]``.  ``idle_wait`` (nothing live) and ``step_fetch``
+# (the step's tokens) wait; ``chunk_fetch`` reads the chunks' counts
+# behind a step's tokens, where they have arrived (the speculative loop
+# waits there for a final chunk's logits); the other five are the host's
+# own work, the dispatch of a row's activation write under ``activate``.
 PHASES = ("idle_wait", "admit", "chunk_dispatch", "chunk_fetch", "activate",
           "step_dispatch", "step_fetch", "retire")
 # their spans in a profiler capture; spelled here and nowhere else
@@ -199,6 +224,7 @@ class _Slot:
 
     def __init__(self):
         self.state = _EMPTY
+        self._act_logits = None         # the final chunk's logits, on the device
         self.req: Optional[SlotRequest] = None
         self.chunks: List[np.ndarray] = []
         self.next_chunk = 0
@@ -263,6 +289,12 @@ class SlotLoop:
         self._step, self._chunk = gen.slot_execs(self.S, self.T, self.C,
                                                  eos_token_id)
         self._kv_heads_per_lane_row = gen.kv_heads_per_lane_row()
+        # a row's activation logits go from the chunk's output into the
+        # step's input on the device; a speculative step carries tokens,
+        # not logits, so it has no plane to write into
+        self._put_row = None if self._spec \
+            else gen.put_logits_row_exec(self.S)
+        self._row_bytes = 4 * gen._vocab_size()
         # the KV reuse plane (prefix cache / session store): its three
         # data movers compile HERE, with the step/chunk programs, so an
         # arbitrary steady-state hit/miss/park/restore mix never
@@ -290,6 +322,7 @@ class SlotLoop:
                          "chunks": 0, "session_resets": 0,
                          "emitted_tokens": 0, "parked": 0, "restored": 0,
                          "prefix_hit_tokens": 0, "restore_pushes": 0,
+                         "rows_activated": 0, "logits_bytes_via_host": 0,
                          **{f"slot_steps_{k}": 0 for k in _SLOT_STATES},
                          **dict.fromkeys(self._count_names, 0)}
         if self._select_tops:
@@ -546,6 +579,7 @@ class SlotLoop:
                 self._ph_acc[k] = 0.0
 
     def _drive(self):
+        died = False
         try:
             while True:
                 self._phase("admit")
@@ -587,6 +621,7 @@ class SlotLoop:
                     continue
                 self._decode_step()
         except BaseException as e:   # noqa: BLE001 — fail rows, not host
+            died = True
             with self._cond:
                 self._dead = e
                 if self._park_req is not None:
@@ -598,8 +633,13 @@ class SlotLoop:
                     s.state, s.req = _EMPTY, None
                 self._fail_pending(e)
         finally:
+            if not died:
+                # chunks that no step followed (their row was parked):
+                # their counts belong to the window all the same
+                self._read_chunk_counts()
             self._phase(None)
             with self._cond:
+                self._commit_tally()
                 self._commit_phases()
 
     def _any_live(self) -> bool:
@@ -798,17 +838,24 @@ class SlotLoop:
                 self.counters["chunks"] += 1
                 if slot.next_chunk == len(slot.chunks):
                     # final chunk: its last column is the last prompt
-                    # token — stash the activation logits for this row.
-                    # MUST be a host copy: activation reads it one or
-                    # more dispatches later, after the runtime may have
-                    # reused the output buffer a zero-copy view aliases.
-                    self._phase("chunk_fetch")
-                    slot._act_logits = np.array(logits, np.float32)
-                    # the chunks' counts, all dispatched before these
-                    # logits: reading them waits for nothing more
-                    for h in self._chunk_counts:
-                        self._tally_counts(np.asarray(h), chunk=True)
-                    self._chunk_counts = []
+                    # token, so these are the row's activation logits.
+                    # They stay the device array they are (holding it
+                    # keeps its buffer): nothing is fetched, and the
+                    # driver goes on to dispatch behind the chunk
+                    slot._act_logits = logits
+
+    def _read_chunk_counts(self):
+        """Driver thread: tally the counts of the chunks dispatched
+        since the last reading.  Called where that waits for nothing:
+        behind a step's read-back (the device runs its programs in
+        order, so every chunk dispatched before the step has finished),
+        which commits them with that step; and when the loop ends."""
+        if not self._chunk_counts:
+            return
+        self._phase("chunk_fetch")
+        for h in self._chunk_counts:
+            self._tally_counts(np.asarray(h), chunk=True)
+        self._chunk_counts = []
 
     def _tally_columns(self, cols, start, chunk=False):
         """Driver thread: the columns at which a dispatch appends tokens
@@ -907,27 +954,31 @@ class SlotLoop:
             self._finished[i] = False
             self._active = self._active.copy()
             self._active[i] = True
-            if not slot.chunks:
-                # mid-generation resume: no suffix chunk produced the
-                # activation logits — the snapshot carried the payload
-                # (the exact values the pre-park loop held for this row)
-                if self._spec:
-                    self._cur = self._cur.copy()
-                    self._cur[i] = np.int32(slot.req.resume_cur)
-                else:
-                    lg = np.array(self._logits)
-                    lg[i] = slot.req.resume_logits
-                    self._logits = lg
-            elif self._spec:
-                # first committed token = target argmax over the final
-                # chunk's logits (the joint-prefill cur0 computation)
-                act = slot._act_logits
+            # a mid-generation resume has no suffix chunk to produce the
+            # activation payload: the snapshot carried it (the exact
+            # values the pre-park loop held for this row)
+            if self._spec:
+                cur = slot.req.resume_cur
+                if slot.chunks:
+                    # first committed token = target argmax over the final
+                    # chunk's logits (the joint-prefill cur0 computation),
+                    # fetched now that the row needs it
+                    self._phase("chunk_fetch")
+                    cur = np.argmax(np.asarray(slot._act_logits))
+                    self._phase("activate")
+                    self._add("logits_bytes_via_host", self._row_bytes)
                 self._cur = self._cur.copy()
-                self._cur[i] = np.int32(np.argmax(act))
+                self._cur[i] = np.int32(cur)
             else:
-                lg = np.array(self._logits)
-                lg[i] = slot._act_logits
-                self._logits = lg
+                row = slot._act_logits
+                if not slot.chunks:
+                    row = slot.req.resume_logits    # host [V]: up, once
+                    self._add("logits_bytes_via_host", self._row_bytes)
+                # written on the device, in place (the plane is donated):
+                # the step's logits never come to the host
+                self._logits = self._put_row(self._logits, row, np.int32(i))
+            slot._act_logits = None
+            self._add("rows_activated", 1)
             slot.state = _GEN
             self._publish_prefix(i, slot)
 
@@ -986,6 +1037,7 @@ class SlotLoop:
         self._phase("step_fetch")
         tok = np.asarray(tok)
         self._finished = np.array(finished)
+        self._read_chunk_counts()
         self._phase("retire")
         # the model's counts came back behind the S tokens
         self._tally_counts(tok[self.S:])
@@ -1028,6 +1080,7 @@ class SlotLoop:
         self._finished = np.array(finished)
         e = np.asarray(e)
         k = int(ncommit)
+        self._read_chunk_counts()
         self._phase("retire")
         self.pos += k
         self._accepted += int(n)
@@ -1088,7 +1141,9 @@ class SlotLoop:
             if self._spec:
                 cur = int(self._cur[i])
             else:
-                logits = np.array(self._logits[i], np.float32)
+                # the whole plane comes down, once a drain (an eager
+                # index on the device array would compile, unledgered)
+                logits = np.array(np.asarray(self._logits)[i], np.float32)
         self._sessions.put(SessionSnapshot(
             session_id=req.session_id, model=self._model, tokens=tokens,
             remaining=int(remaining), emitted=[int(t) for t in slot.emitted],
@@ -1130,6 +1185,7 @@ class SlotLoop:
         self._finished[i] = True
         self._active = self._active.copy()
         self._active[i] = False
+        slot._act_logits = None         # a row parked before it activated
         if self._spec:
             self._cur = self._cur.copy()
             self._cur[i] = 0

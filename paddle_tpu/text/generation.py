@@ -782,6 +782,29 @@ class Generator:
         return self._compile_data(key, "kv_pull_row", pull, avals,
                                   {"slots": S, "cache": C})
 
+    def put_logits_row_exec(self, S):
+        """AOT write of one row into the slot step's logits (ledger kind
+        ``logits_put_row``): ``(logits [S, V] f32, row [V] f32, rowidx)
+        -> logits``.  How the slot loop activates a row: the final
+        chunk's logits go from the chunk program's output into the step
+        program's input without leaving the device.  ``logits`` is
+        donated, so the write is in place and no second ``[S, V]``
+        buffer lives beside the planes."""
+        self._require_unsharded_slots()
+        vocab = self._vocab_size()
+        key = self._key("put_logits_row", S, None, None, None, None)
+
+        def put(logits, row, rowidx):
+            return lax.dynamic_update_slice(logits, row[None, :],
+                                            (rowidx, jnp.int32(0)))
+
+        avals = (jax.ShapeDtypeStruct((S, vocab), jnp.float32),
+                 jax.ShapeDtypeStruct((vocab,), jnp.float32),
+                 jax.ShapeDtypeStruct((), jnp.int32))
+        return self._compile_data(key, "logits_put_row", put, avals,
+                                  {"slots": S, "vocab": vocab},
+                                  donate_argnums=(0,))
+
     def init_slot_cache(self, S, C):
         """Zero device planes for a fresh slot session — never compiled
         as a program of its own (validity windows make the init values
@@ -929,11 +952,16 @@ class Generator:
         if donate_argnums is not None:
             jit_kw["donate_argnums"] = donate_argnums
         ex, _loaded = _pcache.load_or_compile(
-            lambda: jax.jit(fn, **jit_kw).lower(*arg_avals).compile(),
+            lambda: self._lower_data(fn, arg_avals, jit_kw),
             site=self._site, kind=kind, key=key,
             extra_key=self._program_identity(), extra=extra)
         self._execs[key] = ex
         return ex
+
+    def _lower_data(self, fn, arg_avals, jit_kw):
+        """Lower and compile a data mover, ``fn(*args)``: ``_lower``
+        without the state."""
+        return jax.jit(fn, **jit_kw).lower(*arg_avals).compile()
 
     def is_compiled(self, phase, B, P=None, C=None, steps=None,
                     beam=1, eos_token_id=None) -> bool:

@@ -196,6 +196,110 @@ def test_slot_loop_equals_the_reference_and_counts_in_one_piece(served):
         and 0 < st["attn_blocks_read"] <= st["attn_blocks_total"]
 
 
+def _spy_counts(loop):
+    """A blocking read of every dispatch's own counts, as it returns: the
+    chunks' and the steps', each a list of int rows by
+    ``decode_count_names``."""
+    chunk, step, S = loop._chunk, loop._step, loop.S
+    chunks, steps = [], []
+
+    def chunk_read(*args):
+        out = chunk(*args)
+        chunks.append(np.asarray(out[2]).astype(np.int64))
+        return out
+
+    def step_read(*args):
+        out = step(*args)
+        steps.append(np.asarray(out[3])[S:].astype(np.int64))
+        return out
+
+    loop._chunk, loop._step = chunk_read, step_read
+    return chunks, steps
+
+
+def _assert_counts_equal_the_blocking_read(st, chunks, steps):
+    chunks, steps = np.array(chunks), np.array(steps).reshape(-1, 3)
+    assert st["chunks"] == len(chunks)
+    assert st["chunk_moe_assignments"] == chunks[:, 0].sum()
+    assert st["chunk_moe_assignments_held"] == chunks[:, 1].sum()
+    assert st["moe_assignments"] == chunks[:, 0].sum() + steps[:, 0].sum()
+    assert st["moe_expert_tokens_max"] == max(chunks[:, 2].max(),
+                                              steps[:, 2].max(initial=0))
+
+
+@pytest.mark.parametrize("requests,slots", [
+    (REQUESTS, 3),
+    # its final chunk, its activation and its only step in one iteration:
+    # the row is gone before any further step
+    ([(9, 1)], 1),
+    # the short row retires (column 14) while the long one is between its
+    # chunks 1 and 2: the next iteration dispatches chunk 2, finds no row
+    # generating and steps nothing
+    ([(5, 6), (13, 4)], 2),
+], ids=["churn", "retires-at-its-first-step", "a-chunk-and-no-step"])
+def test_chunk_counts_read_a_step_later_equal_a_blocking_read(
+        served, requests, slots):
+    """The loop reads a chunk's counts behind the NEXT step's tokens, not
+    where it dispatched the chunk; what it commits equals what a read at
+    the dispatch gives, over the whole window."""
+    _, model, _ = served
+    gen = Generator(model, max_len=64, seq_buckets=[64])
+    loop = SlotLoop(gen, slots=slots, cache_len=64, chunk=4)
+    chunks, steps = _spy_counts(loop)
+    rng = np.random.default_rng(3)
+    try:
+        futs = [loop.submit(rng.integers(0, VOCAB, n).astype(np.int32), k)
+                for n, k in requests]
+        for f in futs:
+            f.result(timeout=300)
+        # every chunk was followed by a step, and a step's commit comes
+        # before its rows' replies: nothing is left to read
+        assert loop._chunk_counts == []
+        replied = dict(loop.counters)
+    finally:
+        loop.close()
+    st = loop.stats()       # the driver's last phases are committed now
+    assert {k: st[k] for k in replied} == replied   # its end added nothing
+    _assert_counts_equal_the_blocking_read(st, chunks, steps)
+    assert st["chunk_tokens"] == sum(n for n, _ in requests)
+    assert st["rows_activated"] == len(requests)
+    assert st["logits_bytes_via_host"] == 0
+    assert st["phase_s"]["chunk_fetch"] > 0
+    if len(requests) == 2:
+        # the iteration without a step did happen: a step for every token
+        # of the longer answer and the shorter's, none for chunk 2
+        assert st["steps"] == 6 + 4
+
+
+def test_a_loop_that_closes_right_after_a_chunk_counts_it(served):
+    """A chunk that no step follows (its row left before it activated,
+    as a parked session's does): the driver reads its counts when it
+    ends, so that the window still holds every chunk it ran.  Driven by
+    hand, on the test's thread: admission, two of three chunks, the row
+    taken away, the closed loop's last pass."""
+    from paddle_tpu.serving.slots import SlotRequest
+    _, model, _ = served
+    gen = Generator(model, max_len=64, seq_buckets=[64])
+    loop = SlotLoop(gen, slots=2, cache_len=64, chunk=4)
+    chunks, _ = _spy_counts(loop)
+    prompt = np.random.default_rng(4).integers(0, VOCAB, 11).astype(np.int32)
+    loop._pending.append(SlotRequest(prompt=prompt, max_new=3))
+    assert loop._admit() is False and loop._slots[0].act == 12
+    loop.pos = 11                  # chunks 0 and 1 are due, chunk 2 is not
+    loop._dispatch_chunks()
+    assert len(chunks) == 2 and len(loop._chunk_counts) == 2
+    assert loop.stats()["chunk_moe_assignments"] == 0       # not yet read
+    loop._vacate(0)
+    loop._closed = True
+    loop._drive()                  # nothing live: it ends, and reads them
+    st = loop.stats()
+    assert loop._chunk_counts == []
+    assert st["chunk_moe_assignments"] == np.array(chunks)[:, 0].sum() > 0
+    assert st["moe_expert_tokens_max"] == np.array(chunks)[:, 2].max()
+    assert st["chunk_tokens"] == 11 - 4      # the columns of two chunks,
+    #                                          less one of left padding
+
+
 def test_a_reused_slot_gives_the_second_request_a_fresh_start(served):
     """One slot: request A, then request B in the slot A left (its conv
     states and its K/V columns still lie there).  B's logits at every one

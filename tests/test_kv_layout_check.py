@@ -242,3 +242,37 @@ def test_small_weight_copies_are_not_counted(tool):
                         "copy(%params__k__.1)\n")
     assert tool.weight_copies(text, n_state=2) == []
     assert len(tool.weight_copies(text, n_state=2, min_mb=0.001)) == 1
+
+
+# -- the row write that activates a row (ISSUE 32) ----------------------------
+_LOGITS = "f32[32,50257]{1,0:T(8,128)}"
+
+
+def _put_row_hlo(alias="{}: (0, {}, may-alias)", extra="",
+                 operand="%logits.1"):
+    """The recorded shape of ``put_logits_row`` for a v5e: the one output
+    is no tuple, so its alias reads ``{}: (0, ...``."""
+    return f"""HloModule jit_put, is_scheduled=true, input_output_alias={{ {alias} }}, entry_computation_layout={{({_LOGITS}, f32[50257]{{0:T(1024)}}, s32[]{{:T(128)}})->{_LOGITS}}}
+
+ENTRY %main.1 (logits.1: f32[32,50257], row.1: f32[50257], rowidx.1: s32[]) -> f32[32,50257] {{
+  %rowidx.1 = s32[]{{:T(128)}} parameter(2), sharding={{replicated}}, metadata={{op_name="rowidx"}}
+  %row.1 = f32[50257]{{0:T(1024)}} parameter(1), sharding={{replicated}}, metadata={{op_name="row"}}
+  %logits.1 = {_LOGITS} parameter(0), sharding={{replicated}}, metadata={{op_name="logits"}}
+  %broadcast_in_dim.1 = f32[1,50257]{{1,0:T(1,128)S(1)}} reshape(%row.1)
+{extra}  ROOT %dynamic_update_slice.1 = {_LOGITS} dynamic-update-slice({operand}, %broadcast_in_dim.1, %rowidx.1, %constant.3), backend_config={{"indices_config":{{"is_index_aligned":[false,true]}}}}
+}}
+"""
+
+
+@pytest.mark.parametrize("kw,aliased,copies", [
+    ({}, True, 0),
+    ({"alias": ""}, False, 0),
+    ({"extra": f"  %copy.1 = {_LOGITS} copy(%logits.1)\n",
+      "operand": "%copy.1"}, False, 1),
+], ids=["in-place", "not-donated", "plane-copied-first"])
+def test_the_row_write_is_in_place_or_a_fault(tool, kw, aliased, copies):
+    facts = tool.logits_put(_put_row_hlo(**kw), (32, 50257))
+    assert facts == {"logits_aliased": aliased, "logits_plane_copies": copies}
+    # a tuple's aliases still read as they did
+    assert tool._aliased_params(_hlo((2, 2, 8, 128), "3,2,1,0",
+                                     "true,true,false,true")) == {1, 2}

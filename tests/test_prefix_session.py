@@ -17,6 +17,7 @@ import functools
 import random
 import time
 
+import jax
 import numpy as np
 import pytest
 
@@ -406,6 +407,46 @@ def test_drain_parks_mid_generation_and_resumes_bit_identical():
             snapshot=snap).result(timeout=120)).reshape(-1)
         np.testing.assert_array_equal(got[:24], _want(oracle, prompt, 24))
         assert store.take("drainee") is not None  # re-parked on finish
+    finally:
+        loop.close()
+
+
+def test_a_resumed_row_takes_its_logits_up_once_and_continues_bit_identical():
+    """A row parked mid-generation with its planes (a transcript of a
+    chunk or more) resumes with no suffix chunk: nothing on the device
+    made its activation logits, so the snapshot's row goes up, once,
+    through the same row write, and is the only logits traffic of the
+    window.  The park read them off the device plane the steps keep."""
+    gen = Generator(_gpt(), site="sess:resume", seq_buckets=(8, 16, 32),
+                    max_len=64)
+    store = SessionStore()
+    loop = SlotLoop(gen, slots=2, cache_len=64, chunk=8,
+                    session_store=store)
+    prompt = [5, 9, 2, 33, 17, 8, 41, 3, 27, 12]
+    try:
+        fut = loop.submit(prompt, 24, session_id="resumee")
+        deadline = time.monotonic() + 30.0
+        while not loop.stats().get("ttft_p50_ms"):
+            assert time.monotonic() < deadline, "row never activated"
+            time.sleep(0.002)
+        assert isinstance(loop._logits, jax.Array)
+        assert loop.park_sessions(timeout=30.0) == 1
+        with pytest.raises(UnavailableError):
+            fut.result(timeout=30)
+        snap = store.take("resumee")
+        assert snap.remaining > 0 and snap.planes is not None
+        assert np.asarray(snap.logits).shape == (V,)
+        before = dict(loop.counters)
+        assert before["rows_activated"] == 1
+        assert before["logits_bytes_via_host"] == 0
+        got = np.asarray(loop.submit(
+            prompt, 24, session_id="resumee",
+            snapshot=snap).result(timeout=120)).reshape(-1)
+        np.testing.assert_array_equal(got[:24], _want(_oracle(), prompt, 24))
+        c = loop.counters
+        assert c["restored"] == 1 and c["chunks"] == before["chunks"]
+        assert c["rows_activated"] == 2
+        assert c["logits_bytes_via_host"] == V * 4
     finally:
         loop.close()
 

@@ -11,6 +11,7 @@ signals, and the slot-mode Server integration."""
 import functools
 import random
 
+import jax
 import numpy as np
 import pytest
 
@@ -126,6 +127,127 @@ def test_churn_bit_identical_plain_zero_steady_recompiles():
         loop.close()
 
 
+# -- activation: a row's logits stay on the device ----------------------------
+
+def _spy_put_row(loop):
+    """Every activation write of the loop, in order: the frontier it was
+    dispatched at, the row, and the types of the plane and of the row's
+    logits it was handed."""
+    put, seen = loop._put_row, []
+
+    def recording(logits, row, i):
+        seen.append((loop.pos, int(i), type(logits), type(row)))
+        return put(logits, row, i)
+
+    loop._put_row = recording
+    return seen
+
+
+def test_rows_activate_on_the_device_and_are_counted():
+    """After a churn of joins the step's logits are the device array the
+    last program handed back, every row that joined was activated by the
+    row write, and no byte of logits crossed the host for it."""
+    gen = Generator(_gpt(), site="slot:activate", seq_buckets=(8, 16, 32),
+                    max_len=64)
+    loop = SlotLoop(gen, slots=4, cache_len=64, chunk=8)
+    seen = _spy_put_row(loop)
+    try:
+        reqs = _trace(random.Random(1300), 12)
+        outs = _run_churn(loop, reqs)
+        _assert_bit_identical(_oracle(), reqs, outs)
+        assert isinstance(loop._logits, jax.Array)
+        assert loop.counters["rows_activated"] == loop.counters["joined"] \
+            == len(seen) == 12
+        assert loop.counters["logits_bytes_via_host"] == 0
+        # a final chunk's logits were never fetched either: the write took
+        # the chunk program's own output
+        assert all(issubclass(row, jax.Array) for _, _, _, row in seen)
+        assert all(s._act_logits is None for s in loop._slots)
+    finally:
+        loop.close()
+
+
+@pytest.mark.parametrize("together", [True, False],
+                         ids=["two-rows-in-one-iteration",
+                              "first-step-of-the-session"])
+def test_rows_joining_mid_session_stay_bit_identical(together):
+    """The oracle for rows that join a running session, where the write
+    of the activation logits is at its edges: two rows that activate in
+    the SAME iteration (two writes into one plane before the step reads
+    it), and the row that activates on the first step of the loop's life,
+    when the plane is still the host's zeros and not yet a step's
+    output."""
+    gen = Generator(_gpt(), site="slot:join", seq_buckets=(8, 16, 32),
+                    max_len=64)
+    loop = SlotLoop(gen, slots=4, cache_len=64, chunk=8)
+    seen = _spy_put_row(loop)
+    rng = random.Random(1400)
+    reqs = [([rng.randrange(V) for _ in range(n)], mn)
+            for n, mn in ((5, 24), (11, 6), (13, 7))]
+    try:
+        first = loop.submit(*reqs[0])
+        if together:
+            # both enter the FIFO before the driver admits again: one
+            # admission, one chunk count, so one planned activation
+            with loop._cond:
+                futs = [loop.submit(p, mn) for p, mn in reqs[1:]]
+        else:
+            futs = [loop.submit(p, mn) for p, mn in reqs[1:]]
+        outs = [np.asarray(f.result(timeout=120)).reshape(-1)
+                for f in [first] + futs]
+        _assert_bit_identical(_oracle(), reqs, outs)
+        # the first write of a loop's life goes into the host's zeros,
+        # every later one into what a program handed back
+        assert seen[0][2] is np.ndarray
+        assert all(issubclass(t, jax.Array) for _, _, t, _ in seen[1:])
+        assert len(seen) == 3
+        if together:
+            (_, r0, _, _), (pos1, r1, _, _), (pos2, r2, _, _) = seen
+            assert pos1 == pos2 and len({r0, r1, r2}) == 3
+            # ... while the first row was still generating
+            assert pos1 < seen[0][0] + 24
+    finally:
+        loop.close()
+
+
+def test_warm_up_compiles_the_row_write_and_steady_state_adds_none():
+    """The Server's slot-mode warm-up compiles the row write through the
+    ledger with the step and the chunk, and runs it once (the dummy
+    request's activation); serving then compiles nothing."""
+    snap = flags_snapshot()
+    try:
+        set_flags({"FLAGS_decode_slots": 4, "FLAGS_prefill_chunk": 8})
+        srv = serving.Server(serving.ServingConfig(workers=2))
+        srv.register_decode("gpt", _gpt(seed=45), batch_buckets=(1, 2),
+                            seq_buckets=(8, 16), max_new_tokens=4,
+                            max_len=32)
+        srv.start()
+        try:
+            rt = srv._models["gpt"]
+            kinds = [e["kind"] for e in ledger.compile_events(rt.site)]
+            assert kinds.count("logits_put_row") == 1
+            assert {"generate_step", "generate_chunk"} <= set(kinds)
+            # warmed: the dummy request's row went through it, and the
+            # accounting was zeroed after it
+            assert isinstance(rt._loop._logits, jax.Array)
+            assert rt._loop.counters["rows_activated"] == 0
+            mark = len(ledger.compile_events(rt.site))
+            rng = np.random.RandomState(5)
+            futs = [srv.submit_decode("gpt", [rng.randint(1, V, int(n))],
+                                      max_new_tokens=4)
+                    for n in (3, 12, 7, 1, 9)]
+            for f in futs:
+                f.result(timeout=120)
+            assert len(ledger.compile_events(rt.site)) == mark
+            srv.assert_zero_steady_state_recompiles()
+            assert rt._loop.counters["rows_activated"] == 5
+            assert rt._loop.counters["logits_bytes_via_host"] == 0
+        finally:
+            srv.stop()
+    finally:
+        flags_restore(snap)
+
+
 def test_churn_bit_identical_speculative():
     m, d = _gpt(), _draft()
     gen = SpeculativeGenerator(m, d, site="slot:spec",
@@ -143,6 +265,10 @@ def test_churn_bit_identical_speculative():
         assert len(ledger.compile_events("slot:spec")) == mark
         st = loop.stats()
         assert st["spec_proposed"] > 0 and "spec_acceptance_rate" in st
+        # a speculative step carries tokens: the host takes each final
+        # chunk's logits down for their argmax, when the row activates
+        assert st["rows_activated"] == 30
+        assert st["logits_bytes_via_host"] == 30 * V * 4
     finally:
         loop.close()
 

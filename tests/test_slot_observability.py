@@ -351,7 +351,10 @@ def test_span_records_an_event_only_inside_a_profiler_window():
 
 def test_driver_spans_in_a_capture_flat_and_on_one_thread(tmp_path):
     """A jax.profiler capture of a tiny loop: every name of SPAN_NAMES
-    appears, all on the driver thread's line, and no two overlap."""
+    appears, all on the driver thread's line, and no two overlap.  All
+    but ``chunk_fetch``: this model's chunks hand back no counts, and
+    their logits are not fetched (tests/test_hybrid_decoder.py reads that
+    phase for a model that counts)."""
     loop = _loop(seed=27)
     try:
         loop.submit([1, 2, 3], 2).result(timeout=120)       # thread + warm
@@ -382,7 +385,8 @@ def test_driver_spans_in_a_capture_flat_and_on_one_thread(tmp_path):
                 lines[(plane.name, line.name)] = sorted(evs)
     assert len(lines) == 1, list(lines)
     (evs,) = lines.values()
-    assert {n for _, _, n in evs} == set(slots.SPAN_NAMES)
+    assert {n for _, _, n in evs} \
+        == set(slots.SPAN_NAMES) - {"slot_loop::chunk_fetch"}
     for (_, end, a), (start, _, b) in zip(evs, evs[1:]):
         assert start >= end, (a, b, start - end)
 

@@ -102,8 +102,9 @@ def test_relaid_slot_loop_emits_generates_tokens(weight_wishes):
             is gen._params[Q0]
         assert _layout(gen._params["encoder.layers.0.linear1.weight"]) == ROW
         # both free programs are the final ones: two compiles, no third
+        # (and the loop's own mover of a logits row, which reads no weight)
         kinds = [e["kind"] for e in ledger.compile_events("layouts:relaid")]
-        assert kinds == ["generate_step", "generate_chunk"]
+        assert kinds == ["generate_step", "generate_chunk", "logits_put_row"]
         np.testing.assert_array_equal(_served(loop), _want())
     finally:
         loop.close()
@@ -122,7 +123,8 @@ def test_disagreement_pins_both_and_recompiles_the_asker(weight_wishes):
         assert _layout(gen._params["encoder.layers.0.linear1.weight"]) == ROW
         evs = ledger.compile_events("layouts:disagree")
         assert [e["kind"] for e in evs] == [
-            "generate_step", "generate_chunk", "generate_chunk"]
+            "generate_step", "generate_chunk", "generate_chunk",
+            "logits_put_row"]
         assert "auto" in evs[1]["key"] and "auto" not in evs[2]["key"]
         chunk_in = loop._chunk.input_formats[0][0]
         assert chunk_in["encoder.layers.0.linear1.weight"].layout == ROW
@@ -239,7 +241,8 @@ def test_counters_in_stats_and_ledger_events(weight_wishes):
         assert stats["weights_relaid"] == 3
         assert stats["weights_relaid_mb"] == pytest.approx(
             (2 * 32 * 32 + V * 32) * 4 / 1e6, abs=1e-3)
-        evs = ledger.compile_events("layouts:counters")
+        evs = [e for e in ledger.compile_events("layouts:counters")
+               if e["kind"].startswith("generate_")]    # the two that read weights
         assert len(evs) == 3
         for e in evs:
             assert {k: e[k] for k in COUNTERS} == gen.weights_layout
@@ -259,11 +262,12 @@ def test_cpu_pass_through_two_compiles_nothing_relaid():
         assert gen.weights_layout == dict.fromkeys(COUNTERS, 0)
         assert gen._formats == {} and gen._params["wte.weight"] is held
         evs = ledger.compile_events("layouts:pass")
-        assert [e["kind"] for e in evs] == ["generate_step", "generate_chunk"]
+        assert [e["kind"] for e in evs] == ["generate_step", "generate_chunk",
+                                            "logits_put_row"]
         # the free programs serve under the plain keys too
         assert gen.step_exec(4, 64) is loop._step
         assert gen.chunk_exec(4, 8, 64) is loop._chunk
-        assert len(ledger.compile_events("layouts:pass")) == 2
+        assert len(ledger.compile_events("layouts:pass")) == 3
         np.testing.assert_array_equal(_served(loop), _want())
     finally:
         loop.close()
@@ -303,14 +307,16 @@ def test_warm_start_reads_the_formats_off_the_loaded_programs(weight_wishes,
     try:
         cold = _gen(_gpt(), "layouts:cold")
         cold.slot_execs(4, 8, 64)
+        cold.put_logits_row_exec(4)
         kinds = [e["kind"] for e in ledger.compile_events("layouts:cold")]
-        assert kinds == ["generate_step", "generate_chunk", "generate_chunk"]
+        assert kinds == ["generate_step", "generate_chunk", "generate_chunk",
+                         "logits_put_row"]
         # a new process: the same architecture, weights in default layouts
         warm = _gen(_gpt(), "layouts:warm")
         loop = SlotLoop(warm, slots=4, cache_len=64, chunk=8)
         try:
             kinds = [e["kind"] for e in ledger.compile_events("layouts:warm")]
-            assert kinds == ["cache_load"] * 3          # no compile at all
+            assert kinds == ["cache_load"] * 4          # no compile at all
             assert warm._formats == cold._formats and warm._formats
             assert warm.weights_layout == cold.weights_layout
             mark = len(ledger.compile_events("layouts:warm"))

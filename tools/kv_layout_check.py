@@ -37,7 +37,11 @@ program) and prints, from ``compiled.as_text()``:
     1 MB whose operand chain starts at a parameter of the model (a weight
     transposed again in every run), with their MB; ``weight_copies_default``
     is the same count for the weights in their default layouts (the
-    programs ``Generator.step_exec`` / ``chunk_exec`` compile alone).
+    programs ``Generator.step_exec`` / ``chunk_exec`` compile alone);
+  * the program that activates a row (``Generator.put_logits_row_exec``:
+    one row written into the step's ``[S, V]`` logits): ``logits_aliased``,
+    whether its output is its donated input, written in place, with no
+    copy of the plane beside it.
 
 The two programs are compiled through ``Generator.slot_execs``, the slot
 loop's own way to them, so what is checked is what is served: the line
@@ -48,8 +52,9 @@ how many the two disagreed.
 
 Exit code 1 when a write's traced index lies on the minor-most dimension,
 a plane is not aliased, a whole plane is copied (on its way into a loop
-and inside one too; a state plane too), a cache row changes layout, or a
-weight on which the two programs did not disagree is still copied.  Run by hand, one process at
+and inside one too; a state plane too), a cache row changes layout, a
+weight on which the two programs did not disagree is still copied, or the
+row write copies the logits.  Run by hand, one process at
 a time: only one process may load libtpu, so this is not a pytest file.
 """
 from __future__ import annotations
@@ -161,8 +166,20 @@ def weight_copies(hlo_text, n_state, min_mb=1.0):
 
 
 def _aliased_params(hlo_text):
+    # "{1}: (2, {}, may-alias)" of a tuple's element; "{}: (0, ..." where
+    # the program's one output is no tuple
     head = hlo_text.split("\n", 1)[0]
-    return {int(p) for p in re.findall(r"\{\d+\}: \((\d+), \{\}", head)}
+    return {int(p) for p in re.findall(r"\{\d*\}: \((\d+), \{\}", head)}
+
+
+def logits_put(hlo_text, shape):
+    """The row write's facts: the ENTRY copies/transposes of a whole
+    ``shape`` (the ``[S, V]`` logits), and whether the output is the
+    donated first argument with none of them beside it."""
+    copies = [n for n, (dims, _l, op, _a, _line) in _entry(hlo_text).items()
+              if dims == tuple(shape) and op in ("copy", "transpose")]
+    return {"logits_aliased": 0 in _aliased_params(hlo_text) and not copies,
+            "logits_plane_copies": len(copies)}
 
 
 def inspect(hlo_text, plane_shapes, state_shapes=frozenset()):
@@ -285,6 +302,10 @@ def described_generator(device):
             return super()._lower(
                 fn, jax.tree_util.tree_map(place, arg_avals), jit_kw, free)
 
+        def _lower_data(self, fn, arg_avals, jit_kw):
+            return super()._lower_data(
+                fn, jax.tree_util.tree_map(place, arg_avals), jit_kw)
+
         def _held_layouts(self):
             return {(i, name): G._default_layout(a, device)
                     for i, tree in enumerate(self._state) if not i % 2
@@ -368,6 +389,14 @@ def main(argv):
             faults.append(f"{what}: at least {n_agreed} weights on which the "
                           "programs agreed are still copied in every run: "
                           + ", ".join(sorted({n for n, _ in left})[:4]))
+    # the row write that activates a row, lowered as the loop gets it
+    vocab = gen._vocab_size()
+    facts = logits_put(gen.put_logits_row_exec(S).as_text(), (S, vocab))
+    print(json.dumps({"config": cfg["name"], "program": "put_logits_row",
+                      "slots": S, "vocab": vocab, **facts}), flush=True)
+    if not facts["logits_aliased"]:
+        faults.append("put_logits_row: the [S, V] logits are copied, not "
+                      "written in place")
     for f in faults:
         print("FAULT " + f, flush=True)
     return 1 if faults else 0
