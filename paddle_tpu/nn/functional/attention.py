@@ -396,13 +396,59 @@ def attention_bnsh(q, k, v, attn_mask=None, is_causal=False):
 # projections and the planes.
 # ---------------------------------------------------------------------------
 
-def rotary(x, positions, base, dims=None):
+def rotary_frequencies(d, base, scaling=None):
+    """The ``d / 2`` rotary frequencies ``base^(-2i/d)``, float32.
+    ``scaling`` (a config's ``rope_scaling`` of type ``yarn``) blends each
+    with its ``1 / factor`` between the correction dimensions that
+    ``beta_fast`` and ``beta_slow`` turns over ``original_max_position_
+    embeddings`` positions give: frequency ``i`` keeps ``1 - ramp_i (1 -
+    1 / factor)`` of itself, ``ramp`` rising linearly from 0 at the low
+    dimension to 1 at the high one (the fast frequencies stay, the slow
+    ones are interpolated).  ``factor`` 1 leaves every frequency as it
+    is, to the bit."""
+    inv = jnp.float32(base) ** (-jnp.arange(0, d, 2, dtype=jnp.float32) / d)
+    if not scaling:
+        return inv
+    if scaling.get("type", "yarn") != "yarn":
+        raise ValueError(f"rope_scaling of type {scaling['type']!r}")
+    factor = float(scaling["factor"])
+    span = float(scaling["original_max_position_embeddings"])
+
+    def dim_of(turns):
+        return d * math.log(span / (turns * 2 * math.pi)) \
+            / (2 * math.log(base))
+    low = max(math.floor(dim_of(float(scaling.get("beta_fast", 32)))), 0)
+    high = min(math.ceil(dim_of(float(scaling.get("beta_slow", 1)))), d - 1)
+    ramp = jnp.clip((jnp.arange(d // 2, dtype=jnp.float32) - low)
+                    / max(high - low, 0.001), 0, 1)
+    return inv * (1.0 - ramp * jnp.float32(1.0 - 1.0 / factor))
+
+
+def yarn_attention_factor(scaling=None) -> float:
+    """What a ``yarn`` ``rope_scaling`` multiplies the softmax scale by:
+    ``m(mscale_all_dim)^2`` with ``m(a) = 0.1 a ln(factor) + 1`` (1 where
+    there is no scaling or ``factor`` is 1).  The published form also
+    multiplies cos and sin by ``m(mscale) / m(mscale_all_dim)``: 1 for
+    the configurations served here, and refused otherwise."""
+    if not scaling or float(scaling["factor"]) <= 1:
+        return 1.0
+    m = lambda a: 0.1 * float(a or 0) * math.log(               # noqa: E731
+        float(scaling["factor"])) + 1.0
+    if m(scaling.get("mscale", 1)) != m(scaling.get("mscale_all_dim")):
+        raise ValueError("rope_scaling with mscale != mscale_all_dim: the "
+                         "rotary's cos and sin would carry their ratio")
+    return m(scaling.get("mscale_all_dim")) ** 2
+
+
+def rotary(x, positions, base, dims=None, inv=None):
     """Rotate the first ``dims`` (default all; even) features of ``x``
     ``[B, T, d]`` or ``[B, T, H, d]`` by ``positions [B, T]``; the two
     halves of the rotated part are paired ("rotate-half").  Angles in
-    float32 whatever ``x`` is."""
+    float32 whatever ``x`` is.  ``inv`` replaces the plain frequencies
+    of ``base`` (:func:`rotary_frequencies`)."""
     d = x.shape[-1] if dims is None else int(dims)
-    inv = jnp.float32(base) ** (-jnp.arange(0, d, 2, dtype=jnp.float32) / d)
+    if inv is None:
+        inv = rotary_frequencies(d, base)
     ang = positions.astype(jnp.float32)[..., None] * inv      # [B, T, d/2]
     if x.ndim == 4:
         ang = ang[:, :, None, :]

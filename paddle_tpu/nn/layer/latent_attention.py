@@ -10,7 +10,9 @@ layer share the code:
   * **full** layers keep a plane as long as the session and, beside it, a
     plane of selector keys; a learned selector (``index_n_heads`` small
     heads over the query's low-rank latent) scores every cached column and
-    the attention reads the ``index_topk`` best of the causal ones;
+    the attention reads the ``index_topk`` best of the causal ones.  A
+    full layer WITHOUT a selector (``index_topk`` 0) keeps the latent
+    plane alone and reads every valid column of it;
   * **window** layers keep ONE ring plane of ``window + cache_block - 1``
     columns, shorter than the session, written at ``column mod length``
     and masked by absolute column.
@@ -19,7 +21,9 @@ The planes are what :meth:`LatentAttention.gen_ring_cache` builds; the
 namedtuple classes carry what the Generator and the slot loop need to know
 about them (``kind``, whether a plane wraps inside a session).  A headwise
 sigmoid gate, from the layer's normed input, multiplies each head's output
-before the output projection.
+before the output projection (``gate=False``: no gate is built).  The
+rotary positions take a config's ``rope_scaling`` (YaRN: blended
+frequencies, and ``m^2`` on the softmax scale), in all three forms alike.
 
 Everything here runs on raw arrays under ``no_grad`` (decode is
 inference-only); ``forward`` is the cache-less PER-HEAD form over a whole
@@ -37,17 +41,26 @@ from jax import lax
 from ...framework.tensor import Tensor, unwrap
 from .. import initializer as I
 from ..functional.attention import (latent_attend, latent_attend_blocked,
-                                    rotary, select_columns, selector_scores)
+                                    rotary, rotary_frequencies,
+                                    select_columns, selector_scores,
+                                    yarn_attention_factor)
 from .layers import Layer
 from .transformer import ring_block_write
 
-__all__ = ["RMSNorm", "LatentAttention", "LatentCache", "LatentWindowCache"]
+__all__ = ["RMSNorm", "LatentAttention", "LatentCache", "LatentPlane",
+           "LatentWindowCache"]
 
 # full layers: ``latent [B, 1, C, r_kv + d_r]`` and the selector's keys
 # ``index_key [B, 1, C, d_i]``; columns at axis 2 like every ring plane
 LatentCache = collections.namedtuple("LatentCache", ["latent", "index_key"])
 LatentCache.kind = "latent+selector_key"
 LatentCache.wraps = False
+# full layers without a selector: the latent plane alone.  Every column is
+# a token's, written once, its content a function of the token prefix and
+# of ``column - start`` only: a plane the prefix cache can cut
+LatentPlane = collections.namedtuple("LatentPlane", ["latent"])
+LatentPlane.kind = "latent"
+LatentPlane.wraps = False
 # window layers: one ring plane shorter than the session
 LatentWindowCache = collections.namedtuple("LatentWindowCache", ["latent"])
 LatentWindowCache.kind = "latent_window"
@@ -86,15 +99,17 @@ def _layer_norm(x, g, b, eps):
 
 class LatentAttention(Layer):
     """One latent-attention layer.  ``window=None`` with ``index_topk``
-    set is a full layer with the selector; ``window=w`` is a window layer
+    set is a full layer with the selector, with ``index_topk`` 0 a full
+    layer that reads every valid column; ``window=w`` is a window layer
     (no selector).  ``cache_block`` is the widest token block one cached
-    call may append; it sizes the window plane."""
+    call may append; it sizes the window plane.  ``gate`` builds the
+    headwise output gate; ``rope_scaling`` is a config's (``yarn``)."""
 
     def __init__(self, hidden, num_heads, nope_dim, rope_dim, v_dim,
                  q_rank, kv_rank, rope_base, *, window=None,
                  index_heads=0, index_dim=0, index_topk=0, cache_block=512,
-                 attn_block=512, epsilon=1e-5, rescale=True,
-                 weight_attr=None, dtype=None):
+                 attn_block=512, epsilon=1e-5, rescale=True, gate=True,
+                 rope_scaling=None, weight_attr=None, dtype=None):
         super().__init__()
         self.hidden, self.H = int(hidden), int(num_heads)
         self.dn, self.dr, self.dv = int(nope_dim), int(rope_dim), int(v_dim)
@@ -110,7 +125,12 @@ class LatentAttention(Layer):
         # normed latents, sqrt(hidden / rank)
         self.s_q = math.sqrt(hidden / q_rank) if rescale else 1.0
         self.s_kv = math.sqrt(hidden / kv_rank) if rescale else 1.0
-        self.scale = 1.0 / math.sqrt(self.dn + self.dr)
+        self.scale = yarn_attention_factor(rope_scaling) \
+            / math.sqrt(self.dn + self.dr)
+        # None: the plain frequencies of ``base`` (the selector's rotary
+        # keeps those: a selector under YaRN is no published model's)
+        self.inv = rotary_frequencies(self.dr, self.base, rope_scaling) \
+            if rope_scaling else None
 
         def mat(*shape):
             return self.create_parameter(
@@ -125,7 +145,7 @@ class LatentAttention(Layer):
         self.kv_a_norm = RMSNorm(self.rkv, epsilon, dtype=dtype)
         self.w_uk = mat(H, self.rkv, self.dn)
         self.w_uv = mat(H, self.rkv, self.dv)
-        self.gate = mat(hidden, H)
+        self.gate = mat(hidden, H) if gate else None
         self.o_proj = mat(H * self.dv, hidden)
         if self.selects:
             self.idx_q = mat(self.rq, self.J * self.D)
@@ -148,11 +168,16 @@ class LatentAttention(Layer):
     def ring_cache_spec(self, max_len):
         """What the Generator and the slot loop may know of this layer's
         planes (text/generation.py ``cache_spec``)."""
-        cls = LatentWindowCache if self.window is not None else LatentCache
+        cls = self._cache_class()
         return {"kind": cls.kind, "heads_per_lane_row": 1,
                 "columns": self.ring_len(max_len), "wraps": cls.wraps,
                 "window": self.window,
                 "select_top": self.topk if self.selects else None}
+
+    def _cache_class(self):
+        if self.window is not None:
+            return LatentWindowCache
+        return LatentCache if self.selects else LatentPlane
 
     @property
     def row_width(self):
@@ -168,17 +193,16 @@ class LatentAttention(Layer):
         from ...ops import zeros
         n = self.ring_len(max_len)
         lat = zeros([batch, 1, n, self.row_width], dtype=dtype)
-        if self.window is not None:
-            return LatentWindowCache(lat)
-        return LatentCache(lat, zeros([batch, 1, n, max(self.D, 1)],
-                                      dtype=dtype))
+        if not self.selects:
+            return self._cache_class()(lat)
+        return LatentCache(lat, zeros([batch, 1, n, self.D], dtype=dtype))
 
     # -- projections shared by both forms --------------------------------------
     def _project(self, x, pos_ids):
         """From the normed input ``x [B, T, hidden]``: per-head queries
         ``q_n [B, T, H, dn]``, ``q_r [B, T, H, dr]`` (rotated), the cache
         row ``latent ‖ rotary key [B, T, rkv + dr]``, the gate ``[B, T,
-        H]`` and the selector's query latent ``c_q``."""
+        H]`` (None without one) and the selector's query latent ``c_q``."""
         B, T, _ = x.shape
         dt = x.dtype
         w = lambda p: unwrap(p)                                # noqa: E731
@@ -189,14 +213,15 @@ class LatentAttention(Layer):
                        preferred_element_type=jnp.float32).astype(dt)
         q = q.reshape(B, T, self.H, self.dn + self.dr)
         q_n, q_r = q[..., :self.dn], rotary(q[..., self.dn:], pos_ids,
-                                            self.base)
+                                            self.base, inv=self.inv)
         kv = jnp.einsum("bth,hk->btk", x, w(self.kv_a),
                         preferred_element_type=jnp.float32)
         c_kv = (self.s_kv * unwrap(self.kv_a_norm(kv[..., :self.rkv]))
                 ).astype(dt)
-        k_r = rotary(kv[..., self.rkv:].astype(dt), pos_ids, self.base)
+        k_r = rotary(kv[..., self.rkv:].astype(dt), pos_ids, self.base,
+                     inv=self.inv)
         row = jnp.concatenate([c_kv, k_r], -1)
-        gate = jax.nn.sigmoid(jnp.einsum(
+        gate = None if self.gate is None else jax.nn.sigmoid(jnp.einsum(
             "bth,hn->btn", x, w(self.gate),
             preferred_element_type=jnp.float32))
         return q_n, q_r, row, gate, c_q
@@ -228,7 +253,9 @@ class LatentAttention(Layer):
         dt = out_lat.dtype
         o = jnp.einsum("bthr,hrv->bthv", out_lat, unwrap(self.w_uv),
                        preferred_element_type=jnp.float32)
-        o = (o * gate[..., None]).astype(dt).reshape(B, T, self.H * self.dv)
+        if gate is not None:
+            o = o * gate[..., None]
+        o = o.astype(dt).reshape(B, T, self.H * self.dv)
         return jnp.einsum("btk,kh->bth", o, unwrap(self.o_proj),
                           preferred_element_type=jnp.float32).astype(dt)
 
@@ -289,7 +316,7 @@ class LatentAttention(Layer):
 
     def _full(self, x, c_q, q_cat, row, cache, cols, pos_ids, start):
         B, T = q_cat.shape[:2]
-        lat, keys = unwrap(cache.latent), unwrap(cache.index_key)
+        lat = unwrap(cache.latent)
         C = lat.shape[2]
         pos = cols[0] % C
         lat = unwrap(ring_block_write(lat, row[:, None].astype(lat.dtype),
@@ -311,6 +338,7 @@ class LatentAttention(Layer):
         if self.selects:
             with jax.named_scope("selector"):
                 qi, wi, ki = self._selector(x, c_q, pos_ids)
+                keys = unwrap(cache.index_key)
                 keys = unwrap(ring_block_write(
                     keys, ki[:, None].astype(keys.dtype), pos))
                 if C > self.topk:       # else every valid column is chosen
@@ -332,6 +360,8 @@ class LatentAttention(Layer):
                         sel, (0, 0, s0), (B, T, blk))
         out = latent_attend_blocked(q_cat, lat[:, 0], self.rkv, keep_of,
                                     self.scale, lo, hi, blk)
+        if not self.selects:
+            return out, LatentPlane(Tensor(lat))
         return out, LatentCache(Tensor(lat), Tensor(keys))
 
     # -- cache-less, per-head form over a whole sequence -----------------------
@@ -360,7 +390,9 @@ class LatentAttention(Layer):
             keep = select_columns(selector_scores(qi, wi, ki), keep,
                                   self.topk)
         p = jax.nn.softmax(jnp.where(keep[:, None], s, -1e30), -1)
-        o = jnp.einsum("bhts,bshv->bthv", p, v) * gate[..., None]
+        o = jnp.einsum("bhts,bshv->bthv", p, v)
+        if gate is not None:
+            o = o * gate[..., None]
         o = o.astype(raw.dtype).reshape(B, T, self.H * self.dv)
         out = jnp.einsum("btk,kh->bth", o, unwrap(self.o_proj),
                          preferred_element_type=jnp.float32).astype(raw.dtype)

@@ -323,7 +323,7 @@ class _DecodeRuntime:
             import jax.tree_util as tu
             from .cluster.handoff import _np_dtype
             from .prefix_cache import PrefixCache, require_kv_planes
-            require_kv_planes(self.gen.plane_kinds())
+            require_kv_planes(self.gen.cache_spec(C), C)
             block_nbytes = sum(
                 int(np.prod(tuple(a.shape)))
                 * _np_dtype(str(a.dtype)).itemsize

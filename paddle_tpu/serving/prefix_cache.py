@@ -4,8 +4,10 @@ Thousands of requests sharing a system prompt should pay its prefill
 once.  The PR-7 batch-invariance gate makes that sound: a cache column's
 K/V content depends only on the token prefix and the RELATIVE position
 ``column − start``, so a prefilled prefix segment is bit-portable across
-slot rows, pack compositions and window shifts.  This module indexes
-those segments:
+slot rows, pack compositions and window shifts.  The same holds of any
+plane as long as the session whose column is a token's (a latent plane
+that no selector reads beside a key plane: ``require_kv_planes`` decides
+from the model's ``cache_spec``).  This module indexes those segments:
 
   * the trie is keyed by **blocks** of ``T`` tokens (``T`` = the prefill
     chunk width the slot loop runs) — a node's path from the root spells
@@ -211,8 +213,13 @@ class PrefixCache:
                     "evictions": self._evictions}
 
 
-def require_kv_planes(kinds) -> None:
+def require_kv_planes(spec, columns) -> None:
     """Raise ``InvalidArgumentError`` naming the plane kinds of a model
-    that this module cannot cut (anything but uniform K/V planes)."""
-    from ..text.generation import require_kv_planes as _require
-    _require(kinds, "the prefix KV cache (it cuts chunk-wide column blocks out of every plane of a row)")
+    that this module cannot cut.  ``spec`` is what the model says of its
+    planes at a session of ``columns`` (``Generator.cache_spec``): the
+    decision is ``text/generation.py::require_prefix_planes``'s (planes
+    as long as the session whose columns depend on the token prefix and
+    the relative position only: uniform K/V planes, a latent plane on
+    its own)."""
+    from ..text.generation import require_prefix_planes
+    require_prefix_planes(spec, columns, "the prefix KV cache (it cuts chunk-wide column blocks out of every plane of a row)")

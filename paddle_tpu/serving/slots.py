@@ -96,7 +96,14 @@ What the loop measures about itself, always on:
     ``activate`` is host bookkeeping and one dispatch of the row write
     for each row that joins; ``chunk_fetch`` is the reading of the
     chunks' counts (a model that hands none back never enters it) and,
-    in the speculative loop, of a final chunk's logits;
+    in the speculative loop, of a final chunk's logits; ``restore`` and
+    ``publish`` are the prefix cache's block pushes and pulls;
+  * **what the prefix cache did** — ``counters["prompt_tokens_admitted",
+    "prefix_lookups", "prefix_hits", "prefix_hit_tokens",
+    "restore_pushes", "prefix_blocks_published",
+    "prefix_blocks_evicted"]``, committed with ``steps``: of the prompt
+    tokens admitted, those that came out of cached blocks and were not
+    prefilled;
   * **how a row was activated** — ``counters["rows_activated"]``, and
     ``["logits_bytes_via_host"]``: the bytes of logits that crossed the
     host boundary for it, either way (0 for a row whose final chunk
@@ -151,10 +158,13 @@ _EMPTY, _PREFILL, _GEN = 0, 1, 2
 # ``stats()["phase_s"]``.  ``idle_wait`` (nothing live) and ``step_fetch``
 # (the step's tokens) wait; ``chunk_fetch`` reads the chunks' counts
 # behind a step's tokens, where they have arrived (the speculative loop
-# waits there for a final chunk's logits); the other five are the host's
-# own work, the dispatch of a row's activation write under ``activate``.
-PHASES = ("idle_wait", "admit", "chunk_dispatch", "chunk_fetch", "activate",
-          "step_dispatch", "step_fetch", "retire")
+# waits there for a final chunk's logits); the others are the host's own
+# work, the dispatch of a row's activation write under ``activate``.
+# ``restore`` (a row's cached blocks pushed into its columns) and
+# ``publish`` (an activated row's new blocks pulled for the prefix cache,
+# and its bookkeeping) are entered only by a loop that has that cache.
+PHASES = ("idle_wait", "admit", "restore", "chunk_dispatch", "chunk_fetch",
+          "activate", "publish", "step_dispatch", "step_fetch", "retire")
 # their spans in a profiler capture; spelled here and nowhere else
 SPAN_NAMES = tuple(f"slot_loop::{p}" for p in PHASES)
 _SPAN_OF = dict(zip(PHASES, SPAN_NAMES))
@@ -265,8 +275,13 @@ class SlotLoop:
         # hands back with the step's tokens and the chunk's logits
         spec = gen.cache_spec(self.C)
         self._plane_kinds = sorted({str(s["kind"]) for s in spec})
-        self._select_tops = [int(s["select_top"]) for s in spec
-                             if s.get("select_top")]
+        # per layer that reads a plane of its own kind as long as the
+        # session (not K/V: those count ``kv_columns_valid``): how many
+        # of a token's causal columns its attention reads at most, 0 for
+        # all of them (a latent plane with a selector, or without one)
+        self._context_tops = [int(s.get("select_top") or 0) for s in spec
+                              if int(s["columns"]) and not s.get("wraps")
+                              and not str(s["kind"]).startswith("kv")]
         self._wrap_lens = [int(s["columns"]) for s in spec
                            if s.get("wraps") and int(s["columns"]) < self.C]
         # layers whose cache has no columns: a per-row state that every
@@ -280,7 +295,7 @@ class SlotLoop:
         self._count_names = tuple(names()) if names is not None else ()
         if prefix_cache is not None:
             from .prefix_cache import require_kv_planes
-            require_kv_planes(self._plane_kinds)
+            require_kv_planes(spec, self.C)
         if session_store is not None:
             from .sessions import require_kv_planes
             require_kv_planes(self._plane_kinds)
@@ -321,16 +336,19 @@ class SlotLoop:
         self.counters = {"joined": 0, "retired": 0, "steps": 0,
                          "chunks": 0, "session_resets": 0,
                          "emitted_tokens": 0, "parked": 0, "restored": 0,
-                         "prefix_hit_tokens": 0, "restore_pushes": 0,
+                         "prompt_tokens_admitted": 0, "prefix_lookups": 0,
+                         "prefix_hits": 0, "prefix_hit_tokens": 0,
+                         "restore_pushes": 0, "prefix_blocks_published": 0,
+                         "prefix_blocks_evicted": 0,
                          "rows_activated": 0, "logits_bytes_via_host": 0,
                          **{f"slot_steps_{k}": 0 for k in _SLOT_STATES},
                          **dict.fromkeys(self._count_names, 0)}
-        if self._select_tops:
+        if self._context_tops:
             self.counters.update(attn_columns_valid=0,
                                  attn_columns_selected=0,
                                  chunk_attn_columns_valid=0,
                                  chunk_attn_columns_selected=0)
-        if self._select_tops or self._count_names:
+        if self._context_tops or self._count_names:
             # the chunks' own part of the totals, so that a reader can
             # take the average step and the average chunk apart
             self.counters["chunk_tokens"] = 0
@@ -354,6 +372,7 @@ class SlotLoop:
         # driver-thread-owned: what the dispatches since the last commit
         # add to those counters; committed with ``steps`` in one piece
         self._tally = {}
+        self._evictions_seen = 0        # of the prefix cache's, tallied so far
         self._chunk_counts = []         # device handles of chunks' counts
         # the driver's phase clock (driver-thread-owned): the phase it is
         # in, since when, its open span, and the seconds not yet
@@ -719,6 +738,7 @@ class SlotLoop:
         lp = int(p.size)
         slot.restore = []
         slot.pin = None
+        self._add("prompt_tokens_admitted", lp)
         if head.planes_len >= self.T:
             # -- session-snapshot restore (host planes) -------------------
             lc = head.planes_len
@@ -750,6 +770,7 @@ class SlotLoop:
                 # produces the activation logits)
                 blocks, pin = self._prefix.lookup(
                     p.tolist(), max_blocks=(lp - 1) // self.T)
+                self._add("prefix_lookups", 1)
             if blocks:
                 # -- prefix-cache hit (device blocks) ---------------------
                 lhit = len(blocks) * self.T
@@ -766,7 +787,8 @@ class SlotLoop:
                 suffix = p[lp - n_s * self.T:]
                 slot.chunks = [suffix[k * self.T:(k + 1) * self.T]
                                for k in range(n_s)]
-                self.counters["prefix_hit_tokens"] += lhit
+                self._add("prefix_hits", 1)
+                self._add("prefix_hit_tokens", lhit)
             else:
                 if pin:
                     self._prefix.release(pin)
@@ -808,7 +830,10 @@ class SlotLoop:
         for i, slot in enumerate(self._slots):
             if slot.state != _PREFILL:
                 continue
-            self._push_restores(i, slot)
+            if slot.restore:
+                self._phase("restore")
+                self._push_restores(i, slot)
+                self._phase("chunk_dispatch")
             if slot.restore:
                 # chunks READ restored columns through attention — hold
                 # them until every pending push has dispatched.  Never
@@ -877,10 +902,10 @@ class SlotLoop:
         pre = "chunk_" if chunk else ""
         if self._attn_block:
             add(pre + "kv_columns_valid", int(ctx.sum()))
-        for top in self._select_tops:
+        for top in self._context_tops:
             add(pre + "attn_columns_valid", int(ctx.sum()))
             add(pre + "attn_columns_selected",
-                int(np.minimum(ctx, top).sum()))
+                int((np.minimum(ctx, top) if top else ctx).sum()))
         for n in self._wrap_lens:
             add("window_wraps", int(((cols > 0) & (cols % n == 0)).sum()))
 
@@ -932,10 +957,18 @@ class SlotLoop:
             block, base = slot.restore.pop(0)
             self._cache = self._push_block(
                 self._cache, block, np.int32(i), np.int32(base))
-            self.counters["restore_pushes"] += 1
+            self._add("restore_pushes", 1)
         if not slot.restore and slot.pin is not None:
             self._prefix.release(slot.pin)
             slot.pin = None
+            self._tally_evictions()
+
+    def _tally_evictions(self):
+        """Driver thread: the blocks the prefix cache has evicted since
+        the last look (a release and a publish may each evict)."""
+        seen = self._prefix.stats()["evictions"]
+        self._add("prefix_blocks_evicted", seen - self._evictions_seen)
+        self._evictions_seen = seen
 
     # -- activation ----------------------------------------------------------
     def _activate(self):
@@ -1111,11 +1144,14 @@ class SlotLoop:
         pre-donation value)."""
         if self._prefix is None:
             return
+        self._phase("publish")
         slot_start = slot.start
-        self._prefix.publish(
+        self._add("prefix_blocks_published", self._prefix.publish(
             slot.req.prompt.tolist(),
             lambda j: self._pull_block(self._cache, np.int32(i),
-                                       np.int32(slot_start + j * self.T)))
+                                       np.int32(slot_start + j * self.T))))
+        self._tally_evictions()
+        self._phase("activate")
 
     def _park(self, i: int, slot: "_Slot", remaining: int):
         """Snapshot one session row into the store: one full-width row

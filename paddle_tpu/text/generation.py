@@ -164,19 +164,42 @@ def _apply_layer(layer, params, buffers, ids, cache, pos, start, rows=None):
     return unwrap(logits), [tuple(unwrap(p) for p in c) for c in new_cache]
 
 
+def _refuse_planes(kinds, who):
+    raise InvalidArgumentError(
+        f"{who} handles uniform K/V ring planes only; this model "
+        f"keeps planes of kind {', '.join(map(repr, kinds))}, which it "
+        "cannot cut (serve the model with that feature off)")
+
+
 def require_kv_planes(kinds, who):
-    """Refuse plane kinds that ``who`` cannot cut: the prefix cache, the
-    session store and the KV handoff slice, park and ship UNIFORM K/V
-    ring planes (kinds ``kv``, ``kv_int8``: every plane as long as the
-    session, a column a token).  A latent plane beside a selector-key
-    plane, a window plane shorter than the session, or a state without
-    columns (``conv_state``) is not theirs yet."""
+    """Refuse plane kinds that ``who`` cannot cut: the session store and
+    the KV handoff park and ship UNIFORM K/V ring planes (kinds ``kv``,
+    ``kv_int8``: every plane as long as the session, a column a token).
+    A latent plane, a window plane shorter than the session, or a state
+    without columns (``conv_state``) is not theirs yet."""
     bad = sorted(k for k in set(kinds) if not str(k).startswith("kv"))
     if bad:
-        raise InvalidArgumentError(
-            f"{who} handles uniform K/V ring planes only; this model "
-            f"keeps planes of kind {', '.join(map(repr, bad))}, which it "
-            "cannot cut (serve the model with that feature off)")
+        _refuse_planes(bad, who)
+
+
+def require_prefix_planes(spec, columns, who):
+    """Refuse a model whose planes ``who`` (the prefix cache) cannot cut
+    into chunk-wide column blocks and restore into another row at another
+    ``start``.  Decided from what the model says of its planes (``spec``
+    = ``Generator.cache_spec(columns)``), not from their names: every
+    layer must keep column planes as long as the session (``columns``),
+    a column a token, written once (``wraps`` false), every valid one read
+    (no ``select_top``), and no state beside them.  Such a column's
+    content is a function of the token prefix and of ``column - start``
+    only: uniform K/V ring planes, and a latent plane on its own.  A
+    window plane shorter than the session, a selector-key plane beside a
+    latent plane and a state without columns go on being refused, with
+    :func:`require_kv_planes`'s message."""
+    bad = sorted({str(s["kind"]) for s in spec
+                  if int(s["columns"]) != int(columns) or s.get("wraps")
+                  or s.get("select_top")})
+    if bad:
+        _refuse_planes(bad, who)
 
 
 def _slice_row(cache, rowidx):

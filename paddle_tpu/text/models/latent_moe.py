@@ -6,10 +6,13 @@ on the rest.
 Block: ``h = x + Attn(RMSNorm(x))``, ``y = h + FFN(RMSNorm(h))``; final
 RMSNorm; untied output head.  ``layer_types[i]`` says whether layer ``i``
 is a full layer (latent plane as long as the session, selector-key plane,
-top-``index_topk`` selection) or a window layer (one ring plane of
-``window + cache_block - 1`` columns); the first ``dense_layers`` FFNs are
+top-``index_topk`` selection; with ``index_topk`` 0 the latent plane alone,
+every valid column read: the DeepSeek-V3 form itself) or a window layer
+(one ring plane of ``window + cache_block - 1`` columns); the first
+``dense_layers`` FFNs are
 dense SwiGLU, the rest :class:`~paddle_tpu.nn.layer.moe.DroplessMoE` told
-which experts live here.  Rotary positions, one base per kind of layer.
+which experts live here.  Rotary positions, one base per kind of layer;
+``rope_scaling`` (YaRN) reaches the full layers.
 
 The incremental-decoding contract of text/generation.py (``init_cache`` +
 ``forward_cached``) is implemented here; each layer's cache is what that
@@ -58,7 +61,7 @@ class LatentMoEConfig:
     rope_base: float = 8e7
     index_heads: int = 4
     index_dim: int = 32
-    index_topk: int = 64
+    index_topk: int = 64                # 0: no selector, no selector keys
     # window layers
     window: int = 65
     window_heads: int = 2
@@ -70,6 +73,8 @@ class LatentMoEConfig:
     window_rope_base: float = 5e4
     rms_eps: float = 1e-5
     rescale_latents: bool = True
+    attention_gate: bool = True         # the headwise output gate
+    rope_scaling: Optional[dict] = None  # a config's, type "yarn"
     cache_block: int = 64               # widest block one cached call appends
     attn_block: int = 512               # column block of the blocked attention
     dtype: str = "float32"
@@ -99,13 +104,15 @@ class LatentDecoderLayer(nn.Layer):
             raise ValueError(f"layer_types[{index}] = {kind!r}")
         kw = dict(cache_block=cfg.cache_block, attn_block=cfg.attn_block,
                   epsilon=cfg.rms_eps, rescale=cfg.rescale_latents,
-                  weight_attr=weight_attr, dtype=cfg.dtype)
+                  gate=cfg.attention_gate, weight_attr=weight_attr,
+                  dtype=cfg.dtype)
         if kind == FULL:
             self.attn = LatentAttention(
                 cfg.hidden_size, cfg.num_heads, cfg.nope_dim, cfg.rope_dim,
                 cfg.v_dim, cfg.q_rank, cfg.kv_rank, cfg.rope_base,
                 index_heads=cfg.index_heads, index_dim=cfg.index_dim,
-                index_topk=cfg.index_topk, **kw)
+                index_topk=cfg.index_topk, rope_scaling=cfg.rope_scaling,
+                **kw)
         else:
             self.attn = LatentAttention(
                 cfg.hidden_size, cfg.window_heads, cfg.window_nope_dim,

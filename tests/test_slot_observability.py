@@ -349,13 +349,20 @@ def test_span_records_an_event_only_inside_a_profiler_window():
     assert not {n for n in names if n.startswith("outside::")}
 
 
-def test_driver_spans_in_a_capture_flat_and_on_one_thread(tmp_path):
+@pytest.mark.parametrize("prefix", [False, True],
+                         ids=["plain", "prefix_cache"])
+def test_driver_spans_in_a_capture_flat_and_on_one_thread(tmp_path, prefix):
     """A jax.profiler capture of a tiny loop: every name of SPAN_NAMES
     appears, all on the driver thread's line, and no two overlap.  All
     but ``chunk_fetch``: this model's chunks hand back no counts, and
     their logits are not fetched (tests/test_hybrid_decoder.py reads that
-    phase for a model that counts)."""
-    loop = _loop(seed=27)
+    phase for a model that counts); and ``restore`` and ``publish`` only
+    in a loop that has a prefix cache (the six prompts overlap in their
+    tokens, and the last repeats the first: its block is restored)."""
+    from paddle_tpu.serving.prefix_cache import PrefixCache
+    loop = _loop(seed=27, prefix_cache=PrefixCache(8, 1) if prefix else None)
+    absent = {"slot_loop::chunk_fetch"} | (
+        set() if prefix else {"slot_loop::restore", "slot_loop::publish"})
     try:
         loop.submit([1, 2, 3], 2).result(timeout=120)       # thread + warm
         opts = jax.profiler.ProfileOptions()
@@ -368,6 +375,7 @@ def test_driver_spans_in_a_capture_flat_and_on_one_thread(tmp_path):
                     for k in range(6)]
             for f in futs:
                 f.result(timeout=120)
+            loop.submit([rng % V for rng in range(11)], 4).result(timeout=120)
         finally:
             jax.profiler.stop_trace()
     finally:
@@ -385,8 +393,7 @@ def test_driver_spans_in_a_capture_flat_and_on_one_thread(tmp_path):
                 lines[(plane.name, line.name)] = sorted(evs)
     assert len(lines) == 1, list(lines)
     (evs,) = lines.values()
-    assert {n for _, _, n in evs} \
-        == set(slots.SPAN_NAMES) - {"slot_loop::chunk_fetch"}
+    assert {n for _, _, n in evs} == set(slots.SPAN_NAMES) - absent
     for (_, end, a), (start, _, b) in zip(evs, evs[1:]):
         assert start >= end, (a, b, start - end)
 

@@ -38,6 +38,12 @@ program) and prints, from ``compiled.as_text()``:
     transposed again in every run), with their MB; ``weight_copies_default``
     is the same count for the weights in their default layouts (the
     programs ``Generator.step_exec`` / ``chunk_exec`` compile alone);
+  * for a configuration served with the prefix cache on (``serve.
+    prefix_cache``): that the cache admits its planes (decided from
+    ``cache_spec``; the first line prints ``plane_kinds``, a ``latent``
+    plane on its own among them), and the same facts for its two data
+    movers, ``kv_push_block`` (a cached block written into a row: planes
+    aliased, index dimensions, copies) and ``kv_pull_block``;
   * the program that activates a row (``Generator.put_logits_row_exec``:
     one row written into the step's ``[S, V]`` logits): ``logits_aliased``,
     whether its output is its donated input, written in place, with no
@@ -358,8 +364,8 @@ def main(argv):
         fn, avals, {"donate_argnums": donate}).as_text(), n_state)
         for what, (_key, _kind, fn, avals, _extra, donate) in progs.items()}
     served = dict(zip(progs, gen.slot_execs(S, T, C)))
-    print(json.dumps({"config": cfg["name"], "weights": gen.weights_layout}),
-          flush=True)
+    print(json.dumps({"config": cfg["name"], "weights": gen.weights_layout,
+                      "plane_kinds": gen.plane_kinds()}), flush=True)
     faults = []
     for what, compiled in served.items():
         text = compiled.as_text()
@@ -389,6 +395,23 @@ def main(argv):
             faults.append(f"{what}: at least {n_agreed} weights on which the "
                           "programs agreed are still copied in every run: "
                           + ", ".join(sorted({n for n, _ in left})[:4]))
+    if sv.get("prefix_cache"):
+        # the prefix cache's two data movers over the same planes: the push
+        # writes a cached block into a row in place (planes aliased, the
+        # traced row and column indices off the lanes, no plane copied),
+        # the pull reads one out and copies no plane either
+        from paddle_tpu.serving.prefix_cache import require_kv_planes
+        require_kv_planes(gen.cache_spec(C), C)
+        for what, compiled in (
+                ("kv_push_block", gen.push_block_exec(S, T, C)),
+                ("kv_pull_block", gen.pull_block_exec(S, T, C))):
+            facts = inspect(compiled.as_text(), plane_shapes, state_shapes)
+            if what == "kv_pull_block":     # read-only: nothing to alias
+                facts["planes_aliased"] = facts["planes_total"]
+            print(json.dumps({"config": cfg["name"], "program": what,
+                              "slots": S, "cache": C, "block": T, **facts}),
+                  flush=True)
+            faults += _faults(what, facts)
     # the row write that activates a row, lowered as the loop gets it
     vocab = gen._vocab_size()
     facts = logits_put(gen.put_logits_row_exec(S).as_text(), (S, vocab))
