@@ -2,7 +2,7 @@
 """chip_smoke.py — the quickest proof that the system starts on the chip.
 
     python chip_smoke.py              # one chip: train, serve, layouts,
-                                      # hybrid, kernels, cache
+                                      # hybrid, latent forms, kernels, cache
     python chip_smoke.py --multichip  # four chips: only the sharded paths
 
 One process, the entry points a user calls (``parallel.TrainStep``,
@@ -577,6 +577,109 @@ def phase_hybrid(size: Size, seed: int = 0) -> dict:
         "moe_assignments": stats["moe_assignments"]})
 
 
+# -- latent forms: absorbed against per head ----------------------------------
+
+# bfloat16 operands, float32 sums, two evaluation orders of one attention
+# (the per-head form rounds the expanded keys and values where the absorbed
+# one rounds ``W_uk^T q`` and the weighted latent): relative to max|out|
+LATENT_FORM_TOL = 2.0 ** -5
+# (configuration, the tiny overlay the CPU tests lay over it, a full layer)
+LATENT_LAYERS = (("kimi-k2.5-ep32-serve", "kimi_tiny", 1),
+                 ("dots3-note-prev-ep8-serve", "dots3_tiny", 1))
+
+
+def phase_latent_forms(size: Size, seed: int = 0) -> dict:
+    """One full latent-attention layer at Kimi-K2.5's width (64 heads, no
+    selector) and one at dots3's (128 heads, the selector binding), random
+    weights in the served dtype: a prompt prefilled in chunks as wide as
+    the served chunk, each chunk run in BOTH cached forms from the same
+    plane; the outputs agree to ``LATENT_FORM_TOL`` at every context and
+    the rows both write are the same to the bit.  The CPU tests hold the
+    two forms to each other in float32; what the chip's compiler makes of
+    either loop only a run on the chip shows (PERF.md section 6, PR 25).
+    ``chunk_ms`` is the last chunk's time, to say that it ran."""
+    import importlib
+    import os
+    from paddle_tpu.framework.functional import _bound_state
+    from paddle_tpu.framework.tensor import unwrap
+    from paddle_tpu.text.models.latent_moe import latent_attention_of
+    t0 = time.perf_counter()
+    root = os.path.dirname(os.path.abspath(__file__))
+    full = size.name == "full"
+    checked, compile_s = {}, 0.0
+    for name, tiny, index in LATENT_LAYERS:
+        with open(os.path.join(root, "benchmark", "configs",
+                               name + ".json")) as f:
+            cfg = json.load(f)
+        if not full:
+            with open(os.path.join(root, "benchmark", "tests", "data",
+                                   tiny + ".json")) as f:
+                over = json.load(f)["over"]
+            cfg["serve"].update(over.pop("serve"), prefill_chunk=32,
+                                attn_block=32)
+            cfg.update(over)
+        models = importlib.import_module("benchmark.models." + cfg["family"])
+        paddle.seed(seed + index)
+        attn = latent_attention_of(models.program_config(cfg), index)
+        T = cfg["serve"]["prefill_chunk"]
+        n = 8 if full else 3
+        C = n * T
+        for w in (attn.q_b, attn.w_uk):
+            # scores of spread ~2: a softmax far from uniform
+            w._value = w._value * 2
+        state = attn.functional_state()
+        dt = attn.q_a._value.dtype
+        ring = attn.gen_ring_cache(1, C, str(dt))
+        cache0 = tuple(unwrap(p) for p in ring)
+        x = jax.random.normal(jax.random.key(seed), (1, C, attn.hidden),
+                              jnp.float32).astype(dt)
+        start = jnp.full((1,), T // 3, jnp.int32)
+
+        def chunk_of(form):
+            def chunk(params, buffers, xs, planes, pos):
+                with _bound_state(attn, params, buffers):
+                    out, cache = attn.forward_cached(
+                        xs, type(ring)(*planes), pos, start)
+                return out, tuple(unwrap(p) for p in cache)
+            # the form is the layer's own rule of T; here each is forced
+            attn.cached_form = lambda T: form
+            try:
+                return jax.jit(chunk).lower(
+                    *state, x[:, :T], cache0, jnp.int32(0)).compile()
+            finally:
+                del attn.cached_form
+        t1 = time.perf_counter()
+        forms = {f: chunk_of(f) for f in ("absorbed", "per_head")}
+        compile_s += time.perf_counter() - t1
+        planes, worst, ms = cache0, 0.0, {}
+        for k in range(n):
+            outs = {f: ex(*state, x[:, k * T:(k + 1) * T], planes,
+                          jnp.int32(k * T)) for f, ex in forms.items()}
+            a, b = (np.asarray(outs[f][0], np.float32) for f in forms)
+            _check(np.isfinite(a).all() and np.isfinite(b).all(),
+                   f"latent_forms {name}: non-finite output at chunk {k}")
+            worst = max(worst, float(np.abs(a - b).max() / np.abs(a).max()))
+            for p, q in zip(outs["absorbed"][1], outs["per_head"][1]):
+                _check(bool(jnp.array_equal(p, q)),
+                       f"latent_forms {name}: the forms wrote other rows")
+            planes = outs["absorbed"][1]
+        for f, ex in forms.items():
+            runs = []
+            for _ in range(5):
+                t1 = time.perf_counter()
+                jax.block_until_ready(ex(*state, x[:, -T:], planes,
+                                         jnp.int32(C - T)))
+                runs.append(time.perf_counter() - t1)
+            ms[f] = round(1e3 * sorted(runs)[2], 3)
+        _check(worst < LATENT_FORM_TOL,
+               f"latent_forms {name}: the forms lie {worst:.2e} of max|out| "
+               "apart")
+        checked[name] = {"heads": attn.H, "chunk": T, "context": C,
+                         "selects": attn.selects, "rule": attn.cached_form(T),
+                         "rel_worst": worst, "chunk_ms": ms}
+    return _emit("latent_forms", t0, compile_s, checked)
+
+
 # -- kernels ------------------------------------------------------------------
 
 def _close(name: str, got, ref) -> float:
@@ -893,6 +996,7 @@ def main(argv=None) -> int:
         served = phase_serve(FULL, args.seed)
         phase_layouts(FULL, args.seed)
         phase_hybrid(FULL, args.seed)
+        phase_latent_forms(FULL, args.seed)
         phase_kernels(FULL, args.seed)
         phase_cache(FULL, served)
     print(json.dumps({"ok": True, "device": device}), flush=True)

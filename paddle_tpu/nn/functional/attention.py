@@ -9,6 +9,8 @@ TPU, the Pallas flash-attention kernel (paddle_tpu/ops/pallas/) takes over.
 """
 from __future__ import annotations
 
+import collections
+import contextlib
 import functools
 import math
 
@@ -516,49 +518,126 @@ def select_columns(scores, valid, k):
     return valid & (above | (tied & (back >= last)))
 
 
-def latent_attend(q_cat, rows, r_kv, keep, scale):
-    """Absorbed-form attention of queries already carried into the
-    latent: ``q_cat [B, T, H, r_kv + d_r]`` (``W_uk^T q_nope ‖ q_rope``)
-    against cache rows ``rows [B, S, r_kv + d_r]`` (``latent ‖ rotary
-    key``) under ``keep [B, T, S]``; returns ``sum_s p(s) latent(s)``
-    ``[B, T, H, r_kv]`` in the queries' dtype (the caller applies
-    ``W_uv``).  A query with nothing kept (a dead slot row) reads the
-    plain mean of the rows: finite, never used."""
-    s = jnp.einsum("bthk,bsk->bhts", q_cat, rows,
-                   preferred_element_type=jnp.float32) * scale
-    s = jnp.where(keep[:, None], s, _NEG)
-    p = jax.nn.softmax(s, axis=-1).astype(q_cat.dtype)
-    return jnp.einsum("bhts,bsr->bthr", p, rows[..., :r_kv],
-                      preferred_element_type=jnp.float32).astype(q_cat.dtype)
+# The two cached forms differ in a PAIR of products and in nothing else:
+# ``scores(rows) -> (s [B, H, T, S] float32, scaled; ctx)`` and
+# ``weigh(p, ctx, out) -> sum_s p(s) value(s)`` (float32, laid out as the
+# einsum subscript ``out`` says), ``ctx`` being what the second needs of
+# the first's work; ``acc_shape`` is ``[B, H, T, width of a value]``;
+# ``scope`` names the attention's instructions in a trace (None: no name
+# of their own).
+LatentProducts = collections.namedtuple(
+    "LatentProducts", ["scores", "weigh", "acc_shape", "scope"])
 
 
-def latent_attend_blocked(q_cat, plane, r_kv, keep_of, scale, lo, hi, block):
+def _scope(products):
+    return jax.named_scope(products.scope) if products.scope \
+        else contextlib.nullcontext()
+
+
+def absorbed_products(q_cat, r_kv, scale):
+    """The ABSORBED pair: queries already carried into the latent,
+    ``q_cat [B, T, H, r_kv + d_r]`` (``W_uk^T q_nope ‖ q_rope``), scored
+    against whole cache rows ``[B, S, r_kv + d_r]`` (``latent ‖ rotary
+    key``); the probabilities weigh the rows' latent ``[.., :r_kv]`` (the
+    caller applies ``W_uv``).  No key is ever expanded: ``2 r_kv + d_r``
+    multiply-adds a head for each (query, column) pair, the form for a
+    step's one query a row."""
+    B, T, H, _ = q_cat.shape
+
+    def scores(rows):
+        return jnp.einsum("bthk,bsk->bhts", q_cat, rows,
+                          preferred_element_type=jnp.float32) * scale, rows
+
+    def weigh(p, rows, out):
+        return jnp.einsum("bhts,bsr->" + out, p.astype(q_cat.dtype),
+                          rows[..., :r_kv],
+                          preferred_element_type=jnp.float32)
+
+    return LatentProducts(scores, weigh, (B, H, T, r_kv), None)
+
+
+def expand_latent(latent, w_uk, w_uv):
+    """Per-head keys and values of latent rows: ``latent [B, S, r_kv]``
+    times ``w_uk [H, r_kv, d_n]`` and ``w_uv [H, r_kv, d_v]`` gives ``k_n
+    [B, H, S, d_n]`` and ``v [B, H, S, d_v]`` in the latent's dtype
+    (float32 sums)."""
+    k_n = jnp.einsum("bsr,hrd->bhsd", latent, w_uk,
+                     preferred_element_type=jnp.float32)
+    v = jnp.einsum("bsr,hrv->bhsv", latent, w_uv,
+                   preferred_element_type=jnp.float32)
+    return k_n.astype(latent.dtype), v.astype(latent.dtype)
+
+
+def per_head_products(q_n, q_r, w_uk, w_uv, r_kv, scale):
+    """The PER-HEAD pair: the rows' keys and values are expanded once
+    (:func:`expand_latent`, ``r_kv (d_n + d_v)`` a column a head, shared
+    by all ``T`` queries), the queries ``q_n [B, T, H, d_n]``, ``q_r [B,
+    T, H, d_r]`` score ``k_n ‖ rotary key`` and the probabilities weigh
+    ``v``: ``d_n + d_r + d_v`` a pair a head, and a value ``d_v`` wide
+    where the absorbed form's is ``r_kv``.  The form for a wide block of
+    queries; the rows may be padded past ``r_kv + d_r``.  Its instructions
+    lie under a named scope of their own, ``per_head``."""
+    B, T, H, _ = q_n.shape
+    d_r = q_r.shape[-1]
+    q = jnp.transpose(jnp.concatenate([q_n, q_r], -1), (0, 2, 1, 3))
+
+    def scores(rows):
+        k_n, v = expand_latent(rows[..., :r_kv], w_uk, w_uv)
+        k_r = jnp.broadcast_to(rows[:, None, :, r_kv:r_kv + d_r],
+                               k_n.shape[:-1] + (d_r,))
+        s = jnp.einsum("bhtd,bhsd->bhts", q, jnp.concatenate([k_n, k_r], -1),
+                       preferred_element_type=jnp.float32) * scale
+        return s, v
+
+    def weigh(p, v, out):
+        return jnp.einsum("bhts,bhsr->" + out, p.astype(v.dtype), v,
+                          preferred_element_type=jnp.float32)
+
+    return LatentProducts(scores, weigh, (B, H, T, w_uv.shape[-1]),
+                          "per_head")
+
+
+def latent_attend(products, rows, keep):
+    """One pass of latent attention in the form ``products`` gives
+    (:func:`absorbed_products`, :func:`per_head_products`) over cache
+    rows ``rows [B, S, K]`` under ``keep [B, T, S]``; returns ``sum_s p(s)
+    value(s)`` ``[B, T, H, width]`` float32.  A query with nothing kept (a
+    dead slot row) reads the plain mean of the rows: finite, never
+    used."""
+    with _scope(products):
+        s, ctx = products.scores(rows)
+        s = jnp.where(keep[:, None], s, _NEG)
+        return products.weigh(jax.nn.softmax(s, axis=-1), ctx, "bthr")
+
+
+def latent_attend_blocked(products, plane, keep_of, lo, hi, block):
     """:func:`latent_attend` over column blocks ``lo <= i < hi`` (traced)
     of ``plane [B, S, K]``, ``block`` columns each, with a running
     softmax, so that a wide query block never holds ``[H, T, S]`` scores
     and columns no query can see cost nothing.  ``keep_of(s0)`` gives the
-    mask ``[B, T, block]`` of the block that starts at column ``s0``."""
-    B, T, H, _ = q_cat.shape
+    mask ``[B, T, block]`` of the block that starts at column ``s0``.
+    The one blocked loop of the latent family: the form is the pair of
+    products it is handed."""
+    B, H, T, _ = products.acc_shape
     K = plane.shape[-1]
 
     def body(i, carry):
         m, l, acc = carry
         s0 = i * block
         rows = jax.lax.dynamic_slice(plane, (0, s0, 0), (B, block, K))
-        s = jnp.einsum("bthk,bsk->bhts", q_cat, rows,
-                       preferred_element_type=jnp.float32) * scale
+        s, ctx = products.scores(rows)
         s = jnp.where(keep_of(s0)[:, None], s, _NEG)
         m_new = jnp.maximum(m, s.max(-1))
         p = jnp.exp(s - m_new[..., None])
         corr = jnp.exp(m - m_new)
         l = l * corr + p.sum(-1)
-        pv = jnp.einsum("bhts,bsr->bhtr", p.astype(q_cat.dtype),
-                        rows[..., :r_kv], preferred_element_type=jnp.float32)
+        pv = products.weigh(p, ctx, "bhtr")
         return m_new, l, acc * corr[..., None] + pv
 
     init = (jnp.full((B, H, T), _NEG, jnp.float32),
             jnp.zeros((B, H, T), jnp.float32),
-            jnp.zeros((B, H, T, r_kv), jnp.float32))
-    m, l, acc = jax.lax.fori_loop(lo, hi, body, init)
-    out = acc / jnp.maximum(l, 1e-30)[..., None]
-    return jnp.transpose(out, (0, 2, 1, 3)).astype(q_cat.dtype)
+            jnp.zeros(products.acc_shape, jnp.float32))
+    with _scope(products):
+        m, l, acc = jax.lax.fori_loop(lo, hi, body, init)
+        out = acc / jnp.maximum(l, 1e-30)[..., None]
+        return jnp.transpose(out, (0, 2, 1, 3))

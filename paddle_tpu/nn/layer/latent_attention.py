@@ -1,11 +1,24 @@
 """Latent (low-rank) attention with a latent KV plane, serving form.
 
 A token's cache row is ``latent ‖ rotary key`` (``r_kv + d_r`` numbers for
-all heads together); per-head keys and values are never stored.  Decoding
-uses the ABSORBED form: the query is carried into the latent
-(``q_hat_h = W_uk,h^T q_nope,h``), scored against the cache rows directly,
-and ``W_uv,h`` is applied to the attention-weighted latent.  Two kinds of
-layer share the code:
+all heads together); per-head keys and values are never stored.  The cached
+attention has two forms over the same rows, and ``forward_cached`` takes
+the one that costs less for the width ``T`` of the query block it is traced
+for (:meth:`LatentAttention.cached_form`, from the layer's own dimensions):
+
+  * **absorbed**, for a step's one query a row and any narrow block: the
+    query is carried into the latent (``q_hat_h = W_uk,h^T q_nope,h``),
+    scored against the cache rows directly, and ``W_uv,h`` is applied to
+    the attention-weighted latent.  No key is expanded; a (query, column)
+    pair costs ``2 r_kv + d_r`` multiply-adds a head;
+  * **per head**, for a wide block (a prefill chunk): each block of columns
+    is expanded once, ``k_n = latent W_uk``, ``v = latent W_uv`` for all
+    heads, shared by the ``T`` queries; a pair costs ``d_n + d_r + d_v`` a
+    head and the running sum is ``d_v`` wide, not ``r_kv``.  Cheaper from
+    ``T (2 r_kv - d_n - d_v) > r_kv (d_n + d_v)`` on.
+
+The masks, the running softmax and the row that is written are the same in
+both.  Two kinds of layer share the code:
 
   * **full** layers keep a plane as long as the session and, beside it, a
     plane of selector keys; a learned selector (``index_n_heads`` small
@@ -26,8 +39,8 @@ rotary positions take a config's ``rope_scaling`` (YaRN: blended
 frequencies, and ``m^2`` on the softmax scale), in all three forms alike.
 
 Everything here runs on raw arrays under ``no_grad`` (decode is
-inference-only); ``forward`` is the cache-less PER-HEAD form over a whole
-sequence, the same numbers by another route.
+inference-only); ``forward`` is the cache-less per-head form in one pass
+over a whole sequence, the same numbers by another route.
 """
 from __future__ import annotations
 
@@ -40,7 +53,8 @@ from jax import lax
 
 from ...framework.tensor import Tensor, unwrap
 from .. import initializer as I
-from ..functional.attention import (latent_attend, latent_attend_blocked,
+from ..functional.attention import (absorbed_products, latent_attend,
+                                    latent_attend_blocked, per_head_products,
                                     rotary, rotary_frequencies,
                                     select_columns, selector_scores,
                                     yarn_attention_factor)
@@ -246,49 +260,79 @@ class LatentAttention(Layer):
             * (self.J ** -0.5) * (self.D ** -0.5)
         return qi, wi, ki
 
-    def _finish(self, out_lat, gate):
-        """``W_uv`` on the attention-weighted latent, the headwise gate,
-        the output projection."""
-        B, T = out_lat.shape[:2]
-        dt = out_lat.dtype
-        o = jnp.einsum("bthr,hrv->bthv", out_lat, unwrap(self.w_uv),
-                       preferred_element_type=jnp.float32)
+    def _absorb_out(self, out_lat):
+        """``W_uv`` on the attention-weighted latent (absorbed form)."""
+        return jnp.einsum("bthr,hrv->bthv", out_lat, unwrap(self.w_uv),
+                          preferred_element_type=jnp.float32)
+
+    def _project_out(self, o, gate, dt):
+        """The headwise gate on the heads' outputs ``o [B, T, H, d_v]``
+        (float32), then the output projection."""
+        B, T = o.shape[:2]
         if gate is not None:
             o = o * gate[..., None]
         o = o.astype(dt).reshape(B, T, self.H * self.dv)
         return jnp.einsum("btk,kh->bth", o, unwrap(self.o_proj),
                           preferred_element_type=jnp.float32).astype(dt)
 
-    # -- cached, absorbed form -------------------------------------------------
+    # -- cached: absorbed for a narrow block of queries, per head for a wide ---
+    def cached_form(self, T):
+        """The form ``forward_cached`` is traced in for a block of ``T``
+        queries, from the layer's own dimensions.  A (query, column) pair
+        costs a head ``2 r_kv + d_r`` multiply-adds absorbed and ``d_n +
+        d_r + d_v`` per head, where a column's keys and values must first
+        be expanded, ``r_kv (d_n + d_v)`` a head, once for all ``T``
+        queries: per head is the cheaper from ``T (2 r_kv - d_n - d_v) >
+        r_kv (d_n + d_v)`` on (``T`` > 170 at 512 / 128 / 128, > 189 at
+        1024 / 192 / 128; never where ``2 r_kv <= d_n + d_v``)."""
+        saved = 2 * self.rkv - self.dn - self.dv
+        return "per_head" if T * saved > self.rkv * (self.dn + self.dv) \
+            else "absorbed"
+
+    def _per_head(self, q_n, q_r):
+        return per_head_products(q_n, q_r, unwrap(self.w_uk),
+                                 unwrap(self.w_uv), self.rkv, self.scale)
+
     def forward_cached(self, x, cache, pos, start, write_rows=None):
         """Append the block ``x [B, T, hidden]`` (normed) at column
-        ``pos`` and attend.  ``start [B]`` is each row's first valid
-        column; ``write_rows [B]`` (step programs of a slot loop) keeps
-        dead rows from writing into a plane that wraps."""
+        ``pos`` and attend, in the form :meth:`cached_form` names for
+        ``T``; the cache row written is the same in both.  ``start [B]``
+        is each row's first valid column; ``write_rows [B]`` (step
+        programs of a slot loop) keeps dead rows from writing into a plane
+        that wraps."""
         B, T, _ = x.shape
         cols = pos + jnp.arange(T, dtype=jnp.int32)
         pos_ids = jnp.maximum(cols[None, :] - start[:, None], 0)
         q_n, q_r, row, gate, c_q = self._project(x, pos_ids)
-        q_hat = jnp.einsum("bthd,hrd->bthr", q_n, unwrap(self.w_uk),
-                           preferred_element_type=jnp.float32).astype(x.dtype)
+        per_head = self.cached_form(T) == "per_head"
         pad = self.row_width - self.rkv - self.dr
-        # the row's padding: zeros in the row and in the query, so the
-        # scores over the padded width are the scores
-        q_cat = jnp.concatenate(
-            [q_hat, q_r, jnp.zeros(q_r.shape[:-1] + (pad,), q_r.dtype)], -1)
+        if per_head:
+            products = self._per_head(q_n, q_r)
+        else:
+            q_hat = jnp.einsum("bthd,hrd->bthr", q_n, unwrap(self.w_uk),
+                               preferred_element_type=jnp.float32
+                               ).astype(x.dtype)
+            # the row's padding: zeros in the row and in the query, so the
+            # scores over the padded width are the scores
+            q_cat = jnp.concatenate(
+                [q_hat, q_r, jnp.zeros(q_r.shape[:-1] + (pad,), q_r.dtype)],
+                -1)
+            products = absorbed_products(q_cat, self.rkv, self.scale)
         row = jnp.concatenate(
             [row, jnp.zeros(row.shape[:-1] + (pad,), row.dtype)], -1)
         with jax.named_scope("latent_attention"):
             if self.window is not None:
-                out, cache = self._window(q_cat, row, unwrap(cache.latent),
+                out, cache = self._window(products, row, unwrap(cache.latent),
                                           cols, start, write_rows)
             else:
-                out, cache = self._full(x, c_q, q_cat, row, cache, cols,
+                out, cache = self._full(x, c_q, products, row, cache, cols,
                                         pos_ids, start)
-        return self._finish(out, gate), cache
+        if not per_head:
+            out = self._absorb_out(out.astype(x.dtype))
+        return self._project_out(out, gate, x.dtype), cache
 
-    def _window(self, q_cat, row, lat, cols, start, write_rows):
-        B, T = q_cat.shape[:2]
+    def _window(self, products, row, lat, cols, start, write_rows):
+        B, T = row.shape[:2]
         n = lat.shape[2]
         if n == self.window + self.cache_block - 1 and T > self.cache_block:
             # (a shorter plane is a whole session: it never wraps)
@@ -311,11 +355,11 @@ class LatentAttention(Layer):
         c = cols[:, None] - (cols[:, None] - j[None, :]) % n      # [T, n]
         keep = (c[None] > cols[None, :, None] - self.window) \
             & (c[None] >= start[:, None, None])
-        out = latent_attend(q_cat, lat[:, 0], self.rkv, keep, self.scale)
+        out = latent_attend(products, lat[:, 0], keep)
         return out, LatentWindowCache(Tensor(lat))
 
-    def _full(self, x, c_q, q_cat, row, cache, cols, pos_ids, start):
-        B, T = q_cat.shape[:2]
+    def _full(self, x, c_q, products, row, cache, cols, pos_ids, start):
+        B, T = row.shape[:2]
         lat = unwrap(cache.latent)
         C = lat.shape[2]
         pos = cols[0] % C
@@ -358,8 +402,8 @@ class LatentAttention(Layer):
                     sel = select_columns(sc, jnp.isfinite(sc), self.topk)
                     keep_of = lambda s0: lax.dynamic_slice(     # noqa: E731
                         sel, (0, 0, s0), (B, T, blk))
-        out = latent_attend_blocked(q_cat, lat[:, 0], self.rkv, keep_of,
-                                    self.scale, lo, hi, blk)
+        out = latent_attend_blocked(products, lat[:, 0], keep_of, lo, hi,
+                                    blk)
         if not self.selects:
             return out, LatentPlane(Tensor(lat))
         return out, LatentCache(Tensor(lat), Tensor(keys))
@@ -367,21 +411,14 @@ class LatentAttention(Layer):
     # -- cache-less, per-head form over a whole sequence -----------------------
     def forward(self, x):
         """``x [B, T, hidden]`` (normed), causal, every token from
-        position 0: per-head keys and values from the latent, the same
-        selection and window rules.  The slow, plain route."""
+        position 0: the per-head form in ONE pass over the sequence's own
+        rows, the same selection and window rules.  No plane, no blocks:
+        the plain route."""
         raw = unwrap(x)
         B, T, _ = raw.shape
         t = jnp.arange(T, dtype=jnp.int32)
         pos_ids = jnp.broadcast_to(t[None], (B, T))
         q_n, q_r, row, gate, c_q = self._project(raw, pos_ids)
-        c_kv, k_r = row[..., :self.rkv], row[..., self.rkv:]
-        k_n = jnp.einsum("bsr,hrd->bshd", c_kv, unwrap(self.w_uk),
-                         preferred_element_type=jnp.float32)
-        v = jnp.einsum("bsr,hrv->bshv", c_kv, unwrap(self.w_uv),
-                       preferred_element_type=jnp.float32)
-        s = (jnp.einsum("bthd,bshd->bhts", q_n.astype(jnp.float32), k_n)
-             + jnp.einsum("bthd,bsd->bhts", q_r.astype(jnp.float32),
-                          k_r.astype(jnp.float32))) * self.scale
         keep = jnp.broadcast_to((t[None, :] <= t[:, None])[None], (B, T, T))
         if self.window is not None:
             keep = keep & (t[None, None, :] > t[None, :, None] - self.window)
@@ -389,11 +426,7 @@ class LatentAttention(Layer):
             qi, wi, ki = self._selector(raw, c_q, pos_ids)
             keep = select_columns(selector_scores(qi, wi, ki), keep,
                                   self.topk)
-        p = jax.nn.softmax(jnp.where(keep[:, None], s, -1e30), -1)
-        o = jnp.einsum("bhts,bshv->bthv", p, v)
-        if gate is not None:
-            o = o * gate[..., None]
-        o = o.astype(raw.dtype).reshape(B, T, self.H * self.dv)
-        out = jnp.einsum("btk,kh->bth", o, unwrap(self.o_proj),
-                         preferred_element_type=jnp.float32).astype(raw.dtype)
+        out = self._project_out(
+            latent_attend(self._per_head(q_n, q_r), row, keep), gate,
+            raw.dtype)
         return Tensor(out) if isinstance(x, Tensor) else out

@@ -304,6 +304,11 @@ class SlotLoop:
         self._step, self._chunk = gen.slot_execs(self.S, self.T, self.C,
                                                  eos_token_id)
         self._kv_heads_per_lane_row = gen.kv_heads_per_lane_row()
+        # the form of each program's latent attention (none for a model
+        # without latent planes; a speculative step is not one token wide)
+        form = getattr(gen, "latent_form", lambda T: None)
+        self._latent_form = {} if self._spec or form(1) is None else {
+            "latent_form": {"step": form(1), "chunk": form(self.T)}}
         # a row's activation logits go from the chunk's output into the
         # step's input on the device; a speculative step carries tokens,
         # not logits, so it has no plane to write into
@@ -1343,7 +1348,7 @@ class SlotLoop:
                "kv_heads_per_lane_row": self._kv_heads_per_lane_row,
                # the weights the step and the chunk agreed to have relaid
                # (Generator.slot_execs), and those they disagreed on
-               **self._gen.weights_layout,
+               **self._gen.weights_layout, **self._latent_form,
                "plane_kinds": list(self._plane_kinds),
                "occupancy_ewma": round(self._occupancy, 4), **c,
                # the driver's seconds by phase, and the phases of the

@@ -64,6 +64,12 @@ __all__ = ["Generator", "generate", "agree_layouts"]
 _FREE_WEIGHTS = (("arg:weights", "auto"),)
 
 
+def _known(**facts):
+    """The facts that are known: a ledger event's ``extra`` names only
+    what the model has."""
+    return {k: v for k, v in facts.items() if v is not None}
+
+
 def _aval(a, fmt=None):
     """``a``'s abstract value; with ``fmt`` a program lowered from it takes
     that argument in that device format and no other."""
@@ -428,6 +434,16 @@ class Generator:
         spec = [s for s in self.cache_spec(1) if s["columns"]]
         return int(spec[0]["heads_per_lane_row"]) if spec else 1
 
+    def latent_form(self, T):
+        """The form a model's cached attention over latent planes takes
+        for the width ``T`` of the block a program is traced for
+        (``"absorbed"`` / ``"per_head"``, the model's ``latent_form``),
+        None for a model that keeps no latent plane: a fact of the
+        program, in the ledger's ``generate_step`` / ``generate_chunk``
+        events and, per program, in ``SlotLoop.stats()``."""
+        fn = getattr(self._layer, "latent_form", None)
+        return None if fn is None else fn(int(T))
+
     def decode_count_names(self):
         """Names of the int32 counts the model's cached forward leaves
         behind (``decode_counts``), which the slot programs hand back
@@ -590,7 +606,8 @@ class Generator:
         return (self._key("step2", S, None, C, 1, 1, end), "generate_step",
                 self._build_step(S, C, end), self.step_avals(S, C),
                 {"slots": S, "cache": C, "eos": end,
-                 "kv_heads_per_lane_row": self.kv_heads_per_lane_row()},
+                 "kv_heads_per_lane_row": self.kv_heads_per_lane_row(),
+                 **_known(latent_form=self.latent_form(1))},
                 (2,))
 
     def _chunk_program(self, S, T, C):
@@ -600,7 +617,8 @@ class Generator:
         return (self._key("chunk2", S, T, C, None, None), "generate_chunk",
                 self._build_chunk(S, T, C), self.chunk_avals(S, T, C),
                 {"slots": S, "chunk": T, "cache": C,
-                 "kv_heads_per_lane_row": self.kv_heads_per_lane_row()},
+                 "kv_heads_per_lane_row": self.kv_heads_per_lane_row(),
+                 **_known(latent_form=self.latent_form(T))},
                 (2,))
 
     def step_exec(self, S, C, eos_token_id=None):

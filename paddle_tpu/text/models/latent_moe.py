@@ -96,29 +96,32 @@ class LatentMoEConfig:
         return cls(**base)
 
 
+def latent_attention_of(cfg: LatentMoEConfig, index: int, weight_attr=None):
+    """Layer ``index``'s attention as ``cfg.layer_types`` says: a full
+    layer (selector by ``index_topk``) or a window layer."""
+    kind = cfg.layer_types[index]
+    if kind not in (FULL, WINDOW):
+        raise ValueError(f"layer_types[{index}] = {kind!r}")
+    kw = dict(cache_block=cfg.cache_block, attn_block=cfg.attn_block,
+              epsilon=cfg.rms_eps, rescale=cfg.rescale_latents,
+              gate=cfg.attention_gate, weight_attr=weight_attr,
+              dtype=cfg.dtype)
+    if kind == FULL:
+        return LatentAttention(
+            cfg.hidden_size, cfg.num_heads, cfg.nope_dim, cfg.rope_dim,
+            cfg.v_dim, cfg.q_rank, cfg.kv_rank, cfg.rope_base,
+            index_heads=cfg.index_heads, index_dim=cfg.index_dim,
+            index_topk=cfg.index_topk, rope_scaling=cfg.rope_scaling, **kw)
+    return LatentAttention(
+        cfg.hidden_size, cfg.window_heads, cfg.window_nope_dim,
+        cfg.window_rope_dim, cfg.window_v_dim, cfg.window_q_rank,
+        cfg.window_kv_rank, cfg.window_rope_base, window=cfg.window, **kw)
+
+
 class LatentDecoderLayer(nn.Layer):
     def __init__(self, cfg: LatentMoEConfig, index: int, weight_attr=None):
         super().__init__()
-        kind = cfg.layer_types[index]
-        if kind not in (FULL, WINDOW):
-            raise ValueError(f"layer_types[{index}] = {kind!r}")
-        kw = dict(cache_block=cfg.cache_block, attn_block=cfg.attn_block,
-                  epsilon=cfg.rms_eps, rescale=cfg.rescale_latents,
-                  gate=cfg.attention_gate, weight_attr=weight_attr,
-                  dtype=cfg.dtype)
-        if kind == FULL:
-            self.attn = LatentAttention(
-                cfg.hidden_size, cfg.num_heads, cfg.nope_dim, cfg.rope_dim,
-                cfg.v_dim, cfg.q_rank, cfg.kv_rank, cfg.rope_base,
-                index_heads=cfg.index_heads, index_dim=cfg.index_dim,
-                index_topk=cfg.index_topk, rope_scaling=cfg.rope_scaling,
-                **kw)
-        else:
-            self.attn = LatentAttention(
-                cfg.hidden_size, cfg.window_heads, cfg.window_nope_dim,
-                cfg.window_rope_dim, cfg.window_v_dim, cfg.window_q_rank,
-                cfg.window_kv_rank, cfg.window_rope_base, window=cfg.window,
-                **kw)
+        self.attn = latent_attention_of(cfg, index, weight_attr)
         self.input_norm = RMSNorm(cfg.hidden_size, cfg.rms_eps,
                                   dtype=cfg.dtype)
         self.post_norm = RMSNorm(cfg.hidden_size, cfg.rms_eps,
@@ -212,6 +215,15 @@ class LatentMoEDecoder(nn.Layer):
         """Per layer, what its planes are (``LatentAttention.
         ring_cache_spec``)."""
         return [l.attn.ring_cache_spec(max_len) for l in self.layers]
+
+    def latent_form(self, T):
+        """The form the layers' cached attention is traced in for a block
+        of ``T`` tokens (``LatentAttention.cached_form``; a wider block
+        is fed ``cache_block`` at a time): ``"absorbed"``, ``"per_head"``,
+        or ``"mixed"`` where the layers' dimensions decide differently."""
+        forms = {l.attn.cached_form(min(int(T), self.config.cache_block))
+                 for l in self.layers}
+        return forms.pop() if len(forms) == 1 else "mixed"
 
     def init_cache(self, batch, max_len, dtype=None):
         if dtype is None:

@@ -74,6 +74,18 @@ def test_hybrid_phase_tiny(no_native_build):
     assert rec["state_rows_held"] > 0 and rec["steps"] == 32
 
 
+def test_latent_forms_phase_tiny(no_native_build):
+    """A kimi-shaped and a dots3-shaped full layer, chunks of 32 over a
+    context of 96 in both cached forms: float32 here, so the two agree to
+    summation order."""
+    rec = chip_smoke.phase_latent_forms(chip_smoke.TINY)["checked"]
+    assert set(rec) == {name for name, _, _ in chip_smoke.LATENT_LAYERS}
+    for got in rec.values():
+        assert got["rel_worst"] < 1e-5 and got["rule"] == "per_head"
+        assert set(got["chunk_ms"]) == {"absorbed", "per_head"}
+    assert [got["selects"] for got in rec.values()] == [False, True]
+
+
 def test_kernels_phase_tiny_interprets_on_cpu(no_native_build):
     rec = chip_smoke.phase_kernels(chip_smoke.TINY)
     assert rec["checked"]["compiled_not_interpreted"] is False

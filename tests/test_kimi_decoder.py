@@ -76,9 +76,9 @@ def served():
     return (cfg,) + _build(cfg)
 
 
-def _serve(model, requests, prompts=None, **loop_kw):
-    gen = Generator(model, max_len=64, seq_buckets=[64])
-    loop = SlotLoop(gen, slots=3, cache_len=64, chunk=4, **loop_kw)
+def _serve(model, requests, prompts=None, chunk=4, columns=64, **loop_kw):
+    gen = Generator(model, max_len=columns, seq_buckets=[columns])
+    loop = SlotLoop(gen, slots=3, cache_len=columns, chunk=chunk, **loop_kw)
     rng = np.random.default_rng(1)
     if prompts is None:
         prompts = [rng.integers(0, 96, n).astype(np.int32)
@@ -128,6 +128,7 @@ def test_slot_loop_equals_the_reference(served, dtype, tol, monkeypatch):
     prompts, tokens, st = _serve(model, REQUESTS)
     assert _widest_gap(cfg, view, prompts, tokens) < tol
     assert st["plane_kinds"] == ["latent"]
+    assert st["latent_form"] == {"step": "absorbed", "chunk": "absorbed"}
     moe_layers, k = 2, cfg["num_experts_per_tok"]
     assert st["moe_assignments"] == \
         (sum(n for n, _ in REQUESTS) + st["emitted_tokens"]) * k * moe_layers
@@ -183,6 +184,49 @@ def test_absorbed_form_equals_the_per_head_form(served):
         pos += n
     np.testing.assert_allclose(np.concatenate(got, 1), full, atol=2e-6)
     assert len(planes) == 1
+
+@pytest.mark.parametrize("blocks", [(5, 3, 7, 4, 1, 1, 6, 1), (27, 1)],
+                         ids=["narrow_blocks", "one_wide_block"])
+def test_the_cached_forms_agree(served, blocks):
+    """A full layer WITHOUT selector under YaRN, three rows whose ``start``
+    differ and are no multiples of the 8-column ``attn_block``: the
+    absorbed form, the per-head form (each forced, whatever the block's
+    width) and the cache-less ``forward`` agree at every live position, and
+    both cached forms write the same rows (tests/test_latent_decoder.py
+    holds the selector and the window layers to the same)."""
+    from test_latent_decoder import assert_forms_agree
+    _, model, _ = served
+    x = jax.random.normal(jax.random.key(7), (3, 28, 32))
+    assert_forms_agree(model.layers[1].attn, x, blocks, (0, 3, 5), 32)
+
+
+@pytest.fixture(scope="module")
+def served_wide():
+    """The tiny model built for chunks of 32 tokens: over the rule's
+    threshold (24 queries at these widths)."""
+    cfg = _tiny()
+    cfg["serve"]["prefill_chunk"] = 32
+    return (cfg,) + _build(cfg)
+
+
+@pytest.mark.parametrize("dtype,tol", [("float32", GAP_TOL),
+                                       ("bfloat16", GAP_TOL_BF16)])
+def test_wide_chunks_equal_the_reference(served_wide, dtype, tol,
+                                         monkeypatch):
+    """Prompts of 5-70 tokens prefilled in chunks of 32 (per head) and
+    decoded by absorbed steps over the rows those chunks wrote, against
+    the reference's full forward, in float32 and in the served dtype."""
+    cfg, model, view = served_wide
+    if dtype != "float32":
+        _cpu_bf16_products(monkeypatch)
+        cfg = dict(cfg, dtype=dtype)
+        model, view = _build(cfg)
+    requests = [(40, 6), (33, 8), (5, 4), (70, 8), (64, 5), (17, 7)]
+    prompts, tokens, st = _serve(model, requests, chunk=32, columns=128)
+    assert _widest_gap(cfg, view, prompts, tokens) < tol
+    assert st["latent_form"] == {"step": "absorbed", "chunk": "per_head"}
+    assert st["chunk_attn_columns_valid"] == 3 * sum(
+        n * (n + 1) // 2 for n, _ in requests)
 
 
 # -- (b) the shares add up ------------------------------------------------------

@@ -5,6 +5,7 @@ the one source of truth, which imports nothing of the program), the
 absorbed against the per-head form, the expert share, dropless routing,
 and the seam through which a model tells the Generator what its planes are.
 """
+import contextlib
 import json
 import os
 import sys
@@ -62,9 +63,9 @@ def served():
                                               bench_models.leaf_ids(cfg))
 
 
-def _serve(model, requests, **loop_kw):
-    gen = Generator(model, max_len=64, seq_buckets=[64])
-    loop = SlotLoop(gen, slots=3, cache_len=64, chunk=4, **loop_kw)
+def _serve(model, requests, chunk=4, columns=64, **loop_kw):
+    gen = Generator(model, max_len=columns, seq_buckets=[columns])
+    loop = SlotLoop(gen, slots=3, cache_len=columns, chunk=chunk, **loop_kw)
     rng = np.random.default_rng(1)
     prompts = [rng.integers(0, 96, n).astype(np.int32) for n, _ in requests]
     futs = [loop.submit(p, k) for p, (_, k) in zip(prompts, requests)]
@@ -87,6 +88,8 @@ def test_slot_loop_equals_the_reference(served):
     prompts, tokens, st = _serve(model, REQUESTS)
     assert _widest_gap(cfg, view, prompts, tokens) < GAP_TOL
     assert st["plane_kinds"] == ["latent+selector_key", "latent_window"]
+    # chunks of 4 queries are under the rule's threshold at these widths
+    assert st["latent_form"] == {"step": "absorbed", "chunk": "absorbed"}
     moe_layers, k = 3, cfg["num_experts_per_tok"]
     assert st["moe_assignments"] == \
         (sum(n for n, _ in REQUESTS) + st["emitted_tokens"]) * k * moe_layers
@@ -181,6 +184,183 @@ def test_absorbed_form_equals_the_per_head_form(served, layer):
         pos += n
     np.testing.assert_allclose(np.concatenate(got, 1), full, atol=2e-6)
     assert (planes[0].shape[2] == 8) == (layer == 2)
+
+# -- the two cached forms ------------------------------------------------------
+
+@contextlib.contextmanager
+def forced_form(attn, form):
+    """``attn.forward_cached`` traced in ``form`` whatever the block's
+    width (the layer's own rule is ``cached_form``, a function of ``T``)."""
+    attn.cached_form = lambda T: form
+    try:
+        yield
+    finally:
+        del attn.cached_form
+
+
+def cached_in_form(attn, form, x, blocks, start, columns):
+    """``x [B, sum(blocks), hidden]`` appended block by block from column 0
+    in ``form``: (the outputs, the planes after every block)."""
+    planes0 = attn.gen_ring_cache(x.shape[0], columns)
+    start = jnp.asarray(start, jnp.int32)
+
+    def cached(xs, planes, pos):
+        out, cache = attn.forward_cached(xs, type(planes0)(*planes), pos,
+                                         start)
+        return out, tuple(unwrap(p) for p in cache)
+
+    planes, pos, outs, seen = tuple(unwrap(p) for p in planes0), 0, [], []
+    with forced_form(attn, form):
+        step = jax.jit(cached)
+        for n in blocks:
+            out, planes = step(x[:, pos:pos + n], planes, jnp.int32(pos))
+            outs.append(np.asarray(out))
+            seen.append([np.asarray(p) for p in planes])
+            pos += n
+    return np.concatenate(outs, 1), seen
+
+
+def assert_forms_agree(attn, x, blocks, start, columns, tol=1e-5):
+    """The absorbed and the per-head cached forms and the cache-less
+    ``forward`` give one answer at every live position (a row's columns
+    from its ``start`` on: position 0 of ``forward``), and the two cached
+    forms write the same rows, bit for bit."""
+    a, planes_a = cached_in_form(attn, "absorbed", x, blocks, start, columns)
+    b, planes_b = cached_in_form(attn, "per_head", x, blocks, start, columns)
+    for pa, pb in zip(planes_a, planes_b):
+        for u, v in zip(pa, pb):
+            np.testing.assert_array_equal(u, v)
+    for row, s0 in enumerate(start):
+        plain = np.asarray(jax.jit(attn.forward)(x[row:row + 1, s0:]))[0]
+        np.testing.assert_allclose(a[row, s0:], plain, atol=tol)
+        np.testing.assert_allclose(b[row, s0:], plain, atol=tol)
+        np.testing.assert_allclose(a[row, s0:], b[row, s0:], atol=tol)
+
+
+@pytest.mark.parametrize("layer,blocks,ties", [
+    (0, (5, 3, 7, 4, 1, 1, 6, 1), False),
+    (0, (5, 3, 7, 4, 1, 1, 6, 1), True),
+    (2, (3, 2, 4, 1, 4, 1, 4, 4, 1, 4), False),
+], ids=["full_selector", "full_selector_all_tied", "window_wraps"])
+def test_the_cached_forms_agree(served, layer, blocks, ties):
+    """One layer, three rows whose ``start`` differ and are no multiples of
+    the 8-column ``attn_block``, blocks of 1-7 queries over 28 columns: a
+    full layer whose selector binds (6 of up to 28 valid columns; with the
+    selector's head weights zeroed EVERY score ties and the lowest columns
+    are chosen), a window layer round its 8-column plane three times."""
+    _, model, _ = served
+    attn = model.layers[layer].attn
+    x = jax.random.normal(jax.random.key(7), (3, 28, 32))
+    held = attn.idx_w._value if ties else None
+    if ties:
+        attn.idx_w._value = jnp.zeros_like(held)
+    try:
+        assert_forms_agree(attn, x, blocks, (0, 3, 5), 32)
+    finally:
+        if ties:
+            attn.idx_w._value = held
+    assert attn.selects == (layer == 0)
+
+
+@pytest.mark.parametrize("r_kv,d_n,d_v,T,form", [
+    (512, 128, 128, 170, "absorbed"), (512, 128, 128, 171, "per_head"),
+    (1024, 192, 128, 189, "absorbed"), (1024, 192, 128, 190, "per_head"),
+    (512, 128, 128, 1, "absorbed"), (512, 128, 128, 512, "per_head"),
+    (12, 8, 8, 24, "absorbed"), (12, 8, 8, 25, "per_head"),
+    (8, 8, 8, 10 ** 6, "absorbed"),
+], ids=lambda v: str(v))
+def test_the_rule_picks_the_cheaper_form(r_kv, d_n, d_v, T, form):
+    """``cached_form`` from the layer's own dimensions: the served full
+    layers (512 / 128 / 128: per head from 171 queries on) and dots3's
+    window layers (1024 / 192 / 128: from 190), the tiny ones of these
+    tests (from 25), and a layer whose latent is no wider than a head's
+    key and value together (never)."""
+    from paddle_tpu.nn.layer.latent_attention import LatentAttention
+    attn = LatentAttention(8, 1, d_n, 4, d_v, 4, r_kv, 1e4, index_topk=0)
+    assert attn.cached_form(T) == form
+    # the operations either form takes for T queries over one column
+    absorbed = T * (2 * r_kv + 4)
+    per_head = T * (d_n + 4 + d_v) + r_kv * (d_n + d_v)
+    assert (per_head < absorbed) == (form == "per_head")
+
+
+@pytest.mark.parametrize("T", [24, 25])
+def test_either_side_of_the_threshold(T):
+    """A full layer with the selector at the tiny widths (threshold 24),
+    NOT forced: a block of 24 queries is traced absorbed, one of 25 per
+    head (its loop under a scope of its own), and both are ``forward``."""
+    from paddle_tpu.text.models.latent_moe import latent_attention_of
+    cfg = _tiny()
+    cfg["serve"]["prefill_chunk"] = 32
+    attn = latent_attention_of(bench_models.program_config(cfg), 1)
+    x = jax.random.normal(jax.random.key(3), (2, T, 32))
+    planes0 = attn.gen_ring_cache(2, 32)
+
+    def cached(xs, planes):
+        out, _ = attn.forward_cached(xs, type(planes0)(*planes), jnp.int32(0),
+                                     jnp.zeros(2, jnp.int32))
+        return out
+    lowered = jax.jit(cached).lower(x, tuple(unwrap(p) for p in planes0))
+    text = lowered.as_text(debug_info=True)
+    assert "/latent_attention/" in text
+    assert ("/latent_attention/per_head/" in text) == (T == 25)
+    np.testing.assert_allclose(np.asarray(lowered.compile()(
+        x, tuple(unwrap(p) for p in planes0))),
+        np.asarray(jax.jit(attn.forward)(x)), atol=1e-5)
+
+
+@pytest.fixture(scope="module")
+def served_wide():
+    """The tiny model built for chunks of 32 tokens, over the rule's
+    threshold at its widths (window planes of 5 + 32 - 1 columns)."""
+    from benchmark import harness
+    cfg = _tiny()
+    cfg["serve"]["prefill_chunk"] = 32
+    mapped = bench_models.to_program(ref.init_weights(cfg, 5))
+    return cfg, bench_models.build(cfg, mapped), harness.canonical_view(
+        mapped, bench_models.leaf_ids(cfg))
+
+
+def test_the_step_is_absorbed_and_a_wide_chunk_per_head(served_wide):
+    """The slot loop's two programs as the Generator builds them: the step
+    (one query a row) lowers with the ``latent_attention`` scope and no
+    ``per_head`` under it, the 32-wide chunk with ``per_head`` in every
+    layer, and the ledger events' ``extra`` say so."""
+    _, model, _ = served_wide
+    gen = Generator(model, max_len=64, seq_buckets=[64])
+    texts = {}
+    for what, prog in (("step", gen._step_program(3, 64)),
+                       ("chunk", gen._chunk_program(3, 32, 64))):
+        _key, _kind, fn, avals, extra, donate = prog
+        texts[what] = jax.jit(fn, donate_argnums=donate).lower(
+            *gen._state_avals(), *avals).as_text(debug_info=True)
+        assert extra["latent_form"] == \
+            {"step": "absorbed", "chunk": "per_head"}[what]
+    assert "/latent_attention/" in texts["step"]
+    assert "/per_head/" not in texts["step"]
+    assert texts["chunk"].count("/latent_attention/per_head/while") >= 2
+    assert model.latent_form(1) == "absorbed"
+    assert model.latent_form(64) == model.latent_form(32) == "per_head"
+    # (a GPT keeps no latent plane: no such fact in its events)
+    from paddle_tpu.text.models.gpt import GPTConfig, GPTModel
+    gpt = Generator(GPTModel(GPTConfig.tiny(vocab_size=32, hidden_size=16,
+                                            layers=1, heads=2, seq=32)),
+                    max_len=32, seq_buckets=[32])
+    assert gpt.latent_form(1) is None
+    assert "latent_form" not in gpt._step_program(2, 32)[4]
+
+
+def test_wide_chunks_equal_the_reference(served_wide):
+    """Prompts of 5-70 tokens prefilled in chunks of 32 (per head: full
+    layers with the selector binding, window layers round their planes)
+    and decoded by absorbed steps over the rows those chunks wrote, against
+    the reference's full forward."""
+    cfg, model, view = served_wide
+    requests = [(40, 6), (33, 8), (5, 4), (70, 8), (64, 5), (17, 7)]
+    prompts, tokens, st = _serve(model, requests, chunk=32, columns=128)
+    assert _widest_gap(cfg, view, prompts, tokens) < GAP_TOL
+    assert st["latent_form"] == {"step": "absorbed", "chunk": "per_head"}
+    assert st["chunk_tokens"] == sum(n for n, _ in requests)
 
 
 def _moe_fn(layer):

@@ -33,6 +33,10 @@ program) and prints, from ``compiled.as_text()``:
     planes in column blocks under one, ``cached_attention``; the step's
     line says how wide a block is): whether a plane enters one as a
     copy, or is copied inside its body;
+  * for a latent-attention model, ``latent_form`` beside each program: the
+    form its cached attention takes at that program's block width
+    (``absorbed`` for the step's one query a row, ``per_head`` for a wide
+    chunk: ``LatentAttention.cached_form``);
   * ``weight_copies``: the ``copy``/``transpose`` instructions of at least
     1 MB whose operand chain starts at a parameter of the model (a weight
     transposed again in every run), with their MB; ``weight_copies_default``
@@ -373,6 +377,10 @@ def main(argv):
         if what == "step" and "kv" in gen.plane_kinds():
             # the column blocks of the step's attention (cached_attention)
             facts = {"attn_block": decode_block(C), **facts}
+        # the form of a latent model's cached attention at this width
+        form = gen.latent_form(1 if what == "step" else T)
+        if form is not None:
+            facts = {"latent_form": form, **facts}
         left = weight_copies(text, n_state)
         facts.update(
             weight_copies_default=len(default[what]),
