@@ -52,14 +52,10 @@ def synchronize(device=None):
     """cudaDeviceSynchronize parity: drain pending async work — a device
     runs its programs in order, so blocking on one enqueued now waits for
     everything before it (chip_smoke.py's train phase checks on the chip
-    that block_until_ready does wait).  The fence is
-    a profiler span (``device::synchronize``) — the Profiler uses it to
-    close record windows, and its duration is the step's outstanding
-    device time."""
+    that block_until_ready does wait).  The Profiler closes its record
+    windows with it."""
     import jax.numpy as jnp
-    from ..profiler import span as _span
-    with _span("device::synchronize"):
-        jnp.zeros(()).block_until_ready()
+    jnp.zeros(()).block_until_ready()
 
 
 class cuda:

@@ -473,7 +473,8 @@ def load_or_compile(lower: Callable[[], Any], *, site: str, kind: str,
                     ledger_miss: bool = True,
                     cache: Optional[ExecutableCache] = None,
                     writable: Optional[bool] = None,
-                    events: Optional[list] = None):
+                    events: Optional[list] = None,
+                    hlo_text: Optional[Callable[[], Any]] = None):
     """Consult the cache, else compile (and store under readwrite).
 
     ``lower`` runs the cold path: () -> ``jax.stages.Compiled``.  On a
@@ -488,9 +489,12 @@ def load_or_compile(lower: Callable[[], Any], *, site: str, kind: str,
     Executor's legacy per-predictor optim-cache dir passes its own.
     ``events``, a list, receives the ledger event this call records (a
     caller that learns more about the program later adds it there).
+    ``hlo_text`` goes to that event as it is
+    (``profiler.ledger.record_compile``).
     """
     def ledger(kind_, ms, extra_):
-        ev = _ledger.record_compile(site, kind_, key, ms, extra=extra_)
+        ev = _ledger.record_compile(site, kind_, key, ms, extra=extra_,
+                                    hlo_text=hlo_text)
         if events is not None:
             events.append(ev)
 

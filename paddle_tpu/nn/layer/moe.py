@@ -649,13 +649,15 @@ class SwiGLU(Layer):
     def forward(self, u):
         raw = unwrap(u)
         f32 = jnp.float32
-        g = jnp.einsum("...h,hf->...f", raw, unwrap(self.w_gate),
-                       preferred_element_type=f32)
-        v = jnp.einsum("...h,hf->...f", raw, unwrap(self.w_up),
-                       preferred_element_type=f32)
-        y = jnp.einsum("...f,fh->...h", (jax.nn.silu(g) * v).astype(raw.dtype),
-                       unwrap(self.w_down), preferred_element_type=f32)
-        y = y.astype(raw.dtype)
+        with jax.named_scope("mlp"):
+            g = jnp.einsum("...h,hf->...f", raw, unwrap(self.w_gate),
+                           preferred_element_type=f32)
+            v = jnp.einsum("...h,hf->...f", raw, unwrap(self.w_up),
+                           preferred_element_type=f32)
+            y = jnp.einsum("...f,fh->...h",
+                           (jax.nn.silu(g) * v).astype(raw.dtype),
+                           unwrap(self.w_down), preferred_element_type=f32)
+            y = y.astype(raw.dtype)
         return Tensor(y) if isinstance(u, Tensor) else y
 
 
@@ -763,7 +765,8 @@ class DroplessMoE(Layer):
         x = raw.reshape(-1, shape[-1])
         N, k, n = x.shape[0], self.top_k, self.hi - self.lo
         with jax.named_scope("experts"):
-            ids, w = self.route(x)
+            with jax.named_scope("router"):
+                ids, w = self.route(x)
             alive = jnp.ones((N,), bool) if live is None \
                 else unwrap(live).reshape(-1)
             # a dead token (left padding, a dead slot row) is computed by

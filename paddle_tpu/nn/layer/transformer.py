@@ -11,6 +11,8 @@ from __future__ import annotations
 
 import collections
 
+import jax
+
 from ...framework.tensor import Tensor, unwrap
 from ...ops import concat, reshape, transpose
 from .. import functional as F
@@ -60,23 +62,26 @@ def ring_block_write(plane, new, pos, axis=None):
     (:func:`kv_heads_per_lane_row`); ``tools/kv_layout_check.py`` reads
     the layout back from the compiled program.
     """
+    with jax.named_scope("cache_write"):
+        out = _ring_block_write(unwrap(plane), unwrap(new), unwrap(pos), axis)
+    return Tensor(out) if isinstance(plane, Tensor) \
+        or isinstance(new, Tensor) else out
+
+
+def _ring_block_write(p, n, pos, axis):
     import jax.numpy as jnp
     from jax import lax
-    p, n = unwrap(plane), unwrap(new)
-    wrap = isinstance(plane, Tensor) or isinstance(new, Tensor)
     ax = p.ndim - 2 if axis is None else int(axis)
     C, T = p.shape[ax], n.shape[ax]
     if T > C:
         raise ValueError(
             f"ring block of {T} tokens cannot fit a cache of length {C}")
-    pos = unwrap(pos)
     sp = _static_int(pos)
     if T == 1 or (sp is not None and sp + T <= C):
         # width-1 writes never cross the boundary (pos is pre-wrapped),
         # and a statically in-range block (the prefill fill at pos 0)
         # needs no second leg — the existing single-store lowering
-        out = lax.dynamic_update_slice_in_dim(p, n.astype(p.dtype), pos, ax)
-        return Tensor(out) if wrap else out
+        return lax.dynamic_update_slice_in_dim(p, n.astype(p.dtype), pos, ax)
     pos = jnp.asarray(pos, jnp.int32)
     n = n.astype(p.dtype)
     idx_shape = [1] * p.ndim
@@ -98,9 +103,8 @@ def ring_block_write(plane, new, pos, axis=None):
     v2 = lax.dynamic_slice_in_dim(jnp.concatenate([n, pad], axis=ax),
                                   jnp.minimum(jnp.int32(C) - pos,
                                               jnp.int32(T)), T, ax)
-    out = lax.dynamic_update_slice_in_dim(
+    return lax.dynamic_update_slice_in_dim(
         out, jnp.where(idx < w, v2, cur2), 0, ax)
-    return Tensor(out) if wrap else out
 
 
 _LANES = 128     # minor-dimension tile width of the TPU's device layouts
@@ -359,25 +363,30 @@ class TransformerEncoderLayer(Layer):
 
     def forward(self, src, src_mask=None, cache=None, cache_position=None,
                 decode_window=None):
-        residual = src
-        if self.normalize_before:
-            src = self.norm1(src)
-        if cache is None:
-            src = self.self_attn(src, src, src, src_mask)
-        else:
-            src, cache = self.self_attn(src, src, src, src_mask, cache,
-                                        cache_position=cache_position,
-                                        decode_window=decode_window)
-        src = residual + self.dropout1(src)
-        if not self.normalize_before:
-            src = self.norm1(src)
-        residual = src
-        if self.normalize_before:
-            src = self.norm2(src)
-        src = self.linear2(self.dropout(self.activation(self.linear1(src))))
-        src = residual + self.dropout2(src)
-        if not self.normalize_before:
-            src = self.norm2(src)
+        # the layer's two halves, each with its norm and its residual add,
+        # under the names a trace is read by (docs/METRICS.md)
+        with jax.named_scope("attention"):
+            residual = src
+            if self.normalize_before:
+                src = self.norm1(src)
+            if cache is None:
+                src = self.self_attn(src, src, src, src_mask)
+            else:
+                src, cache = self.self_attn(src, src, src, src_mask, cache,
+                                            cache_position=cache_position,
+                                            decode_window=decode_window)
+            src = residual + self.dropout1(src)
+            if not self.normalize_before:
+                src = self.norm1(src)
+        with jax.named_scope("mlp"):
+            residual = src
+            if self.normalize_before:
+                src = self.norm2(src)
+            src = self.linear2(
+                self.dropout(self.activation(self.linear1(src))))
+            src = residual + self.dropout2(src)
+            if not self.normalize_before:
+                src = self.norm2(src)
         return src if cache is None else (src, cache)
 
     def gen_cache(self, src):

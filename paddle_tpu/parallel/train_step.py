@@ -363,10 +363,11 @@ class TrainStep:
         averages through bf16 every step (losing small-momentum updates).
         Returns (params, buffers, inputs)."""
         fl = lambda v: jnp.issubdtype(v.dtype, jnp.floating)
-        params = {n: (v.astype(cd) if fl(v) else v)
-                  for n, v in params.items()}
-        inputs = tuple(x.astype(cd) if x is not None and fl(x) else x
-                       for x in inputs)
+        with jax.named_scope("cast_params"):
+            params = {n: (v.astype(cd) if fl(v) else v)
+                      for n, v in params.items()}
+            inputs = tuple(x.astype(cd) if x is not None and fl(x) else x
+                           for x in inputs)
         return params, buffers, inputs
 
     def _pipe_loss_of(self, params, buffers, inputs, label, rng_key):
@@ -428,8 +429,10 @@ class TrainStep:
                 rng_key=rng_key, mutable_buffers=True)
             if isinstance(out, (tuple, list)):
                 out = out[0]
-            loss = self.loss_fn(out, label)
-        return loss.astype(jnp.float32).mean(), new_buffers
+            with jax.named_scope("loss"):
+                loss = self.loss_fn(out, label)
+        with jax.named_scope("loss"):
+            return loss.astype(jnp.float32).mean(), new_buffers
 
     def _rank_grad(self, loss_of, params, buffers, mb_in, mb_lb, key):
         """(loss, grads, new_buffers) for ONE dp rank's batch shard,
@@ -728,8 +731,9 @@ class TrainStep:
                          for n, g in grads.items()}
             grads = constrain_grads(grads)
 
-            new_params, new_opt = self.optimizer.functional_apply(
-                state["params"], grads, state["opt"], new_step, lr)
+            with jax.named_scope("optimizer"):
+                new_params, new_opt = self.optimizer.functional_apply(
+                    state["params"], grads, state["opt"], new_step, lr)
             new_state = {"params": new_params, "buffers": new_buffers,
                          "opt": new_opt, "step": new_step}
             if not sentinel:
@@ -837,6 +841,24 @@ class TrainStep:
             # loads still ledger as cache_load per the warm-start proof
             ledger_miss=False)
         return compiled
+
+    def _text_of(self, fn, inputs, label, lr, scale):
+        """For the compile ledger (``profiler.ledger.program_scopes``):
+        how to read the text of the step that has just run for these
+        arguments.  ``jax.jit`` keeps the executable it dispatches to
+        itself; lowering the same arguments again, right after the call,
+        is answered from its caches (milliseconds: no trace, no compile)
+        and yields the handle of THAT executable.  The handle is kept by
+        what is returned, not by this TrainStep: a training loop's
+        TrainStep may be gone by the time a capture is read (the
+        benchmark's runner drops it with its frame), and nothing else
+        holds the program.  The text itself is fetched when asked for.
+        None where that fails: a step never fails for its trace's sake."""
+        try:
+            return fn.lower(self._state, inputs, label, lr, scale) \
+                .compile().as_text
+        except Exception:
+            return None
 
     # -- eager entry ---------------------------------------------------------
     def _feed_placer(self, inputs):
@@ -1022,7 +1044,9 @@ class TrainStep:
             with _span("train_step::compile"):
                 self._state, out = fn(self.state, inputs, label, lr, scale)
             _ledger.record_compile(site, "train_step", sig,
-                                   (time.perf_counter() - t0) * 1e3)
+                                   (time.perf_counter() - t0) * 1e3,
+                                   hlo_text=self._text_of(
+                                       fn, inputs, label, lr, scale))
         else:
             _ledger.record_cache_hit(site)
             if prof or tr:

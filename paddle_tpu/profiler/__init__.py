@@ -23,6 +23,7 @@ from __future__ import annotations
 
 import contextlib
 import json
+import os
 import threading
 import time
 from collections import defaultdict, deque
@@ -184,7 +185,11 @@ class Profiler:
     profiler at the end of every record window.  While a window records,
     host spans collect (profiling_enabled() is true) and — unless
     ``timer_only`` — jax.profiler captures device-side XPlane data into
-    ``profiler_result_dir``.
+    ``profiler_result_dir``, and ``program_scopes.json`` is written beside
+    it when the window ends (``ledger.program_scopes()``: which part of
+    the model asked for each instruction of the compiled programs), so
+    that the capture can be read by program and by named scope after the
+    process is gone (docs/METRICS.md).
     """
 
     def __init__(self, targets=None, scheduler=None, on_trace_ready=None,
@@ -234,6 +239,10 @@ class Profiler:
         if self._jax_started:
             try:
                 jax.profiler.stop_trace()
+                from . import ledger
+                with open(os.path.join(self._dir, "program_scopes.json"),
+                          "w") as f:
+                    json.dump(ledger.program_scopes(), f)
             except Exception:
                 pass
             self._jax_started = False

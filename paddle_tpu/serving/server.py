@@ -43,7 +43,6 @@ from ..framework import flags as _flags
 from ..framework.enforce import (InvalidArgumentError, NotFoundError,
                                  PreconditionNotMetError, UnavailableError)
 from ..profiler import ledger as _ledger
-from ..profiler import span as _span
 from ..profiler import tracing as _tracing
 from ..profiler.metrics import LatencyWindow, RateMeter
 from ..utils.monitor import stat_add
@@ -549,12 +548,10 @@ class _Worker(threading.Thread):
         if ex is None:
             with _tracing.use_span(_first_trace(batch)):
                 ex = rt.late_compile(batch.bucket)
-        with _span("serving::h2d"):
-            dev = [jax.device_put(a) for a in padded]
+        dev = [jax.device_put(a) for a in padded]
         t_e0 = time.monotonic()
         _trace_batch(batch, "h2d", t_h0, t_e0, bucket=batch.bucket)
-        with _span("serving::dispatch"):
-            outs = ex(dev)
+        outs = ex(dev)
         self._inflight.append((batch, outs, t_e0))
         while len(self._inflight) > self._depth:
             self._fence_oldest()
@@ -562,8 +559,7 @@ class _Worker(threading.Thread):
     def _fence_oldest(self):
         batch, outs, t_e0 = self._inflight.popleft()
         t_f0 = time.monotonic()
-        with _span("serving::fence"):
-            outs_np = [np.asarray(o) for o in outs]
+        outs_np = [np.asarray(o) for o in outs]
         t_f1 = time.monotonic()
         # execute = dispatch → fence start (the async pipeline residency
         # window); d2h = the blocking fetch that fences it

@@ -37,6 +37,7 @@ transformer stack).
 from __future__ import annotations
 
 import time
+import weakref
 from typing import Optional, Sequence
 
 import numpy as np
@@ -211,18 +212,20 @@ def require_prefix_planes(spec, columns, who):
 def _slice_row(cache, rowidx):
     """Row ``rowidx`` of every cache plane as a batch-1 cache view (a
     traced ``dynamic_slice`` — the row index is a runtime scalar)."""
-    return [tuple(lax.dynamic_slice(p, (rowidx,) + (0,) * (p.ndim - 1),
-                                    (1,) + p.shape[1:]) for p in c)
-            for c in cache]
+    with jax.named_scope("cache_write/row_slice"):
+        return [tuple(lax.dynamic_slice(p, (rowidx,) + (0,) * (p.ndim - 1),
+                                        (1,) + p.shape[1:]) for p in c)
+                for c in cache]
 
 
 def _splice_row(cache, sub, rowidx):
     """Write a batch-1 cache back into row ``rowidx`` of the full
     planes — the single-row inverse of :func:`_slice_row`."""
-    return [tuple(lax.dynamic_update_slice(
-                      p, ps, (rowidx,) + (0,) * (p.ndim - 1))
-                  for p, ps in zip(c, cs))
-            for c, cs in zip(cache, sub)]
+    with jax.named_scope("cache_write/row_splice"):
+        return [tuple(lax.dynamic_update_slice(
+                          p, ps, (rowidx,) + (0,) * (p.ndim - 1))
+                      for p, ps in zip(c, cs))
+                for c, cs in zip(cache, sub)]
 
 
 
@@ -548,16 +551,19 @@ class Generator:
 
         def step(params, buffers, cache, logits, start, finished, active,
                  pos):
-            tok = jnp.argmax(logits, axis=-1).astype(jnp.int32)
-            tok = jnp.where(finished, jnp.int32(end), tok)
-            finished = finished | (tok == end)
-            # inactive rows may carry garbage argmax (end == -1 included)
-            # — clamp their fed token; their write lands in a dead column
-            fed = jnp.where(active, tok, jnp.int32(0))
+            with jax.named_scope("head/sample"):
+                tok = jnp.argmax(logits, axis=-1).astype(jnp.int32)
+                tok = jnp.where(finished, jnp.int32(end), tok)
+                finished = finished | (tok == end)
+                # inactive rows may carry garbage argmax (end == -1
+                # included) — clamp their fed token; their write lands in
+                # a dead column
+                fed = jnp.where(active, tok, jnp.int32(0))
             nlogits, ncache = apply(params, buffers, fed[:, None], cache,
                                     pos, start, active)
-            nlog = jnp.where(active[:, None],
-                             nlogits[:, 0].astype(jnp.float32), logits)
+            with jax.named_scope("head/sample"):
+                nlog = jnp.where(active[:, None],
+                                 nlogits[:, 0].astype(jnp.float32), logits)
             counts = self._decode_counts()
             if counts is not None:
                 # the model's counts ride the token read-back: [S + n]
@@ -582,8 +588,9 @@ class Generator:
         def chunk(params, buffers, cache, ids, start, rowidx, pos):
             sub = _slice_row(cache, rowidx)
             logits, nsub = apply(params, buffers, ids, sub, pos, start)
-            out = (_splice_row(cache, nsub, rowidx),
-                   logits[0, -1, :].astype(jnp.float32))
+            ncache = _splice_row(cache, nsub, rowidx)
+            with jax.named_scope("head"):
+                out = (ncache, logits[0, -1, :].astype(jnp.float32))
             counts = self._decode_counts()
             return out if counts is None else out + (counts,)
 
@@ -973,9 +980,21 @@ class Generator:
             lambda: self._lower(fn, arg_avals, jit_kw, free),
             site=self._site, kind=kind, key=key,
             extra_key=self._program_identity(), extra=extra,
-            events=events)
+            events=events, hlo_text=self._text_of(key))
         self._execs[key] = ex
         return ex
+
+    def _text_of(self, key):
+        """For the compile ledger (``profiler.ledger.program_scopes``):
+        the text of the executable kept under ``key``, read when it is
+        asked for, or None once this Generator is gone."""
+        ref = weakref.ref(self)
+
+        def text():
+            gen = ref()
+            ex = gen._execs.get(key) if gen is not None else None
+            return ex.as_text() if ex is not None else None
+        return text
 
     def _compile_data(self, key, kind, fn, arg_avals, extra,
                       donate_argnums=None):

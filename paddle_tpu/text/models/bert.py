@@ -17,6 +17,8 @@ from __future__ import annotations
 
 import dataclasses
 
+import jax
+
 from ... import nn
 from ...nn import functional as F
 from ...ops import manipulation as M
@@ -105,14 +107,16 @@ class BertModel(nn.Layer):
     def forward(self, input_ids, token_type_ids=None, position_ids=None,
                 attention_mask=None):
         from ... import ops
-        if attention_mask is not None:
-            # [B, S] 1/0 mask -> additive [B, 1, 1, S]
-            m = M.unsqueeze(attention_mask, [1, 2])
-            attention_mask = (1.0 - m.astype("float32")) * -1e4
-        emb = self.embeddings(input_ids, token_type_ids, position_ids)
+        with jax.named_scope("embed"):
+            if attention_mask is not None:
+                # [B, S] 1/0 mask -> additive [B, 1, 1, S]
+                m = M.unsqueeze(attention_mask, [1, 2])
+                attention_mask = (1.0 - m.astype("float32")) * -1e4
+            emb = self.embeddings(input_ids, token_type_ids, position_ids)
         seq = self.encoder(emb, attention_mask)
         if self.pooler is not None:
-            return seq, self.pooler(seq)
+            with jax.named_scope("head"):
+                return seq, self.pooler(seq)
         return seq
 
 
@@ -165,16 +169,18 @@ class BertForPretraining(nn.Layer):
                 masked_positions=None):
         seq, pooled = self.bert(input_ids, token_type_ids,
                                 attention_mask=attention_mask)
-        logits, nsp = self.cls(seq, pooled, masked_positions)
+        with jax.named_scope("head"):
+            logits, nsp = self.cls(seq, pooled, masked_positions)
         if masked_lm_labels is None:
             return logits, nsp
-        mlm_loss = F.cross_entropy(
-            logits.reshape([-1, self.config.vocab_size]),
-            masked_lm_labels.reshape([-1]), ignore_index=-100)
-        loss = mlm_loss
-        if next_sentence_label is not None:
-            loss = loss + F.cross_entropy(nsp,
-                                          next_sentence_label.reshape([-1]))
+        with jax.named_scope("loss"):
+            mlm_loss = F.cross_entropy(
+                logits.reshape([-1, self.config.vocab_size]),
+                masked_lm_labels.reshape([-1]), ignore_index=-100)
+            loss = mlm_loss
+            if next_sentence_label is not None:
+                loss = loss + F.cross_entropy(
+                    nsp, next_sentence_label.reshape([-1]))
         return loss
 
 

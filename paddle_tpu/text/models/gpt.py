@@ -9,6 +9,8 @@ from __future__ import annotations
 import dataclasses
 from typing import Optional
 
+import jax
+
 from ... import nn
 from ...nn import functional as F
 from ...ops import manipulation as M
@@ -49,16 +51,20 @@ class GPTModel(nn.Layer):
     def forward(self, input_ids, labels=None):
         from ... import ops
         b, s = input_ids.shape
-        pos = M.unsqueeze(ops.arange(s, dtype="int64"), 0)
-        h = self.drop(self.wte(input_ids) + self.wpe(pos))
-        causal = ops.triu(ops.full([s, s], -1e4, dtype="float32"), diagonal=1)
+        with jax.named_scope("embed"):
+            pos = M.unsqueeze(ops.arange(s, dtype="int64"), 0)
+            h = self.drop(self.wte(input_ids) + self.wpe(pos))
+            causal = ops.triu(ops.full([s, s], -1e4, dtype="float32"),
+                              diagonal=1)
         h = self.encoder(h, M.unsqueeze(causal, [0, 1]))
-        logits = ops.matmul(h, self.wte.weight, transpose_y=True)
+        with jax.named_scope("head"):
+            logits = ops.matmul(h, self.wte.weight, transpose_y=True)
         if labels is None:
             return logits
-        return F.cross_entropy(
-            logits[:, :-1].reshape([-1, self.config.vocab_size]),
-            labels[:, 1:].reshape([-1]))
+        with jax.named_scope("loss"):
+            return F.cross_entropy(
+                logits[:, :-1].reshape([-1, self.config.vocab_size]),
+                labels[:, 1:].reshape([-1]))
 
     # -- incremental decoding (static-shape KV ring cache) -------------------
     def init_cache(self, batch, max_len, dtype=None):
@@ -101,15 +107,17 @@ class GPTModel(nn.Layer):
         pos = jnp.asarray(pos, jnp.int32) if not isinstance(pos, int) \
             else jnp.int32(pos)
         start = jnp.asarray(unwrap(start_positions), jnp.int32)
-        row = pos + jnp.arange(t, dtype=jnp.int32)       # global cache cols
-        pos_ids = jnp.clip(row[None, :] - start[:, None], 0,
-                           self.config.max_position_embeddings - 1)
-        h = self.drop(self.wte(input_ids) + self.wpe(Tensor(pos_ids)))
-        # valid key col j for query row i: start_b <= j <= pos + i
-        col = jnp.arange(C, dtype=jnp.int32)
-        valid = ((col[None, None, None, :] <= row[None, None, :, None])
-                 & (col[None, None, None, :] >= start[:, None, None, None]))
-        mask = Tensor(jnp.where(valid, 0.0, -1e30).astype(jnp.float32))
+        with jax.named_scope("embed"):
+            row = pos + jnp.arange(t, dtype=jnp.int32)   # global cache cols
+            pos_ids = jnp.clip(row[None, :] - start[:, None], 0,
+                               self.config.max_position_embeddings - 1)
+            h = self.drop(self.wte(input_ids) + self.wpe(Tensor(pos_ids)))
+            # valid key col j for query row i: start_b <= j <= pos + i
+            col = jnp.arange(C, dtype=jnp.int32)
+            valid = ((col[None, None, None, :] <= row[None, None, :, None])
+                     & (col[None, None, None, :]
+                        >= start[:, None, None, None]))
+            mask = Tensor(jnp.where(valid, 0.0, -1e30).astype(jnp.float32))
         window = None
         if t == 1:
             # decode step: the mask is a contiguous [start, pos+1) window,
@@ -119,7 +127,8 @@ class GPTModel(nn.Layer):
             h, mask, cache=cache,
             cache_position=Tensor(pos % jnp.int32(C)),
             decode_window=window)
-        logits = ops.matmul(h, self.wte.weight, transpose_y=True)
+        with jax.named_scope("head"):
+            logits = ops.matmul(h, self.wte.weight, transpose_y=True)
         return logits, new_cache
 
     def generate(self, input_ids, lengths=None, max_new_tokens=32,

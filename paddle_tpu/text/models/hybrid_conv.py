@@ -168,9 +168,10 @@ class ShortConv(nn.Layer):
                            jnp.zeros((), state.dtype))
         with jax.named_scope("short_conv"):
             y, full = self._gated(x, before, live[:, n:])
-            new = full[:, T:].astype(state.dtype)
-            if write_rows is not None:
-                new = jnp.where(write_rows[:, None, None], new, state)
+            with jax.named_scope("cache_write"):
+                new = full[:, T:].astype(state.dtype)
+                if write_rows is not None:
+                    new = jnp.where(write_rows[:, None, None], new, state)
         return y, ConvStateCache(Tensor(new[:, None]))
 
     def forward(self, x):
@@ -308,21 +309,32 @@ class HybridDecoderLayer(nn.Layer):
         operand."""
         return unwrap(norm(x)).astype(unwrap(self.ffn.w_gate).dtype)
 
+    # Each half of the layer, its norm and its residual add included, lies
+    # under the name a trace is read by (docs/METRICS.md).
     def _ffn(self, h, live):
-        u = self._operand(self.ffn_norm, h)
-        y = self.ffn(u, live) if isinstance(self.ffn, DroplessMoE) \
-            else self.ffn(u)
-        return h + unwrap(y).astype(jnp.float32)
+        moe = isinstance(self.ffn, DroplessMoE)
+        with jax.named_scope("experts" if moe else "mlp"):
+            u = self._operand(self.ffn_norm, h)
+            y = self.ffn(u, live) if moe else self.ffn(u)
+            return h + unwrap(y).astype(jnp.float32)
+
+    def _mixer_scope(self):
+        return jax.named_scope("short_conv" if isinstance(
+            self.mixer, ShortConv) else "attention")
 
     def forward_cached(self, x, cache, pos, start, write_rows, live):
-        a, cache = self.mixer.forward_cached(
-            self._operand(self.operator_norm, x), cache, pos, start,
-            write_rows)
-        return self._ffn(x + a.astype(jnp.float32), live), cache
+        with self._mixer_scope():
+            a, cache = self.mixer.forward_cached(
+                self._operand(self.operator_norm, x), cache, pos, start,
+                write_rows)
+            h = x + a.astype(jnp.float32)
+        return self._ffn(h, live), cache
 
     def forward(self, x):
-        a = unwrap(self.mixer(self._operand(self.operator_norm, x)))
-        return self._ffn(x + a.astype(jnp.float32), None)
+        with self._mixer_scope():
+            a = unwrap(self.mixer(self._operand(self.operator_norm, x)))
+            h = x + a.astype(jnp.float32)
+        return self._ffn(h, None)
 
 
 # what ``decode_counts`` returns, in order (the latent family's names: the
@@ -359,12 +371,14 @@ class HybridConvDecoder(nn.Layer):
 
     def _logits(self, h):
         table = unwrap(self.embed.weight)
-        return jnp.einsum("bth,vh->btv",
-                          unwrap(self.norm(h)).astype(table.dtype), table,
-                          preferred_element_type=jnp.float32)
+        with jax.named_scope("head"):
+            return jnp.einsum("bth,vh->btv",
+                              unwrap(self.norm(h)).astype(table.dtype), table,
+                              preferred_element_type=jnp.float32)
 
     def forward(self, input_ids):
-        h = unwrap(self.embed(input_ids)).astype(jnp.float32)
+        with jax.named_scope("embed"):
+            h = unwrap(self.embed(input_ids)).astype(jnp.float32)
         for layer in self.layers:
             h = layer(h)
         return Tensor(self._logits(h))
@@ -393,11 +407,12 @@ class HybridConvDecoder(nn.Layer):
             else jnp.asarray(pos, jnp.int32)
         start = jnp.asarray(unwrap(start_positions), jnp.int32)
         rows = None if write_rows is None else unwrap(write_rows)
-        h = unwrap(self.embed(Tensor(ids))).astype(jnp.float32)
-        live = (pos + jnp.arange(T, dtype=jnp.int32))[None, :] \
-            >= start[:, None]
-        if rows is not None:
-            live = live & rows[:, None]
+        with jax.named_scope("embed"):
+            h = unwrap(self.embed(Tensor(ids))).astype(jnp.float32)
+            live = (pos + jnp.arange(T, dtype=jnp.int32))[None, :] \
+                >= start[:, None]
+            if rows is not None:
+                live = live & rows[:, None]
         zero = jnp.int32(0)
         counts, new = (zero, zero, zero), []
         for layer, c in zip(self.layers, cache):

@@ -25,6 +25,7 @@ from __future__ import annotations
 import dataclasses
 from typing import Optional, Sequence, Tuple
 
+import jax
 import jax.numpy as jnp
 
 from ... import nn
@@ -145,21 +146,31 @@ class LatentDecoderLayer(nn.Layer):
     # rounding the operands alone gives, and the router's top-k flipped
     # accordingly (PERF.md section 6, PR 27); the stream is [tokens,
     # hidden], so float32 costs nothing that shows.
+    # Each half of the layer, its norm and its residual add included, lies
+    # under the name a trace is read by (docs/METRICS.md).
     def _ffn(self, h, live):
-        dt = unwrap(self.ffn.w_gate).dtype
-        u = unwrap(self.post_norm(h)).astype(dt)
-        y = self.ffn(u, live) if isinstance(self.ffn, DroplessMoE) \
-            else self.ffn(u)
-        return h + unwrap(y).astype(jnp.float32)
+        moe = isinstance(self.ffn, DroplessMoE)
+        with jax.named_scope("experts" if moe else "mlp"):
+            dt = unwrap(self.ffn.w_gate).dtype
+            u = unwrap(self.post_norm(h)).astype(dt)
+            y = self.ffn(u, live) if moe else self.ffn(u)
+            return h + unwrap(y).astype(jnp.float32)
 
     def forward_cached(self, x, cache, pos, start, write_rows, live):
-        xn = unwrap(self.input_norm(x)).astype(unwrap(self.attn.q_a).dtype)
-        a, cache = self.attn.forward_cached(xn, cache, pos, start, write_rows)
-        return self._ffn(x + a.astype(jnp.float32), live), cache
+        with jax.named_scope("attention"):
+            xn = unwrap(self.input_norm(x)).astype(
+                unwrap(self.attn.q_a).dtype)
+            a, cache = self.attn.forward_cached(xn, cache, pos, start,
+                                                write_rows)
+            h = x + a.astype(jnp.float32)
+        return self._ffn(h, live), cache
 
     def forward(self, x):
-        xn = unwrap(self.input_norm(x)).astype(unwrap(self.attn.q_a).dtype)
-        return self._ffn(x + unwrap(self.attn(xn)).astype(jnp.float32), None)
+        with jax.named_scope("attention"):
+            xn = unwrap(self.input_norm(x)).astype(
+                unwrap(self.attn.q_a).dtype)
+            h = x + unwrap(self.attn(xn)).astype(jnp.float32)
+        return self._ffn(h, None)
 
 
 # what ``decode_counts`` returns, in order; the slot loop sums the first
@@ -199,16 +210,18 @@ class LatentMoEDecoder(nn.Layer):
 
     # -- whole sequence, per-head form -----------------------------------------
     def forward(self, input_ids):
-        h = unwrap(self.embed(input_ids)).astype(jnp.float32)
+        with jax.named_scope("embed"):
+            h = unwrap(self.embed(input_ids)).astype(jnp.float32)
         for layer in self.layers:
             h = layer(h)
         return Tensor(self._logits(h))
 
     def _logits(self, h):
         head = unwrap(self.head)
-        return jnp.einsum("bth,hv->btv",
-                          unwrap(self.norm(h)).astype(head.dtype), head,
-                          preferred_element_type=jnp.float32)
+        with jax.named_scope("head"):
+            return jnp.einsum("bth,hv->btv",
+                              unwrap(self.norm(h)).astype(head.dtype), head,
+                              preferred_element_type=jnp.float32)
 
     # -- incremental decoding --------------------------------------------------
     def cache_spec(self, max_len):
@@ -261,11 +274,12 @@ class LatentMoEDecoder(nn.Layer):
 
     def _cached_block(self, ids, cache, pos, start, rows):
         B, T = ids.shape
-        h = unwrap(self.embed(Tensor(ids))).astype(jnp.float32)
-        cols = pos + jnp.arange(T, dtype=jnp.int32)
-        live = cols[None, :] >= start[:, None]
-        if rows is not None:
-            live = live & rows[:, None]
+        with jax.named_scope("embed"):
+            h = unwrap(self.embed(Tensor(ids))).astype(jnp.float32)
+            cols = pos + jnp.arange(T, dtype=jnp.int32)
+            live = cols[None, :] >= start[:, None]
+            if rows is not None:
+                live = live & rows[:, None]
         zero = jnp.int32(0)
         counts, new = (zero, zero, zero), []
         for layer, c in zip(self.layers, cache):

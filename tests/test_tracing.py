@@ -441,8 +441,7 @@ def test_obs_report_joins_traces_and_metrics(flags_guard, tmp_path):
     assert rc == 1
 
 
-def test_obs_report_waterfall_marks_tokens_and_compiles(flags_guard,
-                                                        tmp_path):
+def test_obs_report_waterfall_marks_compiles(flags_guard, tmp_path):
     import time
     set_flags({"FLAGS_trace": "full"})
     d = str(tmp_path / "tr")
@@ -455,8 +454,6 @@ def test_obs_report_waterfall_marks_tokens_and_compiles(flags_guard,
                   batch_rows=1, padding_rows=0)
     tracing.child(r, "prefill", t - 0.008, t - 0.006)
     dec = tracing.start_span("decode", parent=r, t0=t - 0.006)
-    for k in range(3):
-        dec.event("token", t=t - 0.006 + (k + 1) * 0.001, index=k)
     dec.event("compile", site="serving:g", kind="serving_recompile",
               ms=12.0)
     tracing.finish(dec, end=t - 0.001)
@@ -465,5 +462,4 @@ def test_obs_report_waterfall_marks_tokens_and_compiles(flags_guard,
     obs = _load_tool("obs_report")
     traces = obs.load_traces(d)
     w = obs.waterfall(traces[r.trace_id])
-    assert "[3 tokens]" in w
     assert "[1 COMPILE]" in w

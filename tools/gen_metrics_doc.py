@@ -56,6 +56,63 @@ gauges (`jit_compile_count`, `serving_queue_depth`, ...) ride the same
 exposition as the `paddle_tpu_stat{name=...}` family.
 """
 
+TRAILER = """\
+
+## Device time by program and by named scope
+
+Not part of the metrics plane above: what a profiler capture (`.xplane.pb`)
+can say about the device's BUSY seconds.  The model code names its work with
+`jax.named_scope` (metadata written when a program is traced; nothing at run
+time), from one small vocabulary.  A name is a kind of work, never a layer
+index: 48 layers of `attention` add up.
+
+| scope | where it is entered | covers |
+|---|---|---|
+| `embed` | `GPTModel.forward` / `forward_cached`, `BertModel.forward`, the latent and hybrid decoders | token, position and type lookups, the mask arithmetic beside them |
+| `attention` | `TransformerEncoderLayer.forward`, `LatentDecoderLayer`, `HybridDecoderLayer` (an attention layer) | a layer's attention half: its norm, the q/k/v/out products, the scores, the residual add.  Inside it the newer families keep `latent_attention`, `latent_attention/per_head`, `selector`, `gqa_attention` |
+| `cache_write` | `ring_block_write`, the short convolution's state, `Generator`'s `_slice_row` / `_splice_row` (`cache_write/row_slice`, `cache_write/row_splice`) | every write into a cache plane, and a chunk's batch-1 row out of the planes and back.  Innermost wins: a plane write inside `attention` counts here |
+| `mlp` | `TransformerEncoderLayer.forward`, `SwiGLU.forward`, the decoders' dense layers | a layer's dense feed-forward half with its norm and residual add; a shared expert |
+| `experts` | `DroplessMoE.forward`, the decoders' expert layers; the router as `experts/router` | routed experts with their norm and residual add |
+| `short_conv` | `ShortConv.forward_cached`, `HybridDecoderLayer` (a convolution layer) | the gated short convolution with its norm and residual add |
+| `head` | the logits product of every model, BERT's pooler and heads; `head/sample` in `Generator._build_step` | vocabulary product; the step's `argmax` / `where` |
+| `loss`, `optimizer`, `cast_params` | `TrainStep._loss_of` and the models' own loss, the optimizer update of `TrainStep._build_step`, `TrainStep._cast_compute` | the training step's own parts; backward is told from forward by the `transpose(jvp(..))` wrapper JAX writes itself |
+
+`paddle_tpu.profiler.ledger.program_scopes()` returns `{XLA module name:
+{instruction name: {"scope": path[, "backward": True][, "inherited":
+True]}}}` for every program the compile ledger recorded with its text
+(`Generator`'s executables, `TrainStep`'s step): the path is read off the
+compiled program's own text (`metadata={op_name=...}`), wrappers and the
+trailing primitive stripped.  An instruction the compiler made (no
+`op_name`: a weight's prefetch, a relayout) takes the entry of the first
+instruction that uses its result, marked `inherited`.  Nothing is fetched or
+parsed until the function is called; an executable is held weakly.  A
+capture names each op event by the same instruction name (`%fusion.555 =
+...`), so the two join: `benchmark/layer_metrics/_program_scopes.py` lays
+every op under the program run that contains it, gives it its self time
+(a `while` holds its body), and adds the seconds up by the bucket
+(`benchmark/scope_buckets.json`) of the innermost listed component of its
+path; no path is `unscoped`, a name the table lacks `unknown_instruction`.
+
+To read a capture of your own: `paddle_tpu.profiler.Profiler` writes
+`program_scopes.json` into `profiler_result_dir` when a record window
+ends, beside the `.xplane.pb`, so both survive the process; then
+
+    python3 -m benchmark.layer_metrics._program_scopes <trace dir>
+
+prints, per program, `device seconds by scope, jit_step: {runs, mean_ms,
+buckets {...}, unscoped, unknown_instruction, backward {...}}` and the ten
+heaviest ops by self time with their scope.  `tools/kv_layout_check.py
+<config>` prints `scope_instructions` and `unscoped_pct` of the served
+programs compiled for a described chip: the coverage, before chip time.
+
+Host spans that no benchmark metric reads, for an operator's own capture
+(`profiler.span`, on their thread's line of any `.xplane.pb`):
+`executor::{data_feed, compile, device_execute, fetch, dataset_upload,
+dataset_scan}` (`static/executor.py`), `jit::{trace_compile, execute}`
+(`jit/__init__.py`), `train_step::{data_feed, compile, dispatch,
+collective_assemble}` (`parallel/train_step.py`).
+"""
+
 
 def render() -> str:
     import importlib
@@ -73,7 +130,7 @@ def render() -> str:
     lines.append("")
     for r in rows:
         lines.append(f"- **`{r['name']}`** ({r['type']}): {r['doc']}")
-    return "\n".join(lines) + "\n"
+    return "\n".join(lines) + "\n" + TRAILER
 
 
 def main():

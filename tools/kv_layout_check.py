@@ -51,7 +51,14 @@ program) and prints, from ``compiled.as_text()``:
   * the program that activates a row (``Generator.put_logits_row_exec``:
     one row written into the step's ``[S, V]`` logits): ``logits_aliased``,
     whether its output is its donated input, written in place, with no
-    copy of the plane beside it.
+    copy of the plane beside it;
+  * ``scope_instructions``: the instructions a capture would show as ops
+    (those of no fused computation and of no reducer), counted by the
+    bucket of their ``jax.named_scope`` path (``profiler.ledger.
+    parse_scopes``, the parser behind ``program_scopes()``;
+    ``benchmark/scope_buckets.json``), and ``unscoped_pct``, the share
+    with no bucket: how far the per-scope device times of a traced run
+    (PERF.md section 3) will reach, read before chip time is spent.
 
 The two programs are compiled through ``Generator.slot_execs``, the slot
 loop's own way to them, so what is checked is what is served: the line
@@ -173,6 +180,37 @@ def weight_copies(hlo_text, n_state, min_mb=1.0):
         if mb >= min_mb and src in params:
             out.append((params[src], mb))
     return out
+
+
+_COMPUTATION = re.compile(r"^(?:ENTRY )?%([\w.\-]+) \(", re.M)
+_CALLED = re.compile(r"(?:calls|to_apply)=%([\w.\-]+)")
+_NO_OP_EVENT = ("parameter", "constant", "get-tuple-element", "tuple",
+                "bitcast")
+
+
+def scope_coverage(hlo_text):
+    """({bucket: instructions}, unscoped %) over the instructions of the
+    computations that run as ops of their own: not a fusion's nor a
+    reducer's (``calls=``, ``to_apply=``), and no parameter, constant,
+    tuple or bitcast."""
+    from paddle_tpu.profiler import ledger
+    from benchmark.layer_metrics import _program_scopes
+    _module, table = ledger.parse_scopes(hlo_text)
+    inner = set(_CALLED.findall(hlo_text))
+    kept, heads = {}, list(_COMPUTATION.finditer(hlo_text))
+    for head, nxt in zip(heads, heads[1:] + [None]):
+        if head.group(1) in inner:
+            continue
+        body = hlo_text[head.end():nxt.start() if nxt else len(hlo_text)]
+        for line in body.splitlines():
+            m = _ANY.match(line) or _INSTR.match(line)
+            if m and m["op"] not in _NO_OP_EVENT and m["name"] in table:
+                kept[m["name"]] = table[m["name"]]
+    counts = _program_scopes.count_buckets(
+        kept, _program_scopes.load_buckets())
+    none = counts.get(_program_scopes.UNSCOPED, 0)
+    return dict(sorted(counts.items())), \
+        round(100.0 * none / max(1, sum(counts.values())), 1)
 
 
 def _aliased_params(hlo_text):
@@ -388,6 +426,8 @@ def main(argv):
                 sum(mb for _, mb in default[what]), 1),
             weight_copies=len(left),
             weight_copies_mb=round(sum(mb for _, mb in left), 1))
+        facts["scope_instructions"], facts["unscoped_pct"] = \
+            scope_coverage(text)
         print(json.dumps({"config": cfg["name"], "program": what,
                           "slots": S, "cache": C, **facts}), flush=True)
         mem = compiled.memory_analysis()

@@ -184,15 +184,9 @@ def waterfall(spans, width=48):
         b = max(a + 1, int((max(0.0, off_ms) + s["dur_ms"]) / total
                            * width))
         bar = " " * a + "#" * min(b - a, width - a)
-        extra = ""
-        n_tok = sum(1 for e in s.get("events", [])
-                    if e.get("name") == "token")
-        if n_tok:
-            extra = f"  [{n_tok} tokens]"
         n_compiles = sum(1 for e in s.get("events", [])
                          if e.get("name") == "compile")
-        if n_compiles:
-            extra += f"  [{n_compiles} COMPILE]"
+        extra = f"  [{n_compiles} COMPILE]" if n_compiles else ""
         nm = s["name"]
         if s.get("process"):
             nm = f"{nm}@{s['process']}"
