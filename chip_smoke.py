@@ -69,6 +69,8 @@ LOSS_TOL = 2e-2
 KERNEL_TOL = {
     "flash_attention_fwd": (2e-2, 2e-2),
     "flash_attention_bwd": (5e-2, 5e-2),
+    "single_block_attention_fwd": (2e-2, 2e-2),
+    "single_block_attention_bwd": (5e-2, 5e-2),
     "flash_decode": (4e-3, 2e-2),
     "flash_decode_int8": (4e-3, 2e-2),
     "packed_cached_attention": (4e-3, 2e-2),
@@ -94,6 +96,7 @@ class Size:
     prompt_lens: Tuple[int, ...]
     slots: int
     attn: Tuple[int, int, int, int]            # B, N, S, H
+    attn_train: Tuple[int, int, int, int]      # B, N, S, H, un-cached
     decode_heads: int                          # of H, over the packed ring
     conv_x: Tuple[int, int, int, int]          # N, H, W, C (NHWC)
     conv_w: Tuple[int, int, int, int]          # O, I, kh, kw
@@ -119,7 +122,9 @@ FULL = Size(
                   max_position_embeddings=1024, dropout=0.0),
     serve_batch_buckets=(1, 4), serve_seq_buckets=(32, 128),
     serve_max_new=16, serve_max_len=256, prompt_lens=(5, 19, 32, 70, 128, 9),
-    slots=8, attn=(8, 12, 1024, 64), decode_heads=25,
+    # attn_train: the benchmark's BERT-large step (16 rows of 512)
+    slots=8, attn=(8, 12, 1024, 64), attn_train=(16, 16, 512, 64),
+    decode_heads=25,
     conv_x=(32, 56, 56, 64),
     conv_w=(64, 64, 3, 3),
     moe=_moe_cfg(vocab_size=128, hidden_size=512, layers=4, heads=8,
@@ -138,7 +143,8 @@ TINY = Size(
                        seq=128),
     serve_batch_buckets=(1, 2), serve_seq_buckets=(8, 16), serve_max_new=4,
     serve_max_len=32, prompt_lens=(3, 7, 12, 1, 9, 5), slots=4,
-    attn=(1, 2, 256, 64), decode_heads=3, conv_x=(2, 8, 8, 8),
+    attn=(1, 2, 256, 64), attn_train=(1, 2, 128, 64), decode_heads=3,
+    conv_x=(2, 8, 8, 8),
     conv_w=(8, 8, 3, 3),
     moe=_moe_cfg(vocab_size=64, hidden_size=16, layers=2, heads=2, seq=32,
                  experts=4),
@@ -715,12 +721,20 @@ def _run_kernel(name: str, fn, ref_fn, args, compiled_expected: bool):
     return compile_s, _close(name, got, ref)
 
 
-def _attention_ref(q, k, v):
+def _attention_ref(q, k, v, causal=True):
     q, k, v = (a.astype(jnp.float32) for a in (q, k, v))
     s = jnp.einsum("bnsh,bnth->bnst", q, k) / np.sqrt(q.shape[-1])
-    mask = jnp.tril(jnp.ones(s.shape[-2:], bool))
-    p = jax.nn.softmax(jnp.where(mask, s, -1e30), axis=-1)
-    return jnp.einsum("bnst,bnth->bnsh", p, v)
+    if causal:
+        s = jnp.where(jnp.tril(jnp.ones(s.shape[-2:], bool)), s, -1e30)
+    return jnp.einsum("bnst,bnth->bnsh", jax.nn.softmax(s, axis=-1), v)
+
+
+def _attention_ref_bse(q, k, v, heads):
+    """The plain attention, not causal, of ``[B, S, N*H]`` operands."""
+    def split(x):
+        return x.reshape(x.shape[:2] + (heads, -1)).transpose(0, 2, 1, 3)
+    out = _attention_ref(split(q), split(k), split(v), causal=False)
+    return out.transpose(0, 2, 1, 3).reshape(q.shape)
 
 
 def _conv_bn_relu_ref(x, w, gamma, beta):
@@ -746,7 +760,7 @@ def phase_kernels(size: Size, seed: int = 0) -> dict:
                                        dequantize_kv, flash_attention_fn,
                                        flash_decode_fn,
                                        flash_decode_quant_fn, fused_bn,
-                                       fused_conv)
+                                       fused_conv, packed_attention_fn)
     from paddle_tpu.nn.layer.transformer import quantize_kv_rows
     t0 = time.perf_counter()
     on_chip = jax.default_backend() == "tpu"
@@ -759,6 +773,16 @@ def phase_kernels(size: Size, seed: int = 0) -> dict:
     B, N, S, H = size.attn
     q, k, v = rand((B, N, S, H)), rand((B, N, S, H)), rand((B, N, S, H))
     q1 = rand((B, N, 1, H))
+    # the un-cached training shape, heads side by side as the projections
+    # write them (the form the dispatch gives BERT's step)
+    Bt, Nt, St, Ht = size.attn_train
+    qt, kt, vt = (rand((Bt, St, Nt * Ht)) for _ in range(3))
+
+    def packed(q, k, v):
+        return packed_attention_fn(q, k, v, Nt)
+
+    def packed_ref(q, k, v):
+        return _attention_ref_bse(q, k, v, Nt)
     # a left-padded ring: each row's valid window starts somewhere else
     start = jnp.asarray(rng.randint(0, S // 2, (B,)), jnp.int32)
     end = jnp.asarray(rng.randint(S // 2 + 1, S + 1, (B,)), jnp.int32)
@@ -782,6 +806,10 @@ def phase_kernels(size: Size, seed: int = 0) -> dict:
             jax.grad(loss(lambda q, k, v: flash_attention_fn(
                 q, k, v, causal=True)), argnums=(0, 1, 2)),
             jax.grad(loss(_attention_ref), argnums=(0, 1, 2)), (q, k, v)),
+        "single_block_attention_fwd": (packed, packed_ref, (qt, kt, vt)),
+        "single_block_attention_bwd": (
+            jax.grad(loss(packed), argnums=(0, 1, 2)),
+            jax.grad(loss(packed_ref), argnums=(0, 1, 2)), (qt, kt, vt)),
         "flash_decode": (
             flash_decode_fn,
             lambda q, k, v, s, e: decode_attention_reference(

@@ -27,7 +27,8 @@ WHITE_LIST = {"matmul_v2", "mul", "conv2d", "conv2d_nobias",
               "scaled_dot_product_attention",
               "scaled_dot_product_attention_mask",
               "scaled_dot_product_attention_packed",
-              "flash_attention", "flash_attention_bias", "bilinear_nobias"}
+              "flash_attention", "flash_attention_bias",
+              "packed_attention", "packed_attention_bias", "bilinear_nobias"}
 BLACK_LIST = {"exp", "log", "softmax", "log_softmax",
               "softmax_with_cross_entropy", "softmax_with_cross_entropy_soft",
               "layer_norm", "layer_norm_nogb", "batch_norm_train",

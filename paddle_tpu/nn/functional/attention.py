@@ -3,9 +3,15 @@
 Reference parity: the reference era predates fused attention ops (it has only
 softmax/matmul composition inside nn/layer/transformer.py); we expose a
 first-class ``scaled_dot_product_attention`` because it is THE hot op on TPU.
-Default path is a single fused XLA expression (bf16 matmuls on the MXU with
-f32 softmax accumulation); when FLAGS_use_pallas_kernels is set and we're on
-TPU, the Pallas flash-attention kernel (paddle_tpu/ops/pallas/) takes over.
+An un-cached call site takes one of two forms, chosen from its shapes when
+the program is traced (``_use_pallas`` -> ops/pallas/flash_attention.py
+``fused_form``, a table measured on the chip, forward + backward): a Pallas
+kernel that keeps a block's float32 scores and probabilities in VMEM in both
+passes, or one XLA expression that writes them out (bf16 products on the MXU,
+f32 softmax statistics and accumulation in both).  Off the TPU, with
+FLAGS_use_pallas_kernels off, and for a trainable mask, always the latter.
+The cached paths (``cached_attention``, ``span_attention``, the latent
+family's) never ask.
 """
 from __future__ import annotations
 
@@ -13,6 +19,7 @@ import collections
 import contextlib
 import functools
 import math
+import threading
 
 import jax
 import jax.numpy as jnp
@@ -273,44 +280,88 @@ _sdpa_packed = Primitive("scaled_dot_product_attention_packed",
                          _sdpa_packed_fn)
 
 
-def _use_pallas(q, k, mask=None, causal=False):
-    if not flag("use_pallas_kernels") or jax.default_backend() != "tpu":
-        return False
-    # the flash kernel's bias input is non-differentiable; a trainable mask
-    # (learned relative-position bias) must take the XLA path
-    if isinstance(mask, Tensor) and not mask.stop_gradient:
-        return False
-    from ...ops.pallas import supports
-    from ...ops.pallas.flash_attention import MIN_SEQ_FOR_FLASH
-    kshape = unwrap(k).shape
-    # short sequences are dispatch/bandwidth-bound: the one-expression XLA
-    # path wins there (measured crossover at Sk=1024 on v5e)
-    if len(kshape) != 4 or kshape[-2] < MIN_SEQ_FOR_FLASH:
-        return False
-    mk = unwrap(mask).shape if mask is not None else None
-    return supports(unwrap(q).shape, kshape, mk, causal=causal)
+def _on_tpu():
+    return jax.default_backend() == "tpu"
+
+
+def _partitioned():
+    """Whether programs are being traced for a mesh of several devices.
+    The kernels carry no partitioning rule: the compiler would gather
+    their operands whole onto every device."""
+    from ...parallel.mesh import get_mesh, has_mesh
+    return has_mesh() and get_mesh().size > 1
+
+
+_tally = threading.local()
+
+
+@contextlib.contextmanager
+def count_attention_forms():
+    """Tally the un-cached attention call sites that are traced inside the
+    block by the form each takes: yields ``{"fused": n, "xla": m}``.  The
+    choice is made while a program is traced, so the count is a fact of
+    the program (``TrainStep`` puts it in its compile-ledger event)."""
+    prev = getattr(_tally, "forms", None)
+    _tally.forms = forms = {"fused": 0, "xla": 0}
+    try:
+        yield forms
+    finally:
+        _tally.forms = prev
+
+
+def _use_pallas(q_shape, k_shape, mask=None, causal=False, packed=False):
+    """The form an un-cached attention call site of ``(B, N, S, H)`` shapes
+    takes: ``"single_block"`` / ``"blocked"`` (the Pallas kernels of
+    ops/pallas/flash_attention.py) or None (the one-expression XLA path).
+    From the shapes alone (``fused_form``: whichever was the faster on
+    the chip, forward + backward), after what no shape says: the flag,
+    the backend, a mesh of several devices (:func:`_partitioned`), and a
+    trainable mask, whose gradient the kernels do not produce.  ``packed``
+    says the caller holds ``[B, S, N*H]`` operands, which the single-block
+    form takes as they lie.  Each call is one call site in
+    :func:`count_attention_forms`'s tally."""
+    form = None
+    if flag("use_pallas_kernels") and _on_tpu() and not _partitioned() \
+            and not (isinstance(mask, Tensor) and not mask.stop_gradient):
+        from ...ops.pallas.flash_attention import fused_form
+        mk = tuple(unwrap(mask).shape) if mask is not None else None
+        form = fused_form(tuple(q_shape), tuple(k_shape), mk,
+                          causal=causal, packed=packed)
+    forms = getattr(_tally, "forms", None)
+    if forms is not None:
+        forms["fused" if form else "xla"] += 1
+    return form
+
+
+def _fused_scope():
+    # the kernels' custom calls by name in a trace, forward and backward,
+    # under the bucket the layer's ``attention`` scope already has
+    return jax.named_scope("attention/fused")
+
+
+def _attend_bnsh(q, k, v, attn_mask, is_causal, form):
+    if form:
+        from ...ops.pallas import flash_attention
+        with _fused_scope():
+            return flash_attention(q, k, v, bias=attn_mask, causal=is_causal)
+    if attn_mask is not None:
+        return _sdpa_mask(q, k, v, attn_mask, causal=bool(is_causal))
+    return _sdpa(q, k, v, causal=bool(is_causal))
 
 
 def scaled_dot_product_attention(query, key, value, attn_mask=None,
                                  dropout_p=0.0, is_causal=False,
                                  training=True, name=None):
-    """Inputs (B, S, N, H) per paddle-incubate convention; internally uses
-    (B, N, S, H)."""
-    from ...ops import transpose
-    q = transpose(query, [0, 2, 1, 3])
-    k = transpose(key, [0, 2, 1, 3])
-    v = transpose(value, [0, 2, 1, 3])
-    if _use_pallas(q, k, attn_mask, causal=bool(is_causal)):
-        from ...ops.pallas import flash_attention
-        out = flash_attention(q, k, v, bias=attn_mask, causal=is_causal)
-    elif attn_mask is not None:
-        out = _sdpa_mask(q, k, v, attn_mask, causal=bool(is_causal))
-    else:
-        out = _sdpa(q, k, v, causal=bool(is_causal))
-    if dropout_p and training:
-        from .common import dropout
-        out = dropout(out, dropout_p, training=training)
-    return transpose(out, [0, 2, 1, 3])
+    """Inputs (B, S, N, H) per paddle-incubate convention: the heads lie
+    side by side as :func:`attention_bse` takes them."""
+    from ...ops import reshape
+    b, sq, n, h = query.shape
+    sk = key.shape[1]
+    out = attention_bse(
+        reshape(query, [b, sq, n * h]), reshape(key, [b, sk, n * h]),
+        reshape(value, [b, sk, n * h]), n, attn_mask=attn_mask,
+        is_causal=is_causal, dropout_p=dropout_p, training=training)
+    return reshape(out, [b, sq, n, h])
 
 
 def _use_flash_decode(q, k, window):
@@ -322,8 +373,7 @@ def _use_flash_decode(q, k, window):
     kernels index heads at axis 1 and were not ported to the packed
     ring planes, so today they can serve head_dim >= 128 and the int8
     cache only."""
-    if window is None or not flag("use_flash_decode") \
-            or jax.default_backend() != "tpu":
+    if window is None or not flag("use_flash_decode") or not _on_tpu():
         return False
     from ...ops.pallas.flash_decode import supports_decode
     return supports_decode(unwrap(q).shape, unwrap(k).shape)
@@ -381,14 +431,47 @@ def cached_attention(q, k, v, attn_mask=None, window=None, k_scale=None,
 
 
 def attention_bnsh(q, k, v, attn_mask=None, is_causal=False):
-    """(B, N, S, H) layout fast path used by our MultiHeadAttention layer."""
-    if _use_pallas(q, k, attn_mask, causal=bool(is_causal)):
-        from ...ops.pallas import flash_attention
-        return flash_attention(q, k, v, bias=attn_mask, causal=is_causal)
-    if attn_mask is not None:
-        return _sdpa_mask(q, k, v, attn_mask, causal=bool(is_causal))
-    return _sdpa(q, k, v, causal=bool(is_causal))
+    """(B, N, S, H) layout path: what a caller that holds split heads (a
+    concatenating or static cache) gets."""
+    form = _use_pallas(q.shape, k.shape, attn_mask, causal=bool(is_causal))
+    return _attend_bnsh(q, k, v, attn_mask, is_causal, form)
 
+
+def attention_bse(q, k, v, num_heads, attn_mask=None, is_causal=False,
+                  dropout_p=0.0, training=True):
+    """Un-cached attention of the projections as they are written: ``q [B,
+    Sq, N*H]`` over ``k``, ``v`` ``[B, Sk, N*H]``, heads side by side on
+    the minor dimension; returns ``[B, Sq, N*H]`` (dropout on the output,
+    as ``MultiHeadAttention`` applies it).  Where the single-block kernel
+    is the faster form (``_use_pallas``) it reads the operands as they
+    lie: no head is split off, transposed or padded.  Every other shape
+    splits the heads and takes the ``(B, N, S, H)`` path exactly as
+    before."""
+    from ...ops import reshape, transpose
+    b, sq, e = q.shape
+    sk, hd = k.shape[1], e // num_heads
+    form = _use_pallas((b, num_heads, sq, hd), (b, num_heads, sk, hd),
+                       attn_mask, causal=bool(is_causal), packed=True)
+
+    def drop(out):
+        if dropout_p and training:
+            from .common import dropout
+            return dropout(out, dropout_p, training=training)
+        return out
+
+    if form == "single_block":
+        from ...ops.pallas import packed_attention
+        with _fused_scope():
+            out = packed_attention(q, k, v, num_heads, bias=attn_mask,
+                                   causal=is_causal)
+        return drop(out)
+
+    def split(x, s):
+        return transpose(reshape(x, [b, s, num_heads, hd]), [0, 2, 1, 3])
+
+    out = drop(_attend_bnsh(split(q, sq), split(k, sk), split(v, sk),
+                            attn_mask, is_causal, form))
+    return reshape(transpose(out, [0, 2, 1, 3]), [b, sq, e])
 
 
 # ---------------------------------------------------------------------------

@@ -2,10 +2,11 @@
 
 Reference parity: python/paddle/nn/layer/transformer.py (MultiHeadAttention,
 TransformerEncoderLayer/Encoder, TransformerDecoderLayer/Decoder,
-Transformer). TPU-first: attention runs through
-functional.attention.attention_bnsh -- one fused XLA expression (or the Pallas
-flash kernel on TPU), bf16 matmuls with f32 softmax; the cache API
-(gen_cache/StaticCache) is kept for decoding parity.
+Transformer). TPU-first: un-cached attention runs through
+functional.attention.attention_bse on the projections as they are written
+(a Pallas kernel that keeps the scores on the chip, or one XLA expression:
+the functional picks from the shapes), bf16 matmuls with f32 softmax; the
+cache API (gen_cache/StaticCache) is kept for decoding parity.
 """
 from __future__ import annotations
 
@@ -16,7 +17,7 @@ import jax
 from ...framework.tensor import Tensor, unwrap
 from ...ops import concat, reshape, transpose
 from .. import functional as F
-from ..functional.attention import attention_bnsh
+from ..functional.attention import attention_bnsh, attention_bse
 from .common import Dropout, Linear
 from .layers import Layer
 from .norm import LayerNorm
@@ -322,6 +323,14 @@ class MultiHeadAttention(Layer):
                                       cache_position, decode_window)
         key = query if key is None else key
         value = key if value is None else value
+        if cache is None:
+            # un-cached: the functional takes the projections as they are
+            # written and splits the heads only where its form wants them
+            out = attention_bse(
+                self.q_proj(query), self.k_proj(key), self.v_proj(value),
+                self.num_heads, attn_mask=attn_mask, dropout_p=self.dropout,
+                training=self.training)
+            return self.out_proj(out)
         q = self._split_heads(self.q_proj(query))
         if isinstance(cache, self.StaticCache):
             k, v = cache.k, cache.v
@@ -336,7 +345,7 @@ class MultiHeadAttention(Layer):
         if self.dropout:
             out = F.dropout(out, self.dropout, training=self.training)
         out = self.out_proj(self._merge_heads(out))
-        if cache is not None and not isinstance(cache, self.StaticCache):
+        if not isinstance(cache, self.StaticCache):
             return out, cache
         return out
 

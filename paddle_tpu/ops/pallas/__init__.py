@@ -8,7 +8,8 @@ array-level ``flash_attention_fn`` for compiled train steps.
 from __future__ import annotations
 
 from ...framework.primitive import Primitive
-from .flash_attention import (DEFAULT_BLOCK, flash_attention_fn, supports)
+from .flash_attention import (DEFAULT_BLOCK, flash_attention_fn, fused_form,
+                              packed_attention_fn, supports, supports_packed)
 
 
 def _flash_nobias(q, k, v, *, causal=False, scale=None):
@@ -28,6 +29,30 @@ def flash_attention(q, k, v, bias=None, causal=False, scale=None):
     if bias is None:
         return _flash_prim(q, k, v, causal=bool(causal), scale=scale)
     return _flash_bias_prim(q, k, v, bias, causal=bool(causal), scale=scale)
+
+
+def _packed_nobias(q, k, v, *, num_heads, causal=False, scale=None):
+    return packed_attention_fn(q, k, v, num_heads, None, causal=causal,
+                               scale=scale)
+
+
+def _packed_bias(q, k, v, bias, *, num_heads, causal=False, scale=None):
+    return packed_attention_fn(q, k, v, num_heads, bias, causal=causal,
+                               scale=scale)
+
+
+_packed_prim = Primitive("packed_attention", _packed_nobias)
+_packed_bias_prim = Primitive("packed_attention_bias", _packed_bias)
+
+
+def packed_attention(q, k, v, num_heads, bias=None, causal=False,
+                     scale=None):
+    """The single-block form on ``[B, S, N*H]`` Tensors (heads side by
+    side on the minor dimension); additive ``bias`` optional."""
+    kw = dict(num_heads=int(num_heads), causal=bool(causal), scale=scale)
+    if bias is None:
+        return _packed_prim(q, k, v, **kw)
+    return _packed_bias_prim(q, k, v, bias, **kw)
 
 
 from .flash_decode import (  # noqa: E402
@@ -58,6 +83,8 @@ def flash_decode_quant(q, k, v, k_scale, v_scale, start, end, scale=None):
 from . import fused_bn, fused_conv  # noqa: F401  (kernel families)
 
 __all__ = ["flash_attention", "flash_attention_fn", "supports",
+           "packed_attention", "packed_attention_fn", "supports_packed",
+           "fused_form",
            "flash_decode", "flash_decode_fn", "supports_decode",
            "flash_decode_quant", "flash_decode_quant_fn", "dequantize_kv",
            "decode_attention_reference",

@@ -1041,10 +1041,15 @@ class TrainStep:
                 audit_train_step(self, inputs, label, site="hlo:" + site)
             self._seen_sigs.add(sig)
             t0 = time.perf_counter()
-            with _span("train_step::compile"):
+            from ..nn.functional.attention import count_attention_forms
+            with _span("train_step::compile"), \
+                    count_attention_forms() as forms:
                 self._state, out = fn(self.state, inputs, label, lr, scale)
+            # the step is traced inside that call: the un-cached attention
+            # call sites it holds, by the form each took
             _ledger.record_compile(site, "train_step", sig,
                                    (time.perf_counter() - t0) * 1e3,
+                                   extra={"attention_form": forms},
                                    hlo_text=self._text_of(
                                        fn, inputs, label, lr, scale))
         else:

@@ -347,6 +347,41 @@ def test_train_step_aot_compile_cached(cache_dir):
     assert c2.as_text() and c2.cost_analysis() is not None
 
 
+@pytest.mark.parametrize("on_tpu", [False, True], ids=["xla", "fused"])
+def test_train_step_event_counts_attention_forms(on_tpu, monkeypatch):
+    """The ``train_step`` compile event's ``attention_form`` counts the
+    un-cached attention call sites of the traced step by the form each
+    took: a two-layer encoder has two, all XLA here, all fused where the
+    dispatch sees a TPU (steered; the kernel interpreted)."""
+    from paddle_tpu.nn.functional import attention as A
+    from paddle_tpu.ops.pallas.flash_attention import MIN_SEQ_SINGLE_BLOCK
+    from paddle_tpu.parallel.mesh import MeshGuard, make_mesh
+    from paddle_tpu.parallel.train_step import TrainStep
+    import jax
+    monkeypatch.setattr(A, "_on_tpu", lambda: on_tpu)
+    S = max(MIN_SEQ_SINGLE_BLOCK, 128)
+    enc = nn.TransformerEncoder(
+        nn.TransformerEncoderLayer(128, 2, 256, dropout=0.0), 2)
+    opt = paddle.optimizer.SGD(parameters=enc.parameters(),
+                               learning_rate=0.1)
+    x = np.random.RandomState(1).randn(2, S, 128).astype("float32")
+    mark = len(ledger.compile_events())
+    # one device: a mesh of several keeps every site on the XLA path
+    with MeshGuard(make_mesh({"dp": 1}, jax.devices()[:1])) as mesh:
+        ts = TrainStep(enc, opt,
+                       lambda pred, label: ((pred - label) ** 2).mean(),
+                       mesh=mesh)
+        ts(x, x)
+        evs = [e for e in ledger.compile_events()[mark:]
+               if e["kind"] == "train_step"]
+        assert len(evs) == 1
+        assert evs[0]["attention_form"] == (
+            {"fused": 2, "xla": 0} if on_tpu else {"fused": 0, "xla": 2})
+        ts(x, x)          # a step that compiles nothing counts nothing
+        assert [e for e in ledger.compile_events()[mark:]
+                if e["kind"] == "train_step"] == evs
+
+
 # ---------------------------------------------------------------------------
 # GC + CLI
 # ---------------------------------------------------------------------------

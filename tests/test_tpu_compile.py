@@ -19,7 +19,8 @@ from jax.sharding import SingleDeviceSharding
 import chip_smoke
 from paddle_tpu.ops.pallas import (flash_attention_fn, flash_decode_fn,
                                    flash_decode_quant_fn, fused_bn,
-                                   fused_conv, supports, supports_decode)
+                                   fused_conv, packed_attention_fn, supports,
+                                   supports_decode, supports_packed)
 from paddle_tpu.ops.pallas import _mode
 
 BF16 = jnp.bfloat16
@@ -80,6 +81,14 @@ def _flash(q, k, v):
     return flash_attention_fn(q, k, v, causal=True)
 
 
+BT, NT, ST, HT = chip_smoke.FULL.attn_train
+QKV_T = ((BT, ST, NT * HT), BF16)
+
+
+def _packed(q, k, v):
+    return packed_attention_fn(q, k, v, NT)
+
+
 def _conv(x, w, g, b):
     return fused_conv.fused_conv_bn_act(x, w, g, b, 1, 1, 1e-5, True)
 
@@ -100,6 +109,10 @@ CASES = {
     "flash_attention_fwd": (_flash, (QKV, QKV, QKV)),
     "flash_attention_bwd": (jax.grad(_loss(_flash), argnums=(0, 1, 2)),
                             (QKV, QKV, QKV)),
+    "single_block_attention_fwd": (_packed, (QKV_T, QKV_T, QKV_T)),
+    "single_block_attention_bwd": (jax.grad(_loss(_packed),
+                                            argnums=(0, 1, 2)),
+                                   (QKV_T, QKV_T, QKV_T)),
     # regression for PR 21 §5: both refused by Mosaic before the windows
     # moved to SMEM
     "flash_decode": (flash_decode_fn, (Q1, QKV, QKV, WIN, WIN)),
@@ -132,6 +145,7 @@ def test_gates_admit_the_compiled_shapes():
     """What the kernels phase compiles is what the dispatch gates admit
     (head dim 64 included) — a gate that said no would hide the kernel."""
     assert supports((B, N, S, H), (B, N, S, H), causal=True)
+    assert supports_packed((BT, NT, ST, HT), (BT, NT, ST, HT))
     assert supports_decode((B, N, 1, H), (B, N, S, H))
     assert fused_conv.supports(CONV_X, CONV_W, stride=1, padding=1,
                                itemsize=2)

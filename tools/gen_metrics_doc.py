@@ -70,6 +70,7 @@ index: 48 layers of `attention` add up.
 |---|---|---|
 | `embed` | `GPTModel.forward` / `forward_cached`, `BertModel.forward`, the latent and hybrid decoders | token, position and type lookups, the mask arithmetic beside them |
 | `attention` | `TransformerEncoderLayer.forward`, `LatentDecoderLayer`, `HybridDecoderLayer` (an attention layer) | a layer's attention half: its norm, the q/k/v/out products, the scores, the residual add.  Inside it the newer families keep `latent_attention`, `latent_attention/per_head`, `selector`, `gqa_attention` |
+| `attention/fused` | `nn.functional.attention` (`attention_bse`, `attention_bnsh`), around the call of a Pallas attention kernel | an un-cached call site that took a kernel: its custom calls by name, `attention_single_block_fwd` and, under `transpose(jvp(..))`, `attention_single_block_bwd` (the blocked form's three have no name of their own).  Bucket `attention`, inside a layer's or alone |
 | `cache_write` | `ring_block_write`, the short convolution's state, `Generator`'s `_slice_row` / `_splice_row` (`cache_write/row_slice`, `cache_write/row_splice`) | every write into a cache plane, and a chunk's batch-1 row out of the planes and back.  Innermost wins: a plane write inside `attention` counts here |
 | `mlp` | `TransformerEncoderLayer.forward`, `SwiGLU.forward`, the decoders' dense layers | a layer's dense feed-forward half with its norm and residual add; a shared expert |
 | `experts` | `DroplessMoE.forward`, the decoders' expert layers; the router as `experts/router` | routed experts with their norm and residual add |
@@ -92,6 +93,14 @@ every op under the program run that contains it, gives it its self time
 (a `while` holds its body), and adds the seconds up by the bucket
 (`benchmark/scope_buckets.json`) of the innermost listed component of its
 path; no path is `unscoped`, a name the table lacks `unknown_instruction`.
+
+Which form an un-cached attention call site takes is decided while its
+program is traced, from the site's shapes (`ops/pallas/flash_attention.py`
+`fused_form`), so the count is a fact of the program: a `train_step`
+compile event of the ledger carries `attention_form: {"fused": n, "xla":
+m}`, the step's call sites by form (`count_attention_forms`; the BERT-large
+step of the benchmark reads `{"fused": 24, "xla": 0}` on the chip, `{"fused":
+0, "xla": 24}` on the CPU).
 
 To read a capture of your own: `paddle_tpu.profiler.Profiler` writes
 `program_scopes.json` into `profiler_result_dir` when a record window
