@@ -396,10 +396,13 @@ class LatentAttention(Layer):
                         sb = jnp.where(valid_blk(s0),
                                        selector_scores(qi, wi, kb), -jnp.inf)
                         return lax.dynamic_update_slice(sc, sb, (0, 0, s0))
-                    sc = lax.fori_loop(
-                        lo, hi, score,
-                        jnp.full((B, T, C), -jnp.inf, jnp.float32))
-                    sel = select_columns(sc, jnp.isfinite(sc), self.topk)
+                    # the scores apart from the search among them, by name
+                    with jax.named_scope("score"):
+                        sc = lax.fori_loop(
+                            lo, hi, score,
+                            jnp.full((B, T, C), -jnp.inf, jnp.float32))
+                    with jax.named_scope("select"):
+                        sel = select_columns(sc, jnp.isfinite(sc), self.topk)
                     keep_of = lambda s0: lax.dynamic_slice(     # noqa: E731
                         sel, (0, 0, s0), (B, T, blk))
         out = latent_attend_blocked(products, lat[:, 0], keep_of, lo, hi,

@@ -5,9 +5,10 @@ once.  The PR-7 batch-invariance gate makes that sound: a cache column's
 K/V content depends only on the token prefix and the RELATIVE position
 ``column − start``, so a prefilled prefix segment is bit-portable across
 slot rows, pack compositions and window shifts.  The same holds of any
-plane as long as the session whose column is a token's (a latent plane
-that no selector reads beside a key plane: ``require_kv_planes`` decides
-from the model's ``cache_spec``).  This module indexes those segments:
+plane as long as the session whose column is a token's (a latent plane,
+alone or with the selector-key plane a learned column selector reads
+beside it: ``require_kv_planes`` decides from the model's
+``cache_spec``).  This module indexes those segments:
 
   * the trie is keyed by **blocks** of ``T`` tokens (``T`` = the prefill
     chunk width the slot loop runs) — a node's path from the root spells
@@ -220,6 +221,6 @@ def require_kv_planes(spec, columns) -> None:
     decision is ``text/generation.py::require_prefix_planes``'s (planes
     as long as the session whose columns depend on the token prefix and
     the relative position only: uniform K/V planes, a latent plane on
-    its own)."""
+    its own or with its selector-key plane)."""
     from ..text.generation import require_prefix_planes
     require_prefix_planes(spec, columns, "the prefix KV cache (it cuts chunk-wide column blocks out of every plane of a row)")

@@ -100,10 +100,11 @@ What the loop measures about itself, always on:
     ``publish`` are the prefix cache's block pushes and pulls;
   * **what the prefix cache did** — ``counters["prompt_tokens_admitted",
     "prefix_lookups", "prefix_hits", "prefix_hit_tokens",
-    "restore_pushes", "prefix_blocks_published",
+    "restore_pushes", "prefix_restored_bytes", "prefix_blocks_published",
     "prefix_blocks_evicted"]``, committed with ``steps``: of the prompt
     tokens admitted, those that came out of cached blocks and were not
-    prefilled;
+    prefilled; the blocks pushed into rows and the bytes they hold (every
+    plane of a block: a layer's selector keys beside its latent rows);
   * **how a row was activated** — ``counters["rows_activated"]``, and
     ``["logits_bytes_via_host"]``: the bytes of logits that crossed the
     host boundary for it, either way (0 for a row whose final chunk
@@ -343,7 +344,8 @@ class SlotLoop:
                          "emitted_tokens": 0, "parked": 0, "restored": 0,
                          "prompt_tokens_admitted": 0, "prefix_lookups": 0,
                          "prefix_hits": 0, "prefix_hit_tokens": 0,
-                         "restore_pushes": 0, "prefix_blocks_published": 0,
+                         "restore_pushes": 0, "prefix_restored_bytes": 0,
+                         "prefix_blocks_published": 0,
                          "prefix_blocks_evicted": 0,
                          "rows_activated": 0, "logits_bytes_via_host": 0,
                          **{f"slot_steps_{k}": 0 for k in _SLOT_STATES},
@@ -958,11 +960,14 @@ class SlotLoop:
         by the dead-column discipline again.  The prefix-cache pin
         releases when the last block is in flight — from then on the
         restored columns live in the row, not the trie."""
+        import jax.tree_util as tu
         while slot.restore and slot.restore[0][1] + self.T <= self.pos:
             block, base = slot.restore.pop(0)
             self._cache = self._push_block(
                 self._cache, block, np.int32(i), np.int32(base))
             self._add("restore_pushes", 1)
+            self._add("prefix_restored_bytes", sum(
+                int(p.nbytes) for p in tu.tree_leaves(block)))
         if not slot.restore and slot.pin is not None:
             self._prefix.release(slot.pin)
             slot.pin = None

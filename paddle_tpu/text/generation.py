@@ -195,16 +195,17 @@ def require_prefix_planes(spec, columns, who):
     ``start``.  Decided from what the model says of its planes (``spec``
     = ``Generator.cache_spec(columns)``), not from their names: every
     layer must keep column planes as long as the session (``columns``),
-    a column a token, written once (``wraps`` false), every valid one read
-    (no ``select_top``), and no state beside them.  Such a column's
-    content is a function of the token prefix and of ``column - start``
-    only: uniform K/V ring planes, and a latent plane on its own.  A
-    window plane shorter than the session, a selector-key plane beside a
-    latent plane and a state without columns go on being refused, with
-    :func:`require_kv_planes`'s message."""
+    a column a token, written once (``wraps`` false), and no state beside
+    them.  Such a column's content is a function of the token prefix and
+    of ``column - start`` only: uniform K/V ring planes, a latent plane
+    on its own, and a latent plane WITH its selector-key plane (a block
+    then carries both; that the layer selects among the restored columns
+    is the reader's business, not the block's: it scores the keys it
+    finds there as it scores those a chunk wrote).  A window plane
+    shorter than the session and a state without columns go on being
+    refused, with :func:`require_kv_planes`'s message."""
     bad = sorted({str(s["kind"]) for s in spec
-                  if int(s["columns"]) != int(columns) or s.get("wraps")
-                  or s.get("select_top")})
+                  if int(s["columns"]) != int(columns) or s.get("wraps")})
     if bad:
         _refuse_planes(bad, who)
 

@@ -5,8 +5,12 @@ chunks and steps against the plain reference's full forward
 (benchmark/reference/kimi_k2.py, which imports nothing of the program), the
 expert shares, YaRN, the prefix cache over latent planes (a row served from
 a hit is the row prefilled whole, to the bit), the document traffic, and
-the count functions at the published size.
+the count functions at the published size.  The cases that GLM-5's
+configuration shares (the expert shares, a hit against the plain prefill)
+run for both families; tests/test_glm5_decoder.py holds what its selector
+adds and takes its helpers from here.
 """
+import importlib
 import json
 import os
 import sys
@@ -23,7 +27,6 @@ if ROOT not in sys.path:
 from benchmark import harness                                 # noqa: E402
 from benchmark.counts import kimi_k2 as counts               # noqa: E402
 from benchmark.generators import closed_loop_docs            # noqa: E402
-from benchmark.models import kimi_k2 as bench_models         # noqa: E402
 from benchmark.reference import kimi_k2 as ref               # noqa: E402
 from benchmark.reference.common import Arith                 # noqa: E402
 from paddle_tpu.framework.enforce import InvalidArgumentError  # noqa: E402
@@ -53,19 +56,33 @@ def _load(rel):
         return json.load(f)
 
 
-def _tiny(**over):
-    cfg = _load("configs/kimi-k2.5-ep32-serve.json")
-    tiny = _load("tests/data/kimi_tiny.json")["over"]
+# the configurations of the latent family that the prefix cache serves:
+# (the benchmark's file, its tiny size)
+FAMILIES = {"kimi": ("kimi-k2.5-ep32-serve", "kimi_tiny"),
+            "glm5": ("glm-5-ep16-serve", "glm5_tiny")}
+
+
+def _tiny(family="kimi", **over):
+    config, size = FAMILIES[family]
+    cfg = _load(f"configs/{config}.json")
+    tiny = _load(f"tests/data/{size}.json")["over"]
     cfg["serve"].update(tiny.pop("serve"))
     cfg.update(tiny)
     cfg.update(over)
     return cfg
 
 
+def _modules(cfg):
+    """(benchmark.models.<family>, benchmark.reference.<family>)."""
+    return tuple(importlib.import_module(f"benchmark.{part}.{cfg['family']}")
+                 for part in ("models", "reference"))
+
+
 def _build(cfg, seed=5):
-    mapped = bench_models.to_program(ref.init_weights(cfg, seed))
-    model = bench_models.build(cfg, mapped)
-    return model, harness.canonical_view(mapped, bench_models.leaf_ids(cfg))
+    models, reference = _modules(cfg)
+    mapped = models.to_program(reference.init_weights(cfg, seed))
+    model = models.build(cfg, mapped)
+    return model, harness.canonical_view(mapped, models.leaf_ids(cfg))
 
 
 @pytest.fixture(scope="module")
@@ -91,7 +108,8 @@ def _serve(model, requests, prompts=None, chunk=4, columns=64, **loop_kw):
 
 
 def _widest_gap(cfg, view, prompts, tokens):
-    return max(float(np.max(ref.served_gaps(cfg, view, p, t)))
+    gaps = _modules(cfg)[1].served_gaps
+    return max(float(np.max(gaps(cfg, view, p, t)))
                for p, t in zip(prompts, tokens))
 
 
@@ -231,31 +249,36 @@ def test_wide_chunks_equal_the_reference(served_wide, dtype, tol,
 
 # -- (b) the shares add up ------------------------------------------------------
 
+def share_parts(cfg, reference, whole, u, lo, hi):
+    """(routed, shared, margin) that the share holding experts [lo, hi) of
+    the uncut layer ``whole`` gives for the normed tokens ``u``."""
+    lw = {k: (v[lo:hi] if k.startswith("exp_") else v)
+          for k, v in whole.items()}
+    return jax.jit(lambda u, lw, lo: reference.moe_parts(
+        Arith("float32"), u, lw, cfg, (lo, None)))(u, lw, jnp.int32(lo))
+
+
 @pytest.mark.parametrize("shares", [4, 2, 1])
-def test_shares_add_up_to_the_uncut_layer(shares):
+@pytest.mark.parametrize("family", list(FAMILIES))
+def test_shares_add_up_to_the_uncut_layer(family, shares):
     """The routed parts that all the shares of one layer give, with what
     every chip computes alike (the shared expert) counted once, equal the
-    uncut 8-expert reference layer's FFN, ``routed_scaling_factor`` 2.827
-    and all (float32: to rounding of another summation order).  The
+    uncut 8-expert reference layer's FFN, ``routed_scaling_factor`` and
+    all (float32: to rounding of another summation order).  The
     PROGRAM's share, ``DroplessMoE`` holding experts [0, n) of the 8, is
     the first of those parts."""
     from paddle_tpu.framework.functional import _bound_state
     from paddle_tpu.nn.layer.moe import DroplessMoE
-    cfg = _tiny()
+    cfg = _tiny(family)
+    reference = _modules(cfg)[1]
     E = cfg["n_routed_experts_published"]
-    whole = ref._layer_weights(
-        ref.init_weights(dict(cfg, experts_held=[0, E]), 7), 1)
+    whole = reference._layer_weights(
+        reference.init_weights(dict(cfg, experts_held=[0, E]), 7), 1)
     u = jax.random.normal(jax.random.key(0), (23, cfg["hidden_size"]))
-    share = jax.jit(lambda u, lw, lo: ref.moe_parts(
-        Arith("float32"), u, lw, cfg, (lo, None)))
-
-    def parts(lo, hi):
-        return share(u, {k: (v[lo:hi] if k.startswith("exp_") else v)
-                         for k, v in whole.items()}, jnp.int32(lo))
-
-    routed, shared, _ = parts(0, E)
+    routed, shared, _ = share_parts(cfg, reference, whole, u, 0, E)
     n = E // shares
-    each = [parts(i * n, (i + 1) * n) for i in range(shares)]
+    each = [share_parts(cfg, reference, whole, u, i * n, (i + 1) * n)
+            for i in range(shares)]
     top = float(jnp.abs(routed + shared).max())
     np.testing.assert_allclose(sum(p[0] for p in each) + each[0][1],
                                routed + shared, atol=1e-5 * top)
@@ -337,7 +360,8 @@ def _block_nbytes(gen, S, T, C):
 
 
 @pytest.mark.parametrize("attn_block", [1, 8])
-def test_a_hit_equals_the_plain_prefill(attn_block):
+@pytest.mark.parametrize("family", list(FAMILIES))
+def test_a_hit_equals_the_plain_prefill(family, attn_block):
     """Three asks of one document through a loop WITH the prefix cache (the
     later ones restore the first's blocks into OTHER rows at OTHER starts
     and prefill their suffix chunks) against the same through a loop
@@ -352,8 +376,13 @@ def test_a_hit_equals_the_plain_prefill(attn_block):
     ABSOLUTE columns (``latent_attend_blocked``), so the order of its sums
     follows ``start mod attn_block``, and a hit activates at another
     ``start`` than the whole prefill would.  Two plain prefills of one
-    prompt at two starts differ as much."""
-    cfg = _tiny()
+    prompt at two starts differ as much.
+
+    With a selector on every layer (``glm5``: a block carries each layer's
+    selector keys beside its latent rows) the same holds: the restored keys
+    are scored as those a chunk wrote, and the row selects the columns the
+    whole prefill selects."""
+    cfg = _tiny(family)
     cfg["serve"]["attn_block"] = attn_block
     model, _ = _build(cfg)
     rng = np.random.default_rng(4)
@@ -395,6 +424,12 @@ def test_a_hit_equals_the_plain_prefill(attn_block):
     assert st["prefix_lookups"] == 4 and st["prefix_hits"] == 2
     assert st["prefix_hit_tokens"] == 2 * 20
     assert st["restore_pushes"] == 2 * 5
+    # by what a block holds: one latent plane a layer, or two planes
+    planes = 2 if family == "glm5" else 1
+    assert st["plane_kinds"] == [
+        "latent+selector_key" if family == "glm5" else "latent"]
+    assert st["prefix_restored_bytes"] == 10 * _block_nbytes(gen, 3, 4, 64) \
+        == 10 * 3 * 4 * 4 * (128 + (planes - 1) * cfg.get("index_head_dim", 0))
     assert st["prompt_tokens_admitted"] == sum(a.size for a in asks) + 9
     # asks of 25, 27 and 24 tokens: 6 whole blocks each, 5 of them the
     # document's (published once); the filler's 2
@@ -454,9 +489,11 @@ def test_eviction_under_a_small_budget_frees_only_unpinned_blocks(served):
 
 @pytest.mark.parametrize("family", ["dots3", "lfm2"])
 def test_the_prefix_cache_still_refuses_planes_it_cannot_cut(family):
-    """A window plane shorter than the session, a selector-key plane
-    beside a latent plane, a state without columns: refused, with the
-    message the refusal had, decided from ``cache_spec``."""
+    """A window plane shorter than the session and a state without
+    columns: refused, with the message the refusal had, decided from
+    ``cache_spec``.  A selector-key plane beside a latent plane is NOT
+    among the refused any more (tests/test_glm5_decoder.py): of dots3's
+    two kinds only the window plane is named."""
     from paddle_tpu.serving import prefix_cache
     from paddle_tpu.text.generation import require_prefix_planes
     latent = {"kind": "latent", "columns": 64, "wraps": False,
@@ -465,7 +502,7 @@ def test_the_prefix_cache_still_refuses_planes_it_cannot_cut(family):
     if family == "dots3":
         bad = [dict(latent, kind="latent+selector_key", select_top=6),
                dict(latent, kind="latent_window", columns=8, wraps=True)]
-        names = "'latent+selector_key', 'latent_window'"
+        names = "'latent_window'"
     else:
         bad = [dict(latent, kind="conv_state", columns=0),
                dict(latent, kind="kv")]

@@ -2,7 +2,7 @@
 tables the program hands out (``profiler.ledger.program_scopes``), that a
 scope is metadata and nothing else, and the reduction that lays a capture's
 ops under programs and buckets (``benchmark/layer_metrics/
-_program_scopes.py``) on the recorded TPU capture.  The four served
+_program_scopes.py``) on the recorded TPU capture.  The five served
 families at their tiny sizes and a tiny ``TrainStep``, on the CPU.
 """
 import contextlib
@@ -30,10 +30,11 @@ from paddle_tpu.text.generation import Generator              # noqa: E402
 
 CAPTURE = os.path.join(ROOT, "benchmark", "tests", "data",
                        "train_two_steps.xplane.pb.gz")
-FAMILIES = ("gpt", "dots3", "lfm2", "kimi_k2")
+FAMILIES = ("gpt", "dots3", "lfm2", "kimi_k2", "glm_moe_dsa")
 TINY = {"dots3": ("dots3-note-prev-ep8-serve", "dots3_tiny"),
         "lfm2": ("lfm2-8b-a1b-pp2-serve", "lfm2_tiny"),
-        "kimi_k2": ("kimi-k2.5-ep32-serve", "kimi_tiny")}
+        "kimi_k2": ("kimi-k2.5-ep32-serve", "kimi_tiny"),
+        "glm_moe_dsa": ("glm-5-ep16-serve", "glm5_tiny")}
 SLOTS, CHUNK, COLUMNS = 3, 4, 64
 # an instruction line of a compiled program's text: its name and its opcode
 _LINE = re.compile(r"^\s*(?:ROOT\s+)?%?([\w.\-]+)\s*=\s+\S+\s+([\w\-]+)\(",

@@ -69,7 +69,7 @@ index: 48 layers of `attention` add up.
 | scope | where it is entered | covers |
 |---|---|---|
 | `embed` | `GPTModel.forward` / `forward_cached`, `BertModel.forward`, the latent and hybrid decoders | token, position and type lookups, the mask arithmetic beside them |
-| `attention` | `TransformerEncoderLayer.forward`, `LatentDecoderLayer`, `HybridDecoderLayer` (an attention layer) | a layer's attention half: its norm, the q/k/v/out products, the scores, the residual add.  Inside it the newer families keep `latent_attention`, `latent_attention/per_head`, `selector`, `gqa_attention` |
+| `attention` | `TransformerEncoderLayer.forward`, `LatentDecoderLayer`, `HybridDecoderLayer` (an attention layer) | a layer's attention half: its norm, the q/k/v/out products, the scores, the residual add.  Inside it the newer families keep `latent_attention`, `latent_attention/per_head`, `selector` (the learned column selector's projections and key write; inside it `selector/score`, the scores of every valid column, apart from `selector/select`, the radix search among them), `gqa_attention` |
 | `attention/fused` | `nn.functional.attention` (`attention_bse`, `attention_bnsh`), around the call of a Pallas attention kernel | an un-cached call site that took a kernel: its custom calls by name, `attention_single_block_fwd` and, under `transpose(jvp(..))`, `attention_single_block_bwd` (the blocked form's three have no name of their own).  Bucket `attention`, inside a layer's or alone |
 | `cache_write` | `ring_block_write`, the short convolution's state, `Generator`'s `_slice_row` / `_splice_row` (`cache_write/row_slice`, `cache_write/row_splice`) | every write into a cache plane, and a chunk's batch-1 row out of the planes and back.  Innermost wins: a plane write inside `attention` counts here |
 | `mlp` | `TransformerEncoderLayer.forward`, `SwiGLU.forward`, the decoders' dense layers | a layer's dense feed-forward half with its norm and residual add; a shared expert |

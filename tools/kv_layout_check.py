@@ -45,9 +45,13 @@ program) and prints, from ``compiled.as_text()``:
   * for a configuration served with the prefix cache on (``serve.
     prefix_cache``): that the cache admits its planes (decided from
     ``cache_spec``; the first line prints ``plane_kinds``, a ``latent``
-    plane on its own among them), and the same facts for its two data
-    movers, ``kv_push_block`` (a cached block written into a row: planes
-    aliased, index dimensions, copies) and ``kv_pull_block``;
+    plane on its own or with its selector-key plane among them), the
+    line ``prefix_cache`` (the planes and bytes of ONE block, and how many
+    blocks ``serve.prefix_cache_hbm_mb`` holds: what lies on the device
+    beside the programs' arguments when ``slots`` is sized), and the same
+    facts for its two data movers, ``kv_push_block`` (a cached block
+    written into a row: planes aliased, index dimensions, copies) and
+    ``kv_pull_block``;
   * the program that activates a row (``Generator.put_logits_row_exec``:
     one row written into the step's ``[S, V]`` logits): ``logits_aliased``,
     whether its output is its donated input, written in place, with no
@@ -450,6 +454,17 @@ def main(argv):
         # the pull reads one out and copies no plane either
         from paddle_tpu.serving.prefix_cache import require_kv_planes
         require_kv_planes(gen.cache_spec(C), C)
+        # what the budget holds beside the programs' arguments: a block is
+        # one row x T columns of EVERY plane (a layer's selector keys
+        # beside its latent rows)
+        block = jax.tree_util.tree_leaves(gen._block_avals(S, T, C))
+        block_bytes = sum(math.prod(a.shape) * a.dtype.itemsize
+                          for a in block)
+        budget = int(float(sv.get("prefix_cache_hbm_mb", 0)) * 2 ** 20)
+        print(json.dumps({"config": cfg["name"], "prefix_cache": {
+            "planes_per_block": len(block), "block_bytes": block_bytes,
+            "budget_gib": round(budget / 2 ** 30, 3),
+            "budget_blocks": budget // block_bytes}}), flush=True)
         for what, compiled in (
                 ("kv_push_block", gen.push_block_exec(S, T, C)),
                 ("kv_pull_block", gen.pull_block_exec(S, T, C))):
