@@ -562,20 +562,29 @@ def _descending_key(x):
 
 def _kth_largest(keys, k, bits=32):
     """Per row of ``keys [..., S]`` (uint32) the ``k``-th largest
-    (``k [..., 1]`` or an int), found four bits at a time from the top:
-    a pass counts, for each of the 15 next-digit candidates, the keys at
-    or above it, so the whole search reads the keys ``bits / 4`` times
-    where a sort moves them ``log^2 S`` times (on the chip a ``top_k`` of
-    [512, 12288] is a 5.5 ms sort)."""
+    (``k [..., 1]`` or an int), found two bits at a time from the top: a
+    pass counts, for each of the 3 next-digit candidates, the keys at or
+    above it, so the whole search reads the keys ``bits / 2`` times where
+    a sort moves them ``log^2 S`` times (on the chip a ``top_k`` of [512,
+    12288] is a 5.5 ms sort).  Two bits and not four: a pass costs by its
+    candidates, and 16 passes of 3 take 0.4-0.7 of the time of 8 passes of
+    15 at every width from 4,096 to 24,576 (PERF.md section 6, PR 39).
+    The passes are ONE loop in the program's text, not ``bits / 2``
+    copies of its body: the search is traced once for every width a
+    selecting layer may take."""
     u = jnp.uint32
-    prefix = jnp.zeros(keys.shape[:-1] + (1,), u)
-    digits = jnp.arange(1, 16, dtype=u)
-    for shift in range(bits - 4, -1, -4):
-        cands = prefix[..., None] | (digits << u(shift))       # [..., 1, 15]
-        counts = (keys[..., None] >= cands).sum(-2)             # [..., 15]
+    digits = jnp.arange(1, 4, dtype=u)
+    passes = -(-bits // 2)
+
+    def one(i, prefix):
+        shift = u(2) * (u(passes - 1) - i.astype(u))
+        cands = prefix[..., None] | (digits << shift)          # [..., 1, 3]
+        counts = (keys[..., None] >= cands).sum(-2)             # [..., 3]
         digit = (counts >= k).sum(-1, keepdims=True)
-        prefix = prefix | (digit.astype(u) << u(shift))
-    return prefix
+        return prefix | (digit.astype(u) << shift)
+
+    return jax.lax.fori_loop(0, passes, one,
+                             jnp.zeros(keys.shape[:-1] + (1,), u))
 
 
 def select_columns(scores, valid, k):
@@ -597,8 +606,70 @@ def select_columns(scores, valid, k):
     # among the tied, the ``room`` of lowest index = of largest S - index
     back = jnp.where(tied, jnp.uint32(S) - jnp.arange(S, dtype=jnp.uint32),
                      jnp.uint32(0))
-    last = _kth_largest(back, room, bits=-(-S.bit_length() // 4) * 4)
+    last = _kth_largest(back, room, bits=S.bit_length())
     return valid & (above | (tied & (back >= last)))
+
+
+def search_widths(C, k):
+    """The widths :func:`select_columns_span` may search in a plane of
+    ``C`` columns of which ``k`` are chosen: ``2 k``, ``4 k``, ... below
+    ``C``, then ``C`` itself (a search costs by its width, so each step
+    at most doubles it); none where the plane holds no more than ``k``
+    columns (nothing is ever searched there).  24,576 columns, 2,048
+    chosen: 4,096, 8,192, 16,384, 24,576."""
+    if C <= k:
+        return ()
+    widths, w = [], 2 * k
+    while w < C:
+        widths.append(w)
+        w *= 2
+    return tuple(widths) + (C,)
+
+
+def span_branch(widths, k, first, last):
+    """Which of ``widths`` (:func:`search_widths`) the search of a dispatch
+    takes whose rows' lowest first valid column is ``first`` and whose last
+    query sits at column ``last``: ``i`` for ``widths[i - 1]``, the
+    narrowest that holds the widest context ``last - first + 1``; 0 where
+    that context is no more than ``k`` columns, so that every valid column
+    is chosen and nothing is searched (or scored).  Plain arithmetic: the
+    program calls it on traced values and the slot loop on its own
+    integers (:func:`searched_columns`), so the two cannot disagree."""
+    widest = last - first + 1
+    return sum(widest > w for w in ((k,) + tuple(widths))[:-1])
+
+
+def searched_columns(widths, k, first, last):
+    """Columns the search of that dispatch goes over (host integers)."""
+    return ((0,) + tuple(widths))[int(span_branch(widths, k, first, last))]
+
+
+def select_columns_span(scores, valid, k, widths, branch, first):
+    """:func:`select_columns` of ``scores [B, T, C]`` at the cost of the
+    dispatch's live span and not of the plane: the scores are ``-inf``
+    outside the columns ``first .. last`` (traced), so the search runs
+    over a slice of ``widths[branch - 1]`` columns that holds them all
+    (``branch`` is :func:`span_branch`'s; the slice starts at ``first``,
+    or as far below it as the plane's end demands) and the membership it
+    finds is laid back into ``[B, T, C]``; columns outside the slice take
+    no part in a search of the whole plane either, and the tie rule is an
+    order on column indices, which an offset keeps: the same membership to
+    the bit.  ``branch`` 0 takes ``valid()`` (``[B, T, C]``) as it stands.
+    One conditional; the widths are static, so nothing compiles at run
+    time."""
+    B, T, C = scores.shape
+
+    def search(W):
+        def run(_):
+            off = jnp.minimum(first, C - W)
+            sc = jax.lax.dynamic_slice(scores, (0, 0, off), (B, T, W))
+            return jax.lax.dynamic_update_slice(
+                jnp.zeros((B, T, C), bool),
+                select_columns(sc, jnp.isfinite(sc), k), (0, 0, off))
+        return run
+
+    return jax.lax.switch(branch, [lambda _: valid()]
+                          + [search(W) for W in widths], None)
 
 
 # The two cached forms differ in a PAIR of products and in nothing else:

@@ -23,7 +23,9 @@ both.  Two kinds of layer share the code:
   * **full** layers keep a plane as long as the session and, beside it, a
     plane of selector keys; a learned selector (``index_n_heads`` small
     heads over the query's low-rank latent) scores every cached column and
-    the attention reads the ``index_topk`` best of the causal ones.  A
+    the attention reads the ``index_topk`` best of the causal ones (the
+    search among the scores goes over the dispatch's live span, not the
+    plane, and over nothing while no context passes ``index_topk``).  A
     full layer WITHOUT a selector (``index_topk`` 0) keeps the latent
     plane alone and reads every valid column of it;
   * **window** layers keep ONE ring plane of ``window + cache_block - 1``
@@ -56,8 +58,9 @@ from .. import initializer as I
 from ..functional.attention import (absorbed_products, latent_attend,
                                     latent_attend_blocked, per_head_products,
                                     rotary, rotary_frequencies,
-                                    select_columns, selector_scores,
-                                    yarn_attention_factor)
+                                    search_widths, select_columns,
+                                    select_columns_span, selector_scores,
+                                    span_branch, yarn_attention_factor)
 from .layers import Layer
 from .transformer import ring_block_write
 
@@ -186,7 +189,15 @@ class LatentAttention(Layer):
         return {"kind": cls.kind, "heads_per_lane_row": 1,
                 "columns": self.ring_len(max_len), "wraps": cls.wraps,
                 "window": self.window,
-                "select_top": self.topk if self.selects else None}
+                "select_top": self.topk if self.selects else None,
+                "select_widths": self.search_widths(max_len)}
+
+    def search_widths(self, max_len):
+        """The widths the selector's search may take in a session of
+        ``max_len`` columns (``functional.attention.search_widths``),
+        narrowest first; None for a layer that selects nothing."""
+        return search_widths(int(max_len), self.topk) if self.selects \
+            else None
 
     def _cache_class(self):
         if self.window is not None:
@@ -375,7 +386,8 @@ class LatentAttention(Layer):
         # GB/s, 3.2 ms a layer at 64 rows, where reading every valid
         # column takes 1.2: PERF.md section 6, PR 27.)
         blk = self.attn_block if C % self.attn_block == 0 else C
-        lo = jnp.min(start) // blk
+        first = jnp.min(start)
+        lo = first // blk
         hi = cols[-1] // blk + 1
         keep_of = lambda s0: valid_of(                           # noqa: E731
             s0 + jnp.arange(blk, dtype=jnp.int32))
@@ -387,6 +399,12 @@ class LatentAttention(Layer):
                     keys, ki[:, None].astype(keys.dtype), pos))
                 if C > self.topk:       # else every valid column is chosen
                     valid_blk = keep_of
+                    # the search covers the dispatch's live span, not the
+                    # plane; where no context passes index_topk (branch
+                    # 0) every valid column is chosen: no search, and no
+                    # scores either (the loop goes over no block)
+                    widths = self.search_widths(C)
+                    branch = span_branch(widths, self.topk, first, cols[-1])
 
                     def score(i, sc):
                         s0 = i * blk
@@ -399,10 +417,13 @@ class LatentAttention(Layer):
                     # the scores apart from the search among them, by name
                     with jax.named_scope("score"):
                         sc = lax.fori_loop(
-                            lo, hi, score,
+                            jnp.where(branch > 0, lo, hi), hi, score,
                             jnp.full((B, T, C), -jnp.inf, jnp.float32))
                     with jax.named_scope("select"):
-                        sel = select_columns(sc, jnp.isfinite(sc), self.topk)
+                        sel = select_columns_span(
+                            sc, lambda: valid_of(
+                                jnp.arange(C, dtype=jnp.int32)),
+                            self.topk, widths, branch, first)
                     keep_of = lambda s0: lax.dynamic_slice(     # noqa: E731
                         sel, (0, 0, s0), (B, T, blk))
         out = latent_attend_blocked(products, lat[:, 0], keep_of, lo, hi,

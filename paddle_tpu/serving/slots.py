@@ -143,7 +143,7 @@ import numpy as np
 
 from ..framework.enforce import (InvalidArgumentError, OutOfRangeError,
                                  UnavailableError)
-from ..nn.functional.attention import decode_block
+from ..nn.functional.attention import decode_block, searched_columns
 from ..profiler import span as _span
 from ..profiler import tracing as _tracing
 from ..profiler.metrics import LatencyWindow
@@ -283,6 +283,13 @@ class SlotLoop:
         self._context_tops = [int(s.get("select_top") or 0) for s in spec
                               if int(s["columns"]) and not s.get("wraps")
                               and not str(s["kind"]).startswith("kv")]
+        # per layer that selects: the widths its search may take, as the
+        # layer hands them out (none: a plane of no more than select_top
+        # columns is never searched), and the plane's columns
+        self._select_rules = [
+            (int(s["select_top"]), tuple(s["select_widths"]),
+             int(s["columns"])) for s in spec
+            if s.get("select_widths") is not None]
         self._wrap_lens = [int(s["columns"]) for s in spec
                            if s.get("wraps") and int(s["columns"]) < self.C]
         # layers whose cache has no columns: a per-row state that every
@@ -362,6 +369,11 @@ class SlotLoop:
             self.counters.update({
                 "chunk_" + k: 0 for k in self._count_names
                 if not k.endswith("_max")})
+        if self._select_rules:
+            self.counters.update(selector_columns_searched=0,
+                                 selector_columns_plane=0,
+                                 chunk_selector_columns_searched=0,
+                                 chunk_selector_columns_plane=0)
         if self._wrap_lens:
             self.counters["window_wraps"] = 0
         if self._state_layers:
@@ -898,15 +910,25 @@ class SlotLoop:
         steps under ``attn_columns_*``, over the tokens of the chunks
         under ``chunk_attn_columns_*``; a plain K/V layer reads all of
         them (``kv_columns_valid``, once a dispatch, not a layer).  A
+        selecting layer's search goes over the narrowest of its widths
+        that holds the dispatch's widest context, none where that is no
+        more than ``select_top`` (``searched_columns``, the program's own
+        rule), of the columns its plane has: ``selector_columns_*``.  A
         write at a column that is a multiple of a wrapping plane's length
         has gone once round it."""
         cols = np.asarray(cols)
+        add = self._add
+        pre = "chunk_" if chunk else ""
+        # (a dispatch with no live row searches nothing)
+        span = (int(np.min(start)), int(cols.max())) if cols.size else None
+        for top, widths, columns in self._select_rules:
+            add(pre + "selector_columns_searched",
+                searched_columns(widths, top, *span) if span else 0)
+            add(pre + "selector_columns_plane", columns)
         ctx = cols - np.asarray(start) + 1
         cols, ctx = cols[ctx > 0], ctx[ctx > 0]
-        add = self._add
         if chunk and "chunk_tokens" in self.counters:
             add("chunk_tokens", int(ctx.size))
-        pre = "chunk_" if chunk else ""
         if self._attn_block:
             add(pre + "kv_columns_valid", int(ctx.sum()))
         for top in self._context_tops:

@@ -414,9 +414,11 @@ class Generator:
         length ``C``: ``kind`` (the cache class's), ``heads_per_lane_row``
         (``g`` of ``gen_ring_cache``), ``columns`` of its planes (0: a
         per-row state that a feed overwrites in place),
-        whether they ``wrap`` inside a session, its ``window`` and
-        ``select_top``.  The model's ``cache_spec``; a model that has
-        none is described from the classes its ``init_cache`` builds."""
+        whether they ``wrap`` inside a session, its ``window``,
+        ``select_top`` and, for a layer that selects, the widths its
+        search may take (``select_widths``).  The model's ``cache_spec``;
+        a model that has none is described from the classes its
+        ``init_cache`` builds."""
         fn = getattr(self._layer, "cache_spec", None)
         if fn is not None:
             return list(fn(int(C)))
@@ -447,6 +449,19 @@ class Generator:
         events and, per program, in ``SlotLoop.stats()``."""
         fn = getattr(self._layer, "latent_form", None)
         return None if fn is None else fn(int(T))
+
+    def selector_widths(self, C):
+        """The widths the search of the model's column selector may take
+        at cache length ``C`` (its selecting layers' ``select_widths``;
+        the search goes over the narrowest that holds the dispatch's live
+        span; a list per distinct rule where the layers differ), None for
+        a model in which no layer selects: a fact of the programs, in the
+        ledger's ``generate_step`` / ``generate_chunk`` events."""
+        rules = sorted({tuple(s["select_widths"]) for s in self.cache_spec(C)
+                        if s.get("select_widths") is not None})
+        if len(rules) == 1:
+            return list(rules[0])
+        return [list(r) for r in rules] or None
 
     def decode_count_names(self):
         """Names of the int32 counts the model's cached forward leaves
@@ -615,7 +630,8 @@ class Generator:
                 self._build_step(S, C, end), self.step_avals(S, C),
                 {"slots": S, "cache": C, "eos": end,
                  "kv_heads_per_lane_row": self.kv_heads_per_lane_row(),
-                 **_known(latent_form=self.latent_form(1))},
+                 **_known(latent_form=self.latent_form(1),
+                          selector_widths=self.selector_widths(C))},
                 (2,))
 
     def _chunk_program(self, S, T, C):
@@ -626,7 +642,8 @@ class Generator:
                 self._build_chunk(S, T, C), self.chunk_avals(S, T, C),
                 {"slots": S, "chunk": T, "cache": C,
                  "kv_heads_per_lane_row": self.kv_heads_per_lane_row(),
-                 **_known(latent_form=self.latent_form(T))},
+                 **_known(latent_form=self.latent_form(T),
+                          selector_widths=self.selector_widths(C))},
                 (2,))
 
     def step_exec(self, S, C, eos_token_id=None):

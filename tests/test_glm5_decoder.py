@@ -30,6 +30,7 @@ from paddle_tpu.framework.enforce import InvalidArgumentError   # noqa: E402
 from paddle_tpu.framework.tensor import Tensor, unwrap          # noqa: E402
 from paddle_tpu.nn.layer import latent_attention                # noqa: E402
 from paddle_tpu.profiler import ledger                          # noqa: E402
+from paddle_tpu.serving.slots import SlotLoop                   # noqa: E402
 from paddle_tpu.text.generation import (Generator,              # noqa: E402
                                         require_prefix_planes)
 from test_kimi_decoder import (GAP_TOL, GAP_TOL_BF16, REQUESTS,  # noqa: E402
@@ -214,13 +215,13 @@ def test_restored_blocks_select_the_same_columns(served, shift, monkeypatch):
     7 | 9 .. 15 | 16 .. 20 from column 5)."""
     _, model, _ = served
     attn, idx_q = _selector_layer(model)
-    seen, real = [], latent_attention.select_columns
+    seen, real = [], latent_attention.select_columns_span
 
-    def spy(scores, valid, k):
-        sel = real(scores, valid, k)
+    def spy(scores, *rule):
+        sel = real(scores, *rule)
         seen.append((np.asarray(sel), np.asarray(scores)))
         return sel
-    monkeypatch.setattr(latent_attention, "select_columns", spy)
+    monkeypatch.setattr(latent_attention, "select_columns_span", spy)
     monkeypatch.setattr(attn.idx_q, "_value", idx_q)
     x = np.array(jax.random.normal(jax.random.key(11), (1, 20, 32)))
     x[0, list(TIED)] = x[0, TIED[0]]
@@ -315,6 +316,254 @@ def test_the_selector_scopes_are_in_both_programs(served):
         assert any(s.endswith("latent_attention/selector") for s in scopes)
     # (the counter ``prefix_restored_bytes`` is held to what a block of
     # this model holds by ``test_a_hit_equals_the_plain_prefill[glm5-*]``)
+
+
+# -- (g) the search covers the dispatch's live span -----------------------------
+
+SPAN_C = 64      # widths 12, 24, 48, 64 at index_topk 6; attn_block 8
+# (pos, start, columns searched): a chunk is B = 1, T = attn_block = 8 with
+# its widest context pos + 8 - start; a step B = 3, T = 1 with pos + 1 -
+# min(start), a row whose start lies above the frontier is dead
+SPAN_CHUNKS = [
+    (0, 3, 0), (0, 2, 0), (0, 1, 12),               # topk - 1, topk, topk + 1
+    (8, 5, 12), (8, 4, 12), (8, 3, 24),             # 12's edge
+    (24, 9, 24), (24, 8, 24), (24, 7, 48),          # 24's edge
+    (48, 9, 48), (48, 8, 48), (48, 7, 64),          # 48's edge
+    (13, 2, 24),                                    # a chunk off the blocks
+    (56, 30, 48), (56, 55, 12),                     # slices clamped at the end
+]
+SPAN_STEPS = [
+    (5, (0, 2, 64), 0), (6, (0, 3, 64), 12),
+    (11, (0, 5, 9), 12), (12, (0, 5, 9), 24),
+    (23, (0, 20, 64), 24), (24, (0, 20, 64), 48),
+    (47, (0, 1, 46), 48), (48, (0, 1, 46), 64),
+    (63, (20, 40, 64), 48),                         # clamped: 20 + 48 > 64
+    (10, (3, 30, 64), 12),                          # two dead rows of three
+    (10, (64, 64, 64), 0),                          # every row dead
+]
+
+
+def _span_planes(attn, B, tied):
+    """Planes of ``SPAN_C`` columns with something in every column; with
+    ``tied``, three selector keys in four are ONE vector, so that their
+    columns' scores tie exactly for every query, on both sides of every
+    width's edge."""
+    rng = np.random.default_rng(17)
+    fresh = attn.gen_ring_cache(B, SPAN_C)
+    lat, key = (rng.normal(size=unwrap(p).shape).astype(np.float32)
+                for p in fresh)
+    if tied:
+        key[:, :, np.arange(SPAN_C) % 4 != 1] = key[0, 0, 0]
+    return type(fresh)(Tensor(jnp.asarray(lat)), Tensor(jnp.asarray(key)))
+
+
+@pytest.fixture(scope="module")
+def span_programs(served):
+    """Layer 1's ``forward_cached`` compiled once for a chunk (B = 1, T =
+    8) and once for a step (B = 3, T = 1), each as it is and with the rule
+    held to its last width, the plane's own, which is the program as it
+    was: scores of every visible block, the search over all ``C`` columns.
+    Each hands back (output, selector-key plane, membership, scores,
+    branch) of one dispatch."""
+    from unittest import mock
+    attn = served[1].layers[1].attn
+    real = latent_attention.select_columns_span
+
+    def program(whole):
+        def run(x, lat, key, pos, start):
+            seen = []
+
+            def spy(scores, valid, k, widths, branch, first):
+                sel = real(scores, valid, k, widths, branch, first)
+                seen.append((sel, scores, jnp.asarray(branch, jnp.int32)))
+                return sel
+            rule = (lambda widths, *_: len(widths)) if whole \
+                else latent_attention.span_branch
+            with mock.patch.object(latent_attention, "select_columns_span",
+                                   spy), \
+                    mock.patch.object(latent_attention, "span_branch", rule):
+                out, cache = attn.forward_cached(
+                    x, latent_attention.LatentCache(Tensor(lat), Tensor(key)),
+                    pos, start)
+            return (out, unwrap(cache.index_key)) + seen[-1]
+        return jax.jit(run)
+    return attn, program(False), program(True)
+
+
+def _span_dispatch(program, x, planes, pos, start):
+    out = program(x, *(unwrap(p) for p in planes), jnp.int32(pos),
+                  jnp.asarray(start, jnp.int32))
+    return tuple(np.asarray(o) for o in out)
+
+
+def _check_span(span_programs, x, pos, start, searched, tied):
+    attn, span, whole = span_programs
+    widths = attn.search_widths(SPAN_C)
+    assert widths == (12, 24, 48, 64)
+    B, T = x.shape[:2]
+    planes = _span_planes(attn, B, tied)
+    out, keys, sel, scores, branch = _span_dispatch(span, x, planes, pos,
+                                                    start)
+    want_out, want_keys, want_sel, all_scores, last = _span_dispatch(
+        whole, x, planes, pos, start)
+    assert ((0,) + widths)[branch] == searched and last == len(widths)
+    # the oracle is the whole-plane search over every visible column's score
+    np.testing.assert_array_equal(want_sel, np.asarray(
+        latent_attention.select_columns(
+            jnp.asarray(all_scores), jnp.isfinite(all_scores), attn.topk)))
+    np.testing.assert_array_equal(sel, want_sel)
+    np.testing.assert_array_equal(out, want_out)
+    # the selector's key is written whatever the branch
+    np.testing.assert_array_equal(keys, want_keys)
+    assert (keys[:, 0, pos:pos + T] != np.asarray(
+        unwrap(planes.index_key))[:, 0, pos:pos + T]).all()
+    ctx = pos + np.arange(T)[None, :] + 1 - np.asarray(start)[:, None]
+    np.testing.assert_array_equal(sel.sum(-1),
+                                  np.clip(ctx, 0, attn.topk))
+    if not searched:
+        # nothing to decide: no search, and no score was computed
+        assert np.isneginf(scores).all() and ctx.max() <= attn.topk
+    else:
+        np.testing.assert_array_equal(scores, all_scores)
+    return sel, all_scores
+
+
+@pytest.mark.parametrize("tied", [False, True], ids=["distinct", "tied"])
+@pytest.mark.parametrize("pos,start,searched", SPAN_CHUNKS)
+def test_a_chunks_search_over_its_span_selects_what_the_plane_search_does(
+        span_programs, pos, start, searched, tied):
+    """A chunk of 8 queries at column ``pos`` of a row that starts at
+    ``start``: the membership, the output and the written selector keys of
+    the span-bounded search equal the whole-plane search's to the bit, at
+    every width's edge, with the slice clamped at the plane's end, and
+    with exact ties on both sides of the edges (the lower column wins)."""
+    x = jax.random.normal(jax.random.key(pos * 64 + start), (1, 8, 32))
+    sel, scores = _check_span(span_programs, x, pos, [start], searched,
+                              tied)
+    if tied and pos - start >= 16:
+        # (the chunk writes its own keys over the plane's: the ties lie
+        # below ``pos``)  some query's threshold lies inside a group of equal scores that
+        # is only partly taken: the tie rule decided, and decided alike
+        partly = 0
+        for t in range(8):
+            sc, chosen = scores[0, t], sel[0, t]
+            if chosen.any():
+                group = sc == sc[chosen].min()
+                took = np.flatnonzero(group & chosen)
+                np.testing.assert_array_equal(
+                    took, np.flatnonzero(group)[:took.size])
+                partly += took.size < group.sum()
+        assert partly >= 1
+
+
+@pytest.mark.parametrize("tied", [False, True], ids=["distinct", "tied"])
+@pytest.mark.parametrize("pos,starts,searched", SPAN_STEPS)
+def test_a_steps_search_over_its_span_selects_what_the_plane_search_does(
+        span_programs, pos, starts, searched, tied):
+    """A step of three rows, one query each at column ``pos``: the same,
+    dead rows (``start`` above the frontier) selecting nothing."""
+    x = jax.random.normal(jax.random.key(pos), (3, 1, 32))
+    sel, _ = _check_span(span_programs, x, pos, list(starts), searched,
+                         tied)
+    assert not sel[np.asarray(starts) > pos].any()
+
+
+@pytest.mark.parametrize("C,k,widths", [
+    (24576, 2048, (4096, 8192, 16384, 24576)),      # glm-5-ep16-serve
+    (12288, 2048, (4096, 8192, 12288)),             # dots3-note-prev-ep8
+    (64, 6, (12, 24, 48, 64)), (13, 6, (12, 13)), (12, 6, (12,)),
+    (7, 6, (7,)), (6, 6, ()), (4, 6, ())])
+def test_the_widths_and_the_rule(C, k, widths):
+    """``2 k``, ``4 k``, ... below the plane, then the plane; the rule
+    takes the narrowest that holds the widest context, none while that is
+    no more than ``k``; host integers and traced values alike."""
+    from paddle_tpu.nn.functional import attention
+    assert attention.search_widths(C, k) == widths
+    for first in (0, 3):
+        # every context about index_topk and about each width's edge
+        lasts = np.array(sorted({
+            first + edge + d - 1 for edge in (0, k) + widths
+            for d in (-2, -1, 0, 1) if first + edge + d - 1 < C}))
+        want = [_searched(widths, k, first, int(last)) if widths else 0
+                for last in lasts]
+        assert [attention.searched_columns(widths, k, first, int(last))
+                for last in lasts] == want
+        if widths:
+            traced = jax.jit(lambda f, l: attention.span_branch(
+                widths, k, f, l))(first, lasts)
+            assert [((0,) + widths)[i] for i in np.asarray(traced)] == want
+
+
+def _searched(widths, top, first, last):
+    """The rule, spelled again: the narrowest width that holds the widest
+    context, nothing while that is no more than ``top``."""
+    widest = last - first + 1
+    return 0 if widest <= top else min(w for w in widths if w >= widest)
+
+
+@pytest.mark.parametrize("requests,searches", [
+    (REQUESTS, True), ([(2, 2), (3, 2), (2, 3), (4, 2), (1, 4)], False)],
+    ids=["contexts_past_index_topk", "contexts_within_index_topk"])
+def test_the_selector_counters_follow_the_rule(served, requests, searches,
+                                               monkeypatch):
+    """``selector_columns_searched`` / ``_plane`` and their ``chunk_``
+    twins: over the three selecting layers of every step and chunk the
+    loop dispatched, the width the rule gives for the dispatch's ``pos``
+    and ``start`` (the lowest among its rows), of the plane's 64 columns;
+    nothing is searched in a run whose contexts never pass index_topk."""
+    _, model, _ = served
+    seen, real = [], SlotLoop._tally_columns
+
+    def spy(self, cols, start, chunk=False):
+        seen.append((np.array(cols), np.array(start), chunk))
+        return real(self, cols, start, chunk)
+    monkeypatch.setattr(SlotLoop, "_tally_columns", spy)
+    _, _, st = _serve(model, requests)
+    want = dict.fromkeys(("selector_columns_searched",
+                          "selector_columns_plane",
+                          "chunk_selector_columns_searched",
+                          "chunk_selector_columns_plane"), 0)
+    for cols, start, chunk in seen:
+        pre = "chunk_" if chunk else ""
+        want[pre + "selector_columns_plane"] += 3 * 64
+        if cols.size:
+            want[pre + "selector_columns_searched"] += 3 * _searched(
+                (12, 24, 48, 64), 6, int(start.min()), int(cols.max()))
+    assert {k: st[k] for k in want} == want
+    assert st["selector_columns_plane"] == 3 * 64 * st["steps"]
+    assert st["chunk_selector_columns_plane"] == 3 * 64 * st["chunks"]
+    if searches:
+        assert 0 < st["selector_columns_searched"] \
+            < st["selector_columns_plane"]
+        assert 0 < st["chunk_selector_columns_searched"] \
+            < st["chunk_selector_columns_plane"]
+    else:
+        assert st["selector_columns_searched"] \
+            == st["chunk_selector_columns_searched"] == 0
+
+
+def test_the_widths_are_in_the_spec_and_in_the_ledger_events(served):
+    """The layer hands the widths out through ``cache_spec`` (the loop
+    reads them there) and the two slot programs' ledger events name them
+    beside ``latent_form``; a model without a selecting layer has no such
+    fact, and its loop no such counter."""
+    _, model, _ = served
+    gen = Generator(model, max_len=64, seq_buckets=[64])
+    assert [s["select_widths"] for s in gen.cache_spec(64)] \
+        == [(12, 24, 48, 64)] * 3
+    assert gen._step_program(3, 64)[4]["selector_widths"] == [12, 24, 48, 64]
+    assert gen._chunk_program(3, 4, 64)[4]["selector_widths"] \
+        == [12, 24, 48, 64]
+    kimi = Generator(_build(_tiny("kimi"))[0], max_len=64, seq_buckets=[64])
+    assert [s["select_widths"] for s in kimi.cache_spec(64)] == [None] * 3
+    assert kimi.selector_widths(64) is None
+    assert "selector_widths" not in kimi._step_program(3, 64)[4]
+    assert "selector_widths" not in kimi._chunk_program(3, 4, 64)[4]
+    loop = SlotLoop(kimi, slots=3, cache_len=64, chunk=4)
+    try:
+        assert not [k for k in loop.counters if "selector_columns" in k]
+    finally:
+        loop.close()
 
 
 # -- (f) the counts at the published size ----------------------------------------

@@ -215,6 +215,81 @@ def test_parse_scopes_lists_every_instruction():
     assert ps.bucket_of("while/body", cb) == ps.UNSCOPED
 
 
+@pytest.mark.parametrize("program", ["jit_step", "jit_chunk"])
+def test_every_branch_of_the_selectors_search_is_under_its_scope(
+        program, fresh_ledger):
+    """The search over the live span is one conditional with a branch for
+    "nothing to decide" and one for each width: the instructions of every
+    branch lie under ``selector/select`` (those of the score loop under
+    ``selector/score``), so that the readers of the component ``selector``
+    and of the ``attention`` bucket see them whichever branch runs."""
+    model = _model("glm_moe_dsa")
+    gen = Generator(model, max_len=COLUMNS, seq_buckets=[COLUMNS])
+    if program == "jit_step":
+        gen.step_exec(SLOTS, COLUMNS)
+    else:
+        gen.chunk_exec(SLOTS, CHUNK, COLUMNS)
+    table = ledger.program_scopes()[program]
+    widths = gen.selector_widths(COLUMNS)
+    assert widths == [12, 24, 48, 64]
+    scopes = {e["scope"] for e in table.values()}
+    select = "attention/latent_attention/selector/select"
+    for branch in range(len(widths) + 1):
+        assert any(s.startswith(f"{select}/cond/branch_{branch}_fun")
+                   for s in scopes), branch
+    assert any(s.startswith("attention/latent_attention/selector/score/while")
+               for s in scopes)
+    # no branch of that conditional lies anywhere else, and all of it is
+    # in the attention bucket
+    branches = {n: e["scope"] for n, e in table.items()
+                if re.search(r"cond/branch_\d_fun", e["scope"])
+                and "experts" not in e["scope"]}
+    assert branches and all(select in s for s in branches.values())
+    buckets = _buckets(table, ps.load_buckets())
+    assert {buckets[n] for n in branches} == {"attention"}
+
+
+def _layer_text(attn, cache_len=COLUMNS, width=CHUNK):
+    """One latent-attention layer's cached call, lowered alone."""
+    from paddle_tpu.framework.tensor import Tensor, unwrap
+    planes = [unwrap(p) for p in attn.gen_ring_cache(SLOTS, cache_len)]
+
+    def call(x, planes, pos, start):
+        out, cache = attn.forward_cached(
+            x, type(attn.gen_ring_cache(1, cache_len))(
+                *(Tensor(p) for p in planes)), pos, start)
+        return out, [unwrap(p) for p in cache]
+    return jax.jit(call).lower(
+        jnp.zeros((SLOTS, width, attn.hidden)), planes, jnp.int32(0),
+        jnp.zeros((SLOTS,), jnp.int32)).as_text(debug_info=True)
+
+
+@pytest.mark.parametrize("kind", ["no_selector", "window", "short_plane",
+                                  "selector"])
+def test_only_a_searching_layer_lowers_a_conditional(kind):
+    """A full layer without a selector (``index_topk`` 0), a window layer,
+    and a selecting layer whose plane holds no more than ``index_topk``
+    columns lower as they did: no conditional, and no ``selector/select``
+    scope; the layer that searches lowers exactly one."""
+    from paddle_tpu.nn.layer.latent_attention import LatentAttention
+    kw = dict(index_heads=2, index_dim=16, cache_block=4, attn_block=8)
+    attn = LatentAttention(
+        32, 4, 8, 8, 12, 16, 12, 100.0,
+        window=16 if kind == "window" else None,
+        index_topk={"no_selector": 0, "window": 0, "short_plane": COLUMNS,
+                    "selector": 6}[kind], **kw)
+    text = _layer_text(attn)
+    searches = kind == "selector"
+    assert text.count("stablehlo.case") == (1 if searches else 0)
+    assert ("selector/select" in text) == searches
+    assert ("selector/score" in text) == searches
+    assert ("/selector/" in text) == (kind in ("selector", "short_plane"))
+    assert (attn.search_widths(COLUMNS) is None) \
+        == (kind in ("no_selector", "window"))
+    if kind == "short_plane":
+        assert attn.search_widths(COLUMNS) == ()
+
+
 # -- (b) a scope is metadata and nothing else ---------------------------------
 def _lowered_texts(family):
     """The StableHLO text (no locations) of the family's step and chunk, or

@@ -69,7 +69,7 @@ index: 48 layers of `attention` add up.
 | scope | where it is entered | covers |
 |---|---|---|
 | `embed` | `GPTModel.forward` / `forward_cached`, `BertModel.forward`, the latent and hybrid decoders | token, position and type lookups, the mask arithmetic beside them |
-| `attention` | `TransformerEncoderLayer.forward`, `LatentDecoderLayer`, `HybridDecoderLayer` (an attention layer) | a layer's attention half: its norm, the q/k/v/out products, the scores, the residual add.  Inside it the newer families keep `latent_attention`, `latent_attention/per_head`, `selector` (the learned column selector's projections and key write; inside it `selector/score`, the scores of every valid column, apart from `selector/select`, the radix search among them), `gqa_attention` |
+| `attention` | `TransformerEncoderLayer.forward`, `LatentDecoderLayer`, `HybridDecoderLayer` (an attention layer) | a layer's attention half: its norm, the q/k/v/out products, the scores, the residual add.  Inside it the newer families keep `latent_attention`, `latent_attention/per_head`, `selector` (the learned column selector's projections and key write; inside it `selector/score`, the scores of every valid column, apart from `selector/select`, the radix search among them: one conditional with a branch for each width the search may take, `select/cond/branch_<i>_fun`, `branch_0_fun` for a dispatch in which nothing is to be decided), `gqa_attention` |
 | `attention/fused` | `nn.functional.attention` (`attention_bse`, `attention_bnsh`), around the call of a Pallas attention kernel | an un-cached call site that took a kernel: its custom calls by name, `attention_single_block_fwd` and, under `transpose(jvp(..))`, `attention_single_block_bwd` (the blocked form's three have no name of their own).  Bucket `attention`, inside a layer's or alone |
 | `cache_write` | `ring_block_write`, the short convolution's state, `Generator`'s `_slice_row` / `_splice_row` (`cache_write/row_slice`, `cache_write/row_splice`) | every write into a cache plane, and a chunk's batch-1 row out of the planes and back.  Innermost wins: a plane write inside `attention` counts here |
 | `mlp` | `TransformerEncoderLayer.forward`, `SwiGLU.forward`, the decoders' dense layers | a layer's dense feed-forward half with its norm and residual add; a shared expert |
@@ -101,6 +101,26 @@ compile event of the ledger carries `attention_form: {"fused": n, "xla":
 m}`, the step's call sites by form (`count_attention_forms`; the BERT-large
 step of the benchmark reads `{"fused": 24, "xla": 0}` on the chip, `{"fused":
 0, "xla": 24}` on the CPU).
+
+How wide the column selector's search runs is decided in the program, from
+what a dispatch can see: it goes over the narrowest of a short list of
+static widths (`nn.functional.attention.search_widths`: `2 k`, `4 k`, ...
+below the plane's columns, then the plane) that holds the dispatch's widest
+context, `last column - lowest start + 1`, and over nothing (no scores
+either) while that context is no more than `index_topk`, where every valid
+column is chosen.  The widths are a fact of the programs: the
+`generate_step` / `generate_chunk` compile events of the ledger carry
+`selector_widths` beside `latent_form` (a model in which no layer selects
+has no such fact), and a layer's `cache_spec` hands them out as `select_widths`.
+`SlotLoop.counters` says how often and how far the rule engaged, by the same
+function the program calls (`searched_columns`), summed over the selecting
+layers, committed with `steps` and zeroed by `reset_stats()`:
+`selector_columns_searched` / `selector_columns_plane` over the steps (the
+columns searched, of those the planes have), `chunk_selector_columns_searched`
+/ `chunk_selector_columns_plane` over the prefill chunks.  Their ratio is 0
+for a run whose contexts never pass `index_topk` and 1 for one whose every
+dispatch needs the whole plane; only a loop over a model with a selecting
+layer has them.
 
 To read a capture of your own: `paddle_tpu.profiler.Profiler` writes
 `program_scopes.json` into `profiler_result_dir` when a record window
