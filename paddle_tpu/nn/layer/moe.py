@@ -40,6 +40,7 @@ the typed registry (``moe_tokens_dropped_total{model}`` counter +
 """
 from __future__ import annotations
 
+import functools
 from typing import Optional, Sequence
 
 import numpy as np
@@ -661,6 +662,37 @@ class SwiGLU(Layer):
         return Tensor(y) if isinstance(u, Tensor) else y
 
 
+class ReluSquared(Layer):
+    """``W_down relu(W_up u)^2``: two matrices, no gate, no biases."""
+
+    def __init__(self, hidden, width, weight_attr=None, dtype=None):
+        super().__init__()
+        from .. import initializer as I
+
+        def mat(*shape):
+            return self.create_parameter(
+                list(shape), attr=weight_attr, dtype=dtype,
+                default_initializer=I.Normal(0.0, 0.02))
+        self.w_up = mat(hidden, width)
+        self.w_down = mat(width, hidden)
+
+    def forward(self, u):
+        raw = unwrap(u)
+        f32 = jnp.float32
+        with jax.named_scope("mlp"):
+            v = jnp.einsum("...h,hf->...f", raw, unwrap(self.w_up),
+                           preferred_element_type=f32)
+            y = jnp.einsum("...f,fh->...h",
+                           jnp.square(jax.nn.relu(v)).astype(raw.dtype),
+                           unwrap(self.w_down), preferred_element_type=f32)
+            y = y.astype(raw.dtype)
+        return Tensor(y) if isinstance(u, Tensor) else y
+
+
+# an expert's form -> the dense layer of that form (the shared expert is one)
+EXPERT_FORMS = {"swiglu": SwiGLU, "relu2": ReluSquared}
+
+
 class DroplessMoE(Layer):
     """Sigmoid-routed experts without capacity and without drops, told
     WHICH experts it holds.
@@ -671,13 +703,17 @@ class DroplessMoE(Layer):
     scores, renormalised over the chosen (``norm_topk``) and times
     ``scaling``.  This layer holds experts ``held = (lo, hi)`` and
     computes THEIR part of ``sum_chosen w_i E_i(u)``: the assignments are
-    sorted by expert and the three products run over the rows of each
-    held expert, however many rows that is.  An assignment to an expert
+    sorted by expert and the expert's products run over the rows of each
+    held expert, however many rows that is.  ``activation`` is the
+    experts' form: ``"swiglu"`` (three matrices, ``W_down(silu(W_gate u) *
+    W_up u)``) or ``"relu2"`` (two, ``W_down relu(W_up u)^2``: such a layer
+    has no ``w_gate``).  An assignment to an expert
     held elsewhere adds nothing here: on a mesh the other shares' parts
     arrive by the exchange that this layer does not do (the one-chip
     share of an expert-parallel deployment); ``held=None`` holds every
-    expert.  ``shared`` experts (dense SwiGLU of ``shared x width``) see
-    every token and are counted once.  ``norm_eps`` is what the
+    expert.  ``shared`` experts (one dense layer of the
+    experts' own form, ``shared x width`` wide) see every token and are
+    counted once.  ``norm_eps`` is what the
     renormalisation adds to the sum of the chosen scores (``w_i = s_i /
     (sum + norm_eps)``, the ``lfm2_moe`` form); ``None`` divides by
     ``max(sum, 1e-20)`` (the ``dots3_note`` form).
@@ -689,16 +725,24 @@ class DroplessMoE(Layer):
     batched product, which streams the experts' weights once at 92% of
     the chip's bandwidth, as long as ``cap <= PADDED_ROWS_MAX``: up to
     there the padded rows cost less than the weights they are multiplied
-    with.  Beyond it, and in any dispatch in which the router sent one
-    expert more than ``cap`` rows, the products run grouped as the rows
-    lie (``lax.ragged_dot``, 45% of the bandwidth whatever the rows:
-    PERF.md section 6, PR 27): nothing is ever dropped.  After PR 31 the
-    benchmark's cells enter: ``dots3-ep8-rag4k-saturated`` the padded
-    branch in its 64-row step (cap 8) and its 512-token chunk (cap 64);
-    ``lfm2-pp2-reason-saturated`` (all 32 experts held, top 4) the padded
-    branch in its 128-row step (cap 64) and its 512-token chunk (cap
-    128); the grouped branch is entered by a dispatch of more than
-    ``PADDED_ROWS_MAX x num_experts / 4`` assignments and by skew.
+    with.  A dispatch in which the router sent one expert more than
+    ``cap`` rows takes a second, WIDE tier of the same padded products
+    (PR 40): a token sends an expert one row at most, so ``min(tokens,
+    PADDED_ROWS_MAX)`` rows hold any skew of a dispatch of up to
+    ``PADDED_ROWS_MAX`` tokens (every decode step), and a 512-token chunk
+    overflows it only when one expert gets more than half its tokens.
+    Beyond that, and in a dispatch whose ``cap`` passes
+    ``PADDED_ROWS_MAX``, the products run grouped as the rows lie
+    (``lax.ragged_dot``, 45% of the bandwidth whatever the rows: PERF.md
+    section 6, PR 27): nothing is ever dropped.  The benchmark's cells
+    enter: ``dots3-ep8-rag4k-saturated`` the first tier in its 64-row
+    step (cap 8) and its 512-token chunk (cap 64);
+    ``lfm2-pp2-reason-saturated`` (all 32 experts held, top 4) in its
+    128-row step (cap 64) and its chunk (cap 128);
+    ``nemotron3-ep8-reason1k-saturated`` (16 of 128 held, top 6) the first
+    tier in its 48-row step (cap 16) and, in most of its chunks (cap 88:
+    one request's 512 tokens send an expert up to 171 rows), the wide one
+    (256).
 
     After a forward, ``last_counts`` holds (assignments made, assignments
     that fell on held experts, the largest per-expert row count), over
@@ -711,9 +755,14 @@ class DroplessMoE(Layer):
 
     def __init__(self, hidden, width, num_experts, top_k, *, held=None,
                  shared=0, scaling=1.0, norm_topk=True, norm_eps=None,
-                 weight_attr=None, dtype=None):
+                 activation="swiglu", weight_attr=None, dtype=None):
         super().__init__()
         from .. import initializer as I
+        if activation not in EXPERT_FORMS:
+            raise InvalidArgumentError(
+                f"experts of form {activation!r}; have "
+                f"{sorted(EXPERT_FORMS)}")
+        self.activation = activation
         lo, hi = (0, num_experts) if held is None else map(int, held)
         if not 0 <= lo < hi <= num_experts:
             raise InvalidArgumentError(
@@ -735,11 +784,12 @@ class DroplessMoE(Layer):
         self.router = mat(hidden, num_experts)
         self.router_bias = self.create_parameter(
             [num_experts], dtype="float32", is_bias=True)
-        self.w_gate = mat(n, hidden, width)
+        if activation == "swiglu":
+            self.w_gate = mat(n, hidden, width)
         self.w_up = mat(n, hidden, width)
         self.w_down = mat(n, width, hidden)
-        self.shared = SwiGLU(hidden, shared * width, weight_attr, dtype) \
-            if shared else None
+        self.shared = EXPERT_FORMS[activation](
+            hidden, shared * width, weight_attr, dtype) if shared else None
         self.last_counts = None
 
     def route(self, u2d):
@@ -781,17 +831,23 @@ class DroplessMoE(Layer):
             place = jnp.argsort(order)          # assignment -> sorted row
             f32, A = jnp.float32, N * k
 
+            def inner(into):
+                """An expert's hidden activation from ``into(w)``, the
+                rows' product with an up-going matrix of each expert."""
+                if self.activation == "relu2":
+                    return jnp.square(
+                        jax.nn.relu(into(unwrap(self.w_up)))).astype(dt)
+                g, v = into(unwrap(self.w_gate)), into(unwrap(self.w_up))
+                return (jax.nn.silu(g) * v).astype(dt)
+
             def ragged():
                 """Grouped products over the sorted rows, as they lie:
                 any number of rows an expert."""
                 xs = x[tok]
-                g = jax.lax.ragged_dot(xs, unwrap(self.w_gate), sizes,
-                                       preferred_element_type=f32)
-                v = jax.lax.ragged_dot(xs, unwrap(self.w_up), sizes,
-                                       preferred_element_type=f32)
                 y = jax.lax.ragged_dot(
-                    (jax.nn.silu(g) * v).astype(dt), unwrap(self.w_down),
-                    sizes, preferred_element_type=f32)
+                    inner(lambda w: jax.lax.ragged_dot(
+                        xs, w, sizes, preferred_element_type=f32)),
+                    unwrap(self.w_down), sizes, preferred_element_type=f32)
                 return y[place]                            # [A, h], unsorted
 
             def padded(cap):
@@ -804,13 +860,11 @@ class DroplessMoE(Layer):
                 at = jnp.minimum(first[:, None] + jnp.arange(cap)[None],
                                  A - 1)                        # [n, cap]
                 xs = x[tok[at]]                                # [n, cap, h]
-                g = jnp.einsum("ech,ehf->ecf", xs, unwrap(self.w_gate),
-                               preferred_element_type=f32)
-                v = jnp.einsum("ech,ehf->ecf", xs, unwrap(self.w_up),
-                               preferred_element_type=f32)
-                y = jnp.einsum("ecf,efh->ech",
-                               (jax.nn.silu(g) * v).astype(dt),
-                               unwrap(self.w_down), preferred_element_type=f32)
+                y = jnp.einsum(
+                    "ecf,efh->ech",
+                    inner(lambda w: jnp.einsum(
+                        "ech,ehf->ecf", xs, w, preferred_element_type=f32)),
+                    unwrap(self.w_down), preferred_element_type=f32)
                 # assignment a lies at sorted row p, in its expert's padded
                 # row p - first[expert]
                 e = jnp.minimum(key, n - 1)
@@ -821,14 +875,24 @@ class DroplessMoE(Layer):
             # that, at most 64 rows over it (64 for a 512-token chunk's
             # 4,096 assignments over 256 experts, the floor of 8 for a
             # 64-row step's 512; 64 for a 128-row step's 512 over 32 and
-            # 128 for a chunk's 2,048).  An expert with more, however
-            # skewed the router, sends the block through the grouped
-            # products: nothing is ever dropped
+            # 128 for a chunk's 2,048).  An expert with more goes to a
+            # second, wide tier of padded products: a token sends an expert
+            # one row at most, so ``N`` rows (up to PADDED_ROWS_MAX) hold
+            # whatever the router does; only a dispatch of more tokens than
+            # that can overflow it, into the grouped products: nothing is
+            # ever dropped
             even = -(-A // self.num_experts)
             cap = max(8, -(-min(4 * even, even + 64) // 8) * 8)
             if cap <= self.PADDED_ROWS_MAX:
-                y = jax.lax.cond(jnp.max(sizes) <= cap,
-                                 lambda: padded(cap), ragged)
+                wide = min(self.PADDED_ROWS_MAX, -(-N // 8) * 8)
+                caps = [cap] + ([wide] if wide > cap else [])
+                branches = [functools.partial(padded, c) for c in caps]
+                if caps[-1] < N:
+                    branches.append(ragged)
+                most = jnp.max(sizes)
+                tier = sum((most > c).astype(jnp.int32) for c in caps)
+                y = jax.lax.switch(jnp.minimum(tier, len(branches) - 1),
+                                   branches)
             else:
                 y = ragged()
             y = jnp.where(held.reshape(-1, 1), y * w.reshape(-1, 1), 0.0)

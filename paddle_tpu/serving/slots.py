@@ -66,14 +66,24 @@ per-request ``generate()`` of the same prompt:
     chunks first — chunk writes never depend on ``pos``;
   * **a state without columns is kept, not garbled** — the dead-column
     rule has no meaning for a plane that a feed overwrites in place
-    (``cache_spec`` ``columns == 0``: a short convolution's last
-    inputs).  Such a state's entries stand for the columns just before
-    the block being fed and count iff those columns are at or after the
-    row's ``start``, so a previous occupant's leftovers, left padding and
-    a ring restart need no reset; and the step hands the model its live
-    rows (``cached_forward_takes_rows``), so a row that waits between
-    two of its chunks, or is done, keeps what it has
-    (``counters["state_rows_held"]``);
+    (``cache_spec`` ``columns == 0``).  Two rules say when such a state
+    counts, side by side.  *Positional* (a short convolution's last
+    inputs, kind ``conv_state``, and the convolution inside a state-space
+    layer): the entries stand for the columns just before the block being
+    fed and count iff those columns are at or after the row's ``start``.
+    *Summed* (a state-space layer's state, kind ``ssm_state``, which
+    stands for EVERY earlier column and may be of another dtype than the
+    planes beside it, float32): the state handed to a block whose first
+    column is ``pos`` counts iff ``pos > start``, else the request begins
+    inside the block and the state is zeros; a token before ``start``
+    inside the block passes it through unchanged.  Under either a
+    previous occupant's leftovers, left padding and a ring restart need
+    no reset; and the step hands the model its live rows
+    (``cached_forward_takes_rows``), so a row that waits between two of
+    its chunks, or is done, keeps what it has
+    (``counters["state_rows_held"]``; ``["ssm_rows_updated"]`` counts the
+    live rows whose summed state a step updated, ``["chunk_ssm_tokens"]``
+    the valid tokens the chunks scanned into one);
   * **bounded ring sessions** — the validity mask compares absolute
     columns, so ``pos`` must stay inside ``[0, C)``: a request admits
     only if ``act + max_new (+ gamma)`` fits, and when the FIFO head
@@ -296,6 +306,10 @@ class SlotLoop:
         # feed overwrites in place, so a step must leave the rows it does
         # not feed as they are (the model takes the step's live rows)
         self._state_layers = sum(1 for s in spec if not int(s["columns"]))
+        # ... those of them whose state sums every earlier token (a
+        # state-space layer): each live row of a step updates it, each
+        # valid token of a chunk is scanned into it
+        self._ssm_layers = sum(1 for s in spec if s["kind"] == "ssm_state")
         # the kinds of the planes that DO have columns
         column_kinds = sorted({str(s["kind"]) for s in spec
                                if int(s["columns"])})
@@ -378,6 +392,8 @@ class SlotLoop:
             self.counters["window_wraps"] = 0
         if self._state_layers:
             self.counters["state_rows_held"] = 0
+        if self._ssm_layers:
+            self.counters.update(ssm_rows_updated=0, chunk_ssm_tokens=0)
         # the plain step over bf16/f32 K/V planes attends in blocks of
         # this many columns (cached_attention), whatever the model keeps
         # beside them that has no columns
@@ -929,6 +945,9 @@ class SlotLoop:
         cols, ctx = cols[ctx > 0], ctx[ctx > 0]
         if chunk and "chunk_tokens" in self.counters:
             add("chunk_tokens", int(ctx.size))
+        if self._ssm_layers:
+            add("chunk_ssm_tokens" if chunk else "ssm_rows_updated",
+                int(ctx.size))
         if self._attn_block:
             add(pre + "kv_columns_valid", int(ctx.sum()))
         for top in self._context_tops:

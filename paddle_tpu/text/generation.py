@@ -183,7 +183,8 @@ def require_kv_planes(kinds, who):
     the KV handoff park and ship UNIFORM K/V ring planes (kinds ``kv``,
     ``kv_int8``: every plane as long as the session, a column a token).
     A latent plane, a window plane shorter than the session, or a state
-    without columns (``conv_state``) is not theirs yet."""
+    without columns (``conv_state``, ``ssm_state``) is not theirs yet: a
+    summed state has no column blocks to cut, only snapshots."""
     bad = sorted(k for k in set(kinds) if not str(k).startswith("kv"))
     if bad:
         _refuse_planes(bad, who)

@@ -1,9 +1,13 @@
 """Decoder-only LM whose layers differ in MIXER and in FFN independently:
-gated short convolutions or grouped-query attention, dense SwiGLU or a
-sigmoid-routed MoE that holds its experts (the ``lfm2_moe`` lineage).
+gated short convolutions, grouped-query attention or Mamba-2 state-space
+mixers; dense SwiGLU or a sigmoid-routed MoE that holds its experts or a
+share of them (the ``lfm2_moe`` and ``nemotron_h`` lineages).
 
-Block: ``h = x + Mix(RMSNorm(x))``, ``y = h + FFN(RMSNorm(h))``; final
-RMSNorm; the output head is the embedding (tied).  ``layer_types[i]`` is
+Block: ``h = x + Mix(RMSNorm(x))``, ``y = h + FFN(RMSNorm(h))``, where a
+layer may have a mixer alone or an FFN alone (``layer_types[i]`` /
+``ffn_types[i]`` ``"none"``: the other half is the whole layer); final
+RMSNorm; the output head is the embedding (tied) or a matrix of its own.
+``layer_types[i]`` is
 
   * ``"conv"``: ``[B | C | X] = W_in x``, ``u = B * X``, ``c(t) = sum_j
     w_j * u(t - (L-1) + j)`` (depthwise, ``L`` taps, ``u = 0`` before the
@@ -14,20 +18,37 @@ RMSNorm; the output head is the embedding (tied).  ``layer_types[i]`` is
   * ``"full_attention"``: ``rep = heads / kv_heads`` query heads a cached
     head, RMSNorm over each head's features of q and k (one learned vector
     each), rotary positions, K (after norm and rotary) and V in the GPT
-    family's packed ring planes (``gen_ring_cache``'s layout, kind ``kv``).
+    family's packed ring planes (``gen_ring_cache``'s layout, kind ``kv``);
+    ``qk_norm`` false leaves the per-head norms out, ``rope_base`` None the
+    rotary positions (the ``nemotron_h`` attention has neither);
+  * ``"ssm"``: :class:`~paddle_tpu.nn.layer.mamba2.Mamba2Mixer`, which
+    keeps a float32 state ``[B, heads, head_dim, state]`` and the last
+    ``taps - 1`` inputs of its convolution (kind ``ssm_state``), both
+    WITHOUT columns.
 
-The first ``dense_layers`` FFNs are dense, the rest
-:class:`~paddle_tpu.nn.layer.moe.DroplessMoE`.
+``ffn_types[i]`` is ``"dense"``, ``"moe"``
+(:class:`~paddle_tpu.nn.layer.moe.DroplessMoE`) or ``"none"``; left out,
+the first ``dense_layers`` FFNs are dense and the rest MoE.
 
-**Liveness of the state is positional**, like everything else in a slot
-loop: the state's entries ARE ``u`` at the columns ``pos - (L-1) .. pos -
-1``, and an entry counts iff its column is at or after the row's
-``start``.  That covers a slot's previous occupant (its leftovers lie
-before the new ``start``), left padding inside a chunk and a ring restart,
-with no reset program.  What it cannot cover is a row that is not being
-fed by a step (it waits for the frontier between its chunks, or is done):
-a step must leave that row's state as it is, so the model takes the step's
-live rows (``write_rows``, ``cached_forward_takes_rows``).
+**Liveness of a state without columns: two rules, side by side.**
+
+  * *A short convolution's inputs are positional*, like everything else
+    in a slot loop (``conv`` layers, and the convolution inside an ``ssm``
+    layer): the entries ARE the inputs at the columns ``pos - (L-1) ..
+    pos - 1``, and an entry counts iff its column is at or after the row's
+    ``start``.
+  * *A summed state cannot be*: it stands for every column before the
+    block.  The state handed to a block whose first column is ``pos``
+    counts iff ``pos > start`` (else the request begins inside the block:
+    zeros), and a token before ``start`` inside the block passes it
+    through unchanged.
+
+Either covers a slot's previous occupant (its leftovers lie before the new
+``start``), left padding inside a chunk and a ring restart, with no reset
+program.  What neither can cover is a row that is not being fed by a step
+(it waits for the frontier between its chunks, or is done): a step must
+leave that row's state as it is, so the model takes the step's live rows
+(``write_rows``, ``cached_forward_takes_rows``).
 
 Inference only: nothing here is taped.
 """
@@ -35,7 +56,7 @@ from __future__ import annotations
 
 import collections
 import dataclasses
-from typing import Sequence
+from typing import Optional, Sequence
 
 import jax
 import jax.numpy as jnp
@@ -45,6 +66,7 @@ from ...framework.tensor import Tensor, unwrap
 from ...nn import initializer as I
 from ...nn.functional.attention import rotary, span_attention
 from ...nn.layer.latent_attention import RMSNorm
+from ...nn.layer.mamba2 import Mamba2Mixer, _product
 from ...nn.layer.moe import DroplessMoE, SwiGLU
 from ...nn.layer.transformer import (MultiHeadAttention,
                                      kv_heads_per_lane_row, pack_heads,
@@ -53,7 +75,8 @@ from ...nn.layer.transformer import (MultiHeadAttention,
 __all__ = ["HybridConvConfig", "HybridConvDecoder", "ConvStateCache",
            "ShortConv", "GroupedQueryAttention"]
 
-CONV, ATTN = "conv", "full_attention"
+CONV, ATTN, SSM, NONE = "conv", "full_attention", "ssm", "none"
+DENSE, MOE = "dense", "moe"
 
 # a conv layer's cache: ``state [B, 1, L-1, hidden]``, row first like every
 # plane (so the slot programs cut a row out of it as out of any other),
@@ -73,10 +96,14 @@ class HybridConvConfig:
     vocab_size: int = 1024
     hidden_size: int = 256
     layer_types: Sequence[str] = (CONV, ATTN)
+    ffn_types: Optional[Sequence[str]] = None   # None: by ``dense_layers``
     dense_layers: int = 1               # leading layers with a dense FFN
     intermediate_size: int = 512
     moe_intermediate_size: int = 128
     num_experts: int = 8
+    held_experts: Optional[Sequence[int]] = None    # (lo, hi); None: all
+    shared_experts: int = 0
+    expert_activation: str = "swiglu"
     experts_per_token: int = 2
     routed_scaling: float = 1.0
     norm_topk: bool = True
@@ -84,10 +111,23 @@ class HybridConvConfig:
     num_heads: int = 4
     num_kv_heads: int = 2
     head_dim: int = 64
-    rope_base: float = 1e6
+    qk_norm: bool = True
+    rope_base: Optional[float] = 1e6    # None: no rotary positions
     conv_taps: int = 3                  # the config's ``conv_L_cache``
+    ssm_heads: int = 4
+    ssm_head_dim: int = 16
+    ssm_state: int = 16
+    ssm_groups: int = 2
+    ssm_taps: int = 4
+    ssm_chunk: int = 8
+    tie_embeddings: bool = True
     rms_eps: float = 1e-5
     dtype: str = "float32"
+
+    def ffn_type(self, index: int) -> str:
+        if self.ffn_types is not None:
+            return self.ffn_types[index]
+        return DENSE if index < self.dense_layers else MOE
 
     @classmethod
     def tiny(cls, **over):
@@ -102,18 +142,34 @@ class HybridConvConfig:
         base.update(over)
         return cls(**base)
 
+    @classmethod
+    def tiny_ssm(cls, **over):
+        """A CPU-test size of the one-part-a-layer form: state-space,
+        expert and attention layers, each a mixer or an FFN alone; 8
+        relu^2 experts of which 3 a token and one shared; an untied head;
+        attention without per-head norm and rotary positions."""
+        pattern = "MEMEM*EME"
+        base = dict(vocab_size=96, hidden_size=64,
+                    layer_types=tuple({"M": SSM, "*": ATTN}.get(c, NONE)
+                                      for c in pattern),
+                    ffn_types=tuple(MOE if c == "E" else NONE
+                                    for c in pattern),
+                    moe_intermediate_size=32, num_experts=8,
+                    experts_per_token=3, shared_experts=2,
+                    expert_activation="relu2", routed_scaling=2.5,
+                    routing_norm_eps=1e-20, num_heads=4, num_kv_heads=2,
+                    head_dim=16, qk_norm=False, rope_base=None,
+                    ssm_heads=4, ssm_head_dim=16, ssm_state=16,
+                    ssm_groups=2, ssm_taps=4, ssm_chunk=8,
+                    tie_embeddings=False)
+        base.update(over)
+        return cls(**base)
+
 
 def _mat(layer, shape, weight_attr, dtype):
     return layer.create_parameter(
         list(shape), attr=weight_attr, dtype=dtype,
         default_initializer=I.Normal(0.0, 0.02))
-
-
-def _product(x, w):
-    """``x [..., a] @ w [a, b]`` with float32 accumulation, in ``x``'s
-    dtype."""
-    return jnp.einsum("...a,ab->...b", x, unwrap(w),
-                      preferred_element_type=jnp.float32).astype(x.dtype)
 
 
 class ShortConv(nn.Layer):
@@ -184,17 +240,19 @@ class ShortConv(nn.Layer):
 
 
 class GroupedQueryAttention(nn.Layer):
-    """``heads`` query heads over ``kv_heads`` cached heads, per-head
-    RMSNorm on q and k, rotary positions; no bias."""
+    """``heads`` query heads over ``kv_heads`` cached heads; per-head
+    RMSNorm on q and k unless ``qk_norm`` is false, rotary positions unless
+    ``rope_base`` is None; no bias."""
 
     def __init__(self, hidden, heads, kv_heads, head_dim, rope_base,
-                 epsilon=1e-5, weight_attr=None, dtype=None):
+                 epsilon=1e-5, weight_attr=None, dtype=None, qk_norm=True):
         super().__init__()
         if heads % kv_heads:
             raise ValueError(f"{heads} query heads over {kv_heads} cached")
         self.H, self.KV, self.d = int(heads), int(kv_heads), int(head_dim)
         self.rep = self.H // self.KV
-        self.base, self.eps = float(rope_base), float(epsilon)
+        self.base = None if rope_base is None else float(rope_base)
+        self.eps = float(epsilon)
         self.q_proj = _mat(self, (hidden, self.H * self.d), weight_attr, dtype)
         self.k_proj = _mat(self, (hidden, self.KV * self.d), weight_attr,
                            dtype)
@@ -202,8 +260,10 @@ class GroupedQueryAttention(nn.Layer):
                            dtype)
         self.o_proj = _mat(self, (self.H * self.d, hidden), weight_attr,
                            dtype)
-        self.q_norm = RMSNorm(self.d, epsilon, dtype=dtype)
-        self.k_norm = RMSNorm(self.d, epsilon, dtype=dtype)
+        self.q_norm = RMSNorm(self.d, epsilon, dtype=dtype) \
+            if qk_norm else None
+        self.k_norm = RMSNorm(self.d, epsilon, dtype=dtype) \
+            if qk_norm else None
 
     def cache_spec(self, max_len):
         return {"kind": RingCache.kind,
@@ -221,16 +281,19 @@ class GroupedQueryAttention(nn.Layer):
 
     def _heads(self, x, pos_ids):
         """q ``[B, H, T, d]``, k and v ``[B, KV, T, d]`` of the normed
-        block ``x``, q and k normed per head and rotated."""
+        block ``x``, q and k normed per head and rotated where the layer
+        does either."""
         B, T, _ = x.shape
 
-        def split(w, n, norm=None):
+        def split(w, n, norm=None, turn=False):
             y = _product(x, w).reshape(B, T, n, self.d)
             if norm is not None:
-                y = rotary(unwrap(norm(y)), pos_ids, self.base)
+                y = unwrap(norm(y))
+            if turn and self.base is not None:
+                y = rotary(y, pos_ids, self.base)
             return jnp.swapaxes(y, 1, 2)
-        return (split(self.q_proj, self.H, self.q_norm),
-                split(self.k_proj, self.KV, self.k_norm),
+        return (split(self.q_proj, self.H, self.q_norm, True),
+                split(self.k_proj, self.KV, self.k_norm, True),
                 split(self.v_proj, self.KV))
 
     def _out(self, o):
@@ -277,7 +340,7 @@ class GroupedQueryAttention(nn.Layer):
 class HybridDecoderLayer(nn.Layer):
     def __init__(self, cfg: HybridConvConfig, index: int, weight_attr=None):
         super().__init__()
-        kind = cfg.layer_types[index]
+        kind, ffn = cfg.layer_types[index], cfg.ffn_type(index)
         if kind == CONV:
             self.mixer = ShortConv(cfg.hidden_size, cfg.conv_taps,
                                    weight_attr, cfg.dtype)
@@ -285,33 +348,52 @@ class HybridDecoderLayer(nn.Layer):
             self.mixer = GroupedQueryAttention(
                 cfg.hidden_size, cfg.num_heads, cfg.num_kv_heads,
                 cfg.head_dim, cfg.rope_base, cfg.rms_eps, weight_attr,
-                cfg.dtype)
+                cfg.dtype, qk_norm=cfg.qk_norm)
+        elif kind == SSM:
+            self.mixer = Mamba2Mixer(
+                cfg.hidden_size, cfg.ssm_heads, cfg.ssm_head_dim,
+                cfg.ssm_state, cfg.ssm_groups, cfg.ssm_taps, cfg.ssm_chunk,
+                cfg.rms_eps, weight_attr, cfg.dtype)
+        elif kind == NONE and ffn != NONE:
+            self.mixer = None
         else:
-            raise ValueError(f"layer_types[{index}] = {kind!r}")
-        self.operator_norm = RMSNorm(cfg.hidden_size, cfg.rms_eps,
-                                     dtype=cfg.dtype)
-        self.ffn_norm = RMSNorm(cfg.hidden_size, cfg.rms_eps, dtype=cfg.dtype)
-        if index < cfg.dense_layers:
+            raise ValueError(f"layer_types[{index}] = {kind!r} with the "
+                             f"FFN {ffn!r}")
+        if self.mixer is not None:
+            self.operator_norm = RMSNorm(cfg.hidden_size, cfg.rms_eps,
+                                         dtype=cfg.dtype)
+        if ffn == DENSE:
             self.ffn = SwiGLU(cfg.hidden_size, cfg.intermediate_size,
                               weight_attr, cfg.dtype)
-        else:
+        elif ffn == MOE:
             self.ffn = DroplessMoE(
                 cfg.hidden_size, cfg.moe_intermediate_size, cfg.num_experts,
-                cfg.experts_per_token, held=None, shared=0,
-                scaling=cfg.routed_scaling, norm_topk=cfg.norm_topk,
-                norm_eps=cfg.routing_norm_eps, weight_attr=weight_attr,
+                cfg.experts_per_token, held=cfg.held_experts,
+                shared=cfg.shared_experts, scaling=cfg.routed_scaling,
+                norm_topk=cfg.norm_topk, norm_eps=cfg.routing_norm_eps,
+                activation=cfg.expert_activation, weight_attr=weight_attr,
                 dtype=cfg.dtype)
+        elif ffn == NONE:
+            self.ffn = None
+        else:
+            raise ValueError(f"ffn_types[{index}] = {ffn!r}")
+        if self.ffn is not None:
+            self.ffn_norm = RMSNorm(cfg.hidden_size, cfg.rms_eps,
+                                    dtype=cfg.dtype)
 
     # the residual stream is float32 whatever the weights are (as in the
     # latent family: text/models/latent_moe.py says why)
-    def _operand(self, norm, x):
-        """``norm(x)`` rounded to the weights' dtype, as a product's
-        operand."""
-        return unwrap(norm(x)).astype(unwrap(self.ffn.w_gate).dtype)
+    @staticmethod
+    def _operand(norm, x):
+        """``norm(x)`` rounded to the weights' dtype (the norm's own gain
+        is one of them), as a product's operand."""
+        return unwrap(norm(x)).astype(unwrap(norm.weight).dtype)
 
     # Each half of the layer, its norm and its residual add included, lies
     # under the name a trace is read by (docs/METRICS.md).
     def _ffn(self, h, live):
+        if self.ffn is None:
+            return h
         moe = isinstance(self.ffn, DroplessMoE)
         with jax.named_scope("experts" if moe else "mlp"):
             u = self._operand(self.ffn_norm, h)
@@ -319,22 +401,27 @@ class HybridDecoderLayer(nn.Layer):
             return h + unwrap(y).astype(jnp.float32)
 
     def _mixer_scope(self):
-        return jax.named_scope("short_conv" if isinstance(
-            self.mixer, ShortConv) else "attention")
+        return jax.named_scope(
+            {ShortConv: "short_conv", Mamba2Mixer: "state_space"}.get(
+                type(self.mixer), "attention"))
 
     def forward_cached(self, x, cache, pos, start, write_rows, live):
-        with self._mixer_scope():
-            a, cache = self.mixer.forward_cached(
-                self._operand(self.operator_norm, x), cache, pos, start,
-                write_rows)
-            h = x + a.astype(jnp.float32)
-        return self._ffn(h, live), cache
+        """``cache`` is None for a layer without a mixer, and comes back
+        so."""
+        if self.mixer is not None:
+            with self._mixer_scope():
+                a, cache = self.mixer.forward_cached(
+                    self._operand(self.operator_norm, x), cache, pos, start,
+                    write_rows)
+                x = x + a.astype(jnp.float32)
+        return self._ffn(x, live), cache
 
     def forward(self, x):
-        with self._mixer_scope():
-            a = unwrap(self.mixer(self._operand(self.operator_norm, x)))
-            h = x + a.astype(jnp.float32)
-        return self._ffn(h, None)
+        if self.mixer is not None:
+            with self._mixer_scope():
+                a = unwrap(self.mixer(self._operand(self.operator_norm, x)))
+                x = x + a.astype(jnp.float32)
+        return self._ffn(x, None)
 
 
 # what ``decode_counts`` returns, in order (the latent family's names: the
@@ -348,7 +435,7 @@ class HybridConvDecoder(nn.Layer):
     that installs its own weights builds with a constant initializer."""
 
     # a slot loop's step hands over its live rows: a row that is not fed
-    # keeps its conv state
+    # keeps its state (a convolution's inputs, a state-space layer's sum)
     cached_forward_takes_rows = True
     decode_count_names = DECODE_COUNT_NAMES
 
@@ -367,10 +454,14 @@ class HybridConvDecoder(nn.Layer):
             HybridDecoderLayer(cfg, i, weight_attr)
             for i in range(len(cfg.layer_types))])
         self.norm = RMSNorm(cfg.hidden_size, cfg.rms_eps, dtype=cfg.dtype)
+        # an untied head lies as the table does, ``[vocab, hidden]``
+        self.lm_head = None if cfg.tie_embeddings else _mat(
+            self, (cfg.vocab_size, cfg.hidden_size), weight_attr, cfg.dtype)
         self._counts = None
 
     def _logits(self, h):
-        table = unwrap(self.embed.weight)
+        table = unwrap(self.embed.weight if self.lm_head is None
+                       else self.lm_head)
         with jax.named_scope("head"):
             return jnp.einsum("bth,vh->btv",
                               unwrap(self.norm(h)).astype(table.dtype), table,
@@ -384,15 +475,22 @@ class HybridConvDecoder(nn.Layer):
         return Tensor(self._logits(h))
 
     # -- incremental decoding --------------------------------------------------
+    def _mixers(self):
+        return [l.mixer for l in self.layers if l.mixer is not None]
+
     def cache_spec(self, max_len):
-        """Per layer, what it keeps: ``kv`` ring planes as long as the
-        session, or a ``conv_state`` plane without columns."""
-        return [l.mixer.cache_spec(max_len) for l in self.layers]
+        """Per layer that has a mixer, what it keeps: ``kv`` ring planes
+        as long as the session, or planes without columns (``conv_state``,
+        ``ssm_state``)."""
+        return [m.cache_spec(max_len) for m in self._mixers()]
 
     def init_cache(self, batch, max_len, dtype=None):
+        """One cache a layer that has a mixer, in ``dtype`` (the weights'
+        by default) but for what a mixer keeps in a dtype of its own (a
+        state-space layer's float32 state)."""
         if dtype is None:
             dtype = str(self.embed.weight.dtype)
-        return [l.mixer.gen_cache(batch, max_len, dtype) for l in self.layers]
+        return [m.gen_cache(batch, max_len, dtype) for m in self._mixers()]
 
     def forward_cached(self, input_ids, cache, cache_position,
                        start_positions, write_rows=None):
@@ -414,10 +512,13 @@ class HybridConvDecoder(nn.Layer):
             if rows is not None:
                 live = live & rows[:, None]
         zero = jnp.int32(0)
-        counts, new = (zero, zero, zero), []
-        for layer, c in zip(self.layers, cache):
-            h, c = layer.forward_cached(h, c, pos, start, rows, live)
-            new.append(c)
+        counts, new, kept = (zero, zero, zero), [], iter(cache)
+        for layer in self.layers:
+            h, c = layer.forward_cached(
+                h, None if layer.mixer is None else next(kept), pos, start,
+                rows, live)
+            if c is not None:
+                new.append(c)
             if isinstance(layer.ffn, DroplessMoE):
                 a, b, m = layer.ffn.last_counts
                 counts = (counts[0] + a, counts[1] + b,

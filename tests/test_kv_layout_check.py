@@ -155,8 +155,9 @@ _SHAPES = {(3, 1, 2, 64), (3, 1, 64, 128)}
 def test_state_planes_are_reported_apart(tool, step):
     facts = tool.inspect(_hybrid_hlo(step), _SHAPES, {(3, 1, 2, 64)})
     assert facts["state_planes"] == [{"shape": [3, 1, 2, 64],
+                                      "dtype": "bf16",
                                       "minor_to_major": [3, 2, 1, 0],
-                                      "count": 1}]
+                                      "count": 1, "bytes": 3 * 2 * 64 * 2}]
     assert facts["planes"] == [{"shape": [3, 1, 64, 128],
                                 "minor_to_major": [3, 2, 1, 0], "count": 1}]
     assert facts["state_planes_aliased"] == 1
@@ -171,7 +172,41 @@ def test_state_planes_are_reported_apart(tool, step):
             "minor_to_major": [3, 2, 1, 0], "unaligned_index_dims": [0],
             "on_minor_most": False, "count": 1}]
     assert facts["state_plane_copies"] == facts["whole_plane_copies"] == 0
+    assert facts["state_row_relayout_copies"] == 0
     assert tool._faults("step", facts) == []
+
+
+@pytest.mark.parametrize("relaid", [0, 2], ids=["rows-as-they-lie",
+                                                "rows-relaid"])
+def test_a_float32_summed_state_beside_bfloat16_planes(tool, relaid):
+    """A state-space layer keeps a float32 ``[S, heads, head_dim, state]``
+    plane beside bfloat16 ones (ISSUE 40): reported with its dtype and its
+    bytes, aliased like any plane; a layout-changing copy of ONE row of it
+    (the chunk computing on the row it cut out) is counted and a fault, a
+    copy that keeps the layout is neither."""
+    state = "f32[3,4,16,16]{3,2,1,0:T(8,128)}"
+    row = "f32[1,4,16,16]{3,2,1,0:T(8,128)}"
+    turned = "f32[1,4,16,16]{2,3,1,0:T(8,128)}"
+    extra = (f"  %slice.1 = {row} fusion(%cache_0__0_.1), kind=kLoop\n"
+             f"  %copy.1 = {row} copy(%slice.1)\n")
+    if relaid:
+        extra += (f"  %copy.2 = {turned} copy(%slice.1)\n"
+                  f"  %copy.3 = {row} copy(%copy.2)\n")
+    text = _hybrid_hlo(step=False, extra=extra).replace(_STATE, state) \
+        .replace("bf16[1,1,2,64]", "f32[1,4,16,16]")
+    shapes = {(3, 4, 16, 16), (3, 1, 64, 128)}
+    facts = tool.inspect(text, shapes, {(3, 4, 16, 16)})
+    assert facts["state_planes"] == [{
+        "shape": [3, 4, 16, 16], "dtype": "f32",
+        "minor_to_major": [3, 2, 1, 0], "count": 1,
+        "bytes": 3 * 4 * 16 * 16 * 4}]
+    assert facts["state_planes_aliased"] == 1
+    assert facts["planes_aliased"] == facts["planes_total"] == 2
+    assert facts["state_row_relayout_copies"] == relaid
+    assert facts["row_relayout_copies"] == 0         # counted apart
+    faults = tool._faults("chunk", facts)
+    assert len(faults) == bool(relaid)
+    assert all("row of a state plane" in f for f in faults)
 
 
 def test_a_state_plane_copied_whole_is_a_fault(tool):

@@ -1,0 +1,60 @@
+"""``tools/slot_replay.py``: the slot loop's admission rules replayed on the
+host for the state-space cell's own traffic.  The numbers are those PERF.md
+section 6 (PR 40) gives as predicted, beside what the chip then read (62
+answers and 45.5% of the slot-steps prefilling inside the cold round; the
+round's end 40.0 s; 117-124 answers in a steady window)."""
+import json
+import os
+import sys
+
+import pytest
+
+ROOT = os.path.dirname(os.path.dirname(os.path.abspath(__file__)))
+if ROOT not in sys.path:
+    sys.path.insert(0, ROOT)
+
+from tools import slot_replay                                  # noqa: E402
+
+
+def _cell():
+    return (slot_replay._load("configs", "nemotron-3-nano-ep8-serve"),
+            slot_replay._load("traffic", "reason1k-closed-2S"))
+
+
+@pytest.mark.parametrize("ramp,first_seen,start,window,by_34,prefilling", [
+    (8.0, 1, 1024, 62, 31, 44.8),     # the window inside the cold round
+    (8.0, 2, 1024, 62, 31, 44.8),     # the race for the first admission
+    (52.0, 1, 1024, 123, 92, 0.6),    # the cell as committed: steady
+    (8.0, 27, 512, 31, 21, 71.4),     # ... were a one-chunk prompt seen first
+], ids=["cold-round", "two-seen", "steady", "one-chunk-first"])
+def test_the_replay_of_the_state_space_cell(ramp, first_seen, start, window,
+                                            by_34, prefilling):
+    config, traffic = _cell()
+    out = slot_replay.replay(config, traffic, step_s=0.029, chunk_s=0.0435,
+                             ramp_s=ramp, first_seen=first_seen)
+    assert out["start"] == start
+    assert len(out["answers"]) == window
+    assert sum(1 for a in out["answers"] if a <= 34.0) == by_34
+    assert out["prefilling_pct"] == pytest.approx(prefilling, abs=0.1)
+    assert out["answers"] == sorted(out["answers"])
+    if start == 1024:
+        # 1,024 steps from column 1,024 to 2,048 and the first round's chunks
+        assert out["round_end_s"] == pytest.approx(39.7, abs=0.1)
+
+
+def test_the_committed_ramp_steps_over_the_replayed_round():
+    """``ramp_s`` is the round's end plus a median answer's seconds: at
+    least ten seconds past what the replay gives at the measured step."""
+    config, traffic = _cell()
+    out = slot_replay.replay(config, traffic, step_s=0.029, chunk_s=0.0435)
+    assert traffic["ramp_s"] >= out["round_end_s"] + 10
+    assert out["prefilling_pct"] < 2
+    # the job ends inside the window with a tenth to spare
+    assert out["answers"][int(traffic["job_requests"]) - 1] * 1.1 < 45.0
+
+
+def test_the_tool_prints_one_line(capsys):
+    slot_replay.main(["nemotron-3-nano-ep8-serve", "reason1k-closed-2S",
+                      "--step-ms", "29", "--chunk-ms", "43.5", "--ramp", "8"])
+    line = json.loads(capsys.readouterr().out)
+    assert line["answers_in_window"] == 62 and line["answers_by"] == 31

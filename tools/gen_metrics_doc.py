@@ -75,6 +75,7 @@ index: 48 layers of `attention` add up.
 | `mlp` | `TransformerEncoderLayer.forward`, `SwiGLU.forward`, the decoders' dense layers | a layer's dense feed-forward half with its norm and residual add; a shared expert |
 | `experts` | `DroplessMoE.forward`, the decoders' expert layers; the router as `experts/router` | routed experts with their norm and residual add |
 | `short_conv` | `ShortConv.forward_cached`, `HybridDecoderLayer` (a convolution layer) | the gated short convolution with its norm and residual add |
+| `state_space` | `HybridDecoderLayer` (a state-space layer); inside it `Mamba2Mixer` enters `state_space/conv`, `state_space/scan` (a block of several tokens: the chunked scan) and `state_space/update` (one token: the step's update) | a Mamba-2 layer with its norm, projections, gate, group norm and residual add; `conv` the causal convolution over the block and the inputs kept before it, `scan` / `update` the recurrence itself.  `benchmark/scope_buckets.json` lists no such component, so the accepted `device_scoped_pct.*` counts these layers as unscoped; `benchmark/layer_metrics/_state_space_scope.py` reads them by reducing the capture once more with these four components listed (`step_state_space_ms`, `chunk_state_space_ms`, `state_update_roofline_pct`, `ssm_scan_roofline_pct`) |
 | `head` | the logits product of every model, BERT's pooler and heads; `head/sample` in `Generator._build_step` | vocabulary product; the step's `argmax` / `where` |
 | `loss`, `optimizer`, `cast_params` | `TrainStep._loss_of` and the models' own loss, the optimizer update of `TrainStep._build_step`, `TrainStep._cast_compute` | the training step's own parts; backward is told from forward by the `transpose(jvp(..))` wrapper JAX writes itself |
 
@@ -121,6 +122,19 @@ columns searched, of those the planes have), `chunk_selector_columns_searched`
 for a run whose contexts never pass `index_topk` and 1 for one whose every
 dispatch needs the whole plane; only a loop over a model with a selecting
 layer has them.
+
+A loop over a model that keeps a SUMMED state (a layer whose `cache_spec`
+kind is `ssm_state`: a state-space layer's float32 state, which stands for
+every earlier token of its row) counts, beside `state_rows_held`, what fed
+those states, by host arithmetic on the dispatch's `pos` and `start`
+(`_tally_columns`), committed with `steps` and zeroed by `reset_stats()`:
+`ssm_rows_updated`, the live rows of the steps (each updates its state once
+a state-space layer: rows, not rows x layers), and `chunk_ssm_tokens`, the
+valid tokens the prefill chunks scanned into a state (left padding passes
+the state through and is not counted).  `state_update_roofline_pct` reads
+the first against the device time under `state_space/update`,
+`ssm_scan_roofline_pct` the second against `state_space/scan`, each with
+the family's least bytes and operations from `benchmark/counts/`.
 
 To read a capture of your own: `paddle_tpu.profiler.Profiler` writes
 `program_scopes.json` into `profiler_result_dir` when a record window

@@ -22,13 +22,17 @@ program) and prints, from ``compiled.as_text()``:
     has another layout (the relayout a lane-major plane forces on the
     chunk's block write);
   * the STATE planes apart (a layer whose ``cache_spec`` has no columns: a
-    short convolution's last inputs, ``[S, 1, L-1, hidden]``): their
+    short convolution's last inputs, ``[S, 1, L-1, hidden]``, and a
+    state-space layer's summed state, float32 ``[S, heads, head_dim,
+    state]`` beside planes of another dtype): their dtype, bytes and
     layout, whether each is aliased in place, which dimension carries the
     index of a write into one (the chunk splices ONE row, index on the
     row dimension, where the compiler leaves that splice an instruction
     of its own; the step writes no slice: it hands back the whole plane
-    with the rows it did not feed as they were), and the copies of a whole
-    state plane in either program;
+    with the rows it did not feed as they were), the copies of a whole
+    state plane in either program, and the layout-changing copies of ONE
+    row of a state plane (``state_row_relayout_copies``: what the chunk
+    pays to compute on the row it cut out);
   * the ``while`` loops of each program (the decode attention reads the
     planes in column blocks under one, ``cached_attention``; the step's
     line says how wide a block is): whether a plane enters one as a
@@ -245,9 +249,12 @@ def inspect(hlo_text, plane_shapes, state_shapes=frozenset()):
         if op == "parameter" and 'op_name="cache[' in line \
                 and dims in plane_shapes:
             planes[name] = int(re.search(r"parameter\((\d+)\)", line)[1])
-    layouts, state_layouts = (collections.Counter(
+    layouts = collections.Counter(
         (instrs[n][0], instrs[n][1]) for n in planes
-        if (instrs[n][0] in state_shapes) == kept) for kept in (False, True))
+        if instrs[n][0] not in state_shapes)
+    state_layouts = collections.Counter(
+        (instrs[n][0], instrs[n][1], _INSTR.match(instrs[n][4])["dtype"])
+        for n in planes if instrs[n][0] in state_shapes)
     aliased = _aliased_params(hlo_text)
     rows = {(1,) + s[1:] for s in plane_shapes}
     writes, state_writes = collections.Counter(), collections.Counter()
@@ -261,6 +268,7 @@ def inspect(hlo_text, plane_shapes, state_shapes=frozenset()):
         (state_writes if dims in state_shapes or dims in state_rows
          else writes)[(layout, unaligned)] += 1
     plane_copies, state_copies, row_relayouts = [], [], []
+    state_row_relayouts = []
     for name, (dims, layout, op, args, _line) in instrs.items():
         if op not in ("copy", "transpose") or not args:
             continue
@@ -269,9 +277,9 @@ def inspect(hlo_text, plane_shapes, state_shapes=frozenset()):
             state_copies.append(name)
         elif dims in plane_shapes:
             plane_copies.append(name)
-        elif dims in rows and dims not in state_rows \
-                and src is not None and src[1] != layout:
-            row_relayouts.append(name)
+        elif dims in rows and src is not None and src[1] != layout:
+            (state_row_relayouts if dims in state_rows
+             else row_relayouts).append(name)
     loops, loop_copies = _while_plane_copies(hlo_text, instrs, plane_shapes)
     def as_writes(counter):
         return [{"minor_to_major": list(l), "unaligned_index_dims": list(u),
@@ -279,15 +287,18 @@ def inspect(hlo_text, plane_shapes, state_shapes=frozenset()):
                 for (l, u), c in counter.items()]
 
     state = {} if not state_shapes else {
-        "state_planes": [{"shape": list(s), "minor_to_major": list(l),
-                          "count": c} for (s, l), c in state_layouts.items()],
+        "state_planes": [{"shape": list(s), "dtype": d,
+                          "minor_to_major": list(l), "count": c,
+                          "bytes": c * _BYTES.get(d, 4) * math.prod(s)}
+                         for (s, l, d), c in state_layouts.items()],
         "state_planes_aliased": sum(
             1 for n, p in planes.items()
             if instrs[n][0] in state_shapes and p in aliased),
         # no write of a slice among ENTRY's instructions: the program hands
         # the plane back whole, or its splice of a row is fused
         "state_writes": as_writes(state_writes) or "none in ENTRY",
-        "state_plane_copies": len(state_copies)}
+        "state_plane_copies": len(state_copies),
+        "state_row_relayout_copies": len(state_row_relayouts)}
     return {
         **state,
         "while_loops": loops,
@@ -318,6 +329,9 @@ def _faults(what, facts):
     if facts.get("state_plane_copies"):
         out.append(f"{what}: {facts['state_plane_copies']} copies or "
                    "transposes of a whole state plane")
+    if facts.get("state_row_relayout_copies"):
+        out.append(f"{what}: {facts['state_row_relayout_copies']} "
+                   "layout-changing copies of a row of a state plane")
     for w in facts.get("state_writes") or ():
         if isinstance(w, dict) and w["on_minor_most"]:
             out.append(f"{what}: {w['count']} writes into a state plane "
