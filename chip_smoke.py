@@ -472,7 +472,8 @@ def phase_layouts(size: Size, seed: int = 0) -> dict:
         for k in range(steps):
             cache, logits, finished, tok = step(
                 *gen._state_args(), cache, logits, jnp.asarray(start),
-                finished, jnp.asarray(active), jnp.int32(T + k))
+                finished, jnp.asarray(active), jnp.zeros((S,), bool),
+                jnp.int32(T + k))
             toks.append(int(tok[0]))
         outs.append((np.asarray(last), toks, np.asarray(logits[0])))
     (last_p, toks_p, log_p), (last_r, toks_r, log_r) = outs
@@ -552,7 +553,8 @@ def phase_hybrid(size: Size, seed: int = 0) -> dict:
         for k in range(steps):
             cache, logits, finished, tok = step(
                 *gen._state_args(), cache, logits, jnp.asarray(start),
-                finished, jnp.asarray(active), jnp.int32(n * T + k))
+                finished, jnp.asarray(active), jnp.zeros((S,), bool),
+                jnp.int32(n * T + k))
             toks.append(int(tok[1]))
             got.append(np.asarray(logits[1]))
         want = np.asarray(ref.served_logits(cfg, view, prompt,

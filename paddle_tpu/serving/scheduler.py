@@ -63,8 +63,10 @@ SLOTS_JOINED = _registry().counter(
     labels=("model",))
 SLOTS_RETIRED = _registry().counter(
     "decode_slots_retired_total",
-    "Rows retired from the slot loop (eos or per-request token budget) "
-    "— retirement frees the slot the same step.",
+    "Rows retired from the slot loop (eos or per-request token budget), "
+    "counted when their last token is read: a row that ends by its "
+    "budget left its slot when that step was dispatched, one that took "
+    "the end token leaves it now.",
     labels=("model",))
 SLOT_STEPS = _registry().counter(
     "decode_slot_steps_total",

@@ -24,6 +24,51 @@ that reads them: the driver thread, the only one that feeds the device,
 fetches neither, so it dispatches the next step BEHIND a final chunk
 and not after it.
 
+**One step in flight.**  Nothing a plain step needs from the host hangs
+on the step before it: the tokens are chosen on the device from the
+device's logits, ``finished`` goes from one step's output into the
+next's input as the device array it is, the cache is donated through,
+and ``pos`` is a host counter.  So an iteration of the plain loop is
+admit -> chunks -> activate -> DISPATCH step k+1 -> only then READ step
+k's tokens (and the counts of the chunks dispatched before step k), emit,
+commit the counters, retire: the device holds its next programs while the
+driver thread does its own work.  Which side the loop then waits on,
+``counters["steps_read_ready"]`` says: a read that waits (the usual case
+where a step outlasts an iteration's host work) means the device was busy
+with step k while step k+1 lay queued behind it; a read that finds step
+k's tokens already there means the driver was the slower side, and the
+device had only the one queued step to live on.  The depth is one, always,
+for every model the plain loop serves.  What follows from it:
+
+  * **a row that reaches ``max_new`` leaves at dispatch** — every
+    generating row emits exactly one token a plain step, so the host knows
+    when it dispatches step k which rows end there.  It hands step k+1 a
+    vacated row for them (``active`` false, ``start = C``) and frees the
+    slot for ``_admit`` in the very next iteration, as a loop that read
+    before it dispatched would; the row's record waits in ``_leaving`` for
+    its last token (a session row's snapshot is pulled behind step k,
+    before a new occupant can write the row), and its future resolves
+    when step k is read;
+  * **a row that takes the end token costs one masked slot-step** — the
+    host learns of it a step late, so step k+1 still lists the row as
+    ``active``; the step itself knows (``finished``, on the device) and
+    passes the row by.  ``counters["slot_steps_retire_lag"]`` counts
+    these; the slot is free one iteration later than it would be;
+  * **whatever reads or rewrites loop state settles the step in flight
+    first** (``_settle``): ``_drive`` when no slot is occupied, which is
+    before it idles, ends (``close()``) or restarts the ring;
+    ``_fast_forward`` (no row generates: nothing to dispatch behind it);
+    and before ``_do_park`` parks sessions (outside the lock: the read
+    commits and replies under it).  A step whose
+    DISPATCH raises fails its rows only after the step before it has
+    delivered its tokens; a step whose READ raises fails every row, the
+    rows in ``_leaving`` too;
+  * **the speculative loop does not run ahead** — its ``ncommit`` decides
+    ``pos``, and ``pos`` decides which chunks and activations come before
+    the next step, so the host has to read a step before it can dispatch
+    another.  That is a property of the program the loop was built for
+    (``self._spec``), not a setting.
+
 The scheduling invariants that make slot reuse BIT-EXACT against a
 per-request ``generate()`` of the same prompt:
 
@@ -42,7 +87,10 @@ per-request ``generate()`` of the same prompt:
     chunks, scheduled after the last garbage write — see
     ``_dispatch_chunks``), below the row's ``start`` (never visible),
     or at ``>= act`` where the row's own active dispatches rewrite it
-    before any commit exposes it;
+    before any commit exposes it.  "After" is DISPATCH order
+    throughout: the device runs its programs in the order the driver
+    thread dispatched them, so a chunk dispatched after a step rewrites
+    what that step wrote, whether or not the step's tokens were read;
   * **planned-activation chunk schedule** — a prompt of ``Lp`` tokens
     left-pads into ``n = ceil(Lp/T)`` chunks.  Columns are PER-ROW
     state, so the prompt block is free to end wherever the row starts
@@ -53,8 +101,8 @@ per-request ``generate()`` of the same prompt:
     which ``pos > act - n + k`` — one chunk per iteration over the
     last ``n`` iterations before activation, so a long prompt costs
     ``n`` iterations of everyone's token cadence, not ``Lp``, AND the
-    chunk rewrite of each column lands strictly after the last decode
-    step that could garbage it (the no-blend invariant above; the
+    chunk rewrite of each column is dispatched strictly after the last
+    decode step that could garbage it (the no-blend invariant above; the
     interval algebra: chunk ``k`` covers ``[act-Pb+kT, act-Pb+(k+1)T)``
     and every step from that iteration on writes columns
     ``>= act-n+k+1``; overlap would need ``n(T-1) < (k+1)(T-1)``,
@@ -127,13 +175,22 @@ What the loop measures about itself, always on:
     FLAGS_trace, the children of the request's root span;
   * **every slot-step** — each decode step counts its ``S`` slots as
     emitting, prefilling, drain-blocked or without demand
-    (``counters["slot_steps_*"]``, summing to ``steps x S``);
-  * **the model's own counts** — a step's ride its token read-back; a
-    chunk's are read behind the NEXT step's read-back, where they wait
-    for nothing (the device runs its programs in order), and committed
-    with that step: the ``chunk_*`` counters run one step behind the
-    chunks, and a chunk that no step follows is counted when the loop
-    closes;
+    (``counters["slot_steps_*"]`` by ``_SLOT_STATES``, summing to
+    ``steps x S``), when its tokens are read.  A row the step passed by
+    because it had taken the end token emitted nothing: it counts with the
+    empty slots, and under ``["slot_steps_retire_lag"]`` beside them;
+  * **which side an iteration waited on** — ``["steps_read_ready"]`` of
+    ``["steps"]``: the plain steps whose read-back waited less than
+    ``READ_READY_S``, i.e. found the tokens already on the host's side:
+    the driver's own work outlasted the device's step.  Near 0 where the
+    device is the bottleneck, near ``steps`` where the host is
+    (``stats()["phase_s"]["step_fetch"]`` has the seconds waited);
+  * **the model's own counts** — a step's ride its token read-back; the
+    counts of the chunks dispatched BEFORE a step are read behind that
+    step's tokens, where they wait for nothing (the device runs its
+    programs in order), and committed with that step, like everything
+    the driver tallied before it dispatched the step: a chunk that no
+    step follows is counted when the loop closes;
   * **the span a step's attention reads** — the step program attends
     in column blocks from the oldest generating row's ``start`` to the
     shared frontier (``cached_attention``); ``counters["attn_blocks_
@@ -167,10 +224,13 @@ _EMPTY, _PREFILL, _GEN = 0, 1, 2
 
 # the driver thread's phases in loop order: the keys of
 # ``stats()["phase_s"]``.  ``idle_wait`` (nothing live) and ``step_fetch``
-# (the step's tokens) wait; ``chunk_fetch`` reads the chunks' counts
-# behind a step's tokens, where they have arrived (the speculative loop
-# waits there for a final chunk's logits); the others are the host's own
-# work, the dispatch of a row's activation write under ``activate``.
+# wait: ``step_fetch`` is the wait for the tokens of the step BEFORE the
+# one just dispatched (the plain loop keeps one step in flight; in the
+# speculative loop, of the step just dispatched).  ``chunk_fetch`` reads
+# the chunks' counts behind a step's tokens, where they have arrived (the
+# speculative loop waits there for a final chunk's logits); the others are
+# the host's own work, the dispatch of a row's activation write under
+# ``activate``.
 # ``restore`` (a row's cached blocks pushed into its columns) and
 # ``publish`` (an activated row's new blocks pulled for the prefix cache,
 # and its bookkeeping) are entered only by a loop that has that cache.
@@ -185,6 +245,11 @@ REQUEST_PHASES = ("handoff", "admit_wait", "prefill", "decode", "reply_hold",
                   "arrival_ttft", "total")
 # what a slot is doing at a decode step
 _SLOT_STATES = ("emitting", "prefilling", "drain_blocked", "no_demand")
+# a read-back that waits less than this found its step's tokens on the
+# host's side already (``steps_read_ready``): a twentieth of the shortest
+# device step of any cell of the benchmark, ten times a fetch that waits
+# for nothing
+READ_READY_S = 0.5e-3
 
 
 @dataclass
@@ -241,7 +306,8 @@ class SlotRequest:
 
 class _Slot:
     __slots__ = ("state", "req", "chunks", "next_chunk", "act",
-                 "start", "emitted", "_act_logits", "restore", "pin")
+                 "start", "emitted", "sent", "row", "_act_logits",
+                 "restore", "pin")
 
     def __init__(self):
         self.state = _EMPTY
@@ -252,8 +318,25 @@ class _Slot:
         self.act = 0                    # planned activation position
         self.start = 0
         self.emitted: List[int] = []
+        self.sent = 0                   # ... and those in the step in flight
+        self.row = None                 # a leaving session row, pulled
         self.restore: List[tuple] = []  # pending (block_tree, base) pushes
         self.pin = None                 # prefix-cache pin held until pushed
+
+
+@dataclass
+class _InFlight:
+    """The plain step the device holds while the driver works: what its
+    read-back needs, as it was when the step was dispatched."""
+
+    tok: Any            # device [S (+ n)]: the tokens, then the model's counts
+    rows: List[tuple]   # (row, its _Slot) of the rows handed over as generating
+    pos: int            # the column it writes
+    split: tuple        # its S slots by _SLOT_STATES
+    blocked: bool       # ... and whether its empty slots had demand
+    held: int           # rows it passed by between two of their chunks
+    chunk_counts: list  # handles of the chunks dispatched before it
+    tally: dict         # what the driver tallied before it
 
 
 class SlotLoop:
@@ -355,6 +438,11 @@ class SlotLoop:
         self._cond = threading.Condition()
         self._pending: "deque[SlotRequest]" = deque()       # guarded-by: _cond
         self._slots = [_Slot() for _ in range(self.S)]  # driver-thread-owned
+        # driver-thread-owned: the plain step whose tokens are not read
+        # yet, and the rows that left their slots with their last token in
+        # it (a row that reaches max_new leaves when the step is dispatched)
+        self._inflight: Optional[_InFlight] = None
+        self._leaving: List[_Slot] = []
         self._closed = False                                # guarded-by: _cond
         self._dead: Optional[BaseException] = None          # guarded-by: _cond
         self._thread: Optional[threading.Thread] = None     # guarded-by: _cond
@@ -390,6 +478,8 @@ class SlotLoop:
                                  chunk_selector_columns_plane=0)
         if self._wrap_lens:
             self.counters["window_wraps"] = 0
+        if not self._spec:
+            self.counters.update(steps_read_ready=0, slot_steps_retire_lag=0)
         if self._state_layers:
             self.counters["state_rows_held"] = 0
         if self._ssm_layers:
@@ -442,7 +532,9 @@ class SlotLoop:
     def _reset_session(self):
         """Fresh ring session: position 0, zero planes (stale data is
         invisible behind the validity windows, but a cold loop has no
-        planes yet), neutral per-row vectors."""
+        planes yet), neutral per-row vectors.  ``_finished`` is the
+        host's own in the speculative loop; in the plain loop it is what
+        the last step handed back, a device array that is never read."""
         self.pos = 0
         self._cache = self._gen.init_slot_cache(self.S, self.C)
         self._start = np.full((self.S,), self.C, np.int32)
@@ -453,6 +545,9 @@ class SlotLoop:
         else:
             vocab = self._gen._vocab_size()
             self._logits = np.zeros((self.S, vocab), np.float32)
+            # the rows activated since the last step was dispatched
+            self._no_rows = np.zeros((self.S,), bool)
+            self._joined = self._no_rows
 
     def _need(self, prompt_len: int, max_new: int) -> int:
         """Ring columns a request consumes: padded chunk span + its own
@@ -597,8 +692,9 @@ class SlotLoop:
                 _tracing.child(req.trace, name, t0, t1)
 
     def close(self):
-        """Stop the driver once in-flight work drains; pending requests
-        not yet admitted fail with UnavailableError."""
+        """Stop the driver once in-flight work drains (the rows in their
+        slots and the step whose tokens are not read yet); pending
+        requests not yet admitted fail with UnavailableError."""
         with self._cond:
             self._closed = True
             self._cond.notify_all()
@@ -636,6 +732,11 @@ class SlotLoop:
         died = False
         try:
             while True:
+                if all(s.state == _EMPTY for s in self._slots):
+                    # nothing to dispatch behind the step in flight (its
+                    # rows left by count): read it before the loop idles,
+                    # ends, or restarts the ring
+                    self._settle()
                 self._phase("admit")
                 with self._cond:
                     while (not self._pending
@@ -660,9 +761,16 @@ class SlotLoop:
                             "slot loop closed before this request was "
                             "admitted"))
                         return
-                    park = self._park_req
-                    self._park_req = None
-                    if park is not None:
+                    parking = self._park_req is not None
+                if parking:
+                    # a row is parked with every token it has: read the
+                    # step in flight first, outside the lock (the read
+                    # commits and replies under it)
+                    self._settle()
+                with self._cond:
+                    if parking:
+                        # (a request that came since waits an iteration)
+                        park, self._park_req = self._park_req, None
                         self._do_park(park)
                     self._commit_phases()
                     self._blocked = self._admit()
@@ -671,6 +779,7 @@ class SlotLoop:
                 self._phase("activate")
                 self._activate()
                 if not any(s.state == _GEN for s in self._slots):
+                    self._settle()
                     self._fast_forward()
                     continue
                 self._decode_step()
@@ -681,16 +790,21 @@ class SlotLoop:
                 if self._park_req is not None:
                     self._park_req[0].set()
                     self._park_req = None
-                for s in self._slots:
+                # a step in flight is dropped unread: whatever failed,
+                # the tokens behind it are no longer in order
+                self._inflight = None
+                for s in self._leaving + self._slots:
                     if s.req is not None and not s.req.future.done():
                         s.req.future.set_exception(e)
                     s.state, s.req = _EMPTY, None
+                self._leaving = []
                 self._fail_pending(e)
         finally:
             if not died:
                 # chunks that no step followed (their row was parked):
                 # their counts belong to the window all the same
-                self._read_chunk_counts()
+                self._read_chunk_counts(self._chunk_counts)
+                self._chunk_counts = []
             self._phase(None)
             with self._cond:
                 self._commit_tally()
@@ -752,8 +866,9 @@ class SlotLoop:
                     - len(head.preseed) + self._gamma > self.C:
                 if all(s.state == _EMPTY for s in self._slots) \
                         and self.pos > 0:
-                    # whole loop idle: restart the ring session (windows
-                    # restart, planes stay — stale columns are invisible)
+                    # whole loop idle (so _drive has read the step in
+                    # flight): restart the ring session (windows restart,
+                    # planes stay — stale columns are invisible)
                     self.pos = 0
                     self.counters["session_resets"] += 1
                     self._m_resets.inc()
@@ -840,6 +955,7 @@ class SlotLoop:
         slot.req = head
         slot.next_chunk = 0
         slot.emitted = list(head.preseed)
+        slot.sent = len(head.preseed)
         slot.state = _PREFILL
         self.counters["joined"] += 1
         self._m_joined.inc()
@@ -854,14 +970,16 @@ class SlotLoop:
         bearing, not cosmetic — the step program writes unmasked
         garbage into inactive rows' lanes (dead-column discipline, see
         the module docstring), and dispatching chunk ``k`` only after
-        the step at ``act - n + k`` has retired guarantees the chunk's
-        column block is rewritten strictly after the last step that
-        could garbage it.  Chunk writes carry their own column base,
-        independent of ``pos`` — a speculative stride that lands on an
-        activation boundary early just bursts the remaining chunks
-        back-to-back before the row activates (catch-up dispatches are
-        safe: running a chunk LATER than planned only moves it further
-        from the garbage frontier)."""
+        the step at ``act - n + k`` has been DISPATCHED (``pos`` moves
+        when a step is dispatched, and the device runs its programs in
+        that order; whether the step's tokens were read plays no part)
+        guarantees the chunk's column block is rewritten strictly after
+        the last step that could garbage it.  Chunk writes carry their
+        own column base, independent of ``pos`` — a speculative stride
+        that lands on an activation boundary early just bursts the
+        remaining chunks back-to-back before the row activates (catch-up
+        dispatches are safe: running a chunk LATER than planned only
+        moves it further from the garbage frontier)."""
         for i, slot in enumerate(self._slots):
             if slot.state != _PREFILL:
                 continue
@@ -904,18 +1022,17 @@ class SlotLoop:
                     # driver goes on to dispatch behind the chunk
                     slot._act_logits = logits
 
-    def _read_chunk_counts(self):
-        """Driver thread: tally the counts of the chunks dispatched
-        since the last reading.  Called where that waits for nothing:
-        behind a step's read-back (the device runs its programs in
-        order, so every chunk dispatched before the step has finished),
-        which commits them with that step; and when the loop ends."""
-        if not self._chunk_counts:
+    def _read_chunk_counts(self, handles):
+        """Driver thread: tally the counts of the chunks behind
+        ``handles``.  Called where that waits for nothing: behind the
+        read-back of the step that was dispatched after them (the device
+        runs its programs in order, so each of them has finished), which
+        commits them with that step; and when the loop ends."""
+        if not handles:
             return
         self._phase("chunk_fetch")
-        for h in self._chunk_counts:
+        for h in handles:
             self._tally_counts(np.asarray(h), chunk=True)
-        self._chunk_counts = []
 
     def _tally_columns(self, cols, start, chunk=False):
         """Driver thread: the columns at which a dispatch appends tokens
@@ -957,15 +1074,15 @@ class SlotLoop:
         for n in self._wrap_lens:
             add("window_wraps", int(((cols > 0) & (cols % n == 0)).sum()))
 
-    def _tally_blocks(self, starts):
+    def _tally_blocks(self, starts, pos):
         """Driver thread: the column blocks the step at ``pos`` reads
         of each plane, of those the plane has: from the block of the
-        lowest ``start`` among the generating rows to the block of
-        ``pos`` (``cached_attention``'s own bounds)."""
+        lowest ``start`` among the rows it was handed as generating to
+        the block of ``pos`` (``cached_attention``'s own bounds)."""
         block = self._attn_block
         if not block:
             return
-        read = self.pos // block + 1 - int(starts.min()) // block \
+        read = pos // block + 1 - int(starts.min()) // block \
             if starts.size else 0
         self._add("attn_blocks_read", read)
         self._add("attn_blocks_total", -(-self.C // block))
@@ -1034,14 +1151,14 @@ class SlotLoop:
             # fresh copy, never the buffer a dispatch has seen
             self._start = self._start.copy()
             self._start[i] = slot.start
-            self._finished = self._finished.copy()
-            self._finished[i] = False
             self._active = self._active.copy()
             self._active[i] = True
             # a mid-generation resume has no suffix chunk to produce the
             # activation payload: the snapshot carried it (the exact
             # values the pre-park loop held for this row)
             if self._spec:
+                self._finished = self._finished.copy()
+                self._finished[i] = False
                 cur = slot.req.resume_cur
                 if slot.chunks:
                     # first committed token = target argmax over the final
@@ -1054,6 +1171,10 @@ class SlotLoop:
                 self._cur = self._cur.copy()
                 self._cur[i] = np.int32(cur)
             else:
+                # the row's ``finished`` lives on the device: the next
+                # step clears it, told by ``joined``
+                self._joined = self._joined.copy()
+                self._joined[i] = True
                 row = slot._act_logits
                 if not slot.chunks:
                     row = slot.req.resume_logits    # host [V]: up, once
@@ -1090,63 +1211,133 @@ class SlotLoop:
         self._occupancy = ratio if self.counters["steps"] == 0 \
             else 0.9 * self._occupancy + 0.1 * ratio
         self._m_occ.set(round(ratio, 4))
-        def commit():
-            # one commit under the lock, so a reset_stats() from another
-            # thread never splits a step: the four states sum to steps x
-            # S.  The step calls it once its tokens are emitted and BEFORE
-            # it retires a row, so a client that holds its answer finds
-            # the step that produced it in stats()
-            with self._cond:
-                self.counters["steps"] += 1
-                self.counters["emitted_tokens"] += self._step_emitted
-                self._commit_tally()
-                for k, n in zip(_SLOT_STATES, split):
-                    self.counters[f"slot_steps_{k}"] += n
-            self._step_emitted = 0
-            for m, n in zip(self._m_steps, split):
-                if n:
-                    m.inc(n)
-
         self._phase("step_dispatch")
         if self._spec:
-            self._spec_step(gen_slots, commit)
+            self._spec_step(gen_slots, split)
         else:
-            self._plain_step(gen_slots, commit)
+            self._plain_step(gen_slots, split)
 
-    def _plain_step(self, gen_slots, commit):
-        self._cache, self._logits, finished, tok = self._step(
-            *self._gen._state_args(), self._cache, self._logits,
-            self._start, self._finished, self._active,
-            np.int32(self.pos))
+    def _commit_step(self, split):
+        """One commit under the lock, so a reset_stats() from another
+        thread never splits a step: the four states sum to steps x S.
+        Called once the step's tokens are emitted and BEFORE it retires a
+        row, so a client that holds its answer finds the step that
+        produced it in stats()."""
+        with self._cond:
+            self.counters["steps"] += 1
+            self.counters["emitted_tokens"] += self._step_emitted
+            self._commit_tally()
+            for k, n in zip(_SLOT_STATES, split):
+                self.counters[f"slot_steps_{k}"] += n
+        self._step_emitted = 0
+        for m, n in zip(self._m_steps, split):
+            if n:
+                m.inc(n)
+
+    def _plain_step(self, gen_slots, split):
+        """Dispatch the step at ``pos``, and only then read the step
+        before it: the device holds this one (and the chunks before it)
+        while the driver emits, commits and retires (module docstring,
+        "One step in flight")."""
+        rows = [(i, self._slots[i]) for i in gen_slots]
+        try:
+            self._cache, self._logits, self._finished, tok = self._step(
+                *self._gen._state_args(), self._cache, self._logits,
+                self._start, self._finished, self._active, self._joined,
+                np.int32(self.pos))
+        except BaseException:
+            # the step before it ran: its rows get their tokens before
+            # this one's are failed
+            self._settle()
+            raise
+        # on its way to the host from the moment it exists: the read, a
+        # whole iteration later, waits for nothing
+        tok.copy_to_host_async()
+        # rows between two of their chunks: their state is what the last
+        # chunk left, and this step passes it by
+        held = sum(1 for s in self._slots
+                   if s.state == _PREFILL and s.next_chunk > 0) \
+            if self._state_layers else 0
+        before, self._inflight = self._inflight, _InFlight(
+            tok, rows, self.pos, split, self._blocked, held,
+            self._chunk_counts, self._tally)
+        self._chunk_counts, self._tally = [], {}
+        self._joined = self._no_rows
+        self.pos += 1
+        for i, slot in rows:
+            # one token a row a step: the host knows without reading it
+            # which rows end with this one
+            slot.sent += 1
+            if slot.sent >= slot.req.max_new:
+                self._leave(i, slot)
+        if before is not None:
+            self._read_step(before)
+
+    def _settle(self):
+        """Driver thread: read the step in flight, if there is one (emit,
+        commit, retire).  For whatever must see the loop's state whole."""
+        flight, self._inflight = self._inflight, None
+        if flight is not None:
+            self._read_step(flight)
+
+    def _read_step(self, flight: _InFlight):
         self._phase("step_fetch")
-        tok = np.asarray(tok)
-        self._finished = np.array(finished)
-        self._read_chunk_counts()
+        t0 = time.monotonic()
+        tok = np.asarray(flight.tok)
+        ready = time.monotonic() - t0 < READ_READY_S
+        # this step's part of the tally: what the driver gathered before
+        # it dispatched the step, and what it reads now; what it has
+        # gathered since goes with the next step
+        later, self._tally = self._tally, flight.tally
+        self._read_chunk_counts(flight.chunk_counts)
         self._phase("retire")
+        # a row retired since the dispatch had taken the end token at the
+        # step before: this step passed it by, its slot counts as empty
+        live = [(i, s) for i, s in flight.rows if s.req is not None]
+        lag = len(flight.rows) - len(live)
+        self._add("steps_read_ready", int(ready))
+        self._add("slot_steps_retire_lag", lag)
         # the model's counts came back behind the S tokens
         self._tally_counts(tok[self.S:])
-        starts = np.array([self._slots[i].start for i in gen_slots], np.int64)
-        self._tally_columns(np.full(len(gen_slots), self.pos), starts)
-        self._tally_blocks(starts)
+        self._tally_columns(np.full(len(live), flight.pos),
+                            np.array([s.start for _, s in live], np.int64))
+        self._tally_blocks(
+            np.array([s.start for _, s in flight.rows], np.int64), flight.pos)
         if self._state_layers:
-            # rows between two of their chunks: their state is what the
-            # last chunk left, and this step passed it by
-            self._add("state_rows_held", sum(
-                1 for s in self._slots
-                if s.state == _PREFILL and s.next_chunk > 0))
-        self.pos += 1
-        for i in gen_slots:
-            self._emit(self._slots[i], [int(tok[i])])
-        commit()
-        self._retire_done(gen_slots)
+            self._add("state_rows_held", flight.held)
+        for i, slot in live:
+            self._emit(slot, [int(tok[i])])
+        n_gen, n_prefill, n_blocked, n_free = flight.split
+        self._commit_step((n_gen - lag, n_prefill,
+                           n_blocked + (lag if flight.blocked else 0),
+                           n_free + (0 if flight.blocked else lag)))
+        self._tally = later
+        for i, slot in live:
+            if tok[i] == self._end \
+                    or len(slot.emitted) >= slot.req.max_new:
+                self._retire(i, slot)
+
+    def _leave(self, i, slot):
+        """Row ``i`` reaches ``max_new`` with the step just dispatched:
+        the next step gets a vacated row and the slot is free for the next
+        admission, while the row's record waits in ``_leaving`` for its
+        last token.  A session row's snapshot is cut from its columns, so
+        those are pulled now, behind the step and before a new occupant's
+        pushes and chunks."""
+        req = slot.req
+        if req.session_id is not None and self._pull_row is not None \
+                and req.prompt.size + slot.sent - len(req.preseed) >= self.T:
+            slot.row = self._pull_row(self._cache, np.int32(i))
+        self._leaving.append(slot)
+        self._vacate(i)
 
     def _retire_done(self, gen_slots):
         for i in gen_slots:
             slot = self._slots[i]
             if self._finished[i] or len(slot.emitted) >= slot.req.max_new:
-                self._retire(i)
+                self._retire(i, slot)
 
-    def _spec_step(self, gen_slots, commit):
+    def _spec_step(self, gen_slots, split):
         # clamp the stride so the commit lands exactly on the nearest
         # activation boundary — a prefilling row's window must start
         # the moment the frontier reaches its planned position (every
@@ -1164,14 +1355,15 @@ class SlotLoop:
         self._finished = np.array(finished)
         e = np.asarray(e)
         k = int(ncommit)
-        self._read_chunk_counts()
+        self._read_chunk_counts(self._chunk_counts)
+        self._chunk_counts = []
         self._phase("retire")
         self.pos += k
         self._accepted += int(n)
         self._proposed += self._gamma
         for i in gen_slots:
             self._emit(self._slots[i], [int(t) for t in e[i, :k]])
-        commit()
+        self._commit_step(split)
         self._retire_done(gen_slots)
 
     def _emit(self, slot, toks):
@@ -1218,7 +1410,9 @@ class SlotLoop:
         planes = None
         if self._pull_row is not None and lc >= self.T:
             import jax.tree_util as tu
-            row = self._pull_row(self._cache, np.int32(i))
+            # (a row that left by count was pulled when it left)
+            row = slot.row if slot.row is not None \
+                else self._pull_row(self._cache, np.int32(i))
             planes = tu.tree_map(
                 lambda p: np.asarray(p)[:, :, slot.start:slot.start + lc,
                                         :].copy(), row)
@@ -1238,8 +1432,10 @@ class SlotLoop:
             kv_dtype=self._kv_dtype(), spec=self._spec))
         self.counters["parked"] += 1
 
-    def _retire(self, i):
-        slot = self._slots[i]
+    def _retire(self, i, slot):
+        """``slot``, of row ``i``, is done and its tokens are read: park
+        its session, resolve its future.  The slot is freed here unless
+        the row left it when its last step was dispatched (``_leave``)."""
         req = slot.req
         out = np.full((req.max_new,), self._end, np.int32)
         out[:len(slot.emitted)] = slot.emitted
@@ -1254,26 +1450,29 @@ class SlotLoop:
         # eos freeze: every position after finish reads eos, exactly the
         # scanned decode's padding — retiring early never changes bytes
         req.future.set_result(out)
-        self._vacate(i)
+        if self._slots[i] is slot:
+            self._vacate(i)
+        else:
+            self._leaving.remove(slot)
+        slot.req = slot.row = None      # done, to a step that still lists it
         self.counters["retired"] += 1
         self._m_retired.inc()
 
     def _vacate(self, i):
-        """Row ``i`` generates no more: its slot is empty, its window
-        too (``start = C``, so that the step's attention does not span
-        the columns it leaves behind)."""
-        slot = self._slots[i]
-        slot.state, slot.req = _EMPTY, None
-        slot.emitted = []
+        """Row ``i`` generates no more: its slot is empty (a new record:
+        a step in flight may still list the old one), its window too
+        (``start = C``, so that the step's attention does not span the
+        columns it leaves behind)."""
+        self._slots[i] = _Slot()
         # copy-on-write for the same aliasing reason as _activate
         self._start = self._start.copy()
         self._start[i] = self.C
-        self._finished = self._finished.copy()
-        self._finished[i] = True
         self._active = self._active.copy()
         self._active[i] = False
-        slot._act_logits = None         # a row parked before it activated
         if self._spec:
+            # (the plain step reads a row that is not active as finished)
+            self._finished = self._finished.copy()
+            self._finished[i] = True
             self._cur = self._cur.copy()
             self._cur[i] = 0
 
@@ -1304,7 +1503,9 @@ class SlotLoop:
 
     def _do_park(self, park):
         """Driver-thread half of :meth:`park_sessions` (called with the
-        condition held, between dispatch rounds — no dispatch races)."""
+        condition held, between dispatch rounds — no dispatch races;
+        ``_drive`` has read the step in flight, so a row is parked with
+        every token it has)."""
         evt, out = park
         try:
             exc = UnavailableError(

@@ -559,27 +559,43 @@ class Generator:
         CPU).  Instead the host guarantees every column a step writes
         for an inactive row is dead: it lies inside the row's pending
         chunk window [act-Pb, act) and the slot loop dispatches chunk k
-        only after the step at position act-n+k has retired (see
-        slots._dispatch_chunks), so the chunk rewrite always lands
+        only after it has DISPATCHED the step at position act-n+k (see
+        slots._dispatch_chunks; the device runs its programs in the
+        order they were dispatched), so the chunk rewrite always lands
         after the last garbage write.  Emitted tokens for active rows
         are bit-identical to the scanned decode's per-row stream (row
-        independence + the PR-7 batch/bucket invariance)."""
+        independence + the PR-7 batch/bucket invariance).
+
+        ``finished`` never visits the host: the loop hands each step the
+        array the step before it returned, while that step may still be
+        running (the loop keeps one step in flight).  What the host knows
+        and the device does not, it says in two masks: ``active`` (the
+        rows it counts as generating; every other row reads as finished)
+        and ``joined`` (of those, the rows that activated since the last
+        step: their flag is cleared).  What the device knows first, the
+        step acts on itself: a row that took the end token at the step
+        before is still ``active`` to a host that has not read that
+        token yet, and takes no part — its token is the end token, its
+        logits pass through, a state without columns is kept, and its
+        write lands in a dead column like any inactive row's."""
         apply = self._apply_cached
 
         def step(params, buffers, cache, logits, start, finished, active,
-                 pos):
+                 joined, pos):
             with jax.named_scope("head/sample"):
+                finished = (finished | ~active) & ~joined
+                live = active & ~finished
                 tok = jnp.argmax(logits, axis=-1).astype(jnp.int32)
                 tok = jnp.where(finished, jnp.int32(end), tok)
                 finished = finished | (tok == end)
-                # inactive rows may carry garbage argmax (end == -1
-                # included) — clamp their fed token; their write lands in
-                # a dead column
-                fed = jnp.where(active, tok, jnp.int32(0))
+                # rows that are not live may carry garbage argmax (end ==
+                # -1 included) — clamp their fed token; their write lands
+                # in a dead column
+                fed = jnp.where(live, tok, jnp.int32(0))
             nlogits, ncache = apply(params, buffers, fed[:, None], cache,
-                                    pos, start, active)
+                                    pos, start, live)
             with jax.named_scope("head/sample"):
-                nlog = jnp.where(active[:, None],
+                nlog = jnp.where(live[:, None],
                                  nlogits[:, 0].astype(jnp.float32), logits)
             counts = self._decode_counts()
             if counts is not None:
@@ -627,7 +643,7 @@ class Generator:
         from its builder to ``jax.jit``."""
         self._require_unsharded_slots()
         end = -1 if eos_token_id is None else int(eos_token_id)
-        return (self._key("step2", S, None, C, 1, 1, end), "generate_step",
+        return (self._key("step3", S, None, C, 1, 1, end), "generate_step",
                 self._build_step(S, C, end), self.step_avals(S, C),
                 {"slots": S, "cache": C, "eos": end,
                  "kv_heads_per_lane_row": self.kv_heads_per_lane_row(),
@@ -728,12 +744,13 @@ class Generator:
 
     def step_avals(self, S, C):
         """Non-state avals of the slot step program (cache, logits,
-        start, finished, active, pos) — shared by the AOT compile and
-        the serving graph-lint admission gate."""
+        start, finished, active, joined, pos) — shared by the AOT compile
+        and the serving graph-lint admission gate."""
         vocab = self._vocab_size()
         return (self._slot_cache_avals(S, C),
                 jax.ShapeDtypeStruct((S, vocab), jnp.float32),
                 jax.ShapeDtypeStruct((S,), jnp.int32),
+                jax.ShapeDtypeStruct((S,), jnp.bool_),
                 jax.ShapeDtypeStruct((S,), jnp.bool_),
                 jax.ShapeDtypeStruct((S,), jnp.bool_),
                 jax.ShapeDtypeStruct((), jnp.int32))

@@ -134,13 +134,25 @@ def test_chunks_then_steps_equal_the_reference_for_unequal_starts(served):
                                    atol=LOGIT_TOL)
 
 
-def _serve(model, requests, slots=3, cache_len=64, one_by_one=False):
+def _serve(model, requests, slots=3, cache_len=64, one_by_one=False,
+           read_at_once=False, in_one_admission=False):
     """``requests`` [(prompt or its length, new tokens)] through a SlotLoop,
-    all at once or ``one_by_one`` (each sent once the one before it has
-    resolved).  Returns (prompts, tokens, stats, the logits the step
-    program handed back at every step)."""
+    all at once (``in_one_admission``: all in the FIFO before the driver
+    looks) or ``one_by_one`` (each sent once the one before it has
+    resolved).  ``read_at_once``: every step is read before anything else
+    is dispatched, as before the loop kept one in flight.  Returns
+    (prompts, tokens, stats, the logits the step program handed back at
+    every step)."""
+    import contextlib
     gen = Generator(model, max_len=cache_len, seq_buckets=[cache_len])
     loop = SlotLoop(gen, slots=slots, cache_len=cache_len, chunk=4)
+    if read_at_once:
+        plain = loop._plain_step
+
+        def plain_then_read(gen_slots, split):
+            plain(gen_slots, split)
+            loop._settle()
+        loop._plain_step = plain_then_read
     step, seen = loop._step, []
 
     def recording(*args):
@@ -155,11 +167,11 @@ def _serve(model, requests, slots=3, cache_len=64, one_by_one=False):
         out = [np.asarray(loop.submit(p, k).result(timeout=300))
                for p, (_, k) in zip(prompts, requests)]
     else:
-        futs = [loop.submit(p, k) for p, (_, k) in zip(prompts, requests)]
+        with loop._cond if in_one_admission else contextlib.nullcontext():
+            futs = [loop.submit(p, k) for p, (_, k) in zip(prompts, requests)]
         out = [np.asarray(f.result(timeout=300)) for f in futs]
-    stats = loop.stats()
     loop.close()
-    return prompts, out, stats, seen
+    return prompts, out, loop.stats(), seen
 
 
 def _widest_gap(cfg, view, prompts, tokens):
@@ -194,6 +206,31 @@ def test_slot_loop_equals_the_reference_and_counts_in_one_piece(served):
     # the span counters run for a model whose COLUMN planes are all kv
     assert st["attn_blocks_total"] == st["steps"] \
         and 0 < st["attn_blocks_read"] <= st["attn_blocks_total"]
+
+
+def test_one_step_in_flight_serves_what_reading_every_step_at_once_does(
+        served):
+    """The churn of the test above (rows held between two of their chunks
+    while their neighbours step, every slot reused, rows that leave by
+    count with their last token in flight) with the next step dispatched
+    before the last is read, against the same schedule read step by step:
+    the same tokens, the same logits out of every step, bit for bit, and
+    the same counts of what ran."""
+    _, model, _ = served
+    _, tokens, st, seen = _serve(model, REQUESTS, in_one_admission=True)
+    _, want, st0, seen0 = _serve(model, REQUESTS, in_one_admission=True,
+                                 read_at_once=True)
+    for got, ref_ in zip(tokens, want):
+        np.testing.assert_array_equal(got, ref_)
+    assert len(seen) == len(seen0) == st["steps"]
+    for got, ref_ in zip(seen, seen0):
+        np.testing.assert_array_equal(got, ref_)
+    assert st["state_rows_held"] == st0["state_rows_held"] > 0
+    assert st["slot_steps_retire_lag"] == 0
+    same = [k for k in st0 if k not in ("phase_s", "phases_ms",
+                                        "occupancy_ewma", "ttft_p50_ms",
+                                        "ttft_p99_ms", "steps_read_ready")]
+    assert {k: st[k] for k in same} == {k: st0[k] for k in same}
 
 
 def _spy_counts(loop):
@@ -346,7 +383,7 @@ def _prefill_beside_a_live_row(model, steps_between):
             outs.append(np.asarray(out))
         if k < 4 and steps_between:
             cache, logits, _, _ = step(*state, cache, logits, start, done,
-                                       live, np.int32(20 + k))
+                                       live, np.zeros(2, bool), np.int32(20 + k))
     return np.stack(outs)
 
 

@@ -135,7 +135,7 @@ def _prefill_beside_a_live_row(model, steps_between):
             outs.append(np.asarray(out))
         if k < 4 and steps_between:
             cache, logits, _, _ = step(*state, cache, logits, start, done,
-                                       live, np.int32(20 + k))
+                                       live, np.zeros(2, bool), np.int32(20 + k))
     return np.stack(outs)
 
 

@@ -469,3 +469,337 @@ def test_slot_mode_off_path_single_branch():
         assert "slot_loop" not in srv.stats("gpt")
     finally:
         srv.stop()
+
+
+# -- one step in flight --------------------------------------------------------
+
+def _parent_order(loop):
+    """The loop as it ran before it kept a step in flight: every step is
+    read before anything else is dispatched."""
+    step = loop._plain_step
+
+    def read_at_once(gen_slots, split):
+        step(gen_slots, split)
+        loop._settle()
+
+    loop._plain_step = read_at_once
+
+
+def _spy_flights(loop):
+    """The columns of the steps as they were dispatched and as they were
+    read, and how many steps were in flight at each read (the one being
+    read not counted)."""
+    dispatched, read, behind = [], [], []
+    step, read_step = loop._step, loop._read_step
+
+    def stepping(*args):
+        dispatched.append(int(args[-1]))
+        return step(*args)
+
+    def reading(flight):
+        read.append(flight.pos)
+        behind.append(int(loop._inflight is not None))
+        return read_step(flight)
+
+    loop._step, loop._read_step = stepping, reading
+    return dispatched, read, behind
+
+
+def _want_tokens(seed, p, mn, eos=None):
+    """The scanned decode's tokens: ``mn`` of them, frozen at the end
+    token where one is given."""
+    kw = {} if eos is None else {"eos_token_id": eos}
+    return np.asarray(_oracle(seed).generate(
+        np.asarray([p], np.int32), lengths=np.asarray([len(p)], np.int32),
+        max_new_tokens=_oracle_steps(p, mn), **kw).numpy())[0][:mn]
+
+
+def _serve_at_once(seed, reqs, order, slots=2, cache_len=64, chunk=8,
+                   eos=None, spy=None, **kw):
+    """``reqs`` [(prompt, max_new)] through a fresh loop, all in the FIFO
+    before the driver's first admission, so that the schedule follows from
+    the lengths alone.  ``order`` "parent" reads every step at once.
+    Returns (tokens or the exception of each, stats, what ``spy(loop)``
+    returned, the flights)."""
+    gen = Generator(_gpt(seed), seq_buckets=(8, 16, 32), max_len=64)
+    loop = SlotLoop(gen, slots=slots, cache_len=cache_len, chunk=chunk,
+                    eos_token_id=eos, **kw)
+    if order == "parent":
+        _parent_order(loop)
+    flights = _spy_flights(loop)
+    seen = spy(loop) if spy is not None else None
+    try:
+        with loop._cond:
+            futs = [loop.submit(p, mn) for p, mn in reqs]
+        outs = []
+        for f in futs:
+            try:
+                outs.append(np.asarray(f.result(timeout=120)).reshape(-1))
+            except Exception as e:   # noqa: BLE001 — the test looks at it
+                outs.append(e)
+    finally:
+        loop.close()
+    assert loop._inflight is None and loop._leaving == []
+    return outs, loop.stats(), seen, flights
+
+
+def _assert_every_step_read_once_in_order(flights, order):
+    dispatched, read, behind = flights
+    assert read == dispatched
+    if order == "parent":
+        assert not any(behind)
+    else:
+        # the next step was on the device whenever there was one to send
+        assert any(behind)
+
+
+ORDERS = ("in_flight", "parent")
+
+
+def _end_token_scenario():
+    """Row A (5 tokens: two chunks of 4, activates at column 8) takes, as
+    its eighth token, one it has not produced before: the end token, at
+    the step that writes column 15.  Row B (13 tokens: four chunks)
+    activates at column 16, the very next step, which the host dispatches
+    before it has read A's end token."""
+    a = [8, 30, 11, 54, 7]
+    free = _want_tokens(37, a, 16)
+    assert free[7] not in free[:7]
+    eos = int(free[7])
+    rng = random.Random(5)
+    while True:
+        b = [rng.randrange(V) for _ in range(13)]
+        if eos not in _want_tokens(37, b, 6):
+            return eos, [(a, 14), (b, 6)]
+
+
+@pytest.mark.parametrize("order", ORDERS)
+def test_end_token_in_a_batch_and_a_row_joining_on_the_next_step(order):
+    eos, reqs = _end_token_scenario()
+    outs, st, _, flights = _serve_at_once(37, reqs, order, chunk=4, eos=eos)
+    for (p, mn), got in zip(reqs, outs):
+        np.testing.assert_array_equal(got, _want_tokens(37, p, mn, eos))
+    assert list(outs[0][7:]) == [eos] * 7          # frozen from its 8th on
+    _assert_every_step_read_once_in_order(flights, order)
+    dispatched = flights[0]
+    assert dispatched[:9] == list(range(8, 17))
+    # the step at column 16 listed A although it was done: one slot-step
+    assert st["slot_steps_retire_lag"] == (1 if order == "in_flight" else 0)
+    assert st["slot_steps_emitting"] == st["emitted_tokens"] == 8 + 6
+    assert sum(st[f"slot_steps_{k}"] for k in (
+        "emitting", "prefilling", "drain_blocked", "no_demand")) \
+        == st["steps"] * 2
+    assert st["steps"] == len(dispatched) == 8 + 6
+    assert 0 <= st["steps_read_ready"] <= st["steps"]
+
+
+def _spy_install(loop):
+    """At each admission: the frontier, the rows whose last token was in
+    flight, and whether a step was."""
+    seen, install = [], loop._install
+
+    def installing(slot, head):
+        seen.append((loop.pos, len(loop._leaving),
+                     loop._inflight is not None))
+        return install(slot, head)
+
+    loop._install = installing
+    return seen
+
+
+@pytest.mark.parametrize("order", ORDERS)
+def test_a_slot_freed_by_count_is_admitted_into_in_the_next_iteration(order):
+    """Two slots, three requests: A ends by count with the step at column
+    10 while C goes on; B takes A's slot in the next iteration, as it did
+    when the loop read before it dispatched, although A's last token is
+    still in flight then."""
+    rng = random.Random(17)
+    reqs = [([rng.randrange(V) for _ in range(n)], mn)
+            for n, mn in ((6, 3), (7, 12), (5, 4))]
+    outs, st, seen, flights = _serve_at_once(21, reqs, order,
+                                             spy=_spy_install)
+    for (p, mn), got in zip(reqs, outs):
+        np.testing.assert_array_equal(got, _want_tokens(21, p, mn))
+    _assert_every_step_read_once_in_order(flights, order)
+    assert [pos for pos, _, _ in seen] == [0, 0, 11]
+    assert seen[2][1:] == ((1, True) if order == "in_flight" else (0, False))
+    assert st["slot_steps_retire_lag"] == 0
+    assert st["joined"] == st["retired"] == 3
+
+
+@pytest.mark.parametrize("order", ORDERS)
+def test_ring_restarts_with_a_step_in_flight(order):
+    """A ring of 32 columns and more traffic than it holds: the last row
+    of a session leaves by count, the restart finds its last step still in
+    flight and reads it before the frontier goes back to 0."""
+    rng = random.Random(11)
+    reqs = [([rng.randrange(V) for _ in range(6)], 6) for _ in range(8)]
+    outs, st, _, flights = _serve_at_once(39, reqs, order, cache_len=32)
+    for (p, mn), got in zip(reqs, outs):
+        np.testing.assert_array_equal(got, _want_tokens(39, p, mn))
+    _assert_every_step_read_once_in_order(flights, order)
+    assert st["session_resets"] >= 1
+    dispatched = flights[0]
+    assert any(b < a for a, b in zip(dispatched, dispatched[1:]))
+
+
+@pytest.mark.parametrize("order", ORDERS)
+def test_close_with_a_step_in_flight_drains_it(order):
+    """``close()`` right behind the submissions: the rows admitted by then
+    run to their end, the step in flight included; what was not admitted
+    fails."""
+    rng = random.Random(23)
+    reqs = [([rng.randrange(V) for _ in range(5)], 9) for _ in range(3)]
+    gen = Generator(_gpt(21), seq_buckets=(8, 16, 32), max_len=64)
+    loop = SlotLoop(gen, slots=2, cache_len=64, chunk=8)
+    if order == "parent":
+        _parent_order(loop)
+    flights = _spy_flights(loop)
+    with loop._cond:
+        futs = [loop.submit(p, mn) for p, mn in reqs[:2]]
+    while not loop.counters["steps"]:      # both rows are generating
+        pass
+    loop.close()
+    for (p, mn), f in zip(reqs, futs):
+        np.testing.assert_array_equal(
+            np.asarray(f.result(timeout=1)).reshape(-1),
+            _want_tokens(21, p, mn))
+    _assert_every_step_read_once_in_order(flights, order)
+    assert loop._inflight is None and loop._leaving == []
+    st = loop.stats()
+    assert st["steps"] == 9 and st["emitted_tokens"] == 18
+    with pytest.raises(Exception, match="closed"):
+        loop.submit(*reqs[2])
+
+
+class _Unreadable:
+    """A step's tokens that cannot be fetched."""
+
+    def copy_to_host_async(self):
+        pass
+
+    def __array__(self, *a, **kw):
+        raise RuntimeError("boom: read")
+
+
+@pytest.mark.parametrize("order", ORDERS)
+@pytest.mark.parametrize("where", ["dispatch", "read"])
+def test_a_step_that_raises_fails_its_rows_after_the_tokens_before_it(
+        where, order):
+    """A (3 tokens) and B (10) step together at columns 8, 9, 10; the
+    step at column 11 fails.  At its DISPATCH: A's last token, in flight
+    then, is delivered first, and B fails.  At its READ (a device fault
+    shows there): A was read a step earlier all the same; B fails; and
+    had A's own last step been the unreadable one, A would fail too."""
+    rng = random.Random(29)
+    reqs = [([rng.randrange(V) for _ in range(n)], mn)
+            for n, mn in ((6, 3), (7, 10))]
+
+    def spy(loop):
+        step = loop._step
+
+        def failing(*args):
+            if int(args[-1]) == 11:
+                if where == "dispatch":
+                    raise RuntimeError("boom: dispatch")
+                return step(*args)[:3] + (_Unreadable(),)
+            return step(*args)
+
+        loop._step = failing
+        return loop
+
+    outs, st, loop, flights = _serve_at_once(21, reqs, order, spy=spy)
+    np.testing.assert_array_equal(outs[0], _want_tokens(21, *reqs[0]))
+    assert isinstance(outs[1], RuntimeError) and "boom" in str(outs[1])
+    assert st["steps"] == 3 and st["emitted_tokens"] == 6
+    assert st["retired"] == 1
+    assert isinstance(loop._dead, RuntimeError)
+
+
+@pytest.mark.parametrize("order", ORDERS)
+def test_an_unreadable_last_step_fails_the_row_that_left_with_it(order):
+    rng = random.Random(29)
+    reqs = [([rng.randrange(V) for _ in range(n)], mn)
+            for n, mn in ((6, 3), (7, 10))]
+
+    def spy(loop):
+        step = loop._step
+        loop._step = lambda *a: step(*a)[:3] + (_Unreadable(),) \
+            if int(a[-1]) == 10 else step(*a)
+
+    outs, st, _, _ = _serve_at_once(21, reqs, order, spy=spy)
+    assert all(isinstance(o, RuntimeError) for o in outs)
+    assert st["steps"] == 2 and st["retired"] == 0
+
+
+def test_sessions_leave_and_park_with_a_step_in_flight():
+    """Two slots.  C decodes throughout.  A, a session's turn, ends by
+    count and leaves its slot with its last token in flight; B, the next
+    turn of ANOTHER session, is admitted into that slot in the next
+    iteration and its restored block is pushed over A's columns before
+    A's token is read.  A's snapshot was pulled when A left: it equals,
+    bit for bit, the one a loop that reads before it dispatches parks.
+    Then ``park_sessions`` with a step in flight: the row is parked with
+    every token the device has produced for it."""
+    from paddle_tpu.framework.enforce import UnavailableError
+    from paddle_tpu.serving.sessions import SessionStore
+    rng = random.Random(41)
+    first = [rng.randrange(1, V) for _ in range(17)]
+    a = [rng.randrange(1, V) for _ in range(9)]
+    c = [rng.randrange(1, V) for _ in range(5)]
+    snaps = {}
+    for order in ORDERS:
+        gen = Generator(_gpt(21), seq_buckets=(8, 16, 32), max_len=64)
+        store = SessionStore()
+        loop = SlotLoop(gen, slots=2, cache_len=64, chunk=8,
+                        session_store=store)
+        if order == "parent":
+            _parent_order(loop)
+        seen = _spy_install(loop)
+        try:
+            got = np.asarray(loop.submit(first, 3, session_id="s2")
+                             .result(timeout=120)).reshape(-1)
+            turn2 = first + [int(t) for t in got] + [7, 9]
+            with loop._cond:
+                fc = loop.submit(c, 30)
+                fa = loop.submit(a, 3, session_id="s1")
+                fb = loop.submit(turn2, 4, session_id="s2",
+                                 snapshot=store.take("s2"))
+            np.testing.assert_array_equal(
+                np.asarray(fa.result(timeout=120)).reshape(-1),
+                _want_tokens(21, a, 3))
+            np.testing.assert_array_equal(
+                np.asarray(fb.result(timeout=120)).reshape(-1),
+                _want_tokens(21, turn2, 4))
+            if order == "in_flight":
+                assert seen[-1][1:] == (1, True)     # B came while A left
+            assert loop.counters["restore_pushes"] >= 1
+            snaps[order] = store.take("s1")
+            # a session row parked mid-stream, a step in flight behind it
+            fd = loop.submit(a, 20, session_id="s3")
+            while fd.running() or not any(
+                    s.req is not None and s.req.session_id == "s3"
+                    and s.emitted for s in loop._slots):
+                assert not fd.done()
+            assert loop.park_sessions(timeout=30.0) == 1
+            with pytest.raises(UnavailableError):
+                fd.result(timeout=30)
+            parked = store.take("s3")
+            want = _want_tokens(21, a, 20)
+            n = len(parked.emitted)
+            assert 0 < n == 20 - parked.remaining
+            np.testing.assert_array_equal(parked.emitted, want[:n])
+            np.testing.assert_array_equal(
+                np.asarray(loop.submit(a, 20, session_id="s3",
+                                       snapshot=parked)
+                           .result(timeout=120)).reshape(-1), want)
+            np.testing.assert_array_equal(
+                np.asarray(fc.result(timeout=120)).reshape(-1),
+                _want_tokens(21, c, 30))
+        finally:
+            loop.close()
+    one, two = snaps["in_flight"], snaps["parent"]
+    assert one.tokens == two.tokens and one.remaining == two.remaining == 0
+    for p, q in zip(jax.tree_util.tree_leaves(one.planes),
+                    jax.tree_util.tree_leaves(two.planes)):
+        np.testing.assert_array_equal(p, q)

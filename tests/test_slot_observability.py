@@ -128,12 +128,22 @@ def test_ring_too_small_for_the_head_counts_drain_blocked(cache_len, blocked):
         assert c["session_resets"] == 0 and resets.value == before
 
 
-def test_snapshots_stay_whole_under_concurrent_resets():
+@pytest.mark.parametrize("eos", [None, 25], ids=["by-count", "end-token"])
+def test_snapshots_stay_whole_under_concurrent_resets(eos):
     """A step's counters are committed in one piece under the loop's lock:
     whatever moment other threads pick to read or zero them, the four
-    states sum to steps x S and emitting equals the tokens emitted."""
-    loop = _loop(seed=29)
-    stop, torn = threading.Event(), []
+    states sum to steps x S and emitting equals the tokens emitted.  With
+    an end token (one this model takes often) rows are passed by for a
+    step before the host retires them: they count with the empty slots."""
+    loop = _loop(seed=29, eos_token_id=eos)
+    stop, torn, lag, add = threading.Event(), [], [], loop._add
+
+    def adding(key, n, chunk=False):
+        if key == "slot_steps_retire_lag":
+            lag.append(n)
+        add(key, n, chunk)
+
+    loop._add = adding
 
     def poke():
         while not stop.is_set():
@@ -162,6 +172,45 @@ def test_snapshots_stay_whole_under_concurrent_resets():
         loop.close()
     assert not any(t.is_alive() for t in pokers)
     assert not torn, torn[:2]
+    assert (sum(lag) > 0) == (eos is not None)
+
+
+def test_the_in_flight_counters_are_committed_with_steps_and_reset(
+        monkeypatch):
+    """``steps_read_ready`` counts the read-backs that waited less than
+    ``READ_READY_S`` (none under a threshold of 0, all under a huge one),
+    ``slot_steps_retire_lag`` the slot-steps of rows the step passed by;
+    both are zeroed with the rest, and a speculative loop, which reads
+    every step before the next, has neither."""
+    for threshold, ready in ((0.0, lambda st: 0), (1e9, lambda st: st["steps"])):
+        monkeypatch.setattr(slots, "READ_READY_S", threshold)
+        loop = _loop(seed=29, eos_token_id=25)
+        try:
+            for f in [loop.submit(p, mn)
+                      for p, mn in _mixed(random.Random(1), 10)]:
+                f.result(timeout=120)
+        finally:
+            loop.close()
+        st = loop.stats()
+        assert st["steps"] > 0 and st["steps_read_ready"] == ready(st)
+        assert 0 < st["slot_steps_retire_lag"] \
+            <= st["slot_steps_no_demand"] + st["slot_steps_drain_blocked"]
+        loop.reset_stats()
+        st = loop.stats()
+        assert st["steps_read_ready"] == st["slot_steps_retire_lag"] == 0
+    from paddle_tpu.text.speculative import SpeculativeGenerator
+    paddle.seed(101)
+    draft = GPTModel(GPTConfig.tiny(vocab_size=V, hidden_size=16, layers=1,
+                                    heads=2, seq=64))
+    draft.eval()
+    spec = SlotLoop(SpeculativeGenerator(
+        _gpt(), draft, seq_buckets=(8, 16, 32), max_len=64, gamma=3),
+        slots=2, cache_len=64, chunk=8)
+    try:
+        assert "steps_read_ready" not in spec.stats()
+        assert "slot_steps_retire_lag" not in spec.stats()
+    finally:
+        spec.close()
 
 
 # -- C: a request's life -------------------------------------------------------
@@ -263,7 +312,7 @@ def test_attn_blocks_follow_the_frontier_and_the_oldest_live_start(
     seen, real = [], loop._step
 
     def step(*args):
-        start, _finished, active, pos = args[-4:]
+        start, _finished, active, _joined, pos = args[-5:]
         seen.append((int(pos), np.array(start), np.array(active)))
         return real(*args)
 

@@ -42,6 +42,28 @@ def test_the_replay_of_the_state_space_cell(ramp, first_seen, start, window,
         assert out["round_end_s"] == pytest.approx(39.7, abs=0.1)
 
 
+@pytest.mark.parametrize("share,ramp,window,lag", [
+    (0.0, 52.0, 123, 0.0),       # the benchmark's traffic: every row by count
+    (1.0, 52.0, 123, 0.235),     # every answer by the end token: one slot-step
+    (0.5, 52.0, 123, 0.117),     #   each, 1 in ~425 of a 384-token answer's
+    (1.0, 8.0, 61, 0.113),       # in the cold round a late slot costs an answer
+], ids=["by-count", "by-end-token", "half", "by-end-token-cold"])
+def test_the_replay_follows_the_retirement_timing(share, ramp, window, lag):
+    """One step in flight: a slot freed by count is admitted into in the same
+    iteration as when the loop read before it dispatched (the numbers above
+    stand as they were), one freed by the end token an iteration later, after
+    a step that passed its row by; the round's end is the frontier's and does
+    not move."""
+    config, traffic = _cell()
+    out = slot_replay.replay(config, traffic, step_s=0.029, chunk_s=0.0435,
+                             ramp_s=ramp, end_token_share=share)
+    assert len(out["answers"]) == window
+    assert out["retire_lag_pct"] == pytest.approx(lag, abs=0.001)
+    assert out["round_end_s"] == pytest.approx(39.7, abs=0.1)
+    assert out["emitting_pct"] + out["prefilling_pct"] \
+        + out["retire_lag_pct"] == pytest.approx(100.0, abs=1e-6)
+
+
 def test_the_committed_ramp_steps_over_the_replayed_round():
     """``ramp_s`` is the round's end plus a median answer's seconds: at
     least ten seconds past what the replay gives at the measured step."""

@@ -136,6 +136,21 @@ the first against the device time under `state_space/update`,
 `ssm_scan_roofline_pct` the second against `state_space/scan`, each with
 the family's least bytes and operations from `benchmark/counts/`.
 
+The plain slot loop keeps ONE decode step in flight: it dispatches step k+1
+and only then reads step k's tokens (`paddle_tpu/serving/slots.py`, "One step
+in flight"), so the device holds its next programs while the driver thread
+emits, commits and retires.  `slot_loop::step_fetch`
+(`stats()["phase_s"]["step_fetch"]`) is therefore the wait for the step
+BEFORE the one just dispatched.  Two of `SlotLoop.counters` say what that
+costs and whether it works, committed with `steps` and zeroed by
+`reset_stats()` (a speculative loop, which reads every step before the
+next, has neither):
+
+| counter | counts | read beside |
+|---|---|---|
+| `steps_read_ready` | the steps whose read-back waited less than `slots.READ_READY_S` (0.5 ms): the tokens were on the host's side before the driver asked, so the driver's own work had outlasted the device's step and the device was living on the one queued step.  Near 0 where the device is the bottleneck (the read waits for a step that is still running, with the next queued behind it), near `steps` where the host is | `steps`; the seconds waited are `phase_s["step_fetch"]`, the device's side `device_idle_pct.*` |
+| `slot_steps_retire_lag` | the slot-steps of rows a step passed by because they had taken the end token at the step before, which the host had not read when it dispatched: they count with the empty slots (`slot_steps_no_demand` / `slot_steps_drain_blocked`), not with `slot_steps_emitting`.  A row that ends by `max_new` costs none: the host knows at dispatch, and frees the slot in the same iteration as a loop that reads first | `steps x slots` |
+
 To read a capture of your own: `paddle_tpu.profiler.Profiler` writes
 `program_scopes.json` into `profiler_result_dir` when a record window
 ends, beside the `.xplane.pb`, so both survive the process; then

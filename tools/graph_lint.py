@@ -112,7 +112,7 @@ def build_decode_step(slots=2, cache=32):
     — the hot serving dispatch, traced exactly as step_exec compiles it.
     Returns ``(fn, avals)``: a RAW traceable callable, not a layer — the
     already-functionalized step takes (params, buffers, cache, logits,
-    start, finished, active, pos)."""
+    start, finished, active, joined, pos)."""
     from paddle_tpu.text.models.gpt import GPTConfig, GPTModel
     from paddle_tpu.text.generation import Generator
     m = GPTModel(GPTConfig.tiny(seq=64))
