@@ -751,6 +751,44 @@ def per_head_products(q_n, q_r, w_uk, w_uv, r_kv, scale):
                           "per_head")
 
 
+# The operands of the per-head pair as the one-kernel form takes them
+# (:func:`latent_attend_fused`): the pair's arguments, nothing computed.
+PerHeadOperands = collections.namedtuple(
+    "PerHeadOperands", ["q_n", "q_r", "w_uk", "w_uv", "r_kv", "scale"])
+
+
+def fused_latent(T, block, d_n, d_r, d_v, r_kv, row_width, heads):
+    """Whether a per-head pass of ``T`` queries over column blocks of
+    ``block`` takes the one-kernel form (ops/pallas/latent_attention.py):
+    after what no shape says (the flag, the backend, a mesh of several
+    devices: :func:`_partitioned`), from the shapes alone
+    (``fused_latent_form``: what the kernel supports; the chip's timings
+    are beside it).  Decided while a program is traced, like
+    :func:`_use_pallas`."""
+    if not (flag("use_pallas_kernels") and _on_tpu() and not _partitioned()):
+        return False
+    from ...ops.pallas.latent_attention import fused_latent_form
+    return fused_latent_form(T, block, d_n, d_r, d_v, r_kv, row_width, heads)
+
+
+def latent_attend_fused(operands, plane, lo, hi, block, start, pos,
+                        keep=None):
+    """:func:`latent_attend_blocked` of :func:`per_head_products` as ONE
+    Pallas kernel: the column blocks ``lo <= i < hi`` of ``plane [B, S,
+    K]`` with the expanded keys and values, the float32 scores and the
+    running sums in VMEM.  ``keep [B, T, S]`` is the mask (a selector's
+    membership, a window's ring mask); without one a query at column
+    ``pos + t`` keeps ``start[b] .. pos + t``.  Returns ``[B, T, H, d_v]``
+    float32; the custom call lies under the per-head form's scope."""
+    from ...ops.pallas.latent_attention import latent_chunk_attention_fn
+    q_n, q_r, w_uk, w_uv, r_kv, scale = operands
+    with jax.named_scope("per_head"):
+        q = jnp.transpose(jnp.concatenate([q_n, q_r], -1), (0, 2, 1, 3))
+        return latent_chunk_attention_fn(
+            q, w_uk, w_uv, plane, start, pos, lo, hi, r_kv=r_kv, scale=scale,
+            block=block, keep=keep)
+
+
 def latent_attend(products, rows, keep):
     """One pass of latent attention in the form ``products`` gives
     (:func:`absorbed_products`, :func:`per_head_products`) over cache

@@ -15,7 +15,13 @@ for (:meth:`LatentAttention.cached_form`, from the layer's own dimensions):
     is expanded once, ``k_n = latent W_uk``, ``v = latent W_uv`` for all
     heads, shared by the ``T`` queries; a pair costs ``d_n + d_r + d_v`` a
     head and the running sum is ``d_v`` wide, not ``r_kv``.  Cheaper from
-    ``T (2 r_kv - d_n - d_v) > r_kv (d_n + d_v)`` on.
+    ``T (2 r_kv - d_n - d_v) > r_kv (d_n + d_v)`` on.  It runs as an XLA
+    loop over the column blocks (``"per_head"``) or, where the program is
+    traced for a TPU at shapes on the lane grid, as ONE Pallas kernel over
+    the row's live blocks (``"per_head_fused"``,
+    ops/pallas/latent_attention.py: expanded keys and values, float32
+    scores and the accumulator stay in VMEM), the same numbers to float32
+    rounding.
 
 The masks, the running softmax and the row that is written are the same in
 both.  Two kinds of layer share the code:
@@ -55,8 +61,10 @@ from jax import lax
 
 from ...framework.tensor import Tensor, unwrap
 from .. import initializer as I
-from ..functional.attention import (absorbed_products, latent_attend,
-                                    latent_attend_blocked, per_head_products,
+from ..functional.attention import (PerHeadOperands, absorbed_products,
+                                    fused_latent, latent_attend,
+                                    latent_attend_blocked,
+                                    latent_attend_fused, per_head_products,
                                     rotary, rotary_frequencies,
                                     search_widths, select_columns,
                                     select_columns_span, selector_scores,
@@ -287,7 +295,7 @@ class LatentAttention(Layer):
                           preferred_element_type=jnp.float32).astype(dt)
 
     # -- cached: absorbed for a narrow block of queries, per head for a wide ---
-    def cached_form(self, T):
+    def cached_form(self, T, columns=None):
         """The form ``forward_cached`` is traced in for a block of ``T``
         queries, from the layer's own dimensions.  A (query, column) pair
         costs a head ``2 r_kv + d_r`` multiply-adds absorbed and ``d_n +
@@ -295,14 +303,38 @@ class LatentAttention(Layer):
         be expanded, ``r_kv (d_n + d_v)`` a head, once for all ``T``
         queries: per head is the cheaper from ``T (2 r_kv - d_n - d_v) >
         r_kv (d_n + d_v)`` on (``T`` > 170 at 512 / 128 / 128, > 189 at
-        1024 / 192 / 128; never where ``2 r_kv <= d_n + d_v``)."""
-        saved = 2 * self.rkv - self.dn - self.dv
-        return "per_head" if T * saved > self.rkv * (self.dn + self.dv) \
-            else "absorbed"
+        1024 / 192 / 128; never where ``2 r_kv <= d_n + d_v``).
 
-    def _per_head(self, q_n, q_r):
-        return per_head_products(q_n, q_r, unwrap(self.w_uk),
-                                 unwrap(self.w_uv), self.rkv, self.scale)
+        The per-head form is one of two programs for the same numbers:
+        ``"per_head"``, an XLA loop over the column blocks, and
+        ``"per_head_fused"``, ONE Pallas kernel over them with the
+        expanded keys and values, the float32 scores and the running sums
+        in VMEM (ops/pallas/latent_attention.py).  Which, is decided when
+        the program is traced, from what the code can see: the backend
+        (the TPU), no mesh of several devices, and the shapes
+        (``functional.attention.fused_latent``: ``T`` and ``attn_block``
+        whole multiples of 128, the widths on the lane grid, an even
+        number of heads; the chip's own timings are beside
+        ``fused_latent_form``: alone the loop is as fast at 64 heads,
+        served the kernel won at every published width set).
+        ``columns`` is the plane's length where the caller holds the
+        plane: the kernel walks whole blocks of ``attn_block``, so a plane
+        they do not divide keeps the loop."""
+        saved = 2 * self.rkv - self.dn - self.dv
+        if T * saved <= self.rkv * (self.dn + self.dv):
+            return "absorbed"
+        whole = columns is None or columns % self.attn_block == 0
+        fused = whole and fused_latent(
+            T, self.attn_block, self.dn, self.dr, self.dv, self.rkv,
+            self.row_width, self.H)
+        return "per_head_fused" if fused else "per_head"
+
+    def _per_head(self, q_n, q_r, fused=False):
+        """The per-head pair of products, or, for the one-kernel form,
+        just what they are made of."""
+        operands = PerHeadOperands(q_n, q_r, unwrap(self.w_uk),
+                                   unwrap(self.w_uv), self.rkv, self.scale)
+        return operands if fused else per_head_products(*operands)
 
     def forward_cached(self, x, cache, pos, start, write_rows=None):
         """Append the block ``x [B, T, hidden]`` (normed) at column
@@ -315,10 +347,11 @@ class LatentAttention(Layer):
         cols = pos + jnp.arange(T, dtype=jnp.int32)
         pos_ids = jnp.maximum(cols[None, :] - start[:, None], 0)
         q_n, q_r, row, gate, c_q = self._project(x, pos_ids)
-        per_head = self.cached_form(T) == "per_head"
+        form = self.cached_form(T, unwrap(cache.latent).shape[2])
+        per_head = form != "absorbed"
         pad = self.row_width - self.rkv - self.dr
         if per_head:
-            products = self._per_head(q_n, q_r)
+            products = self._per_head(q_n, q_r, form == "per_head_fused")
         else:
             q_hat = jnp.einsum("bthd,hrd->bthr", q_n, unwrap(self.w_uk),
                                preferred_element_type=jnp.float32
@@ -366,7 +399,13 @@ class LatentAttention(Layer):
         c = cols[:, None] - (cols[:, None] - j[None, :]) % n      # [T, n]
         keep = (c[None] > cols[None, :, None] - self.window) \
             & (c[None] >= start[:, None, None])
-        out = latent_attend(products, lat[:, 0], keep)
+        if isinstance(products, PerHeadOperands):
+            # the ring mask handed in, every block of the ring read
+            out = latent_attend_fused(products, lat[:, 0], 0,
+                                      n // self.attn_block, self.attn_block,
+                                      start, cols[0], keep=keep)
+        else:
+            out = latent_attend(products, lat[:, 0], keep)
         return out, LatentWindowCache(Tensor(lat))
 
     def _full(self, x, c_q, products, row, cache, cols, pos_ids, start):
@@ -391,6 +430,7 @@ class LatentAttention(Layer):
         hi = cols[-1] // blk + 1
         keep_of = lambda s0: valid_of(                           # noqa: E731
             s0 + jnp.arange(blk, dtype=jnp.int32))
+        sel = None      # (no membership: the valid columns are the mask)
         if self.selects:
             with jax.named_scope("selector"):
                 qi, wi, ki = self._selector(x, c_q, pos_ids)
@@ -426,8 +466,12 @@ class LatentAttention(Layer):
                             self.topk, widths, branch, first)
                     keep_of = lambda s0: lax.dynamic_slice(     # noqa: E731
                         sel, (0, 0, s0), (B, T, blk))
-        out = latent_attend_blocked(products, lat[:, 0], keep_of, lo, hi,
-                                    blk)
+        if isinstance(products, PerHeadOperands):
+            out = latent_attend_fused(products, lat[:, 0], lo, hi, blk,
+                                      start, cols[0], keep=sel)
+        else:
+            out = latent_attend_blocked(products, lat[:, 0], keep_of, lo, hi,
+                                        blk)
         if not self.selects:
             return out, LatentPlane(Tensor(lat))
         return out, LatentCache(Tensor(lat), Tensor(keys))

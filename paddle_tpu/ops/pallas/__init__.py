@@ -81,6 +81,8 @@ def flash_decode_quant(q, k, v, k_scale, v_scale, start, end, scale=None):
 
 
 from . import fused_bn, fused_conv  # noqa: F401  (kernel families)
+from .latent_attention import (  # noqa: E402
+    fused_latent_form, latent_chunk_attention_fn, supports_latent)
 
 __all__ = ["flash_attention", "flash_attention_fn", "supports",
            "packed_attention", "packed_attention_fn", "supports_packed",
@@ -88,4 +90,6 @@ __all__ = ["flash_attention", "flash_attention_fn", "supports",
            "flash_decode", "flash_decode_fn", "supports_decode",
            "flash_decode_quant", "flash_decode_quant_fn", "dequantize_kv",
            "decode_attention_reference",
+           "latent_chunk_attention_fn", "supports_latent",
+           "fused_latent_form",
            "DEFAULT_BLOCK", "fused_bn", "fused_conv"]

@@ -965,6 +965,12 @@ class Generator:
                 *((("step_passes_rows", True),) if getattr(
                     self._layer, "cached_forward_takes_rows", False)
                   else ()),
+                # the form of a latent model's cached attention is picked
+                # when a program is traced (a step's, the widest block's):
+                # an executable stored under another choice must not load
+                *((("latent_form", self.latent_form(1),
+                    self.latent_form(self._max_len)),)
+                  if self.latent_form(1) is not None else ()),
                 # a program compiled for relaid weights takes no others
                 *((("weight_formats", tuple(sorted(
                     (k, repr(f.layout)) for k, f in self._formats.items()))),)

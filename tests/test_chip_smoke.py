@@ -76,13 +76,17 @@ def test_hybrid_phase_tiny(no_native_build):
 
 def test_latent_forms_phase_tiny(no_native_build):
     """A kimi-shaped and a dots3-shaped full layer, chunks of 32 over a
-    context of 96 in both cached forms: float32 here, so the two agree to
-    summation order."""
+    context of 96 in all three cached forms (the kernel interpreted, at
+    widths the chip's compiler would refuse): float32 here, so they agree
+    to summation order.  The layer's own rule keeps the XLA loop: this is
+    the CPU, and the widths are off the lane grid."""
     rec = chip_smoke.phase_latent_forms(chip_smoke.TINY)["checked"]
     assert set(rec) == {name for name, _, _ in chip_smoke.LATENT_LAYERS}
     for got in rec.values():
         assert got["rel_worst"] < 1e-5 and got["rule"] == "per_head"
-        assert set(got["chunk_ms"]) == {"absorbed", "per_head"}
+        assert got["row_flips"] == 0.0
+        assert set(got["chunk_ms"]) == {"absorbed", "per_head",
+                                        "per_head_fused"}
     assert [got["selects"] for got in rec.values()] == [False, True]
 
 

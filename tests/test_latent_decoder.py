@@ -191,7 +191,7 @@ def test_absorbed_form_equals_the_per_head_form(served, layer):
 def forced_form(attn, form):
     """``attn.forward_cached`` traced in ``form`` whatever the block's
     width (the layer's own rule is ``cached_form``, a function of ``T``)."""
-    attn.cached_form = lambda T: form
+    attn.cached_form = lambda T, columns=None: form
     try:
         yield
     finally:
@@ -284,6 +284,130 @@ def test_the_rule_picks_the_cheaper_form(r_kv, d_n, d_v, T, form):
     assert (per_head < absorbed) == (form == "per_head")
 
 
+@pytest.fixture()
+def on_one_tpu(monkeypatch):
+    """The backend steered (on the CPU every site keeps the XLA loop) and
+    the process's mesh held to one device; the kernel is interpreted."""
+    from paddle_tpu.nn.functional import attention as A
+    from paddle_tpu.parallel.mesh import MeshGuard, make_mesh
+    monkeypatch.setattr(A, "_on_tpu", lambda: True)
+    with MeshGuard(make_mesh({"dp": 1}, jax.devices()[:1])):
+        yield
+
+
+# the published width sets: heads, d_n, d_r, d_v, r_kv, window
+DOTS3_FULL = (128, 128, 64, 128, 512, None)
+KIMI = (64, 128, 64, 128, 512, None)
+GLM5 = (64, 192, 64, 256, 512, None)
+DOTS3_WINDOW = (64, 192, 64, 128, 1024, 513)
+TINY = (4, 8, 4, 8, 12, None)
+
+
+@pytest.mark.parametrize("name,widths,T,columns,where,form", [
+    ("dots3_full", DOTS3_FULL, 512, 12288, "tpu", "per_head_fused"),
+    ("dots3_full_step", DOTS3_FULL, 1, 12288, "tpu", "absorbed"),
+    ("kimi", KIMI, 512, 16384, "tpu", "per_head_fused"),
+    ("kimi_step", KIMI, 1, 16384, "tpu", "absorbed"),
+    ("glm5", GLM5, 512, 24576, "tpu", "per_head_fused"),
+    ("glm5_step", GLM5, 1, 24576, "tpu", "absorbed"),
+    ("dots3_window", DOTS3_WINDOW, 512, 1024, "tpu", "per_head_fused"),
+    ("dots3_window_step", DOTS3_WINDOW, 1, 1024, "tpu", "absorbed"),
+    ("dots3_window_plane_unseen", DOTS3_WINDOW, 512, None, "tpu",
+     "per_head_fused"),
+    ("an_odd_number_of_heads", (63,) + KIMI[1:], 512, 16384, "tpu",
+     "per_head"),
+    ("tiny_model", TINY, 32, 96, "tpu", "per_head"),
+    ("a_chunk_off_the_lanes", DOTS3_FULL, 500, 12288, "tpu", "per_head"),
+    ("a_plane_the_block_does_not_divide", DOTS3_FULL, 512, 12000, "tpu",
+     "per_head"),
+    ("a_plane_unseen", DOTS3_FULL, 512, None, "tpu", "per_head_fused"),
+    ("a_mesh_of_several_devices", DOTS3_FULL, 512, 12288, "mesh",
+     "per_head"),
+    ("the_cpu_backend", DOTS3_FULL, 512, 12288, "cpu", "per_head"),
+], ids=lambda v: v if isinstance(v, str) and "_" in v else "")
+def test_the_rule_picks_the_kernel_by_what_it_sees(name, widths, T, columns,
+                                                   where, form, on_one_tpu,
+                                                   monkeypatch):
+    """``cached_form`` as a table: the per-head form is ONE kernel where
+    the program is traced for a TPU, no mesh of several devices, at
+    shapes on the lane grid (ops/pallas/latent_attention.py: all three
+    published width sets and dots3's window layers); the XLA loop
+    everywhere else; a step is absorbed wherever it is traced.  No flag,
+    no name of a model: the layer's own dimensions and what ``jax``
+    says."""
+    from paddle_tpu import nn
+    from paddle_tpu.nn.functional import attention as A
+    from paddle_tpu.nn.layer.latent_attention import LatentAttention
+    from paddle_tpu.parallel.mesh import MeshGuard, make_mesh
+    H, d_n, d_r, d_v, r_kv, window = widths
+    with jax.default_device(jax.devices("cpu")[0]):
+        attn = LatentAttention(
+            8, H, d_n, d_r, d_v, 4, r_kv, 1e4, window=window, index_topk=0,
+            cache_block=min(T, 512), attn_block=512 if T > 32 else 32,
+            weight_attr=nn.ParamAttr(initializer=nn.initializer.Constant(0.)))
+    if where == "cpu":
+        monkeypatch.setattr(A, "_on_tpu", lambda: False)
+    if where == "mesh":
+        with MeshGuard(make_mesh({"dp": 2}, jax.devices()[:2])):
+            assert attn.cached_form(T, columns) == form
+    else:
+        assert attn.cached_form(T, columns) == form
+
+
+def _aligned_layer(kind):
+    """A layer at the least widths the kernel takes (every width on the
+    lane grid, column blocks of 128), float32: ``kind`` "selector" (topk
+    160 of up to 512 columns), "plain" (no selector) or "window" (129
+    columns over a ring of 384)."""
+    from paddle_tpu.nn.layer.latent_attention import LatentAttention
+    import paddle_tpu as paddle
+    paddle.seed(3)
+    kw = {"selector": dict(index_heads=2, index_dim=64, index_topk=160),
+          "plain": dict(index_topk=0),
+          "window": dict(window=129, index_topk=0)}[kind]
+    return LatentAttention(32, 2, 64, 64, 128, 16, 256, 1e4, cache_block=256,
+                           attn_block=128, **kw)
+
+
+@pytest.mark.parametrize("kind", ["selector", "plain", "window"])
+def test_the_kernel_form_agrees_with_the_loop(kind, on_one_tpu):
+    """``forward_cached`` in the XLA loop and in the kernel (interpreted),
+    NOT forced: the layer's own rule picks the kernel for a block of 256
+    under the steered backend and the loop on the CPU.  Three rows with
+    other ``start``s, three blocks of 256 over 768 columns (the selector
+    binding from the second block on, the window's ring of 384 wrapped):
+    outputs to float32 rounding, the planes to the bit."""
+    from paddle_tpu.nn.functional import attention as A
+    attn = _aligned_layer(kind)
+    assert attn.cached_form(256) == "per_head_fused"
+    x = jax.random.normal(jax.random.key(5), (3, 768, 32))
+    start = (0, 37, 300)
+    fused, planes_f = cached_in_form(attn, "per_head_fused", x,
+                                     (256, 256, 256), start, 768)
+    loop, planes_l = cached_in_form(attn, "per_head", x, (256, 256, 256),
+                                    start, 768)
+    for pf, pl in zip(planes_f, planes_l):
+        for u, v in zip(pf, pl):
+            np.testing.assert_array_equal(u, v)
+    for row, s0 in enumerate(start):
+        np.testing.assert_allclose(fused[row, s0:], loop[row, s0:],
+                                   atol=1e-5 * np.abs(loop).max())
+    # the rule itself, asked where the program would be traced
+    planes0 = attn.gen_ring_cache(1, 768)
+
+    def cached(xs, planes):
+        return attn.forward_cached(xs, type(planes0)(*planes), jnp.int32(0),
+                                   jnp.zeros(1, jnp.int32))[0]
+    text = jax.jit(cached).lower(
+        x[:1, :256], tuple(unwrap(p) for p in planes0)).as_text(
+            debug_info=True)
+    assert "latent_attention_per_head" in text
+    assert "/latent_attention/per_head/" in text
+    with pytest.MonkeyPatch.context() as m:
+        m.setattr(A, "_on_tpu", lambda: False)
+        assert attn.cached_form(256) == "per_head"
+
+
 @pytest.mark.parametrize("T", [24, 25])
 def test_either_side_of_the_threshold(T):
     """A full layer with the selector at the tiny widths (threshold 24),
@@ -361,6 +485,52 @@ def test_wide_chunks_equal_the_reference(served_wide):
     assert _widest_gap(cfg, view, prompts, tokens) < GAP_TOL
     assert st["latent_form"] == {"step": "absorbed", "chunk": "per_head"}
     assert st["chunk_tokens"] == sum(n for n, _ in requests)
+
+
+def test_the_slot_loop_serves_the_kernel_form(on_one_tpu):
+    """The tiny model at the least widths the kernel takes (heads of 64 +
+    64 / 128 over latents of 256, chunks of 256, column blocks of 128; a
+    selector that binds past 160 columns, window planes of 129 + 255
+    columns), traced under the steered backend: the chunk program carries
+    the kernel's custom call under the per-head scope in every layer, the
+    step is the absorbed XLA text, the ledger events, ``stats()`` and the
+    executable cache's identity say ``per_head_fused``, and the served
+    tokens are the reference's."""
+    from benchmark import harness
+    cfg = _tiny()
+    cfg.update(num_attention_heads=2, qk_nope_head_dim=64,
+               qk_rope_head_dim=64, v_head_dim=128, kv_lora_rank=256,
+               index_head_dim=64, index_topk=160, sliding_window_size=129,
+               swa_num_attention_heads=2, swa_qk_nope_head_dim=64,
+               swa_qk_rope_head_dim=64, swa_v_head_dim=128,
+               swa_kv_lora_rank=256, reference_pad=256)
+    cfg["serve"].update(prefill_chunk=256, attn_block=128)
+    mapped = bench_models.to_program(ref.init_weights(cfg, 5))
+    model = bench_models.build(cfg, mapped)
+    view = harness.canonical_view(mapped, bench_models.leaf_ids(cfg))
+    assert model.latent_form(1) == "absorbed"
+    assert model.latent_form(256) == "per_head_fused"
+    gen = Generator(model, max_len=768, seq_buckets=[768])
+    assert ("latent_form", "absorbed", "per_head_fused") \
+        in gen._program_identity()
+    texts = {}
+    for what, prog in (("step", gen._step_program(3, 768)),
+                       ("chunk", gen._chunk_program(3, 256, 768))):
+        _key, _kind, fn, avals, extra, donate = prog
+        texts[what] = jax.jit(fn, donate_argnums=donate).lower(
+            *gen._state_avals(), *avals).as_text(debug_info=True)
+        assert extra["latent_form"] == \
+            {"step": "absorbed", "chunk": "per_head_fused"}[what]
+    assert "latent_attention_per_head" not in texts["step"]
+    assert "/per_head/" not in texts["step"]
+    assert "/latent_attention/per_head/while" not in texts["chunk"]
+    assert texts["chunk"].count("latent_attention_per_head") >= 4
+    requests = [(300, 4), (470, 5), (130, 3)]
+    prompts, tokens, st = _serve(model, requests, chunk=256, columns=768)
+    assert _widest_gap(cfg, view, prompts, tokens) < GAP_TOL
+    assert st["latent_form"] == {"step": "absorbed",
+                                 "chunk": "per_head_fused"}
+    assert st["attn_columns_selected"] < st["attn_columns_valid"]
 
 
 def _moe_fn(layer):

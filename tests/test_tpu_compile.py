@@ -105,7 +105,29 @@ def _loss(fn):
     return loss
 
 
+def _latent(H, d_n, d_v, r_kv, K, S, masked):
+    """The latent family's chunk kernel at a published width set: 512
+    queries over column blocks of 512 of a ``[1, S, K]`` plane, the span
+    traced; ``masked``: a membership / ring mask handed in."""
+    from paddle_tpu.ops.pallas.latent_attention import \
+        latent_chunk_attention_fn
+    i32 = ((), jnp.int32)
+
+    def fn(q, w_uk, w_uv, plane, start, pos, lo, hi, *keep):
+        return latent_chunk_attention_fn(
+            q, w_uk, w_uv, plane, start, pos, lo, hi, r_kv=r_kv,
+            scale=0.07, block=512, keep=keep[0] if keep else None)
+    return fn, (((1, H, 512, d_n + 64), BF16), ((H, r_kv, d_n), BF16), ((H, r_kv, d_v), BF16),
+                ((1, S, K), BF16), ((1,), jnp.int32), i32, i32, i32) \
+        + ((((1, 512, S), jnp.bool_),) if masked else ())
+
+
 CASES = {
+    "latent_chunk_dots3_full": _latent(128, 128, 128, 512, 640, 12288, True),
+    "latent_chunk_kimi": _latent(64, 128, 128, 512, 640, 16384, False),
+    "latent_chunk_glm5": _latent(64, 192, 256, 512, 640, 24576, True),
+    "latent_chunk_dots3_window": _latent(64, 192, 128, 1024, 1152, 1024,
+                                         True),
     "flash_attention_fwd": (_flash, (QKV, QKV, QKV)),
     "flash_attention_bwd": (jax.grad(_loss(_flash), argnums=(0, 1, 2)),
                             (QKV, QKV, QKV)),

@@ -69,7 +69,7 @@ index: 48 layers of `attention` add up.
 | scope | where it is entered | covers |
 |---|---|---|
 | `embed` | `GPTModel.forward` / `forward_cached`, `BertModel.forward`, the latent and hybrid decoders | token, position and type lookups, the mask arithmetic beside them |
-| `attention` | `TransformerEncoderLayer.forward`, `LatentDecoderLayer`, `HybridDecoderLayer` (an attention layer) | a layer's attention half: its norm, the q/k/v/out products, the scores, the residual add.  Inside it the newer families keep `latent_attention`, `latent_attention/per_head`, `selector` (the learned column selector's projections and key write; inside it `selector/score`, the scores of every valid column, apart from `selector/select`, the radix search among them: one conditional with a branch for each width the search may take, `select/cond/branch_<i>_fun`, `branch_0_fun` for a dispatch in which nothing is to be decided), `gqa_attention` |
+| `attention` | `TransformerEncoderLayer.forward`, `LatentDecoderLayer`, `HybridDecoderLayer` (an attention layer) | a layer's attention half: its norm, the q/k/v/out products, the scores, the residual add.  Inside it the newer families keep `latent_attention`, `latent_attention/per_head` (the per-head form of a wide block of queries: an XLA `while` loop over the column blocks, or, where the chunk takes the kernel, ONE custom call `latent_attention_per_head` a layer, under the same path), `selector` (the learned column selector's projections and key write; inside it `selector/score`, the scores of every valid column, apart from `selector/select`, the radix search among them: one conditional with a branch for each width the search may take, `select/cond/branch_<i>_fun`, `branch_0_fun` for a dispatch in which nothing is to be decided), `gqa_attention` |
 | `attention/fused` | `nn.functional.attention` (`attention_bse`, `attention_bnsh`), around the call of a Pallas attention kernel | an un-cached call site that took a kernel: its custom calls by name, `attention_single_block_fwd` and, under `transpose(jvp(..))`, `attention_single_block_bwd` (the blocked form's three have no name of their own).  Bucket `attention`, inside a layer's or alone |
 | `cache_write` | `ring_block_write`, the short convolution's state, `Generator`'s `_slice_row` / `_splice_row` (`cache_write/row_slice`, `cache_write/row_splice`) | every write into a cache plane, and a chunk's batch-1 row out of the planes and back.  Innermost wins: a plane write inside `attention` counts here |
 | `mlp` | `TransformerEncoderLayer.forward`, `SwiGLU.forward`, the decoders' dense layers | a layer's dense feed-forward half with its norm and residual add; a shared expert |
@@ -102,6 +102,24 @@ compile event of the ledger carries `attention_form: {"fused": n, "xla":
 m}`, the step's call sites by form (`count_attention_forms`; the BERT-large
 step of the benchmark reads `{"fused": 24, "xla": 0}` on the chip, `{"fused":
 0, "xla": 24}` on the CPU).
+
+Which form a latent model's cached attention takes is decided the same way,
+from the layer's own dimensions and the width of the query block a program
+is traced for (`LatentAttention.cached_form`): `"absorbed"` for a step's one
+query a row and any narrow block; for a wide block (a prefill chunk) the
+per-head form, as `"per_head"`, an XLA loop over the column blocks, or as
+`"per_head_fused"`, ONE Pallas kernel over the row's live column blocks with
+the expanded keys and values, the float32 scores and the running sums in
+VMEM (`ops/pallas/latent_attention.py`; taken on a TPU, without a mesh of
+several devices, where the query block and `attn_block` are whole multiples
+of 128, the widths lie on the lane grid and the measured table beside
+`fused_latent_form` says it is the faster); `"mixed"` where a model's layers
+decide differently.  `latent_form` is a fact of the programs: in the
+`extra` of the `generate_step` / `generate_chunk` compile events, in
+`SlotLoop.stats()["latent_form"]` as `{"step": .., "chunk": ..}`, in
+`tools/kv_layout_check.py`'s line for each program, and in the persistent
+executable cache's identity (an executable stored under another choice is
+not loaded).
 
 How wide the column selector's search runs is decided in the program, from
 what a dispatch can see: it goes over the narrowest of a short list of

@@ -39,8 +39,10 @@ program) and prints, from ``compiled.as_text()``:
     copy, or is copied inside its body;
   * for a latent-attention model, ``latent_form`` beside each program: the
     form its cached attention takes at that program's block width
-    (``absorbed`` for the step's one query a row, ``per_head`` for a wide
-    chunk: ``LatentAttention.cached_form``);
+    (``absorbed`` for the step's one query a row; for a wide chunk
+    ``per_head_fused``, the per-head form as ONE Pallas kernel, where the
+    shapes take it, else ``per_head``, the XLA loop:
+    ``LatentAttention.cached_form``);
   * ``weight_copies``: the ``copy``/``transpose`` instructions of at least
     1 MB whose operand chain starts at a parameter of the model (a weight
     transposed again in every run), with their MB; ``weight_copies_default``
@@ -394,7 +396,15 @@ def main(argv):
     import jax
     from jax.experimental import topologies
     import importlib
+    from paddle_tpu.nn.functional import attention
     from paddle_tpu.nn.functional.attention import decode_block
+    from paddle_tpu.ops.pallas import _mode
+    # the programs are traced HERE, on the CPU, for the described chip:
+    # what the code asks of the backend while it traces (the kernels'
+    # gates, interpret or compile) is answered for that chip, so that
+    # what is checked is what is served
+    attention._on_tpu = lambda: True
+    _mode.interpret = lambda: False
     with open(os.path.join(ROOT, "benchmark", "configs",
                            argv[0] + ".json")) as f:
         cfg = json.load(f)
