@@ -424,12 +424,11 @@ def _layout(ctx: LintContext) -> List[Diagnostic]:
                         # lowers to a sublane-masked store/load within
                         # tiles, not a cross-tile gather.  Covers both
                         # the canonical generate() cache append
-                        # (dynamic_update_slice, PR 7) and the quantized
-                        # KV reads the fused-dequant path issues — int8
-                        # rows and per-head scale planes read by
-                        # dynamic_slice at the traced cache_position
-                        # with their (full) lane extent.  Only a traced
-                        # lane-dim start is a hazard
+                        # (dynamic_update_slice, PR 7) and the int8
+                        # cache's reads — int8 rows and per-head scale
+                        # planes read by dynamic_slice at the traced
+                        # cache_position with their (full) lane extent.
+                        # Only a traced lane-dim start is a hazard
                         continue
                     which = "lane (last)" if d == ndim - 1 else "sublane"
                     key = (user_source(eqn), name, d)

@@ -559,18 +559,6 @@ define_flag("log_writer_max_mb", 64.0,
             validator=lambda v: float(v) >= 0)
 
 # ---- Autoregressive decoding (text.generation + serving decode) -------------
-define_flag("use_flash_decode",
-            os.environ.get("PADDLE_TPU_FLASH_DECODE", "").lower()
-            in ("1", "true", "yes", "on"),
-            "Route single-query cached attention (the decode step of "
-            "generate()) through the Pallas flash-decoding kernel "
-            "(ops/pallas/flash_decode.py): split-K over the cached "
-            "context with an online-softmax merge, so one query row "
-            "still fills the chip. OFF by default under the "
-            "measured-crossover honesty rule — no chip measurement this "
-            "round (PERF.md decode section records the pending state); "
-            "the XLA masked-attention reference path is bit-matched by "
-            "the interpret-mode tests. Seeded by PADDLE_TPU_FLASH_DECODE.")
 define_flag("decode_buckets", "16,32,64,128,256,512,1024",
             "Sequence-length bucket ladder for incremental decoding: "
             "prompt lengths pad (left) up to the smallest bucket, and "
@@ -716,10 +704,9 @@ define_flag("kv_cache_dtype",
             "model dtype planes — today's layout) or 'int8' (int8 rows "
             "+ per-(token, head) f32 scales as extra cache planes "
             "written at the same traced cache_position), halving "
-            "cached-context HBM.  The dequant is fused into the "
-            "flash-decode kernel's split-K loop when "
-            "FLAGS_use_flash_decode dispatches, and falls back to a "
-            "dequantize-then-attend XLA read otherwise.  One Python "
+            "cached-context HBM.  The attention read dequantizes the "
+            "planes (rows times scales, to the query's dtype) and "
+            "attends over them under the caller's mask.  One Python "
             "branch at cache init; flipping it recompiles the generate "
             "executables (the cache dtype is part of the compile key). "
             "Seeded by PADDLE_TPU_KV_CACHE_DTYPE.",

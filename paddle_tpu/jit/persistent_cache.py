@@ -187,7 +187,7 @@ def runtime_fingerprint() -> Tuple[str, ...]:
 # including them would fragment the cache for identical programs.
 _LOWERING_FLAGS = (
     "use_pallas_kernels", "use_pallas_fused_bn", "use_pallas_fused_conv",
-    "use_flash_decode", "kv_cache_dtype", "use_int8_inference",
+    "kv_cache_dtype", "use_int8_inference",
     "train_sentinel", "spec_decode", "spec_gamma", "static_executor_mode",
     "wide_deep_device_dedup",
 )

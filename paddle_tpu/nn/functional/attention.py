@@ -364,21 +364,6 @@ def scaled_dot_product_attention(query, key, value, attn_mask=None,
     return reshape(out, [b, sq, n, h])
 
 
-def _use_flash_decode(q, k, window):
-    """Dispatch gate for the decode step: FLAGS_use_flash_decode + TPU
-    platform + single-query shapes + a contiguous [start, end) validity
-    window (the kernel masks a window, not an arbitrary dense mask) +
-    UNPACKED (B, N, S, H) planes (``supports_decode`` refuses a cache
-    whose head count or head_dim differs from the query's): the Pallas
-    kernels index heads at axis 1 and were not ported to the packed
-    ring planes, so today they can serve head_dim >= 128 and the int8
-    cache only."""
-    if window is None or not flag("use_flash_decode") or not _on_tpu():
-        return False
-    from ...ops.pallas.flash_decode import supports_decode
-    return supports_decode(unwrap(q).shape, unwrap(k).shape)
-
-
 def cached_attention(q, k, v, attn_mask=None, window=None, k_scale=None,
                      v_scale=None):
     """Incremental attention: (B, N, Tq, H) new-token queries over the
@@ -393,31 +378,22 @@ def cached_attention(q, k, v, attn_mask=None, window=None, k_scale=None,
     optional ``(start[B], end[B])`` contiguous form of the same validity
     (decode steps: Tq == 1).  With it, bf16/f32 planes are read in
     column blocks over the live span only (:func:`_decode_span_fn`;
-    inference-only like every cached path, nothing is taped), unless
-    the Pallas flash-decoding kernel (split-K over the cached context)
-    is eligible and takes over; without it (prefill, a chunk, a verify
-    block) the one-expression XLA masked attention runs.
+    inference-only like every cached path, nothing is taped); without it
+    (prefill, a chunk, a verify block) the one-expression masked
+    attention runs, packed or not.
 
     With ``k_scale``/``v_scale`` given (FLAGS_kv_cache_dtype=int8), k/v
     are int8 row planes and the scales are the per-(token, head) f32
-    planes: the eligible kernel path fuses the dequant into its split-K
-    loop (flash_decode_quant); the XLA fallback dequantizes the cache
-    then attends (decode is inference-only, so the raw read costs no
-    tape).
+    planes: the cache is dequantized to the query's dtype and attended
+    in one expression under ``attn_mask``, window or not (decode is
+    inference-only, so the raw read costs no tape).
     """
     if k_scale is not None:
-        if _use_flash_decode(q, k, window):
-            from ...ops.pallas import flash_decode_quant
-            return flash_decode_quant(q, k, v, k_scale, v_scale,
-                                      window[0], window[1])
         from ..layer.transformer import dequantize_kv_rows
         dt = unwrap(q).dtype
         k = Tensor(dequantize_kv_rows(k, k_scale, dtype=dt))
         v = Tensor(dequantize_kv_rows(v, v_scale, dtype=dt))
-    if _use_flash_decode(q, k, window):
-        from ...ops.pallas import flash_decode
-        return flash_decode(q, k, v, window[0], window[1])
-    if window is not None and k_scale is None:
+    elif window is not None:
         k = unwrap(k)
         return Tensor(_decode_span_fn(
             unwrap(q), k, unwrap(v), unwrap(window[0]), unwrap(window[1]),

@@ -139,8 +139,8 @@ def pack_heads(x, g):
 def quantize_kv_rows(x):
     """Per-(token, head) symmetric int8 quantization of a K/V block
     ``[B, N, T, H]``: one f32 scale per head-row (the dequant is a
-    rank-1 broadcast the flash-decode split-K loop fuses).  Returns
-    (int8 rows ``[B, N, T, H]``, f32 scales ``[B, N, T, 1]``)."""
+    rank-1 broadcast).  Returns (int8 rows ``[B, N, T, H]``, f32 scales
+    ``[B, N, T, 1]``)."""
     import jax.numpy as jnp
     xv = unwrap(x)
     scale = jnp.max(jnp.abs(xv).astype(jnp.float32), axis=-1,
@@ -152,9 +152,8 @@ def quantize_kv_rows(x):
 
 
 def dequantize_kv_rows(q, scale, dtype=None):
-    """Inverse of :func:`quantize_kv_rows` (the XLA fallback's
-    dequantize-then-attend read; the Pallas kernel fuses the same
-    product into its split-K loop)."""
+    """Inverse of :func:`quantize_kv_rows` (the int8 cache's
+    dequantize-then-attend read)."""
     import jax.numpy as jnp
     out = unwrap(q).astype(jnp.float32) * unwrap(scale)
     if dtype is not None:
@@ -180,8 +179,7 @@ class MultiHeadAttention(Layer):
     # extra (B, N, max_len, 1) cache planes written at the SAME traced
     # position — cached-context HBM halves (plus the scale overhead).
     # Keeps the UNPACKED (B, N, max_len, H) contract: one scale per
-    # (token, head) and the Pallas flash_decode_quant kernel both index
-    # heads at axis 1
+    # (token, head), heads at axis 1
     QuantRingCache = collections.namedtuple(
         "QuantRingCache", ["k", "v", "k_scale", "v_scale"])
     # what kind of planes a cache class holds, for whoever must cut or
@@ -282,11 +280,9 @@ class MultiHeadAttention(Layer):
         validity mask: the whole of it, or, for a decode step's one
         query with its ``decode_window``, the column blocks the window
         spans (``cached_attention``).  Quantized caches keep unpacked
-        planes,
-        additionally write int8 rows + scale planes at the same
-        position and dequantize at the attention read (fused into the
-        flash-decode kernel when it dispatches).  Returns (out, updated
-        RingCache/QuantRingCache)."""
+        planes, additionally write int8 rows + scale planes at the same
+        position and dequantize at the attention read.  Returns (out,
+        updated RingCache/QuantRingCache)."""
         from ..functional.attention import cached_attention
         q = self._split_heads(self.q_proj(query))
         k_new = self._split_heads(self.k_proj(query))

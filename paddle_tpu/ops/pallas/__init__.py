@@ -55,31 +55,6 @@ def packed_attention(q, k, v, num_heads, bias=None, causal=False,
     return _packed_bias_prim(q, k, v, bias, **kw)
 
 
-from .flash_decode import (  # noqa: E402
-    decode_attention_reference, dequantize_kv, flash_decode_fn,
-    flash_decode_quant_fn, supports_decode)
-
-_flash_decode_prim = Primitive("flash_decode", flash_decode_fn,
-                               differentiable=False)
-_flash_decode_quant_prim = Primitive("flash_decode_quant",
-                                     flash_decode_quant_fn,
-                                     differentiable=False)
-
-
-def flash_decode(q, k, v, start, end, scale=None):
-    """Flash-decoding on Tensors: (B, N, 1, H) query vs (B, N, S, H)
-    ring cache, valid window [start, end) per row (inference-only)."""
-    return _flash_decode_prim(q, k, v, start, end, scale=scale)
-
-
-def flash_decode_quant(q, k, v, k_scale, v_scale, start, end, scale=None):
-    """Flash-decoding over an int8-quantized ring cache on Tensors: the
-    per-(token, head) dequant is fused into the kernel's split-K loop
-    (inference-only)."""
-    return _flash_decode_quant_prim(q, k, v, k_scale, v_scale, start, end,
-                                    scale=scale)
-
-
 from . import fused_bn, fused_conv  # noqa: F401  (kernel families)
 from .latent_attention import (  # noqa: E402
     fused_latent_form, latent_chunk_attention_fn, supports_latent)
@@ -87,9 +62,6 @@ from .latent_attention import (  # noqa: E402
 __all__ = ["flash_attention", "flash_attention_fn", "supports",
            "packed_attention", "packed_attention_fn", "supports_packed",
            "fused_form",
-           "flash_decode", "flash_decode_fn", "supports_decode",
-           "flash_decode_quant", "flash_decode_quant_fn", "dequantize_kv",
-           "decode_attention_reference",
            "latent_chunk_attention_fn", "supports_latent",
            "fused_latent_form",
            "DEFAULT_BLOCK", "fused_bn", "fused_conv"]

@@ -121,7 +121,7 @@ class GPTModel(nn.Layer):
         window = None
         if t == 1:
             # decode step: the mask is a contiguous [start, pos+1) window,
-            # which is what the flash-decoding kernel dispatches on
+            # which is what the blocked read of the live span goes by
             window = (Tensor(start), Tensor(jnp.broadcast_to(pos + 1, (b,))))
         h, new_cache = self.encoder(
             h, mask, cache=cache,
@@ -180,7 +180,7 @@ class GPTMoEModel(GPTModel):
     stacked ``[E, ...]`` parameters sharded over the expert-parallel
     axis, and the training loss carries the gates' load-balance aux
     term.  Shares GPTModel's incremental-decoding contract verbatim —
-    ``generate()``, flash-decode and the serving decode grid run
+    ``generate()`` and the serving decode grid run
     unchanged (the MoE dispatch is just more ops inside the same two
     executables).
 

@@ -6,14 +6,18 @@ in blocks of ``DECODE_BLOCK`` columns, from the block of the lowest
 Held here, for the head geometries of ``tests/test_kv_packed_layout.py``
 (packed ``g`` = 8 and 2, unpacked ``g`` = 1): the blocked form against the
 one-expression attention under the dense mask; the frontier on and
-around a block edge; unlike ``start``s, on and off an edge; a row that is
+around a block edge; unlike ``start``s, on and off an edge, and every row
+with ONE valid column; bf16 planes within one bfloat16 step of the
+float32 attention; a row that is
 not generating (``start = C``, where the slot loop keeps it) leaves the
 span alone; no live row gives finite values; stale values outside a row's
 window change nothing, and infinities in the blocks outside the span are
 never read; a cache that is no multiple of the block; the slot loop's
 tokens equal to ``generate()`` when the two sides cut their columns into
 different blocks; which calls of ``cached_attention`` take the blocked
-form; and the benchmark's reader of the loop's ``attn_blocks_*``.
+form; the int8 cache's read under a decode window (dequantize, then
+the one expression under the mask); and the benchmark's reader of the
+loop's ``attn_blocks_*``.
 """
 import importlib
 
@@ -25,7 +29,8 @@ import jax.numpy as jnp
 import paddle_tpu as paddle
 from paddle_tpu.framework.tensor import Tensor, unwrap
 from paddle_tpu.nn.functional import attention as A
-from paddle_tpu.nn.layer.transformer import (kv_heads_per_lane_row,
+from paddle_tpu.nn.layer.transformer import (dequantize_kv_rows,
+                                             kv_heads_per_lane_row,
                                              pack_heads, quantize_kv_rows)
 from paddle_tpu.serving.slots import SlotLoop
 from paddle_tpu.text.generation import Generator
@@ -38,6 +43,11 @@ BLOCK = 16          # the tests' block; C spans four of them
 C = 4 * BLOCK
 B = 5
 TOL = dict(rtol=2e-5, atol=2e-6)
+# bf16 operands against float32: one bfloat16 step at the outputs' size
+BF16_TOL = dict(rtol=2e-2, atol=4e-3)
+# each row's start below its frontier; ``one-column``: start == pos
+UNLIKE = [0, 3, BLOCK, BLOCK + 5, 2 * BLOCK]
+STARTS = {"unlike": UNLIKE, "one-column": [C] * B}
 
 
 @pytest.fixture
@@ -56,11 +66,16 @@ def _planes(hd, heads, seed=0, cols=C):
     return q, pack_heads(k, g), pack_heads(v, g), k, v
 
 
+def _mask(start, end, cols=C):
+    """The dense additive mask a ``[start, end)`` window spells."""
+    col = jnp.arange(cols)
+    valid = (col[None, :] >= start[:, None]) & (col[None, :] < end[:, None])
+    return jnp.where(valid, 0.0, -1e30).astype(jnp.float32)[:, None, None, :]
+
+
 def _dense(q, kp, vp, k, v, start, end):
     """Today's one-expression attention under the dense additive mask."""
-    col = jnp.arange(kp.shape[2])
-    valid = (col[None, :] >= start[:, None]) & (col[None, :] < end[:, None])
-    mask = jnp.where(valid, 0.0, -1e30).astype(jnp.float32)[:, None, None, :]
+    mask = _mask(start, end, kp.shape[2])
     if kp.shape[-1] != q.shape[-1]:
         return A._sdpa_packed_fn(q, kp, vp, mask)
     return A._sdpa_mask_fn(q, k, v, mask)
@@ -76,18 +91,36 @@ def blocked(q, k, v, start, end):
                              block=A.decode_block(k.shape[2]))
 
 
+@pytest.mark.parametrize("starts", sorted(STARTS))
 @pytest.mark.parametrize("pos", [BLOCK - 1, BLOCK, C - 1],
                          ids=["edge-1", "edge", "last"])
 @pytest.mark.parametrize("hd,heads", CASES, ids=IDS)
-def test_blocked_equals_the_dense_mask(hd, heads, pos, block16):
-    """Rows with unlike starts, on a block edge (0, BLOCK) and off one."""
+def test_blocked_equals_the_dense_mask(hd, heads, pos, starts, block16):
+    """Rows with unlike starts, on a block edge (0, BLOCK) and off one;
+    rows that hold exactly one valid column, the frontier's."""
     q, kp, vp, k, v = _planes(hd, heads)
-    starts = [0, 3, BLOCK, BLOCK + 5, 2 * BLOCK]
-    start, end = _window([min(s, pos) for s in starts], pos)
+    start, end = _window([min(s, pos) for s in STARTS[starts]], pos)
     got = blocked(q, kp, vp, start, end)
     want = _dense(q, kp, vp, k, v, start, end)
     assert got.shape == want.shape == q.shape
     np.testing.assert_allclose(got, want, **TOL)
+
+
+@pytest.mark.parametrize("whole", [False, True],
+                         ids=["windowed", "whole-ring"])
+@pytest.mark.parametrize("hd,heads", CASES, ids=IDS)
+def test_bf16_planes_within_one_step_of_float32(hd, heads, whole, block16):
+    """What the cells serve: bf16 queries and planes (products in bf16,
+    float32 scores and sums) against the dense mask in float32 over the
+    same values."""
+    q, kp, vp, k, v = (a.astype(jnp.bfloat16) for a in _planes(hd, heads, 8))
+    start, end = _window([0] * B, C - 1) if whole \
+        else _window(UNLIKE, 2 * BLOCK + 9)
+    got = blocked(q, kp, vp, start, end)
+    assert got.dtype == jnp.bfloat16 and got.shape == q.shape
+    want = _dense(*(a.astype(jnp.float32) for a in (q, kp, vp, k, v)),
+                  start, end)
+    np.testing.assert_allclose(got.astype(jnp.float32), want, **BF16_TOL)
 
 
 @pytest.mark.parametrize("hd,heads", CASES, ids=IDS)
@@ -243,6 +276,35 @@ def test_which_calls_take_the_blocked_form(monkeypatch):
         A.cached_attention(Tensor(q), Tensor(kq), Tensor(vq), attn_mask=mask,
                            window=win, k_scale=Tensor(ks), v_scale=Tensor(vs))
         assert not calls
+
+
+@pytest.mark.parametrize("case", ["whole-ring", "windowed", "one-column",
+                                  "bf16-query"])
+def test_int8_read_under_a_window(case):
+    """The int8 cache's planes are unpacked rows and scales: the step
+    dequantizes them to the query's dtype and attends in one expression
+    under the mask its window spells, over every column: held to the
+    float32 attention over the same dequantized values."""
+    hd, heads = CASES[1]
+    q, _, _, k, v = _planes(hd, heads, seed=9)
+    pos = C - 1 if case == "whole-ring" else 2 * BLOCK + 3
+    start, end = _window({"whole-ring": [0] * B,
+                          "one-column": [pos] * B}.get(case, UNLIKE), pos)
+    tol = TOL
+    if case == "bf16-query":
+        q, tol = q.astype(jnp.bfloat16), BF16_TOL
+    k8, ks = quantize_kv_rows(k)
+    v8, vs = quantize_kv_rows(v)
+    mask = _mask(start, end)
+    got = unwrap(A.cached_attention(
+        Tensor(q), Tensor(k8), Tensor(v8), attn_mask=Tensor(mask),
+        window=(Tensor(start), Tensor(end)), k_scale=Tensor(ks),
+        v_scale=Tensor(vs)))
+    assert got.dtype == q.dtype and got.shape == q.shape
+    want = A._sdpa_mask_fn(*(a.astype(jnp.float32) for a in (
+        q, dequantize_kv_rows(k8, ks, dtype=q.dtype),
+        dequantize_kv_rows(v8, vs, dtype=q.dtype))), mask)
+    np.testing.assert_allclose(got.astype(jnp.float32), want, **tol)
 
 
 @pytest.mark.parametrize("stats,value", [

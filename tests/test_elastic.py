@@ -145,7 +145,19 @@ def test_elastic_watchdog_real_heartbeats(tmp_path):
     rendezvous (no heartbeat) is evicted by the launcher-side monitor and
     the whole gang relaunched — process polling alone would wait forever.
     Uses real HeartbeatReporter/TCPStore traffic, the lazy monitor
-    factory the launch CLI uses, and SIGKILL eviction."""
+    factory the launch CLI uses, and SIGKILL eviction.
+
+    The launcher counts its warm-up from the moment the gang is spawned,
+    and a deployment sizes it for its workers' start-up.  Here importing
+    the package takes anything from 3 to 15 s with the host's load, so
+    the test owns that clock: a worker says when it has imported, the
+    spawn of the gang's last rank returns once both have and lets them
+    go, and the 1.5 s warm-up covers what it is meant to, a store and a
+    first heartbeat.  (Counted from ``Popen`` it was over before either
+    import, and a relaunched rank 1 whose first heartbeat came more than
+    one poll behind rank 0's store was evicted a second time.)  A worker
+    outlives the warm-up, so the watchdog looks at the healthy gang too."""
+    import signal
     import socket as _socket
     import subprocess
     from paddle_tpu.distributed.fleet.base.tcp_store import TCPStore
@@ -160,13 +172,15 @@ def test_elastic_watchdog_real_heartbeats(tmp_path):
         "from paddle_tpu.distributed.fleet.base.tcp_store import TCPStore\n"
         "from paddle_tpu.distributed.fleet.elastic import HeartbeatReporter\n"
         "rank, port, attempt = (int(a) for a in sys.argv[1:4])\n"
+        "print('imported', flush=True)\n"
+        "sys.stdin.readline()           # the gang goes together\n"
         "if rank == 1 and attempt == 0:\n"
         "    time.sleep(120)            # hung before rendezvous: no store,"
         " no heartbeat\n"
         "store = TCPStore('127.0.0.1', port, is_master=(rank == 0),"
         " timeout=30.0)\n"
         "hb = HeartbeatReporter(store, rank, interval=0.1).start()\n"
-        "time.sleep(1.0)\n"
+        "time.sleep(3.0)\n"
         "hb.stop()\n"
         "raise SystemExit(0)\n").format(
             repo=os.path.dirname(os.path.dirname(os.path.abspath(__file__))))
@@ -174,12 +188,22 @@ def test_elastic_watchdog_real_heartbeats(tmp_path):
     script.write_text(worker)
 
     supervisor = []
+    spawned = []
 
     def spawn(local):
         attempt = supervisor[0].generation if supervisor else 0
-        return subprocess.Popen(
+        spawned.append(subprocess.Popen(
             [sys.executable, str(script), str(local), str(port),
-             str(attempt)])
+             str(attempt)], stdin=subprocess.PIPE, stdout=subprocess.PIPE,
+            text=True))
+        if local == 1:
+            gang = spawned[-2:]
+            for p in gang:
+                assert p.stdout.readline() == "imported\n"
+            for p in gang:
+                p.stdin.write("go\n")
+                p.stdin.flush()
+        return spawned[-1]
 
     state = {}
 
@@ -197,9 +221,18 @@ def test_elastic_watchdog_real_heartbeats(tmp_path):
                        monitor=monitor_factory, watchdog_warmup=1.5)
     supervisor.append(el)
     t0 = time.time()
-    rc, restarts = el.run()
+    try:
+        rc, restarts = el.run()
+    finally:
+        for p in spawned:
+            if p.poll() is None:
+                p.kill()
+            p.communicate()
     assert rc == 0
     assert restarts[0] == 1
-    assert time.time() - t0 < 60
+    # evicted, not waited for: the hung rank died of the watchdog's
+    # SIGKILL, well inside the 120 s it meant to sleep
+    assert [p.returncode for p in spawned] == [-signal.SIGKILL] * 2 + [0, 0]
+    assert time.time() - t0 < 120
     from paddle_tpu.utils.monitor import stat_get
     assert stat_get("elastic_restart_generation") >= 1

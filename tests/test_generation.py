@@ -260,35 +260,31 @@ def test_module_level_generate_and_model_surface():
 # -- flags hygiene (satellite) -----------------------------------------------
 
 def test_decode_flags_registered_with_defaults():
-    assert flag("use_flash_decode") is False       # gated OFF
     assert flag("decode_max_len") == 1024
     assert "16" in str(flag("decode_buckets"))
 
 
 def test_decode_flags_idempotent_reregistration():
     # same default: no-op; different default: loud error
-    define_flag("use_flash_decode", False, "dup")
     define_flag("decode_max_len", 1024, "dup")
     define_flag("decode_buckets", "16,32,64,128,256,512,1024", "dup")
     with pytest.raises(ValueError):
-        define_flag("use_flash_decode", True, "conflicting")
+        define_flag("decode_buckets", "16,32", "conflicting")
     with pytest.raises(ValueError):
         define_flag("decode_max_len", 2048, "conflicting")
 
 
 def test_decode_flags_snapshot_restore_roundtrip():
     snap = flags_snapshot()
-    set_flags({"FLAGS_use_flash_decode": True,
-               "FLAGS_decode_buckets": "4,8",
+    set_flags({"FLAGS_decode_buckets": "4,8",
                "FLAGS_decode_max_len": 8})
-    assert flag("use_flash_decode") is True
     assert flag("decode_max_len") == 8
     # the generator reads the mutated flags...
     m = _model(seed=19)
     gen = Generator(m)
     assert gen.seq_buckets == [4, 8]
     flags_restore(snap)
-    assert flag("use_flash_decode") is False
+    assert "16" in str(flag("decode_buckets"))
     assert flag("decode_max_len") == 1024
     with pytest.raises(ValueError):
         set_flags({"FLAGS_decode_buckets": "0,4"})     # validator

@@ -263,7 +263,7 @@ def test_layout_lane_dim_dynamic_update_seeded():
 
 
 def test_layout_quantized_kv_scale_read_clean():
-    """The fused-dequant read pattern (PR 12): dynamic_slice at a TRACED
+    """The int8 cache's read pattern (PR 12): dynamic_slice at a TRACED
     cache position on the sublane (sequence) dim with the lane dim fully
     read — the canonical quantized-KV access (int8 rows and their
     per-head scale planes) is a sublane-masked in-tile load, exempt the

@@ -288,27 +288,3 @@ def test_session_park_and_resume_on_packed_planes(hd, heads):
         assert loop.counters["restored"] >= 2
     finally:
         loop.close()
-
-
-def test_flash_decode_gate_refuses_packed_planes(monkeypatch):
-    """The Pallas decode kernels keep the unpacked (B, N, S, H) contract:
-    with the flag on and a TPU backend the gate must still answer False
-    for packed planes (and True for the same cache unpacked)."""
-    from paddle_tpu.framework.flags import (flags_restore, flags_snapshot,
-                                            set_flags)
-    from paddle_tpu.nn.functional import attention as att
-    from paddle_tpu.ops.pallas.flash_decode import supports_decode
-    assert supports_decode((4, 25, 1, 64), (4, 25, 1024, 64))
-    assert not supports_decode((4, 25, 1, 64), (4, 13, 1024, 128))
-    assert not supports_decode((4, 5, 1, 16), (4, 1, 1024, 128))
-    snap = flags_snapshot()
-    try:
-        set_flags({"FLAGS_use_flash_decode": True})
-        monkeypatch.setattr(att.jax, "default_backend", lambda: "tpu")
-        q = jnp.zeros((4, 25, 1, 64))
-        window = (jnp.zeros((4,), jnp.int32), jnp.ones((4,), jnp.int32))
-        assert att._use_flash_decode(q, jnp.zeros((4, 25, 1024, 64)), window)
-        assert not att._use_flash_decode(q, jnp.zeros((4, 13, 1024, 128)),
-                                         window)
-    finally:
-        flags_restore(snap)

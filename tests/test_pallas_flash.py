@@ -371,7 +371,6 @@ def test_cached_paths_never_ask(monkeypatch, one_device):
     from paddle_tpu import nn
     from paddle_tpu.nn.functional import attention as A
     monkeypatch.setattr(A, "_on_tpu", lambda: True)
-    monkeypatch.setattr(A, "_use_flash_decode", lambda *a: False)
     mha = nn.MultiHeadAttention(128, 2)
     mha.eval()
     x = paddle.to_tensor(rng.randn(2, 128, 128).astype("float32"))

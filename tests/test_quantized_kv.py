@@ -1,12 +1,12 @@
 """int8-quantized KV ring cache tests.
 
-The fused-dequant flash-decode kernel must bit-match the
-dequantize-then-attend XLA reference (interpret mode — the PR 7
-tolerance discipline), the quantized ring writes must store int8 rows +
-per-(token, head) f32 scale planes at the same traced position, cache
-plane bytes/token must halve vs bf16 (plus the scale overhead), and
-quantization must compose with both plain and speculative generate()
-behind FLAGS_kv_cache_dtype with one Python branch off-path."""
+The quantized ring writes must store int8 rows + per-(token, head) f32
+scale planes at the same traced position, the read must equal the exact
+masked attention over the dequantized planes, cache plane bytes/token
+must halve vs bf16 (plus the scale overhead), and quantization must
+compose with both plain and speculative generate() behind
+FLAGS_kv_cache_dtype with one Python branch off-path.  (The read under a
+decode window: tests/test_decode_blocked_attention.py.)"""
 import numpy as np
 import pytest
 import jax
@@ -19,9 +19,6 @@ from paddle_tpu.nn.layer.transformer import (MultiHeadAttention,
                                              dequantize_kv_rows,
                                              kv_heads_per_lane_row,
                                              quantize_kv_rows)
-from paddle_tpu.ops.pallas.flash_decode import (decode_attention_reference,
-                                                dequantize_kv,
-                                                flash_decode_quant_fn)
 from paddle_tpu.profiler import ledger
 from paddle_tpu.text.generation import Generator
 from paddle_tpu.text.models.gpt import GPTConfig, GPTModel
@@ -29,70 +26,8 @@ from paddle_tpu.text.models.gpt import GPTConfig, GPTModel
 V = 64
 
 
-def _quantize(x):
-    scale = np.maximum(np.abs(x).max(-1, keepdims=True), 1e-9) / 127.0
-    q = np.clip(np.round(x / scale), -127, 127).astype(np.int8)
-    return jnp.asarray(q), jnp.asarray(scale.astype(np.float32))
-
-
 def _rand(shape, seed=0):
     return np.random.RandomState(seed).randn(*shape).astype(np.float32)
-
-
-def _check_kernel(B, N, H, S, start, end, block_k, seed=0, qdtype=None):
-    q = jnp.asarray(_rand((B, N, 1, H), seed))
-    if qdtype is not None:
-        q = q.astype(qdtype)
-    k8, ks = _quantize(_rand((B, N, S, H), seed + 1))
-    v8, vs = _quantize(_rand((B, N, S, H), seed + 2))
-    s = None if start is None else jnp.asarray(start, jnp.int32)
-    e = None if end is None else jnp.asarray(end, jnp.int32)
-    out = flash_decode_quant_fn(q, k8, v8, ks, vs, s, e, block_k=block_k)
-    ref = decode_attention_reference(
-        q.astype(jnp.float32), dequantize_kv(k8, ks),
-        dequantize_kv(v8, vs), s, e)
-    assert out.shape == (B, N, 1, H) and out.dtype == q.dtype
-    atol = 4e-3 if qdtype is not None else 2e-6
-    np.testing.assert_allclose(np.asarray(out, np.float32),
-                               np.asarray(ref, np.float32), atol=atol,
-                               rtol=1e-6 if qdtype is None else 2e-2)
-
-
-# -- fused dequant kernel vs the dequantize-then-attend reference ------------
-
-def test_quant_kernel_matches_reference_full_window():
-    _check_kernel(2, 3, 64, 256, None, None, block_k=128)
-
-
-def test_quant_kernel_matches_reference_windowed_multi_split():
-    _check_kernel(2, 2, 64, 512, [3, 200], [380, 512], block_k=128)
-
-
-def test_quant_kernel_empty_splits_ignored():
-    _check_kernel(1, 2, 64, 512, [400], [512], block_k=128)
-    _check_kernel(1, 1, 64, 512, [140], [250], block_k=128)
-
-
-def test_quant_kernel_head_dim_128_and_single_column():
-    _check_kernel(2, 2, 128, 256, [0, 30], [256, 100], block_k=128)
-    _check_kernel(2, 1, 64, 256, [17, 255], [18, 256], block_k=128)
-
-
-def test_quant_kernel_bf16_query():
-    _check_kernel(2, 2, 64, 256, [5, 100], None, block_k=128,
-                  qdtype=jnp.bfloat16)
-
-
-def test_quant_split_merge_matches_single_split():
-    q = jnp.asarray(_rand((2, 2, 1, 64)))
-    k8, ks = _quantize(_rand((2, 2, 256, 64), 1))
-    v8, vs = _quantize(_rand((2, 2, 256, 64), 2))
-    s = jnp.asarray([10, 64], jnp.int32)
-    e = jnp.asarray([200, 256], jnp.int32)
-    many = flash_decode_quant_fn(q, k8, v8, ks, vs, s, e, block_k=128)
-    one = flash_decode_quant_fn(q, k8, v8, ks, vs, s, e, block_k=256)
-    np.testing.assert_allclose(np.asarray(many), np.asarray(one),
-                               atol=2e-6, rtol=1e-6)
 
 
 # -- quantize/dequantize row helpers -----------------------------------------

@@ -2,8 +2,8 @@
 chip_smoke.py's kernels phase, at that phase's shapes, handed to the TPU
 compiler for a *described* (not attached) ``v5e:2x2``.
 
-Interpret mode passes shapes the chip's compiler refuses (the flash-decode
-``start``/``end`` windows were a ``(1, 1)`` VMEM block until PR 21), so
+Interpret mode passes shapes the chip's compiler refuses (block shapes
+that do not tile, a VMEM stack over the limit), so
 these compiles are the regression guard that costs no chip time.  Nothing
 runs: a passing compile is not a chip run.
 
@@ -17,10 +17,9 @@ import pytest
 from jax.sharding import SingleDeviceSharding
 
 import chip_smoke
-from paddle_tpu.ops.pallas import (flash_attention_fn, flash_decode_fn,
-                                   flash_decode_quant_fn, fused_bn,
+from paddle_tpu.ops.pallas import (flash_attention_fn, fused_bn,
                                    fused_conv, packed_attention_fn, supports,
-                                   supports_decode, supports_packed)
+                                   supports_packed)
 from paddle_tpu.ops.pallas import _mode
 
 BF16 = jnp.bfloat16
@@ -69,8 +68,6 @@ def compile_for_chip(one_chip, cache_off, monkeypatch):
 
 B, N, S, H = chip_smoke.FULL.attn
 QKV = ((B, N, S, H), BF16)
-Q1 = ((B, N, 1, H), BF16)
-WIN = ((B,), jnp.int32)
 CONV_X, CONV_W = chip_smoke.FULL.conv_x, chip_smoke.FULL.conv_w
 COUT = CONV_W[0]
 CH = ((COUT,), jnp.float32)
@@ -135,14 +132,6 @@ CASES = {
     "single_block_attention_bwd": (jax.grad(_loss(_packed),
                                             argnums=(0, 1, 2)),
                                    (QKV_T, QKV_T, QKV_T)),
-    # regression for PR 21 §5: both refused by Mosaic before the windows
-    # moved to SMEM
-    "flash_decode": (flash_decode_fn, (Q1, QKV, QKV, WIN, WIN)),
-    "flash_decode_int8": (
-        flash_decode_quant_fn,
-        (Q1, ((B, N, S, H), jnp.int8), ((B, N, S, H), jnp.int8),
-         ((B, N, S, 1), jnp.float32), ((B, N, S, 1), jnp.float32),
-         WIN, WIN)),
     "fused_conv_bn_relu_fwd": (_conv, ((CONV_X, BF16), (CONV_W, BF16),
                                        CH, CH)),
     "fused_conv_bn_relu_bwd": (
@@ -168,7 +157,6 @@ def test_gates_admit_the_compiled_shapes():
     (head dim 64 included) — a gate that said no would hide the kernel."""
     assert supports((B, N, S, H), (B, N, S, H), causal=True)
     assert supports_packed((BT, NT, ST, HT), (BT, NT, ST, HT))
-    assert supports_decode((B, N, 1, H), (B, N, S, H))
     assert fused_conv.supports(CONV_X, CONV_W, stride=1, padding=1,
                                itemsize=2)
     assert fused_bn._pick_tile(*X2D[0]) > 0
