@@ -1,0 +1,7 @@
+"""Mean device ms a run that the slot loop's chunk program spends under the
+scope component ``linear_attention`` (``_sala_scope``): the chunked scans."""
+from benchmark.layer_metrics import _sala_scope
+
+
+def compute(ctx):
+    return _sala_scope.ms(ctx, "chunk", _sala_scope.LINEAR)

@@ -133,7 +133,7 @@ def decode_block(C):
 
 
 @functools.partial(jax.jit, static_argnames=("block", "rep"))
-def _decode_span_fn(q, k, v, start, end, block, rep=1):
+def _decode_span_fn(q, k, v, start, end, block, rep=1, keep=None):
     """One-query attention of ``(B, N, 1, H)`` queries over ring planes
     ``(B, G, C, g*H)`` (packed as for :func:`_sdpa_packed_fn`, with
     ``rep`` query heads a cached head: grouped-query attention over
@@ -157,7 +157,9 @@ def _decode_span_fn(q, k, v, start, end, block, rep=1):
     section 6, PR 28).  A row that is not generating must come with
     ``start >= C`` (the slot loop keeps it there) so that it does not
     widen the span; it, and every row of a step with nothing live, gets
-    finite values that nobody uses."""
+    finite values that nobody uses.  ``keep [B, G, 1, C]`` (bool), where
+    given, takes further columns of a lane row away (a layer that chose
+    the blocks it reads); they are masked, not skipped."""
     b, n, t, hd = q.shape
     groups, C, lanes = k.shape[1], k.shape[2], k.shape[3]
     blocks = -(-C // block)
@@ -188,8 +190,14 @@ def _decode_span_fn(q, k, v, start, end, block, rep=1):
     slab = jax.lax.fori_loop(
         lo, hi, score, jnp.zeros((blocks,) + qs.shape[:-1] + (block,),
                                  jnp.float32))
-    slab = jnp.where(valid[:, :, None, None, None, :],
-                     slab * (1.0 / math.sqrt(hd)), _NEG)
+    valid = valid[:, :, None, None, None, :]
+    if keep is not None:
+        # [B, G, 1, C] -> [blocks, B, G, 1, 1, block], slot i as ``cols[i]``
+        # (a plain reshape where the blocks tile the plane)
+        kb = keep.reshape(b, groups, blocks, block) if C % block == 0 \
+            else keep[:, :, 0][:, :, cols]
+        valid = valid & jnp.moveaxis(kb, 2, 0)[:, :, :, None, None, :]
+    slab = jnp.where(valid, slab * (1.0 / math.sqrt(hd)), _NEG)
     e = jnp.exp(slab - slab.max(axis=(0, -1))[None, ..., None])
     probs = (e / e.sum(axis=(0, -1))[None, ..., None]).astype(q.dtype)
 
@@ -206,7 +214,7 @@ SPAN_BLOCK = 512
 
 
 @functools.partial(jax.jit, static_argnames=("block", "rep"))
-def _block_span_fn(q, k, v, start, first, block, rep=1):
+def _block_span_fn(q, k, v, start, first, block, rep=1, keep=None):
     """Attention of a BLOCK of queries ``(B, N, T, H)`` that sit at the
     columns ``first .. first + T - 1`` over ring planes ``(B, G, C, g*H)``
     (packed as for :func:`_sdpa_packed_fn`, ``rep`` queries a cached
@@ -218,7 +226,9 @@ def _block_span_fn(q, k, v, start, first, block, rep=1):
     ``[heads, T, C]`` scores and the columns nobody can see cost nothing:
     the one-expression form scores every column of the ring whatever the
     context.  A query with no valid column (left padding) gets finite
-    values that nobody uses."""
+    values that nobody uses.  ``keep [B, G, T, C]`` (bool), where given,
+    takes further columns away from a query of a lane row: masked, not
+    skipped."""
     b, n, t, hd = q.shape
     groups, C, lanes = k.shape[1], k.shape[2], k.shape[3]
     blocks = -(-C // block)
@@ -240,7 +250,11 @@ def _block_span_fn(q, k, v, start, first, block, rep=1):
         s = jnp.einsum("bgjtl,bgcl->bgjtc", qs,
                        jax.lax.dynamic_slice_in_dim(k, s0, block, 2),
                        preferred_element_type=jnp.float32) * scale
-        s = jnp.where(valid[:, None, None], s, _NEG)
+        valid = valid[:, None, None]
+        if keep is not None:
+            valid = valid & jax.lax.dynamic_slice_in_dim(
+                keep, s0, block, 3)[:, :, None]
+        s = jnp.where(valid, s, _NEG)
         m_new = jnp.maximum(m, s.max(-1))
         p = jnp.exp(s - m_new[..., None])
         corr = jnp.exp(m - m_new)
@@ -257,21 +271,23 @@ def _block_span_fn(q, k, v, start, first, block, rep=1):
     return _own_lanes(out.astype(q.dtype), own, n, hd, rep)
 
 
-def span_attention(q, k, v, start, first, rep=1):
+def span_attention(q, k, v, start, first, rep=1, keep=None):
     """Causal attention of the queries ``(B, N, T, H)`` at columns ``first
     .. first + T - 1`` over bf16/f32 ring planes, each row from its
     ``start[B]``, reading the live span of the ring only: a step's one
     query a row through :func:`_decode_span_fn` (two passes, one softmax),
     a wider block through :func:`_block_span_fn` (a running softmax).
-    Raw arrays; inference only."""
+    ``keep [B, G, T, C]`` masks further columns of a lane row for a query
+    (:func:`block_keep`).  Raw arrays; inference only."""
     B, _, T, _ = q.shape
     C = k.shape[2]
+    kw = {} if keep is None else {"keep": keep}
     if T == 1:
         return _decode_span_fn(q, k, v, start,
                                jnp.broadcast_to(first + 1, (B,)),
-                               block=decode_block(C), rep=rep)
+                               block=decode_block(C), rep=rep, **kw)
     return _block_span_fn(q, k, v, start, first,
-                          block=min(SPAN_BLOCK, C), rep=rep)
+                          block=min(SPAN_BLOCK, C), rep=rep, **kw)
 
 
 _sdpa = Primitive("scaled_dot_product_attention", _sdpa_fn)
@@ -646,6 +662,127 @@ def select_columns_span(scores, valid, k, widths, branch, first):
 
     return jax.lax.switch(branch, [lambda _: valid()]
                           + [search(W) for W in widths], None)
+
+
+# -- block-sparse attention over a pooled-key plane ------------------------------
+# A layer that reads, past ``dense_len`` tokens of context, only ``top``
+# blocks of ``block`` columns, chosen for all the query heads of a cached
+# head at once from scores over mean-pooled keys: window ``j`` of a request is
+# the mean of its keys at positions ``[stride j, stride j + kernel)``.  Block
+# and window edges count from the REQUEST's first token (``column - start``),
+# so two rows of one session have them at different columns.
+BlockSparse = collections.namedtuple(
+    "BlockSparse", ["kernel", "stride", "block", "top", "init_blocks",
+                    "window", "dense_len"])
+
+
+def pooled_entries(C, stride):
+    """Entries of the pooled-key plane beside a K plane of ``C`` columns:
+    one for every ``stride`` columns."""
+    return -(-int(C) // int(stride))
+
+
+def pool_keys_write(pooled, k, pos, T, start, sp):
+    """Write the pooled keys that the block of ``T`` columns from ``pos``
+    completes.  ``pooled [B, G, E, L]``, ``k [B, G, C, L]`` (the K plane
+    AFTER the block's write), ``start [B]``.  Entry ``e`` of a row holds
+    the row's one window whose LAST key lies in the columns ``[stride e,
+    stride e + stride)``: window ``j`` ends at column ``start + stride j +
+    kernel - 1``, so there is exactly one, at the in-group offset ``(start
+    + kernel - 1) % stride``, and ``e = j + (start + kernel - 1) //
+    stride``.  Every row writes at the same entries (the slot loop's
+    lockstep column), each under its own mask: a row whose window does not
+    end inside the block, or begins before its ``start``, keeps what the
+    entry held.  The mean is a product with a 0/1 matrix over the slice
+    of ``k`` that holds the windows, accumulated in float32."""
+    B, G, E, L = pooled.shape
+    C = k.shape[2]
+    st, kn = sp.stride, sp.kernel
+    n = min((T + st - 2) // st + 1, E)          # groups the block may touch
+    a = jnp.clip(pos // st, 0, E - n)
+    ends = (a + jnp.arange(n, dtype=jnp.int32))[None, :] * st \
+        + ((start + (kn - 1)) % st)[:, None]                      # [B, n]
+    ok = (ends >= pos) & (ends <= pos + (T - 1)) \
+        & (ends - (kn - 1) >= start[:, None])
+    W = min(n * st + kn, C)
+    w0 = jnp.clip(a * st - kn, 0, C - W)
+    cols = w0 + jnp.arange(W, dtype=jnp.int32)
+    inside = ok[..., None] & (cols >= ends[..., None] - (kn - 1)) \
+        & (cols <= ends[..., None])                               # [B, n, W]
+    new = jnp.einsum("bew,bgwl->bgel", inside.astype(k.dtype),
+                     jax.lax.dynamic_slice_in_dim(k, w0, W, 2),
+                     preferred_element_type=jnp.float32) * (1.0 / kn)
+    old = jax.lax.dynamic_slice_in_dim(pooled, a, n, 2)
+    return jax.lax.dynamic_update_slice_in_dim(
+        pooled, jnp.where(ok[:, None, :, None], new.astype(pooled.dtype),
+                          old), a, 2)
+
+
+def choose_blocks(q, pooled, pos, start, sp, rep):
+    """Which blocks of its request each query reads: ``[B, G, T, nb]``
+    bool over the ``nb = ceil(C / block)`` blocks of request positions.
+    ``q [B, G * rep, T, d]`` (the queries at columns ``pos .. pos + T -
+    1``), ``pooled [B, G, E, d]`` (:func:`pool_keys_write`'s plane, a
+    cached head a lane row).  For a query with context ``n = column -
+    start + 1``: every valid block while ``n <= dense_len``; else ``a_j =
+    sum_{h in group} softmax_j(q_h . c_j / sqrt(d))`` over the windows
+    that lie whole inside the context, a block's score the largest
+    ``a_j`` among the windows that overlap it, block(s) ``< init_blocks``
+    and the blocks that hold the last ``window`` tokens always, the rest
+    of ``top`` by score (:func:`select_columns`: the lower block first
+    among equals)."""
+    B, N, T, d = q.shape
+    G, E = pooled.shape[1], pooled.shape[2]
+    st, kn, bk = sp.stride, sp.kernel, sp.block
+    r, kk = bk // st, kn // st
+    if bk % st or kn % st:
+        raise ValueError(f"block {bk} and kernel {kn} over stride {st}")
+    C = E * st
+    nb = -(-C // bk)
+    cols = pos + jnp.arange(T, dtype=jnp.int32)
+    ctx = cols[None, :] - start[:, None] + 1                      # [B, T]
+    # a row's window ``j`` is its entry ``j + off`` and ends ``lag`` columns
+    # into that entry's group (:func:`pool_keys_write`)
+    off, lag = jnp.divmod(start + (kn - 1), st)                   # [B]
+    e = jnp.arange(E, dtype=jnp.int32)
+    # a window is scored iff it begins at or after ``start`` and ends at
+    # or before the query's column
+    seen = (e[None, None, :] >= off[:, None, None]) \
+        & (e[None, None, :] * st + lag[:, None, None]
+           <= cols[None, :, None])                                # [B, T, E]
+    s = jnp.einsum("bgrtd,bged->bgrte", q.reshape(B, G, rep, T, d), pooled,
+                   preferred_element_type=jnp.float32) * d ** -0.5
+    s = jnp.where(seen[:, None, None], s, _NEG)
+    p = jnp.exp(s - s.max(-1, keepdims=True))
+    p = jnp.where(seen[:, None, None], p / p.sum(-1, keepdims=True), 0.0)
+    a = p.sum(2)                                              # [B, G, T, E]
+    # entry -> window
+    a = jax.vmap(lambda x, o: jax.lax.dynamic_slice_in_dim(x, o, E, 2))(
+        jnp.pad(a, ((0, 0),) * 3 + ((0, E),)), off)
+    # block ``b`` overlaps the windows ``r b - kk + 1 .. r b + r - 1``
+    a = jnp.pad(a, ((0, 0),) * 3 + ((kk - 1, r * nb - E),))
+    score = functools.reduce(jnp.maximum, (
+        a[..., i:i + r * (nb - 1) + 1:r] for i in range(r + kk - 1)))
+    blk = jnp.arange(nb, dtype=jnp.int32)
+    n = ctx[:, None, :, None]                                 # [B, 1, T, 1]
+    valid = blk * bk < n
+    forced = (blk < sp.init_blocks) | (blk >= jnp.maximum(n - sp.window, 0)
+                                       // bk)
+    chosen = select_columns(
+        jnp.where(forced, jnp.inf, score),
+        jnp.broadcast_to(valid, score.shape), sp.top)
+    return jnp.where(n <= sp.dense_len, valid, chosen)
+
+
+def block_keep(member, start, block, C):
+    """:func:`choose_blocks`' membership ``[B, G, T, nb]`` over a request's
+    blocks as a mask over the plane's columns ``[B, G, T, C]``: column
+    ``c`` of a row is position ``c - start`` of its request (``keep`` of
+    :func:`span_attention`; columns below ``start`` read false)."""
+    wide = jnp.pad(jnp.repeat(member, block, axis=-1),
+                   ((0, 0),) * 3 + ((C, 0),))
+    return jax.vmap(lambda x, o: jax.lax.dynamic_slice_in_dim(x, o, C, 2))(
+        wide, C - start)
 
 
 # The two cached forms differ in a PAIR of products and in nothing else:

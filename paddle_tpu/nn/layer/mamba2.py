@@ -72,6 +72,92 @@ def _product(x, w):
                       preferred_element_type=_F32).astype(x.dtype)
 
 
+def state_update(x, dt, b, c, a_log, h0):
+    """The one-token update of ``h_t = exp(dt_t a) h_{t-1} + dt_t x_t (x)
+    b_t``, ``y_t = h_t c_t``, ``a = -exp(a_log)``: ``x [B, H, P]``, ``dt
+    [B, H]`` float32, ``b``, ``c [B, G, N]`` (head ``h`` reads group ``h //
+    (H / G)``), ``a_log [H]``, ``h0 [B, H, P, N]`` float32 -> (``y [B, H,
+    P]`` float32, the new state)."""
+    rep = x.shape[1] // b.shape[1]
+    a = -jnp.exp(a_log.astype(_F32))
+    bh, ch = (jnp.repeat(t.astype(_F32), rep, axis=1) for t in (b, c))
+    decay = jnp.exp(dt * a)                                  # [B, H]
+    h = h0 * decay[..., None, None] \
+        + (dt[..., None] * x.astype(_F32))[..., None] * bh[:, :, None, :]
+    return jnp.sum(h * ch[:, :, None, :], axis=-1), h
+
+
+def state_scan(x, dt, b, c, a_log, h0, chunk):
+    """The same recurrence over ``T = n x chunk`` tokens as a chunked
+    scan: ``x [B, T, H, P]``, ``dt [B, T, H]`` float32 (0 where a token is
+    not live: it passes the state through), ``b``, ``c [B, T, G, N]``,
+    ``a_log [H]``, ``h0 [B, H, P, N]`` float32 -> (``y [B, T, H, P]``
+    float32, the state after the last token)."""
+    Bt, T, H, P = x.shape
+    G, N, L = b.shape[2], b.shape[3], chunk
+    n, r, dt_op = T // L, H // G, x.dtype
+    x, dt, b, c = (t.reshape((Bt, n, L) + t.shape[2:])
+                   for t in (x, dt, b, c))
+    a = dt * -jnp.exp(a_log.astype(_F32))                  # [B, n, L, H]
+    cs = jnp.cumsum(a, axis=2)                  # log decay up to t, incl.
+    total = cs[:, :, -1]                                    # [B, n, H]
+    # inside a chunk: y_t += sum_{s <= t} e^{cs_t - cs_s} D_s (C_t.B_s) x_s
+    cb = jnp.einsum("bnlgk,bnsgk->bngls", c, b,
+                    preferred_element_type=_F32)
+    cst = jnp.moveaxis(cs, 3, 2)                            # [B, n, H, L]
+    gap = cst[..., :, None] - cst[..., None, :]            # [.., t, s]
+    causal = jnp.tril(jnp.ones((L, L), bool))
+    # (the mask goes inside the exp: above the diagonal the gap is
+    # positive and may overflow)
+    w = jnp.exp(jnp.where(causal, gap, -jnp.inf)) \
+        * jnp.moveaxis(dt, 3, 2)[..., None, :]
+    m = (jnp.repeat(cb, r, axis=2) * w).astype(dt_op)      # [B,n,H,t,s]
+    y = jnp.einsum("bnhts,bnshp->bnthp", m, x,
+                   preferred_element_type=_F32)
+    # what a chunk adds to the state by its end
+    to_end = jnp.exp(total[:, :, None, :] - cs) * dt        # [B, n, L, H]
+    xw = (x.astype(_F32) * to_end[..., None]).astype(dt_op)
+    add = jnp.einsum("bnsgrp,bnsgk->bngrpk",
+                     xw.reshape(Bt, n, L, G, r, P), b,
+                     preferred_element_type=_F32).reshape(Bt, n, H, P, N)
+    # the states pass from chunk to chunk one after another
+    keep = jnp.exp(total)                                   # [B, n, H]
+    h, entering = h0, []
+    for i in range(n):
+        entering.append(h)
+        h = h * keep[:, i, :, None, None] + add[:, i]
+    carried = jnp.stack(entering, axis=1)               # [B, n, H, P, N]
+    # the carried state read through C, as the float32 it is
+    through = jnp.einsum("bnlgk,bngrpk->bnlgrp", c.astype(_F32),
+                         carried.reshape(Bt, n, G, r, P, N),
+                         precision=_EXACT,
+                         preferred_element_type=_F32)
+    y = y + through.reshape(Bt, n, L, H, P) * jnp.exp(cs)[..., None]
+    return y.reshape(Bt, T, H, P), h
+
+
+def state_mix(x, dt, b, c, a_log, h0, chunk):
+    """The recurrence over the block ``x [B, T, H, P]`` in the form its
+    width asks for: the one-token update (scope ``update``) for ``T ==
+    1``, else the chunked scan (scope ``scan``) over the block padded to
+    whole chunks (a padded token has ``dt = 0``: it passes the state
+    through).  Operands as :func:`state_scan`'s; returns (``y [B, T, H,
+    P]`` float32, the state after the block)."""
+    T = x.shape[1]
+    if T == 1:
+        with jax.named_scope("update"):
+            y, h = state_update(x[:, 0], dt[:, 0], b[:, 0], c[:, 0], a_log,
+                                h0)
+            return y[:, None], h
+    pad = -T % chunk
+    with jax.named_scope("scan"):
+        xs, dts, bs, cs = (jnp.pad(
+            t, ((0, 0), (0, pad)) + ((0, 0),) * (t.ndim - 2))
+            for t in (x, dt, b, c))
+        y, h = state_scan(xs, dts, bs, cs, a_log, h0, chunk)
+        return y[:, :T], h
+
+
 class Mamba2Mixer(Layer):
     def __init__(self, hidden, heads, head_dim, state, groups, taps=4,
                  chunk=128, epsilon=1e-5, weight_attr=None, dtype=None):
@@ -146,66 +232,6 @@ class Mamba2Mixer(Layer):
                 b.reshape(lead + (self.G, self.N)),
                 c.reshape(lead + (self.G, self.N)))
 
-    def _update(self, x, dt, b, c, h0):
-        """The one-token update: ``x [B, H, P]``, ``dt [B, H]`` float32,
-        ``b``, ``c [B, G, N]``, ``h0 [B, H, P, N]`` float32 -> (``y [B, H,
-        P]`` float32 without the skip, the new state)."""
-        rep = self.H // self.G
-        a = -jnp.exp(unwrap(self.A_log).astype(_F32))
-        bh, ch = (jnp.repeat(t.astype(_F32), rep, axis=1) for t in (b, c))
-        decay = jnp.exp(dt * a)                                  # [B, H]
-        h = h0 * decay[..., None, None] \
-            + (dt[..., None] * x.astype(_F32))[..., None] * bh[:, :, None, :]
-        return jnp.sum(h * ch[:, :, None, :], axis=-1), h
-
-    def _scan(self, x, dt, b, c, h0):
-        """The chunked scan over ``T = n x chunk`` tokens: ``x [B, T, H,
-        P]``, ``dt [B, T, H]`` float32 (0 where a token is not live),
-        ``b``, ``c [B, T, G, N]``, ``h0 [B, H, P, N]`` float32 -> (``y [B,
-        T, H, P]`` float32 without the skip, the state after the last
-        token)."""
-        Bt, T, H, P = x.shape
-        G, N, L = self.G, self.N, self.chunk
-        n, r, dt_op = T // L, H // G, x.dtype
-        x, dt, b, c = (t.reshape((Bt, n, L) + t.shape[2:])
-                       for t in (x, dt, b, c))
-        a = dt * -jnp.exp(unwrap(self.A_log).astype(_F32))     # [B, n, L, H]
-        cs = jnp.cumsum(a, axis=2)                  # log decay up to t, incl.
-        total = cs[:, :, -1]                                    # [B, n, H]
-        # inside a chunk: y_t += sum_{s <= t} e^{cs_t - cs_s} D_s (C_t.B_s) x_s
-        cb = jnp.einsum("bnlgk,bnsgk->bngls", c, b,
-                        preferred_element_type=_F32)
-        cst = jnp.moveaxis(cs, 3, 2)                            # [B, n, H, L]
-        gap = cst[..., :, None] - cst[..., None, :]            # [.., t, s]
-        causal = jnp.tril(jnp.ones((L, L), bool))
-        # (the mask goes inside the exp: above the diagonal the gap is
-        # positive and may overflow)
-        w = jnp.exp(jnp.where(causal, gap, -jnp.inf)) \
-            * jnp.moveaxis(dt, 3, 2)[..., None, :]
-        m = (jnp.repeat(cb, r, axis=2) * w).astype(dt_op)      # [B,n,H,t,s]
-        y = jnp.einsum("bnhts,bnshp->bnthp", m, x,
-                       preferred_element_type=_F32)
-        # what a chunk adds to the state by its end
-        to_end = jnp.exp(total[:, :, None, :] - cs) * dt        # [B, n, L, H]
-        xw = (x.astype(_F32) * to_end[..., None]).astype(dt_op)
-        add = jnp.einsum("bnsgrp,bnsgk->bngrpk",
-                         xw.reshape(Bt, n, L, G, r, P), b,
-                         preferred_element_type=_F32).reshape(Bt, n, H, P, N)
-        # the states pass from chunk to chunk one after another
-        keep = jnp.exp(total)                                   # [B, n, H]
-        h, entering = h0, []
-        for i in range(n):
-            entering.append(h)
-            h = h * keep[:, i, :, None, None] + add[:, i]
-        carried = jnp.stack(entering, axis=1)               # [B, n, H, P, N]
-        # the carried state read through C, as the float32 it is
-        through = jnp.einsum("bnlgk,bngrpk->bnlgrp", c.astype(_F32),
-                             carried.reshape(Bt, n, G, r, P, N),
-                             precision=_EXACT,
-                             preferred_element_type=_F32)
-        y = y + through.reshape(Bt, n, L, H, P) * jnp.exp(cs)[..., None]
-        return y.reshape(Bt, T, H, P), h
-
     def _mix(self, u, before, h0, live):
         """The whole mixer over the block ``u [B, T, hidden]`` (normed):
         (output ``[B, T, hidden]``, the convolution's inputs over
@@ -220,19 +246,7 @@ class Mamba2Mixer(Layer):
         dt = jax.nn.softplus(dt.astype(_F32)
                              + unwrap(self.dt_bias).astype(_F32))
         dt = jnp.where(live[..., None], dt, 0.0)
-        if T == 1:
-            with jax.named_scope("update"):
-                y, h = self._update(x[:, 0], dt[:, 0], b[:, 0], c[:, 0], h0)
-                y = y[:, None]
-        else:
-            pad = -T % self.chunk
-            with jax.named_scope("scan"):
-                # a padded token has D_t = 0: it passes the state through
-                xs, dts, bs, cs = (jnp.pad(
-                    t, ((0, 0), (0, pad)) + ((0, 0),) * (t.ndim - 2))
-                    for t in (x, dt, b, c))
-                y, h = self._scan(xs, dts, bs, cs, h0)
-                y = y[:, :T]
+        y, h = state_mix(x, dt, b, c, unwrap(self.A_log), h0, self.chunk)
         y = y + unwrap(self.D).astype(_F32)[:, None] * x.astype(_F32)
         y = y.reshape(Bt, T, self.inner) \
             * jax.nn.silu(z.astype(_F32))

@@ -393,9 +393,18 @@ class SlotLoop:
         # state-space layer): each live row of a step updates it, each
         # valid token of a chunk is scanned into it
         self._ssm_layers = sum(1 for s in spec if s["kind"] == "ssm_state")
+        # per layer that chooses BLOCKS of its K/V columns from scores
+        # over a pooled-key plane: its rule (``select_blocks``)
+        self._block_rules = [dict(s["select_blocks"]) for s in spec
+                             if s.get("select_blocks")]
         # the kinds of the planes that DO have columns
         column_kinds = sorted({str(s["kind"]) for s in spec
                                if int(s["columns"])})
+        # ... all of them K/V planes a column a token, read whole or in
+        # chosen blocks (beside whatever has no columns)
+        self._kv_columns = not self._spec and bool(column_kinds) and all(
+            s["kind"] == "kv" or s.get("select_blocks") for s in spec
+            if int(s["columns"]))
         names = getattr(gen, "decode_count_names", None)
         self._count_names = tuple(names()) if names is not None else ()
         if prefix_cache is not None:
@@ -484,6 +493,11 @@ class SlotLoop:
             self.counters["state_rows_held"] = 0
         if self._ssm_layers:
             self.counters.update(ssm_rows_updated=0, chunk_ssm_tokens=0)
+        if self._block_rules:
+            self.counters.update(dict.fromkeys(
+                [pre + k for pre in ("", "chunk_") for k in (
+                    "sparse_blocks_valid", "sparse_blocks_selected",
+                    "pooled_entries_scored")], 0))
         # the plain step over bf16/f32 K/V planes attends in blocks of
         # this many columns (cached_attention), whatever the model keeps
         # beside them that has no columns
@@ -491,8 +505,9 @@ class SlotLoop:
         if column_kinds == ["kv"] and not self._spec:
             self._attn_block = decode_block(self.C)
             self.counters.update(attn_blocks_read=0, attn_blocks_total=0)
-            # ... and the valid columns themselves, of steps and of
-            # chunks (what a roofline is counted from)
+        if self._kv_columns:
+            # the valid columns themselves, of steps and of chunks (what
+            # a roofline is counted from)
             self.counters.update(kv_columns_valid=0, chunk_kv_columns_valid=0)
         # driver-thread-owned: what the dispatches since the last commit
         # add to those counters; committed with ``steps`` in one piece
@@ -1065,8 +1080,20 @@ class SlotLoop:
         if self._ssm_layers:
             add("chunk_ssm_tokens" if chunk else "ssm_rows_updated",
                 int(ctx.size))
-        if self._attn_block:
+        if self._kv_columns:
             add(pre + "kv_columns_valid", int(ctx.sum()))
+        for rule in self._block_rules:
+            # a token past ``dense_len`` scores the pooled windows that
+            # lie whole inside its context and reads ``top`` of its
+            # context's blocks; any other reads them all and scores none
+            blocks = -(-ctx // rule["block"])
+            far = ctx > rule["dense_len"]
+            add(pre + "sparse_blocks_valid", int(blocks.sum()))
+            add(pre + "sparse_blocks_selected", int(np.where(
+                far, np.minimum(blocks, rule["top"]), blocks).sum()))
+            add(pre + "pooled_entries_scored", int(np.where(
+                far, np.maximum((ctx - rule["kernel"]) // rule["stride"] + 1,
+                                0), 0).sum()))
         for top in self._context_tops:
             add(pre + "attn_columns_valid", int(ctx.sum()))
             add(pre + "attn_columns_selected",

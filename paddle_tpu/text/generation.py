@@ -184,8 +184,11 @@ def require_kv_planes(kinds, who):
     ``kv_int8``: every plane as long as the session, a column a token).
     A latent plane, a window plane shorter than the session, or a state
     without columns (``conv_state``, ``ssm_state``) is not theirs yet: a
-    summed state has no column blocks to cut, only snapshots."""
-    bad = sorted(k for k in set(kinds) if not str(k).startswith("kv"))
+    summed state has no column blocks to cut, only snapshots.  Nor is a
+    K/V plane with another plane beside it (``kv+pooled_key``: the pooled
+    keys are an entry every 16 columns, not a column a token)."""
+    bad = sorted(k for k in set(kinds)
+                 if not str(k).startswith("kv") or "+" in str(k))
     if bad:
         _refuse_planes(bad, who)
 
@@ -203,10 +206,14 @@ def require_prefix_planes(spec, columns, who):
     then carries both; that the layer selects among the restored columns
     is the reader's business, not the block's: it scores the keys it
     finds there as it scores those a chunk wrote).  A window plane
-    shorter than the session and a state without columns go on being
-    refused, with :func:`require_kv_planes`'s message."""
+    shorter than the session, a state without columns and a plane that
+    keeps an entry every ``pooled_stride`` columns (pooled keys: a block
+    of it is no function of the block's own tokens, its windows reach
+    back over the block's edge) go on being refused, with
+    :func:`require_kv_planes`'s message."""
     bad = sorted({str(s["kind"]) for s in spec
-                  if int(s["columns"]) != int(columns) or s.get("wraps")})
+                  if int(s["columns"]) != int(columns) or s.get("wraps")
+                  or int(s.get("pooled_stride") or 1) != 1})
     if bad:
         _refuse_planes(bad, who)
 

@@ -1,13 +1,18 @@
 """Decoder-only LM whose layers differ in MIXER and in FFN independently:
-gated short convolutions, grouped-query attention or Mamba-2 state-space
+gated short convolutions, grouped-query attention (dense, or block-sparse
+over a pooled-key plane), Mamba-2 state-space or lightning linear-attention
 mixers; dense SwiGLU or a sigmoid-routed MoE that holds its experts or a
-share of them (the ``lfm2_moe`` and ``nemotron_h`` lineages).
+share of them (the ``lfm2_moe``, ``nemotron_h`` and ``minicpm_sala``
+lineages).
 
-Block: ``h = x + Mix(RMSNorm(x))``, ``y = h + FFN(RMSNorm(h))``, where a
+Block: ``h = x + r Mix(RMSNorm(x))``, ``y = h + r FFN(RMSNorm(h))``, where a
 layer may have a mixer alone or an FFN alone (``layer_types[i]`` /
 ``ffn_types[i]`` ``"none"``: the other half is the whole layer); final
 RMSNorm; the output head is the embedding (tied) or a matrix of its own.
-``layer_types[i]`` is
+Three scalings, each 1 unless the configuration says otherwise (muP): the
+embedding times ``embed_scale``, each residual branch times ``r =
+residual_scale``, the final normed state divided by ``logit_divisor``
+before the head.  ``layer_types[i]`` is
 
   * ``"conv"``: ``[B | C | X] = W_in x``, ``u = B * X``, ``c(t) = sum_j
     w_j * u(t - (L-1) + j)`` (depthwise, ``L`` taps, ``u = 0`` before the
@@ -24,7 +29,17 @@ RMSNorm; the output head is the embedding (tied) or a matrix of its own.
   * ``"ssm"``: :class:`~paddle_tpu.nn.layer.mamba2.Mamba2Mixer`, which
     keeps a float32 state ``[B, heads, head_dim, state]`` and the last
     ``taps - 1`` inputs of its convolution (kind ``ssm_state``), both
-    WITHOUT columns.
+    WITHOUT columns;
+  * ``"linear_attention"``: :class:`~paddle_tpu.nn.layer.linear_attention.
+    LightningAttention`, a float32 matrix state a head fed by outer
+    products (kind ``ssm_state`` too: the same summed-state rule), with
+    per-head norms and rotary positions before it, a norm and a sigmoid
+    gate after it;
+  * ``"sparse_attention"``: :class:`BlockSparseAttention`, grouped-query
+    attention with a sigmoid output gate that, past ``dense_len`` tokens
+    of context, reads ``top`` blocks of ``block`` columns chosen from
+    scores over mean-pooled keys, which it keeps in a third plane beside
+    K and V (kind ``kv+pooled_key``: an entry every ``stride`` columns).
 
 ``ffn_types[i]`` is ``"dense"``, ``"moe"``
 (:class:`~paddle_tpu.nn.layer.moe.DroplessMoE`) or ``"none"``; left out,
@@ -64,8 +79,12 @@ import jax.numpy as jnp
 from ... import nn
 from ...framework.tensor import Tensor, unwrap
 from ...nn import initializer as I
-from ...nn.functional.attention import rotary, span_attention
+from ...nn.functional.attention import (BlockSparse, block_keep,
+                                        choose_blocks, pool_keys_write,
+                                        pooled_entries, rotary,
+                                        span_attention)
 from ...nn.layer.latent_attention import RMSNorm
+from ...nn.layer.linear_attention import LightningAttention, decay_slopes
 from ...nn.layer.mamba2 import Mamba2Mixer, _product
 from ...nn.layer.moe import DroplessMoE, SwiGLU
 from ...nn.layer.transformer import (MultiHeadAttention,
@@ -73,9 +92,11 @@ from ...nn.layer.transformer import (MultiHeadAttention,
                                      ring_block_write)
 
 __all__ = ["HybridConvConfig", "HybridConvDecoder", "ConvStateCache",
-           "ShortConv", "GroupedQueryAttention"]
+           "ShortConv", "GroupedQueryAttention", "BlockSparseAttention",
+           "PooledKeyCache"]
 
 CONV, ATTN, SSM, NONE = "conv", "full_attention", "ssm", "none"
+LINEAR, SPARSE = "linear_attention", "sparse_attention"
 DENSE, MOE = "dense", "moe"
 
 # a conv layer's cache: ``state [B, 1, L-1, hidden]``, row first like every
@@ -89,6 +110,14 @@ ConvStateCache = collections.namedtuple("ConvStateCache", ["state"])
 ConvStateCache.kind = "conv_state"
 ConvStateCache.wraps = False
 RingCache = MultiHeadAttention.RingCache
+# a block-sparse layer's cache: K and V ring planes with a cached head a
+# lane row (``[B, KV, C, d]``: a block is chosen a cached head), and the
+# pooled keys ``[B, KV, ceil(C / stride), d]``, whose entry ``col // stride``
+# holds the row's window that ends in that group of columns
+PooledKeyCache = collections.namedtuple("PooledKeyCache",
+                                        ["k", "v", "pooled"])
+PooledKeyCache.kind = "kv+pooled_key"
+PooledKeyCache.wraps = False
 
 
 @dataclasses.dataclass
@@ -120,6 +149,20 @@ class HybridConvConfig:
     ssm_groups: int = 2
     ssm_taps: int = 4
     ssm_chunk: int = 8
+    linear_heads: int = 4
+    linear_head_dim: int = 16
+    linear_rope_base: Optional[float] = 10000.0
+    linear_chunk: int = 8
+    # a linear layer's decay falls with its relative depth ``l / (L - 1)``
+    # in the model its slopes are taken from: that depth for each layer of
+    # ``layer_types`` (a stage of a deeper model says where it lay there);
+    # None: the layers' own indices over their count
+    linear_decay_depth: Optional[Sequence[float]] = None
+    sparse: Optional[BlockSparse] = None    # the sparse layers' rule
+    sparse_gate: bool = False       # the sparse layers' sigmoid output gate
+    embed_scale: float = 1.0
+    residual_scale: float = 1.0
+    logit_divisor: float = 1.0
     tie_embeddings: bool = True
     rms_eps: float = 1e-5
     dtype: str = "float32"
@@ -337,6 +380,88 @@ class GroupedQueryAttention(nn.Layer):
         return self._out(o)
 
 
+class BlockSparseAttention(GroupedQueryAttention):
+    """Grouped-query attention that, for a query with more than
+    ``sparse.dense_len`` tokens of context, reads ``sparse.top`` blocks of
+    ``sparse.block`` columns (``choose_blocks``: scores over the pooled
+    keys, chosen a cached head).  Its planes keep a cached head a lane row
+    whatever the head's width, and a third plane the pooled keys.
+
+    Both programs score every valid column of the span and MASK the
+    blocks that were not chosen (``block_keep``).  A prefill chunk's 512
+    queries between them choose nearly every block, so for it that is the
+    read.  For a step it is the faster of two forms measured on the chip
+    (PERF.md section 6, PR 44): XLA's gather of the 64 chosen 16 KB blocks
+    a row a cached head ran at 35 GB/s, 2.9 ms a layer at 24 rows of 12k
+    context, against 1.2 ms for the masked read of three times the bytes;
+    a kernel that takes the block offsets as prefetched scalars is what
+    would read the chosen blocks only (ROADMAP R12)."""
+
+    def __init__(self, hidden, heads, kv_heads, head_dim, rope_base,
+                 epsilon=1e-5, weight_attr=None, dtype=None, qk_norm=True,
+                 *, sparse: BlockSparse, gate=False):
+        super().__init__(hidden, heads, kv_heads, head_dim, rope_base,
+                         epsilon, weight_attr, dtype, qk_norm)
+        self.sparse = sparse
+        self.gate_proj = _mat(self, (hidden, self.H * self.d), weight_attr,
+                              dtype) if gate else None
+
+    def _out(self, o, x):
+        """``W_o`` over the heads' outputs ``o [B, H, T, d]``, gated by the
+        layer's input ``x`` where the layer has a gate."""
+        if self.gate_proj is None:
+            return super()._out(o)
+        B, _, T, _ = o.shape
+        o = jnp.swapaxes(o, 1, 2).reshape(B, T, self.H * self.d)
+        o = (o.astype(jnp.float32) * jax.nn.sigmoid(
+            _product(x, self.gate_proj).astype(jnp.float32))).astype(o.dtype)
+        return _product(o, self.o_proj)
+
+    def cache_spec(self, max_len):
+        sp = self.sparse
+        return {"kind": PooledKeyCache.kind, "heads_per_lane_row": 1,
+                "columns": int(max_len), "wraps": False, "window": None,
+                "select_top": None,
+                # the third plane is not a column a token, and what the
+                # layer reads of its K/V is counted in blocks
+                "pooled_stride": sp.stride, "select_blocks": sp._asdict()}
+
+    def gen_cache(self, batch, max_len, dtype="float32"):
+        from ...ops import zeros
+        plane = [batch, self.KV, max_len, self.d]
+        return PooledKeyCache(
+            zeros(plane, dtype=dtype), zeros(plane, dtype=dtype),
+            zeros([batch, self.KV, pooled_entries(max_len, self.sparse.stride),
+                   self.d], dtype=dtype))
+
+    def forward_cached(self, x, cache, pos, start, write_rows=None):
+        """As the dense layer's, with the pooled keys written behind the
+        block's K and the chosen blocks' mask over the span's read."""
+        T, sp = x.shape[1], self.sparse
+        kp, vp, pk = unwrap(cache.k), unwrap(cache.v), unwrap(cache.pooled)
+        C = kp.shape[2]
+        cols = pos + jnp.arange(T, dtype=jnp.int32)
+        q, k, v = self._heads(x, jnp.maximum(cols[None, :] - start[:, None],
+                                             0))
+        kp = ring_block_write(kp, k, pos)
+        vp = ring_block_write(vp, v, pos)
+        with jax.named_scope("sparse_attention"):
+            with jax.named_scope("pool"):
+                pk = pool_keys_write(pk, kp, pos, T, start, sp)
+            with jax.named_scope("select"):
+                member = choose_blocks(q, pk, pos, start, sp, self.rep)
+            with jax.named_scope("read"):
+                o = span_attention(
+                    q, kp, vp, start, pos, rep=self.rep,
+                    keep=block_keep(member, start, sp.block, C))
+        return self._out(o, x), PooledKeyCache(Tensor(kp), Tensor(vp),
+                                               Tensor(pk))
+
+    def forward(self, x):
+        raise NotImplementedError(
+            "a block-sparse layer is served through forward_cached")
+
+
 class HybridDecoderLayer(nn.Layer):
     def __init__(self, cfg: HybridConvConfig, index: int, weight_attr=None):
         super().__init__()
@@ -349,6 +474,20 @@ class HybridDecoderLayer(nn.Layer):
                 cfg.hidden_size, cfg.num_heads, cfg.num_kv_heads,
                 cfg.head_dim, cfg.rope_base, cfg.rms_eps, weight_attr,
                 cfg.dtype, qk_norm=cfg.qk_norm)
+        elif kind == SPARSE:
+            self.mixer = BlockSparseAttention(
+                cfg.hidden_size, cfg.num_heads, cfg.num_kv_heads,
+                cfg.head_dim, cfg.rope_base, cfg.rms_eps, weight_attr,
+                cfg.dtype, qk_norm=cfg.qk_norm, sparse=cfg.sparse,
+                gate=cfg.sparse_gate)
+        elif kind == LINEAR:
+            depth = index / max(len(cfg.layer_types) - 1, 1) \
+                if cfg.linear_decay_depth is None \
+                else cfg.linear_decay_depth[index]
+            self.mixer = LightningAttention(
+                cfg.hidden_size, cfg.linear_heads, cfg.linear_head_dim,
+                decay_slopes(cfg.linear_heads, depth), cfg.linear_rope_base,
+                cfg.linear_chunk, cfg.rms_eps, weight_attr, cfg.dtype)
         elif kind == SSM:
             self.mixer = Mamba2Mixer(
                 cfg.hidden_size, cfg.ssm_heads, cfg.ssm_head_dim,
@@ -380,6 +519,7 @@ class HybridDecoderLayer(nn.Layer):
         if self.ffn is not None:
             self.ffn_norm = RMSNorm(cfg.hidden_size, cfg.rms_eps,
                                     dtype=cfg.dtype)
+        self.residual_scale = float(cfg.residual_scale)
 
     # the residual stream is float32 whatever the weights are (as in the
     # latent family: text/models/latent_moe.py says why)
@@ -388,6 +528,13 @@ class HybridDecoderLayer(nn.Layer):
         """``norm(x)`` rounded to the weights' dtype (the norm's own gain
         is one of them), as a product's operand."""
         return unwrap(norm(x)).astype(unwrap(norm.weight).dtype)
+
+    def _add(self, x, branch):
+        """``x + r branch`` in float32 (``r`` 1 adds as it stands)."""
+        branch = unwrap(branch).astype(jnp.float32)
+        if self.residual_scale != 1.0:
+            branch = branch * self.residual_scale
+        return x + branch
 
     # Each half of the layer, its norm and its residual add included, lies
     # under the name a trace is read by (docs/METRICS.md).
@@ -398,7 +545,7 @@ class HybridDecoderLayer(nn.Layer):
         with jax.named_scope("experts" if moe else "mlp"):
             u = self._operand(self.ffn_norm, h)
             y = self.ffn(u, live) if moe else self.ffn(u)
-            return h + unwrap(y).astype(jnp.float32)
+            return self._add(h, y)
 
     def _mixer_scope(self):
         return jax.named_scope(
@@ -413,14 +560,14 @@ class HybridDecoderLayer(nn.Layer):
                 a, cache = self.mixer.forward_cached(
                     self._operand(self.operator_norm, x), cache, pos, start,
                     write_rows)
-                x = x + a.astype(jnp.float32)
+                x = self._add(x, a)
         return self._ffn(x, live), cache
 
     def forward(self, x):
         if self.mixer is not None:
             with self._mixer_scope():
-                a = unwrap(self.mixer(self._operand(self.operator_norm, x)))
-                x = x + a.astype(jnp.float32)
+                x = self._add(
+                    x, self.mixer(self._operand(self.operator_norm, x)))
         return self._ffn(x, None)
 
 
@@ -463,13 +610,22 @@ class HybridConvDecoder(nn.Layer):
         table = unwrap(self.embed.weight if self.lm_head is None
                        else self.lm_head)
         with jax.named_scope("head"):
-            return jnp.einsum("bth,vh->btv",
-                              unwrap(self.norm(h)).astype(table.dtype), table,
+            h = unwrap(self.norm(h))
+            if self.config.logit_divisor != 1.0:
+                h = h * (1.0 / self.config.logit_divisor)
+            return jnp.einsum("bth,vh->btv", h.astype(table.dtype), table,
                               preferred_element_type=jnp.float32)
+
+    def _embedded(self, ids):
+        """The stream's first state, float32 (inside scope ``embed``)."""
+        h = unwrap(self.embed(ids)).astype(jnp.float32)
+        if self.config.embed_scale != 1.0:
+            h = h * self.config.embed_scale
+        return h
 
     def forward(self, input_ids):
         with jax.named_scope("embed"):
-            h = unwrap(self.embed(input_ids)).astype(jnp.float32)
+            h = self._embedded(input_ids)
         for layer in self.layers:
             h = layer(h)
         return Tensor(self._logits(h))
@@ -506,7 +662,7 @@ class HybridConvDecoder(nn.Layer):
         start = jnp.asarray(unwrap(start_positions), jnp.int32)
         rows = None if write_rows is None else unwrap(write_rows)
         with jax.named_scope("embed"):
-            h = unwrap(self.embed(Tensor(ids))).astype(jnp.float32)
+            h = self._embedded(Tensor(ids))
             live = (pos + jnp.arange(T, dtype=jnp.int32))[None, :] \
                 >= start[:, None]
             if rows is not None:

@@ -224,6 +224,42 @@ def test_a_state_plane_copied_whole_is_a_fault(tool):
     assert "state_planes" not in tool.inspect(text, _SHAPES)
 
 
+def test_a_pooled_key_plane_beside_its_kv_planes(tool):
+    """A block-sparse layer keeps a third plane, an entry every 16 columns
+    (ISSUE 44): listed with the K/V planes by its own shape, its write's
+    traced index on the entries (dimension 2, not the lanes), aliased; the
+    chunk's copies of it whole, on its way to another memory space and
+    back, are a fault that names its shape and bytes, so that it is told
+    from a copy of the K plane sixteen times its size."""
+    pooled = "bf16[3,1,4,128]{3,2,1,0:T(8,128)(2,1)}"
+    there = pooled.replace("(2,1)}", "(2,1)S(1)}")
+    write = (f"  %dynamic_update_slice.5 = {pooled} dynamic-update-slice("
+             "%cache_0__0_.1, %new.5, %c.0, %c.0, %entry.1, %c.0), "
+             'backend_config={"indices_config":{"is_index_aligned":'
+             "[true,true,false,true]}}\n")
+
+    def text(extra=""):
+        return _hybrid_hlo(step=True, extra=write + extra) \
+            .replace(_STATE, pooled) \
+            .replace("tuple(%fusion.7", "tuple(%dynamic_update_slice.5")
+    shapes = {(3, 1, 4, 128), (3, 1, 64, 128)}
+    facts = tool.inspect(text(), shapes)
+    assert sorted(p["shape"] for p in facts["planes"]) \
+        == [[3, 1, 4, 128], [3, 1, 64, 128]]
+    assert facts["planes_aliased"] == facts["planes_total"] == 2
+    assert sum(w["count"] for w in facts["writes"]) == 2
+    assert all(w["unaligned_index_dims"] == [2] and not w["on_minor_most"]
+               for w in facts["writes"])
+    assert facts["whole_plane_copies"] == 0 and tool._faults("step", facts) == []
+    facts = tool.inspect(text(
+        f"  %copy.61 = {there} copy(%custom-call.5)\n"
+        f"  %copy.62 = {there} copy(%copy.61)\n"), shapes)
+    assert facts["whole_plane_copied"] == [
+        {"shape": [3, 1, 4, 128], "count": 2, "mb": 0.0}]
+    faults = tool._faults("chunk", facts)
+    assert len(faults) == 1 and "[3, 1, 4, 128]" in faults[0]
+
+
 # -- weights copied again in every run (ISSUE 30) ----------------------------
 _W = "bf16[1600,1600]"
 _SLICE = "(bf16[400,1600]{1,0:T(8,128)(2,1)S(1)}, bf16[1600,1600]{1,0:T(8,128)(2,1)}, u32[]{:S(2)})"

@@ -80,3 +80,29 @@ def test_the_tool_prints_one_line(capsys):
                       "--step-ms", "29", "--chunk-ms", "43.5", "--ramp", "8"])
     line = json.loads(capsys.readouterr().out)
     assert line["answers_in_window"] == 62 and line["answers_by"] == 31
+
+
+@pytest.mark.parametrize("ramp,first_seen,window,by_34,prefilling", [
+    (0.0, 1, 27, 14, 11.7),     # a window opened cold: the round inside it
+    (25.0, 1, 43, 34, 7.7),     # the cell as sized: steady
+    (25.0, 2, 43, 34, 7.7),     # no race for the first admission
+], ids=["cold-round", "steady", "two-seen"])
+def test_the_replay_of_the_long_document_cell(ramp, first_seen, window,
+                                              by_34, prefilling):
+    """The linear / block-sparse cell's traffic (PR 44) at the traced 19.5
+    ms step and 32.3 ms chunk: every prompt pads to 24 chunks of 512, so
+    the frontier starts at column 12,288 whichever requests the first
+    admission sees, and the cold round is 24 slots x 24 chunks + the steps
+    between them."""
+    config = slot_replay._load("configs", "minicpm-sala-pp2-serve")
+    traffic = slot_replay._load("traffic", "doc12k-closed-2S")
+    out = slot_replay.replay(config, traffic, step_s=0.0195, chunk_s=0.0323,
+                             ramp_s=ramp, first_seen=first_seen)
+    assert out["start"] == 12288
+    assert len(out["answers"]) == window
+    assert sum(1 for a in out["answers"] if a <= 34.0) == by_34
+    assert out["prefilling_pct"] == pytest.approx(prefilling, abs=0.1)
+    # 24 x 24 chunks of 32.3 ms and the steps that let them in; on the chip
+    # the steady window read 7.53% prefilling, 41-43 answers, the 33rd at
+    # 32.9-35.1 s (PERF.md section 6, PR 44)
+    assert out["round_end_s"] == pytest.approx(19.1, abs=0.1)

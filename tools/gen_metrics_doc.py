@@ -76,6 +76,8 @@ index: 48 layers of `attention` add up.
 | `experts` | `DroplessMoE.forward`, the decoders' expert layers; the router as `experts/router` | routed experts with their norm and residual add |
 | `short_conv` | `ShortConv.forward_cached`, `HybridDecoderLayer` (a convolution layer) | the gated short convolution with its norm and residual add |
 | `state_space` | `HybridDecoderLayer` (a state-space layer); inside it `Mamba2Mixer` enters `state_space/conv`, `state_space/scan` (a block of several tokens: the chunked scan) and `state_space/update` (one token: the step's update) | a Mamba-2 layer with its norm, projections, gate, group norm and residual add; `conv` the causal convolution over the block and the inputs kept before it, `scan` / `update` the recurrence itself.  `benchmark/scope_buckets.json` lists no such component, so the accepted `device_scoped_pct.*` counts these layers as unscoped; `benchmark/layer_metrics/_state_space_scope.py` reads them by reducing the capture once more with these four components listed (`step_state_space_ms`, `chunk_state_space_ms`, `state_update_roofline_pct`, `ssm_scan_roofline_pct`) |
+| `linear_attention` (inside `attention`) | `LightningAttention._mix` around the recurrence; inside it `mamba2.state_mix` enters `linear_attention/scan` (a block of several tokens: the chunked scan) and `linear_attention/update` (one token: the step's update) | the float32 matrix state's recurrence alone (`S_t = exp(-s_h) S_{t-1} + k_t^T v_t`, read through `q`); the layer's projections, norms, positions and gate lie directly under `attention`, where the accepted `step_attention_ms.*` / `chunk_attention_ms.*` count the whole layer.  `benchmark/layer_metrics/_sala_scope.py` reads the component and its parts by reducing the capture once more with them listed (`step_linear_attention_ms`, `chunk_linear_attention_ms`; `linear_update_roofline_pct` and `linear_scan_roofline_pct` take the time under `update` / `scan` plus the compiler's own copies of a whole state plane, `linear_attention/plane_copy` in the reader's printed table: the step's update writes each new plane into the chip's other memory space and a copy that carries no scope takes it to the output; no other op without a scope is counted) |
+| `sparse_attention` (inside `attention`) | `BlockSparseAttention.forward_cached`: `sparse_attention/pool` (`pool_keys_write`: the pooled keys that the block completes, written under each row's mask), `sparse_attention/select` (`choose_blocks`: the pooled scores, the blocks' scores, the choice), `sparse_attention/read` (the attention itself, in both programs: `span_attention` over every valid column of the span under `block_keep`'s mask of the chosen blocks; the tree holds no gathered form, PERF.md section 6, PR 44) | a block-sparse layer's own work beside its projections; `_sala_scope.py` reads it (`step_sparse_attention_ms`, `chunk_sparse_attention_ms`; `sparse_read_roofline_pct` = the chosen blocks' and pooled entries' least bytes over the step's time under `select` + `read`) |
 | `head` | the logits product of every model, BERT's pooler and heads; `head/sample` in `Generator._build_step` | vocabulary product; the step's `argmax` / `where` |
 | `loss`, `optimizer`, `cast_params` | `TrainStep._loss_of` and the models' own loss, the optimizer update of `TrainStep._build_step`, `TrainStep._cast_compute` | the training step's own parts; backward is told from forward by the `transpose(jvp(..))` wrapper JAX writes itself |
 
@@ -153,6 +155,26 @@ the state through and is not counted).  `state_update_roofline_pct` reads
 the first against the device time under `state_space/update`,
 `ssm_scan_roofline_pct` the second against `state_space/scan`, each with
 the family's least bytes and operations from `benchmark/counts/`.
+
+A linear-attention layer's matrix state is such a summed state too (kind
+`ssm_state`: the same rule and the same two counters;
+`linear_update_roofline_pct` and `linear_scan_roofline_pct` read them
+against `linear_attention/update` and `linear_attention/scan`, each with
+the compiler's own copies of a whole state plane).  A loop over
+a model with a layer that chooses BLOCKS of its K/V columns from scores over
+a pooled-key plane (`cache_spec` `select_blocks`, kind `kv+pooled_key`;
+`stats()["plane_kinds"]` lists it) counts, by the same host arithmetic, a
+layer and a live token: `sparse_blocks_valid` (the blocks its context has),
+`sparse_blocks_selected` (those it reads: `top` of them past `dense_len`,
+all of them inside it) and `pooled_entries_scored` (the pooled windows that
+lie whole inside its context, past `dense_len`; none inside it), over the
+steps' live rows, and their `chunk_` twins over the chunks' valid tokens.
+`sparse_blocks_selected_pct` is the first two's ratio;
+`sparse_read_roofline_pct` takes the selected blocks and the scored entries
+a step.  Such a loop counts `kv_columns_valid` like a plain K/V model's, and
+no `attn_blocks_*`: those count the column blocks of a span over plain K/V
+planes alone (kind `kv`), and this loop's span is read under the chosen
+blocks' mask (what it keeps of the span is in `sparse_blocks_*`).
 
 The plain slot loop keeps ONE decode step in flight: it dispatches step k+1
 and only then reads step k's tokens (`paddle_tpu/serving/slots.py`, "One step

@@ -23,8 +23,9 @@ program) and prints, from ``compiled.as_text()``:
     chunk's block write);
   * the STATE planes apart (a layer whose ``cache_spec`` has no columns: a
     short convolution's last inputs, ``[S, 1, L-1, hidden]``, and a
-    state-space layer's summed state, float32 ``[S, heads, head_dim,
-    state]`` beside planes of another dtype): their dtype, bytes and
+    state-space layer's summed state or a linear-attention layer's matrix
+    state, float32 ``[S, heads, head_dim, state]`` beside planes of another
+    dtype): their dtype, bytes and
     layout, whether each is aliased in place, which dimension carries the
     index of a write into one (the chunk splices ONE row, index on the
     row dimension, where the compiler leaves that splice an instruction
@@ -311,6 +312,14 @@ def inspect(hlo_text, plane_shapes, state_shapes=frozenset()):
         "planes_total": len(planes),
         "writes": as_writes(writes),
         "whole_plane_copies": len(plane_copies),
+        # which planes: a pooled-key plane (an entry every 16 columns) is
+        # a sixteenth of the K plane beside it
+        "whole_plane_copied": [
+            {"shape": list(s), "count": c, "mb": round(
+                c * _BYTES.get(d, 4) * math.prod(s) / 1e6, 1)}
+            for (s, d), c in collections.Counter(
+                (instrs[n][0], _INSTR.match(instrs[n][4])["dtype"])
+                for n in plane_copies).items()],
         "row_relayout_copies": len(row_relayouts),
     }
 
@@ -327,7 +336,9 @@ def _faults(what, facts):
                        f"{w['minor_to_major']}")
     if facts["whole_plane_copies"]:
         out.append(f"{what}: {facts['whole_plane_copies']} copies or "
-                   "transposes of a whole plane")
+                   "transposes of a whole plane"
+                   + "".join(f", {c['count']} of {c['shape']} ({c['mb']} MB)"
+                             for c in facts.get("whole_plane_copied", ())))
     if facts.get("state_plane_copies"):
         out.append(f"{what}: {facts['state_plane_copies']} copies or "
                    "transposes of a whole state plane")
