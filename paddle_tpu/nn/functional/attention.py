@@ -381,7 +381,7 @@ def scaled_dot_product_attention(query, key, value, attn_mask=None,
 
 
 def cached_attention(q, k, v, attn_mask=None, window=None, k_scale=None,
-                     v_scale=None):
+                     v_scale=None, row=None):
     """Incremental attention: (B, N, Tq, H) new-token queries over the
     full KV ring cache — bf16/f32 planes packed ``(B, ceil(N/g), S,
     g*H)`` as ``gen_ring_cache`` builds them (``g`` is read from the
@@ -403,7 +403,19 @@ def cached_attention(q, k, v, attn_mask=None, window=None, k_scale=None,
     planes: the cache is dequantized to the query's dtype and attended
     in one expression under ``attn_mask``, window or not (decode is
     inference-only, so the raw read costs no tape).
+
+    With ``row`` (a traced int32 scalar) the queries are ONE row's,
+    ``(1, N, Tq, H)``, and the planes are the full ``(S, ...)`` ones: row
+    ``row`` of each plane (and of each scale plane) is the operand of the
+    same expressions, cut with ``lax.dynamic_slice`` where it is read, so
+    scale, mask, softmax and accumulation are what a batch-1 cache gets,
+    to the bit, and no other row is touched.
     """
+    if row is not None:
+        def one(plane):
+            return None if plane is None else Tensor(
+                jax.lax.dynamic_slice_in_dim(unwrap(plane), unwrap(row), 1, 0))
+        k, v, k_scale, v_scale = map(one, (k, v, k_scale, v_scale))
     if k_scale is not None:
         from ..layer.transformer import dequantize_kv_rows
         dt = unwrap(q).dtype

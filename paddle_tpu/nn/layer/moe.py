@@ -552,7 +552,7 @@ class MoEEncoderLayer(Layer):
         self.dropout2 = Dropout(dropout)
 
     def forward(self, src, src_mask=None, cache=None, cache_position=None,
-                decode_window=None):
+                decode_window=None, row=None):
         residual = src
         if self.normalize_before:
             src = self.norm1(src)
@@ -561,7 +561,8 @@ class MoEEncoderLayer(Layer):
         else:
             src, cache = self.self_attn(src, src, src, src_mask, cache,
                                         cache_position=cache_position,
-                                        decode_window=decode_window)
+                                        decode_window=decode_window,
+                                        row=row)
         src = residual + self.dropout1(src)
         if not self.normalize_before:
             src = self.norm1(src)

@@ -31,9 +31,17 @@ def _static_int(x):
         return None
 
 
-def ring_block_write(plane, new, pos, axis=None):
+def ring_block_write(plane, new, pos, axis=None, row=None):
     """Write a ``T``-wide token block into a ``C``-long ring-buffer plane
     at the (already wrapped, possibly traced) position ``pos``.
+
+    With ``row`` (a traced int32 scalar) the block is ONE row's: ``new
+    [1, ..., T, L]`` lands in row ``row`` of ``plane [S, ..., C, L]``, at
+    ``(row, 0, .., pos, 0)``, and every other row of the plane is left
+    as it lies (a prefill chunk writes into the full donated plane in
+    place; no row is cut out of the plane and none is spliced back).
+    Both legs below then read and write that row's ``T`` columns only.
+    ``row=None`` is the write of all rows at once, lowered as before.
 
     A plain ``lax.dynamic_update_slice`` CLAMPS its start to ``C - T``,
     so a multi-token block landing near the ring boundary would silently
@@ -64,12 +72,13 @@ def ring_block_write(plane, new, pos, axis=None):
     the layout back from the compiled program.
     """
     with jax.named_scope("cache_write"):
-        out = _ring_block_write(unwrap(plane), unwrap(new), unwrap(pos), axis)
+        out = _ring_block_write(unwrap(plane), unwrap(new), unwrap(pos), axis,
+                                None if row is None else unwrap(row))
     return Tensor(out) if isinstance(plane, Tensor) \
         or isinstance(new, Tensor) else out
 
 
-def _ring_block_write(p, n, pos, axis):
+def _ring_block_write(p, n, pos, axis, row=None):
     import jax.numpy as jnp
     from jax import lax
     ax = p.ndim - 2 if axis is None else int(axis)
@@ -77,12 +86,36 @@ def _ring_block_write(p, n, pos, axis):
     if T > C:
         raise ValueError(
             f"ring block of {T} tokens cannot fit a cache of length {C}")
+    if row is None:
+        def cut(plane, at):
+            return lax.dynamic_slice_in_dim(plane, at, T, ax)
+
+        def put(plane, block, at):
+            return lax.dynamic_update_slice_in_dim(plane, block, at, ax)
+    else:
+        # one row's block: the same two operations at (row, 0, .., at, 0)
+        if n.shape[0] != 1 or ax == 0:
+            raise ValueError(
+                f"a row's block is [1, ..., T, L]; got {tuple(n.shape)} "
+                f"for the column axis {ax}")
+        row = jnp.asarray(row, jnp.int32)
+
+        def at_row(at):
+            idx = [jnp.int32(0)] * p.ndim
+            idx[0], idx[ax] = row, jnp.asarray(at, jnp.int32)
+            return idx
+
+        def cut(plane, at):
+            return lax.dynamic_slice(plane, at_row(at), n.shape)
+
+        def put(plane, block, at):
+            return lax.dynamic_update_slice(plane, block, at_row(at))
     sp = _static_int(pos)
     if T == 1 or (sp is not None and sp + T <= C):
         # width-1 writes never cross the boundary (pos is pre-wrapped),
         # and a statically in-range block (the prefill fill at pos 0)
         # needs no second leg — the existing single-store lowering
-        return lax.dynamic_update_slice_in_dim(p, n.astype(p.dtype), pos, ax)
+        return put(p, n.astype(p.dtype), pos)
     pos = jnp.asarray(pos, jnp.int32)
     n = n.astype(p.dtype)
     idx_shape = [1] * p.ndim
@@ -93,19 +126,18 @@ def _ring_block_write(p, n, pos, axis):
     # columns back to their current values so clamping never corrupts
     s1 = jnp.minimum(pos, jnp.int32(C - T))
     off = pos - s1                                  # 0 unless wrapping
-    cur1 = lax.dynamic_slice_in_dim(p, s1, T, ax)
+    cur1 = cut(p, s1)
     v1 = lax.dynamic_slice_in_dim(jnp.concatenate([pad, n], axis=ax),
                                   jnp.int32(T) - off, T, ax)
-    out = lax.dynamic_update_slice_in_dim(
-        p, jnp.where(idx < off, cur1, v1), s1, ax)
+    out = put(p, jnp.where(idx < off, cur1, v1), s1)
     # leg 2: wrapped head run [0, pos + T - C) at a STATIC start
     w = pos + jnp.int32(T - C)                      # <= 0: nothing wrapped
-    cur2 = lax.slice_in_dim(out, 0, T, axis=ax)
+    cur2 = lax.slice_in_dim(out, 0, T, axis=ax) if row is None \
+        else cut(out, 0)
     v2 = lax.dynamic_slice_in_dim(jnp.concatenate([n, pad], axis=ax),
                                   jnp.minimum(jnp.int32(C) - pos,
                                               jnp.int32(T)), T, ax)
-    return lax.dynamic_update_slice_in_dim(
-        out, jnp.where(idx < w, v2, cur2), 0, ax)
+    return put(out, jnp.where(idx < w, v2, cur2), 0)
 
 
 _LANES = 128     # minor-dimension tile width of the TPU's device layouts
@@ -270,7 +302,7 @@ class MultiHeadAttention(Layer):
                 "select_top": None}
 
     def _forward_ring(self, query, attn_mask, cache, cache_position,
-                      decode_window):
+                      decode_window, row=None):
         """Incremental attention over the ring cache: project the new
         tokens, pack their K/V the way the planes are packed
         (``pack_heads``; ``g`` read from the plane's minor dim), write
@@ -281,8 +313,12 @@ class MultiHeadAttention(Layer):
         query with its ``decode_window``, the column blocks the window
         spans (``cached_attention``).  Quantized caches keep unpacked
         planes, additionally write int8 rows + scale planes at the same
-        position and dequantize at the attention read.  Returns (out,
-        updated RingCache/QuantRingCache)."""
+        position and dequantize at the attention read.  With ``row``
+        (a traced int32 scalar; a prefill chunk's joining row) the
+        batch-1 block is written into row ``row`` of the FULL planes in
+        place and the batch-1 queries attend that row of the written
+        planes: the other rows are neither read nor written.  Returns
+        (out, updated RingCache/QuantRingCache)."""
         from ..functional.attention import cached_attention
         q = self._split_heads(self.q_proj(query))
         k_new = self._split_heads(self.k_proj(query))
@@ -290,33 +326,31 @@ class MultiHeadAttention(Layer):
         if isinstance(cache, self.QuantRingCache):
             kq, ks = quantize_kv_rows(k_new)
             vq, vs = quantize_kv_rows(v_new)
-            cache = self.QuantRingCache(
-                ring_block_write(cache.k, Tensor(kq), cache_position),
-                ring_block_write(cache.v, Tensor(vq), cache_position),
-                ring_block_write(cache.k_scale, Tensor(ks), cache_position),
-                ring_block_write(cache.v_scale, Tensor(vs), cache_position))
+            cache = self.QuantRingCache(*(
+                ring_block_write(plane, Tensor(new), cache_position, row=row)
+                for plane, new in zip(cache, (kq, vq, ks, vs))))
             out = cached_attention(q, cache.k, cache.v, attn_mask=attn_mask,
                                    window=decode_window,
                                    k_scale=cache.k_scale,
-                                   v_scale=cache.v_scale)
+                                   v_scale=cache.v_scale, row=row)
         else:
             g = cache.k.shape[3] // self.head_dim
             k = ring_block_write(cache.k, Tensor(pack_heads(k_new, g)),
-                                 cache_position)
+                                 cache_position, row=row)
             v = ring_block_write(cache.v, Tensor(pack_heads(v_new, g)),
-                                 cache_position)
+                                 cache_position, row=row)
             cache = self.RingCache(k, v)
             out = cached_attention(q, k, v, attn_mask=attn_mask,
-                                   window=decode_window)
+                                   window=decode_window, row=row)
         if self.dropout:
             out = F.dropout(out, self.dropout, training=self.training)
         return self.out_proj(self._merge_heads(out)), cache
 
     def forward(self, query, key=None, value=None, attn_mask=None, cache=None,
-                cache_position=None, decode_window=None):
+                cache_position=None, decode_window=None, row=None):
         if isinstance(cache, (self.RingCache, self.QuantRingCache)):
             return self._forward_ring(query, attn_mask, cache,
-                                      cache_position, decode_window)
+                                      cache_position, decode_window, row)
         key = query if key is None else key
         value = key if value is None else value
         if cache is None:
@@ -367,7 +401,7 @@ class TransformerEncoderLayer(Layer):
         self.activation = getattr(F, activation)
 
     def forward(self, src, src_mask=None, cache=None, cache_position=None,
-                decode_window=None):
+                decode_window=None, row=None):
         # the layer's two halves, each with its norm and its residual add,
         # under the names a trace is read by (docs/METRICS.md)
         with jax.named_scope("attention"):
@@ -379,7 +413,8 @@ class TransformerEncoderLayer(Layer):
             else:
                 src, cache = self.self_attn(src, src, src, src_mask, cache,
                                             cache_position=cache_position,
-                                            decode_window=decode_window)
+                                            decode_window=decode_window,
+                                            row=row)
             src = residual + self.dropout1(src)
             if not self.normalize_before:
                 src = self.norm1(src)
@@ -428,7 +463,7 @@ class TransformerEncoder(Layer):
         self.norm = norm
 
     def forward(self, src, src_mask=None, cache=None, cache_position=None,
-                decode_window=None):
+                decode_window=None, row=None):
         output = src
         new_caches = []
         for i, mod in enumerate(self.layers):
@@ -437,7 +472,8 @@ class TransformerEncoder(Layer):
             else:
                 output, new_cache = mod(output, src_mask, cache[i],
                                         cache_position=cache_position,
-                                        decode_window=decode_window)
+                                        decode_window=decode_window,
+                                        row=row)
                 new_caches.append(new_cache)
         if self.norm is not None:
             output = self.norm(output)

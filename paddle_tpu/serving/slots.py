@@ -1620,6 +1620,9 @@ class SlotLoop:
             wins = dict(self._phase_win)
         out = {"slots": self.S, "cache": self.C, "chunk": self.T,
                "kv_heads_per_lane_row": self._kv_heads_per_lane_row,
+               # how the chunk program reaches its row: written into the
+               # full planes in place, or cut out and spliced back
+               "chunk_row": self._gen.chunk_row(),
                # the weights the step and the chunk agreed to have relaid
                # (Generator.slot_execs), and those they disagreed on
                **self._gen.weights_layout, **self._latent_form,

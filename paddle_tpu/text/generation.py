@@ -152,16 +152,20 @@ def _rebuild_ring(layer, cache):
     return [cls(*(Tensor(p) for p in c)) for cls, c in zip(types, cache)]
 
 
-def _apply_layer(layer, params, buffers, ids, cache, pos, start, rows=None):
+def _apply_layer(layer, params, buffers, ids, cache, pos, start, rows=None,
+                 row=None):
     """Raw-array incremental forward of ONE model: bind the state
     snapshot into the live layer and run its forward_cached under
     no-grad (the @to_static pure-fn pattern, jit/__init__.py).  Shared
     by the Generator (target) and the speculative draft.  ``rows`` (a
     slot step's live rows) reaches only a model that asks for it
     (``cached_forward_takes_rows``: one of its planes wraps inside a
-    session, so a dead row must not write)."""
+    session, so a dead row must not write).  ``row`` (a chunk's joining
+    row, a traced scalar) goes to a model that writes a batch-1 block
+    into one row of the full planes (``cached_forward_takes_row``); the
+    caller asks for it only of such a model."""
     ring = _rebuild_ring(layer, cache)
-    kw = {}
+    kw = {} if row is None else {"row": row}
     if rows is not None and getattr(layer, "cached_forward_takes_rows",
                                     False):
         kw["write_rows"] = Tensor(rows)
@@ -409,9 +413,9 @@ class Generator:
 
     # -- the two pure programs ----------------------------------------------
     def _apply_cached(self, params, buffers, ids, cache, pos, start,
-                      rows=None):
+                      rows=None, row=None):
         return _apply_layer(self._layer, params, buffers, ids, cache, pos,
-                            start, rows)
+                            start, rows, row)
 
     def _init_cache_raw(self, B, C):
         ring = self._layer.init_cache(B, C)
@@ -447,6 +451,18 @@ class Generator:
         ``SlotLoop.stats()`` say which layout a run used."""
         spec = [s for s in self.cache_spec(1) if s["columns"]]
         return int(spec[0]["heads_per_lane_row"]) if spec else 1
+
+    def chunk_row(self):
+        """How the prefill chunk reaches its joining row: ``"in_place"``
+        for a model whose cached forward takes the row
+        (``cached_forward_takes_row``: the block is written into the full
+        donated planes at ``(row, 0, pos, 0)`` and attends that row
+        there), ``"sliced"`` for every other (the row is cut out of every
+        plane, run at batch 1 and spliced back).  Decided when the
+        program is traced: a fact of the program, in the ledger's
+        ``generate_chunk`` event and in ``SlotLoop.stats()``."""
+        return "in_place" if getattr(
+            self._layer, "cached_forward_takes_row", False) else "sliced"
 
     def latent_form(self, T):
         """The form a model's cached attention over latent planes takes
@@ -615,20 +631,34 @@ class Generator:
     def _build_chunk(self, S, T, C):
         """One Sarathi-style prefill chunk: forward ``T`` prompt tokens
         of ONE joining row at the block position ``pos``, writing its
-        K/V block without touching any other slot's plane.  The forward
-        runs at batch 1 over the row's sliced planes — rows are
-        independent in forward_cached, so the batch-1 compute is bit-
-        identical to that row's lane in a batched dispatch, and a chunk
-        costs the row's own FLOPs instead of ``S``× them.  Returns the
+        K/V block without touching any other slot's row.  The forward
+        runs at batch 1 — rows are independent in forward_cached, so the
+        batch-1 compute is bit-identical to that row's lane in a batched
+        dispatch, and a chunk costs the row's own FLOPs instead of
+        ``S``× them.  How it reaches the row is decided HERE, when the
+        program is traced, from what the model declares
+        (:meth:`chunk_row`): a model whose cached forward takes the row
+        is handed the full donated planes and ``rowidx``, writes its
+        ``[1, G, T, L]`` block at ``(rowidx, 0, pos, 0)`` in place and
+        reads that row's columns from the plane; for every other model
+        the row is cut out of every plane (``_slice_row``), run as a
+        batch-1 cache and spliced back whole (``_splice_row``).  Both
+        write exactly the block's columns of that row, so the ordering
+        rule of :meth:`_build_step` holds for either.  Returns the
         chunk's last-column logits — the final chunk's are the
         activation logits (= the prefill executable's ``logits[:, -1]``
         for the same prompt)."""
         apply = self._apply_cached
+        in_place = self.chunk_row() == "in_place"
 
         def chunk(params, buffers, cache, ids, start, rowidx, pos):
-            sub = _slice_row(cache, rowidx)
-            logits, nsub = apply(params, buffers, ids, sub, pos, start)
-            ncache = _splice_row(cache, nsub, rowidx)
+            if in_place:
+                logits, ncache = apply(params, buffers, ids, cache, pos,
+                                       start, row=rowidx)
+            else:
+                sub = _slice_row(cache, rowidx)
+                logits, nsub = apply(params, buffers, ids, sub, pos, start)
+                ncache = _splice_row(cache, nsub, rowidx)
             with jax.named_scope("head"):
                 out = (ncache, logits[0, -1, :].astype(jnp.float32))
             counts = self._decode_counts()
@@ -666,6 +696,7 @@ class Generator:
                 self._build_chunk(S, T, C), self.chunk_avals(S, T, C),
                 {"slots": S, "chunk": T, "cache": C,
                  "kv_heads_per_lane_row": self.kv_heads_per_lane_row(),
+                 "chunk_row": self.chunk_row(),
                  **_known(latent_form=self.latent_form(T),
                           selector_widths=self.selector_widths(C))},
                 (2,))
@@ -972,6 +1003,9 @@ class Generator:
                 *((("step_passes_rows", True),) if getattr(
                     self._layer, "cached_forward_takes_rows", False)
                   else ()),
+                # so does one whose chunk writes its row in place
+                *((("chunk_row", "in_place"),)
+                  if self.chunk_row() == "in_place" else ()),
                 # the form of a latent model's cached attention is picked
                 # when a program is traced (a step's, the widest block's):
                 # an executable stored under another choice must not load

@@ -84,8 +84,12 @@ class GPTModel(nn.Layer):
         the layout from here, not from the planes' count."""
         return self.encoder.ring_cache_spec(max_len)
 
+    # forward_cached takes ``row``: a batch-1 block goes into one row of
+    # the full planes in place (text/generation.py, the prefill chunk)
+    cached_forward_takes_row = True
+
     def forward_cached(self, input_ids, cache, cache_position,
-                       start_positions):
+                       start_positions, row=None):
         """One incremental step over the ring cache.
 
         input_ids [B, T] — the tokens to append (the LEFT-padded prompt
@@ -95,7 +99,11 @@ class GPTModel(nn.Layer):
         ``start_positions`` [B] is each row's first valid cache column
         (its left-pad offset).  Token positions and the additive
         validity+causality mask are derived from those two, so batch and
-        cache length stay compile-time constants.  Returns
+        cache length stay compile-time constants.  With ``row`` (a
+        traced int32 scalar) ``input_ids`` is ``[1, T]``, ``start_positions``
+        ``[1]`` and ``cache`` the FULL ``S``-row planes: the block is
+        written into row ``row`` in place and attends that row only
+        (``MultiHeadAttention._forward_ring``).  Returns
         (logits [B, T, V], updated cache).
         """
         import jax.numpy as jnp
@@ -108,13 +116,13 @@ class GPTModel(nn.Layer):
             else jnp.int32(pos)
         start = jnp.asarray(unwrap(start_positions), jnp.int32)
         with jax.named_scope("embed"):
-            row = pos + jnp.arange(t, dtype=jnp.int32)   # global cache cols
-            pos_ids = jnp.clip(row[None, :] - start[:, None], 0,
+            qcol = pos + jnp.arange(t, dtype=jnp.int32)  # global cache cols
+            pos_ids = jnp.clip(qcol[None, :] - start[:, None], 0,
                                self.config.max_position_embeddings - 1)
             h = self.drop(self.wte(input_ids) + self.wpe(Tensor(pos_ids)))
             # valid key col j for query row i: start_b <= j <= pos + i
             col = jnp.arange(C, dtype=jnp.int32)
-            valid = ((col[None, None, None, :] <= row[None, None, :, None])
+            valid = ((col[None, None, None, :] <= qcol[None, None, :, None])
                      & (col[None, None, None, :]
                         >= start[:, None, None, None]))
             mask = Tensor(jnp.where(valid, 0.0, -1e30).astype(jnp.float32))
@@ -126,7 +134,7 @@ class GPTModel(nn.Layer):
         h, new_cache = self.encoder(
             h, mask, cache=cache,
             cache_position=Tensor(pos % jnp.int32(C)),
-            decode_window=window)
+            decode_window=window, row=row)
         with jax.named_scope("head"):
             logits = ops.matmul(h, self.wte.weight, transpose_y=True)
         return logits, new_cache

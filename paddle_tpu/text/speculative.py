@@ -326,6 +326,10 @@ class SpeculativeGenerator(_Generator):
 
         return step
 
+    def chunk_row(self):
+        """The joint chunk below cuts its row out of both caches."""
+        return "sliced"
+
     def _build_chunk(self, S, T, C):
         """One JOINT prefill chunk: target and draft both consume the
         joining row's ``T`` prompt tokens at the block position, so the

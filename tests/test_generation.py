@@ -288,3 +288,166 @@ def test_decode_flags_snapshot_restore_roundtrip():
     assert flag("decode_max_len") == 1024
     with pytest.raises(ValueError):
         set_flags({"FLAGS_decode_buckets": "0,4"})     # validator
+
+
+# -- the prefill chunk writes its block into the planes in place -------------
+#
+# A model whose cached forward takes the row (``cached_forward_takes_row``)
+# gets the full donated planes and the row index; every other model's row is
+# cut out of the planes and spliced back.  The two programs must agree to
+# the bit: the planes they hand back (the chunk's row and every other row)
+# and the chunk's last-column logits.
+
+_S, _T, _C = 5, 8, 32
+# (dtype, head_dim, heads, FLAGS_kv_cache_dtype; "bf16" = the model's own
+# dtype): packed planes with two heads a
+# lane row (3 heads pad to 4) and plain ones, float32 and bfloat16, and the
+# int8 cache's four unpacked planes a layer
+_CHUNK_MODELS = {
+    "f32-g2": ("float32", 64, 3, "bf16"),
+    "bf16-g2": ("bfloat16", 64, 3, "bf16"),
+    "f32-g1": ("float32", 128, 2, "bf16"),
+    "bf16-g1": ("bfloat16", 128, 2, "bf16"),
+    "int8": ("float32", 32, 2, "int8"),
+}
+_CHUNK_ROWS = {"row0": 0, "middle-row": 2, "last-row": _S - 1}
+# a block at column 0, in the middle of the ring, ending at C, and one that
+# wraps (ring_block_write's two legs)
+_CHUNK_COLUMNS = {"at-0": 0, "mid-ring": 11, "ends-at-C": _C - _T,
+                  "wraps": _C - 3}
+_CHUNK_PROGRAMS = {}
+
+
+def _chunk_programs(case):
+    """``(in place, sliced, state, planes, ids)`` for one model case, each
+    program compiled once.  The sliced form is the same Generator's program
+    traced for a model that does not declare the capability (the tests' way
+    to the reference, not an option of the program)."""
+    import jax
+    import jax.numpy as jnp
+    if case in _CHUNK_PROGRAMS:
+        return _CHUNK_PROGRAMS[case]
+    dtype, hd, heads, kv = _CHUNK_MODELS[case]
+    paddle.seed(41)
+    m = GPTModel(GPTConfig.tiny(vocab_size=V, hidden_size=hd * heads,
+                                layers=2, heads=heads, seq=64))
+    if dtype != "float32":
+        m.to(dtype=dtype)
+    m.eval()
+    snap = flags_snapshot()
+    try:
+        set_flags({"FLAGS_kv_cache_dtype": kv})
+        gen = Generator(m, max_len=_C, seq_buckets=[_C])
+        assert gen.chunk_row() == "in_place"
+        rng = np.random.RandomState(3)
+        planes = [tuple(
+            jnp.asarray(rng.randint(-127, 128, p.shape), p.dtype)
+            if p.dtype == jnp.int8
+            else jnp.asarray(rng.uniform(0.01, 1.0, p.shape)
+                             * (1 if p.shape[-1] == 1
+                                else rng.choice([-1, 1], p.shape)), p.dtype)
+            for p in c) for c in gen.init_slot_cache(_S, _C)]
+        assert len(planes[0]) == (4 if kv == "int8" else 2)
+        ids = jnp.asarray(rng.randint(1, V, (1, _T)), jnp.int32)
+        args = (*gen._state, planes, ids, jnp.zeros((1,), jnp.int32),
+                jnp.int32(0), jnp.int32(0))
+        progs = []
+        for takes in (True, False):
+            type(m).cached_forward_takes_row = takes
+            try:
+                progs.append(jax.jit(gen._build_chunk(_S, _T, _C))
+                             .lower(*args).compile())
+            finally:
+                type(m).cached_forward_takes_row = True
+    finally:
+        flags_restore(snap)
+    out = _CHUNK_PROGRAMS[case] = (*progs, gen._state, planes, ids)
+    return out
+
+
+@pytest.mark.parametrize("column", _CHUNK_COLUMNS)
+@pytest.mark.parametrize("row", _CHUNK_ROWS)
+@pytest.mark.parametrize("case", _CHUNK_MODELS)
+def test_chunk_in_place_equals_the_sliced_row_to_the_bit(case, row, column):
+    import jax
+    import jax.numpy as jnp
+    in_place, sliced, state, planes, ids = _chunk_programs(case)
+    r, pos = _CHUNK_ROWS[row], _CHUNK_COLUMNS[column]
+    start = jnp.asarray([min(pos, 2)], jnp.int32)
+    got = in_place(*state, planes, ids, start, jnp.int32(r), jnp.int32(pos))
+    want = sliced(*state, planes, ids, start, jnp.int32(r), jnp.int32(pos))
+    np.testing.assert_array_equal(np.asarray(got[1]), np.asarray(want[1]))
+    assert np.isfinite(np.asarray(got[1])).all()
+    others = np.arange(_S) != r
+    cols = (pos + np.arange(_T)) % _C
+    for new, ref, old in zip(*map(jax.tree_util.tree_leaves,
+                                  (got[0], want[0], planes))):
+        new, ref, old = (np.asarray(a.astype(jnp.float32))
+                         for a in (new, ref, old))
+        np.testing.assert_array_equal(new, ref)
+        # every OTHER row of every plane as it was, and of the chunk's row
+        # every column outside the block; the block itself is new
+        np.testing.assert_array_equal(new[others], old[others])
+        outside = np.setdiff1d(np.arange(_C), cols)
+        np.testing.assert_array_equal(new[r][:, outside], old[r][:, outside])
+        assert not np.array_equal(new[r][:, cols], old[r][:, cols])
+
+
+@pytest.mark.parametrize("takes", [True, False],
+                         ids=["model-takes-the-row", "model-does-not"])
+def test_the_chunk_cuts_a_row_only_for_a_model_that_takes_none(
+        takes, monkeypatch):
+    """Decided when the program is traced, from the model's declaration:
+    ``_slice_row`` / ``_splice_row`` are entered for a model without
+    ``cached_forward_takes_row`` and never for one with it, and the
+    program's ``extra`` says which."""
+    import jax
+    from paddle_tpu.text import generation as G
+    m = _model(seed=23)
+    if not takes:
+        monkeypatch.delattr(GPTModel, "cached_forward_takes_row")
+    calls = []
+    for name in ("_slice_row", "_splice_row"):
+        real = getattr(G, name)
+        monkeypatch.setattr(
+            G, name, lambda *a, _r=real, _n=name: (calls.append(_n),
+                                                   _r(*a))[1])
+    gen = Generator(m, max_len=32, seq_buckets=[32])
+    _key, kind, fn, avals, extra, donate = gen._chunk_program(3, 8, 32)
+    jax.jit(fn, donate_argnums=donate).lower(*gen._state_avals(), *avals)
+    assert kind == "generate_chunk"
+    assert extra["chunk_row"] == ("in_place" if takes else "sliced")
+    assert calls == ([] if takes else ["_slice_row", "_splice_row"])
+    # part of the executable cache's identity only where it is in place:
+    # every other model's identity is what it was
+    assert (("chunk_row", "in_place") in gen._program_identity()) is takes
+
+
+@pytest.mark.parametrize("width", [1, 3, 8])
+def test_ring_block_write_with_a_row_at_every_boundary_offset(width):
+    """Both legs of the wrap-aware write, addressed by row: at every start
+    column the block lands in THAT row, wrapped, the columns the clamped
+    window re-writes keep that row's contents, and no other row moves."""
+    import jax
+    import jax.numpy as jnp
+    from paddle_tpu.nn.layer.transformer import ring_block_write
+    S, G, C, L = 4, 2, 8, 4
+    rng = np.random.RandomState(width)
+    plane = rng.randn(S, G, C, L).astype(np.float32)
+    new = rng.randn(1, G, width, L).astype(np.float32)
+    write = jax.jit(lambda p, n, pos, row: ring_block_write(
+        p, n, pos, row=row))
+    for row in range(S):
+        for pos in range(C):
+            want = plane.copy()
+            want[row][:, (pos + np.arange(width)) % C] = new[0]
+            got = write(plane, new, jnp.int32(pos), jnp.int32(row))
+            np.testing.assert_array_equal(np.asarray(got), want)
+    # a static in-range position takes the single store
+    got = ring_block_write(plane, new, 0, row=jnp.int32(2))
+    want = plane.copy()
+    want[2][:, :width] = new[0]
+    np.testing.assert_array_equal(np.asarray(got), want)
+    with pytest.raises(ValueError):
+        ring_block_write(plane, np.concatenate([new, new]), 0,
+                         row=jnp.int32(0))

@@ -112,6 +112,8 @@ def test_slot_loop_equals_the_reference(served, dtype, tol, monkeypatch):
                                for p, t in zip(prompts, tokens)])
         assert np.median(gaps) < 1e-3 and (gaps < tol).mean() > 0.8
     assert st["plane_kinds"] == ["latent+selector_key"]
+    # this family's chunk still runs on a row cut out of the planes
+    assert st["chunk_row"] == "sliced"
     assert st["latent_form"] == {"step": "absorbed", "chunk": "absorbed"}
     n = [p.size for p in prompts]
     assert st["chunk_tokens"] == sum(n)

@@ -188,6 +188,8 @@ def test_slot_loop_equals_the_reference_and_counts_in_one_piece(served):
     prompts, tokens, st, _ = _serve(model, REQUESTS)
     assert _widest_gap(cfg, view, prompts, tokens) < GAP_TOL
     assert st["plane_kinds"] == ["conv_state", "kv"]
+    # this family's chunk still runs on a row cut out of the planes
+    assert st["chunk_row"] == "sliced"
     assert st["kv_heads_per_lane_row"] == 8            # 128 / head size 16
     moe_layers, k = 4, cfg["num_experts_per_tok"]
     fed = sum(n for n, _ in REQUESTS) + st["emitted_tokens"]

@@ -146,6 +146,8 @@ def test_slot_loop_equals_the_reference(served, dtype, tol, monkeypatch):
     prompts, tokens, st = _serve(model, REQUESTS)
     assert _widest_gap(cfg, view, prompts, tokens) < tol
     assert st["plane_kinds"] == ["latent"]
+    # this family's chunk still runs on a row cut out of the planes
+    assert st["chunk_row"] == "sliced"
     assert st["latent_form"] == {"step": "absorbed", "chunk": "absorbed"}
     moe_layers, k = 2, cfg["num_experts_per_tok"]
     assert st["moe_assignments"] == \

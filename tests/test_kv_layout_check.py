@@ -32,6 +32,7 @@ ENTRY %main.1 (w: bf16[8,8], c0: {plane}, c1: {plane}) -> ({plane}, {plane}) {{
   %w.1 = bf16[8,8]{{1,0:T(8,128)(2,1)}} parameter(0), metadata={{op_name="params['w']"}}
   %cache_0__0_.1 = {plane} parameter(1), sharding={{replicated}}, metadata={{op_name="cache[0][0]"}}
   %cache_0__1_.1 = {plane} parameter(2), sharding={{replicated}}, metadata={{op_name="cache[0][1]"}}
+  %c.0 = s32[]{{:T(128)}} constant(0)
   %dynamic_update_slice.1 = {plane} dynamic-update-slice(%cache_0__0_.1, %new.1, %c.0, %c.0, %pos.1, %c.0), backend_config={{"indices_config":{{"is_index_aligned":[{aligned}]}}}}
   %dynamic_update_slice.2 = {plane} dynamic-update-slice(%cache_0__1_.1, %new.2, %c.0, %c.0, %pos.1, %c.0), backend_config={{"indices_config":{{"is_index_aligned":[{aligned}]}}}}
 {body}  ROOT %tuple.1 = ({plane}, {plane}) tuple(%dynamic_update_slice.1, %dynamic_update_slice.2)
@@ -52,6 +53,7 @@ def test_lane_major_planes_are_reported_as_faults(tool):
     assert facts["planes_aliased"] == facts["planes_total"] == 2
     assert facts["writes"] == [{"minor_to_major": [2, 3, 1, 0],
                                 "unaligned_index_dims": [2],
+                                "traced_index_dims": [2],
                                 "on_minor_most": True, "count": 2}]
     assert facts["row_relayout_copies"] == 2
     faults = tool._faults("step", facts)
@@ -142,6 +144,7 @@ def _hybrid_hlo(step=True, extra=""):
 ENTRY %main.1 (c0: {_STATE}, c1: {_KV}) -> ({_STATE}, {_KV}) {{
   %cache_0__0_.1 = {_STATE} parameter(0), sharding={{replicated}}, metadata={{op_name="cache[0][0]"}}
   %cache_1__0_.1 = {_KV} parameter(1), sharding={{replicated}}, metadata={{op_name="cache[1][0]"}}
+  %c.0 = s32[]{{:T(128)}} constant(0)
   %dynamic_update_slice.1 = {_KV} dynamic-update-slice(%cache_1__0_.1, %new.1, %c.0, %c.0, %pos.1, %c.0), backend_config={{"indices_config":{{"is_index_aligned":[true,true,false,true]}}}}
 {write}{extra}  ROOT %tuple.1 = ({_STATE}, {_KV}) tuple({out}, %dynamic_update_slice.1)
 }}
@@ -170,7 +173,7 @@ def test_state_planes_are_reported_apart(tool, step):
     else:
         assert facts["state_writes"] == [{
             "minor_to_major": [3, 2, 1, 0], "unaligned_index_dims": [0],
-            "on_minor_most": False, "count": 1}]
+            "traced_index_dims": [0], "on_minor_most": False, "count": 1}]
     assert facts["state_plane_copies"] == facts["whole_plane_copies"] == 0
     assert facts["state_row_relayout_copies"] == 0
     assert tool._faults("step", facts) == []
@@ -347,3 +350,106 @@ def test_the_row_write_is_in_place_or_a_fault(tool, kw, aliased, copies):
     # a tuple's aliases still read as they did
     assert tool._aliased_params(_hlo((2, 2, 8, 128), "3,2,1,0",
                                      "true,true,false,true")) == {1, 2}
+
+
+# -- a chunk's row: cut out and spliced back, or addressed in place (ISSUE 46)
+_ROW = "bf16[1,13,1024,128]{3,2,1,0:T(8,128)(2,1)S(1)}"
+_BLOCK = "bf16[1,13,16,128]{3,2,1,0:T(8,128)(2,1)S(1)}"
+_ALIGNED = ('backend_config={"indices_config":{"is_index_aligned":'
+            '[true,true,false,true]}}')
+
+
+def _chunk_hlo(entry, computations=""):
+    """A chunk over ONE K plane in outline, as the compiler prints it for
+    ``gpt2-xl-serve``: ``entry`` are ENTRY's instructions between the
+    parameters and the ROOT, which hands back ``%out``."""
+    return f"""HloModule jit_chunk, is_scheduled=true, input_output_alias={{ {{0}}: (0, {{}}, may-alias) }}
+
+{computations}ENTRY %main.1 (c0: {_PLANE}) -> ({_PLANE}) {{
+  %cache_0__0_.1 = {_PLANE} parameter(0), sharding={{replicated}}, metadata={{op_name="cache[0][0]"}}
+  %row.1 = s32[]{{:T(128)S(6)}} parameter(1)
+  %pos.1 = s32[]{{:T(128)S(6)}} parameter(2)
+  %c.0 = s32[]{{:T(128)}} constant(0)
+{entry}  ROOT %tuple.1 = ({_PLANE}) tuple(%out)
+}}
+"""
+
+
+# the sliced form: a fusion cuts the row, the block is written into the
+# ROW, a fusion splices the row back into the plane
+_SLICED = _chunk_hlo(
+    f"  %cut.1 = {_ROW} fusion(%cache_0__0_.1, %row.1), kind=kLoop, calls=%fused_cut\n"
+    f"  %dynamic_update_slice.1 = {_ROW} dynamic-update-slice(%cut.1, %new.1, %c.0, %c.0, %pos.1, %c.0), {_ALIGNED}\n"
+    f"  %out = {_PLANE} fusion(%cache_0__0_.1, %dynamic_update_slice.1, %row.1), kind=kLoop, calls=%fused_splice\n",
+    f"""%fused_cut (p0: {_PLANE}, p1: s32[]) -> {_ROW} {{
+  %p0 = {_PLANE} parameter(0)
+  %p1 = s32[]{{:T(128)S(6)}} parameter(1)
+  %z = s32[]{{:T(128)}} constant(0)
+  ROOT %dynamic_slice.1 = {_ROW} dynamic-slice(%p0, %p1, %z, %z, %z), dynamic_slice_sizes={{1,13,1024,128}}
+}}
+
+%fused_splice (q0: {_PLANE}, q1: {_ROW}, q2: s32[]) -> {_PLANE} {{
+  %q0 = {_PLANE} parameter(0)
+  %q1 = {_ROW} parameter(1)
+  %q2 = s32[]{{:T(128)S(6)}} parameter(2)
+  %z.1 = s32[]{{:T(128)}} constant(0)
+  ROOT %dynamic_update_slice.9 = {_PLANE} dynamic-update-slice(%q0, %q1, %q2, %z.1, %z.1, %z.1)
+}}
+
+""")
+
+# in place: the block goes into the plane at (row, 0, pos, 0); the row's
+# dynamic-slice is an operand of the product, nested inside its fusion
+_IN_PLACE = _chunk_hlo(
+    f"  %out = {_PLANE} dynamic-update-slice(%cache_0__0_.1, %new.1, %row.1, %c.0, %pos.1, %c.0), {_ALIGNED}\n"
+    "  %attend.1 = bf16[13,16,128]{2,1,0:T(8,128)(2,1)S(1)} fusion(%probs.1, %out, %row.1), kind=kOutput, calls=%fused_product\n",
+    f"""%fused_read (r0: {_PLANE}, r1: s32[]) -> bf16[13,1024,128,1] {{
+  %r0 = {_PLANE} parameter(0)
+  %r1 = s32[]{{:T(128)S(6)}} parameter(1)
+  %z.2 = s32[]{{:T(128)}} constant(0)
+  %dynamic_slice.2 = bf16[1,13,1024,128]{{3,2,1,0:T(8,128)(2,1)}} dynamic-slice(%r0, %r1, %z.2, %z.2, %z.2), dynamic_slice_sizes={{1,13,1024,128}}
+  ROOT %bitcast.1 = bf16[13,1024,128,1]{{2,1,3,0:T(8,128)(2,1)}} bitcast(%dynamic_slice.2)
+}}
+
+%fused_product (s0: bf16[13,2,16,1024], s1: {_PLANE}, s2: s32[]) -> bf16[13,16,128] {{
+  %s0 = bf16[13,2,16,1024]{{3,2,1,0:T(8,128)(2,1)}} parameter(0)
+  %s1 = {_PLANE} parameter(1)
+  %s2 = s32[]{{:T(128)S(6)}} parameter(2)
+  %fusion.5 = bf16[13,1024,128,1]{{2,1,3,0:T(8,128)(2,1)}} fusion(%s1, %s2), kind=kLoop, calls=%fused_read
+  ROOT %convolution.1 = bf16[13,16,128]{{2,1,0:T(8,128)(2,1)}} convolution(%s0, %fusion.5), dim_labels=01bf_0io1->01bf
+}}
+
+""")
+
+# a row cut out by an instruction of ENTRY's own, nothing fused
+_BARE = _chunk_hlo(
+    f"  %dynamic_slice.3 = {_ROW} dynamic-slice(%cache_0__0_.1, %row.1, %c.0, %c.0, %c.0), dynamic_slice_sizes={{1,13,1024,128}}\n"
+    f"  %dynamic_update_slice.1 = {_ROW} dynamic-update-slice(%dynamic_slice.3, %new.1, %c.0, %c.0, %pos.1, %c.0), {_ALIGNED}\n"
+    f"  %out = {_PLANE} dynamic-update-slice(%cache_0__0_.1, %dynamic_update_slice.1, %row.1, %c.0, %c.0, %c.0), {_ALIGNED}\n")
+
+
+@pytest.mark.parametrize("text,slices,traced", [
+    (_SLICED, 2, [[2]]),
+    (_IN_PLACE, 0, [[0, 2]]),
+    (_BARE, 2, [[2], [0]]),
+], ids=["row-cut-and-spliced-by-fusions", "block-written-in-place",
+        "row-cut-and-spliced-in-entry"])
+def test_row_sized_slices_count_a_cut_row_and_not_an_in_place_block(
+        tool, text, slices, traced):
+    facts = tool.inspect(text, {(32, 13, 1024, 128)})
+    assert facts["row_sized_slices"] == slices
+    assert [w["traced_index_dims"] for w in facts["writes"]] == traced
+    assert not any(w["on_minor_most"] for w in facts["writes"])
+    assert facts["planes_aliased"] == facts["planes_total"] == 1
+    assert facts["whole_plane_copies"] == 0
+    # reported, never a fault: six configurations still cut their row
+    assert tool._faults("chunk", facts) == []
+
+
+def test_a_block_sized_slice_of_a_row_is_no_row_sized_slice(tool):
+    """The wrap-aware write reads the ``T`` current columns of its row
+    (``ring_block_write``'s blend): a block, not a row."""
+    text = _chunk_hlo(
+        f"  %cur.1 = {_BLOCK} dynamic-slice(%cache_0__0_.1, %row.1, %c.0, %pos.1, %c.0), dynamic_slice_sizes={{1,13,16,128}}\n"
+        f"  %out = {_PLANE} dynamic-update-slice(%cache_0__0_.1, %cur.1, %row.1, %c.0, %pos.1, %c.0), {_ALIGNED}\n")
+    assert tool.row_sized_slices(text, {(32, 13, 1024, 128)}) == 0

@@ -88,6 +88,8 @@ def test_slot_loop_equals_the_reference(served):
     prompts, tokens, st = _serve(model, REQUESTS)
     assert _widest_gap(cfg, view, prompts, tokens) < GAP_TOL
     assert st["plane_kinds"] == ["latent+selector_key", "latent_window"]
+    # this family's chunk still runs on a row cut out of the planes
+    assert st["chunk_row"] == "sliced"
     # chunks of 4 queries are under the rule's threshold at these widths
     assert st["latent_form"] == {"step": "absorbed", "chunk": "absorbed"}
     moe_layers, k = 3, cfg["num_experts_per_tok"]

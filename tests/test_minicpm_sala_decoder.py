@@ -460,6 +460,8 @@ def test_slot_loop_equals_the_reference_and_counts_in_one_piece(served):
     prompts, tokens, st = _serve(model, REQUESTS)
     assert max(_served_logit_errors(cfg, view, prompts, tokens)) < GAP_TOL
     assert st["plane_kinds"] == ["kv+pooled_key", "ssm_state"]
+    # this family's chunk still runs on a row cut out of the planes
+    assert st["chunk_row"] == "sliced"
     assert st["chunk_tokens"] == sum(n for n, _ in REQUESTS)
     assert st["ssm_rows_updated"] == st["emitted_tokens"]
     assert st["chunk_ssm_tokens"] == st["chunk_tokens"]

@@ -369,6 +369,8 @@ def test_slot_loop_equals_the_reference_and_counts_in_one_piece(served):
     prompts, tokens, st, _ = _serve(model, REQUESTS)
     assert _widest_gap(cfg, view, prompts, tokens) < GAP_TOL
     assert st["plane_kinds"] == ["kv", "ssm_state"]
+    # this family's chunk still runs on a row cut out of the planes
+    assert st["chunk_row"] == "sliced"
     expert_layers, k = 4, cfg["num_experts_per_tok"]
     fed = sum(n for n, _ in REQUESTS) + st["emitted_tokens"]
     assert st["chunk_tokens"] == sum(n for n, _ in REQUESTS)

@@ -354,6 +354,35 @@ def test_attn_blocks_are_counted_for_the_plain_step_over_kv_planes_only(
         loop.close()
 
 
+@pytest.mark.parametrize("takes", [True, False],
+                         ids=["in-place", "sliced"])
+def test_chunk_row_is_a_fact_of_the_program_in_stats_and_the_event(
+        takes, monkeypatch):
+    """How the chunk program reaches its row (ISSUE 46), decided from the
+    model's ``cached_forward_takes_row`` when the program is traced: in
+    ``stats()`` beside ``kv_heads_per_lane_row``, in the ``generate_chunk``
+    event's ``extra`` (not in the step's), and not reset with the counters."""
+    from paddle_tpu.profiler import ledger
+    if not takes:
+        monkeypatch.delattr(GPTModel, "cached_forward_takes_row")
+    want = "in_place" if takes else "sliced"
+    gen = Generator(_gpt(seed=27), seq_buckets=(8, 16, 32), max_len=64,
+                    site=f"test_chunk_row:{want}")
+    loop = SlotLoop(gen, slots=3, cache_len=64, chunk=8)
+    try:
+        assert loop.submit([3, 1, 4, 1, 5, 9, 2, 6, 5, 3], 4).result(
+            timeout=120).shape[-1] == 4
+        assert loop.stats()["chunk_row"] == want
+        loop.reset_stats()
+        st = loop.stats()
+        assert st["chunk_row"] == want and st["chunks"] == 0
+    finally:
+        loop.close()
+    evs = {e["kind"]: e for e in ledger.compile_events(gen.site)}
+    assert evs["generate_chunk"]["chunk_row"] == want
+    assert "chunk_row" not in evs["generate_step"]
+
+
 def test_reset_stats_zeroes_phases_and_slot_steps():
     loop = _loop(seed=25)
     try:

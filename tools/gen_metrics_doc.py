@@ -71,7 +71,7 @@ index: 48 layers of `attention` add up.
 | `embed` | `GPTModel.forward` / `forward_cached`, `BertModel.forward`, the latent and hybrid decoders | token, position and type lookups, the mask arithmetic beside them |
 | `attention` | `TransformerEncoderLayer.forward`, `LatentDecoderLayer`, `HybridDecoderLayer` (an attention layer) | a layer's attention half: its norm, the q/k/v/out products, the scores, the residual add.  Inside it the newer families keep `latent_attention`, `latent_attention/per_head` (the per-head form of a wide block of queries: an XLA `while` loop over the column blocks, or, where the chunk takes the kernel, ONE custom call `latent_attention_per_head` a layer, under the same path), `selector` (the learned column selector's projections and key write; inside it `selector/score`, the scores of every valid column, apart from `selector/select`, the radix search among them: one conditional with a branch for each width the search may take, `select/cond/branch_<i>_fun`, `branch_0_fun` for a dispatch in which nothing is to be decided), `gqa_attention` |
 | `attention/fused` | `nn.functional.attention` (`attention_bse`, `attention_bnsh`), around the call of a Pallas attention kernel | an un-cached call site that took a kernel: its custom calls by name, `attention_single_block_fwd` and, under `transpose(jvp(..))`, `attention_single_block_bwd` (the blocked form's three have no name of their own).  Bucket `attention`, inside a layer's or alone |
-| `cache_write` | `ring_block_write`, the short convolution's state, `Generator`'s `_slice_row` / `_splice_row` (`cache_write/row_slice`, `cache_write/row_splice`) | every write into a cache plane, and a chunk's batch-1 row out of the planes and back.  Innermost wins: a plane write inside `attention` counts here |
+| `cache_write` | `ring_block_write`, the short convolution's state, `Generator`'s `_slice_row` / `_splice_row` (`cache_write/row_slice`, `cache_write/row_splice`) | every write into a cache plane, and, for a chunk whose `chunk_row` is `"sliced"`, its batch-1 row out of the planes and back (an `"in_place"` chunk has only `ring_block_write`'s block at `(row, 0, pos, 0)` here; its read of the row is the `attention` product's operand).  Innermost wins: a plane write inside `attention` counts here |
 | `mlp` | `TransformerEncoderLayer.forward`, `SwiGLU.forward`, the decoders' dense layers | a layer's dense feed-forward half with its norm and residual add; a shared expert |
 | `experts` | `DroplessMoE.forward`, the decoders' expert layers; the router as `experts/router` | routed experts with their norm and residual add |
 | `short_conv` | `ShortConv.forward_cached`, `HybridDecoderLayer` (a convolution layer) | the gated short convolution with its norm and residual add |
@@ -122,6 +122,22 @@ decide differently.  `latent_form` is a fact of the programs: in the
 `tools/kv_layout_check.py`'s line for each program, and in the persistent
 executable cache's identity (an executable stored under another choice is
 not loaded).
+
+How a prefill chunk reaches its joining row is decided when the chunk
+program is traced, from what the model declares (`Generator.chunk_row`):
+`"in_place"` for a model whose cached forward takes the row
+(`cached_forward_takes_row`: the GPT family, uniform K/V ring planes): the
+`[1, G, T, L]` block is written into the full donated `[S, G, C, L]` plane
+at `(row, 0, pos, 0)` and the batch-1 queries attend that row of the plane
+where it lies; `"sliced"` for every other model: the row is cut out of
+every plane, run as a batch-1 cache and spliced back whole.  `chunk_row` is
+a fact of the program, set once and not reset with the counters: in the
+`extra` of the `generate_chunk` compile event, in
+`SlotLoop.stats()["chunk_row"]` beside `kv_heads_per_lane_row`, in the
+persistent executable cache's identity where it is `"in_place"`, and
+`tools/kv_layout_check.py`'s chunk line counts the whole rows a program cuts
+or writes back (`row_sized_slices`: 0 in place, 2 a plane sliced).  No
+metric of its own: `chunk_cache_write_ms.*` reads what it costs.
 
 How wide the column selector's search runs is decided in the program, from
 what a dispatch can see: it goes over the narrowest of a short list of

@@ -13,7 +13,18 @@ program) and prints, from ``compiled.as_text()``:
   * for each write (``dynamic-update-slice`` into a plane or into a
     ``[1, ., C, .]`` row of one): which dimension carries the traced,
     unaligned index and whether that is the layout's minor-most (lane)
-    dimension;
+    dimension, and ``traced_index_dims``, every dimension whose index is
+    no constant (a step's column write: ``[2]``; a chunk that writes its
+    block into the full plane in place: ``[0, 2]``, row and column);
+  * ``row_sized_slices``: how many whole cache rows ``[1, ., C, .]`` a
+    program cuts out of a plane or writes back into one, as instructions
+    of their own or inside a fusion (``dynamic-slice`` whose row is the
+    instruction's result, ``dynamic-update-slice`` whose update is a
+    row): what a chunk pays that runs on a cut-out row
+    (``Generator.chunk_row() == "sliced"``), 2 a plane; 0 for one that
+    addresses the row inside the plane (``"in_place"``), where the row's
+    ``dynamic-slice`` is an operand of the attention's product, fused
+    into it, and never a 3.4 MB array of its own;
   * whether every plane is aliased input to output (donation kept), and
     the program's ``memory_analysis()`` (arguments, temporaries), which is
     how a configuration's ``slots`` is sized before any chip time;
@@ -224,6 +235,73 @@ def scope_coverage(hlo_text):
         round(100.0 * none / max(1, sum(counts.values())), 1)
 
 
+def _computations(hlo_text):
+    """{name: its lines} of every computation of the module, and ENTRY's
+    name."""
+    out, entry, cur = {}, None, None
+    for line in hlo_text.splitlines():
+        m = _COMPUTATION.match(line)
+        if m:
+            cur = out[m.group(1)] = []
+            if line.startswith("ENTRY"):
+                entry = m.group(1)
+        elif line == "}":
+            cur = None
+        elif cur is not None:
+            cur.append(line)
+    return out, entry
+
+
+# "%name = <type> opcode(operands...)", the type an array's or a tuple's
+_TYPED = re.compile(r"^\s*(?:ROOT )?%(?P<name>[\w.\-]+) = (?P<type>.*?) "
+                    r"(?P<op>[\w\-]+)\((?P<args>[^)]*)\)")
+_SHAPE = re.compile(r"\w+\[([\d,]*)\]")
+
+
+def _dims(type_text):
+    """The dims of every array in a type (one, or a tuple's several)."""
+    return [tuple(int(d) for d in m.group(1).split(",") if d)
+            for m in _SHAPE.finditer(type_text)]
+
+
+def row_sized_slices(hlo_text, plane_shapes):
+    """How many whole rows ``[1, ., C, .]`` of a cache plane ENTRY cuts out
+    or writes back: its ``dynamic-slice`` instructions whose result is a
+    row, its ``dynamic-update-slice`` instructions whose update is one,
+    and its fusions that do either inside (a cut counts where a row is
+    among the fusion's results: a row that only feeds a product of the
+    same fusion is read where it lies and is no array of its own)."""
+    rows = {(1,) + s[1:] for s in plane_shapes if s[0] != 1}
+    comps, entry = _computations(hlo_text)
+    parsed = {name: [m for m in map(_TYPED.match, lines) if m]
+              for name, lines in comps.items()}
+
+    def n_rows(type_text):
+        return sum(d in rows for d in _dims(type_text))
+
+    def counts(name, seen=()):
+        """Per instruction of ``name``: (it, rows cut, rows written), what
+        it calls counted in."""
+        types = {m["name"]: m["type"] for m in parsed[name]}
+        for m in parsed[name]:
+            args = re.findall(r"%([\w.\-]+)", m["args"])
+            cuts = puts = 0
+            if m["op"] == "dynamic-slice":
+                cuts = min(1, n_rows(m["type"]))
+            elif m["op"] == "dynamic-update-slice" and len(args) > 1:
+                puts = min(1, n_rows(types.get(args[1], "")))
+            for callee in _CALLED.findall(m.string):
+                if callee in parsed and callee not in seen:
+                    for _m, c, p in counts(callee, seen + (name,)):
+                        cuts, puts = cuts + c, puts + p
+            yield m, cuts, puts
+
+    # a fusion's cut rows count as far as rows are among its results
+    return sum(puts + (min(cuts, n_rows(m["type"])) if m["op"] == "fusion"
+                       else cuts)
+               for m, cuts, puts in counts(entry))
+
+
 def _aliased_params(hlo_text):
     # "{1}: (2, {}, may-alias)" of a tuple's element; "{}: (0, ..." where
     # the program's one output is no tuple
@@ -268,8 +346,13 @@ def inspect(hlo_text, plane_shapes, state_shapes=frozenset()):
         m = re.search(r'"is_index_aligned":\[([\w,]*)\]', line)
         unaligned = tuple(i for i, a in enumerate(m[1].split(","))
                           if a != "true") if m else ()
+        # the dimensions whose index is no constant: the column alone for
+        # a step's write, row and column for a chunk's block written into
+        # the full plane (a major dimension's index is always "aligned")
+        traced = tuple(i for i, a in enumerate(args[2:])
+                       if a not in instrs or instrs[a][2] != "constant")
         (state_writes if dims in state_shapes or dims in state_rows
-         else writes)[(layout, unaligned)] += 1
+         else writes)[(layout, unaligned, traced)] += 1
     plane_copies, state_copies, row_relayouts = [], [], []
     state_row_relayouts = []
     for name, (dims, layout, op, args, _line) in instrs.items():
@@ -286,8 +369,9 @@ def inspect(hlo_text, plane_shapes, state_shapes=frozenset()):
     loops, loop_copies = _while_plane_copies(hlo_text, instrs, plane_shapes)
     def as_writes(counter):
         return [{"minor_to_major": list(l), "unaligned_index_dims": list(u),
+                 "traced_index_dims": list(t),
                  "on_minor_most": bool(l) and l[0] in u, "count": c}
-                for (l, u), c in counter.items()]
+                for (l, u, t), c in counter.items()]
 
     state = {} if not state_shapes else {
         "state_planes": [{"shape": list(s), "dtype": d,
@@ -321,6 +405,7 @@ def inspect(hlo_text, plane_shapes, state_shapes=frozenset()):
                 (instrs[n][0], _INSTR.match(instrs[n][4])["dtype"])
                 for n in plane_copies).items()],
         "row_relayout_copies": len(row_relayouts),
+        "row_sized_slices": row_sized_slices(hlo_text, plane_shapes),
     }
 
 
