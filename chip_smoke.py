@@ -73,6 +73,9 @@ KERNEL_TOL = {
     "single_block_attention_bwd": (5e-2, 5e-2),
     "packed_cached_attention": (4e-3, 2e-2),
     "blocked_decode_attention": (4e-3, 2e-2),
+    # the kernel against the loops: the same bf16 operands and float32
+    # sums, another order of the sums
+    "per_row_decode_attention": (4e-3, 2e-2),
     "fused_conv_bn_relu": (5e-2, 5e-2),
     "fused_bn_relu": (5e-2, 5e-2),
 }
@@ -96,6 +99,10 @@ class Size:
     attn: Tuple[int, int, int, int]            # B, N, S, H
     attn_train: Tuple[int, int, int, int]      # B, N, S, H, un-cached
     decode_heads: int                          # of H, over the packed ring
+    # the step's per-row read against the loops, alone: (name, rows, query
+    # heads, head_dim, queries a cached head, columns, live rows, their
+    # contexts (low, high), frontier, loop trips (few, many))
+    decode_rows: Tuple[tuple, ...]
     conv_x: Tuple[int, int, int, int]          # N, H, W, C (NHWC)
     conv_w: Tuple[int, int, int, int]          # O, I, kh, kw
     moe: GPTMoEConfig
@@ -123,6 +130,13 @@ FULL = Size(
     # attn_train: the benchmark's BERT-large step (16 rows of 512)
     slots=8, attn=(8, 12, 1024, 64), attn_train=(16, 16, 512, 64),
     decode_heads=25,
+    # the two GPT-2 XL cells' steps (2 live rows of 32; 15 of ~350 columns)
+    # and lfm2's (126 of 128 rows, 4 queries a cached head, 8,192 columns)
+    decode_rows=(
+        ("gpt2_xl_chat", 32, 25, 64, 1, 1024, 2, (200, 350), 500, (20, 60)),
+        ("gpt2_xl_saturated", 32, 25, 64, 1, 1024, 15, (150, 550), 600,
+         (20, 60)),
+        ("lfm2", 128, 32, 64, 4, 8192, 126, (300, 1500), 4000, (10, 30))),
     conv_x=(32, 56, 56, 64),
     conv_w=(64, 64, 3, 3),
     moe=_moe_cfg(vocab_size=128, hidden_size=512, layers=4, heads=8,
@@ -142,6 +156,7 @@ TINY = Size(
     serve_batch_buckets=(1, 2), serve_seq_buckets=(8, 16), serve_max_new=4,
     serve_max_len=32, prompt_lens=(3, 7, 12, 1, 9, 5), slots=4,
     attn=(1, 2, 256, 64), attn_train=(1, 2, 128, 64), decode_heads=3,
+    decode_rows=(("tiny", 4, 8, 64, 4, 256, 2, (20, 150), 200, (1, 2)),),
     conv_x=(2, 8, 8, 8),
     conv_w=(8, 8, 3, 3),
     moe=_moe_cfg(vocab_size=64, hidden_size=16, layers=2, heads=2, seq=32,
@@ -792,6 +807,70 @@ def _bn_relu_ref(x, gamma, beta):
     return jnp.maximum(y, 0.0), mean, var
 
 
+def _chained_us(fn, q, rest, trips):
+    """Microseconds a call of ``fn(q, *rest)``: the output is fed back into
+    the queries under ``lax.fori_loop`` (nothing is hoisted or dropped) and
+    two trip counts are timed, the best of three each: their difference
+    leaves the dispatch out."""
+    def best(n):
+        @jax.jit
+        def run(q, *rest):
+            return jax.lax.fori_loop(
+                0, n, lambda _, q: (q + fn(q, *rest) * 1e-3).astype(q.dtype),
+                q)
+        jax.block_until_ready(run(q, *rest))
+        took = []
+        for _ in range(3):
+            t0 = time.perf_counter()
+            jax.block_until_ready(run(q, *rest))
+            took.append(time.perf_counter() - t0)
+        return min(took)
+    few, many = trips
+    return (best(many) - best(few)) / (many - few) * 1e6
+
+
+def _decode_rows_case(case, rng) -> dict:
+    from paddle_tpu.nn.functional.attention import (_decode_rows_fn,
+                                                    _decode_span_fn,
+                                                    decode_block)
+    from paddle_tpu.nn.layer.transformer import kv_heads_per_lane_row
+    name, rows, heads, hd, rep, cols, live, ctx, pos, trips = case
+    g = kv_heads_per_lane_row(hd)
+    groups = -(-(heads // rep) // g)
+    block = decode_block(cols)
+    q = jnp.asarray(rng.randn(rows, heads, 1, hd), jnp.bfloat16)
+    k, v = (jnp.asarray(rng.randn(rows, groups, cols, g * hd), jnp.bfloat16)
+            for _ in range(2))
+    start = np.full(rows, cols)
+    at = rng.permutation(rows)[:live]
+    start[at] = pos + 1 - rng.randint(ctx[0], ctx[1] + 1, live)
+    end = jnp.full((rows,), pos + 1, jnp.int32)
+    start = jnp.asarray(start, jnp.int32)
+    forms = {"loops": lambda *a: _decode_span_fn(*a, block=block, rep=rep),
+             "kernel": lambda *a: _decode_rows_fn(*a, block=block, rep=rep)}
+    out = {f: jax.block_until_ready(fn(q, k, v, start, end))
+           for f, fn in forms.items()}
+    _check(bool(np.isfinite(np.asarray(out["kernel"], np.float32)).all()),
+           f"kernels: per_row_decode_attention {name} non-finite")
+    own = np.asarray(start)[at]
+    blocks = -(-cols // block)
+    rec = {"rows": rows, "live": live, "lane_rows": groups, "block": block,
+           "max_abs_diff_loops": round(_close(
+               "per_row_decode_attention", out["kernel"][at],
+               out["loops"][at]), 6),
+           "blocks_read_pct": {
+               "loops": round(100.0 * (pos // block + 1 - own.min() // block)
+                              / blocks, 2),
+               "kernel": round(100.0 * int((pos // block + 1
+                                            - own // block).sum())
+                               / (rows * blocks), 2)},
+           "tolerance": list(KERNEL_TOL["per_row_decode_attention"])}
+    rec["us_a_call"] = {f: round(_chained_us(fn, q, (k, v, start, end),
+                                             trips), 1)
+                        for f, fn in forms.items()}
+    return rec
+
+
 def phase_kernels(size: Size, seed: int = 0) -> dict:
     """Every Pallas kernel a default or a flag can reach, compiled (not
     interpreted) once at a main-path shape, against its XLA reference."""
@@ -915,6 +994,12 @@ def phase_kernels(size: Size, seed: int = 0) -> dict:
             got[live].astype(jnp.float32)
             - whole[live].astype(jnp.float32)).max()), 6),
         "tolerance": list(KERNEL_TOL["blocked_decode_attention"])}
+    # the step's read of each generating row's own blocks in ONE kernel
+    # (ISSUE 47; ops/pallas/span_decode.py), alone against those loops at
+    # the cells' shapes: the outputs agree on the live rows, a dead row
+    # reads finite values, and what a call costs in either form
+    checked["per_row_decode_attention"] = {
+        case[0]: _decode_rows_case(case, rng) for case in size.decode_rows}
     checked["compiled_not_interpreted"] = on_chip
     checked["shapes"] = {"attention": list(size.attn),
                          "conv_x": list(size.conv_x),

@@ -132,6 +132,55 @@ def decode_block(C):
     return min(DECODE_BLOCK, int(C))
 
 
+def decode_read_form(plane_shape, dtype, block, keep=None):
+    """The form a decode step's read of ring planes ``[B, G, C, L]``
+    takes: ``"per_row"``, the kernel of ops/pallas/span_decode.py (of
+    each row the blocks that hold its own valid columns, nothing of a row
+    that generates nothing), or ``"span"``, the XLA loops over the union
+    span (:func:`_decode_span_fn`).  Decided while the program is traced,
+    from what the code can see: the backend (the TPU), no mesh of several
+    devices (:func:`_partitioned`), no ``keep`` mask, and the planes
+    (``supports_span_decode``: bf16 / f32, whole lane rows, blocks on a
+    tile's edge)."""
+    if keep is not None or not _on_tpu() or _partitioned():
+        return "span"
+    from ...ops.pallas.span_decode import supports_span_decode
+    return "per_row" if supports_span_decode(plane_shape, dtype, block) \
+        else "span"
+
+
+def decode_attention(q, k, v, start, end, block, rep=1, keep=None):
+    """One-query attention of ``(B, N, 1, H)`` queries over ring planes
+    ``(B, G, C, g*H)`` whose valid columns are each row's ``[start[b],
+    end[b])``, in the form :func:`decode_read_form` names: each row's own
+    blocks in one kernel (:func:`_decode_rows_fn`) or the union span in
+    two XLA loops (:func:`_decode_span_fn`, which says what the operands
+    are).  The same numbers either way."""
+    if decode_read_form(k.shape, k.dtype, block, keep) == "per_row":
+        return _decode_rows_fn(q, k, v, start, end, block=block, rep=rep)
+    kw = {} if keep is None else {"keep": keep}
+    return _decode_span_fn(q, k, v, start, end, block=block, rep=rep, **kw)
+
+
+def _decode_rows_fn(q, k, v, start, end, block, rep=1):
+    """:func:`_decode_span_fn` as ONE Pallas kernel that reads, of each
+    row, the blocks ``[start[b] // block, ceil(end[b] / block))`` and
+    nothing of a row with ``start[b] >= end[b]``
+    (ops/pallas/span_decode.py): spread queries, float32 scores times ``1
+    / sqrt(H)``, ONE softmax over a row's scores, probabilities in the
+    queries' dtype, float32 sums; the queries are spread and each head's
+    lanes are kept here, as the loops do it.  A row with no valid column
+    reads zeros.  The kernel's call is a ``jax.jit`` of its own (a
+    model's layers share one trace of its body); its custom call
+    ``span_decode_attention`` lies under the caller's scope."""
+    from ...ops.pallas.span_decode import span_decode_attention_fn
+    n, hd = q.shape[1], q.shape[3]
+    qs, own = _spread_queries(q, k.shape[1], k.shape[3], rep)
+    out = span_decode_attention_fn(qs[:, :, :, 0], k, v, start, end,
+                                   block=block, scale=1.0 / math.sqrt(hd))
+    return _own_lanes(out[:, :, :, None], own, n, hd, rep)
+
+
 @functools.partial(jax.jit, static_argnames=("block", "rep"))
 def _decode_span_fn(q, k, v, start, end, block, rep=1, keep=None):
     """One-query attention of ``(B, N, 1, H)`` queries over ring planes
@@ -281,11 +330,11 @@ def span_attention(q, k, v, start, first, rep=1, keep=None):
     (:func:`block_keep`).  Raw arrays; inference only."""
     B, _, T, _ = q.shape
     C = k.shape[2]
-    kw = {} if keep is None else {"keep": keep}
     if T == 1:
-        return _decode_span_fn(q, k, v, start,
-                               jnp.broadcast_to(first + 1, (B,)),
-                               block=decode_block(C), rep=rep, **kw)
+        return decode_attention(q, k, v, start,
+                                jnp.broadcast_to(first + 1, (B,)),
+                                block=decode_block(C), rep=rep, keep=keep)
+    kw = {} if keep is None else {"keep": keep}
     return _block_span_fn(q, k, v, start, first,
                           block=min(SPAN_BLOCK, C), rep=rep, **kw)
 
@@ -423,7 +472,7 @@ def cached_attention(q, k, v, attn_mask=None, window=None, k_scale=None,
         v = Tensor(dequantize_kv_rows(v, v_scale, dtype=dt))
     elif window is not None:
         k = unwrap(k)
-        return Tensor(_decode_span_fn(
+        return Tensor(decode_attention(
             unwrap(q), k, unwrap(v), unwrap(window[0]), unwrap(window[1]),
             block=decode_block(k.shape[2])))
     if unwrap(k).shape[-1] != unwrap(q).shape[-1]:

@@ -58,10 +58,13 @@ def packed_attention(q, k, v, num_heads, bias=None, causal=False,
 from . import fused_bn, fused_conv  # noqa: F401  (kernel families)
 from .latent_attention import (  # noqa: E402
     fused_latent_form, latent_chunk_attention_fn, supports_latent)
+from .span_decode import (  # noqa: E402
+    span_decode_attention_fn, supports_span_decode)
 
 __all__ = ["flash_attention", "flash_attention_fn", "supports",
            "packed_attention", "packed_attention_fn", "supports_packed",
            "fused_form",
            "latent_chunk_attention_fn", "supports_latent",
            "fused_latent_form",
+           "span_decode_attention_fn", "supports_span_decode",
            "DEFAULT_BLOCK", "fused_bn", "fused_conv"]

@@ -191,11 +191,14 @@ What the loop measures about itself, always on:
     programs in order), and committed with that step, like everything
     the driver tallied before it dispatched the step: a chunk that no
     step follows is counted when the loop closes;
-  * **the span a step's attention reads** — the step program attends
-    in column blocks from the oldest generating row's ``start`` to the
-    shared frontier (``cached_attention``); ``counters["attn_blocks_
-    read"]`` of ``["attn_blocks_total"]`` is that span, per step, by
-    the same arithmetic on the host.
+  * **the blocks a step's attention reads** — the step program attends
+    in column blocks (``cached_attention``): each generating row its
+    own, from its ``start`` to the shared frontier (``stats()["step_
+    read"]`` ``"per_row"``: one kernel on the TPU), or every row the
+    span from the oldest generating row's ``start`` (``"span"``: the
+    XLA loops); ``counters["attn_blocks_read"]`` of ``["attn_blocks_
+    total"]`` is what a step streamed of the planes' blocks under
+    either form, by the same arithmetic on the host.
 """
 from __future__ import annotations
 
@@ -502,9 +505,14 @@ class SlotLoop:
         # this many columns (cached_attention), whatever the model keeps
         # beside them that has no columns
         self._attn_block = 0
+        self._step_read = {}
         if column_kinds == ["kv"] and not self._spec:
             self._attn_block = decode_block(self.C)
             self.counters.update(attn_blocks_read=0, attn_blocks_total=0)
+            # ... each generating row's own blocks in one kernel
+            # ("per_row") or the union span in XLA loops ("span"): the
+            # form the step program was traced in (Generator.step_read)
+            self._step_read = {"step_read": gen.step_read(self.C)}
         if self._kv_columns:
             # the valid columns themselves, of steps and of chunks (what
             # a roofline is counted from)
@@ -1103,16 +1111,27 @@ class SlotLoop:
 
     def _tally_blocks(self, starts, pos):
         """Driver thread: the column blocks the step at ``pos`` reads
-        of each plane, of those the plane has: from the block of the
-        lowest ``start`` among the rows it was handed as generating to
-        the block of ``pos`` (``cached_attention``'s own bounds)."""
+        of each plane, of those the plane has, by the step's own bounds
+        (``nn.functional.attention.decode_attention``).  Read
+        ``"per_row"``, each row it was handed as generating reads the
+        blocks from its own ``start``'s to the block of ``pos`` and the
+        other rows none: their sum, of ``slots x blocks``.  Read as a
+        ``"span"``, every row reads from the block of the lowest such
+        ``start`` to the block of ``pos``: that span, of the plane's
+        blocks (the same share of ``slots x blocks``)."""
         block = self._attn_block
         if not block:
+            return
+        blocks = -(-self.C // block)
+        if self._step_read["step_read"] == "per_row":
+            self._add("attn_blocks_read",
+                      int((pos // block + 1 - starts // block).sum()))
+            self._add("attn_blocks_total", self.S * blocks)
             return
         read = pos // block + 1 - int(starts.min()) // block \
             if starts.size else 0
         self._add("attn_blocks_read", read)
-        self._add("attn_blocks_total", -(-self.C // block))
+        self._add("attn_blocks_total", blocks)
 
     def _add(self, key, n, chunk=False):
         t = self._tally
@@ -1626,6 +1645,7 @@ class SlotLoop:
                # the weights the step and the chunk agreed to have relaid
                # (Generator.slot_execs), and those they disagreed on
                **self._gen.weights_layout, **self._latent_form,
+               **self._step_read,
                "plane_kinds": list(self._plane_kinds),
                "occupancy_ewma": round(self._occupancy, 4), **c,
                # the driver's seconds by phase, and the phases of the

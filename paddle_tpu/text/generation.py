@@ -464,6 +464,28 @@ class Generator:
         return "in_place" if getattr(
             self._layer, "cached_forward_takes_row", False) else "sliced"
 
+    def step_read(self, C):
+        """The form the step's one-query read of the model's K/V ring
+        planes takes at cache length ``C``
+        (``nn.functional.attention.decode_read_form``, from the backend,
+        the mesh and the planes as they would be traced): ``"per_row"``,
+        one kernel over each generating row's own column blocks, or
+        ``"span"``, the XLA loops over the union span; ``"mixed"`` where
+        the layers' planes decide differently; None for a model whose
+        column planes are not all plain ``kv`` planes (an int8 cache, a
+        latent plane, a layer that passes a mask of chosen blocks).  A
+        fact of the program, in the ledger's ``generate_step`` event and
+        in ``SlotLoop.stats()``."""
+        from ..nn.functional.attention import decode_block, decode_read_form
+        layers = [(s, c) for s, c in zip(self.cache_spec(C),
+                                         self._slot_cache_avals(1, C))
+                  if int(s["columns"])]
+        if not layers or any(s["kind"] != "kv" for s, _ in layers):
+            return None
+        forms = {decode_read_form(c[0].shape, c[0].dtype, decode_block(C))
+                 for _, c in layers}
+        return forms.pop() if len(forms) == 1 else "mixed"
+
     def latent_form(self, T):
         """The form a model's cached attention over latent planes takes
         for the width ``T`` of the block a program is traced for
@@ -685,7 +707,8 @@ class Generator:
                 {"slots": S, "cache": C, "eos": end,
                  "kv_heads_per_lane_row": self.kv_heads_per_lane_row(),
                  **_known(latent_form=self.latent_form(1),
-                          selector_widths=self.selector_widths(C))},
+                          selector_widths=self.selector_widths(C),
+                          step_read=self.step_read(C))},
                 (2,))
 
     def _chunk_program(self, S, T, C):
@@ -1006,6 +1029,9 @@ class Generator:
                 # so does one whose chunk writes its row in place
                 *((("chunk_row", "in_place"),)
                   if self.chunk_row() == "in_place" else ()),
+                # so does one whose step reads its planes row by row
+                *((("step_read", "per_row"),)
+                  if self.step_read(self._max_len) == "per_row" else ()),
                 # the form of a latent model's cached attention is picked
                 # when a program is traced (a step's, the widest block's):
                 # an executable stored under another choice must not load

@@ -18,6 +18,17 @@ different blocks; which calls of ``cached_attention`` take the blocked
 form; the int8 cache's read under a decode window (dequantize, then
 the one expression under the mask); and the benchmark's reader of the
 loop's ``attn_blocks_*``.
+
+Since ISSUE 47 the blocked read has two forms, picked when the program is
+traced (``decode_read_form``): the XLA loops over the union span, and on
+the TPU ONE Pallas kernel that reads each row's own blocks
+(ops/pallas/span_decode.py).  Held here with the backend steered and the
+kernel interpreted: the kernel against the loops over the served head
+geometries (two heads a lane row; grouped queries, 4 and 16 a cached
+head), planes the block divides and does not, dead rows, one-column
+spans, a row that starts inside the last block; what the rule admits; and
+a slot-loop session with joins, leaves and a ring restart under both
+forms, tokens and ``attn_blocks_*`` alike.
 """
 import importlib
 
@@ -319,3 +330,199 @@ def test_attn_span_read_pct_reader(stats, value):
         "benchmark.layer_metrics.attn_span_read_pct")
     got = reader.compute({"counters": {"slot_loop": stats} if stats else {}})
     assert got == (pytest.approx(value) if value is not None else None)
+
+
+# -- the per-row kernel (ISSUE 47) -------------------------------------------------
+
+@pytest.fixture
+def on_one_tpu(monkeypatch):
+    """The backend steered (on the CPU every read keeps the XLA loops) and
+    the process's mesh held to one device; the kernel is interpreted."""
+    from paddle_tpu.parallel.mesh import MeshGuard, make_mesh
+    monkeypatch.setattr(A, "_on_tpu", lambda: True)
+    with MeshGuard(make_mesh({"dp": 1}, jax.devices()[:1])):
+        yield
+
+
+# (heads a lane row, queries a cached head, query heads): GPT-2 XL's 25
+# heads of 64 on 13 lane rows; lfm2's 32 over 8 of 64; nemotron3's 32
+# over 2 of 128
+GEOMETRIES = [(2, 1, 25), (2, 4, 32), (1, 16, 32)]
+# name: (columns, starts, frontier) in blocks of ``K`` columns; a start at
+# the plane's length is a row that is not generating
+def _rows(K):
+    cols = 4 * K
+    return {
+        "unlike-starts": (cols, [0, cols, K + 1, 2 * K + 8, 2 * K + 1],
+                          2 * K + 8),
+        # (half a block over: the last block starts at ``cols - K``)
+        "plane-the-block-does-not-divide": (
+            cols + K // 2, [0, cols + K // 2, K + 1, cols + 2, cols + 6],
+            cols + 6),
+        "every-row-dead": (cols, [cols] * B, K + 1),
+        "one-column": (cols, [2 * K + 3] * B, 2 * K + 3),
+        "starts-inside-the-last-block": (cols, [3 * K + 2, cols, cols - 1, 0,
+                                                3 * K], cols - 1),
+        "frontier-past-the-ring": (cols, [0, 3, K, cols, 2], cols + 5),
+    }
+
+
+def _grouped(g, rep, n, cols, dtype, seed=11):
+    """Queries ``[B, n, 1, 128 / g]`` and planes of ``n / rep`` cached
+    heads, ``g`` a lane row."""
+    hd = 128 // g
+    groups = -(-(-(-n // rep)) // g)
+    rng = np.random.RandomState(seed)
+    q = jnp.asarray(rng.standard_normal((B, n, 1, hd)), dtype)
+    k, v = (jnp.asarray(rng.standard_normal((B, groups, cols, g * hd)),
+                        dtype) for _ in range(2))
+    return q, k, v
+
+
+@pytest.mark.parametrize("dtype", [jnp.float32, jnp.bfloat16],
+                         ids=["f32", "bf16"])
+@pytest.mark.parametrize("rows", sorted(_rows(BLOCK)))
+@pytest.mark.parametrize("g,rep,n", GEOMETRIES,
+                         ids=[f"g{g}rep{r}n{n}" for g, r, n in GEOMETRIES])
+def test_the_per_row_kernel_equals_the_loops(g, rep, n, rows, dtype,
+                                             on_one_tpu, monkeypatch):
+    # a block is a whole number of the dtype's tiles, half a block too
+    block = BLOCK if dtype == jnp.float32 else 2 * BLOCK
+    cols, starts, pos = _rows(block)[rows]
+    q, k, v = _grouped(g, rep, n, cols, dtype)
+    start, end = _window(starts, pos)
+    took = []
+    real = A._decode_rows_fn
+    monkeypatch.setattr(A, "_decode_rows_fn",
+                        lambda *a, **kw: took.append(kw) or real(*a, **kw))
+    got = A.decode_attention(q, k, v, start, end, block=block, rep=rep)
+    assert took == [{"block": block, "rep": rep}]
+    want = A._decode_span_fn(q, k, v, start, end, block=block, rep=rep)
+    assert got.shape == want.shape == q.shape and got.dtype == q.dtype
+    got, want = (np.asarray(a, np.float32) for a in (got, want))
+    assert np.isfinite(got).all()
+    live = np.asarray(start) < min(pos + 1, cols)
+    assert live.sum() == sum(s < cols for s in starts)
+    np.testing.assert_allclose(
+        got[live], want[live], **(TOL if dtype == jnp.float32 else BF16_TOL))
+
+
+def test_the_kernel_reads_nothing_outside_a_rows_own_blocks(block16,
+                                                            on_one_tpu):
+    """Infinities in every block that holds no valid column OF THAT ROW
+    (inside the union span too, where the loops read them for every row):
+    the rows come out bit-equal, and a row that generates nothing reads
+    zeros."""
+    g, rep, n = GEOMETRIES[0]
+    q, k, v = _grouped(g, rep, n, C, jnp.float32, seed=12)
+    pos = 3 * BLOCK + 2
+    start, end = _window([0, C, 2 * BLOCK + 1, 3 * BLOCK, BLOCK], pos)
+    col = jnp.arange(C)
+    other = col[None, :] // BLOCK < start[:, None] // BLOCK
+    clean = np.asarray(A.decode_attention(q, k, v, start, end, block=BLOCK))
+    inf = np.asarray(A.decode_attention(
+        q, _poison(k, jnp.inf, other), _poison(v, jnp.inf, other), start,
+        end, block=BLOCK))
+    np.testing.assert_array_equal(clean, inf)
+    assert not clean[1].any() and clean[0].any()
+    loops = np.asarray(A._decode_span_fn(
+        q, _poison(k, jnp.inf, other), _poison(v, jnp.inf, other), start,
+        end, block=BLOCK))
+    assert not np.isfinite(loops).all()
+
+
+def test_what_takes_the_per_row_kernel(monkeypatch, block16):
+    """The TPU, one device, no mask of chosen blocks, bf16 / f32 planes of
+    whole lane rows whose blocks start on a tile's edge: from what the
+    code can see, no flag."""
+    from paddle_tpu.parallel.mesh import MeshGuard, make_mesh
+    plane, f32, bf16 = (B, 13, C, 128), jnp.float32, jnp.bfloat16
+    with MeshGuard(make_mesh({"dp": 1}, jax.devices()[:1])):
+        assert A.decode_read_form(plane, bf16, BLOCK) == "span"     # the CPU
+        monkeypatch.setattr(A, "_on_tpu", lambda: True)
+        assert A.decode_read_form(plane, bf16, BLOCK) == "per_row"
+        assert A.decode_read_form(plane, f32, BLOCK) == "per_row"
+        assert A.decode_read_form(plane, f32, 8) == "per_row"
+        for shape, dtype, block, keep in [
+                (plane, bf16, BLOCK, jnp.ones((B, 13, 1, C), bool)),
+                (plane, jnp.int8, BLOCK, None),
+                ((B, 25, C, 80), bf16, BLOCK, None),     # half a lane row
+                (plane, bf16, 8, None),                  # half a bf16 tile
+                ((B, 13, C + 8, 128), bf16, BLOCK, None),
+                ((B, 13, 8, 128), f32, BLOCK, None),     # wider than it
+                # a row's float32 scores would not wait in VMEM
+                ((2, 13, 65536, 128), bf16, 128, None)]:
+            assert A.decode_read_form(shape, dtype, block, keep) == "span"
+    if len(jax.devices()) > 1:
+        with MeshGuard(make_mesh({"dp": 2}, jax.devices()[:2])):
+            assert A.decode_read_form(plane, bf16, BLOCK) == "span"
+
+
+@pytest.mark.parametrize("form", ["span", "per_row"])
+def test_a_session_serves_the_same_tokens_under_both_read_forms(
+        form, block16, monkeypatch, request):
+    """Joins, leaves and a ring restart in a loop of 2 slots over 32
+    columns: the tokens are ``generate()``'s under either form, and the
+    counters are the host's arithmetic on what each step was handed: the
+    generating rows' own blocks of ``slots x blocks`` where the step
+    reads per row, the span from the oldest generating row's ``start`` of
+    the plane's blocks where it reads the span."""
+    if form == "per_row":
+        request.getfixturevalue("on_one_tpu")
+    hd, heads = CASES[0]
+    m = _gpt(hd, heads, seed=41)
+    gen = Generator(m, site=f"read_form:{form}", seq_buckets=(8, 16, 32),
+                    max_len=64)
+    monkeypatch.setattr(A, "_on_tpu", lambda: False)
+    oracle = Generator(m, seq_buckets=(8, 16, 32), max_len=64)
+    want = [np.asarray(oracle.generate(
+        np.asarray([p], np.int32), lengths=np.asarray([len(p)], np.int32),
+        max_new_tokens=mn).numpy())[0][:mn]
+        for p, mn in SESSION]
+    monkeypatch.setattr(A, "_on_tpu", lambda: form == "per_row")
+    assert gen.step_read(32) == form
+    loop = SlotLoop(gen, slots=2, cache_len=32, chunk=8)
+    seen, real = [], loop._step
+
+    def step(*args):
+        start, _finished, active, _joined, pos = args[-5:]
+        seen.append((int(pos), np.array(start), np.array(active)))
+        return real(*args)
+
+    loop._step = step
+    try:
+        futs = [loop.submit(p, mn) for p, mn in SESSION]
+        for f, w in zip(futs, want):
+            np.testing.assert_array_equal(
+                np.asarray(f.result(timeout=120)).reshape(-1)[:len(w)], w)
+    finally:
+        loop.close()
+    c = loop.stats()
+    # the form is a fact of the step program: in ``stats()``, and in the
+    # ``generate_step`` event's ``extra`` (not in the chunk's)
+    from paddle_tpu.profiler import ledger
+    evs = {e["kind"]: e for e in ledger.compile_events(gen.site)}
+    assert c["step_read"] == evs["generate_step"]["step_read"] == form
+    assert "step_read" not in evs["generate_chunk"]
+    assert c["session_resets"] >= 1 and c["steps"] == len(seen)
+    own = sum(int((pos // BLOCK + 1 - start[active] // BLOCK).sum())
+              for pos, start, active in seen)
+    span = sum(pos // BLOCK + 1 - int(start[active].min()) // BLOCK
+               for pos, start, active in seen)
+    blocks = 32 // BLOCK
+    if form == "per_row":
+        assert (c["attn_blocks_read"], c["attn_blocks_total"]) \
+            == (own, c["steps"] * 2 * blocks)
+    else:
+        assert (c["attn_blocks_read"], c["attn_blocks_total"]) \
+            == (span, c["steps"] * blocks)
+    # one generating row of two reads half of what the span charges
+    assert own < 2 * span
+    reader = importlib.import_module(
+        "benchmark.layer_metrics.attn_span_read_pct")
+    assert reader.compute({"counters": {"slot_loop": c}}) == pytest.approx(
+        100.0 * c["attn_blocks_read"] / c["attn_blocks_total"])
+
+
+SESSION = [([7, 3, 9, 1, 5, 2], 4), ([4, 8, 6, 2, 9, 1], 20),
+           ([1, 2, 3, 4, 5, 6], 20), ([9, 9, 1], 6)]

@@ -190,6 +190,8 @@ def test_slot_loop_equals_the_reference_and_counts_in_one_piece(served):
     assert st["plane_kinds"] == ["conv_state", "kv"]
     # this family's chunk still runs on a row cut out of the planes
     assert st["chunk_row"] == "sliced"
+    # the grouped-query layers' step read: the XLA loops on the CPU
+    assert st["step_read"] == "span"
     assert st["kv_heads_per_lane_row"] == 8            # 128 / head size 16
     moe_layers, k = 4, cfg["num_experts_per_tok"]
     fed = sum(n for n, _ in REQUESTS) + st["emitted_tokens"]

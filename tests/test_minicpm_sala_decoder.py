@@ -462,6 +462,9 @@ def test_slot_loop_equals_the_reference_and_counts_in_one_piece(served):
     assert st["plane_kinds"] == ["kv+pooled_key", "ssm_state"]
     # this family's chunk still runs on a row cut out of the planes
     assert st["chunk_row"] == "sliced"
+    # its sparse layers read under a mask of chosen blocks: no read form
+    # of plain K/V planes to name (ISSUE 47)
+    assert "step_read" not in st
     assert st["chunk_tokens"] == sum(n for n, _ in REQUESTS)
     assert st["ssm_rows_updated"] == st["emitted_tokens"]
     assert st["chunk_ssm_tokens"] == st["chunk_tokens"]

@@ -119,7 +119,26 @@ def _latent(H, d_n, d_v, r_kv, K, S, masked):
         + ((((1, 512, S), jnp.bool_),) if masked else ())
 
 
+def _decode_rows(rows, heads, hd, rep, groups, cols, dtype=BF16):
+    """The decode step's per-row read (ops/pallas/span_decode.py) at a
+    served geometry: one query a row over ``[rows, groups, cols, 128]``
+    planes in blocks of 128, each row's window traced."""
+    from paddle_tpu.nn.functional.attention import _decode_rows_fn
+    lanes = max(128, hd)
+    plane = ((rows, groups, cols, lanes), dtype)
+    return (lambda q, k, v, start, end: _decode_rows_fn(
+        q, k, v, start, end, block=128, rep=rep),
+        (((rows, heads, 1, hd), dtype), plane, plane,
+         ((rows,), jnp.int32), ((rows,), jnp.int32)))
+
+
 CASES = {
+    "decode_rows_gpt2_xl": _decode_rows(32, 25, 64, 1, 13, 1024),
+    "decode_rows_lfm2": _decode_rows(128, 32, 64, 4, 4, 8192),
+    "decode_rows_nemotron3": _decode_rows(48, 32, 128, 16, 2, 8192),
+    # a plane the block does not divide (the last block starts early), f32
+    "decode_rows_f32_ragged": _decode_rows(8, 25, 64, 1, 13, 1024 + 64,
+                                           jnp.float32),
     "latent_chunk_dots3_full": _latent(128, 128, 128, 512, 640, 12288, True),
     "latent_chunk_kimi": _latent(64, 128, 128, 512, 640, 16384, False),
     "latent_chunk_glm5": _latent(64, 192, 256, 512, 640, 24576, True),

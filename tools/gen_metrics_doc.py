@@ -139,6 +139,27 @@ persistent executable cache's identity where it is `"in_place"`, and
 or writes back (`row_sized_slices`: 0 in place, 2 a plane sliced).  No
 metric of its own: `chunk_cache_write_ms.*` reads what it costs.
 
+Which form a decode step's one-query read of plain K/V ring planes takes is
+decided when the step program is traced, from what the code can see
+(`nn.functional.attention.decode_read_form`): `"per_row"`, ONE Pallas
+kernel that reads, of each generating row, the column blocks from its own
+`start` to the frontier and nothing of a row that generates nothing
+(`ops/pallas/span_decode.py`; taken on a TPU, without a mesh of several
+devices, for bf16 / f32 planes of whole lane rows whose blocks start on a
+tile's edge, where the layer passes no mask of chosen blocks), or `"span"`,
+two XLA loops in which every row reads the blocks from the oldest generating
+row's `start` to the frontier (the CPU, a mesh, a `keep` mask).  `step_read`
+is a fact of the program (`Generator.step_read`; None for a model whose
+column planes are not all of kind `kv`): in the `extra` of the
+`generate_step` compile event, in `SlotLoop.stats()["step_read"]` beside
+`chunk_row`, in `tools/kv_layout_check.py`'s step line, and in the
+persistent executable cache's identity where it is `"per_row"`.  The loop's
+`attn_blocks_read` / `attn_blocks_total` follow it, by host arithmetic on
+what each step was handed: under `"per_row"` the sum over the generating
+rows of `pos // block + 1 - start // block`, of `slots x blocks`; under
+`"span"` the union span, of the plane's blocks.  Either way
+`attn_span_read_pct.*` is the share of the planes' blocks a step streamed.
+
 How wide the column selector's search runs is decided in the program, from
 what a dispatch can see: it goes over the narrowest of a short list of
 static widths (`nn.functional.attention.search_widths`: `2 k`, `4 k`, ...

@@ -46,9 +46,12 @@ program) and prints, from ``compiled.as_text()``:
     row of a state plane (``state_row_relayout_copies``: what the chunk
     pays to compute on the row it cut out);
   * the ``while`` loops of each program (the decode attention reads the
-    planes in column blocks under one, ``cached_attention``; the step's
-    line says how wide a block is): whether a plane enters one as a
-    copy, or is copied inside its body;
+    planes in column blocks, ``cached_attention``; the step's line says
+    how wide a block is and, ``step_read``, in which form: ``"span"``,
+    every row the union span under two loops, or ``"per_row"``, each
+    generating row its own blocks in one kernel, the planes handed to the
+    custom call as they lie and no loop in the text): whether a plane
+    enters a loop as a copy, or is copied inside its body;
   * for a latent-attention model, ``latent_form`` beside each program: the
     form its cached attention takes at that program's block width
     (``absorbed`` for the step's one query a row; for a wide chunk
@@ -538,7 +541,10 @@ def main(argv):
         facts = inspect(text, plane_shapes, state_shapes)
         if what == "step" and "kv" in gen.plane_kinds():
             # the column blocks of the step's attention (cached_attention)
-            facts = {"attn_block": decode_block(C), **facts}
+            # ... and whether each row reads its own (one kernel, no
+            # while loop) or every row the span (``Generator.step_read``)
+            facts = {"attn_block": decode_block(C),
+                     "step_read": gen.step_read(C), **facts}
         # the form of a latent model's cached attention at this width
         form = gen.latent_form(1 if what == "step" else T)
         if form is not None:

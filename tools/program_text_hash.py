@@ -100,7 +100,8 @@ def _serve(cfg, dev):
         # the facts of the program its ledger event would carry
         _line(cfg["name"], what, text, **{
             k: v for k, v in extra.items()
-            if k in ("chunk_row", "latent_form", "kv_heads_per_lane_row")})
+            if k in ("chunk_row", "step_read", "latent_form",
+                     "kv_heads_per_lane_row")})
 
 
 def main(argv):
