@@ -2,7 +2,8 @@
 """chip_smoke.py — the quickest proof that the system starts on the chip.
 
     python chip_smoke.py              # one chip: train, serve, layouts,
-                                      # hybrid, latent forms, kernels, cache
+                                      # hybrid, latent forms, gated delta,
+                                      # kernels, cache
     python chip_smoke.py --multichip  # four chips: only the sharded paths
 
 One process, the entry points a user calls (``parallel.TrainStep``,
@@ -727,6 +728,153 @@ def phase_latent_forms(size: Size, seed: int = 0) -> dict:
     return _emit("latent_forms", t0, compile_s, checked)
 
 
+# -- the gated delta rule alone --------------------------------------------------
+
+# the chunked scan's outputs against the token-by-token recurrence in
+# float32 at precision "highest", relative to max|out|: the scan's products
+# that do not read the carried state take bfloat16 operands
+DELTA_TOL = 2.0 ** -6
+DELTA_CONFIG = ("gigachat3.5-ep16-serve", "gigachat3_5_tiny")
+
+
+def _product_inverse(a):
+    """``(I + A)^-1`` as ``(I - A)(I + A^2)(I + A^4)...``: exact in exact
+    arithmetic, ``log2 L`` squarings and as many products; its powers of
+    ``A`` grow like binomials where the keys repeat (tests/
+    test_gigachat35_decoder.py), which is why the layer does not use it."""
+    mm = lambda x, y: jnp.einsum("...ab,...bc->...ac", x, y,    # noqa: E731
+                                 precision=jax.lax.Precision.HIGHEST)
+    eye = jnp.eye(a.shape[-1], dtype=a.dtype)
+    t, p, n = eye - a, a, 1
+    while 2 * n < a.shape[-1]:
+        p = mm(p, p)
+        t, n = mm(t, eye + p), 2 * n
+    return t
+
+
+def _substitution_inverse(a):
+    """... and by forward substitution: row ``i`` from the rows above it,
+    ``L - 1`` dependent steps."""
+    eye = jnp.broadcast_to(jnp.eye(a.shape[-1], dtype=a.dtype), a.shape)
+
+    def row(i, t):
+        r = jnp.einsum("...j,...jk->...k",
+                       jax.lax.dynamic_index_in_dim(a, i, a.ndim - 2, False),
+                       t, precision=jax.lax.Precision.HIGHEST)
+        return jax.lax.dynamic_update_index_in_dim(
+            t, jax.lax.dynamic_index_in_dim(t, i, a.ndim - 2, False) - r, i,
+            a.ndim - 2)
+    return jax.lax.fori_loop(1, a.shape[-1], row, eye)
+
+
+def phase_gated_delta(size: Size, seed: int = 0) -> dict:
+    """The gated delta rule ALONE at GigaChat3.5's widths (32 key heads
+    under 64 value heads of 128 x 128), operands in the served dtype, the
+    state float32: one prefill chunk of one row as the chunked scan, at
+    scan chunks of 64 and of 128 tokens and with the triangular system
+    solved by halves (the layer's), by the product of ``log2 L`` factors
+    and by forward substitution, each held to the token-by-token
+    recurrence (benchmark/reference/gigachat3_5.py); and one step of every
+    slot as the one-token update.  Each form is timed with its state
+    chained through a loop (:func:`_chained_s`), and the layer's own two against the least time
+    ``benchmark/counts/gigachat3_5.py`` gives them (``roofline_pct``, from
+    the chip's published peaks; none where the device has no entry)."""
+    import os
+    from benchmark.counts import gigachat3_5 as counts
+    from benchmark.reference import gigachat3_5 as reference
+    from paddle_tpu.nn.layer.gated_delta import (delta_scan, delta_update,
+                                                 unit_lower_inverse)
+    t0 = time.perf_counter()
+    root = os.path.dirname(os.path.abspath(__file__))
+    name, tiny = DELTA_CONFIG
+    with open(os.path.join(root, "benchmark", "configs", name + ".json")) as f:
+        cfg = json.load(f)
+    full = size.name == "full"
+    if not full:
+        with open(os.path.join(root, "benchmark", "tests", "data",
+                               tiny + ".json")) as f:
+            over = json.load(f)["over"]
+        cfg["serve"].update(over.pop("serve"))
+        cfg.update(over)
+    with open(os.path.join(root, "benchmark", "peaks.json")) as f:
+        peaks = json.load(f).get(jax.devices()[0].device_kind)
+    G, H = cfg["linear_num_key_heads"], cfg["linear_num_value_heads"]
+    N, P = cfg["linear_key_head_dim"], cfg["linear_value_head_dim"]
+    S, T = int(cfg["serve"]["slots"]), int(cfg["serve"]["prefill_chunk"])
+    dt = jnp.dtype(cfg["dtype"])
+    one = dict(cfg, num_hidden_layers=1, full_attention_layers=[],
+               first_k_dense_replace=1)
+
+    def least_ms(count):
+        return None if not peaks else 1e3 * max(
+            count["bytes"] / peaks["hbm_bytes_per_s"],
+            count["flops"] / peaks["bf16_flops_per_s"])
+
+    def operands(B, n, key):
+        ks = jax.random.split(key, 5)
+        unit = lambda k, scale: (                               # noqa: E731
+            lambda x: (x * jax.lax.rsqrt(jnp.sum(x * x, -1, keepdims=True))
+                       * scale).astype(dt))(
+            jax.random.normal(k, (B, n, G, N), jnp.float32))
+        return (unit(ks[0], N ** -0.5), unit(ks[1], 1.0),
+                jax.random.normal(ks[2], (B, n, H, P), jnp.float32).astype(dt),
+                -0.2 * jax.random.uniform(ks[3], (B, n, H), jnp.float32),
+                jax.random.uniform(ks[4], (B, n, H), jnp.float32))
+    key = jax.random.key(seed)
+    q, k, v, a, beta = operands(1, T, key)
+    h0 = jax.random.normal(jax.random.fold_in(key, 1), (1, H, P, N),
+                           jnp.float32)
+    # the recurrence from a zero state, then the scans from the same
+    with jax.default_matmul_precision("highest"):
+        want, want_h = jax.jit(lambda q, k, v, a, b: reference.recurrence(
+            jnp.repeat(q[0], H // G, 1).astype(jnp.float32),
+            jnp.repeat(k[0], H // G, 1).astype(jnp.float32),
+            v[0].astype(jnp.float32), jnp.exp(a[0]), b[0],
+            final_state=True))(q, k, v, a, beta)
+    want, want_h = np.asarray(want), np.asarray(want_h)
+    widths = (64, 128) if full else (4, 8)
+    forms = {"halves": unit_lower_inverse, "product": _product_inverse,
+             "substitution": _substitution_inverse}
+    scan_ms, worst, compile_s = {}, {}, 0.0
+    for L in widths:
+        for form, inverse in forms.items():
+            scan = lambda h, L=L, inverse=inverse: delta_scan(   # noqa: E731
+                q, k, v, a, beta, h, L, inverse)
+            t1 = time.perf_counter()
+            o, h = jax.jit(scan)(jnp.zeros_like(h0))
+            compile_s += time.perf_counter() - t1
+            tag = f"{form}/{L}"
+            worst[tag] = max(
+                float(np.abs(np.asarray(o)[0] - want).max()
+                      / np.abs(want).max()),
+                float(np.abs(np.asarray(h)[0] - want_h).max()
+                      / np.abs(want_h).max()))
+            _check(worst[tag] < DELTA_TOL,
+                   f"gated_delta: the scan ({tag}) lies {worst[tag]:.2e} of "
+                   "max|out| from the recurrence")
+            scan_ms[tag] = round(1e3 * _chained_s(
+                lambda h: scan(h)[1], h0, (), (3, 9)), 4)
+    q1, k1, v1, a1, b1 = (t[:, 0] for t in operands(
+        S, 1, jax.random.fold_in(key, 2)))
+    hs = jax.random.normal(jax.random.fold_in(key, 3), (S, H, P, N),
+                           jnp.float32)
+    o, h = jax.jit(delta_update)(q1, k1, v1, a1, b1, hs)
+    _check(np.isfinite(np.asarray(o)).all(), "gated_delta: update not finite")
+    update_ms = round(1e3 * _chained_s(
+        lambda h: delta_update(q1, k1, v1, a1, b1, h)[1], hs, (), (3, 9)), 4)
+    kept = f"halves/{int(cfg.get('linear_scan_chunk', widths[0]))}"
+    least = {"update": least_ms(counts.linear_update(one, S)),
+             "scan": least_ms(counts.linear_scan(one, T))}
+    share = lambda least, ms: None if least is None or not ms \
+        else round(100.0 * least / ms, 2)                       # noqa: E731
+    return _emit("gated_delta", t0, compile_s, {
+        "heads": [G, H], "dims": [N, P], "rows": S, "chunk": T,
+        "scan_ms": scan_ms, "scan_rel_worst": worst, "update_ms": update_ms,
+        "kept": kept,
+        "roofline_pct": {"update": share(least["update"], update_ms),
+                         "scan": share(least["scan"], scan_ms.get(kept))}})
+
+
 # -- kernels ------------------------------------------------------------------
 
 def _close(name: str, got, ref) -> float:
@@ -807,26 +955,31 @@ def _bn_relu_ref(x, gamma, beta):
     return jnp.maximum(y, 0.0), mean, var
 
 
-def _chained_us(fn, q, rest, trips):
-    """Microseconds a call of ``fn(q, *rest)``: the output is fed back into
-    the queries under ``lax.fori_loop`` (nothing is hoisted or dropped) and
-    two trip counts are timed, the best of three each: their difference
-    leaves the dispatch out."""
+def _chained_s(body, x, rest, trips):
+    """Seconds a trip of ``x = body(x, *rest)`` under ``lax.fori_loop``
+    (nothing is hoisted or dropped): two trip counts are timed, the best of
+    three each, and their difference leaves the dispatch out."""
     def best(n):
         @jax.jit
-        def run(q, *rest):
-            return jax.lax.fori_loop(
-                0, n, lambda _, q: (q + fn(q, *rest) * 1e-3).astype(q.dtype),
-                q)
-        jax.block_until_ready(run(q, *rest))
+        def run(x, *rest):
+            return jax.lax.fori_loop(0, n, lambda _, x: body(x, *rest), x)
+        jax.block_until_ready(run(x, *rest))
         took = []
         for _ in range(3):
             t0 = time.perf_counter()
-            jax.block_until_ready(run(q, *rest))
+            jax.block_until_ready(run(x, *rest))
             took.append(time.perf_counter() - t0)
         return min(took)
     few, many = trips
-    return (best(many) - best(few)) / (many - few) * 1e6
+    return (best(many) - best(few)) / (many - few)
+
+
+def _chained_us(fn, q, rest, trips):
+    """Microseconds a call of ``fn(q, *rest)``: the output is fed back into
+    the queries (:func:`_chained_s`)."""
+    return 1e6 * _chained_s(
+        lambda q, *rest: (q + fn(q, *rest) * 1e-3).astype(q.dtype), q, rest,
+        trips)
 
 
 def _decode_rows_case(case, rng) -> dict:
@@ -1130,6 +1283,7 @@ def main(argv=None) -> int:
         phase_layouts(FULL, args.seed)
         phase_hybrid(FULL, args.seed)
         phase_latent_forms(FULL, args.seed)
+        phase_gated_delta(FULL, args.seed)
         phase_kernels(FULL, args.seed)
         phase_cache(FULL, served)
     print(json.dumps({"ok": True, "device": device}), flush=True)

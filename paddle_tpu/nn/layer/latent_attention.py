@@ -40,9 +40,13 @@ both.  Two kinds of layer share the code:
 
 The planes are what :meth:`LatentAttention.gen_ring_cache` builds; the
 namedtuple classes carry what the Generator and the slot loop need to know
-about them (``kind``, whether a plane wraps inside a session).  A headwise
-sigmoid gate, from the layer's normed input, multiplies each head's output
-before the output projection (``gate=False``: no gate is built).  The
+about them (``kind``, whether a plane wraps inside a session).  A sigmoid
+gate, from the layer's normed input, multiplies the heads' outputs before
+the output projection: one number a HEAD (``gate=True``, ``W_g`` ``hidden
+-> H``) or one a FEATURE (``gate="feature"``, ``W_g`` ``hidden -> H x
+d_v``: each of a head's ``d_v`` outputs has a gate of its own);
+``gate=False`` builds none.  The two latents' norms are ``norm_layer``'s
+(RMSNorm with a learned gain unless the model's norm has another form).  The
 rotary positions take a config's ``rope_scaling`` (YaRN: blended
 frequencies, and ``m^2`` on the softmax scale), in all three forms alike.
 
@@ -72,8 +76,8 @@ from ..functional.attention import (PerHeadOperands, absorbed_products,
 from .layers import Layer
 from .transformer import ring_block_write
 
-__all__ = ["RMSNorm", "LatentAttention", "LatentCache", "LatentPlane",
-           "LatentWindowCache"]
+__all__ = ["RMSNorm", "SigmoidGainRMSNorm", "LatentAttention",
+           "LatentCache", "LatentPlane", "LatentWindowCache"]
 
 # full layers: ``latent [B, 1, C, r_kv + d_r]`` and the selector's keys
 # ``index_key [B, 1, C, d_i]``; columns at axis 2 like every ring plane
@@ -114,6 +118,27 @@ class RMSNorm(Layer):
         return Tensor(out) if isinstance(x, Tensor) else out
 
 
+class SigmoidGainRMSNorm(Layer):
+    """``x / rms(x) * (scale * sigmoid(weight))``, statistics in float32:
+    a gain held inside ``(0, scale)``, and ``weight = 0`` gives ``scale /
+    2`` (1 at ``scale`` 2: zero-centred)."""
+
+    def __init__(self, size, epsilon=1e-5, scale=2.0, weight_attr=None,
+                 dtype=None):
+        super().__init__()
+        self._epsilon, self._scale = float(epsilon), float(scale)
+        self.weight = self.create_parameter(
+            [size], attr=weight_attr, dtype=dtype,
+            default_initializer=I.Constant(0.0))
+
+    def forward(self, x):
+        raw = unwrap(x)
+        gain = self._scale * jax.nn.sigmoid(
+            unwrap(self.weight).astype(jnp.float32))
+        out = (_rms(raw, self._epsilon) * gain).astype(raw.dtype)
+        return Tensor(out) if isinstance(x, Tensor) else out
+
+
 def _layer_norm(x, g, b, eps):
     x = x.astype(jnp.float32)
     mu = jnp.mean(x, -1, keepdims=True)
@@ -128,14 +153,23 @@ class LatentAttention(Layer):
     layer that reads every valid column; ``window=w`` is a window layer
     (no selector).  ``cache_block`` is the widest token block one cached
     call may append; it sizes the window plane.  ``gate`` builds the
-    headwise output gate; ``rope_scaling`` is a config's (``yarn``)."""
+    output gate, a number a head (True) or a feature (``"feature"``);
+    ``norm_layer(size)`` builds the latents' norms; ``rope_scaling`` is a
+    config's (``yarn``)."""
 
     def __init__(self, hidden, num_heads, nope_dim, rope_dim, v_dim,
                  q_rank, kv_rank, rope_base, *, window=None,
                  index_heads=0, index_dim=0, index_topk=0, cache_block=512,
                  attn_block=512, epsilon=1e-5, rescale=True, gate=True,
-                 rope_scaling=None, weight_attr=None, dtype=None):
+                 rope_scaling=None, norm_layer=None, weight_attr=None,
+                 dtype=None):
         super().__init__()
+        if gate not in (False, True, "feature"):
+            raise ValueError(f"gate {gate!r}: False, True (a head) or "
+                             f"'feature'")
+        if norm_layer is None:
+            norm_layer = lambda size: RMSNorm(                  # noqa: E731
+                size, epsilon, dtype=dtype)
         self.hidden, self.H = int(hidden), int(num_heads)
         self.dn, self.dr, self.dv = int(nope_dim), int(rope_dim), int(v_dim)
         self.rq, self.rkv = int(q_rank), int(kv_rank)
@@ -164,13 +198,15 @@ class LatentAttention(Layer):
 
         H = self.H
         self.q_a = mat(hidden, self.rq)
-        self.q_a_norm = RMSNorm(self.rq, epsilon, dtype=dtype)
+        self.q_a_norm = norm_layer(self.rq)
         self.q_b = mat(self.rq, H * (self.dn + self.dr))
         self.kv_a = mat(hidden, self.rkv + self.dr)
-        self.kv_a_norm = RMSNorm(self.rkv, epsilon, dtype=dtype)
+        self.kv_a_norm = norm_layer(self.rkv)
         self.w_uk = mat(H, self.rkv, self.dn)
         self.w_uv = mat(H, self.rkv, self.dv)
-        self.gate = mat(hidden, H) if gate else None
+        self.gate_features = gate == "feature"
+        self.gate = mat(hidden, H * self.dv if self.gate_features else H) \
+            if gate else None
         self.o_proj = mat(H * self.dv, hidden)
         if self.selects:
             self.idx_q = mat(self.rq, self.J * self.D)
@@ -192,7 +228,9 @@ class LatentAttention(Layer):
 
     def ring_cache_spec(self, max_len):
         """What the Generator and the slot loop may know of this layer's
-        planes (text/generation.py ``cache_spec``)."""
+        planes (text/generation.py ``cache_spec``; ``cache_spec`` /
+        ``gen_cache`` are the names a decoder of mixed layers asks its
+        mixers by)."""
         cls = self._cache_class()
         return {"kind": cls.kind, "heads_per_lane_row": 1,
                 "columns": self.ring_len(max_len), "wraps": cls.wraps,
@@ -230,12 +268,15 @@ class LatentAttention(Layer):
             return self._cache_class()(lat)
         return LatentCache(lat, zeros([batch, 1, n, self.D], dtype=dtype))
 
+    cache_spec, gen_cache = ring_cache_spec, gen_ring_cache
+
     # -- projections shared by both forms --------------------------------------
     def _project(self, x, pos_ids):
         """From the normed input ``x [B, T, hidden]``: per-head queries
         ``q_n [B, T, H, dn]``, ``q_r [B, T, H, dr]`` (rotated), the cache
         row ``latent ‖ rotary key [B, T, rkv + dr]``, the gate ``[B, T,
-        H]`` (None without one) and the selector's query latent ``c_q``."""
+        H]`` or ``[B, T, H x dv]`` (None without one) and the selector's
+        query latent ``c_q``."""
         B, T, _ = x.shape
         dt = x.dtype
         w = lambda p: unwrap(p)                                # noqa: E731
@@ -285,11 +326,12 @@ class LatentAttention(Layer):
                           preferred_element_type=jnp.float32)
 
     def _project_out(self, o, gate, dt):
-        """The headwise gate on the heads' outputs ``o [B, T, H, d_v]``
-        (float32), then the output projection."""
+        """The gate (a head or a feature) on the heads' outputs ``o [B,
+        T, H, d_v]`` (float32), then the output projection."""
         B, T = o.shape[:2]
         if gate is not None:
-            o = o * gate[..., None]
+            o = o * (gate.reshape(o.shape) if self.gate_features
+                     else gate[..., None])
         o = o.astype(dt).reshape(B, T, self.H * self.dv)
         return jnp.einsum("btk,kh->bth", o, unwrap(self.o_proj),
                           preferred_element_type=jnp.float32).astype(dt)

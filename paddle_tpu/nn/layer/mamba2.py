@@ -55,7 +55,7 @@ from ...framework.tensor import Tensor, unwrap
 from .. import initializer as I
 from .layers import Layer
 
-__all__ = ["Mamba2Mixer", "SsmStateCache"]
+__all__ = ["Mamba2Mixer", "SsmStateCache", "decay_between", "conv_silu"]
 
 SsmStateCache = collections.namedtuple("SsmStateCache", ["conv", "state"])
 SsmStateCache.kind = "ssm_state"
@@ -70,6 +70,38 @@ def _product(x, w):
     dtype."""
     return jnp.einsum("...a,ab->...b", x, unwrap(w),
                       preferred_element_type=_F32).astype(x.dtype)
+
+
+def decay_between(cs):
+    """``cs [..., L]``, the log decay summed up to each token (its own
+    included) -> ``[..., t, s]``: the decay from token ``s`` to token
+    ``t``, ``exp(cs_t - cs_s)``, for ``s <= t`` and 0 above the diagonal.
+    (The mask goes inside the exp: above the diagonal the gap is positive
+    and may overflow.)"""
+    L = cs.shape[-1]
+    gap = cs[..., :, None] - cs[..., None, :]
+    causal = jnp.tril(jnp.ones((L, L), bool))
+    return jnp.exp(jnp.where(causal, gap, -jnp.inf))
+
+
+def conv_silu(x, before, live, w, bias=None):
+    """``silu(conv(x) + bias)`` over the block ``x [B, T, C]``: causal,
+    depthwise, ``w [C, taps]`` (tap j weighs the input ``taps - 1 - j``
+    tokens back: the last tap is the token's own), ``before [B, taps-1,
+    C]`` the inputs at the columns before the block, ``live [B, T]`` the
+    block's columns that belong to the request (any other counts as
+    zeros).  Returns (that, the inputs over ``before`` and the block).
+    Inputs are kept as rounded to the dtype the cache keeps them in, so
+    that a token fed in a chunk and one fed by a step see the same
+    past."""
+    T, taps = x.shape[1], w.shape[1]
+    u = jnp.where(live[..., None], x, jnp.zeros((), x.dtype))
+    full = jnp.concatenate([before.astype(x.dtype), u], axis=1)
+    w = w.astype(_F32)
+    acc = sum(w[:, j] * full[:, j:j + T].astype(_F32) for j in range(taps))
+    if bias is not None:
+        acc = acc + bias.astype(_F32)
+    return jax.nn.silu(acc).astype(x.dtype), full
 
 
 def state_update(x, dt, b, c, a_log, h0):
@@ -104,13 +136,8 @@ def state_scan(x, dt, b, c, a_log, h0, chunk):
     # inside a chunk: y_t += sum_{s <= t} e^{cs_t - cs_s} D_s (C_t.B_s) x_s
     cb = jnp.einsum("bnlgk,bnsgk->bngls", c, b,
                     preferred_element_type=_F32)
-    cst = jnp.moveaxis(cs, 3, 2)                            # [B, n, H, L]
-    gap = cst[..., :, None] - cst[..., None, :]            # [.., t, s]
-    causal = jnp.tril(jnp.ones((L, L), bool))
-    # (the mask goes inside the exp: above the diagonal the gap is
-    # positive and may overflow)
-    w = jnp.exp(jnp.where(causal, gap, -jnp.inf)) \
-        * jnp.moveaxis(dt, 3, 2)[..., None, :]
+    w = decay_between(jnp.moveaxis(cs, 3, 2)) \
+        * jnp.moveaxis(dt, 3, 2)[..., None, :]              # [B,n,H,t,s]
     m = (jnp.repeat(cb, r, axis=2) * w).astype(dt_op)      # [B,n,H,t,s]
     y = jnp.einsum("bnhts,bnshp->bnthp", m, x,
                    preferred_element_type=_F32)
@@ -209,19 +236,9 @@ class Mamba2Mixer(Layer):
     # -- the pieces ------------------------------------------------------------
     def _conv(self, xbc, before, live):
         """``silu(conv(xBC) + b)`` over the block ``xbc [B, T, conv_dim]``
-        with ``before [B, taps-1, conv_dim]`` the inputs at the columns
-        before it; returns (that, the inputs over ``before`` and the
-        block).  Inputs are kept as rounded to the dtype the cache keeps
-        them in, so that a token fed in a chunk and one fed by a step see
-        the same past."""
-        T = xbc.shape[1]
-        u = jnp.where(live[..., None], xbc, jnp.zeros((), xbc.dtype))
-        full = jnp.concatenate([before.astype(xbc.dtype), u], axis=1)
-        w = unwrap(self.conv).astype(_F32)
-        acc = sum(w[:, j] * full[:, j:j + T].astype(_F32)
-                  for j in range(self.taps))
-        acc = acc + unwrap(self.conv_bias).astype(_F32)
-        return jax.nn.silu(acc).astype(xbc.dtype), full
+        (:func:`conv_silu`)."""
+        return conv_silu(xbc, before, live, unwrap(self.conv),
+                         unwrap(self.conv_bias))
 
     def _split(self, xbc):
         """``x [B, T, H, P]``, ``B`` and ``C [B, T, G, N]``."""

@@ -633,12 +633,24 @@ def publish_moe_metrics(layer, model: str = "moe"):
 # dropless, sigmoid-routed experts of which this chip holds a share
 # ---------------------------------------------------------------------------
 
-class SwiGLU(Layer):
-    """``W_down(silu(W_gate u) * W_up u)``, no biases."""
+def clamped(g, v, limit):
+    """A SwiGLU's two halves held to ``limit``: the gate's input from above
+    (``min(g, limit)``: below, the SiLU flattens by itself), the linear
+    half from both sides; ``limit`` None leaves them as they are."""
+    if limit is None:
+        return g, v
+    return jnp.minimum(g, limit), jnp.clip(v, -limit, limit)
 
-    def __init__(self, hidden, width, weight_attr=None, dtype=None):
+
+class SwiGLU(Layer):
+    """``W_down(silu(W_gate u) * W_up u)``, no biases; with ``limit``,
+    ``W_down(silu(min(g, limit)) * clip(v, -limit, limit))``."""
+
+    def __init__(self, hidden, width, weight_attr=None, dtype=None,
+                 limit=None):
         super().__init__()
         from .. import initializer as I
+        self.limit = None if limit is None else float(limit)
 
         def mat(*shape):
             return self.create_parameter(
@@ -656,6 +668,7 @@ class SwiGLU(Layer):
                            preferred_element_type=f32)
             v = jnp.einsum("...h,hf->...f", raw, unwrap(self.w_up),
                            preferred_element_type=f32)
+            g, v = clamped(g, v, self.limit)
             y = jnp.einsum("...f,fh->...h",
                            (jax.nn.silu(g) * v).astype(raw.dtype),
                            unwrap(self.w_down), preferred_element_type=f32)
@@ -708,7 +721,9 @@ class DroplessMoE(Layer):
     held expert, however many rows that is.  ``activation`` is the
     experts' form: ``"swiglu"`` (three matrices, ``W_down(silu(W_gate u) *
     W_up u)``) or ``"relu2"`` (two, ``W_down relu(W_up u)^2``: such a layer
-    has no ``w_gate``).  An assignment to an expert
+    has no ``w_gate``); ``limit`` clamps a SwiGLU's two halves
+    (:func:`clamped`), in the routed experts and the shared one alike.  An
+    assignment to an expert
     held elsewhere adds nothing here: on a mesh the other shares' parts
     arrive by the exchange that this layer does not do (the one-chip
     share of an expert-parallel deployment); ``held=None`` holds every
@@ -756,14 +771,19 @@ class DroplessMoE(Layer):
 
     def __init__(self, hidden, width, num_experts, top_k, *, held=None,
                  shared=0, scaling=1.0, norm_topk=True, norm_eps=None,
-                 activation="swiglu", weight_attr=None, dtype=None):
+                 activation="swiglu", limit=None, weight_attr=None,
+                 dtype=None):
         super().__init__()
         from .. import initializer as I
         if activation not in EXPERT_FORMS:
             raise InvalidArgumentError(
                 f"experts of form {activation!r}; have "
                 f"{sorted(EXPERT_FORMS)}")
+        if limit is not None and activation != "swiglu":
+            raise InvalidArgumentError(
+                f"a clamp ({limit}) on experts of form {activation!r}")
         self.activation = activation
+        self.limit = None if limit is None else float(limit)
         lo, hi = (0, num_experts) if held is None else map(int, held)
         if not 0 <= lo < hi <= num_experts:
             raise InvalidArgumentError(
@@ -789,8 +809,11 @@ class DroplessMoE(Layer):
             self.w_gate = mat(n, hidden, width)
         self.w_up = mat(n, hidden, width)
         self.w_down = mat(n, width, hidden)
+        # (only a SwiGLU takes a clamp, and only it is ever given one)
+        extra = {"limit": limit} if activation == "swiglu" else {}
         self.shared = EXPERT_FORMS[activation](
-            hidden, shared * width, weight_attr, dtype) if shared else None
+            hidden, shared * width, weight_attr, dtype, **extra) \
+            if shared else None
         self.last_counts = None
 
     def route(self, u2d):
@@ -838,7 +861,8 @@ class DroplessMoE(Layer):
                 if self.activation == "relu2":
                     return jnp.square(
                         jax.nn.relu(into(unwrap(self.w_up)))).astype(dt)
-                g, v = into(unwrap(self.w_gate)), into(unwrap(self.w_up))
+                g, v = clamped(into(unwrap(self.w_gate)),
+                               into(unwrap(self.w_up)), self.limit)
                 return (jax.nn.silu(g) * v).astype(dt)
 
             def ragged():

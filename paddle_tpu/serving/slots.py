@@ -403,10 +403,15 @@ class SlotLoop:
         # the kinds of the planes that DO have columns
         column_kinds = sorted({str(s["kind"]) for s in spec
                                if int(s["columns"])})
-        # ... all of them K/V planes a column a token, read whole or in
-        # chosen blocks (beside whatever has no columns)
+        # ... all of them a column a token, read whole or in chosen
+        # blocks (beside whatever has no columns): K/V planes, and a
+        # latent plane without selector or window that lies beside layers
+        # of another kind (a model of latent planes alone counts its
+        # columns a layer, ``attn_columns_*``, and nothing here)
+        mixed = self._plane_kinds != ["latent"]
         self._kv_columns = not self._spec and bool(column_kinds) and all(
-            s["kind"] == "kv" or s.get("select_blocks") for s in spec
+            s["kind"] == "kv" or s.get("select_blocks")
+            or (mixed and s["kind"] == "latent") for s in spec
             if int(s["columns"]))
         names = getattr(gen, "decode_count_names", None)
         self._count_names = tuple(names()) if names is not None else ()

@@ -1,14 +1,23 @@
 """Decoder-only LM whose layers differ in MIXER and in FFN independently:
 gated short convolutions, grouped-query attention (dense, or block-sparse
-over a pooled-key plane), Mamba-2 state-space or lightning linear-attention
-mixers; dense SwiGLU or a sigmoid-routed MoE that holds its experts or a
-share of them (the ``lfm2_moe``, ``nemotron_h`` and ``minicpm_sala``
+over a pooled-key plane), latent attention, Mamba-2 state-space, lightning
+linear-attention or gated delta-rule mixers; dense SwiGLU or a
+sigmoid-routed MoE that holds its experts or a share of them (the
+``lfm2_moe``, ``nemotron_h``, ``minicpm_sala`` and ``gigachat3_5``
 lineages).
 
-Block: ``h = x + r Mix(RMSNorm(x))``, ``y = h + r FFN(RMSNorm(h))``, where a
-layer may have a mixer alone or an FFN alone (``layer_types[i]`` /
-``ffn_types[i]`` ``"none"``: the other half is the whole layer); final
-RMSNorm; the output head is the embedding (tied) or a matrix of its own.
+Block (``block_norms`` ``"pre"``): ``h = x + r Mix(N(x))``, ``y = h + r
+FFN(N(h))``, where a layer may have a mixer alone or an FFN alone
+(``layer_types[i]`` / ``ffn_types[i]`` ``"none"``: the other half is the
+whole layer); with ``block_norms`` ``"pre_post"`` each half is normed
+after as well as before, inside its residual branch: ``h = x + r N(Mix(N(x)))``,
+``y = h + r N(FFN(N(h)))``, four norm vectors a layer.  ``N`` is RMSNorm
+with a learned gain (``norm_gain`` ``"plain"``) or with the gain
+``norm_gain_scale x sigmoid(w)`` (``"sigmoid"``:
+:class:`~paddle_tpu.nn.layer.latent_attention.SigmoidGainRMSNorm`), every
+norm of the model alike; final ``N``; the output head is the embedding
+(tied) or a matrix of its own.  ``ffn_limit`` clamps every SwiGLU's two
+halves (``nn.layer.moe.clamped``).
 Three scalings, each 1 unless the configuration says otherwise (muP): the
 embedding times ``embed_scale``, each residual branch times ``r =
 residual_scale``, the final normed state divided by ``logit_divisor``
@@ -39,7 +48,18 @@ before the head.  ``layer_types[i]`` is
     attention with a sigmoid output gate that, past ``dense_len`` tokens
     of context, reads ``top`` blocks of ``block`` columns chosen from
     scores over mean-pooled keys, which it keeps in a third plane beside
-    K and V (kind ``kv+pooled_key``: an entry every ``stride`` columns).
+    K and V (kind ``kv+pooled_key``: an entry every ``stride`` columns);
+  * ``"gated_delta"``: :class:`~paddle_tpu.nn.layer.gated_delta.
+    GatedDeltaNet`, a float32 matrix state a value head that each token
+    decays by a rate of its own and corrects by what the state already
+    gives for its key (the delta rule), behind a short convolution over
+    ``[q | k | v]``: the state and the convolution's inputs, kind
+    ``ssm_state``, WITHOUT columns;
+  * ``"latent_attention"``: :class:`~paddle_tpu.nn.layer.latent_attention.
+    LatentAttention` without selector or window: ONE latent plane as long
+    as the session (kind ``latent``: ``kv_rank + rope_dim`` numbers a
+    token for all heads), YaRN positions (``rope_scaling``), a sigmoid
+    output gate a head or a feature (``latent_gate``).
 
 ``ffn_types[i]`` is ``"dense"``, ``"moe"``
 (:class:`~paddle_tpu.nn.layer.moe.DroplessMoE`) or ``"none"``; left out,
@@ -49,14 +69,20 @@ the first ``dense_layers`` FFNs are dense and the rest MoE.
 
   * *A short convolution's inputs are positional*, like everything else
     in a slot loop (``conv`` layers, and the convolution inside an ``ssm``
-    layer): the entries ARE the inputs at the columns ``pos - (L-1) ..
-    pos - 1``, and an entry counts iff its column is at or after the row's
-    ``start``.
-  * *A summed state cannot be*: it stands for every column before the
-    block.  The state handed to a block whose first column is ``pos``
-    counts iff ``pos > start`` (else the request begins inside the block:
-    zeros), and a token before ``start`` inside the block passes it
-    through unchanged.
+    or a ``gated_delta`` layer): the entries ARE the inputs at the columns
+    ``pos - (L-1) .. pos - 1``, and an entry counts iff its column is at
+    or after the row's ``start``.
+  * *A summed state cannot be* (an ``ssm`` layer's, a ``linear_attention``
+    layer's, a ``gated_delta`` layer's matrix state): it stands for every
+    column before the block.  The state handed to a block whose first
+    column is ``pos`` counts iff ``pos > start`` (else the request begins
+    inside the block: zeros), and a token before ``start`` inside the
+    block passes it through unchanged (a delta-rule token with decay 1 and
+    ``beta`` 0 neither reads nor writes).
+
+A ``latent_attention`` layer's plane follows neither: it HAS columns, a
+row a token, masked by ``start`` like K/V (a dead row's write lands in a
+dead column).
 
 Either covers a slot's previous occupant (its leftovers lie before the new
 ``start``), left padding inside a chunk and a ring restart, with no reset
@@ -71,7 +97,7 @@ from __future__ import annotations
 
 import collections
 import dataclasses
-from typing import Optional, Sequence
+from typing import Optional, Sequence, Union
 
 import jax
 import jax.numpy as jnp
@@ -83,7 +109,9 @@ from ...nn.functional.attention import (BlockSparse, block_keep,
                                         choose_blocks, pool_keys_write,
                                         pooled_entries, rotary,
                                         span_attention)
-from ...nn.layer.latent_attention import RMSNorm
+from ...nn.layer.gated_delta import GatedDeltaNet
+from ...nn.layer.latent_attention import (LatentAttention, RMSNorm,
+                                          SigmoidGainRMSNorm)
 from ...nn.layer.linear_attention import LightningAttention, decay_slopes
 from ...nn.layer.mamba2 import Mamba2Mixer, _product
 from ...nn.layer.moe import DroplessMoE, SwiGLU
@@ -97,6 +125,7 @@ __all__ = ["HybridConvConfig", "HybridConvDecoder", "ConvStateCache",
 
 CONV, ATTN, SSM, NONE = "conv", "full_attention", "ssm", "none"
 LINEAR, SPARSE = "linear_attention", "sparse_attention"
+DELTA, LATENT = "gated_delta", "latent_attention"
 DENSE, MOE = "dense", "moe"
 
 # a conv layer's cache: ``state [B, 1, L-1, hidden]``, row first like every
@@ -136,7 +165,8 @@ class HybridConvConfig:
     experts_per_token: int = 2
     routed_scaling: float = 1.0
     norm_topk: bool = True
-    routing_norm_eps: float = 1e-6      # w_i = s_i / (sum_chosen s + eps)
+    # w_i = s_i / (sum_chosen s + eps); None: / max(sum_chosen s, 1e-20)
+    routing_norm_eps: Optional[float] = 1e-6
     num_heads: int = 4
     num_kv_heads: int = 2
     head_dim: int = 64
@@ -160,6 +190,29 @@ class HybridConvConfig:
     linear_decay_depth: Optional[Sequence[float]] = None
     sparse: Optional[BlockSparse] = None    # the sparse layers' rule
     sparse_gate: bool = False       # the sparse layers' sigmoid output gate
+    # the gated delta-rule layers
+    delta_key_heads: int = 2
+    delta_value_heads: int = 4
+    delta_key_dim: int = 16
+    delta_value_dim: int = 16
+    delta_taps: int = 4
+    delta_chunk: int = 8
+    delta_gate_scale: float = 2.0
+    # the latent layers (``num_heads`` heads)
+    nope_dim: int = 16
+    latent_rope_dim: int = 8
+    v_dim: int = 16
+    q_rank: int = 32
+    kv_rank: int = 24
+    latent_rope_base: float = 1e4
+    rope_scaling: Optional[dict] = None     # a config's, type "yarn"
+    latent_gate: Union[bool, str] = False   # True: a head; "feature"
+    latent_block: int = 512     # widest block fed at once (the chunk)
+    attn_block: int = 512       # column block of the latent read
+    block_norms: str = "pre"            # or "pre_post"
+    norm_gain: str = "plain"            # or "sigmoid"
+    norm_gain_scale: float = 2.0
+    ffn_limit: Optional[float] = None   # the SwiGLUs' clamp
     embed_scale: float = 1.0
     residual_scale: float = 1.0
     logit_divisor: float = 1.0
@@ -171,6 +224,15 @@ class HybridConvConfig:
         if self.ffn_types is not None:
             return self.ffn_types[index]
         return DENSE if index < self.dense_layers else MOE
+
+    def norm(self, size: int):
+        """The model's norm over ``size`` features (``norm_gain``)."""
+        if self.norm_gain == "sigmoid":
+            return SigmoidGainRMSNorm(size, self.rms_eps,
+                                      self.norm_gain_scale, dtype=self.dtype)
+        if self.norm_gain != "plain":
+            raise ValueError(f"norm_gain {self.norm_gain!r}")
+        return RMSNorm(size, self.rms_eps, dtype=self.dtype)
 
     @classmethod
     def tiny(cls, **over):
@@ -488,6 +550,21 @@ class HybridDecoderLayer(nn.Layer):
                 cfg.hidden_size, cfg.linear_heads, cfg.linear_head_dim,
                 decay_slopes(cfg.linear_heads, depth), cfg.linear_rope_base,
                 cfg.linear_chunk, cfg.rms_eps, weight_attr, cfg.dtype)
+        elif kind == DELTA:
+            self.mixer = GatedDeltaNet(
+                cfg.hidden_size, cfg.delta_key_heads, cfg.delta_value_heads,
+                cfg.delta_key_dim, cfg.delta_value_dim, cfg.delta_taps,
+                cfg.delta_chunk, cfg.rms_eps, cfg.delta_gate_scale,
+                weight_attr, cfg.dtype)
+        elif kind == LATENT:
+            self.mixer = LatentAttention(
+                cfg.hidden_size, cfg.num_heads, cfg.nope_dim,
+                cfg.latent_rope_dim, cfg.v_dim, cfg.q_rank, cfg.kv_rank,
+                cfg.latent_rope_base, cache_block=cfg.latent_block,
+                attn_block=cfg.attn_block, epsilon=cfg.rms_eps,
+                rescale=False, gate=cfg.latent_gate,
+                rope_scaling=cfg.rope_scaling, norm_layer=cfg.norm,
+                weight_attr=weight_attr, dtype=cfg.dtype)
         elif kind == SSM:
             self.mixer = Mamba2Mixer(
                 cfg.hidden_size, cfg.ssm_heads, cfg.ssm_head_dim,
@@ -498,12 +575,16 @@ class HybridDecoderLayer(nn.Layer):
         else:
             raise ValueError(f"layer_types[{index}] = {kind!r} with the "
                              f"FFN {ffn!r}")
+        if cfg.block_norms not in ("pre", "pre_post"):
+            raise ValueError(f"block_norms {cfg.block_norms!r}")
+        post = cfg.block_norms == "pre_post"
         if self.mixer is not None:
-            self.operator_norm = RMSNorm(cfg.hidden_size, cfg.rms_eps,
-                                         dtype=cfg.dtype)
+            self.operator_norm = cfg.norm(cfg.hidden_size)
+            self.operator_post_norm = cfg.norm(cfg.hidden_size) \
+                if post else None
         if ffn == DENSE:
             self.ffn = SwiGLU(cfg.hidden_size, cfg.intermediate_size,
-                              weight_attr, cfg.dtype)
+                              weight_attr, cfg.dtype, cfg.ffn_limit)
         elif ffn == MOE:
             self.ffn = DroplessMoE(
                 cfg.hidden_size, cfg.moe_intermediate_size, cfg.num_experts,
@@ -511,14 +592,14 @@ class HybridDecoderLayer(nn.Layer):
                 shared=cfg.shared_experts, scaling=cfg.routed_scaling,
                 norm_topk=cfg.norm_topk, norm_eps=cfg.routing_norm_eps,
                 activation=cfg.expert_activation, weight_attr=weight_attr,
-                dtype=cfg.dtype)
+                dtype=cfg.dtype, limit=cfg.ffn_limit)
         elif ffn == NONE:
             self.ffn = None
         else:
             raise ValueError(f"ffn_types[{index}] = {ffn!r}")
         if self.ffn is not None:
-            self.ffn_norm = RMSNorm(cfg.hidden_size, cfg.rms_eps,
-                                    dtype=cfg.dtype)
+            self.ffn_norm = cfg.norm(cfg.hidden_size)
+            self.ffn_post_norm = cfg.norm(cfg.hidden_size) if post else None
         self.residual_scale = float(cfg.residual_scale)
 
     # the residual stream is float32 whatever the weights are (as in the
@@ -529,8 +610,12 @@ class HybridDecoderLayer(nn.Layer):
         is one of them), as a product's operand."""
         return unwrap(norm(x)).astype(unwrap(norm.weight).dtype)
 
-    def _add(self, x, branch):
-        """``x + r branch`` in float32 (``r`` 1 adds as it stands)."""
+    def _add(self, x, branch, post=None):
+        """``x + r branch`` in float32 (``r`` 1 adds as it stands), the
+        branch normed first where the block norms its halves after
+        too."""
+        if post is not None:
+            branch = post(unwrap(branch))
         branch = unwrap(branch).astype(jnp.float32)
         if self.residual_scale != 1.0:
             branch = branch * self.residual_scale
@@ -545,7 +630,7 @@ class HybridDecoderLayer(nn.Layer):
         with jax.named_scope("experts" if moe else "mlp"):
             u = self._operand(self.ffn_norm, h)
             y = self.ffn(u, live) if moe else self.ffn(u)
-            return self._add(h, y)
+            return self._add(h, y, self.ffn_post_norm)
 
     def _mixer_scope(self):
         return jax.named_scope(
@@ -560,14 +645,15 @@ class HybridDecoderLayer(nn.Layer):
                 a, cache = self.mixer.forward_cached(
                     self._operand(self.operator_norm, x), cache, pos, start,
                     write_rows)
-                x = self._add(x, a)
+                x = self._add(x, a, self.operator_post_norm)
         return self._ffn(x, live), cache
 
     def forward(self, x):
         if self.mixer is not None:
             with self._mixer_scope():
                 x = self._add(
-                    x, self.mixer(self._operand(self.operator_norm, x)))
+                    x, self.mixer(self._operand(self.operator_norm, x)),
+                    self.operator_post_norm)
         return self._ffn(x, None)
 
 
@@ -600,7 +686,7 @@ class HybridConvDecoder(nn.Layer):
         self.layers = nn.LayerList([
             HybridDecoderLayer(cfg, i, weight_attr)
             for i in range(len(cfg.layer_types))])
-        self.norm = RMSNorm(cfg.hidden_size, cfg.rms_eps, dtype=cfg.dtype)
+        self.norm = cfg.norm(cfg.hidden_size)
         # an untied head lies as the table does, ``[vocab, hidden]``
         self.lm_head = None if cfg.tie_embeddings else _mat(
             self, (cfg.vocab_size, cfg.hidden_size), weight_attr, cfg.dtype)
@@ -636,9 +722,19 @@ class HybridConvDecoder(nn.Layer):
 
     def cache_spec(self, max_len):
         """Per layer that has a mixer, what it keeps: ``kv`` ring planes
-        as long as the session, or planes without columns (``conv_state``,
-        ``ssm_state``)."""
+        or a ``latent`` plane as long as the session, or planes without
+        columns (``conv_state``, ``ssm_state``)."""
         return [m.cache_spec(max_len) for m in self._mixers()]
+
+    def latent_form(self, T):
+        """The form the latent layers' cached attention is traced in for
+        a block of ``T`` tokens (``LatentAttention.cached_form``); None
+        for a model without such a layer."""
+        forms = {m.cached_form(int(T)) for m in self._mixers()
+                 if isinstance(m, LatentAttention)}
+        if not forms:
+            return None
+        return forms.pop() if len(forms) == 1 else "mixed"
 
     def init_cache(self, batch, max_len, dtype=None):
         """One cache a layer that has a mixer, in ``dtype`` (the weights'

@@ -90,6 +90,21 @@ def test_latent_forms_phase_tiny(no_native_build):
     assert [got["selects"] for got in rec.values()] == [False, True]
 
 
+def test_gated_delta_phase_tiny(no_native_build):
+    """The delta rule alone at the tiny widths: one 16-token chunk of one
+    row at scan chunks of 4 and 8 with the triangular system solved in all
+    three forms, each held to the token-by-token recurrence (float32 here,
+    so they agree to summation order), and one step of the 3 slots; the CPU
+    has no entry in the peaks' table, so no roofline share is read."""
+    rec = chip_smoke.phase_gated_delta(chip_smoke.TINY)["checked"]
+    assert set(rec["scan_ms"]) == {f"{f}/{w}" for w in (4, 8) for f in (
+        "halves", "product", "substitution")}
+    assert max(rec["scan_rel_worst"].values()) < 1e-5
+    assert rec["kept"] == "halves/8" and rec["rows"] == 3
+    assert "update_ms" in rec       # (a time on the CPU says nothing)
+    assert rec["roofline_pct"] == {"update": None, "scan": None}
+
+
 def test_kernels_phase_tiny_interprets_on_cpu(no_native_build):
     rec = chip_smoke.phase_kernels(chip_smoke.TINY)
     assert rec["checked"]["compiled_not_interpreted"] is False

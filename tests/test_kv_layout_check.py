@@ -453,3 +453,34 @@ def test_a_block_sized_slice_of_a_row_is_no_row_sized_slice(tool):
         f"  %cur.1 = {_BLOCK} dynamic-slice(%cache_0__0_.1, %row.1, %c.0, %pos.1, %c.0), dynamic_slice_sizes={{1,13,16,128}}\n"
         f"  %out = {_PLANE} dynamic-update-slice(%cache_0__0_.1, %cur.1, %row.1, %c.0, %pos.1, %c.0), {_ALIGNED}\n")
     assert tool.row_sized_slices(text, {(32, 13, 1024, 128)}) == 0
+
+
+def _serving_configs():
+    import glob
+    import json
+    root = os.path.dirname(os.path.dirname(_TOOL))
+    out = []
+    for path in sorted(glob.glob(os.path.join(root, "benchmark", "configs",
+                                              "*.json"))):
+        with open(path) as f:
+            cfg = json.load(f)
+        if "serve" in cfg:
+            out.append(cfg)
+    return out
+
+
+@pytest.mark.parametrize("cfg", _serving_configs(),
+                         ids=[c["name"] for c in _serving_configs()])
+def test_every_serving_configuration_is_taken_by_name(cfg):
+    """The tool (and ``tools/program_text_hash.py``) finds a configuration's
+    model by the ``family`` the file names, as the runners do: the module
+    has what ``main`` asks of it, and ``serve`` the three sizes it reads.
+    No table, no option a family."""
+    import importlib
+    family = importlib.import_module("benchmark.models." + cfg["family"])
+    assert callable(family.build_unweighted) and callable(family.leaf_ids)
+    sv = cfg["serve"]
+    assert all(int(sv[k]) > 0 for k in ("slots", "max_len", "prefill_chunk"))
+    assert sv["max_len"] % sv["prefill_chunk"] == 0
+    pc = family.program_config(cfg)
+    assert pc.vocab_size == cfg["vocab_size"]

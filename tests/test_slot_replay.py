@@ -106,3 +106,51 @@ def test_the_replay_of_the_long_document_cell(ramp, first_seen, window,
     # the steady window read 7.53% prefilling, 41-43 answers, the 33rd at
     # 32.9-35.1 s (PERF.md section 6, PR 44)
     assert out["round_end_s"] == pytest.approx(19.1, abs=0.1)
+
+
+@pytest.mark.parametrize("ramp,window,by_34", [
+    (8.0, 244, 187),        # the window opens a second after the cold round
+    (20.0, 273, 201),       # the round + one median answer: steady
+], ids=["after-the-round", "steady"])
+def test_the_replay_of_the_delta_rule_cell_at_128_slots(ramp, window, by_34):
+    """The delta-rule / latent cell's traffic (PR 48) at 128 slots and the
+    traced 25.33 ms step and 26.54 ms chunk: every prompt pads to 2 chunks
+    of 512, so the frontier starts at column 1,024 whichever requests the
+    first admission sees, and the cold round is 128 rows x 2 chunks + the
+    steps between them, 6.9 s.  On the chip six seeds at ``ramp_s`` 20 then
+    read 263-276 answers a window and 199-205 by 34 s (191 in the one run
+    that a 2 s host stall held up): PERF.md section 6, PR 48."""
+    config = slot_replay._load("configs", "gigachat3.5-ep16-serve")
+    config = dict(config, serve=dict(config["serve"], slots=128))
+    traffic = slot_replay._load("traffic", "reason1k-2chunk-closed-2S")
+    for first_seen in (1, 2):       # one chunk count: no first-admission race
+        out = slot_replay.replay(config, traffic, step_s=0.025332,
+                                 chunk_s=0.026541, ramp_s=ramp,
+                                 first_seen=first_seen)
+        assert out["start"] == 1024
+        assert len(out["answers"]) == window
+        assert sum(1 for a in out["answers"] if a <= 34.0) == by_34
+        assert out["round_end_s"] == pytest.approx(6.87, abs=0.02)
+        assert out["prefilling_pct"] < 0.5
+
+
+def test_the_replay_of_the_delta_rule_cell_as_committed():
+    """The cell as committed (160 slots, ``ramp_s`` 23, 225 answers a job)
+    at the traced 28.50 ms step and 25.81 ms chunk: the cold round ends at
+    8.3 s, and the ramp is that + one median answer's 512 steps.  On the
+    chip six seeds read 289-297 answers a window, 219-227 by 34 s and the
+    225th at 33.79-34.66 s (PERF.md section 6, PR 48): the replay's
+    constant step is 1-3% fast."""
+    config = slot_replay._load("configs", "gigachat3.5-ep16-serve")
+    traffic = slot_replay._load("traffic", "reason1k-2chunk-closed-2S")
+    assert (config["serve"]["slots"], traffic["ramp_s"],
+            traffic["job_requests"]) == (160, 23.0, 225)
+    out = slot_replay.replay(config, traffic, step_s=0.0284953,
+                             chunk_s=0.0258096)
+    assert out["start"] == 1024
+    assert out["round_end_s"] == pytest.approx(8.34, abs=0.02)
+    assert traffic["ramp_s"] >= out["round_end_s"] + 512 * 0.0284953
+    assert len(out["answers"]) == 298
+    assert sum(1 for a in out["answers"] if a <= 34.0) == 228
+    # the job ends inside the window with a tenth to spare
+    assert out["answers"][traffic["job_requests"] - 1] * 1.1 < 45.0

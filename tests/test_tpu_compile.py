@@ -237,3 +237,29 @@ def test_slot_loop_step_compiles_for_v5e(one_chip, cache_off):
     step = gen._build_step(size.slots, size.serve_max_len, -1)
     compiled = jax.jit(step, donate_argnums=(2,)).lower(*avals).compile()
     assert compiled.memory_analysis().argument_size_in_bytes > 2 ** 27
+
+
+F32 = jnp.float32
+# GigaChat3.5's delta-rule layer: 32 key heads under 64 value heads, 128 x 128
+_DELTA = {"scan_64": (1, 512, 64), "scan_128": (1, 512, 128),
+          "update_128_rows": (128, 1, 64)}
+
+
+@pytest.mark.parametrize("name", sorted(_DELTA))
+def test_the_delta_rule_compiles_for_v5e(name, compile_for_chip):
+    """The gated delta rule at its published widths (plain XLA: no kernel
+    of its own): a 512-token chunk of one row at both scan chunks, and one
+    step of 128 rows.  (The chunk's triangular inverse by halves is
+    ``log2 L`` rounds of two products of whole ``[L, L]`` matrices a head
+    and scan chunk.)"""
+    from paddle_tpu.nn.layer.gated_delta import delta_mix
+    rows, T, chunk = _DELTA[name]
+    G, H, N, P = 32, 64, 128, 128
+    text = compile_for_chip(
+        lambda q, k, v, a, beta, h: delta_mix(q, k, v, a, beta, h, chunk),
+        ((rows, T, G, N), BF16), ((rows, T, G, N), BF16),
+        ((rows, T, H, P), BF16), ((rows, T, H), F32), ((rows, T, H), F32),
+        ((rows, H, P, N), F32))
+    # under the names the trace is read by, and nothing but XLA
+    want = "update" if T == 1 else "scan/solve"
+    assert want in text and "tpu_custom_call" not in text

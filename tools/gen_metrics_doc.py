@@ -77,6 +77,7 @@ index: 48 layers of `attention` add up.
 | `short_conv` | `ShortConv.forward_cached`, `HybridDecoderLayer` (a convolution layer) | the gated short convolution with its norm and residual add |
 | `state_space` | `HybridDecoderLayer` (a state-space layer); inside it `Mamba2Mixer` enters `state_space/conv`, `state_space/scan` (a block of several tokens: the chunked scan) and `state_space/update` (one token: the step's update) | a Mamba-2 layer with its norm, projections, gate, group norm and residual add; `conv` the causal convolution over the block and the inputs kept before it, `scan` / `update` the recurrence itself.  `benchmark/scope_buckets.json` lists no such component, so the accepted `device_scoped_pct.*` counts these layers as unscoped; `benchmark/layer_metrics/_state_space_scope.py` reads them by reducing the capture once more with these four components listed (`step_state_space_ms`, `chunk_state_space_ms`, `state_update_roofline_pct`, `ssm_scan_roofline_pct`) |
 | `linear_attention` (inside `attention`) | `LightningAttention._mix` around the recurrence; inside it `mamba2.state_mix` enters `linear_attention/scan` (a block of several tokens: the chunked scan) and `linear_attention/update` (one token: the step's update) | the float32 matrix state's recurrence alone (`S_t = exp(-s_h) S_{t-1} + k_t^T v_t`, read through `q`); the layer's projections, norms, positions and gate lie directly under `attention`, where the accepted `step_attention_ms.*` / `chunk_attention_ms.*` count the whole layer.  `benchmark/layer_metrics/_sala_scope.py` reads the component and its parts by reducing the capture once more with them listed (`step_linear_attention_ms`, `chunk_linear_attention_ms`; `linear_update_roofline_pct` and `linear_scan_roofline_pct` take the time under `update` / `scan` plus the compiler's own copies of a whole state plane, `linear_attention/plane_copy` in the reader's printed table: the step's update writes each new plane into the chip's other memory space and a copy that carries no scope takes it to the output; no other op without a scope is counted) |
+| `linear_attention/scan/solve`, `linear_attention/scan/carry` (inside `attention`) | `nn/layer/gated_delta.py::delta_scan`, under the SAME `linear_attention/scan` that a lightning layer's scan lies under (and `delta_update` under `linear_attention/update`: `GatedDeltaNet._mix` enters `linear_attention`, `delta_mix` the part) | a gated delta-rule layer's chunked scan in its two parts: `solve`, the triangular system that gives a scan chunk's pseudo-values (the scores `K K^T`, `unit_lower_inverse`, its two right-hand sides), the part no other linear layer has; `carry`, the chunks' own sums and the state passed from one to the next.  The accepted `_sala_scope.py` lists neither part, so its readers go on counting both under `linear_attention/scan` (`chunk_linear_attention_ms`, `linear_scan_roofline_pct`, with this family's operations and bytes from `benchmark/counts/gigachat3_5.py`); `benchmark/layer_metrics/_delta_scope.py` reduces the capture once more with the two listed (`chunk_delta_solve_ms`).  The layer's convolution lies under `attention/conv`, its projections, norm and gate directly under `attention` |
 | `sparse_attention` (inside `attention`) | `BlockSparseAttention.forward_cached`: `sparse_attention/pool` (`pool_keys_write`: the pooled keys that the block completes, written under each row's mask), `sparse_attention/select` (`choose_blocks`: the pooled scores, the blocks' scores, the choice), `sparse_attention/read` (the attention itself, in both programs: `span_attention` over every valid column of the span under `block_keep`'s mask of the chosen blocks; the tree holds no gathered form, PERF.md section 6, PR 44) | a block-sparse layer's own work beside its projections; `_sala_scope.py` reads it (`step_sparse_attention_ms`, `chunk_sparse_attention_ms`; `sparse_read_roofline_pct` = the chosen blocks' and pooled entries' least bytes over the step's time under `select` + `read`) |
 | `head` | the logits product of every model, BERT's pooler and heads; `head/sample` in `Generator._build_step` | vocabulary product; the step's `argmax` / `where` |
 | `loss`, `optimizer`, `cast_params` | `TrainStep._loss_of` and the models' own loss, the optimizer update of `TrainStep._build_step`, `TrainStep._cast_compute` | the training step's own parts; backward is told from forward by the `transpose(jvp(..))` wrapper JAX writes itself |
@@ -211,7 +212,13 @@ steps' live rows, and their `chunk_` twins over the chunks' valid tokens.
 a step.  Such a loop counts `kv_columns_valid` like a plain K/V model's, and
 no `attn_blocks_*`: those count the column blocks of a span over plain K/V
 planes alone (kind `kv`), and this loop's span is read under the chosen
-blocks' mask (what it keeps of the span is in `sparse_blocks_*`).
+blocks' mask (what it keeps of the span is in `sparse_blocks_*`).  A loop
+over a model that keeps ONE kind of plane with columns beside states without
+(a latent plane without selector or window beside `ssm_state` layers:
+`stats()["plane_kinds"]` `["latent", "ssm_state"]`) counts the latent plane's
+valid columns under `kv_columns_valid` too, once a dispatch, beside the
+latent family's own `attn_columns_*` a layer; a model of latent planes ALONE
+counts the latter only.
 
 The plain slot loop keeps ONE decode step in flight: it dispatches step k+1
 and only then reads step k's tokens (`paddle_tpu/serving/slots.py`, "One step
